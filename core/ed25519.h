@@ -1,5 +1,5 @@
 // Ed25519 (RFC 8032) for the C++ replica core: the *CPU verifier backend*
-// (the control arm of the CPU-vs-TPU A/B, BASELINE.md config 2) and the
+// (the control arm of the CPU-vs-TPU A/B, BASELINE.json config 2) and the
 // host-side signer used by pbftd.
 //
 // Our own implementation: GF(2^255-19) in 5x51-bit limbs with unsigned

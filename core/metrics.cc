@@ -21,6 +21,9 @@ const char* kCounterNames[] = {
     "pbft_view_changes_total",       "pbft_verify_batches_total",
     "pbft_verify_items_total",       "pbft_verify_rejected_total",
     "pbft_verify_deadline_fired_total",
+    // Batches verified on the host although a verify service is
+    // configured (service warming / unreachable / dead / past deadline).
+    "pbft_verify_service_fallbacks_total",
     // Wire-codec surface: outbound frames per payload codec, plus the
     // serialize-once invariant counter (encodes per broadcast, never per
     // peer — tests compare it against the broadcast count).

@@ -1431,7 +1431,7 @@ void ReplicaServer::check_verify_deadline(
       now - inflight_start_ < std::chrono::milliseconds(verify_deadline_ms_)) {
     return;
   }
-  // Wedged async verifier (ADVICE.md core/net.cc item): the connection is
+  // Wedged async verifier: the connection is
   // alive but the reply never comes, so verify_inflight_ would stay true
   // forever. Drop the transport and run the CPU safety net on the batch —
   // same degradation contract as a detected transport failure. Any late
@@ -1450,6 +1450,7 @@ void ReplicaServer::check_verify_deadline(
   }
   CpuVerifier safety_net;
   auto verdicts = safety_net.verify_batch(inflight_items_);
+  ++safety_net_batches_;
   auto dispatched_at = inflight_start_;
   size_t n_items = inflight_items_.size();
   verify_inflight_ = false;
@@ -1540,10 +1541,21 @@ void ReplicaServer::run_verify_batch() {
   deliver_verified(items.size(), t0, verifier_->verify_batch(items));
 }
 
+int64_t ReplicaServer::verify_service_fallbacks() const {
+  return safety_net_batches_ + verifier_->host_fallbacks();
+}
+
 void ReplicaServer::deliver_verified(size_t n_items,
                                      std::chrono::steady_clock::time_point t0,
                                      std::vector<uint8_t> verdicts) {
   ++batches_run_;
+  // Every host-fallback path ends here, so the counter metric follows
+  // the total without a hook in each of them.
+  if (int64_t fb = verify_service_fallbacks(); fb > fallbacks_reported_) {
+    metrics_.inc("pbft_verify_service_fallbacks_total",
+                 fb - fallbacks_reported_);
+    fallbacks_reported_ = fb;
+  }
   {
     FlightRecorder& fl = global_flight();
     if (fl.enabled()) {
@@ -1592,6 +1604,7 @@ void ReplicaServer::finish_verify_async() {
     // never safety/liveness — re-verify this batch in-process.
     CpuVerifier safety_net;
     verdicts = safety_net.verify_batch(inflight_items_);
+    ++safety_net_batches_;
   }
   auto dispatched_at = inflight_start_;
   size_t n_items = inflight_items_.size();
@@ -2490,6 +2503,10 @@ std::string ReplicaServer::metrics_json() {
   o["chaos_dropped"] =
       Json(chaos_dropped_ + (shards_ ? shards_->chaos_dropped() : 0));
   o["verify_deadline_fired"] = Json(verify_deadline_fired_);
+  // Batches verified on the host although a verify service is configured
+  // (warming, unreachable, killed mid-stream, past its deadline): the
+  // liveness fallback, counted so it cannot hide a dead device.
+  o["verify_service_fallbacks"] = Json(verify_service_fallbacks());
   // Fast-path surface (ISSUE 14): the negotiated-offer mode, tentative
   // execution, MAC frame tallies, committed floor.
   o["mode"] = Json(std::string(fastpath_mac_ ? "mac" : "sig"));

@@ -361,7 +361,7 @@ class ReplicaServer {
   Metrics& metrics() { return metrics_; }
   std::string metrics_prometheus() const;
 
-  // Wedged-async-verifier bound (ADVICE.md): an inflight remote launch
+  // Wedged-async-verifier bound: an inflight remote launch
   // older than this is abandoned — connection dropped, batch re-verified
   // on the CPU safety net, verify_deadline_fired traced + counted.
   // Generous default: a first XLA compile can legitimately take tens of
@@ -740,6 +740,12 @@ class ReplicaServer {
   std::chrono::steady_clock::time_point inflight_start_{};
   int verify_deadline_ms_ = 15000;
   int64_t verify_deadline_fired_ = 0;  // surfaced in metrics_json
+  // Batches the CPU safety net verified HERE because the remote launch
+  // failed or overran its deadline. verify_service_fallbacks() adds the
+  // verifier's own host fallbacks (service warming/unreachable/killed).
+  int64_t safety_net_batches_ = 0;
+  int64_t fallbacks_reported_ = 0;  // already fed to the counter metric
+  int64_t verify_service_fallbacks() const;
 
   // Health-document progress tracker (ISSUE 16): the executed_upto we
   // last saw move and when we saw it. Updated by refresh_health(), so

@@ -259,6 +259,12 @@ static std::vector<uint8_t> encode_request(
   return buf;
 }
 
+std::vector<uint8_t> RemoteVerifier::verify_on_host(
+    const std::vector<VerifyItem>& items) {
+  ++host_fallbacks_;
+  return fallback_.verify_batch(items);
+}
+
 std::vector<uint8_t> RemoteVerifier::verify_batch(
     const std::vector<VerifyItem>& items) {
   if (items.empty()) return {};
@@ -271,7 +277,7 @@ std::vector<uint8_t> RemoteVerifier::verify_batch(
     fd_ = -1;
     inflight_ = false;
   }
-  if (!ensure_connected()) return fallback_.verify_batch(items);
+  if (!ensure_connected()) return verify_on_host(items);
   auto buf = encode_request(items);
   std::vector<uint8_t> out(items.size());
   if (!write_all(fd_, buf.data(), buf.size()) ||
@@ -279,7 +285,7 @@ std::vector<uint8_t> RemoteVerifier::verify_batch(
     // Killed mid-stream: drop the link (with reconnect backoff) and
     // verify THIS batch on the native pool — the liveness contract.
     drop_connection();
-    return fallback_.verify_batch(items);
+    return verify_on_host(items);
   }
   return out;
 }

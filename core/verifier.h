@@ -5,7 +5,7 @@
 //   process-wide worker pool (core/verify_pool.cc): fixed RLC windows
 //   (random-linear-combination check + Pippenger MSM, bisecting failing
 //   windows to per-item verify) dispatched across threads — the control
-//   arm (BASELINE.md configs 1-2). Pooled and serial verification share
+//   arm (BASELINE.json configs 1-2). Pooled and serial verification share
 //   window boundaries, so the accept set is thread-count independent; see
 //   the accept-set note in ed25519.cc for the one documented divergence
 //   from strict per-item semantics (colluding torsion-defect pairs inside
@@ -75,6 +75,11 @@ class Verifier {
   // net.cc check_verify_deadline): drop the transport so a late reply
   // lands on a closed socket instead of mis-pairing with the next batch.
   virtual void cancel_inflight() {}
+  // Batches this backend verified on the host because its service was
+  // warming, unreachable or died mid-stream (RemoteVerifier's liveness
+  // fallback). Reported so a cluster that never reached the device is
+  // distinguishable from one that did. 0 for in-process backends.
+  virtual int64_t host_fallbacks() const { return 0; }
   // How many verification lanes one dispatch can occupy — the event loop
   // sizes its accumulation window to capacity instead of one inflight
   // window (net.cc run_verify_batch). 1 for serial/remote backends; the
@@ -101,6 +106,7 @@ class RemoteVerifier : public Verifier {
   bool begin_batch(const std::vector<VerifyItem>& items) override;
   bool poll_result(std::vector<uint8_t>* out, bool* failed) override;
   void cancel_inflight() override;
+  int64_t host_fallbacks() const override { return host_fallbacks_; }
   // Test hook: adopt an already-connected fd (e.g. a socketpair end).
   void adopt_fd_for_test(int fd) { fd_ = fd; }
 
@@ -134,9 +140,12 @@ class RemoteVerifier : public Verifier {
   // (called after every successful connect, including legacy re-dials).
   void tune_send_budget();
   void drop_connection();
+  // The host fallback, counted (every path to the native pool goes here).
+  std::vector<uint8_t> verify_on_host(const std::vector<VerifyItem>& items);
   std::string target_;
   int fd_ = -1;
   CpuVerifier fallback_;
+  int64_t host_fallbacks_ = 0;
   ServiceState state_ = ServiceState::kUnknown;
   // Target answered no status probe once (pre-handshake service):
   // assumed ready, and reconnects skip the probe deadline entirely so a

@@ -1,4 +1,4 @@
-"""pbft_tpu.bench — the benchmark harness for BASELINE.md's five configs.
+"""pbft_tpu.bench — the benchmark harness for BASELINE.json's five configs.
 
 The repo-root ``bench.py`` prints the single headline metric (batched
 Ed25519 verifies/sec on one chip); this package measures the *consensus*

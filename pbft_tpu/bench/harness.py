@@ -63,13 +63,12 @@ class BenchResult:
 
 def _verifier(arm: str, batch_pad: int) -> Callable:
     if arm == "cpu":
-        try:
-            from .. import native
+        from .. import native
 
-            if native.available():
-                return native.verify_batch
-        except Exception:
-            pass
+        # False only where there is no compiler; a build that FAILS raises
+        # instead of quietly timing the Python oracle.
+        if native.available():
+            return native.verify_batch
         from ..crypto import ref
 
         return lambda items: [ref.verify(p, m, s) for p, m, s in items]
@@ -467,7 +466,7 @@ def main() -> None:
         "--trace-dir",
         default=None,
         help="write per-replica JSONL traces here (native arms only) — "
-        "input for scripts/launch_cost_model.py",
+        "input for scripts/trace_report.py",
     )
     parser.add_argument(
         "--secure",

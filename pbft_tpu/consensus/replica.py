@@ -93,7 +93,7 @@ _HOST_SIGNER = None
 
 def _host_sign(seed: bytes, msg: bytes) -> bytes:
     """Host-side message signing: the native C++ signer when built
-    (~40-55 us warm; BASELINE.md "Native-runtime arm"),
+    (~40-55 us warm on the host),
     else the pure-Python oracle (~4 ms). The two are byte-identical (RFC
     8032 deterministic signatures; parity pinned by
     tests/test_native_crypto.py), so the choice cannot diverge replicas."""

@@ -47,7 +47,7 @@ EQUIV_SUFFIX = "#equiv"
 
 
 def cpu_verifier(items: List[Tuple[bytes, bytes, bytes]]) -> List[bool]:
-    """Per-message host verification — the control arm (BASELINE.md config 1)."""
+    """Per-message host verification — the control arm (BASELINE.json config 1)."""
     return [crypto.verify(pub, msg, sig) for pub, msg, sig in items]
 
 

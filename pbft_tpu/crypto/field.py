@@ -171,15 +171,12 @@ def _pick_mul():
         return _mul_conv
     if impl == "schoolbook":
         return _mul_schoolbook
-    try:
-        import jax
+    import jax
 
-        backend = jax.default_backend()
-    except Exception:
-        backend = "cpu"
     # conv wins on TPU-class backends; the shifted-accumulate loop wins on
-    # XLA:CPU (measured ~2x each way).
-    return _mul_schoolbook if backend == "cpu" else _mul_conv
+    # XLA:CPU (measured ~2x each way). A backend that fails to initialize
+    # raises here: answering "cpu" would hide a dead chip.
+    return _mul_schoolbook if jax.default_backend() == "cpu" else _mul_conv
 
 
 def mul(a, b):

@@ -8,7 +8,10 @@ environment; the C ABI in core/capi.cc is the binding surface.
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import fcntl
+import hashlib
 import json
 import os
 import shutil
@@ -19,6 +22,10 @@ from typing import List, Optional, Sequence, Tuple
 _REPO_ROOT = Path(__file__).resolve().parent.parent
 _BUILD_DIR = _REPO_ROOT / "build-core"
 _LIB_PATH = _BUILD_DIR / "libpbftcore.so"
+# What a current build directory holds; LocalCluster needs pbftd, the
+# ctest wrapper needs core_test.
+_ARTIFACTS = ("libpbftcore.so", "pbftd", "core_test")
+_STAMP = ".source-stamp"
 
 _lib: Optional[ctypes.CDLL] = None
 
@@ -32,13 +39,31 @@ _LIB_SOURCES = [
 ]
 
 
-def _build_direct() -> Path:
+class NativeToolchainMissing(RuntimeError):
+    """No C++ compiler on this machine: the native core cannot exist here
+    (the one case ``available()`` answers False for)."""
+
+
+class NativeBuildError(RuntimeError):
+    """The compiler ran and failed; the message carries its output."""
+
+
+def _run_build_step(cmd: List[str]) -> None:
+    proc = subprocess.run(cmd, capture_output=True, text=True)
+    if proc.returncode != 0:
+        raise NativeBuildError(
+            f"native core build failed (exit {proc.returncode}): "
+            f"{' '.join(cmd)}\n{proc.stdout[-4000:]}{proc.stderr[-8000:]}"
+        )
+
+
+def _build_direct() -> None:
     """Fallback build without cmake/ninja: drive g++ directly (same flags
     as the CMake Release config). Keeps the native arm usable on stripped
     containers where only a compiler is present."""
     cxx = shutil.which("g++") or shutil.which("c++") or shutil.which("clang++")
     if cxx is None:
-        raise RuntimeError("no C++ compiler found for the native core")
+        raise NativeToolchainMissing("no C++ compiler found for the native core")
     _BUILD_DIR.mkdir(exist_ok=True)
     core = _REPO_ROOT / "core"
     # Strict by default, like the CMake STRICT option: warnings fail the
@@ -47,37 +72,88 @@ def _build_direct() -> Path:
     common = ["-O2", "-std=c++17", "-Wall", "-Wextra", "-pthread"]
     if not os.environ.get("PBFT_CORE_NO_WERROR"):
         common.append("-Werror")
-    subprocess.run(
-        [cxx, *common, "-fPIC", "-shared", "-o", str(_LIB_PATH)]
-        + [str(core / s) for s in _LIB_SOURCES],
-        check=True,
-        capture_output=True,
+    _run_build_step(
+        [cxx, *common, "-fPIC", "-shared", "-o", str(_BUILD_DIR / "libpbftcore.so")]
+        + [str(core / s) for s in _LIB_SOURCES]
     )
     for exe, src in (("pbftd", "pbftd.cc"), ("core_test", "core_test.cc")):
-        subprocess.run(
+        _run_build_step(
             [cxx, *common, "-o", str(_BUILD_DIR / exe), str(core / src),
-             "-L", str(_BUILD_DIR), "-lpbftcore", "-Wl,-rpath,$ORIGIN"],
-            check=True,
-            capture_output=True,
+             "-L", str(_BUILD_DIR), "-lpbftcore", "-Wl,-rpath,$ORIGIN"]
         )
-    return _LIB_PATH
+
+
+def _build_cmake() -> None:
+    core = _REPO_ROOT / "core"
+    cache = _BUILD_DIR / "CMakeCache.txt"
+    if cache.exists():
+        # A build directory configured for another checkout (a copied
+        # tree) is not ours to update: start over.
+        text = cache.read_text(errors="replace")
+        if (
+            f"CMAKE_HOME_DIRECTORY:INTERNAL={core}\n" not in text
+            or f"CMAKE_CACHEFILE_DIR:INTERNAL={_BUILD_DIR}\n" not in text
+        ):
+            shutil.rmtree(_BUILD_DIR)
+    _run_build_step(
+        ["cmake", "-S", str(core), "-B", str(_BUILD_DIR), "-G", "Ninja"]
+    )
+    _run_build_step(["cmake", "--build", str(_BUILD_DIR)])
+
+
+def _source_stamp() -> str:
+    """Digest of everything the binaries are made from: every file under
+    core/, the checkout's path (cmake bakes it into the artifacts' run
+    paths, so a copied tree is not current) and the -Werror lever."""
+    h = hashlib.sha256()
+    h.update(str(_REPO_ROOT).encode())
+    h.update(b"no-werror" if os.environ.get("PBFT_CORE_NO_WERROR") else b"strict")
+    for path in sorted((_REPO_ROOT / "core").iterdir()):
+        if path.is_file():
+            h.update(path.name.encode() + b"\0")
+            h.update(path.read_bytes())
+    return h.hexdigest()
+
+
+def _is_current(stamp: str) -> bool:
+    try:
+        recorded = (_BUILD_DIR / _STAMP).read_text()
+    except OSError:
+        return False
+    return recorded == stamp and all(
+        (_BUILD_DIR / name).exists() for name in _ARTIFACTS
+    )
+
+
+@contextlib.contextmanager
+def _build_lock():
+    """One builder at a time across processes (a cluster's replicas and
+    the test runner all call build() at start-up)."""
+    # The lock rides on the core/ directory itself: nothing to create,
+    # and it survives a wipe of the build directory.
+    fd = os.open(_REPO_ROOT / "core", os.O_RDONLY)
+    try:
+        fcntl.flock(fd, fcntl.LOCK_EX)
+        yield
+    finally:
+        os.close(fd)
 
 
 def build(force: bool = False) -> Path:
-    """Build the native core with cmake+ninja (idempotent); falls back to
-    a direct g++ build when cmake or ninja is unavailable."""
-    if _LIB_PATH.exists() and not force:
-        return _LIB_PATH
-    if shutil.which("cmake") is None or shutil.which("ninja") is None:
-        return _build_direct()
-    subprocess.run(
-        ["cmake", "-S", str(_REPO_ROOT / "core"), "-B", str(_BUILD_DIR), "-G", "Ninja"],
-        check=True,
-        capture_output=True,
-    )
-    subprocess.run(
-        ["cmake", "--build", str(_BUILD_DIR)], check=True, capture_output=True
-    )
+    """Make build-core/ current for the sources as they are NOW and return
+    the library path. Current means: the recorded source stamp matches
+    core/* and this checkout's path, and the library, pbftd and core_test
+    all exist — an existing .so proves nothing. Otherwise rebuild with
+    cmake+ninja (direct g++ when either is missing). A failed build raises
+    NativeBuildError with the compiler's output."""
+    stamp = _source_stamp()
+    with _build_lock():
+        if force or not _is_current(stamp):
+            if shutil.which("cmake") is None or shutil.which("ninja") is None:
+                _build_direct()
+            else:
+                _build_cmake()
+            (_BUILD_DIR / _STAMP).write_text(stamp)
     return _LIB_PATH
 
 
@@ -91,10 +167,13 @@ def lib() -> ctypes.CDLL:
 
 
 def available() -> bool:
+    """False only where there is no C++ toolchain. A build that FAILS is
+    not "unavailable": it raises, so nothing quietly swaps in the Python
+    oracle for a broken core."""
     try:
         lib()
         return True
-    except Exception:
+    except NativeToolchainMissing:
         return False
 
 

@@ -134,6 +134,9 @@ class ClientGateway:
         self._metrics_server = None
         self.metrics_listen_port = 0
         self._server: Optional[asyncio.Server] = None
+        # Accepted client connections, so stop() can close them: since
+        # Python 3.12 Server.wait_closed() waits for every handler.
+        self._inbound: set = set()
         # token -> downstream writer (the reply route), and the per-token
         # forwarded-timestamp high-water mark (retransmission detection).
         self._routes: Dict[str, asyncio.StreamWriter] = {}
@@ -219,6 +222,8 @@ class ClientGateway:
             self._metrics_server.server_close()
         if self._server:
             self._server.close()
+            for writer in list(self._inbound):
+                writer.close()
             await self._server.wait_closed()
         for link in self._links.values():
             link.writer.close()
@@ -275,6 +280,7 @@ class ClientGateway:
     ) -> None:
         self.clients_open += 1
         self._set_clients_gauge()
+        self._inbound.add(writer)
         owned_tokens: List[str] = []
         try:
             buf = b""
@@ -295,6 +301,7 @@ class ClientGateway:
         finally:
             self.clients_open -= 1
             self._set_clients_gauge()
+            self._inbound.discard(writer)
             for token in owned_tokens:
                 if self._routes.get(token) is writer:
                     self._routes.pop(token, None)

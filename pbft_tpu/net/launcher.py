@@ -3,7 +3,7 @@
 The reference's 'launcher' was four shell windows plus netcat
 (README.md:5-43); here the same scenario is a context manager used by the
 integration tests and the benchmark harness. Builds the native core on
-demand (cmake+ninja, pbft_tpu.native.build)."""
+demand (pbft_tpu.native.build: rebuilt whenever core/* changed)."""
 
 from __future__ import annotations
 
@@ -177,6 +177,21 @@ class LocalCluster:
         self.metrics_every = metrics_every
         self.vc_timeout_ms = vc_timeout_ms
         self.impl = [impl] * self.config.n if isinstance(impl, str) else list(impl)
+        # One process per chip: a "py" replica with verifier="jax"
+        # initializes JAX in-process, and on an accelerator only the first
+        # such process gets the device. That arm is the CPU test arm; on
+        # the chip every replica of either runtime points at verifyd's
+        # address instead.
+        if (
+            verifier == "jax"
+            and self.impl.count("py") > 1
+            and os.environ.get("JAX_PLATFORMS") != "cpu"
+        ):
+            raise ValueError(
+                f"{self.impl.count('py')} in-process jax replicas would "
+                "each claim the accelerator; pin JAX_PLATFORMS=cpu (the "
+                "test arm) or pass verifier=<verifyd host:port>"
+            )
         # Per-replica environment overrides (e.g. PBFT_WIRE_CODEC=json to
         # force a JSON-only 1.0.0 peer in a mixed-codec interop test).
         self.extra_env = extra_env or [None] * self.config.n

@@ -425,6 +425,9 @@ class AsyncReplicaServer:
         # Recently broadcast messages, for the stutter mode's replays.
         self._stutter_history: List[Message] = []
         self._server: Optional[asyncio.Server] = None
+        # Accepted connections, so stop() can close them: since Python
+        # 3.12 Server.wait_closed() waits for every handler to finish.
+        self._inbound: set = set()
         # Gateway tier (ISSUE 10): inbound links whose hello carried
         # role=gateway. Framed client requests arrive on them; replies for
         # the clients they forwarded fan BACK over the same link instead
@@ -565,11 +568,13 @@ class AsyncReplicaServer:
             self._metrics_server.server_close()
         if self._discovery:
             self._discovery.stop()
-        if self._server:
-            self._server.close()
-            await self._server.wait_closed()
         for link in self._peer_links.values():
             link.writer.close()
+        if self._server:
+            self._server.close()
+            for writer in list(self._inbound):
+                writer.close()
+            await self._server.wait_closed()
 
     # -- inbound ------------------------------------------------------------
 
@@ -577,6 +582,7 @@ class AsyncReplicaServer:
         self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter
     ) -> None:
         self._conn_delta(+1)
+        self._inbound.add(writer)
         try:
             first = await reader.read(1)
             if not first:
@@ -589,6 +595,7 @@ class AsyncReplicaServer:
             pass
         finally:
             self._conn_delta(-1)
+            self._inbound.discard(writer)
             writer.close()
 
     # -- scale-out accounting (ISSUE 10) -------------------------------------
@@ -1070,6 +1077,13 @@ class AsyncReplicaServer:
                 self.metrics_registry.counter("pbft_verify_rejected_total").inc(
                     verdicts.count(False)
                 )
+                if self.service_verifier is not None:
+                    # The counter follows the client's own tally (parity
+                    # with core/net.cc deliver_verified).
+                    fb = self.metrics_registry.counter(
+                        "pbft_verify_service_fallbacks_total"
+                    )
+                    fb.inc(self.service_verifier.used_fallback - fb.value)
                 self.metrics_registry.histogram("pbft_verify_batch_size").observe(len(items))
                 self.metrics_registry.histogram("pbft_verify_seconds").observe(secs)
                 # In-process verifier: the "inflight age" IS the last

@@ -1,28 +1,32 @@
 """The persistent multi-chip verify service: own the accelerator, pay
 compile once, shard every window.
 
-Why a daemon (ROADMAP item 1, BENCH_r02-r05 postmortem): the in-bench TPU
-probe paid backend init + an 816 s cold kernel compile *inside* the round
-budget, so the CPU fallback won by default. The service flips the
-lifecycle: one long-lived process initializes the JAX backend ONCE,
+One long-lived process per host initializes the JAX backend ONCE,
 AOT-compiles the sharded verify kernel for every fixed `_PAD_LADDER`
 window shape at startup (``jax.jit(...).lower().compile()`` ahead of
-first traffic, persistent on-disk cache keyed by host identity + CPU via
-``utils/cache.host_keyed_cache_dir``, optional serialized-executable
-export so a warm restart skips even tracing), and then serves the
+first traffic, through JAX's persistent compile cache placed by
+``utils/cache.configure_compile_cache``), and then serves the
 128-byte-triple protocol from ``service.py`` for its whole lifetime —
 batches from ALL colocated replicas coalesce into one XLA launch sharded
-across every local device (``parallel/verifier.py``).
+across every local device (``parallel/verifier.py``). A chip belongs to
+one process: this one. Replicas and benches stay off JAX and dial it.
 
 Readiness handshake: a request with item count 0 returns an 8-byte
 status record (state warming|ready|cpu-only + device count + warmed
 shape count); count 0xFFFFFFFF returns a length-prefixed JSON status
-(compile timings, shapes, uptime) for humans and the bench. Replicas —
-``core/verifier.cc`` RemoteVerifier and the asyncio runtime via
-:class:`ServiceVerifier` — dial with a SHORT connect deadline, consume
-the handshake, and fall back to the PR-2 native pool
+(platform, device kind, devices seen and in the mesh, per-shape compile
+seconds, engine and fallback dispatch counts) for humans, the bench and
+``chip_smoke.py``. Replicas — ``core/verifier.cc`` RemoteVerifier and the
+asyncio runtime via :class:`ServiceVerifier` — dial with a SHORT connect
+deadline, consume the handshake, and fall back to the native pool
 (``consensus.replica.host_batch_verify``) while the service is warming
-or gone: a cold accelerator can never block consensus.
+or gone: a cold accelerator can never block consensus. Both ends count
+those fallbacks, so a run that never reached the device shows it.
+
+``--backend jax`` is the chip deployment: a warm-up failure, or a
+backend that is not a TPU (unless ``JAX_PLATFORMS`` itself names cpu,
+the test arm), ends the process non-zero. ``auto`` degrades to
+``cpu-only`` instead, for chip-less deployments.
 
 Host↔device pipeline: every window is staged with an async
 ``jax.device_put`` against the batch sharding and launched through a
@@ -37,10 +41,11 @@ from __future__ import annotations
 
 import json
 import os
-import pickle
 import socket
+import sys
 import threading
 import time
+import warnings
 from typing import Callable, List, Optional, Sequence, Tuple
 
 # The readiness wire format (STATUS_* / STATE_* / pack_status /
@@ -71,23 +76,19 @@ class ShardedVerifyEngine:
     """Owns the JAX backend: one mesh over the host's local devices and one
     AOT-compiled, input-donating sharded verify executable per window shape.
 
-    ``warm()`` is the once-per-deploy cost the daemon pays at startup,
-    outside any request: per shape it first tries the serialized-executable
-    export (``deserialize_and_load`` — no tracing at all), else lowers and
-    compiles (the persistent compile cache makes a warm restart cheap) and
-    writes the export for next time. Export files are keyed by host cache
-    key + device count + kernel tag, so a foreign or re-meshed artifact is
-    never loaded (same contract as utils/cache).
+    ``init_backend()`` touches the backend (platform, device kind, the
+    devices JAX sees, the mesh); ``warm()`` then lowers and compiles every
+    window shape — the once-per-deploy cost the daemon pays at startup,
+    outside any request. JAX's persistent compile cache, keyed by the
+    lowered module, makes a restart over unchanged kernels a cache hit
+    and a changed kernel a miss.
     """
 
     def __init__(
         self,
         shapes: Optional[Sequence[int]] = None,
         devices: Optional[int] = None,
-        cache_root: Optional[str] = None,
-        export_dir: Optional[str] = None,
         kernel=None,
-        kernel_tag: str = "ed25519",
     ):
         if shapes is None:
             from ..crypto.batch import _PAD_LADDER
@@ -95,90 +96,81 @@ class ShardedVerifyEngine:
             shapes = _PAD_LADDER
         self._want_shapes = tuple(sorted(set(shapes)))
         self._want_devices = devices
-        self._cache_root = cache_root
-        self._export_dir = export_dir
         self._kernel = kernel
-        self._kernel_tag = kernel_tag
         self._lock = threading.Lock()
         self._mesh = None
         self._spec = None
         self._compiled: dict = {}  # padded size -> jax.stages.Compiled
-        self.device_count = 0
+        self.platform: Optional[str] = None
+        self.device_kind: Optional[str] = None
+        self.devices_seen = 0  # len(jax.devices())
+        self.device_count = 0  # devices in the mesh
         self.stats: dict = {}
 
     # -- startup -------------------------------------------------------------
 
-    def _export_path(self, size: int) -> Optional[str]:
-        if not self._export_dir:
-            return None
-        from ..utils.cache import host_cache_key
+    def init_backend(self) -> None:
+        """First backend touch: record what JAX runs on and build the mesh."""
+        from ..utils.cache import configure_compile_cache
 
-        name = (
-            f"verify-{self._kernel_tag}-{host_cache_key()}"
-            f"-d{self.device_count}-b{size}.exec"
-        )
-        return os.path.join(self._export_dir, name)
-
-    def warm(self) -> dict:
-        """Initialize the backend and precompile every window shape.
-
-        Returns (and stores in ``self.stats``) the warmup accounting:
-        ``aot_loaded``/``compiled`` per-shape counts, ``warm_load_s``
-        (seconds spent reloading serialized executables) and
-        ``cold_compile_s`` (seconds spent tracing+compiling — cache-hit
-        cheap on a warm restart, minutes on a truly cold deploy).
-        """
-        from ..utils.cache import host_keyed_cache_dir
-
-        if self._cache_root:
-            os.environ.setdefault(
-                "JAX_COMPILATION_CACHE_DIR",
-                host_keyed_cache_dir(self._cache_root),
-            )
+        cache_dir = configure_compile_cache()
         import jax
 
-        if "JAX_COMPILATION_CACHE_DIR" in os.environ:
-            try:
-                jax.config.update(
-                    "jax_compilation_cache_dir",
-                    os.environ["JAX_COMPILATION_CACHE_DIR"],
-                )
-                jax.config.update(
-                    "jax_persistent_cache_min_compile_time_secs", 0.5
-                )
-            except Exception:  # pragma: no cover - knob renamed upstream
-                pass
-        from ..parallel import batch_sharding, compile_sharded, make_mesh
+        from ..parallel import batch_sharding, make_mesh
 
         with self._lock:
+            seen = jax.devices()
             devs = jax.local_devices()
             if self._want_devices:
                 devs = devs[: self._want_devices]
+            self.platform = seen[0].platform
+            self.device_kind = seen[0].device_kind
+            self.devices_seen = len(seen)
             self.device_count = len(devs)
             self._mesh = make_mesh(devices=devs)
             self._spec = batch_sharding(self._mesh)
-            if self._export_dir:
-                os.makedirs(self._export_dir, exist_ok=True)
-            stats = {
-                "devices": self.device_count,
-                "shapes": [],
-                "aot_loaded": 0,
-                "compiled": 0,
-                "warm_load_s": 0.0,
-                "cold_compile_s": 0.0,
-            }
-            for want in self._want_shapes:
-                size = self._round_to_mesh(want)
-                if size in self._compiled:
-                    continue
-                t0 = time.perf_counter()
-                compiled = self._load_export(size)
-                if compiled is not None:
-                    stats["aot_loaded"] += 1
-                    stats["warm_load_s"] += time.perf_counter() - t0
-                else:
-                    import warnings
+            self.stats = {"cache_dir": cache_dir}
 
+    def warm(self) -> dict:
+        """Precompile every window shape (``init_backend`` runs first if
+        the caller has not).
+
+        Returns (and stores in ``self.stats``) the warm-up accounting, as
+        set-up facts: per shape the seconds spent, whether the persistent
+        cache answered, and the devices its input sharding spans;
+        ``cold_compile_s`` sums the shapes that traced+compiled,
+        ``warm_load_s`` the shapes the cache answered.
+        """
+        if self._mesh is None:
+            self.init_backend()
+        import jax
+
+        from ..parallel import compile_sharded
+
+        hits: list = []
+
+        def on_event(name: str, **_kw) -> None:
+            if name == "/jax/compilation_cache/cache_hits":
+                hits.append(name)
+
+        jax.monitoring.register_event_listener(on_event)
+        try:
+            with self._lock:
+                stats = dict(
+                    self.stats,
+                    shapes=[],
+                    per_shape=[],
+                    compiled=0,
+                    cache_hits=0,
+                    cold_compile_s=0.0,
+                    warm_load_s=0.0,
+                )
+                for want in self._want_shapes:
+                    size = self._round_to_mesh(want)
+                    if size in self._compiled:
+                        continue
+                    hits.clear()
+                    t0 = time.perf_counter()
                     with warnings.catch_warnings():
                         # Donation cannot alias the (B,128B) inputs to the
                         # (B,) bool output, so XLA warns per shape; the
@@ -190,55 +182,39 @@ class ShardedVerifyEngine:
                         compiled = compile_sharded(
                             self._mesh, size, kernel=self._kernel
                         )
-                    stats["compiled"] += 1
-                    stats["cold_compile_s"] += time.perf_counter() - t0
-                    self._write_export(size, compiled)
-                self._compiled[size] = compiled
-                stats["shapes"].append(size)
-            stats["warm_load_s"] = round(stats["warm_load_s"], 3)
-            stats["cold_compile_s"] = round(stats["cold_compile_s"], 3)
-            self.stats = stats
+                    secs = time.perf_counter() - t0
+                    hit = bool(hits)
+                    stats["cache_hits" if hit else "compiled"] += 1
+                    stats["warm_load_s" if hit else "cold_compile_s"] += secs
+                    # What the executable itself says about placement —
+                    # the devices its first input is sharded over and the
+                    # rows each one holds — not what we asked for.
+                    in_sharding = compiled.input_shardings[0][0]
+                    stats["per_shape"].append(
+                        {
+                            "size": size,
+                            "seconds": round(secs, 3),
+                            "cache_hit": hit,
+                            "devices": sorted(
+                                d.id for d in in_sharding.device_set
+                            ),
+                            "rows_per_device": in_sharding.shard_shape(
+                                (size, 32)
+                            )[0],
+                        }
+                    )
+                    self._compiled[size] = compiled
+                    stats["shapes"].append(size)
+                stats["warm_load_s"] = round(stats["warm_load_s"], 3)
+                stats["cold_compile_s"] = round(stats["cold_compile_s"], 3)
+                self.stats = stats
+        finally:
+            jax.monitoring.unregister_event_listener(on_event)
         return stats
 
     def _round_to_mesh(self, size: int) -> int:
         d = max(1, self.device_count)
         return ((size + d - 1) // d) * d
-
-    def _load_export(self, size: int):
-        path = self._export_path(size)
-        if not path or not os.path.exists(path):
-            return None
-        try:
-            from jax.experimental.serialize_executable import (
-                deserialize_and_load,
-            )
-
-            with open(path, "rb") as fh:
-                serialized, in_tree, out_tree = pickle.load(fh)
-            return deserialize_and_load(serialized, in_tree, out_tree)
-        except Exception:
-            # A stale/foreign export must cost a recompile, never a crash
-            # (mirror of the host-keyed cache contract).
-            try:
-                os.remove(path)
-            except OSError:
-                pass
-            return None
-
-    def _write_export(self, size: int, compiled) -> None:
-        path = self._export_path(size)
-        if not path:
-            return
-        try:
-            from jax.experimental.serialize_executable import serialize
-
-            blob = pickle.dumps(serialize(compiled))
-            tmp = f"{path}.tmp.{os.getpid()}"
-            with open(tmp, "wb") as fh:
-                fh.write(blob)
-            os.replace(tmp, path)
-        except Exception:  # pragma: no cover - serialization unsupported
-            pass  # next startup pays the (cached) compile instead
 
     @property
     def warmed_sizes(self) -> Tuple[int, ...]:
@@ -285,14 +261,21 @@ class ShardedVerifyEngine:
 # -- the daemon --------------------------------------------------------------
 
 
+def _jax_platforms_names_cpu() -> bool:
+    return "cpu" in os.environ.get("JAX_PLATFORMS", "").lower().split(",")
+
+
 class VerifyServiceDaemon:
     """A :class:`~pbft_tpu.net.service.VerifierService` that owns its
     accelerator lifecycle: starts in ``warming`` (all traffic served by the
     native-pool fallback), warms the :class:`ShardedVerifyEngine` on a
-    background thread, and flips to ``ready`` — or to ``cpu-only`` when no
-    usable JAX backend exists (or ``backend`` pins native/cpu). The
-    readiness handshake reports the state + device count so replicas and
-    the bench route accordingly without ever blocking on a cold chip."""
+    background thread, and flips to ``ready``. What a failed warm-up does
+    depends on ``backend``: ``auto`` flips to ``cpu-only`` (chip-less
+    deployments keep coalescing on the native pool); ``jax`` — the chip
+    deployment — records ``fatal_error`` and the CLI exits non-zero,
+    and so does a backend that is not a TPU unless ``JAX_PLATFORMS``
+    itself names cpu. ``native``/``cpu`` never touch JAX. The status
+    counts engine dispatches apart from fallback dispatches."""
 
     def __init__(
         self,
@@ -308,8 +291,6 @@ class VerifyServiceDaemon:
         inflight: int = 2,
         trace_path: Optional[str] = None,
         metrics_port: Optional[int] = None,
-        cache_root: Optional[str] = None,
-        export_dir: Optional[str] = None,
         engine: Optional[ShardedVerifyEngine] = None,
         fallback: Optional[Callable[[List[Item]], List[bool]]] = None,
     ):
@@ -320,14 +301,17 @@ class VerifyServiceDaemon:
         self._state = STATE_WARMING
         self._state_lock = threading.Lock()
         self._warm_error: Optional[str] = None
+        self.fatal_error: Optional[str] = None
         self._warm_thread: Optional[threading.Thread] = None
+        self._counts_lock = threading.Lock()
+        self.engine_launches = 0
+        self.engine_items = 0
+        self.fallback_launches = 0
+        self.fallback_items = 0
         self.engine = engine
         if engine is None and backend in ("auto", "jax"):
             self.engine = ShardedVerifyEngine(
-                shapes=warm_shapes,
-                devices=devices,
-                cache_root=cache_root,
-                export_dir=export_dir,
+                shapes=warm_shapes, devices=devices
             )
         if fallback is None:
             if backend == "cpu":
@@ -384,15 +368,26 @@ class VerifyServiceDaemon:
 
     def status_json(self) -> dict:
         eng = self.engine
+        with self._counts_lock:
+            counts = {
+                "engine_launches": self.engine_launches,
+                "engine_items": self.engine_items,
+                "fallback_launches": self.fallback_launches,
+                "fallback_items": self.fallback_items,
+            }
         out = {
             "state": self.state_name,
+            # None until the backend has been touched (and always for
+            # native/cpu): a reader must not mistake "unknown" for a chip.
+            "platform": eng.platform if eng else None,
+            "device_kind": eng.device_kind if eng else None,
+            "devices_seen": eng.devices_seen if eng else 0,
             "devices": eng.device_count if eng else 0,
             "warmed_shapes": list(eng.warmed_sizes) if eng else [],
             "backend": self.backend,
             "uptime_s": round(time.monotonic() - self._t0, 3),
             "requests": self.service.requests,
-            "launches": self.service.batches,
-            "items": self.service.items,
+            **counts,
         }
         if eng and eng.stats:
             out["warm_stats"] = eng.stats
@@ -404,17 +399,41 @@ class VerifyServiceDaemon:
 
     def _dispatch(self, items: List[Item]) -> List[bool]:
         """The service backend: the warmed sharded engine when ready, the
-        native-pool fallback otherwise — a request never waits on warmup."""
+        native-pool fallback otherwise — a request never waits on warmup.
+        Counted apart, after the verdicts exist, so ``engine_items`` is
+        what the device really verified."""
         if self.state == STATE_READY:
-            return self.engine.verify(items)
-        return self._fallback(items)
+            verdicts = self.engine.verify(items)
+            with self._counts_lock:
+                self.engine_launches += 1
+                self.engine_items += len(items)
+            return verdicts
+        verdicts = self._fallback(items)
+        with self._counts_lock:
+            self.fallback_launches += 1
+            self.fallback_items += len(items)
+        return verdicts
 
     def _warm(self) -> None:
         try:
+            self.engine.init_backend()
+            if (
+                self.backend == "jax"
+                and self.engine.platform != "tpu"
+                and not _jax_platforms_names_cpu()
+            ):
+                raise RuntimeError(
+                    f"--backend jax needs a TPU but JAX runs on "
+                    f"{self.engine.platform!r} ({self.engine.device_kind}); "
+                    f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}"
+                )
             stats = self.engine.warm()
         except Exception as e:  # noqa: BLE001 - any backend failure
             self._warm_error = f"{type(e).__name__}: {e}"
-            self._set_state(STATE_CPU_ONLY)
+            if self.backend == "jax":
+                self.fatal_error = self._warm_error
+            else:
+                self._set_state(STATE_CPU_ONLY)
             return
         reg = self.service.metrics_registry
         if reg.enabled:
@@ -483,6 +502,85 @@ def probe_status_json(target: str, timeout: float = 2.0) -> Optional[dict]:
             return json.loads(_recv_exact(sock, n).decode())
     except (OSError, ConnectionError, ValueError):
         return None
+
+
+class VerifydNotReady(RuntimeError):
+    """No verify service ready on a TPU (the message says what was found)."""
+
+
+def spawn_verifyd(
+    args: Sequence[str] = ("--backend", "jax"), stdout=None, stderr=None
+):
+    """Start ``scripts/verifyd.py`` on a free loopback port as the process
+    that owns the chip. Returns (Popen, "127.0.0.1:port"). The caller — a
+    bench or smoke parent that itself stays off JAX — stops it."""
+    import subprocess
+
+    from .launcher import free_ports
+
+    port = free_ports(1)[0]
+    script = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+        "scripts",
+        "verifyd.py",
+    )
+    proc = subprocess.Popen(
+        [sys.executable, script, "--port", str(port), *args],
+        stdout=stdout,
+        stderr=stderr,
+    )
+    return proc, f"127.0.0.1:{port}"
+
+
+def stop_child(proc, grace_s: float = 15.0) -> None:
+    """SIGTERM, wait, SIGKILL: the parent's half of "stops every process
+    it starts" (``proc`` may be None or already gone)."""
+    import subprocess
+
+    if proc is None or proc.poll() is not None:
+        return
+    proc.terminate()
+    try:
+        proc.wait(timeout=grace_s)
+    except subprocess.TimeoutExpired:
+        proc.kill()
+        proc.wait()
+
+
+def wait_for_tpu_service(target: str, proc=None, budget_s: float = 900.0) -> dict:
+    """Poll ``target`` until it reports ``ready`` on platform ``tpu`` and
+    return that status. Raises :class:`VerifydNotReady` as soon as the
+    answer cannot become yes: ``proc`` (the daemon, if we started it)
+    exited, the backend came up on another platform, the state is
+    ``cpu-only``, or ``budget_s`` ran out. Never settles for a CPU."""
+    deadline = time.monotonic() + budget_s
+    status = None
+    while time.monotonic() < deadline:
+        if proc is not None and proc.poll() is not None:
+            raise VerifydNotReady(
+                f"verifyd exited with code {proc.returncode} before ready "
+                f"(last status: {status})"
+            )
+        status = probe_status_json(target, timeout=2.0)
+        if status is not None:
+            platform = status.get("platform")
+            if platform is not None and platform != "tpu":
+                raise VerifydNotReady(
+                    f"no TPU: verify service at {target} runs on platform "
+                    f"{platform!r} ({status.get('device_kind')})"
+                )
+            if status.get("state") == "cpu-only":
+                raise VerifydNotReady(
+                    f"no TPU: verify service at {target} is cpu-only "
+                    f"({status.get('warm_error') or status.get('backend')})"
+                )
+            if status.get("state") == "ready" and platform == "tpu":
+                return status
+        time.sleep(0.5)
+    raise VerifydNotReady(
+        f"verify service at {target} not ready on a TPU after "
+        f"{budget_s:.0f}s (last status: {status})"
+    )
 
 
 class ServiceVerifier:
@@ -603,8 +701,12 @@ class ServiceVerifier:
                 self._sock = None
 
 
-def main(argv: Optional[List[str]] = None) -> None:
-    """The verifyd CLI (scripts/verifyd.py is a thin path-setup wrapper)."""
+def main(
+    argv: Optional[List[str]] = None,
+    engine: Optional[ShardedVerifyEngine] = None,
+) -> None:
+    """The verifyd CLI (scripts/verifyd.py is a thin path-setup wrapper).
+    ``engine`` substitutes the accelerator engine (tests)."""
     import argparse
 
     parser = argparse.ArgumentParser(
@@ -617,8 +719,11 @@ def main(argv: Optional[List[str]] = None) -> None:
         "--backend",
         default="auto",
         choices=["auto", "jax", "native", "cpu"],
-        help="auto/jax warm the sharded JAX engine (native-pool fallback "
-        "while warming); native/cpu skip JAX entirely (state cpu-only)",
+        help="jax = the chip deployment: warm the sharded engine and EXIT "
+        "non-zero if warm-up fails or the backend is not a TPU (unless "
+        "JAX_PLATFORMS names cpu); auto = same engine but degrade to "
+        "cpu-only on failure; native/cpu skip JAX entirely (state "
+        "cpu-only). The native pool serves while the engine warms.",
     )
     parser.add_argument(
         "--devices",
@@ -628,10 +733,9 @@ def main(argv: Optional[List[str]] = None) -> None:
     )
     parser.add_argument(
         "--warm-shapes",
-        default=os.environ.get("PBFT_SERVICE_WARM_SHAPES"),
-        help="comma-separated window sizes to precompile (default: "
-        "$PBFT_SERVICE_WARM_SHAPES, else the crypto pad ladder "
-        "16,64,256,1024,4096)",
+        default=None,
+        help="comma-separated window sizes to precompile (default: the "
+        "crypto pad ladder 16,64,256,1024,4096)",
     )
     parser.add_argument(
         "--window",
@@ -651,29 +755,12 @@ def main(argv: Optional[List[str]] = None) -> None:
     parser.add_argument("--trace", default=None)
     parser.add_argument("--metrics-port", type=int, default=None)
     parser.add_argument(
-        "--cache-dir",
-        default=None,
-        help="persistent compile cache ROOT (host-keyed subdir is "
-        "appended); default: <repo>/.jax_cache",
-    )
-    parser.add_argument(
-        "--export-dir",
-        default=None,
-        help="serialized-executable exports (warm restarts skip tracing); "
-        "default: <cache-dir>/executables",
-    )
-    parser.add_argument(
         "--wait-ready",
         action="store_true",
         help="block until warmup finishes before announcing readiness "
         "on stdout (the socket still answers status probes meanwhile)",
     )
     args = parser.parse_args(argv)
-    repo_root = os.path.dirname(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    )
-    cache_root = args.cache_dir or os.path.join(repo_root, ".jax_cache")
-    export_dir = args.export_dir or os.path.join(cache_root, "executables")
     shapes = (
         [int(s) for s in args.warm_shapes.split(",") if s]
         if args.warm_shapes
@@ -692,8 +779,7 @@ def main(argv: Optional[List[str]] = None) -> None:
         inflight=args.inflight,
         trace_path=args.trace,
         metrics_port=args.metrics_port,
-        cache_root=cache_root,
-        export_dir=export_dir,
+        engine=engine,
     )
     daemon.start(wait_ready=args.wait_ready)
     print(
@@ -707,13 +793,19 @@ def main(argv: Optional[List[str]] = None) -> None:
         flush=True,
     )
     try:
-        while True:
-            state = daemon.state
-            time.sleep(0.25)
+        state = daemon.state
+        while daemon.fatal_error is None:
             if daemon.state != state:
+                state = daemon.state
                 print(json.dumps(daemon.status_json()), flush=True)
+            time.sleep(0.25)
     except KeyboardInterrupt:  # pragma: no cover - operator stop
         daemon.stop()
+        return
+    # --backend jax and no usable chip: never serve on as a CPU service.
+    print(f"verifyd: fatal: {daemon.fatal_error}", file=sys.stderr, flush=True)
+    daemon.stop()
+    sys.exit(1)
 
 
 if __name__ == "__main__":  # pragma: no cover - CLI entry

@@ -31,16 +31,10 @@ from typing import Optional, Sequence
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..crypto.ed25519 import verify_kernel
-
-try:  # jax >= 0.4.35 exposes shard_map at the top level
-    from jax import shard_map as _shard_map_mod
-
-    shard_map = _shard_map_mod  # type: ignore[assignment]
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 def make_mesh(
@@ -101,10 +95,9 @@ def compile_sharded(
     ``jax.jit(...).lower(...).compile()`` ahead of first traffic: the
     persistent verify service warms every `_PAD_LADDER` shape at startup
     so no request ever pays tracing or compilation (the persistent
-    on-disk cache makes the warm-restart compile cache-hit cheap; the
-    serialized-executable export in net/verify_service.py skips even
-    tracing). Returns a ``jax.stages.Compiled`` expecting inputs placed
-    with :func:`batch_sharding`.
+    on-disk cache makes the warm-restart compile cache-hit cheap).
+    Returns a ``jax.stages.Compiled`` expecting inputs placed with
+    :func:`batch_sharding`.
     """
     if size % mesh.devices.size:
         raise ValueError(

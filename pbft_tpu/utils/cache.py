@@ -1,79 +1,31 @@
-"""Host-keyed persistent compile cache location.
+"""The one place the persistent XLA compile cache is located.
 
-XLA:CPU AOT cache entries embed the compiling machine's CPU features;
-loading an entry compiled on a better-featured host only WARNS at load
-time but can SIGILL at execution time. The multichip dryrun is the one
-gate that must never flake, and its workspace (including `.jax_cache/`)
-can move between hosts — so the cache directory is keyed by the host's
-identity AND its CPU description: a foreign cache lands under a
-different key and is simply never read. The cost of a key mismatch is a
-cold recompile, never a crash.
-
-Why both components (MULTICHIP_r05 postmortem): keying by the
-`/proc/cpuinfo` feature flags alone was not enough — XLA's *target*
-feature set is derived from the CPU model (e.g. `+prefer-no-gather` on
-some microarchitectures), so two hosts can report byte-identical flag
-lists yet compile incompatible AOT artifacts, and the r05 log duly
-spewed `cpu_aot_loader` feature-mismatch warnings threatening SIGILL.
-The key therefore folds in (a) a stable host id (`/etc/machine-id`,
-falling back to the hostname) and (b) the machine type + CPU model name
-+ feature flags. Same host, same kernel → same key → warm cache; any
-move or CPU change → new key → cold but safe.
-
-This module must stay importable without touching jax (bench.py and
-__graft_entry__.py compute the cache path before backend init).
+``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself at import — that
+directory, as given, and nothing is configured in code. Unset: the fixed
+``<checkout>/.jax_cache``. The installed JAX keys every entry by the
+lowered module, the jaxlib and backend versions, the XLA flags and the
+device topology (for XLA:CPU that includes the host's CPU feature list),
+so a changed kernel or a foreign host misses instead of loading a stale
+entry; no sub-directory of our own is needed.
 """
 
 from __future__ import annotations
 
-import hashlib
 import os
-import platform
+
+_DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
 
-def _cpuinfo_fields(*names: str) -> str:
-    """First occurrence of each named /proc/cpuinfo field, joined."""
-    found = {n: "" for n in names}
-    try:
-        with open("/proc/cpuinfo") as fh:
-            for line in fh:
-                key = line.split(":")[0].strip()
-                if key in found and not found[key]:
-                    found[key] = line.split(":", 1)[1].strip()
-                if all(found.values()):
-                    break
-    except OSError:
-        pass  # non-Linux: machine type + host id still separate real moves
-    return "|".join(found[n] for n in names)
+def configure_compile_cache() -> str:
+    """Point JAX's persistent compile cache and return its directory.
+    Call before the first compile of the process."""
+    given = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if given:
+        return given
+    import jax
 
-
-def _host_id() -> str:
-    """A stable identifier for THIS host (not the workspace)."""
-    for path in ("/etc/machine-id", "/var/lib/dbus/machine-id"):
-        try:
-            with open(path) as fh:
-                hid = fh.read().strip()
-            if hid:
-                return hid
-        except OSError:
-            continue
-    return platform.node()
-
-
-def host_cache_key() -> str:
-    """12-hex digest of host id + machine type + CPU model + features.
-
-    ``PBFT_CACHE_HOST_KEY`` overrides the computed key (tests pin it to
-    exercise warm-restart behavior deterministically)."""
-    override = os.environ.get("PBFT_CACHE_HOST_KEY")
-    if override:
-        return override
-    cpu = _cpuinfo_fields("model name", "flags", "Features")
-    return hashlib.blake2b(
-        f"{_host_id()}|{platform.machine()}|{cpu}".encode(), digest_size=6
-    ).hexdigest()
-
-
-def host_keyed_cache_dir(root: str) -> str:
-    """<root>/<host_cache_key()>, e.g. .jax_cache/a1b2c3d4e5f6."""
-    return os.path.join(root, host_cache_key())
+    jax.config.update("jax_compilation_cache_dir", _DEFAULT_DIR)
+    return _DEFAULT_DIR

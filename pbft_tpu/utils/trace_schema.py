@@ -51,7 +51,7 @@ EVENT_SCHEMAS = {
         "optional": {"request", "prepared", "committed"},
         "emitters": {"server.py", "net.cc"},
     },
-    # The wedged-async-verifier bound (ADVICE.md core/net.cc item): the
+    # The wedged-async-verifier bound: the
     # inflight launch overran its deadline, the connection was dropped and
     # the batch re-verified on the CPU safety net.
     "verify_deadline_fired": {
@@ -133,6 +133,12 @@ METRIC_SCHEMAS = {
     "pbft_verify_items_total": ("counter", {"server.py", "service.py", "net.cc"}),
     "pbft_verify_rejected_total": ("counter", {"server.py", "service.py", "net.cc"}),
     "pbft_verify_deadline_fired_total": ("counter", {"net.cc"}),
+    # Batches a replica verified on the host although a verify service is
+    # configured: the service was warming, unreachable, killed mid-stream
+    # or (pbftd) past its verify deadline. The fallback is the liveness
+    # guarantee; the count is what keeps it from hiding a dead device.
+    # metrics_json mirrors it as verify_service_fallbacks.
+    "pbft_verify_service_fallbacks_total": ("counter", {"server.py", "net.cc"}),
     "pbft_verify_queue_depth": ("gauge", {"server.py", "service.py", "net.cc"}),
     "pbft_verify_inflight_age_seconds": ("gauge", {"server.py", "service.py", "net.cc"}),
     # Native verify-pool surface (core/verify_pool.cc): pool width, windows

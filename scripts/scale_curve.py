@@ -45,7 +45,7 @@ from pbft_tpu.consensus.messages import ClientRequest  # noqa: E402
 from pbft_tpu.net.gateway import GATEWAY_CLIENT_PREFIX  # noqa: E402
 from pbft_tpu.net.launcher import LocalCluster  # noqa: E402
 
-# f per cluster size for the BASELINE.md target rows.
+# f per cluster size for the BASELINE.json target configs.
 CURVE_NS = (4, 7, 16, 31)
 
 

@@ -196,9 +196,7 @@ def expand_trace_args(args) -> list:
     """Directory args expand to their sorted *.jsonl files, including one
     level of subdirectories (the harness's --trace-dir writes per-config
     cfg<i>/ subdirs); file args pass through. Single source of the
-    trace-layout rule. trace_report aggregates freely; launch_cost_model
-    additionally REQUIRES the expanded set to come from one config
-    directory (occupancy is per-config) and rejects mixed sets."""
+    trace-layout rule."""
     files = []
     for arg in args:
         p = pathlib.Path(arg)

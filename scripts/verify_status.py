@@ -1,12 +1,12 @@
 #!/usr/bin/env python
 """verify_status — introspect a running verify service (scripts/verifyd.py).
 
-Sends the 0xFFFFFFFF JSON-status probe (the introspection surface that
-has existed since the persistent-service PR but had no consumer) and
-pretty-prints what the daemon is actually doing: state, devices, warmed
-window shapes, and the once-per-deploy compile timings — the numbers
-that tell you whether a restart will be warm (serialized-executable
-reload, ~0 compiles) or cold (full trace+compile).
+Sends the 0xFFFFFFFF JSON-status probe and pretty-prints what the daemon
+is actually doing: state, platform and device kind, devices seen and in
+the mesh, warmed window shapes, engine vs fallback dispatch counts, and
+the once-per-deploy compile timings per shape — which shapes the
+persistent compile cache answered (warm) and which were traced+compiled
+(cold).
 
     python scripts/verify_status.py                      # default target
     python scripts/verify_status.py 127.0.0.1:7600
@@ -74,7 +74,7 @@ def main(argv=None) -> int:
             print(f"  cold compile    {cold:.3f}s (traced+compiled shapes)")
         loaded = warm.get("warm_load_s")
         if loaded is not None:
-            print(f"  warm load       {loaded:.3f}s (export/cache reloads)")
+            print(f"  warm load       {loaded:.3f}s (shapes the compile cache answered)")
         for k in sorted(warm):
             if k in ("cold_compile_s", "warm_load_s"):
                 continue

@@ -1,21 +1,23 @@
 #!/usr/bin/env python
 """verifyd — the persistent multi-chip verify service daemon.
 
-One per TPU host: owns the accelerator, initializes the JAX backend ONCE,
-AOT-warms the sharded verify kernel for every pad-ladder window shape
-(persistent compile cache + serialized-executable exports, so a redeploy
-is cache-hit cheap and a warm restart skips tracing entirely), then
-serves coalesced signature windows to every colocated replica for its
-whole lifetime. Replicas dial it with a short connect deadline and fall
-back to their native verify pool while it warms — start it before, after,
-or during the cluster; consensus never waits.
+One per TPU host, and the ONLY process on it that touches JAX: it owns
+the accelerator, initializes the backend once, AOT-warms the sharded
+verify kernel for every pad-ladder window shape (through JAX's persistent
+compile cache: $JAX_COMPILATION_CACHE_DIR if set, else <checkout>/.jax_cache),
+then serves coalesced signature windows to every colocated replica for
+its whole lifetime. Replicas dial it with a short connect deadline and
+fall back to their native verify pool while it warms — start it before,
+after, or during the cluster; consensus never waits.
 
-    python scripts/verifyd.py --port 7600                  # TPU/JAX, all devices
-    python scripts/verifyd.py --backend native             # CPU control arm
+    python scripts/verifyd.py --backend jax --port 7600    # the chip: TPU or exit 1
+    python scripts/verifyd.py --backend native             # chip-less control arm
     python scripts/verifyd.py --unix /tmp/verify.sock --metrics-port 9100
 
 Readiness: probe with an item count of 0 (8-byte binary status) or
-0xFFFFFFFF (JSON status); see pbft_tpu/net/verify_service.py.
+0xFFFFFFFF (JSON status: platform, device kind, devices, per-shape
+compile seconds, engine vs fallback dispatch counts); see
+pbft_tpu/net/verify_service.py and scripts/verify_status.py.
 """
 
 import os

@@ -1,0 +1,186 @@
+"""The ways the served path could quietly run on the CPU instead of the
+chip, each pinned to fail loudly (all on CPU, no device needed): bench.py's
+default arm, the native build's staleness check, the one-process-per-chip
+launcher rule, and PBFT_PALLAS off the TPU. (verifyd's own refusals live in
+test_service_coalesce.py, chip_smoke.py's in test_chip_smoke.py.)"""
+
+import json
+import os
+import subprocess
+import sys
+from pathlib import Path
+
+import pytest
+
+from pbft_tpu import native
+
+REPO = Path(__file__).resolve().parent.parent
+
+
+def test_bench_default_arm_exits_1_with_an_error_line_without_a_tpu():
+    """No TPU service comes up -> one error line, exit 1. Never a CPU
+    number under the device metric's name."""
+    from pbft_tpu.net.launcher import free_ports
+
+    env = dict(
+        os.environ,
+        JAX_PLATFORMS="cpu",
+        PBFT_VERIFY_SERVICE=f"127.0.0.1:{free_ports(1)[0]}",  # nobody there
+        PBFT_SERVICE_WARM_BUDGET_S="60",
+    )
+    for arm in ("PBFT_BENCH_NATIVE", "PBFT_BENCH_CPU", "PBFT_BENCH_CONSENSUS"):
+        env.pop(arm, None)
+    out = subprocess.run(
+        [sys.executable, str(REPO / "bench.py")],
+        capture_output=True,
+        text=True,
+        env=env,
+        timeout=120,
+    )
+    assert out.returncode == 1, out.stderr[-2000:]
+    lines = out.stdout.strip().splitlines()
+    assert len(lines) == 1
+    result = json.loads(lines[0])
+    assert result["value"] == 0.0 and "backend" not in result
+    assert "no TPU" in result["error"]
+
+
+# -- native.build(): decided from the sources, not from a file existing ------
+
+
+@pytest.fixture
+def fake_tree(tmp_path, monkeypatch):
+    """A stand-in checkout (core/ with two sources, an up-to-date
+    build-core/) and a recording builder in place of cmake/g++."""
+    root = tmp_path / "checkout"
+    (root / "core").mkdir(parents=True)
+    (root / "core" / "net.cc").write_text("int net;\n")
+    (root / "core" / "CMakeLists.txt").write_text("project(x)\n")
+    build_dir = root / "build-core"
+    monkeypatch.setattr(native, "_REPO_ROOT", root)
+    monkeypatch.setattr(native, "_BUILD_DIR", build_dir)
+    monkeypatch.setattr(native, "_LIB_PATH", build_dir / "libpbftcore.so")
+    builds = []
+
+    def fake_build():
+        builds.append(1)
+        build_dir.mkdir(exist_ok=True)
+        for name in native._ARTIFACTS:
+            (build_dir / name).write_text("binary")
+
+    monkeypatch.setattr(native, "_build_direct", fake_build)
+    monkeypatch.setattr(native.shutil, "which", lambda tool: None)  # -> direct
+    native.build()
+    assert len(builds) == 1
+    return root, build_dir, builds
+
+
+def test_build_is_a_noop_while_sources_and_artifacts_are_current(fake_tree):
+    _, _, builds = fake_tree
+    native.build()
+    native.build()
+    assert len(builds) == 1
+
+
+def test_build_rebuilds_when_a_core_source_changed(fake_tree):
+    """A changed core/*.cc can not be served by the previous pbftd."""
+    root, _, builds = fake_tree
+    (root / "core" / "net.cc").write_text("int net; int newer;\n")
+    native.build()
+    assert len(builds) == 2
+    native.build()
+    assert len(builds) == 2
+
+
+def test_build_rebuilds_when_pbftd_is_missing(fake_tree):
+    """The library alone proves nothing: LocalCluster needs pbftd."""
+    _, build_dir, builds = fake_tree
+    (build_dir / "pbftd").unlink()
+    native.build()
+    assert len(builds) == 2 and (build_dir / "pbftd").exists()
+
+
+def test_build_does_not_trust_a_build_dir_from_another_path(
+    fake_tree, tmp_path, monkeypatch
+):
+    """The tree copied elsewhere WITH its build directory (what the chip
+    tool does): same sources, but the artifacts and the CMake cache name
+    the old path. Rebuilt, and cmake starts from a clean directory."""
+    import shutil
+
+    root, build_dir, builds = fake_tree
+    (build_dir / "CMakeCache.txt").write_text(
+        f"CMAKE_HOME_DIRECTORY:INTERNAL={root / 'core'}\n"
+        f"CMAKE_CACHEFILE_DIR:INTERNAL={build_dir}\n"
+    )
+    moved = tmp_path / "elsewhere"
+    shutil.copytree(root, moved)
+    monkeypatch.setattr(native, "_REPO_ROOT", moved)
+    monkeypatch.setattr(native, "_BUILD_DIR", moved / "build-core")
+    monkeypatch.setattr(native, "_LIB_PATH", moved / "build-core" / "libpbftcore.so")
+    # This time through the cmake path, with the real stale-cache check.
+    monkeypatch.setattr(native.shutil, "which", lambda tool: f"/usr/bin/{tool}")
+    steps = []
+
+    def fake_step(cmd):
+        steps.append((cmd[:2], (moved / "build-core" / "CMakeCache.txt").exists()))
+        (moved / "build-core").mkdir(exist_ok=True)
+        for name in native._ARTIFACTS:
+            (moved / "build-core" / name).write_text("binary")
+
+    monkeypatch.setattr(native, "_run_build_step", fake_step)
+    native.build()
+    assert [s[0] for s in steps] == [["cmake", "-S"], ["cmake", "--build"]]
+    assert steps[0][1] is False  # the foreign cache was gone before configure
+
+
+def test_a_failed_build_shows_the_compilers_words(monkeypatch, tmp_path):
+    """build() used to run the compiler with capture_output and drop what
+    it said; available() then read the failure as "not built"."""
+    with pytest.raises(native.NativeBuildError, match="(?s)exit 3.*boom on line 7"):
+        native._run_build_step(
+            [sys.executable, "-c",
+             "import sys; sys.stderr.write('boom on line 7'); sys.exit(3)"]
+        )
+    # ...and available() does not turn that into False.
+    monkeypatch.setattr(native, "_lib", None)
+
+    def broken():
+        raise native.NativeBuildError("compiler said no")
+
+    monkeypatch.setattr(native, "build", broken)
+    with pytest.raises(native.NativeBuildError):
+        native.available()
+
+
+# -- one process per chip ------------------------------------------------------
+
+
+def test_launcher_refuses_several_inprocess_jax_replicas_off_the_cpu_arm(
+    monkeypatch,
+):
+    from pbft_tpu.net import LocalCluster
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(ValueError, match="each claim the accelerator"):
+        LocalCluster(n=4, verifier="jax", impl="py")
+    LocalCluster(n=4, verifier="jax", impl=["py", "cxx", "cxx", "cxx"])  # one: fine
+    LocalCluster(n=4, verifier="127.0.0.1:7600", impl="py")  # verifyd: fine
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    LocalCluster(n=4, verifier="jax", impl="py")  # the CPU test arm
+
+
+# -- the Pallas arm never falls back in silence --------------------------------
+
+
+def test_pbft_pallas_off_the_tpu_is_an_error_not_a_silent_xla_run(monkeypatch):
+    from pbft_tpu.crypto import ed25519
+
+    monkeypatch.setenv("PBFT_PALLAS", "1")
+    monkeypatch.delenv("PBFT_PALLAS_INTERPRET", raising=False)
+    with pytest.raises(RuntimeError, match="PBFT_PALLAS=1 on backend 'cpu'"):
+        ed25519._use_pallas()
+    monkeypatch.setenv("PBFT_PALLAS_INTERPRET", "1")
+    assert ed25519._use_pallas() is True  # asked for by name
+    monkeypatch.delenv("PBFT_PALLAS")
+    assert ed25519._use_pallas() is False

@@ -221,7 +221,7 @@ def test_reordered_delivery_still_commits():
 
 
 def test_byzantine_signer_isolated():
-    """BASELINE.md config 5 in miniature: replica 3 corrupts every signature;
+    """BASELINE.json config 5 in miniature: replica 3 corrupts every signature;
     consensus proceeds (f=1 tolerates it) and rejections are counted."""
     c = Cluster(n=4)
 

@@ -13,7 +13,7 @@ pytestmark = pytest.mark.skipif(
 
 
 def test_readme_scenario_end_to_end():
-    """4 replicas (f=1), 1 client, single request — BASELINE.md config 1."""
+    """4 replicas (f=1), 1 client, single request — BASELINE.json config 1."""
     with LocalCluster(n=4, verifier="cpu") as cluster:
         client = PbftClient(cluster.config)
         try:
@@ -51,7 +51,7 @@ def test_liveness_with_f_crashed_replicas():
 
 def test_many_requests_pipeline():
     """A burst of requests commits in order — the batching window carries
-    multiple concurrent (view, seq) rounds (BASELINE.md config 2 shape)."""
+    multiple concurrent (view, seq) rounds (BASELINE.json config 2 shape)."""
     with LocalCluster(n=4, verifier="cpu") as cluster:
         client = PbftClient(cluster.config)
         try:
@@ -295,7 +295,7 @@ def test_byzantine_asyncio_backup_tolerated():
 def test_byzantine_backup_tolerated():
     """A backup daemon running with --byzantine (every outgoing signature
     corrupted) cannot stall the cluster: the honest 2f+1 carry each round
-    and its garbage votes are rejected, never counted (BASELINE.md
+    and its garbage votes are rejected, never counted (BASELINE.json
     config 5, as real processes instead of the simulation mutator)."""
     with LocalCluster(n=4, verifier="cpu", byzantine=[3]) as cluster:
         client = PbftClient(cluster.config)

@@ -319,7 +319,7 @@ def test_consensus_timeline_merges_span_events(tmp_path):
     assert result["straggler_counts"] == {"1": 1}
 
 
-# -- wedged-async-verifier deadline (ADVICE.md, core/net.cc) -----------------
+# -- wedged-async-verifier deadline (core/net.cc) ----------------------------
 
 
 def _free_port() -> int:

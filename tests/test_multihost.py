@@ -2,10 +2,10 @@
 
 Exercises the non-degenerate branches of pbft_tpu/parallel/multihost.py
 (jax.distributed.initialize, make_array_from_process_local_data, the psum
-crossing a process boundary) that the single-process tests cannot reach —
-VERDICT r2 weak #5 / next-round item #8. Each process is one "host" with 4
-virtual CPU devices; the 8-device mesh spans both, and both must read back
-identical globally-replicated quorum verdicts.
+crossing a process boundary) that the single-process tests cannot reach.
+Each process is one "host" with 4 virtual CPU devices; the 8-device mesh
+spans both, and both must read back identical globally-replicated quorum
+verdicts.
 """
 
 import json
@@ -21,8 +21,6 @@ pytestmark = pytest.mark.slow  # two cold kernel compiles in subprocesses
 
 _WORKER = Path(__file__).parent / "multihost_worker.py"
 _REPO = str(Path(__file__).resolve().parent.parent)
-sys.path.insert(0, _REPO)
-from pbft_tpu.utils.cache import host_keyed_cache_dir  # noqa: E402
 
 
 def _free_port() -> int:
@@ -33,14 +31,9 @@ def _free_port() -> int:
 
 def test_two_process_quorum_certify_agrees(tmp_path):
     port = _free_port()
-    env = dict(
-        os.environ,
-        PYTHONPATH=_REPO,
-        JAX_PLATFORMS="cpu",
-        JAX_COMPILATION_CACHE_DIR=host_keyed_cache_dir(
-            str(Path(_REPO) / ".jax_cache")
-        ),
-    )
+    # The workers place their own compile cache (force_cpu ->
+    # utils/cache.configure_compile_cache).
+    env = dict(os.environ, PYTHONPATH=_REPO, JAX_PLATFORMS="cpu")
     # stdout/stderr go to FILES, not pipes: a worker spewing more than a
     # pipe buffer of JAX warnings before the gloo barrier would otherwise
     # block on write while the sibling blocks at the barrier.

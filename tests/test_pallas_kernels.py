@@ -25,6 +25,13 @@ pytestmark = pytest.mark.slow  # interpret-mode kernels, minutes not seconds
 _RNG = np.random.default_rng(0xED25519)
 
 
+@pytest.fixture(autouse=True)
+def _interpret_mode(monkeypatch):
+    # Off the TPU the kernels run only under the interpreter, and only
+    # when asked for by name (read at trace time).
+    monkeypatch.setenv("PBFT_PALLAS_INTERPRET", "1")
+
+
 def _rand_field(batch, lo=-(2**9) + 1, hi=2**9):
     """Random carried-form limb arrays (the bound every chain input obeys)."""
     return jnp.asarray(
@@ -52,7 +59,7 @@ def test_pow_p58_matches_field():
 
 def test_ladder_matches_xla_ladder():
     # Batch 1: the ladder math is per-element, so extra batch rows only
-    # replicate work in the minutes-slow interpreter (VERDICT r3 weak #3).
+    # replicate work in the minutes-slow interpreter.
     batch = 1
     pubs, s_list, h_list = [], [], []
     for i in range(batch):
@@ -88,7 +95,6 @@ def test_full_verify_pallas_path(monkeypatch):
     """verify_kernel with PBFT_PALLAS=1: same accept/reject set as the
     oracle, including a corrupted signature and a corrupted message."""
     monkeypatch.setenv("PBFT_PALLAS", "1")
-    monkeypatch.setenv("PBFT_PALLAS_INTERPRET", "1")  # CPU backend opt-in
     # One valid + one corrupt-R + one corrupt-message row: full coverage
     # of the accept/reject branches at the smallest interpreter cost
     # (each row re-runs the whole ladder in the Python interpreter).

@@ -153,7 +153,7 @@ def test_sharded_matches_unsharded():
     ).tolist()
 
 
-def test_persistent_engine_matches_oracle_and_native(tmp_path):
+def test_persistent_engine_matches_oracle_and_native():
     """ISSUE 7 parity pin: the persistent service's AOT-compiled,
     donated-buffer engine must produce the SAME accept set as the Python
     oracle (and the native C++ pool when built) with the REAL Ed25519
@@ -167,7 +167,7 @@ def test_persistent_engine_matches_oracle_and_native(tmp_path):
     items = _signed_items(11, bad=bad)
     want = [i not in bad for i in range(11)]
 
-    eng = ShardedVerifyEngine(shapes=(8, 16), export_dir=str(tmp_path))
+    eng = ShardedVerifyEngine(shapes=(8, 16))
     stats = eng.warm()
     assert stats["shapes"] == [8, 16]
     got = eng.verify(items)
@@ -185,8 +185,10 @@ def test_persistent_engine_matches_oracle_and_native(tmp_path):
     if native_ok:
         assert native.verify_batch(items) == want
 
-    # Warm restart over the serialized export: zero compiles, same bits.
-    eng2 = ShardedVerifyEngine(shapes=(8, 16), export_dir=str(tmp_path))
-    s2 = eng2.warm()
-    assert s2["compiled"] == 0 and s2["aot_loaded"] == 2, s2
-    assert eng2.verify(items) == want
+    # Every shape's input sharding spans the whole virtual mesh, and the
+    # smallest rung still gives each device at least one row.
+    n_dev = eng.device_count
+    assert n_dev >= 1
+    for shape in stats["per_shape"]:
+        assert len(shape["devices"]) == n_dev
+        assert shape["rows_per_device"] * n_dev == shape["size"]

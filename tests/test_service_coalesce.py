@@ -4,9 +4,10 @@ submissions from separate connections must merge into fewer backend calls
 
 Plus the persistent-service lifecycle (ISSUE 7): readiness handshake,
 warming -> ready transitions, the ServiceVerifier client's native-pool
-fallback when the service is warming / killed mid-stream, and the
-warm-restart path that reloads serialized executables instead of
-compiling."""
+fallback when the service is warming / killed mid-stream, the counted
+fallbacks on both ends, the chip deployment's refusals (``--backend jax``
+never settles for a CPU), and the warm restart through the persistent
+compile cache."""
 
 import socket
 import threading
@@ -455,9 +456,14 @@ def test_status_probe_reports_state_and_traffic_continues():
 class _StubEngine:
     """Engine double with a gated warmup and a distinguishable verdict."""
 
-    def __init__(self, gate):
+    def __init__(self, gate, platform="tpu", warm_error=None):
         self.gate = gate
-        self.device_count = 5
+        self._platform = platform
+        self._warm_error = warm_error
+        self.platform = None
+        self.device_kind = None
+        self.devices_seen = 0
+        self.device_count = 0
         self.stats = {}
         self._warmed = ()
 
@@ -465,8 +471,15 @@ class _StubEngine:
     def warmed_sizes(self):
         return self._warmed
 
+    def init_backend(self):
+        self.platform = self._platform
+        self.device_kind = f"stub {self._platform}"
+        self.devices_seen = self.device_count = 5
+
     def warm(self):
         assert self.gate.wait(10)
+        if self._warm_error:
+            raise RuntimeError(self._warm_error)
         self._warmed = (16, 64)
         self.stats = {"cold_compile_s": 0.5, "warm_load_s": 0.0}
         return self.stats
@@ -518,9 +531,147 @@ def test_daemon_warming_serves_fallback_then_flips_ready():
         js = probe_status_json(daemon.address)
         assert js["state"] == "ready" and js["devices"] == 5
         assert js["warm_stats"]["cold_compile_s"] == 0.5
+        # What the device runs on, and engine dispatches counted apart
+        # from the fallback's: one pre-handshake batch hit the fallback
+        # while warming; everything since `ready` went to the engine.
+        assert js["platform"] == "tpu" and js["device_kind"] == "stub tpu"
+        assert js["devices_seen"] == 5
+        assert (js["fallback_launches"], js["fallback_items"]) == (1, 1)
+        assert js["engine_launches"] >= 1
+        assert js["engine_items"] == js["engine_launches"]  # 1-item batches
     finally:
         gate.set()
         daemon.stop()
+
+
+def _run_verifyd_main(engine, backend="jax"):
+    """verifyd's CLI in-process with a stub engine; returns its exit code
+    (None = it kept serving past the deadline)."""
+    from pbft_tpu.net import verify_service
+
+    box = {}
+
+    def run():
+        try:
+            verify_service.main(
+                ["--backend", backend, "--port", "0"], engine=engine
+            )
+        except SystemExit as e:
+            box["code"] = e.code
+
+    t = threading.Thread(target=run, daemon=True)
+    t.start()
+    t.join(10)
+    return box.get("code")
+
+
+def test_verifyd_backend_jax_exits_nonzero_when_warm_raises(capfd):
+    """The chip deployment never degrades to a CPU service: a warm-up
+    exception ends the process non-zero with the error."""
+    gate = threading.Event()
+    gate.set()
+    code = _run_verifyd_main(_StubEngine(gate, warm_error="mosaic said no"))
+    assert code == 1
+    assert "mosaic said no" in capfd.readouterr().err
+
+
+def test_verifyd_backend_jax_exits_nonzero_off_tpu(monkeypatch, capfd):
+    """JAX that silently fell back to XLA:CPU (JAX_PLATFORMS does not name
+    cpu) must not be warmed and served as if it were the chip."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    gate = threading.Event()  # never set: warm() must not even be reached
+    code = _run_verifyd_main(_StubEngine(gate, platform="cpu"))
+    assert code == 1
+    err = capfd.readouterr().err
+    assert "needs a TPU" in err and "'cpu'" in err
+
+
+def test_backend_jax_on_cpu_is_allowed_when_platforms_names_cpu(monkeypatch):
+    """JAX_PLATFORMS=cpu is the test arm: --backend jax may warm there
+    (and says platform cpu, which chip_smoke.py / bench.py then refuse)."""
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    gate = threading.Event()
+    gate.set()
+    daemon = VerifyServiceDaemon(
+        backend="jax", engine=_StubEngine(gate, platform="cpu")
+    ).start(wait_ready=True)
+    try:
+        js = probe_status_json(daemon.address)
+        assert daemon.fatal_error is None
+        assert js["state"] == "ready" and js["platform"] == "cpu"
+    finally:
+        daemon.stop()
+
+
+def test_backend_auto_still_degrades_to_cpu_only():
+    """auto keeps its meaning for chip-less deployments: a failed warm-up
+    is cpu-only service, not an exit."""
+    gate = threading.Event()
+    gate.set()
+    daemon = VerifyServiceDaemon(
+        backend="auto",
+        engine=_StubEngine(gate, warm_error="no backend"),
+        fallback=lambda items: [False] * len(items),
+    ).start(wait_ready=True)
+    try:
+        assert daemon.fatal_error is None
+        assert daemon.state == STATE_CPU_ONLY
+        js = probe_status_json(daemon.address)
+        assert "no backend" in js["warm_error"]
+    finally:
+        daemon.stop()
+
+
+def test_wait_for_tpu_service_refuses_everything_but_a_ready_tpu():
+    """bench.py's and chip_smoke.py's gate: only `ready` on platform
+    `tpu` passes; a CPU platform, a cpu-only state and a dead daemon
+    fail at once, not at the end of the budget."""
+    import pytest
+
+    from pbft_tpu.net.verify_service import (
+        VerifydNotReady,
+        wait_for_tpu_service,
+    )
+
+    gate = threading.Event()
+    gate.set()
+    ok = VerifyServiceDaemon(backend="auto", engine=_StubEngine(gate)).start(
+        wait_ready=True
+    )
+    try:
+        st = wait_for_tpu_service(ok.address, budget_s=5)
+        assert st["platform"] == "tpu" and st["state"] == "ready"
+    finally:
+        ok.stop()
+
+    hold = threading.Event()  # keeps the CPU stub in `warming`
+    on_cpu = VerifyServiceDaemon(
+        backend="auto", engine=_StubEngine(hold, platform="cpu")
+    ).start()
+    try:
+        t0 = time.monotonic()
+        with pytest.raises(VerifydNotReady, match="no TPU.*'cpu'"):
+            wait_for_tpu_service(on_cpu.address, budget_s=60)
+        assert time.monotonic() - t0 < 10  # while still warming, not after
+    finally:
+        hold.set()
+        on_cpu.stop()
+
+    native_svc = VerifyServiceDaemon(backend="native").start()
+    try:
+        with pytest.raises(VerifydNotReady, match="cpu-only"):
+            wait_for_tpu_service(native_svc.address, budget_s=60)
+    finally:
+        native_svc.stop()
+
+    class _Dead:
+        returncode = 1
+
+        def poll(self):
+            return 1
+
+    with pytest.raises(VerifydNotReady, match="exited with code 1"):
+        wait_for_tpu_service("127.0.0.1:1", proc=_Dead(), budget_s=60)
 
 
 def test_service_verifier_falls_back_when_killed_mid_stream():
@@ -613,12 +764,39 @@ def test_cluster_falls_back_when_service_killed_mid_stream(tmp_path):
             assert proc.poll() is None, "verifyd died at startup"
             time.sleep(0.1)
         with LocalCluster(
-            n=4, verifier=target, impl=["cxx", "py", "cxx", "py"]
+            n=4,
+            verifier=target,
+            impl=["cxx", "py", "cxx", "py"],
+            metrics_every=1,
         ) as cluster:
+
+            def fallbacks():
+                """Each replica's latest verify_service_fallbacks, from
+                the metrics line both runtimes print (None = no line yet)."""
+                import re
+                from pathlib import Path
+
+                out = []
+                for i in range(4):
+                    log = Path(cluster.tmpdir.name) / f"replica-{i}.log"
+                    seen = re.findall(
+                        r'"verify_service_fallbacks":\s*(\d+)',
+                        log.read_text(errors="replace"),
+                    )
+                    out.append(int(seen[-1]) if seen else None)
+                return out
+
             client = PbftClient(cluster.config)
             try:
                 req = client.request("with-service")
                 assert client.wait_result(req.timestamp, timeout=20) == "awesome!"
+                # The service answered from the first dial: a cluster that
+                # reached it reports ZERO host fallbacks, on both runtimes.
+                deadline = time.monotonic() + 10
+                while None in fallbacks():
+                    assert time.monotonic() < deadline, cluster.logs()
+                    time.sleep(0.2)
+                assert fallbacks() == [0, 0, 0, 0], cluster.logs()
                 proc.send_signal(signal.SIGKILL)
                 proc.wait(timeout=10)
                 # No stall: every post-kill request commits on the
@@ -629,6 +807,16 @@ def test_cluster_falls_back_when_service_killed_mid_stream(tmp_path):
                         client.wait_result(req.timestamp, timeout=20)
                         == "awesome!"
                     ), cluster.logs()
+                # ...and is COUNTED: the fallback is the liveness
+                # guarantee, the count is what keeps it from hiding a dead
+                # service.
+                deadline = time.monotonic() + 10
+                while not all(n and n > 0 for n in fallbacks()):
+                    assert time.monotonic() < deadline, (
+                        fallbacks(),
+                        cluster.logs(),
+                    )
+                    time.sleep(0.2)
             finally:
                 client.close()
     finally:
@@ -641,14 +829,7 @@ def test_engine_parity_pad_slots_and_window_boundaries():
     evaluation of the same rule across pad slots, shape boundaries, and
     the multi-window chunking path (the real-kernel equivalence against
     the oracle/native arms is pinned in test_parallel.py's slow tier)."""
-    import tempfile
-
-    eng = ShardedVerifyEngine(
-        shapes=(8, 16),
-        export_dir=tempfile.mkdtemp(),
-        kernel=_fake_kernel,
-        kernel_tag="fake-parity",
-    )
+    eng = ShardedVerifyEngine(shapes=(8, 16), kernel=_fake_kernel)
     eng.warm()
     assert eng.device_count >= 1
     # 11 items -> padded to 16: pad slots must be sliced off, invalid
@@ -664,45 +845,83 @@ def test_engine_parity_pad_slots_and_window_boundaries():
     assert eng.verify(big) == [i % 5 != 0 for i in range(40)]
 
 
-def test_warm_restart_reloads_exports_instead_of_compiling(tmp_path):
-    """Warm-restart contract: the FIRST startup compiles (and exports
-    serialized executables); a second startup over the same export dir
-    loads every shape without tracing — zero cold-compile seconds — and
-    verdicts survive the reload bit-for-bit."""
-    export_dir = str(tmp_path / "executables")
-    eng1 = ShardedVerifyEngine(
-        shapes=(8, 16),
-        export_dir=export_dir,
-        kernel=_fake_kernel,
-        kernel_tag="fake-restart",
-    )
-    s1 = eng1.warm()
-    assert s1["compiled"] == 2 and s1["aot_loaded"] == 0
-    items = [_item(i + 1, i % 2 == 0) for i in range(10)]
-    want = eng1.verify(items)
+_WARM_TWICE = """
+import json, sys
+from pbft_tpu.net import ShardedVerifyEngine
 
-    eng2 = ShardedVerifyEngine(
-        shapes=(8, 16),
-        export_dir=export_dir,
-        kernel=_fake_kernel,
-        kernel_tag="fake-restart",
+def kernel(pubs, msgs, sigs):
+    return pubs[:, 0] == sigs[:, 0] + {delta}
+
+eng = ShardedVerifyEngine(shapes=(8, 16), kernel=kernel)
+stats = eng.warm()
+item = (bytes([7]) * 32, bytes(32), bytes([7 - {delta}]) + bytes(63))
+print(json.dumps({{"stats": stats, "verdict": eng.verify([item])}}))
+"""
+
+
+def _warm_in_fresh_process(cache_dir, delta=0):
+    """One verifyd-like start in its own process, the compile cache placed
+    from OUTSIDE by JAX_COMPILATION_CACHE_DIR (the thresholds too, so the
+    millisecond stand-in kernel qualifies for the cache at all)."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(
+        os.environ,
+        PYTHONPATH=repo,
+        JAX_PLATFORMS="cpu",
+        JAX_COMPILATION_CACHE_DIR=str(cache_dir),
+        JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+        JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="-1",
     )
-    s2 = eng2.warm()
-    assert s2["aot_loaded"] == 2 and s2["compiled"] == 0, s2
-    assert s2["cold_compile_s"] == 0.0  # cache-hit cheap, by construction
-    assert eng2.verify(items) == want
-    # A corrupt export must cost a recompile, never a crash.
+    out = subprocess.run(
+        [sys.executable, "-c", _WARM_TWICE.format(delta=delta)],
+        capture_output=True,
+        text=True,
+        env=env,
+        timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-4000:]
+    return json.loads(out.stdout.strip().splitlines()[-1])
+
+
+def test_second_warm_over_the_same_cache_dir_is_a_cache_hit(tmp_path):
+    """Warm-restart contract, through the ONE cache there is: the first
+    start compiles every shape, a second start over the same
+    JAX_COMPILATION_CACHE_DIR gets every shape from the cache (zero
+    cold-compile seconds), in the directory as given — nothing appended —
+    and verdicts survive the reload bit-for-bit."""
     import os
 
-    victim = sorted(os.listdir(export_dir))[0]
-    with open(os.path.join(export_dir, victim), "wb") as fh:
-        fh.write(b"not an executable")
-    eng3 = ShardedVerifyEngine(
-        shapes=(8, 16),
-        export_dir=export_dir,
-        kernel=_fake_kernel,
-        kernel_tag="fake-restart",
-    )
-    s3 = eng3.warm()
-    assert s3["aot_loaded"] == 1 and s3["compiled"] == 1
-    assert eng3.verify(items) == want
+    cache = tmp_path / "cc"
+    first = _warm_in_fresh_process(cache)
+    s1 = first["stats"]
+    assert s1["cache_dir"] == str(cache)
+    assert s1["compiled"] == 2 and s1["cache_hits"] == 0, s1
+    assert [p["cache_hit"] for p in s1["per_shape"]] == [False, False]
+    # As given: the entries sit directly in the directory named.
+    entries = os.listdir(cache)
+    assert entries and all(
+        os.path.isfile(cache / e) for e in entries
+    ), entries
+
+    second = _warm_in_fresh_process(cache)
+    s2 = second["stats"]
+    assert s2["cache_hits"] == 2 and s2["compiled"] == 0, s2
+    assert s2["cold_compile_s"] == 0.0
+    assert second["verdict"] == first["verdict"] == [True]
+
+
+def test_changed_kernel_is_never_served_from_the_old_cache(tmp_path):
+    """A cache warmed by one kernel must MISS for a changed kernel: the
+    entry is keyed by the lowered module, so an edit to the crypto can
+    not be measured as "unchanged" through an old artifact."""
+    cache = tmp_path / "cc"
+    _warm_in_fresh_process(cache)
+    changed = _warm_in_fresh_process(cache, delta=1)
+    s = changed["stats"]
+    assert s["compiled"] == 2 and s["cache_hits"] == 0, s
+    assert changed["verdict"] == [True]  # the NEW rule answered
