@@ -1,0 +1,24 @@
+"""A kernel's share of its roofline: the least time the chip could take for
+the window's launches (operations over the peak rate, or bytes over the peak
+bandwidth, whichever is longer) over the device time the executable
+(``module``) takes for them, each launch at the device time its padded shape
+showed in the traced slice (``_rungs.py``). The operations and bytes come
+from ``kernel_ops.<ops>`` at the padded shape, the peaks from ``peaks.json``
+by device kind. Nothing without a trace."""
+
+import kernel_ops
+from reducers import _rungs
+
+
+def reduce(run: dict, args: dict):
+    peaks = run["peaks"]
+    rows = _rungs.weighted(run, args["module"])
+    if not rows or not peaks:
+        return None
+    count = getattr(kernel_ops, args["ops"])
+    need = [(n, count(rung)) for rung, n, _ in rows]
+    least = max(
+        sum(n * c["ops"] for n, c in need) / peaks[args["ops_peak"]],
+        sum(n * c["bytes"] for n, c in need) / peaks["hbm_bytes_per_s"],
+    )
+    return 100.0 * least / sum(n * sec for _, n, sec in rows)
