@@ -773,10 +773,68 @@ int parity_listen_ephemeral(int* port_out) {
   return fd;
 }
 
+// Send `req` to the replicas in turn (a retransmission every 400 ms, as a
+// client would) until `want` DISTINCT replicas have dialed back with a
+// reply, or `seconds` pass; returns who replied. Every replica dials back
+// once it has executed, so waiting for all four is waiting for the whole
+// cluster: a round that stops its servers at the client's quorum (f+1)
+// and then holds each to executed_upto() >= 1 fails on a loaded host,
+// where the last replica's commits are still in flight (the one red test
+// of the driver's 6-worker run).
+std::set<std::string> await_repliers(int reply_fd, const int* ports,
+                                     const std::string& req, size_t want,
+                                     int seconds) {
+  std::set<std::string> repliers;
+  auto deadline =
+      std::chrono::steady_clock::now() + std::chrono::seconds(seconds);
+  int attempt = 0;
+  while (repliers.size() < want &&
+         std::chrono::steady_clock::now() < deadline) {
+    int fd = pbft::dial_tcp("127.0.0.1:" +
+                            std::to_string(ports[attempt++ % 4]));
+    if (fd >= 0) {
+      (void)!::send(fd, req.data(), req.size(), MSG_NOSIGNAL);
+      ::close(fd);
+    }
+    auto retry_at =
+        std::chrono::steady_clock::now() + std::chrono::milliseconds(400);
+    while (repliers.size() < want &&
+           std::chrono::steady_clock::now() < retry_at) {
+      pollfd pfd{reply_fd, POLLIN, 0};
+      if (::poll(&pfd, 1, 50) <= 0) continue;
+      int cfd = ::accept(reply_fd, nullptr, nullptr);
+      if (cfd < 0) continue;
+      char buf[512];
+      ssize_t got = ::recv(cfd, buf, sizeof(buf) - 1, 0);
+      ::close(cfd);
+      if (got <= 0) continue;
+      const std::string reply(buf, (size_t)got);
+      const size_t at = reply.find("\"replica\":");
+      if (at != std::string::npos) {
+        repliers.insert(
+            reply.substr(at + 10, reply.find_first_of(",}", at) - at - 10));
+      }
+    }
+  }
+  return repliers;
+}
+
+// A sample's value in Prometheus text ("<name>{replica="r"} <value>").
+double prometheus_sample(const std::string& text, const std::string& name) {
+  const size_t at = text.find("\n" + name + "{");
+  if (at == std::string::npos) return -1;
+  const size_t sp = text.find("} ", at);
+  return sp == std::string::npos ? -1 : std::atof(text.c_str() + sp + 2);
+}
+
 // One real-socket 4-replica round: client request in, f+1 dial-back
 // replies observed, on whichever readiness backend the environment
 // selects. The PBFT_NET_POLL=1 arm proves the incrementally-maintained
 // poll() fallback is behaviorally identical to edge-triggered epoll.
+// Every replica runs with metrics and a WAL, so the round also pins the
+// verify-trip histograms: one inbox-wait observation per verify batch
+// (blocking branch here; tests/test_verify_spans.py drives the async
+// one), one flush observation per WAL flush that had records pending.
 void parity_round(const char* want_backend) {
   int ports[4];
   int hold[4];
@@ -797,10 +855,15 @@ void parity_round(const char* want_backend) {
     seeds.push_back(seed);
   }
   for (int i = 0; i < 4; ++i) ::close(hold[i]);
+  const char* tmp = std::getenv("TMPDIR");
+  std::string wal_dir = std::string(tmp ? tmp : "/tmp") + "/pbft-parity-wal-XXXXXX";
+  CHECK(::mkdtemp(wal_dir.data()) != nullptr);
   std::vector<std::unique_ptr<pbft::ReplicaServer>> servers;
   for (int i = 0; i < 4; ++i) {
     servers.push_back(std::make_unique<pbft::ReplicaServer>(
         cfg, i, seeds[i].data(), std::make_unique<pbft::CpuVerifier>()));
+    servers[i]->metrics().enabled = true;
+    CHECK(servers[i]->enable_wal(wal_dir));
     CHECK(servers[i]->start());
     CHECK(std::string(servers[i]->net_backend()) == want_backend);
   }
@@ -815,32 +878,25 @@ void parity_round(const char* want_backend) {
   const std::string req =
       "{\"type\":\"client-request\",\"operation\":\"backend\","
       "\"timestamp\":1,\"client\":\"" + reply_addr + "\"}\n";
-  int replies = 0;
-  auto deadline = std::chrono::steady_clock::now() + std::chrono::seconds(20);
-  int attempt = 0;
-  while (replies < 2 && std::chrono::steady_clock::now() < deadline) {
-    int fd = pbft::dial_tcp("127.0.0.1:" +
-                            std::to_string(ports[attempt++ % 4]));
-    if (fd >= 0) {
-      (void)!::send(fd, req.data(), req.size(), MSG_NOSIGNAL);
-      ::close(fd);
-    }
-    auto retry_at =
-        std::chrono::steady_clock::now() + std::chrono::milliseconds(400);
-    while (replies < 2 && std::chrono::steady_clock::now() < retry_at) {
-      pollfd pfd{reply_fd, POLLIN, 0};
-      if (::poll(&pfd, 1, 50) <= 0) continue;
-      int cfd = ::accept(reply_fd, nullptr, nullptr);
-      if (cfd < 0) continue;
-      char buf[512];
-      if (::recv(cfd, buf, sizeof(buf) - 1, 0) > 0) ++replies;
-      ::close(cfd);
-    }
-  }
-  CHECK(replies >= 2);  // f+1 distinct dial-backs observed
+  const auto repliers = await_repliers(reply_fd, ports, req, 4, 30);
+  CHECK(repliers.size() >= 2);  // f+1 distinct dial-backs: the client's quorum
+  CHECK(repliers.size() == 4);
   for (auto& s : servers) s->stop();
   for (auto& t : loops) t.join();
   for (auto& s : servers) CHECK(s->replica().executed_upto() >= 1);
+  for (int i = 0; i < 4; ++i) {
+    const std::string text = "\n" + servers[i]->metrics().render_prometheus("r");
+    const double batches = prometheus_sample(text, "pbft_verify_batches_total");
+    CHECK(batches >= 1);
+    CHECK(prometheus_sample(text, "pbft_verify_inbox_wait_seconds_count") ==
+          batches);
+    CHECK(prometheus_sample(text, "pbft_verify_inbox_wait_seconds_sum") >= 0);
+    const double flushes = prometheus_sample(text, "pbft_wal_flush_seconds_count");
+    CHECK(flushes >= 1);
+    CHECK(flushes <= prometheus_sample(text, "pbft_wal_appends_total"));
+    ::unlink((wal_dir + "/replica-" + std::to_string(i) + ".wal").c_str());
+  }
+  ::rmdir(wal_dir.c_str());
   ::close(reply_fd);
 }
 
@@ -901,36 +957,19 @@ int64_t multicore_round(int net_threads, bool fastpath_mac = false,
         "{\"type\":\"client-request\",\"operation\":\"mc-" +
         std::to_string(ts) + "\",\"timestamp\":" + std::to_string(ts) +
         ",\"client\":\"" + reply_addr + "\"}\n";
-    int replies = 0;
-    auto deadline =
-        std::chrono::steady_clock::now() + std::chrono::seconds(30);
-    int attempt = 0;
-    while (replies < 2 && std::chrono::steady_clock::now() < deadline) {
-      int fd = pbft::dial_tcp("127.0.0.1:" +
-                              std::to_string(ports[attempt++ % 4]));
-      if (fd >= 0) {
-        (void)!::send(fd, req.data(), req.size(), MSG_NOSIGNAL);
-        ::close(fd);
-      }
-      auto retry_at =
-          std::chrono::steady_clock::now() + std::chrono::milliseconds(400);
-      while (replies < 2 && std::chrono::steady_clock::now() < retry_at) {
-        pollfd pfd{reply_fd, POLLIN, 0};
-        if (::poll(&pfd, 1, 50) <= 0) continue;
-        int cfd = ::accept(reply_fd, nullptr, nullptr);
-        if (cfd < 0) continue;
-        char buf[512];
-        if (::recv(cfd, buf, sizeof(buf) - 1, 0) > 0) ++replies;
-        ::close(cfd);
-      }
+    const auto repliers = await_repliers(reply_fd, ports, req, 4, 30);
+    CHECK(repliers.size() >= 2);  // f+1 distinct dial-backs per request
+    CHECK(repliers.size() == 4);  // ...and every replica executed it
+    if (repliers.size() != 4) {
+      std::string who;
+      for (const auto& r : repliers) who += r + " ";
+      std::fprintf(stderr, "  net_threads=%d mac=%d tentative=%d ts=%d replied: %s\n",
+                   net_threads, (int)fastpath_mac, (int)tentative, ts, who.c_str());
     }
-    CHECK(replies >= 2);  // f+1 distinct dial-backs per request
   }
-  // Let the trailing commits land everywhere before the stop.
-  auto settle = std::chrono::steady_clock::now() + std::chrono::seconds(2);
-  while (std::chrono::steady_clock::now() < settle) {
-    std::this_thread::sleep_for(std::chrono::milliseconds(50));
-  }
+  // Let the trailing commits land everywhere before the stop (a tentative
+  // reply leaves at PREPARED, ahead of the commits that promote it).
+  std::this_thread::sleep_for(std::chrono::seconds(2));
   for (auto& s : servers) s->stop();
   for (auto& t : loops) t.join();
   int64_t max_executed = 0;

@@ -107,6 +107,11 @@ const std::pair<const char*, bool> kHistogramNames[] = {
     {"pbft_phase_commit_seconds", false},
     {"pbft_phase_reply_seconds", false},
     {"pbft_request_reply_seconds", false},
+    // One verify trip, the replica's share: the oldest item's wait in the
+    // verify inbox (once per batch, at launch) and one WAL group-commit
+    // flush (write + fsync).
+    {"pbft_verify_inbox_wait_seconds", false},
+    {"pbft_wal_flush_seconds", false},
 };
 
 // JSONL trace events net.cc emits (trace_batch, trace_view_change,

@@ -1455,6 +1455,7 @@ void ReplicaServer::check_verify_deadline(
   size_t n_items = inflight_items_.size();
   verify_inflight_ = false;
   inflight_items_.clear();
+  inbox_launched_ = 0;
   deliver_verified(n_items, dispatched_at, std::move(verdicts));
   if (cfg_.verify_flush_us > 0 && replica_->pending_count() > 0) {
     // Same backdating as finish_verify_async: what queued during the
@@ -1500,6 +1501,7 @@ void ReplicaServer::run_verify_batch() {
   metrics_.set_gauge("pbft_verify_queue_depth", (double)pending);
   if (pending == 0) {
     verify_window_open_ = false;
+    inbox_waiting_ = false;
     return;
   }
   if (cfg_.verify_flush_us > 0) {
@@ -1525,6 +1527,15 @@ void ReplicaServer::run_verify_batch() {
     verify_window_open_ = false;
   }
   auto items = replica_->pending_items();
+  if (inbox_waiting_) {
+    // How long the oldest item of this batch sat in the inbox: a message
+    // that arrives while a batch is in flight waits out that whole trip.
+    inbox_waiting_ = false;
+    metrics_.observe("pbft_verify_inbox_wait_seconds",
+                     std::chrono::duration<double>(
+                         std::chrono::steady_clock::now() - inbox_since_)
+                         .count());
+  }
   // Async first (RemoteVerifier): ship the batch and keep the loop
   // draining sockets — the round-trip is where the next window's
   // occupancy accumulates. Falls through to the blocking path when the
@@ -1533,6 +1544,7 @@ void ReplicaServer::run_verify_batch() {
   if (verifier_->begin_batch(items)) {
     verify_inflight_ = true;
     inflight_items_ = std::move(items);
+    inbox_launched_ = pending;
     inflight_start_ = std::chrono::steady_clock::now();
     register_verifier_fd();
     return;
@@ -1610,6 +1622,7 @@ void ReplicaServer::finish_verify_async() {
   size_t n_items = inflight_items_.size();
   verify_inflight_ = false;
   inflight_items_.clear();
+  inbox_launched_ = 0;
   deliver_verified(n_items, dispatched_at, std::move(verdicts));
   // Items that queued DURING the launch have already waited up to the
   // round-trip: backdate the next flush window to the dispatch time so
@@ -1732,7 +1745,12 @@ bool ReplicaServer::enable_wal(const std::string& dir) {
 
 void ReplicaServer::flush_wal() {
   if (!wal_ || wal_->pending() == 0) return;
+  const auto t0 = std::chrono::steady_clock::now();
   wal_->flush();
+  metrics_.observe(
+      "pbft_wal_flush_seconds",
+      std::chrono::duration<double>(std::chrono::steady_clock::now() - t0)
+          .count());
   const int64_t appends = wal_->appends();
   const int64_t fsyncs = wal_->fsyncs();
   const int64_t bytes = wal_->bytes_written();
@@ -1753,6 +1771,14 @@ void ReplicaServer::emit(Actions&& actions) {
   // pass (a verify batch's worth of votes), keeping fsync off the
   // per-message path.
   if (wal_) flush_wal();
+  // Verify-inbox wait: every receive() comes back through here, so the
+  // first pass that finds an item no launch has taken stamps its arrival
+  // — one clock read per wait, none per message.
+  if (metrics_.enabled && !inbox_waiting_ &&
+      replica_->pending_count() > inbox_launched_) {
+    inbox_waiting_ = true;
+    inbox_since_ = std::chrono::steady_clock::now();
+  }
   const bool mute = fault_mode_ == FaultMode::kMute;
   for (auto& b : actions.broadcasts) {
     // A broadcast of our OWN pre-prepare is the seal of a request batch

@@ -738,6 +738,12 @@ class ReplicaServer {
   bool verify_inflight_ = false;
   std::vector<VerifyItem> inflight_items_;
   std::chrono::steady_clock::time_point inflight_start_{};
+  // pbft_verify_inbox_wait_seconds: inbox entries the launch in flight
+  // took (they stay queued until its verdicts come), and since when an
+  // entry beyond them — one no launch has taken — has been waiting.
+  size_t inbox_launched_ = 0;
+  bool inbox_waiting_ = false;
+  std::chrono::steady_clock::time_point inbox_since_{};
   int verify_deadline_ms_ = 15000;
   int64_t verify_deadline_fired_ = 0;  // surfaced in metrics_json
   // Batches the CPU safety net verified HERE because the remote launch

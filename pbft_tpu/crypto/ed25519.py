@@ -26,6 +26,7 @@ field.py). All control flow is static; everything vmaps/jits.
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -257,20 +258,27 @@ def verify_kernel(pub, msg, sig):
     sig = jnp.asarray(sig, jnp.uint8)
     r_bytes = sig[..., :32]
     s_bytes = sig[..., 32:]
-    # Challenge hash: h = SHA512(R || A || M) mod L.
-    h_raw = sha512(jnp.concatenate([r_bytes, pub, msg], axis=-1))
-    h = F.reduce512_mod_l(F.bytes_to_limbs(h_raw))
-    s = F.bytes_to_limbs(s_bytes)
-    s_ok = F.scalar_lt_l(s)
-    ok_a, a_pt = decompress(pub)
-    if _use_pallas():
-        from . import pallas_kernels
+    # The four stages carry names (jax.named_scope -> the operations'
+    # op_name), so that a profiler trace can say which one a launch's
+    # device time went to; names only, the computation is the same.
+    with jax.named_scope("sha512_challenge"):
+        # Challenge hash: h = SHA512(R || A || M) mod L.
+        h_raw = sha512(jnp.concatenate([r_bytes, pub, msg], axis=-1))
+        h = F.reduce512_mod_l(F.bytes_to_limbs(h_raw))
+        s = F.bytes_to_limbs(s_bytes)
+        s_ok = F.scalar_lt_l(s)
+    with jax.named_scope("decompress"):
+        ok_a, a_pt = decompress(pub)
+    with jax.named_scope("ladder"):
+        if _use_pallas():
+            from . import pallas_kernels
 
-        p = pallas_kernels.ladder(
-            F.scalar_bits(s), F.scalar_bits(h), point_neg(a_pt)
-        )
-    else:
-        p = shamir_ladder(F.scalar_bits(s), F.scalar_bits(h), point_neg(a_pt))
-    enc = compress(p)
-    match = jnp.all(enc == r_bytes, axis=-1)
+            p = pallas_kernels.ladder(
+                F.scalar_bits(s), F.scalar_bits(h), point_neg(a_pt)
+            )
+        else:
+            p = shamir_ladder(F.scalar_bits(s), F.scalar_bits(h), point_neg(a_pt))
+    with jax.named_scope("compress"):
+        enc = compress(p)
+        match = jnp.all(enc == r_bytes, axis=-1)
     return ok_a & s_ok & match

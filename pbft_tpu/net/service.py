@@ -30,6 +30,8 @@ import threading
 import time
 from typing import Callable, List, Optional, Tuple
 
+from ..utils.trace import Tracer, open_span
+
 Item = Tuple[bytes, bytes, bytes]
 
 # -- readiness handshake wire format (ISSUE 7) -------------------------------
@@ -116,10 +118,11 @@ def native_backend(items: List[Item]) -> List[bool]:
 
 
 class _Pending:
-    __slots__ = ("items", "event", "verdicts", "error")
+    __slots__ = ("items", "arrived", "event", "verdicts", "error")
 
     def __init__(self, items: List[Item]):
         self.items = items
+        self.arrived = time.monotonic()
         self.event = threading.Event()
         self.verdicts: Optional[List[bool]] = None
         self.error: Optional[Exception] = None
@@ -194,8 +197,6 @@ class VerifierService:
         # the honest occupancy measurement for the launch-cost model — the
         # merged window IS the launch, where per-replica traces only see
         # each daemon's share.
-        from ..utils.trace import Tracer
-
         self._tracer = Tracer(open(trace_path, "a") if trace_path else None)
         # Metrics (utils/metrics.py; the verify subset of the cross-runtime
         # contract in utils/trace_schema.py). Disabled unless a scrape
@@ -215,6 +216,13 @@ class VerifierService:
         self.batches = 0  # backend calls (XLA launches)
         self.requests = 0  # wire requests (>= batches when coalescing)
         self.items = 0
+        # What an operator without --trace needs to see a stall: running
+        # totals of each stage of a launch — the dispatcher's two waits and
+        # every duration (``*_s``) the backend writes into its span, the
+        # sharded engine's five steps — and the slowest launch so far with
+        # the step that held it (written under _cond by the launch threads).
+        self.stage_seconds = {"queue_s": 0.0, "slot_s": 0.0}
+        self._slowest: Optional[dict] = None
         self._coalesce = coalesce
         self._cond = threading.Condition()
         self._pending: List[_Pending] = []
@@ -372,18 +380,30 @@ class VerifierService:
                         break
                     size += nxt
                     window.append(self._pending.pop(0))
-                if self.metrics_registry.enabled:  # items left queued past MAX_WINDOW
-                    self.metrics_registry.gauge("pbft_verify_queue_depth").set(
-                        sum(len(p.items) for p in self._pending)
-                    )
+                cut_at = time.monotonic()
+                left = self._pending_items()  # queued past MAX_WINDOW
+                if self.metrics_registry.enabled:
+                    self.metrics_registry.gauge("pbft_verify_queue_depth").set(left)
+            # The window is cut BEFORE a launch slot is free and cannot grow
+            # while it waits for one: slot_s is that wait, pending_at_launch
+            # what a cut made only now would have merged into it.
             self._inflight_sem.acquire()
+            got_slot = time.monotonic()
+            with self._cond:
+                arrived_since = self._pending_items()
+            waits = {
+                "queue_s": round(cut_at - min(p.arrived for p in window), 6),
+                "slot_s": round(got_slot - cut_at, 6),
+                "pending_at_cut": left,
+                "pending_at_launch": arrived_since,
+            }
             if self._inflight == 1:
-                self._dispatch_guarded(window)
+                self._dispatch_guarded(window, waits)
             else:
                 # Overlapped mode: the launch runs on its own thread while
                 # the dispatcher loops back to accumulate the next window.
                 t = threading.Thread(
-                    target=self._dispatch_guarded, args=(window,), daemon=True
+                    target=self._dispatch_guarded, args=(window, waits), daemon=True
                 )
                 with self._cond:  # stop() reads this list concurrently
                     self._launch_threads = [
@@ -392,9 +412,12 @@ class VerifierService:
                     self._launch_threads.append(t)
                 t.start()
 
-    def _dispatch_guarded(self, window: List[_Pending]) -> None:
+    def _pending_items(self) -> int:
+        return sum(len(p.items) for p in self._pending)
+
+    def _dispatch_guarded(self, window: List[_Pending], waits: dict) -> None:
         try:
-            self._dispatch_window(window)
+            self._dispatch_window(window, waits)
         except Exception as e:  # noqa: BLE001 - never strand a handler
             # Any dispatcher bug outside the backend guard must still
             # wake every waiting connection with an error rather than
@@ -418,18 +441,51 @@ class VerifierService:
             )
         return verdicts
 
-    def _dispatch_window(self, window: List[_Pending]) -> None:
+    def _spanned(self, items: List[Item]) -> Tuple[List[bool], dict]:
+        """One backend call -> (verdicts, what the backend wrote into the
+        span opened round it: the engine's steps, or nothing)."""
+        with open_span() as span:
+            return self._checked(self.backend, items), span
+
+    def _account(self, secs: float, size: int, waits: dict, span: dict) -> None:
+        """Fold one finished launch into the status totals (under _cond)."""
+        steps = {k: v for k, v in span.items() if k.endswith("_s")}
+        for name in ("queue_s", "slot_s"):
+            self.stage_seconds[name] += waits[name]
+        for name, took in steps.items():
+            self.stage_seconds[name] = self.stage_seconds.get(name, 0.0) + took
+        if self._slowest is None or secs > self._slowest["secs"]:
+            self._slowest = {
+                "secs": round(secs, 6),
+                "size": size,
+                "rung": span.get("rung"),
+                "stage": max(steps, key=steps.get) if steps else None,
+                "at": time.monotonic(),  # launch_status() turns it into ago_s
+            }
+
+    def launch_status(self) -> dict:
+        """The stage totals and the slowest launch, for the status JSON."""
+        with self._cond:
+            slowest = dict(self._slowest) if self._slowest else None
+            totals = {k: round(v, 6) for k, v in self.stage_seconds.items()}
+        if slowest:
+            slowest["ago_s"] = round(time.monotonic() - slowest.pop("at"), 3)
+        return {"stage_seconds": totals, "slowest_launch": slowest}
+
+    def _dispatch_window(self, window: List[_Pending], waits: dict) -> None:
         merged: List[Item] = []
         for p in window:
             merged.extend(p.items)
         t0 = time.monotonic()
+        span: dict = {}
         try:
-            verdicts = self._checked(self.backend, merged)
+            verdicts, span = self._spanned(merged)
         except Exception:
             # One launch failing must not reject every client's honest
             # signatures ("never a false reject"): retry each request
             # alone so only the actually-poisoned one errors out.
             verdicts = None
+        secs = time.monotonic() - t0
         if self._tracer.enabled:
             # A failed merged launch is NOT a verify_batch event: the
             # launch-cost model reads verify_batch sizes as items-per-
@@ -443,25 +499,24 @@ class VerifierService:
                 rejected=(
                     verdicts.count(False) if verdicts is not None else -1
                 ),
-                secs=round(time.monotonic() - t0, 6),
+                secs=round(secs, 6),
+                **({**waits, **span} if verdicts is not None else {}),
             )
         with self._cond:
+            # Under the lock: with --inflight > 1 several launch threads
+            # finish concurrently (the replica runtimes' single-writer
+            # discipline doesn't hold here).
             self.batches += 1
             self.items += len(merged)
+            if verdicts is not None:
+                self._account(secs, len(merged), waits, span)
             if self.metrics_registry.enabled:
-                # Under the lock: with --inflight > 1 several launch
-                # threads finish concurrently (the replica runtimes'
-                # single-writer discipline doesn't hold here).
-                secs = time.monotonic() - t0
                 self.metrics_registry.counter("pbft_verify_batches_total").inc()
                 self.metrics_registry.counter("pbft_verify_items_total").inc(len(merged))
                 self.metrics_registry.histogram("pbft_verify_batch_size").observe(
                     len(merged)
                 )
                 self.metrics_registry.histogram("pbft_verify_seconds").observe(secs)
-                self.metrics_registry.gauge("pbft_verify_inflight_age_seconds").set(
-                    round(secs, 6)
-                )
                 # Service launch surface (ISSUE 7): items per XLA launch
                 # and how many connections each merged window carried —
                 # the coalescing win the launch-cost model prices.
@@ -481,8 +536,9 @@ class VerifierService:
         if verdicts is None:
             for p in window:
                 t1 = time.monotonic()
+                span: dict = {}
                 try:
-                    p.verdicts = self._checked(self.backend, p.items)
+                    p.verdicts, span = self._spanned(p.items)
                 except Exception as e:  # noqa: BLE001 - handed to submitter
                     p.error = e
                 if self._tracer.enabled:
@@ -494,6 +550,7 @@ class VerifierService:
                             requests=1,
                             rejected=p.verdicts.count(False),
                             secs=round(time.monotonic() - t1, 6),
+                            **span,
                         )
                     else:
                         # NOT a verify_batch event: trace_report sums the

@@ -67,6 +67,7 @@ from .service import (  # noqa: F401 - re-exported API
     pack_status,
     unpack_status,
 )
+from ..utils.trace import current_span
 
 
 # -- the accelerator-owning engine -------------------------------------------
@@ -222,13 +223,22 @@ class ShardedVerifyEngine:
 
     # -- serving -------------------------------------------------------------
 
+    # The steps of a chunk, in order; each is timed and is a profiler span.
+    STEPS = ("pad_s", "put_s", "dispatch_s", "wait_s", "unpack_s")
+
     def verify(self, items: List[Item]) -> List[bool]:
         """Pad to a warmed window shape, stage (async device_put against
         the batch sharding), launch the precompiled executable, read back.
         Oversized batches chunk into top-of-ladder windows — the service
         never compiles a new shape at runtime. Verdicts are bit-identical
         to the single-device and CPU paths (pinned in tests/test_parallel
-        and tests/test_service_coalesce)."""
+        and tests/test_service_coalesce).
+
+        The five steps of every chunk are timed (summed over chunks) into
+        the caller's ``utils.trace.current_span()``, where one is open —
+        the service's ``verify_batch`` line — and each is a profiler
+        annotation (``verifyd.<step>``; free while no trace runs)."""
+        t_in = time.monotonic()
         if not items:
             return []
         if not self._compiled:
@@ -238,24 +248,69 @@ class ShardedVerifyEngine:
 
         from ..crypto.batch import pad_batch
 
+        step = jax.profiler.TraceAnnotation
         top = max(self._compiled)
         out: List[bool] = []
+        secs = dict.fromkeys(self.STEPS, 0.0)
+        rung = 0
+        t_dev = None
         for off in range(0, len(items), top):
             chunk = items[off : off + top]
             size = min(
                 (s for s in self._compiled if s >= len(chunk)), default=top
             )
-            pubs, msgs, sigs, n = pad_batch(chunk, size)
+            marks = [t_in]  # the chunk's start, then the end of each step
+            with step("verifyd.pad"):
+                pubs, msgs, sigs, n = pad_batch(chunk, size)
+            marks.append(time.monotonic())
             # Host->device staging is async dispatch; with the service's
             # overlapped launches (inflight=2) window N+1 stages here
             # while window N computes. Donated inputs let XLA reuse the
             # same device memory for every window of this shape.
-            dp = jax.device_put(pubs, self._spec)
-            dm = jax.device_put(msgs, self._spec)
-            ds = jax.device_put(sigs, self._spec)
-            verdicts = np.asarray(self._compiled[size](dp, dm, ds))
-            out.extend(bool(v) for v in verdicts[:n])
+            with step("verifyd.put"):
+                dp = jax.device_put(pubs, self._spec)
+                dm = jax.device_put(msgs, self._spec)
+                ds = jax.device_put(sigs, self._spec)
+            marks.append(time.monotonic())
+            with step("verifyd.dispatch"):  # returns once enqueued
+                result = self._compiled[size](dp, dm, ds)
+            marks.append(time.monotonic())
+            # Behind the other launch in flight, then the device, then the
+            # read-back: np.asarray returns when the verdicts are here.
+            with step("verifyd.wait"):
+                verdicts = np.asarray(result)
+            marks.append(time.monotonic())
+            with step("verifyd.unpack"):
+                out.extend(bool(v) for v in verdicts[:n])
+                # Dropping the device buffers takes its time too (~0.1 ms):
+                # here, so that it is timed, not at the function's exit.
+                del dp, dm, ds, result, verdicts
+            marks.append(time.monotonic())
+            for name, start, end in zip(self.STEPS, marks, marks[1:]):
+                secs[name] += end - start
+            rung += size
+            if t_dev is None:
+                t_dev = marks[2]  # the first dispatch
+            t_in = marks[-1]
+        span = current_span()
+        if span is not None:
+            span.update({k: round(v, 6) for k, v in secs.items()})
+            span.update(rung=rung, t_dev=round(t_dev, 6))
         return out
+
+    def memory_peak_bytes(self) -> Optional[int]:
+        """The fullest local device's ``peak_bytes_in_use``; None before the
+        backend is up and where the backend reports no memory statistics."""
+        if self._mesh is None:
+            return None
+        import jax
+
+        peaks = [
+            (dev.memory_stats() or {}).get("peak_bytes_in_use")
+            for dev in jax.local_devices()
+        ]
+        peaks = [int(p) for p in peaks if p is not None]
+        return max(peaks) if peaks else None
 
 
 # -- the daemon --------------------------------------------------------------
@@ -389,6 +444,10 @@ class VerifyServiceDaemon:
             "requests": self.service.requests,
             **counts,
         }
+        # A stall without --trace: where launches spend their time, the
+        # slowest one with the step that held it, the device's peak memory.
+        out.update(self.service.launch_status())
+        out["memory_peak_bytes"] = eng.memory_peak_bytes() if eng else None
         if eng and eng.stats:
             out["warm_stats"] = eng.stats
         if self._warm_error:

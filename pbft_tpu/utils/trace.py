@@ -8,10 +8,11 @@ did (reference src/handler.rs:265,:269; SURVEY.md §5).
 
 from __future__ import annotations
 
+import contextlib
 import json
 import threading
 import time
-from typing import IO, Optional
+from typing import IO, Iterator, Optional
 
 
 class Tracer:
@@ -35,6 +36,34 @@ class Tracer:
         with self._lock:
             self.sink.write(line)
             self.sink.flush()
+
+
+# -- per-thread span record ---------------------------------------------------
+#
+# A caller that writes one trace line for a piece of work opens a record
+# round the call; code further down the same thread's stack (which the
+# caller reaches only through a plain callable, e.g. the verify service's
+# ``backend(items) -> verdicts``) adds what it timed to ``current_span()``.
+# Per thread, so two launches in flight never see each other's record.
+
+_span = threading.local()
+
+
+@contextlib.contextmanager
+def open_span() -> Iterator[dict]:
+    rec: dict = {}
+    outer = current_span()
+    _span.rec = rec
+    try:
+        yield rec
+    finally:
+        _span.rec = outer
+
+
+def current_span() -> Optional[dict]:
+    """The record the nearest ``open_span()`` on this thread yielded, or
+    None when nobody up the stack asked for one."""
+    return getattr(_span, "rec", None)
 
 
 _tracer = Tracer()

@@ -22,9 +22,21 @@ from __future__ import annotations
 # -- trace events (JSONL lines: {"ts": .., "ev": <name>, ...fields}) --------
 
 EVENT_SCHEMAS = {
+    # The verify service's line is one LAUNCH, and carries its stages, all
+    # on time.monotonic(): queue_s (window cut minus the arrival of the
+    # oldest request in it), slot_s (launch slot acquired minus window
+    # cut), pending_at_cut / pending_at_launch (items queued at those two
+    # moments); and, where the sharded engine ran it, the engine's five
+    # steps summed over chunks (pad_s, put_s, dispatch_s, wait_s, unpack_s:
+    # they add up to secs), rung (padded slots run) and t_dev (absolute
+    # stamp at the first dispatch).
     "verify_batch": {
         "required": {"ts", "ev", "replica", "size", "rejected", "secs"},
-        "optional": {"view", "executed", "requests"},
+        "optional": {
+            "view", "executed", "requests",
+            "queue_s", "slot_s", "pending_at_cut", "pending_at_launch",
+            "pad_s", "put_s", "dispatch_s", "wait_s", "unpack_s", "rung", "t_dev",
+        },
         "emitters": {"server.py", "service.py", "net.cc"},
     },
     "verify_window_failed": {
@@ -140,7 +152,7 @@ METRIC_SCHEMAS = {
     # metrics_json mirrors it as verify_service_fallbacks.
     "pbft_verify_service_fallbacks_total": ("counter", {"server.py", "net.cc"}),
     "pbft_verify_queue_depth": ("gauge", {"server.py", "service.py", "net.cc"}),
-    "pbft_verify_inflight_age_seconds": ("gauge", {"server.py", "service.py", "net.cc"}),
+    "pbft_verify_inflight_age_seconds": ("gauge", {"server.py", "net.cc"}),
     # Native verify-pool surface (core/verify_pool.cc): pool width, windows
     # queued by the last dispatch, lifetime busy/(wall*threads) ratio, and
     # the per-dispatch RLC window width. C++ runtime only — the Python
@@ -294,6 +306,13 @@ METRIC_SCHEMAS = {
     "pbft_phase_commit_seconds": ("histogram", {"server.py", "net.cc"}),
     "pbft_phase_reply_seconds": ("histogram", {"server.py", "net.cc"}),
     "pbft_request_reply_seconds": ("histogram", {"server.py", "net.cc"}),
+    # One verify trip, the replica's share (pbftd only). Inbox wait: observed
+    # once per verify batch at launch, launch time minus the arrival of the
+    # oldest item no earlier launch took (an item that arrives while a batch
+    # is in flight waits out that whole trip). WAL flush: round one
+    # group-commit flush (write + fsync) that had records pending.
+    "pbft_verify_inbox_wait_seconds": ("histogram", {"net.cc"}),
+    "pbft_wal_flush_seconds": ("histogram", {"net.cc"}),
 }
 
 # Fixed histogram bucket upper edges (le semantics: v <= edge). Shared by

@@ -6,7 +6,9 @@ is actually doing: state, platform and device kind, devices seen and in
 the mesh, warmed window shapes, engine vs fallback dispatch counts, and
 the once-per-deploy compile timings per shape — which shapes the
 persistent compile cache answered (warm) and which were traced+compiled
-(cold).
+(cold) — the running total of each launch stage (queue, slot, pad, put,
+dispatch, wait, unpack), the slowest launch so far with the step that
+held it, and the device's peak memory.
 
     python scripts/verify_status.py                      # default target
     python scripts/verify_status.py 127.0.0.1:7600
@@ -79,8 +81,28 @@ def main(argv=None) -> int:
             if k in ("cold_compile_s", "warm_load_s"):
                 continue
             print(f"  {k:<15} {warm[k]}")
+    # Where launches spend their time, for seeing a stall without --trace:
+    # running totals per stage, and the slowest launch with the step that
+    # held it.
+    stages = status.get("stage_seconds") or {}
+    if stages:
+        print("  stage seconds   " + "  ".join(
+            f"{name.removesuffix('_s')} {secs:.3f}" for name, secs in stages.items()
+        ))
+    slowest = status.get("slowest_launch")
+    if slowest:
+        print(
+            "  slowest launch  {secs:.3f}s, {size} items at rung {rung}, longest "
+            "step {stage}, {ago_s:.0f}s ago".format(**slowest)
+        )
+    peak = status.get("memory_peak_bytes")
+    if peak is not None:
+        print(f"  device memory   peak {peak / 2**20:.1f} MiB on the fullest device")
     # Anything else the daemon reports rides along un-dropped.
-    known = {"state", "devices", "uptime_s", "warmed_shapes", "warm_stats"}
+    known = {
+        "state", "devices", "uptime_s", "warmed_shapes", "warm_stats",
+        "stage_seconds", "slowest_launch", "memory_peak_bytes",
+    }
     for k in sorted(set(status) - known):
         print(f"  {k:<15} {status[k]}")
     return 0

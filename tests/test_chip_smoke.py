@@ -50,6 +50,9 @@ class _NativeEngine:
     def warm(self):
         return self.stats
 
+    def memory_peak_bytes(self):
+        return None
+
     def verify(self, items):
         out = [bool(v) for v in native.verify_batch(items)]
         if self._lie is not None:

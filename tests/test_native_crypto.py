@@ -19,8 +19,10 @@ def test_native_ctest_binary():
     if not binary.exists():
         pytest.skip("core_test not built")
     out = subprocess.run([str(binary)], capture_output=True, text=True)
-    assert out.returncode == 0, out.stderr
-    assert "all native tests passed" in out.stdout
+    # Which CHECK failed is on stderr; how far the suite got, on stdout.
+    tail = f"exit {out.returncode}\nstdout: {out.stdout[-2000:]}\nstderr: {out.stderr[-4000:]}"
+    assert out.returncode == 0, tail
+    assert "all native tests passed" in out.stdout, tail
 from pbft_tpu.crypto import ref
 from tests.test_crypto_ref import RFC8032_VECTORS
 

@@ -487,6 +487,9 @@ class _StubEngine:
     def verify(self, items):
         return [True] * len(items)  # accept-all: provably not the fallback
 
+    def memory_peak_bytes(self):
+        return None
+
 
 def test_daemon_warming_serves_fallback_then_flips_ready():
     """While the accelerator warms, traffic is served by the fallback

@@ -1,0 +1,386 @@
+"""One verify trip, stage by stage (ISSUE 26): the spans the verify service
+writes on every ``verify_batch`` line (dispatcher: ``queue_s`` / ``slot_s`` /
+``pending_at_*``; engine: the five steps, ``rung``, ``t_dev``), the status
+fields that show a stall without ``--trace``, the replica's two histograms
+on the async (RemoteVerifier) branch, and the benchmark's readers of all of
+them (``chipbench/reducers/launch_field_stat.py``,
+``launch_union_idle_pct.py`` and the sixteen ``chipbench/metrics`` files)."""
+
+import hashlib
+import importlib.util
+import json
+import statistics
+import sys
+import threading
+import time
+import urllib.request
+from pathlib import Path
+
+import pytest
+
+from pbft_tpu.net import ShardedVerifyEngine, VerifierService, VerifyServiceDaemon
+from pbft_tpu.utils.trace import current_span, open_span
+
+from tests.test_service_coalesce import _item, _send_batch
+
+ROOT = Path(__file__).resolve().parent.parent
+CHIPBENCH = ROOT / "chipbench"
+STEPS = ShardedVerifyEngine.STEPS
+
+
+def _lines(path):
+    events = [json.loads(line) for line in Path(path).read_text().splitlines()]
+    return [e for e in events if e["ev"] == "verify_batch"]
+
+
+# -- (a) the dispatcher's waits ------------------------------------------------
+
+
+def test_dispatcher_lines_carry_queue_and_slot_waits(tmp_path):
+    """Three back-to-back windows on two launch slots: the third is cut at
+    once and then waits for a slot (``slot_s``), and what arrives meanwhile
+    (``pending_at_launch``) becomes the fourth, whose ``queue_s`` is its
+    OLDEST request's wait."""
+    gate = threading.Event()
+
+    def backend(items):
+        assert gate.wait(20)
+        return [p[0] == s[0] for p, m, s in items]
+
+    trace = tmp_path / "service.jsonl"
+    svc = VerifierService(backend=backend, inflight=2, trace_path=str(trace)).start()
+    threads, sent_at = [], {}
+
+    def send(tag, after_requests):
+        deadline = time.monotonic() + 10
+        while svc.requests < after_requests:  # the one before it has queued
+            assert time.monotonic() < deadline
+            time.sleep(0.005)
+        sent_at[tag] = time.monotonic()
+        t = threading.Thread(target=_send_batch, args=(svc.address, [_item(tag, True)] * tag))
+        t.start()
+        threads.append(t)
+
+    try:
+        for n, tag in enumerate((1, 2, 3)):  # windows 1 and 2 hold the slots, 3 waits
+            send(tag, n)
+            time.sleep(0.05)
+        send(4, 3)
+        time.sleep(0.15)
+        send(5, 4)  # 4 and 5 queue behind the window that waits for a slot
+        deadline = time.monotonic() + 10
+        while svc.requests < 5:
+            assert time.monotonic() < deadline
+            time.sleep(0.005)
+        released = time.monotonic()
+        gate.set()
+        for t in threads:
+            t.join(timeout=20)
+            assert not t.is_alive()
+    finally:
+        gate.set()
+        svc.stop()
+    lines = sorted(_lines(trace), key=lambda e: e["size"])
+    assert [e["size"] for e in lines] == [1, 2, 3, 9]
+    for e in lines:
+        assert {"queue_s", "slot_s", "pending_at_cut", "pending_at_launch"} <= set(e)
+        assert e["queue_s"] >= 0 and e["slot_s"] >= 0 and e["pending_at_cut"] == 0
+    first, second, third, fourth = lines
+    assert first["slot_s"] < 0.05 and second["slot_s"] < 0.05  # a slot was free
+    # The third window was cut when its request arrived and got a slot only
+    # when the gate opened; by then requests 4 and 5 (9 items) had queued.
+    assert third["slot_s"] >= released - sent_at[3] - 0.05 > 0.1
+    assert third["queue_s"] < 0.05
+    assert third["pending_at_launch"] == 9
+    # The fourth window holds requests 4 and 5, cut once the dispatcher was
+    # back: its wait is request 4's (the oldest), not request 5's.
+    assert fourth["requests"] == 2
+    cut_by = fourth["ts"] - fourth["secs"]
+    assert sent_at[5] - sent_at[4] <= fourth["queue_s"] <= cut_by - sent_at[4] + 0.05
+    status = svc.launch_status()
+    assert status["stage_seconds"]["slot_s"] >= third["slot_s"]
+    assert status["stage_seconds"]["queue_s"] >= fourth["queue_s"]
+
+
+# -- (b) the engine's five steps, two launches in flight ----------------------
+
+
+def test_span_records_are_per_thread():
+    barrier = threading.Barrier(2, timeout=10)
+    seen = {}
+
+    def work(name):
+        assert current_span() is None
+        with open_span() as span:
+            barrier.wait()  # both spans are open now
+            current_span()["who"] = name
+            barrier.wait()  # both have written
+            seen[name] = dict(span)
+        assert current_span() is None
+
+    threads = [threading.Thread(target=work, args=(n,)) for n in ("a", "b")]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=10)
+        assert not t.is_alive()
+    assert seen == {"a": {"who": "a"}, "b": {"who": "b"}}
+
+
+def _slow_kernel(pubs, msgs, sigs):
+    """valid iff sig[0] == pub[0], after some milliseconds of device work,
+    so that two launches are really in the engine at once."""
+    import jax
+    import jax.numpy as jnp
+
+    spin = jax.lax.fori_loop(
+        0, 400_000, lambda i, acc: acc + (i & 1), pubs[0, 0].astype(jnp.int32)
+    )
+    return (pubs[:, 0] == sigs[:, 0]) & (spin >= 0)
+
+
+def _rung_of(size, shapes):
+    top = max(shapes)
+    chunks = [min(top, size - off) for off in range(0, size, top)]
+    return sum(min(s for s in shapes if s >= n) for n in chunks)
+
+
+def test_engine_steps_sum_to_secs_with_two_launches_in_flight(tmp_path):
+    shapes = (8, 16)
+    engine = ShardedVerifyEngine(shapes=shapes, kernel=_slow_kernel)
+    trace = tmp_path / "verifyd.jsonl"
+    daemon = VerifyServiceDaemon(
+        backend="auto", engine=engine, inflight=2, trace_path=str(trace),
+        fallback=lambda items: pytest.fail("the fallback ran"),
+    ).start(wait_ready=True, timeout=300)
+    assert daemon.state_name == "ready"
+    errors = []
+
+    def client(n_items, rounds):
+        items = [_item(n_items, i % 2 == 0) for i in range(n_items)]
+        try:
+            for _ in range(rounds):
+                assert _send_batch(daemon.address, items) == [i % 2 == 0 for i in range(n_items)]
+        except Exception as e:  # noqa: BLE001 - reported below
+            errors.append(e)
+
+    try:
+        # Sizes whose rungs differ (5 -> 8, 12 -> 16, merged 17 -> 16 + 8):
+        # a record read from the other thread would carry the wrong rung.
+        threads = [threading.Thread(target=client, args=(n, 8)) for n in (5, 12, 5)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+            assert not t.is_alive()
+        crowded = len(_lines(trace))
+        client(7, 8)  # and one caller alone: nothing contends for the interpreter
+        status = daemon.status_json()
+    finally:
+        daemon.stop()
+    assert not errors, errors
+    lines = _lines(trace)
+    assert sum(e["size"] for e in lines) == 8 * (5 + 12 + 5 + 7)
+    spans = sorted((e["ts"] - e["secs"], e["ts"]) for e in lines)
+    assert any(b_start < a_end for (_, a_end), (b_start, _) in zip(spans, spans[1:])), (
+        "no two launches overlapped: the test did not exercise --inflight 2"
+    )
+    for e in lines:
+        assert set(STEPS) | {"rung", "t_dev", "queue_s", "slot_s"} <= set(e)
+        assert e["rung"] == _rung_of(e["size"], shapes)
+        assert e["ts"] - e["secs"] - 1e-3 <= e["t_dev"] <= e["ts"]
+        # The steps lie inside the interval `secs` times, one after another,
+        # so they never add up to more than it.
+        assert sum(e[k] for k in STEPS) <= e["secs"] + 1e-5
+    # What they leave out is a few bytecodes: within 2% or 0.5 ms of `secs`
+    # (read where one caller was alone: among many threads the interpreter
+    # lock changes hands just there, between many short calls; the median,
+    # so that one descheduled thread cannot fail it).
+    alone = lines[crowded:]
+    assert len(alone) == 8
+    gaps = [e["secs"] - sum(e[k] for k in STEPS) for e in alone]
+    assert statistics.median(gaps) < max(0.0005, 0.02 * statistics.median(e["secs"] for e in alone))
+    # The status JSON: totals of the seven stages, the slowest launch with
+    # the step that held it, the device's peak memory (None on a backend
+    # that reports no memory statistics, as the CPU's).
+    totals = status["stage_seconds"]
+    assert set(totals) == {"queue_s", "slot_s", *STEPS}
+    for k in STEPS:
+        assert totals[k] == pytest.approx(sum(e[k] for e in lines), abs=1e-4)
+    slowest = status["slowest_launch"]
+    assert set(slowest) == {"secs", "size", "rung", "stage", "ago_s"}
+    assert slowest["secs"] == pytest.approx(max(e["secs"] for e in lines), abs=1e-3)
+    assert slowest["stage"] in STEPS and slowest["ago_s"] >= 0
+    assert "memory_peak_bytes" in status
+    assert status["memory_peak_bytes"] is None or status["memory_peak_bytes"] >= 0
+
+
+def test_verify_status_prints_the_stall_fields(capsys):
+    sys.path.insert(0, str(ROOT / "scripts"))
+    try:
+        import verify_status
+    finally:
+        sys.path.pop(0)
+    daemon = VerifyServiceDaemon(backend="cpu").start()
+    try:
+        _send_batch(daemon.address, [_item(1, True)])  # rejected by the real oracle
+        assert verify_status.main([daemon.address]) == 0
+    finally:
+        daemon.stop()
+    out = capsys.readouterr().out
+    assert "stage seconds   queue " in out and " slot " in out
+    assert "slowest launch" in out and "1 items at rung None" in out
+
+
+# -- the replica's two histograms, async branch --------------------------------
+
+
+def test_replica_histograms_on_the_async_branch():
+    """pbftd behind a verify service (RemoteVerifier, the async branch of
+    run_verify_batch): one inbox-wait observation per verify batch, and a
+    WAL flush histogram, on every replica's /metrics."""
+    from pbft_tpu import native
+
+    if not native.available():  # pragma: no cover - unbuilt container
+        pytest.skip("native core not built")
+    from pbft_tpu.net import LocalCluster, PbftClient
+
+    sys.path.insert(0, str(CHIPBENCH))
+    try:
+        import stats
+    finally:
+        sys.path.pop(0)
+    daemon = VerifyServiceDaemon(backend="native").start()
+    try:
+        with LocalCluster(
+            n=4, verifier=daemon.address, impl="cxx", metrics_ports=True, wal=True
+        ) as cluster:
+            client = PbftClient(cluster.config)
+            try:
+                for i in range(3):
+                    req = client.request(f"spans-{i}")
+                    assert client.wait_result(req.timestamp, timeout=30) == "awesome!"
+            finally:
+                client.close()
+            time.sleep(0.5)  # trailing commits and checkpoints
+            scrapes = []
+            for port in cluster.metrics_ports:
+                with urllib.request.urlopen(f"http://127.0.0.1:{port}/metrics", timeout=10) as r:
+                    scrapes.append(stats.parse_prometheus(r.read().decode()))
+    finally:
+        daemon.stop()
+    assert daemon.fallback_items > 0  # the service (native backend) verified them
+    for m in scrapes:
+        batches = m[("pbft_verify_batches_total", "")]
+        waits = m[("pbft_verify_inbox_wait_seconds_count", "")]
+        # A batch still in flight at the scrape has been observed already.
+        assert batches >= 3 and batches <= waits <= batches + 1
+        assert 0 <= m[("pbft_verify_inbox_wait_seconds_sum", "")] < 30
+        assert m[("pbft_wal_flush_seconds_count", "")] >= 3
+        assert m[("pbft_wal_flush_seconds_sum", "")] > 0
+        assert m[("pbft_verify_service_fallbacks_total", "")] == 0
+
+
+# -- (c) the two reducers, on hand-made runs -----------------------------------
+
+
+def _reducer(name):
+    sys.path.insert(0, str(CHIPBENCH))
+    try:
+        spec = importlib.util.spec_from_file_location(
+            f"reducers.{name}", CHIPBENCH / "reducers" / f"{name}.py"
+        )
+        module = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(module)
+    finally:
+        sys.path.pop(0)
+    return module.reduce
+
+
+def _launch(t_dev, dispatch_s, wait_s, **more):
+    return {"ts": t_dev + dispatch_s + wait_s, "size": 16, "secs": dispatch_s + wait_s,
+            "t_dev": t_dev, "dispatch_s": dispatch_s, "wait_s": wait_s, **more}
+
+
+@pytest.mark.parametrize(
+    "stat, fields, want",
+    [
+        ("mean", ["pad_s", "put_s"], 1e3 * (0.003 + 0.005 + 0.010) / 3),
+        ("max", ["wait_s"], 40.0),
+        ("p50", ["wait_s"], 20.0),
+        ("mean", ["queue_s"], None),  # no launch carries it
+        ("mean", ["pad_s", "slot_s"], 1e3 * 0.104),  # only the one launch that has both
+    ],
+)
+def test_launch_field_stat(stat, fields, want):
+    run = {"launches": [
+        _launch(1.0, 0.001, 0.010, pad_s=0.001, put_s=0.002),
+        _launch(2.0, 0.001, 0.020, pad_s=0.002, put_s=0.003),
+        _launch(3.0, 0.001, 0.040, pad_s=0.004, put_s=0.006, slot_s=0.1),
+    ]}
+    got = _reducer("launch_field_stat")(run, {"fields": fields, "stat": stat, "scale": 1000})
+    assert got == (want if want is None else pytest.approx(want))
+
+
+def test_launch_union_idle_pct():
+    reduce = _reducer("launch_union_idle_pct")
+    args = {"start": "t_dev", "lengths": ["dispatch_s", "wait_s"]}
+    run = {"t0": 10.0, "t1": 20.0, "launches": [
+        _launch(9.5, 0.1, 0.9),  # straddles t0: only [10.0, 10.5] counts
+        _launch(11.0, 0.5, 1.5),  # [11, 13]
+        _launch(12.0, 0.5, 1.5),  # [12, 14] overlaps the one before: union [11, 14]
+        _launch(16.0, 0.0, 1.0),  # [16, 17]
+    ]}
+    assert reduce(run, args) == pytest.approx(100.0 * (1 - (0.5 + 3.0 + 1.0) / 10.0))
+    # A program from before the spans, or a backend that is not the engine:
+    # the launches are there, the fields are not.
+    bare = {"t0": 10.0, "t1": 20.0, "launches": [{"ts": 11.0, "size": 4, "secs": 0.1}]}
+    assert reduce(bare, args) is None
+    assert reduce({"t0": 10.0, "t1": 20.0, "launches": []}, args) is None
+
+
+# -- (d) the benchmark's files ---------------------------------------------------
+
+NEW_METRICS = {
+    "inbox_wait_ms_mean": ("ms", "verify inbox to RemoteVerifier"),
+    "wal_flush_ms_mean": ("ms", "WAL"),
+    "verifyd_queue_ms_mean": ("ms", "verifyd dispatcher"),
+    "slot_wait_ms_mean": ("ms", "verifyd dispatcher"),
+    "staging_ms_mean": ("ms", "verifyd engine"),
+    "device_wait_ms_mean": ("ms", "verifyd engine"),
+    "device_wait_ms_max": ("ms", "verifyd engine"),
+    "engine_idle_pct": ("%", "device"),
+}
+FORMS = {".closed": ("commit_rate", "f1-sig-wal.closed"), ".rate": ("reply_p50_ms", "f1-sig-wal.rate")}
+# What PR 25's benchmark held, which this PR may only add to: everything in
+# BENCHMARK.json but the entries appended to `per_layer` (a `benchmark` PR
+# that edits an entry pins its own digest here).
+ACCEPTED_PER_LAYER = 27
+ACCEPTED_DIGEST = "7f2b2c41f15ac07556e716381fd4915806724ddc56d4b01011fdd21d1e6e3800"
+
+
+@pytest.mark.parametrize("name", sorted(NEW_METRICS))
+@pytest.mark.parametrize("form", sorted(FORMS))
+def test_new_metric_has_its_reader_and_its_entry(name, form):
+    unit, layer = NEW_METRICS[name]
+    moves, cell = FORMS[form]
+    spec = json.loads((CHIPBENCH / "metrics" / f"{name}{form}.json").read_text())
+    assert spec["name"] == name + form
+    assert (CHIPBENCH / "reducers" / f"{spec['reducer']}.py").is_file()
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    entry = [m for m in bench["per_layer"] if m["name"] == name + form]
+    assert entry == [{
+        "name": name + form, "unit": unit, "better": "lower", "source": "program_span",
+        "layer": layer, "moves": moves, "workloads": [cell],
+    }]
+    assert bench["per_layer"].index(entry[0]) >= ACCEPTED_PER_LAYER
+
+
+def test_accepted_benchmark_entries_are_unchanged():
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    accepted = dict(bench, per_layer=bench["per_layer"][:ACCEPTED_PER_LAYER])
+    digest = hashlib.sha256(json.dumps(accepted, sort_keys=True).encode()).hexdigest()
+    assert digest == ACCEPTED_DIGEST
+    # Every metric has a reader file, and every reader file a metric.
+    names = {m["name"] for m in bench["end_to_end"] + bench["per_layer"]}
+    assert names == {p.name[: -len(".json")] for p in (CHIPBENCH / "metrics").glob("*.json")}
