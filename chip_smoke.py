@@ -58,6 +58,7 @@ from pbft_tpu.net.gateway import GatewayClient  # noqa: E402
 from pbft_tpu.net.launcher import LocalCluster  # noqa: E402
 from pbft_tpu.net.verify_service import (  # noqa: E402
     probe_status_json,
+    serving_table_text,
     spawn_verifyd,
     stop_child,
     wait_for_tpu_service,
@@ -224,6 +225,10 @@ def device_stage(target: str, seed: int, ladder=LADDER, trace_path=None) -> None
     log(f"{top} items signed from seed {seed} and decided by the oracle "
         f"in {time.monotonic() - t0:.1f}s")
     before = ready_status(target)
+    # Which executable serves which window on THIS mesh: the engine measured
+    # every shape at warm-up (a window of exactly `fit` items runs at `runs`).
+    log("serving table (smallest shape that fits→shape run): "
+        + serving_table_text(before["warm_stats"]["serving_table"]))
 
     # One window of exactly each rung size, alone on the wire.
     for size in ladder:
@@ -293,7 +298,9 @@ def device_stage(target: str, seed: int, ladder=LADDER, trace_path=None) -> None
             f"no coalesced window reached the {top} shape: merged={merged}",
         )
         log(f"coalesced launches (requests, items): {merged}")
-    log(f"device stage: {sent} verdicts, all through the engine")
+    log(f"device stage: {sent} verdicts, all through the engine, "
+        f"{after['promoted_launches'] - before['promoted_launches']} launches "
+        "on a larger shape than the smallest fit")
 
 
 # -- stage 2: the deployment ---------------------------------------------------
@@ -422,6 +429,10 @@ def deployment_stage(
                         and b["executed"] == requests
                         for a, b in zip(reports, again)
                     ):
+                        # The later reading: a batch still in flight at the
+                        # earlier one (sent, its verdicts not yet consumed)
+                        # has come back by now.
+                        reports = again
                         break
                     check(time.monotonic() < deadline,
                           f"replicas never quiesced: {again}")
@@ -513,7 +524,8 @@ def main() -> int:
             log(f"shape {shape['size']}: {shape['seconds']}s "
                 f"({'cache hit' if shape['cache_hit'] else 'compiled'}), input "
                 f"sharded over devices {shape['devices']}, "
-                f"{shape['rows_per_device']} rows each")
+                f"{shape['rows_per_device']} rows each, one launch "
+                f"{1e3 * shape['launch_s']:.2f} ms")
         log(f"warm-up: cold_compile_s={warm['cold_compile_s']} "
             f"warm_load_s={warm['warm_load_s']} compiled={warm['compiled']} "
             f"cache_hits={warm['cache_hits']} cache_dir={warm['cache_dir']} "

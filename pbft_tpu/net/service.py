@@ -118,10 +118,11 @@ def native_backend(items: List[Item]) -> List[bool]:
 
 
 class _Pending:
-    __slots__ = ("items", "arrived", "event", "verdicts", "error")
+    __slots__ = ("items", "conn", "arrived", "event", "verdicts", "error")
 
-    def __init__(self, items: List[Item]):
+    def __init__(self, items: List[Item], conn=None):
         self.items = items
+        self.conn = conn  # the connection it came over (None: not known)
         self.arrived = time.monotonic()
         self.event = threading.Event()
         self.verdicts: Optional[List[bool]] = None
@@ -184,6 +185,19 @@ class VerifierService:
         # window. 0 = dispatch as soon as the previous launch returns.
         self._flush_s = flush_us / 1e6
         self._flush_target = flush_items or self.MAX_WINDOW
+        # Hold for company (no flush_us given): ``hold_s(items)`` says how
+        # long a window of that many items may stay open for more, counted
+        # from its oldest request's arrival; 0 cuts at once. None of a bare
+        # service's backends has an opinion; the daemon sets its engine's
+        # (one launch time of the shape the window would run at, while that
+        # shape has room: ``ShardedVerifyEngine.hold_s``). The hold ends
+        # early once nobody in step is still out: no launch is in flight
+        # and every connection the last one answered has its next request
+        # in the window (replicas keep one batch in flight each and come
+        # back together; a caller alone never waits for anybody).
+        self.hold_s: Optional[Callable[[int], float]] = None
+        self._flying = 0  # windows cut and not yet answered
+        self._answered: set = set()  # connections the last launch answered
         # Overlapped launches: with inflight > 1 the dispatcher ships
         # window N+1 while N is still executing, hiding host-side launch
         # overhead behind device compute (XLA serializes execution per
@@ -221,7 +235,10 @@ class VerifierService:
         # every duration (``*_s``) the backend writes into its span, the
         # sharded engine's five steps — and the slowest launch so far with
         # the step that held it (written under _cond by the launch threads).
+        # promoted_launches: launches the engine ran on a larger shape than
+        # the smallest that fits (its span's ``promoted``).
         self.stage_seconds = {"queue_s": 0.0, "slot_s": 0.0}
+        self.promoted_launches = 0
         self._slowest: Optional[dict] = None
         self._coalesce = coalesce
         self._cond = threading.Condition()
@@ -265,10 +282,12 @@ class VerifierService:
                             )
                             for i in range(n)
                         ]
-                        verdicts = service._submit(items)
+                        verdicts = service._submit(items, conn=self)
                         sock.sendall(bytes(1 if v else 0 for v in verdicts))
                 except (ConnectionError, OSError):
                     return
+                finally:
+                    service._gone(self)
 
         if unix_path is not None:
 
@@ -300,7 +319,20 @@ class VerifierService:
     # shapes at runtime. Overflow stays queued for the next window.
     MAX_WINDOW = 4096
 
-    def _submit(self, items: List[Item]) -> List[bool]:
+    def _gone(self, conn) -> None:
+        """A connection closed: no held window waits for it any more."""
+        with self._cond:
+            self._answered.discard(conn)
+            self._cond.notify_all()
+
+    def _in_step_are_back(self) -> bool:
+        """No launch in flight, and every connection the last one answered
+        has a request pending again (under ``_cond``)."""
+        return self._flying == 0 and self._answered <= {
+            p.conn for p in self._pending
+        }
+
+    def _submit(self, items: List[Item], conn=None) -> List[bool]:
         """Handler-thread entry: verify `items`, possibly merged with other
         connections' concurrent submissions into one backend call."""
         if not self._coalesce:
@@ -332,7 +364,7 @@ class VerifierService:
                     "pbft_verify_service_coalesced_clients"
                 ).observe(1)
             return verdicts
-        p = _Pending(items)
+        p = _Pending(items, conn)
         with self._cond:
             self.requests += 1
             if not self._running:  # dispatcher gone: fail this connection
@@ -370,6 +402,15 @@ class VerifierService:
                         if remaining <= 0:
                             break
                         self._cond.wait(remaining)
+                elif self.hold_s is not None:
+                    while self._running:
+                        hold = self.hold_s(self._pending_items())
+                        remaining = (
+                            self._pending[0].arrived + hold - time.monotonic()
+                        )
+                        if hold <= 0 or remaining <= 0 or self._in_step_are_back():
+                            break
+                        self._cond.wait(remaining)
                 # Take whole requests up to MAX_WINDOW items (a single
                 # oversized request still goes through, alone).
                 window: List[_Pending] = []
@@ -380,6 +421,7 @@ class VerifierService:
                         break
                     size += nxt
                     window.append(self._pending.pop(0))
+                self._flying += 1
                 cut_at = time.monotonic()
                 left = self._pending_items()  # queued past MAX_WINDOW
                 if self.metrics_registry.enabled:
@@ -427,6 +469,10 @@ class VerifierService:
                     p.error = e
                     p.event.set()
         finally:
+            with self._cond:
+                self._flying -= 1
+                self._answered = {p.conn for p in window} - {None}
+                self._cond.notify_all()  # a held window may go now
             self._inflight_sem.release()
 
     @staticmethod
@@ -454,6 +500,7 @@ class VerifierService:
             self.stage_seconds[name] += waits[name]
         for name, took in steps.items():
             self.stage_seconds[name] = self.stage_seconds.get(name, 0.0) + took
+        self.promoted_launches += bool(span.get("promoted"))
         if self._slowest is None or secs > self._slowest["secs"]:
             self._slowest = {
                 "secs": round(secs, 6),
@@ -464,13 +511,19 @@ class VerifierService:
             }
 
     def launch_status(self) -> dict:
-        """The stage totals and the slowest launch, for the status JSON."""
+        """The stage totals, the promoted launches and the slowest launch,
+        for the status JSON."""
         with self._cond:
             slowest = dict(self._slowest) if self._slowest else None
             totals = {k: round(v, 6) for k, v in self.stage_seconds.items()}
+            promoted = self.promoted_launches
         if slowest:
             slowest["ago_s"] = round(time.monotonic() - slowest.pop("at"), 3)
-        return {"stage_seconds": totals, "slowest_launch": slowest}
+        return {
+            "stage_seconds": totals,
+            "promoted_launches": promoted,
+            "slowest_launch": slowest,
+        }
 
     def _dispatch_window(self, window: List[_Pending], waits: dict) -> None:
         merged: List[Item] = []

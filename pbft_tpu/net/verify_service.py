@@ -28,6 +28,18 @@ backend that is not a TPU (unless ``JAX_PLATFORMS`` itself names cpu,
 the test arm), ends the process non-zero. ``auto`` degrades to
 ``cpu-only`` instead, for chip-less deployments.
 
+Which executable a window runs on is decided by what each one COSTS on the
+device the engine has, not by size alone: warm-up launches every shape on
+the all-pad window, keeps the least of a few timed launches as the shape's
+``launch_s``, and :func:`serving_table` sends a window to a larger shape
+where that one measured clearly cheaper (on a TPU v5e the 256-slot program
+takes 5 ms and the 16- and 64-slot ones 42 ms). Where cost grows with size,
+as on a CPU, that is the smallest shape that fits. The same measurement
+bounds how long a window waits for company: while it leaves room on the
+shape it would run at, the dispatcher keeps it open for at most one
+``launch_s`` of that shape (:meth:`ShardedVerifyEngine.hold_s`), so
+replicas' batches that arrive a few ms apart share a launch.
+
 Host↔device pipeline: every window is staged with an async
 ``jax.device_put`` against the batch sharding and launched through a
 precompiled executable with DONATED input buffers (XLA reuses the device
@@ -72,6 +84,39 @@ from ..utils.trace import current_span
 
 # -- the accelerator-owning engine -------------------------------------------
 
+# A larger shape takes a smaller one's windows only where it measured under
+# a THIRD of the smaller one's time. Running on a larger shape is not free
+# (more pad slots to fill, three larger transfers, fewer rows of real work a
+# device), and ``launch_s`` is the least of a few launches read on the
+# host's clock: on a loaded host two programs of equal cost have read 1.8x
+# apart (tier-1 under eight-fold contention). The gap this is for is 8x
+# (TPU v5e: 42 ms at 16 and 64 slots, 5.3 at 256, 5.5x with the host's
+# staging on both sides). Equal costs, and costs inside the margin, keep the
+# smaller shape, so wherever cost grows with size the table is the identity.
+PROMOTE_MARGIN = 3.0
+
+
+def serving_table(launch_s: dict) -> dict:
+    """{shape: the shape that serves its windows}, from what one launch of
+    each warmed shape cost (``{shape: seconds}``). From the largest shape
+    down, a shape serves itself unless the shape that serves the next
+    larger one measured cheaper by ``PROMOTE_MARGIN``: so one cheap shape
+    takes every window under it, and none above it."""
+    table: dict = {}
+    above = None  # what serves the next larger shape
+    for shape in sorted(launch_s, reverse=True):
+        promote = (
+            above is not None
+            and launch_s[above] * PROMOTE_MARGIN < launch_s[shape]
+        )
+        above = table[shape] = above if promote else shape
+    return dict(sorted(table.items()))
+
+
+def serving_table_text(table: dict) -> str:
+    """``16→256 64→256 …``: a window that fits 16 slots runs at 256."""
+    return " ".join(f"{fit}→{runs}" for fit, runs in table.items())
+
 
 class ShardedVerifyEngine:
     """Owns the JAX backend: one mesh over the host's local devices and one
@@ -82,7 +127,9 @@ class ShardedVerifyEngine:
     window shape — the once-per-deploy cost the daemon pays at startup,
     outside any request. JAX's persistent compile cache, keyed by the
     lowered module, makes a restart over unchanged kernels a cache hit
-    and a changed kernel a miss.
+    and a changed kernel a miss. ``warm()`` also launches each shape on
+    the all-pad window and times it: ``verify()`` pads a window to the
+    shape :func:`serving_table` gives for the smallest one that fits.
     """
 
     def __init__(
@@ -102,6 +149,8 @@ class ShardedVerifyEngine:
         self._mesh = None
         self._spec = None
         self._compiled: dict = {}  # padded size -> jax.stages.Compiled
+        self._launch_s: dict = {}  # padded size -> seconds, read at warm-up
+        self._serves: dict = {}  # smallest fitting size -> size it runs at
         self.platform: Optional[str] = None
         self.device_kind: Optional[str] = None
         self.devices_seen = 0  # len(jax.devices())
@@ -138,9 +187,11 @@ class ShardedVerifyEngine:
 
         Returns (and stores in ``self.stats``) the warm-up accounting, as
         set-up facts: per shape the seconds spent, whether the persistent
-        cache answered, and the devices its input sharding spans;
+        cache answered, the devices its input sharding spans and what one
+        launch of it costs (``launch_s``, :meth:`_measure`);
         ``cold_compile_s`` sums the shapes that traced+compiled,
-        ``warm_load_s`` the shapes the cache answered.
+        ``warm_load_s`` the shapes the cache answered; ``serving_table`` is
+        :func:`serving_table` of every shape's ``launch_s``.
         """
         if self._mesh is None:
             self.init_backend()
@@ -191,6 +242,7 @@ class ShardedVerifyEngine:
                     # the devices its first input is sharded over and the
                     # rows each one holds — not what we asked for.
                     in_sharding = compiled.input_shardings[0][0]
+                    launch_s = round(self._measure(size, compiled), 6)
                     stats["per_shape"].append(
                         {
                             "size": size,
@@ -202,16 +254,71 @@ class ShardedVerifyEngine:
                             "rows_per_device": in_sharding.shard_shape(
                                 (size, 32)
                             )[0],
+                            "launch_s": launch_s,
                         }
                     )
+                    self._launch_s[size] = launch_s
                     self._compiled[size] = compiled
                     stats["shapes"].append(size)
+                self._serves = serving_table(self._launch_s)
+                # JSON has no integer keys.
+                stats["serving_table"] = {
+                    str(fit): runs for fit, runs in self._serves.items()
+                }
                 stats["warm_load_s"] = round(stats["warm_load_s"], 3)
                 stats["cold_compile_s"] = round(stats["cold_compile_s"], 3)
                 self.stats = stats
         finally:
             jax.monitoring.unregister_event_listener(on_event)
         return stats
+
+    # Timed launches a shape at warm-up, after one that is not timed.
+    WARM_LAUNCHES = 3
+
+    def _measure(self, size: int, compiled) -> float:
+        """What one launch of ``compiled`` costs here, in seconds: the
+        all-pad window through the path ``verify()`` takes (``device_put``,
+        the executable, ``np.asarray``), once untimed and then the least of
+        ``WARM_LAUNCHES`` timed ones, with nothing else in flight (the
+        daemon serves from its fallback until ``warm()`` returns). Every
+        slot holds the known-good triple, so the engine's own kernel has to
+        answer True in every slot of every launch: a self-test of each
+        executable, and a failure of warm-up like a compile failure. (A
+        stand-in kernel decides by its own rule, which the pad triple need
+        not satisfy.)"""
+        import numpy as np
+        import jax
+
+        from ..crypto.batch import pad_batch
+
+        window = pad_batch([], size)[:3]
+        took = []
+        for _ in range(1 + self.WARM_LAUNCHES):
+            t0 = time.perf_counter()
+            staged = [jax.device_put(a, self._spec) for a in window]
+            verdicts = np.asarray(compiled(*staged))
+            took.append(time.perf_counter() - t0)
+            if self._kernel is None and not verdicts.all():
+                raise RuntimeError(
+                    f"warm-up self-test: the {size}-slot executable rejected "
+                    f"the known-good pad triple in "
+                    f"{size - int(verdicts.sum())} of {size} slots"
+                )
+        return min(took[1:])
+
+    def hold_s(self, n: int) -> float:
+        """How long a window of ``n`` items may be held open for more, in
+        seconds: one launch of the shape it would run at, while that shape
+        has room. Below a shape's size an extra item costs the device
+        nothing and a launch of its own costs it ``launch_s``, so company
+        is worth waiting for, but never longer than the launch the wait
+        would save; a window that fills its shape goes at once. 0 before
+        warm-up has timed the shapes."""
+        fit = min((s for s in self._compiled if s >= n), default=None)
+        if fit is None:
+            return 0.0
+        run = self._serves.get(fit, fit)
+        return self._launch_s.get(run, 0.0) if n < run else 0.0
 
     def _round_to_mesh(self, size: int) -> int:
         d = max(1, self.device_count)
@@ -229,10 +336,14 @@ class ShardedVerifyEngine:
     def verify(self, items: List[Item]) -> List[bool]:
         """Pad to a warmed window shape, stage (async device_put against
         the batch sharding), launch the precompiled executable, read back.
-        Oversized batches chunk into top-of-ladder windows — the service
-        never compiles a new shape at runtime. Verdicts are bit-identical
-        to the single-device and CPU paths (pinned in tests/test_parallel
-        and tests/test_service_coalesce).
+        The shape is what the serving table gives for the smallest one that
+        fits: that one, or a larger one that warm-up measured clearly
+        cheaper (``promoted`` counts such chunks; the pad slots verify True
+        and are sliced off, so the verdicts are the same). Oversized batches
+        chunk into top-of-ladder windows — the service never compiles a new
+        shape at runtime. Verdicts are bit-identical to the single-device
+        and CPU paths (pinned in tests/test_parallel and
+        tests/test_service_coalesce).
 
         The five steps of every chunk are timed (summed over chunks) into
         the caller's ``utils.trace.current_span()``, where one is open —
@@ -252,13 +363,15 @@ class ShardedVerifyEngine:
         top = max(self._compiled)
         out: List[bool] = []
         secs = dict.fromkeys(self.STEPS, 0.0)
-        rung = 0
+        rung = promoted = 0
         t_dev = None
         for off in range(0, len(items), top):
             chunk = items[off : off + top]
-            size = min(
+            fit = min(
                 (s for s in self._compiled if s >= len(chunk)), default=top
             )
+            size = self._serves.get(fit, fit)
+            promoted += size != fit
             marks = [t_in]  # the chunk's start, then the end of each step
             with step("verifyd.pad"):
                 pubs, msgs, sigs, n = pad_batch(chunk, size)
@@ -295,7 +408,7 @@ class ShardedVerifyEngine:
         span = current_span()
         if span is not None:
             span.update({k: round(v, 6) for k, v in secs.items()})
-            span.update(rung=rung, t_dev=round(t_dev, 6))
+            span.update(rung=rung, promoted=promoted, t_dev=round(t_dev, 6))
         return out
 
     def memory_peak_bytes(self) -> Optional[int]:
@@ -502,6 +615,9 @@ class VerifyServiceDaemon:
             reg.gauge("pbft_verify_service_warm_compile_seconds").set(
                 stats["warm_load_s"]
             )
+        # From here on a window that leaves room on its shape waits for
+        # company, at most one launch of that shape (``hold_s``).
+        self.service.hold_s = getattr(self.engine, "hold_s", None)
         self._set_state(STATE_READY)
 
     def start(self, wait_ready: bool = False, timeout: float = 900.0):

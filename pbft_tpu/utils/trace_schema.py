@@ -28,14 +28,17 @@ EVENT_SCHEMAS = {
     # cut), pending_at_cut / pending_at_launch (items queued at those two
     # moments); and, where the sharded engine ran it, the engine's five
     # steps summed over chunks (pad_s, put_s, dispatch_s, wait_s, unpack_s:
-    # they add up to secs), rung (padded slots run) and t_dev (absolute
-    # stamp at the first dispatch).
+    # they add up to secs), rung (the padded slots the chunks really ran
+    # at), promoted (chunks run on a larger shape than the smallest that
+    # fits, by the engine's serving table: 0 or 1 for all but oversized
+    # windows) and t_dev (absolute stamp at the first dispatch).
     "verify_batch": {
         "required": {"ts", "ev", "replica", "size", "rejected", "secs"},
         "optional": {
             "view", "executed", "requests",
             "queue_s", "slot_s", "pending_at_cut", "pending_at_launch",
-            "pad_s", "put_s", "dispatch_s", "wait_s", "unpack_s", "rung", "t_dev",
+            "pad_s", "put_s", "dispatch_s", "wait_s", "unpack_s", "rung", "promoted",
+            "t_dev",
         },
         "emitters": {"server.py", "service.py", "net.cc"},
     },
@@ -375,6 +378,35 @@ FLIGHT_EVENTS = {
     18: "recovery_complete",
 }
 FLIGHT_EVENT_IDS = {name: i for i, name in FLIGHT_EVENTS.items()}
+
+# -- the verify service's status JSON ----------------------------------------
+#
+# What ``VerifyServiceDaemon.status_json`` may carry (the 0xFFFFFFFF probe;
+# ``scripts/verify_status.py`` prints it, ``chip_smoke.py``, ``bench.py`` and
+# the benchmark read it), and inside it the engine's warm-up accounting.
+# tests/test_verify_spans.py holds the daemon to these sets.
+VERIFYD_STATUS_KEYS = {
+    "state", "platform", "device_kind", "devices_seen", "devices",
+    "warmed_shapes", "backend", "uptime_s", "requests",
+    "engine_launches", "engine_items", "fallback_launches", "fallback_items",
+    # Launches, without --trace: running totals of every stage, launches the
+    # engine ran on a larger shape than the smallest fit, the slowest one.
+    "stage_seconds", "promoted_launches", "slowest_launch",
+    "memory_peak_bytes", "warm_stats", "warm_error",
+}
+VERIFYD_WARM_STATS_KEYS = {
+    "cache_dir", "shapes", "per_shape", "compiled", "cache_hits",
+    "cold_compile_s", "warm_load_s",
+    # {smallest shape that fits: the shape such a window runs at}, from
+    # every shape's launch_s (verify_service.serving_table).
+    "serving_table",
+}
+VERIFYD_PER_SHAPE_KEYS = {
+    "size", "seconds", "cache_hit", "devices", "rows_per_device",
+    # Seconds one launch of the shape takes on the engine's own device(s):
+    # the least of a few timed launches of the all-pad window at warm-up.
+    "launch_s",
+}
 
 # -- health document (ISSUE 16) ----------------------------------------------
 #

@@ -6,7 +6,10 @@ is actually doing: state, platform and device kind, devices seen and in
 the mesh, warmed window shapes, engine vs fallback dispatch counts, and
 the once-per-deploy compile timings per shape — which shapes the
 persistent compile cache answered (warm) and which were traced+compiled
-(cold) — the running total of each launch stage (queue, slot, pad, put,
+(cold) — what one launch of each shape costs (``launch_s``, read at
+warm-up), the serving table made from those costs (``16→256`` = a window
+that fits 16 slots runs on the 256-slot program) and how many launches it
+promoted, the running total of each launch stage (queue, slot, pad, put,
 dispatch, wait, unpack), the slowest launch so far with the step that
 held it, and the device's peak memory.
 
@@ -44,7 +47,7 @@ def main(argv=None) -> int:
     parser.add_argument("--json", action="store_true", help="raw status JSON")
     args = parser.parse_args(argv)
 
-    from pbft_tpu.net.verify_service import probe_status_json
+    from pbft_tpu.net.verify_service import probe_status_json, serving_table_text
 
     status = probe_status_json(args.target, timeout=args.timeout)
     if status is None:
@@ -77,8 +80,20 @@ def main(argv=None) -> int:
         loaded = warm.get("warm_load_s")
         if loaded is not None:
             print(f"  warm load       {loaded:.3f}s (shapes the compile cache answered)")
+        costs = [
+            f"{shape['size']}: {1e3 * shape['launch_s']:.2f} ms"
+            for shape in warm.get("per_shape") or []
+            if "launch_s" in shape
+        ]
+        if costs:
+            print("  launch cost     " + "  ".join(costs))
+        table = warm.get("serving_table")
+        if table:
+            print("  serving table   %s  (%d launches promoted)" % (
+                serving_table_text(table), status.get("promoted_launches", 0),
+            ))
         for k in sorted(warm):
-            if k in ("cold_compile_s", "warm_load_s"):
+            if k in ("cold_compile_s", "warm_load_s", "serving_table"):
                 continue
             print(f"  {k:<15} {warm[k]}")
     # Where launches spend their time, for seeing a stall without --trace:
@@ -102,6 +117,7 @@ def main(argv=None) -> int:
     known = {
         "state", "devices", "uptime_s", "warmed_shapes", "warm_stats",
         "stage_seconds", "slowest_launch", "memory_peak_bytes",
+        "promoted_launches",
     }
     for k in sorted(set(status) - known):
         print(f"  {k:<15} {status[k]}")

@@ -39,7 +39,11 @@ class _NativeEngine:
     device_kind = "native pool behind an engine stub"
     devices_seen = device_count = 1
     warmed_sizes = (16, 64)
-    stats = {"cold_compile_s": 0.0, "warm_load_s": 0.0}
+    stats = {
+        "cold_compile_s": 0.0,
+        "warm_load_s": 0.0,
+        "serving_table": {"16": 16, "64": 64},  # the stage logs it
+    }
 
     def __init__(self, lie=None):
         self._lie = lie

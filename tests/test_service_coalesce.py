@@ -318,6 +318,159 @@ def test_flush_items_short_circuits_the_deadline():
         svc.stop()
 
 
+class _Conn:
+    """One connection kept open over many batches, as a replica keeps its."""
+
+    def __init__(self, addr: str):
+        host, port = addr.rsplit(":", 1)
+        self.sock = socket.create_connection((host, int(port)), timeout=30)
+
+    def send(self, items):
+        payload = b"".join(p + m + s for p, m, s in items)
+        self.sock.sendall(len(items).to_bytes(4, "big") + payload)
+        out = b""
+        while len(out) < len(items):
+            chunk = self.sock.recv(len(items) - len(out))
+            assert chunk
+            out += chunk
+        return [bool(b) for b in out]
+
+    def send_later(self, items, results, key):
+        t = threading.Thread(target=lambda: results.__setitem__(key, self.send(items)))
+        t.start()
+        return t
+
+    def close(self):
+        self.sock.close()
+
+
+def _sizes_backend(calls, gate=None):
+    def backend(items):
+        calls.append(len(items))
+        if gate is not None and len(calls) == 1:
+            gate.wait(20)
+        return [p[0] == s[0] for p, m, s in items]
+
+    return backend
+
+
+def test_a_window_with_room_is_held_for_whoever_is_in_step():
+    """``hold_s`` (the daemon sets its engine's) keeps a window open while
+    the shape it would run at has room AND somebody in step is still out: a
+    connection the last launch answered whose next request has not come.
+    The window goes the moment the last of them is back (one launch for
+    both), or has hung up. The hold here is 30 s: only that explains it."""
+    calls, results = [], {}
+    svc = VerifierService(backend=_sizes_backend(calls)).start()
+    svc.hold_s = lambda n: 30.0
+    a, b = _Conn(svc.address), _Conn(svc.address)
+    try:
+        assert a.send([_item(1, True)]) == [True]  # nobody to wait for: at once
+        t = b.send_later([_item(2, False)], results, "b")
+        t.join(0.4)
+        assert t.is_alive() and calls == [1], "b's window went without a"
+        assert a.send([_item(3, True)]) == [True]  # a is back: both go, together
+        t.join(10)
+        assert results == {"b": [False]} and calls == [1, 2]
+        t = a.send_later([_item(4, True)], results, "a")  # now b is the one out
+        t.join(0.4)
+        assert t.is_alive() and calls == [1, 2]
+        b.close()  # ... and hangs up: nothing left to wait for
+        t.join(10)
+        assert results["a"] == [True] and calls == [1, 2, 1]
+    finally:
+        a.close()
+        b.close()
+        svc.stop()
+
+
+def test_a_window_is_held_while_a_launch_is_in_flight():
+    """Whoever rides the launch in flight comes back after it: a window cut
+    now would miss them, so it stays open (until its hold runs out)."""
+    calls, results = [], {}
+    gate = threading.Event()
+    svc = VerifierService(backend=_sizes_backend(calls, gate), inflight=2).start()
+    svc.hold_s = lambda n: 30.0
+    a, b = _Conn(svc.address), _Conn(svc.address)
+    try:
+        ta = a.send_later([_item(1, True)], results, "a1")
+        while not calls:
+            time.sleep(0.01)  # a's launch is on the (gated) device
+        tb = b.send_later([_item(2, True)], results, "b")
+        tb.join(0.4)
+        assert tb.is_alive() and calls == [1], "a second slot was free, and was taken"
+        gate.set()
+        ta.join(10)
+        tb.join(0.4)
+        assert tb.is_alive() and calls == [1]  # a was answered and is still out
+        assert a.send([_item(3, False)]) == [False]
+        tb.join(10)
+        assert results == {"a1": [True], "b": [True]} and calls == [1, 2]
+    finally:
+        gate.set()
+        a.close()
+        b.close()
+        svc.stop()
+
+
+def test_a_caller_alone_never_pays_the_hold():
+    calls = []
+    svc = VerifierService(backend=_sizes_backend(calls)).start()
+    svc.hold_s = lambda n: 30.0
+    a = _Conn(svc.address)
+    try:
+        t0 = time.monotonic()
+        for k in range(5):
+            assert a.send([_item(k + 1, True), _item(k + 9, False)]) == [True, False]
+        assert time.monotonic() - t0 < 10 and calls == [2] * 5
+    finally:
+        a.close()
+        svc.stop()
+
+
+def test_a_held_window_goes_when_it_fills_or_its_hold_runs_out(tmp_path):
+    """The hold is a bound, counted from the oldest request's ARRIVAL (it
+    shows as ``queue_s``); a window that fills the shape it would run at
+    (``hold_s`` says 0) goes at once; an explicit ``flush_us`` window takes
+    the hold's place."""
+    import json
+
+    calls = []
+    trace = tmp_path / "service.jsonl"
+    svc = VerifierService(backend=_sizes_backend(calls), trace_path=str(trace)).start()
+    a, b = _Conn(svc.address), _Conn(svc.address)
+    try:
+        svc.hold_s = lambda n: 0.3 if n < 2 else 0.0
+        assert a.send([_item(1, True)]) == [True]  # a is answered, and stays out
+        t0 = time.monotonic()
+        assert b.send([_item(2, True)]) == [True]
+        held = time.monotonic() - t0
+        t0 = time.monotonic()
+        assert b.send([_item(3, True), _item(4, False)]) == [True, False]
+        full = time.monotonic() - t0
+    finally:
+        a.close()
+        b.close()
+        svc.stop()
+    assert 0.3 <= held < 5.0 and full < 0.25, (held, full)
+    lines = [json.loads(ln) for ln in trace.read_text().splitlines()]
+    assert [e["size"] for e in lines] == [1, 1, 2]
+    assert lines[1]["queue_s"] >= 0.29 and lines[2]["queue_s"] < 0.25
+
+    flushed = VerifierService(backend=_sizes_backend([]), flush_us=1).start()
+    flushed.hold_s = lambda n: 30.0
+    a, b = _Conn(flushed.address), _Conn(flushed.address)
+    try:
+        assert a.send([_item(5, True)]) == [True]
+        t0 = time.monotonic()
+        assert b.send([_item(6, True)]) == [True]
+        assert time.monotonic() - t0 < 20
+    finally:
+        a.close()
+        b.close()
+        flushed.stop()
+
+
 def test_service_trace_records_merged_windows(tmp_path):
     """The per-dispatch trace is the honest items-per-LAUNCH record for
     the launch-cost model (per-replica traces only see each daemon's
@@ -644,6 +797,7 @@ def test_wait_for_tpu_service_refuses_everything_but_a_ready_tpu():
     try:
         st = wait_for_tpu_service(ok.address, budget_s=5)
         assert st["platform"] == "tpu" and st["state"] == "ready"
+        assert ok.service.hold_s is None  # an engine with no rule: no window is held
     finally:
         ok.stop()
 
@@ -846,6 +1000,31 @@ def test_engine_parity_pad_slots_and_window_boundaries():
     # Oversized: chunks into top-of-ladder windows, order preserved.
     big = [_item((i % 23) + 1, i % 5 != 0) for i in range(40)]
     assert eng.verify(big) == [i % 5 != 0 for i in range(40)]
+
+
+def test_engine_parity_when_a_window_runs_on_a_larger_shape():
+    """The serving table may send a window to a larger warmed shape than the
+    smallest that fits (ISSUE 27): more pad slots, the same verdicts, and
+    every shape asked for still compiled."""
+    from pbft_tpu.utils.trace import open_span
+
+    eng = ShardedVerifyEngine(shapes=(8, 16), kernel=_fake_kernel)
+    stats = eng.warm()
+    assert eng.warmed_sizes == (8, 16)
+    assert [p["size"] for p in stats["per_shape"]] == [8, 16]
+    assert all(p["launch_s"] > 0 for p in stats["per_shape"])
+    assert set(stats["serving_table"]) == {"8", "16"}
+    sizes = (1, 8, 9, 16, 17)
+    for table, rungs, promoted in (
+        ({}, (8, 8, 16, 16, 16 + 8), (0, 0, 0, 0, 0)),
+        ({8: 16}, (16, 16, 16, 16, 16 + 16), (1, 1, 0, 0, 1)),
+    ):
+        eng._serves = table
+        for n, rung, chunks in zip(sizes, rungs, promoted):
+            items = [_item(i + 1, i % 3 != 0) for i in range(n)]
+            with open_span() as span:
+                assert eng.verify(items) == [i % 3 != 0 for i in range(n)]
+            assert (span["rung"], span["promoted"]) == (rung, chunks)
 
 
 _WARM_TWICE = """

@@ -19,6 +19,8 @@ from pathlib import Path
 import pytest
 
 from pbft_tpu.net import ShardedVerifyEngine, VerifierService, VerifyServiceDaemon
+from pbft_tpu.net.verify_service import PROMOTE_MARGIN, serving_table
+from pbft_tpu.utils import trace_schema
 from pbft_tpu.utils.trace import current_span, open_span
 
 from tests.test_service_coalesce import _item, _send_batch
@@ -186,8 +188,9 @@ def test_engine_steps_sum_to_secs_with_two_launches_in_flight(tmp_path):
         "no two launches overlapped: the test did not exercise --inflight 2"
     )
     for e in lines:
-        assert set(STEPS) | {"rung", "t_dev", "queue_s", "slot_s"} <= set(e)
+        assert set(STEPS) | {"rung", "promoted", "t_dev", "queue_s", "slot_s"} <= set(e)
         assert e["rung"] == _rung_of(e["size"], shapes)
+        assert e["promoted"] == 0  # a flat-cost kernel: the tie keeps smallest-fit
         assert e["ts"] - e["secs"] - 1e-3 <= e["t_dev"] <= e["ts"]
         # The steps lie inside the interval `secs` times, one after another,
         # so they never add up to more than it.
@@ -213,6 +216,180 @@ def test_engine_steps_sum_to_secs_with_two_launches_in_flight(tmp_path):
     assert slowest["stage"] in STEPS and slowest["ago_s"] >= 0
     assert "memory_peak_bytes" in status
     assert status["memory_peak_bytes"] is None or status["memory_peak_bytes"] >= 0
+    assert status["promoted_launches"] == 0
+    assert status["warm_stats"]["serving_table"] == {"8": 8, "16": 16}
+
+
+# -- (b') which executable serves a window: the table, and an engine that promotes
+
+
+V5E_LAUNCH_S = {16: 0.04250, 64: 0.04202, 256: 0.00531, 1024: 0.01347, 4096: 0.060}
+IDENTITY = {s: s for s in V5E_LAUNCH_S}
+
+
+@pytest.mark.parametrize(
+    "launch_s, want",
+    [
+        # The TPU v5e's five device times (PERF.md section 5).
+        (V5E_LAUNCH_S, {16: 256, 64: 256, 256: 256, 1024: 1024, 4096: 4096}),
+        # Cost grows with size (a CPU): smallest-fit, whatever the steps.
+        ({16: 0.001, 64: 0.004, 256: 0.016, 1024: 0.064, 4096: 0.256}, IDENTITY),
+        ({16: 0.0050, 64: 0.0051, 256: 0.0052, 1024: 0.0053, 4096: 0.0054}, IDENTITY),
+        # Equal costs, and a larger shape that is cheaper but inside the
+        # margin, keep the smaller shape.
+        (dict.fromkeys(V5E_LAUNCH_S, 0.005), IDENTITY),
+        ({16: 0.010, 64: 0.010, 256: 0.010 / PROMOTE_MARGIN, 1024: 0.02, 4096: 0.06}, IDENTITY),
+        ({16: 0.010, 64: 0.010, 256: 0.006, 1024: 0.02, 4096: 0.06}, IDENTITY),
+        # Just past the margin.
+        ({16: 0.010, 64: 0.010, 256: 0.010 / PROMOTE_MARGIN - 1e-4, 1024: 0.02, 4096: 0.06},
+         {16: 256, 64: 256, 256: 256, 1024: 1024, 4096: 4096}),
+        # One cheap shape in the middle serves everything under it, nothing above.
+        ({16: 0.03, 64: 0.03, 256: 0.03, 1024: 0.004, 4096: 0.06},
+         {16: 1024, 64: 1024, 256: 1024, 1024: 1024, 4096: 4096}),
+        # A cheap top shape takes all; a cheap shape above a dear one does not
+        # reach past it (each shape looks at what serves the NEXT larger one).
+        ({8: 0.02, 16: 0.001}, {8: 16, 16: 16}),
+        ({8: 0.02, 16: 0.02, 32: 0.001}, {8: 32, 16: 32, 32: 32}),
+        ({8: 0.02, 16: 0.002, 32: 0.001}, {8: 16, 16: 16, 32: 32}),
+        ({}, {}),
+    ],
+)
+def test_serving_table(launch_s, want):
+    assert serving_table(launch_s) == want
+    assert list(serving_table(launch_s)) == sorted(launch_s)
+
+
+@pytest.mark.parametrize(
+    "n,want",
+    [
+        (1, 0.00531),  # fits 16, runs at 256: room for 255 more
+        (50, 0.00531),
+        (255, 0.00531),
+        (256, 0.0),  # fills the shape it runs at: goes at once
+        (257, 0.01347),
+        (1024, 0.0),
+        (4095, 0.060),
+        (4096, 0.0),
+        (5000, 0.0),  # oversized: chunks at the top shape
+    ],
+)
+def test_hold_is_one_launch_of_the_shape_run_while_it_has_room(n, want):
+    engine = ShardedVerifyEngine(shapes=tuple(V5E_LAUNCH_S))
+    assert engine.hold_s(n) == 0.0  # before warm-up: nothing timed, nothing held
+    engine._compiled = dict.fromkeys(V5E_LAUNCH_S)
+    engine._launch_s = dict(V5E_LAUNCH_S)
+    engine._serves = serving_table(V5E_LAUNCH_S)
+    assert engine.hold_s(n) == want
+
+
+def _slow_below_32(pubs, msgs, sigs):
+    """``_slow_kernel``'s rule on a device whose small programs are the slow
+    ones: twenty times the spin where the window has fewer than 32 slots.
+    (The shape is static under jit: each executable is slow or fast outright.
+    The fast ones spin too, for a millisecond: the timer tells two launches
+    of microseconds apart by whatever else the host was doing.)"""
+    import jax
+    import jax.numpy as jnp
+
+    spin = jax.lax.fori_loop(
+        0,
+        2_000_000 if pubs.shape[0] < 32 else 100_000,
+        lambda i, acc: acc + (i & 1),
+        pubs[0, 0].astype(jnp.int32),
+    )
+    return (pubs[:, 0] == sigs[:, 0]) & (spin >= 0)
+
+
+def test_engine_serves_small_windows_on_the_cheaper_larger_shape(tmp_path):
+    shapes = (8, 16, 32, 64)
+    engine = ShardedVerifyEngine(shapes=shapes, kernel=_slow_below_32)
+    plain = ShardedVerifyEngine(shapes=shapes, kernel=lambda p, m, s: p[:, 0] == s[:, 0])
+    plain.warm()
+    plain._serves = {}  # smallest-fit, whatever its microsecond launches read
+    trace = tmp_path / "verifyd.jsonl"
+    daemon = VerifyServiceDaemon(
+        backend="auto", engine=engine, trace_path=str(trace),
+        fallback=lambda items: pytest.fail("the fallback ran"),
+    ).start(wait_ready=True, timeout=300)
+    sizes = (5, 8, 12, 16, 20, 32, 33, 64, 64 + 5, 64 + 40)
+    try:
+        assert daemon.state_name == "ready"
+        assert engine.warmed_sizes == shapes  # every shape asked for stays compiled
+        costs = {p["size"]: p["launch_s"] for p in engine.stats["per_shape"]}
+        assert set(costs) == set(shapes) and all(c > 0 for c in costs.values())
+        assert min(costs[8], costs[16]) > PROMOTE_MARGIN * max(costs[32], costs[64]), costs
+        assert engine.stats["serving_table"] == {"8": 32, "16": 32, "32": 32, "64": 64}
+        assert daemon.service.hold_s == engine.hold_s  # set once warm-up has timed the shapes
+        assert engine.hold_s(5) == costs[32] and engine.hold_s(32) == 0.0
+        for n in sizes:  # accepted and rejected items, alone on the wire
+            items = [_item(n + i, i % 3 != 0) for i in range(n)]
+            got = _send_batch(daemon.address, items)
+            assert got == [i % 3 != 0 for i in range(n)] == plain.verify(items)
+        status = daemon.status_json()
+    finally:
+        daemon.stop()
+    lines = _lines(trace)
+    assert [e["size"] for e in lines] == list(sizes)
+    #          5   8   12  16  20  32  33  64  64+5     64+40
+    assert [e["rung"] for e in lines] == [32, 32, 32, 32, 32, 32, 64, 64, 64 + 32, 64 + 64]
+    assert [e["promoted"] for e in lines] == [1, 1, 1, 1, 0, 0, 0, 0, 1, 0]
+    assert status["promoted_launches"] == 5
+    assert status["warmed_shapes"] == list(shapes)
+    assert set(status) <= trace_schema.VERIFYD_STATUS_KEYS
+    assert set(status["warm_stats"]) <= trace_schema.VERIFYD_WARM_STATS_KEYS
+    for shape in status["warm_stats"]["per_shape"]:
+        assert set(shape) == trace_schema.VERIFYD_PER_SHAPE_KEYS
+        assert shape["launch_s"] == costs[shape["size"]]
+    assert status["warm_stats"]["serving_table"] == {"8": 32, "16": 32, "32": 32, "64": 64}
+    assert "promoted" in trace_schema.EVENT_SCHEMAS["verify_batch"]["optional"]
+
+
+def test_warm_up_fails_where_an_executable_rejects_the_pad_triple(monkeypatch):
+    """The timed launches are a self-test of each executable: the engine's
+    own kernel has to accept the known-good triple in every pad slot."""
+    import pbft_tpu.parallel as parallel
+
+    real = parallel.compile_sharded
+
+    def broken(mesh, size, kernel=None):
+        import jax.numpy as jnp
+
+        assert kernel is None  # the engine's own kernel was asked for
+        # A program that rejects its last slot, in the real kernel's place.
+        return real(mesh, size, kernel=lambda p, m, s: jnp.arange(p.shape[0]) < p.shape[0] - 1)
+
+    monkeypatch.setattr(parallel, "compile_sharded", broken)
+    engine = ShardedVerifyEngine(shapes=(8,))
+    with pytest.raises(RuntimeError, match="8-slot executable rejected .* 1 of 8 slots"):
+        engine.warm()
+    assert engine.warmed_sizes == ()
+    daemon = VerifyServiceDaemon(backend="auto", engine=ShardedVerifyEngine(shapes=(8,)))
+    daemon.start(wait_ready=True, timeout=300)
+    try:
+        assert daemon.state_name == "cpu-only"
+        assert "self-test" in daemon.status_json()["warm_error"]
+    finally:
+        daemon.stop()
+
+
+def test_verify_status_prints_the_serving_table(capsys):
+    sys.path.insert(0, str(ROOT / "scripts"))
+    try:
+        import verify_status
+    finally:
+        sys.path.pop(0)
+    engine = ShardedVerifyEngine(shapes=(8, 32), kernel=_slow_below_32)
+    daemon = VerifyServiceDaemon(backend="auto", engine=engine).start(wait_ready=True, timeout=300)
+    try:
+        assert _send_batch(daemon.address, [_item(3, True)]) == [True]
+        assert verify_status.main([daemon.address]) == 0
+    finally:
+        daemon.stop()
+    out = capsys.readouterr().out
+    assert "serving table   8→32 32→32  (1 launches promoted)" in out
+    costs = out.split("launch cost     ")[1].splitlines()[0]
+    assert [w for w in costs.split() if w.endswith(":")] == ["8:", "32:"] and costs.endswith(" ms")
+    assert "promoted_launches" not in out  # printed once, with the table
 
 
 def test_verify_status_prints_the_stall_fields(capsys):
@@ -350,11 +527,14 @@ NEW_METRICS = {
     "device_wait_ms_mean": ("ms", "verifyd engine"),
     "device_wait_ms_max": ("ms", "verifyd engine"),
     "engine_idle_pct": ("%", "device"),
+    # PR 27: how often, and to what, the engine's serving table promotes.
+    "promoted_share": ("ratio", "verifyd engine", "higher", "program_counter"),
+    "rung_slots_mean": ("slots", "verifyd engine", "lower", "program_counter"),
 }
 FORMS = {".closed": ("commit_rate", "f1-sig-wal.closed"), ".rate": ("reply_p50_ms", "f1-sig-wal.rate")}
-# What PR 25's benchmark held, which this PR may only add to: everything in
-# BENCHMARK.json but the entries appended to `per_layer` (a `benchmark` PR
-# that edits an entry pins its own digest here).
+# What the accepted benchmark held (PR 25's, which PR 26 and PR 27 may only
+# add to): everything in BENCHMARK.json but the entries appended to
+# `per_layer` (a `benchmark` PR that edits an entry pins its own digest here).
 ACCEPTED_PER_LAYER = 27
 ACCEPTED_DIGEST = "7f2b2c41f15ac07556e716381fd4915806724ddc56d4b01011fdd21d1e6e3800"
 
@@ -362,7 +542,7 @@ ACCEPTED_DIGEST = "7f2b2c41f15ac07556e716381fd4915806724ddc56d4b01011fdd21d1e6e3
 @pytest.mark.parametrize("name", sorted(NEW_METRICS))
 @pytest.mark.parametrize("form", sorted(FORMS))
 def test_new_metric_has_its_reader_and_its_entry(name, form):
-    unit, layer = NEW_METRICS[name]
+    unit, layer, better, source = (*NEW_METRICS[name], "lower", "program_span")[:4]
     moves, cell = FORMS[form]
     spec = json.loads((CHIPBENCH / "metrics" / f"{name}{form}.json").read_text())
     assert spec["name"] == name + form
@@ -370,7 +550,7 @@ def test_new_metric_has_its_reader_and_its_entry(name, form):
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     entry = [m for m in bench["per_layer"] if m["name"] == name + form]
     assert entry == [{
-        "name": name + form, "unit": unit, "better": "lower", "source": "program_span",
+        "name": name + form, "unit": unit, "better": better, "source": source,
         "layer": layer, "moves": moves, "workloads": [cell],
     }]
     assert bench["per_layer"].index(entry[0]) >= ACCEPTED_PER_LAYER
