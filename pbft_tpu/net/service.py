@@ -60,6 +60,13 @@ STATE_NAMES = {
 }
 
 
+# Connections the kernel holds between SYN and accept(): a whole cluster
+# dials at its first batch (16 replicas at f=5, 31 at f=10), socketserver's
+# default of 5 overflows, and a dropped SYN is retried only after a second,
+# longer than a replica's connect deadline (PBFT_VERIFY_CONNECT_MS, 250).
+LISTEN_QUEUE = 128
+
+
 def pack_status(state: int, devices: int, warmed: int) -> bytes:
     """8 bytes: 'V' 'S' version state u16be devices u16be warmed-shapes."""
     return STATUS_MAGIC + struct.pack(
@@ -237,8 +244,14 @@ class VerifierService:
         # the step that held it (written under _cond by the launch threads).
         # promoted_launches: launches the engine ran on a larger shape than
         # the smallest that fits (its span's ``promoted``).
+        # held_out_launches / in_step_launches: windows whose hold ran out,
+        # and windows cut early because nobody in step was still out;
+        # launches_by_rung: launches by the padded slots the engine ran.
         self.stage_seconds = {"queue_s": 0.0, "slot_s": 0.0}
         self.promoted_launches = 0
+        self.held_out_launches = 0
+        self.in_step_launches = 0
+        self.launches_by_rung: dict = {}
         self._slowest: Optional[dict] = None
         self._coalesce = coalesce
         self._cond = threading.Condition()
@@ -293,6 +306,7 @@ class VerifierService:
 
             class UnixServer(socketserver.ThreadingUnixStreamServer):
                 daemon_threads = True
+                request_queue_size = LISTEN_QUEUE
 
             self.server = UnixServer(unix_path, Handler)
             self.address = unix_path
@@ -301,6 +315,7 @@ class VerifierService:
             class TcpServer(socketserver.ThreadingTCPServer):
                 daemon_threads = True
                 allow_reuse_address = True
+                request_queue_size = LISTEN_QUEUE
 
             self.server = TcpServer((host, port), Handler)
             self.address = "%s:%d" % self.server.server_address
@@ -388,6 +403,10 @@ class VerifierService:
                     self._cond.wait(0.5)
                 if not self._running and not self._pending:
                     return
+                # The hold the window is granted at its cut, and which of
+                # its exits cut it (neither: it filled its shape, or no
+                # hold applies).
+                hold, held_out, in_step = 0.0, 0, 0
                 if self._flush_s > 0:
                     # Bounded accumulation: hold the window open until the
                     # item target or the deadline. _cond.wait releases the
@@ -408,7 +427,13 @@ class VerifierService:
                         remaining = (
                             self._pending[0].arrived + hold - time.monotonic()
                         )
-                        if hold <= 0 or remaining <= 0 or self._in_step_are_back():
+                        if hold <= 0:  # the window fills its shape
+                            break
+                        if remaining <= 0:
+                            held_out = 1
+                            break
+                        if self._in_step_are_back():
+                            in_step = 1
                             break
                         self._cond.wait(remaining)
                 # Take whole requests up to MAX_WINDOW items (a single
@@ -438,6 +463,9 @@ class VerifierService:
                 "slot_s": round(got_slot - cut_at, 6),
                 "pending_at_cut": left,
                 "pending_at_launch": arrived_since,
+                "hold_s": round(hold, 6),
+                "held_out": held_out,
+                "in_step": in_step,
             }
             if self._inflight == 1:
                 self._dispatch_guarded(window, waits)
@@ -501,6 +529,11 @@ class VerifierService:
         for name, took in steps.items():
             self.stage_seconds[name] = self.stage_seconds.get(name, 0.0) + took
         self.promoted_launches += bool(span.get("promoted"))
+        self.held_out_launches += waits["held_out"]
+        self.in_step_launches += waits["in_step"]
+        if "rung" in span:
+            rung = str(span["rung"])  # JSON has no integer keys
+            self.launches_by_rung[rung] = self.launches_by_rung.get(rung, 0) + 1
         if self._slowest is None or secs > self._slowest["secs"]:
             self._slowest = {
                 "secs": round(secs, 6),
@@ -511,19 +544,20 @@ class VerifierService:
             }
 
     def launch_status(self) -> dict:
-        """The stage totals, the promoted launches and the slowest launch,
-        for the status JSON."""
+        """The stage totals, the counts of launches (promoted, by exit of
+        the hold, by shape run) and the slowest launch, for the status JSON."""
         with self._cond:
             slowest = dict(self._slowest) if self._slowest else None
             totals = {k: round(v, 6) for k, v in self.stage_seconds.items()}
-            promoted = self.promoted_launches
+            counts = {
+                "promoted_launches": self.promoted_launches,
+                "held_out_launches": self.held_out_launches,
+                "in_step_launches": self.in_step_launches,
+                "launches_by_rung": dict(self.launches_by_rung),
+            }
         if slowest:
             slowest["ago_s"] = round(time.monotonic() - slowest.pop("at"), 3)
-        return {
-            "stage_seconds": totals,
-            "promoted_launches": promoted,
-            "slowest_launch": slowest,
-        }
+        return {"stage_seconds": totals, **counts, "slowest_launch": slowest}
 
     def _dispatch_window(self, window: List[_Pending], waits: dict) -> None:
         merged: List[Item] = []

@@ -26,7 +26,10 @@ EVENT_SCHEMAS = {
     # on time.monotonic(): queue_s (window cut minus the arrival of the
     # oldest request in it), slot_s (launch slot acquired minus window
     # cut), pending_at_cut / pending_at_launch (items queued at those two
-    # moments); and, where the sharded engine ran it, the engine's five
+    # moments), hold_s (the hold for company the window was granted at its
+    # cut, 0 where none) and the 0/1 pair held_out (cut because that hold
+    # ran out) / in_step (cut early because nobody in step was still out);
+    # and, where the sharded engine ran it, the engine's five
     # steps summed over chunks (pad_s, put_s, dispatch_s, wait_s, unpack_s:
     # they add up to secs), rung (the padded slots the chunks really ran
     # at), promoted (chunks run on a larger shape than the smallest that
@@ -37,6 +40,7 @@ EVENT_SCHEMAS = {
         "optional": {
             "view", "executed", "requests",
             "queue_s", "slot_s", "pending_at_cut", "pending_at_launch",
+            "hold_s", "held_out", "in_step",
             "pad_s", "put_s", "dispatch_s", "wait_s", "unpack_s", "rung", "promoted",
             "t_dev",
         },
@@ -390,8 +394,11 @@ VERIFYD_STATUS_KEYS = {
     "warmed_shapes", "backend", "uptime_s", "requests",
     "engine_launches", "engine_items", "fallback_launches", "fallback_items",
     # Launches, without --trace: running totals of every stage, launches the
-    # engine ran on a larger shape than the smallest fit, the slowest one.
-    "stage_seconds", "promoted_launches", "slowest_launch",
+    # engine ran on a larger shape than the smallest fit, windows whose hold
+    # ran out / ended early with everybody in step back, launches by the
+    # padded slots run ({"1024": n, ...}), the slowest one.
+    "stage_seconds", "promoted_launches", "held_out_launches",
+    "in_step_launches", "launches_by_rung", "slowest_launch",
     "memory_peak_bytes", "warm_stats", "warm_error",
 }
 VERIFYD_WARM_STATS_KEYS = {

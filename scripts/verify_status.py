@@ -10,7 +10,8 @@ persistent compile cache answered (warm) and which were traced+compiled
 warm-up), the serving table made from those costs (``16→256`` = a window
 that fits 16 slots runs on the 256-slot program) and how many launches it
 promoted, the running total of each launch stage (queue, slot, pad, put,
-dispatch, wait, unpack), the slowest launch so far with the step that
+dispatch, wait, unpack), the launches by shape run and by which exit of
+the hold cut their window, the slowest launch so far with the step that
 held it, and the device's peak memory.
 
     python scripts/verify_status.py                      # default target
@@ -104,6 +105,12 @@ def main(argv=None) -> int:
         print("  stage seconds   " + "  ".join(
             f"{name.removesuffix('_s')} {secs:.3f}" for name, secs in stages.items()
         ))
+    by_rung = status.get("launches_by_rung") or {}
+    if by_rung:
+        print("  launches        " + "  ".join(
+            f"{rung} slots: {n}" for rung, n in sorted(by_rung.items(), key=lambda kv: int(kv[0]))
+        ) + "  (hold ran out %d, in step %d)" % (
+            status.get("held_out_launches", 0), status.get("in_step_launches", 0)))
     slowest = status.get("slowest_launch")
     if slowest:
         print(
@@ -117,7 +124,8 @@ def main(argv=None) -> int:
     known = {
         "state", "devices", "uptime_s", "warmed_shapes", "warm_stats",
         "stage_seconds", "slowest_launch", "memory_peak_bytes",
-        "promoted_launches",
+        "promoted_launches", "launches_by_rung", "held_out_launches",
+        "in_step_launches",
     }
     for k in sorted(set(status) - known):
         print(f"  {k:<15} {status[k]}")

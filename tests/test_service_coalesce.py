@@ -13,6 +13,8 @@ import socket
 import threading
 import time
 
+import pytest
+
 from pbft_tpu.net import (
     ServiceVerifier,
     ShardedVerifyEngine,
@@ -469,6 +471,94 @@ def test_a_held_window_goes_when_it_fills_or_its_hold_runs_out(tmp_path):
         a.close()
         b.close()
         flushed.stop()
+
+
+@pytest.mark.parametrize(
+    "hold, b_sends, a_comes_back, want",
+    [
+        # b's window has room and a, whom the last launch answered, is out:
+        # it is cut when a is back, long before its hold is over.
+        (30.0, 1, True, {"size": 2, "hold_s": 30.0, "held_out": 0, "in_step": 1}),
+        # a stays out: the hold runs out.
+        (0.3, 1, False, {"size": 1, "hold_s": 0.3, "held_out": 1, "in_step": 0}),
+        # the window fills the shape it would run at: no hold is granted.
+        (30.0, 3, False, {"size": 3, "hold_s": 0.0, "held_out": 0, "in_step": 0}),
+    ],
+    ids=["in_step", "held_out", "full"],
+)
+def test_the_line_says_which_exit_of_the_hold_cut_the_window(
+    tmp_path, hold, b_sends, a_comes_back, want
+):
+    """Every ``verify_batch`` line carries the hold its window was granted at
+    the cut (``hold_s``) and which exit cut it: ``in_step`` (nobody in step
+    was still out), ``held_out`` (the hold ran out) or neither (it filled
+    its shape); the status JSON counts both."""
+    import json
+
+    trace = tmp_path / "service.jsonl"
+    svc = VerifierService(backend=_sizes_backend([]), trace_path=str(trace)).start()
+    svc.hold_s = lambda n: hold if n < 3 else 0.0
+    a, b = _Conn(svc.address), _Conn(svc.address)
+    results = {}
+    try:
+        assert a.send([_item(1, True)]) == [True]  # a caller alone: in step with nobody
+        t = b.send_later([_item(2 + k, True) for k in range(b_sends)], results, "b")
+        if a_comes_back:
+            t.join(0.2)
+            assert t.is_alive()
+            assert a.send([_item(9, False)]) == [False]
+        t.join(10)
+        assert results["b"] == [True] * b_sends
+        status = svc.launch_status()
+    finally:
+        a.close()
+        b.close()
+        svc.stop()
+    first, second = [json.loads(ln) for ln in trace.read_text().splitlines()]
+    assert {k: first[k] for k in ("hold_s", "held_out", "in_step")} == {
+        "hold_s": hold, "held_out": 0, "in_step": 1,
+    }
+    assert {k: second[k] for k in want} == want
+    assert second["queue_s"] >= want["hold_s"] * want["held_out"]
+    assert status["in_step_launches"] == 1 + want["in_step"]
+    assert status["held_out_launches"] == want["held_out"]
+    assert status["launches_by_rung"] == {}  # this backend runs no shape
+
+
+@pytest.mark.parametrize("family", ["tcp", "unix"])
+def test_a_whole_cluster_dialing_at_once_is_accepted_without_a_retry(tmp_path, family):
+    """32 replicas dial in the same instant, before the server has accepted
+    anybody (it is not even serving yet): the listen queue holds them all.
+    With socketserver's queue of 5 the seventh SYN is dropped and retried a
+    second later, past a replica's connect deadline of 250 ms."""
+    if family == "unix":
+        svc = VerifierService(unix_path=str(tmp_path / "v.sock"), backend=_sizes_backend([]))
+    else:
+        svc = VerifierService(backend=_sizes_backend([]))
+    socks = []
+    try:
+        for _ in range(32):
+            if family == "unix":
+                sock = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
+                sock.settimeout(0.25)
+                sock.connect(svc.address)
+            else:
+                host, port = svc.address.rsplit(":", 1)
+                sock = socket.create_connection((host, int(port)), timeout=0.25)
+            socks.append(sock)
+        svc.start()
+        for k, sock in enumerate(socks):  # and every one of them is served
+            sock.settimeout(30)
+            pub, msg, sig = _item(k + 1, k % 2 == 0)
+            sock.sendall((1).to_bytes(4, "big") + pub + msg + sig)
+            assert sock.recv(1) == bytes([k % 2 == 0])
+    finally:
+        for sock in socks:
+            sock.close()
+        if svc._thread is None:  # never served: stop() would wait for a loop that never ran
+            svc.server.server_close()
+        else:
+            svc.stop()
 
 
 def test_service_trace_records_merged_windows(tmp_path):

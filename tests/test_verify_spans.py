@@ -334,6 +334,14 @@ def test_engine_serves_small_windows_on_the_cheaper_larger_shape(tmp_path):
     assert [e["rung"] for e in lines] == [32, 32, 32, 32, 32, 32, 64, 64, 64 + 32, 64 + 64]
     assert [e["promoted"] for e in lines] == [1, 1, 1, 1, 0, 0, 0, 0, 1, 0]
     assert status["promoted_launches"] == 5
+    assert status["launches_by_rung"] == {"32": 6, "64": 2, "96": 1, "128": 1}
+    # The hold each window was granted at its cut: one launch of the shape it
+    # runs at while that shape has room; every caller here is alone, so a
+    # granted hold ends at once (in step with nobody) unless the connection
+    # before it was slow to hang up (then it runs out).
+    assert [e["hold_s"] for e in lines] == [costs[32]] * 5 + [0.0, costs[64], 0.0, 0.0, 0.0]
+    assert [e["in_step"] + e["held_out"] for e in lines] == [1] * 5 + [0, 1, 0, 0, 0]
+    assert status["in_step_launches"] + status["held_out_launches"] == 6
     assert status["warmed_shapes"] == list(shapes)
     assert set(status) <= trace_schema.VERIFYD_STATUS_KEYS
     assert set(status["warm_stats"]) <= trace_schema.VERIFYD_WARM_STATS_KEYS
@@ -341,7 +349,9 @@ def test_engine_serves_small_windows_on_the_cheaper_larger_shape(tmp_path):
         assert set(shape) == trace_schema.VERIFYD_PER_SHAPE_KEYS
         assert shape["launch_s"] == costs[shape["size"]]
     assert status["warm_stats"]["serving_table"] == {"8": 32, "16": 32, "32": 32, "64": 64}
-    assert "promoted" in trace_schema.EVENT_SCHEMAS["verify_batch"]["optional"]
+    assert {"promoted", "hold_s", "held_out", "in_step"} <= (
+        trace_schema.EVENT_SCHEMAS["verify_batch"]["optional"]
+    )
 
 
 def test_warm_up_fails_where_an_executable_rejects_the_pad_triple(monkeypatch):
@@ -531,19 +541,37 @@ NEW_METRICS = {
     "promoted_share": ("ratio", "verifyd engine", "higher", "program_counter"),
     "rung_slots_mean": ("slots", "verifyd engine", "lower", "program_counter"),
 }
-FORMS = {".closed": ("commit_rate", "f1-sig-wal.closed"), ".rate": ("reply_p50_ms", "f1-sig-wal.rate")}
-# What the accepted benchmark held (PR 25's, which PR 26 and PR 27 may only
-# add to): everything in BENCHMARK.json but the entries appended to
-# `per_layer` (a `benchmark` PR that edits an entry pins its own digest here).
+# PR 28: what the hold did to each window, in the closed cells only.
+CLOSED_ONLY_METRICS = {
+    "requests_per_launch": ("requests", "verifyd dispatcher", "higher", "program_span"),
+    "window_items_max": ("items", "verifyd dispatcher", "higher", "program_span"),
+    "hold_ms_mean": ("ms", "verifyd dispatcher"),
+    "held_out_share": ("ratio", "verifyd dispatcher"),
+    "in_step_share": ("ratio", "verifyd dispatcher", "higher", "program_span"),
+}
+FORMS = {
+    ".closed": ("commit_rate", ["f1-sig-wal.closed", "f5-sig-wal.closed"]),
+    ".rate": ("reply_p50_ms", ["f1-sig-wal.rate"]),
+}
+# What the accepted benchmark held (PR 25's, which later PRs may only add
+# to): everything in BENCHMARK.json but the entries appended to `per_layer`
+# and the configuration and cell PR 28 appended (a `benchmark` PR that edits
+# an entry pins its own digest here).
 ACCEPTED_PER_LAYER = 27
 ACCEPTED_DIGEST = "7f2b2c41f15ac07556e716381fd4915806724ddc56d4b01011fdd21d1e6e3800"
+ADDED_CONFIGS, ADDED_CELLS = ["f5-sig-wal"], ["f5-sig-wal.closed"]
 
 
-@pytest.mark.parametrize("name", sorted(NEW_METRICS))
-@pytest.mark.parametrize("form", sorted(FORMS))
+@pytest.mark.parametrize(
+    "name, form",
+    [(name, form) for name in sorted(NEW_METRICS) for form in sorted(FORMS)]
+    + [(name, ".closed") for name in sorted(CLOSED_ONLY_METRICS)],
+)
 def test_new_metric_has_its_reader_and_its_entry(name, form):
-    unit, layer, better, source = (*NEW_METRICS[name], "lower", "program_span")[:4]
-    moves, cell = FORMS[form]
+    unit, layer, better, source = (
+        *(NEW_METRICS | CLOSED_ONLY_METRICS)[name], "lower", "program_span"
+    )[:4]
+    moves, cells = FORMS[form]
     spec = json.loads((CHIPBENCH / "metrics" / f"{name}{form}.json").read_text())
     assert spec["name"] == name + form
     assert (CHIPBENCH / "reducers" / f"{spec['reducer']}.py").is_file()
@@ -551,14 +579,32 @@ def test_new_metric_has_its_reader_and_its_entry(name, form):
     entry = [m for m in bench["per_layer"] if m["name"] == name + form]
     assert entry == [{
         "name": name + form, "unit": unit, "better": better, "source": source,
-        "layer": layer, "moves": moves, "workloads": [cell],
+        "layer": layer, "moves": moves, "workloads": cells,
     }]
     assert bench["per_layer"].index(entry[0]) >= ACCEPTED_PER_LAYER
 
 
 def test_accepted_benchmark_entries_are_unchanged():
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    accepted = dict(bench, per_layer=bench["per_layer"][:ACCEPTED_PER_LAYER])
+    # A configuration and a cell are appended, and the cell's name to the
+    # lists of the metrics it reports; taken off again, nothing has changed.
+    assert [c["name"] for c in bench["configs"]][-len(ADDED_CONFIGS):] == ADDED_CONFIGS
+    assert [c["name"] for c in bench["workloads"]][-len(ADDED_CELLS):] == ADDED_CELLS
+
+    def as_accepted(metric: dict) -> dict:
+        cells = metric.get("workloads")
+        if cells is None or cells[-1] not in ADDED_CELLS:
+            return metric
+        assert not set(cells[:-1]) & set(ADDED_CELLS)
+        return dict(metric, workloads=cells[:-1])
+
+    accepted = dict(
+        bench,
+        configs=bench["configs"][: -len(ADDED_CONFIGS)],
+        workloads=bench["workloads"][: -len(ADDED_CELLS)],
+        end_to_end=[as_accepted(m) for m in bench["end_to_end"]],
+        per_layer=[as_accepted(m) for m in bench["per_layer"][:ACCEPTED_PER_LAYER]],
+    )
     digest = hashlib.sha256(json.dumps(accepted, sort_keys=True).encode()).hexdigest()
     assert digest == ACCEPTED_DIGEST
     # Every metric has a reader file, and every reader file a metric.
