@@ -229,6 +229,8 @@ def device_stage(target: str, seed: int, ladder=LADDER, trace_path=None) -> None
     # every shape at warm-up (a window of exactly `fit` items runs at `runs`).
     log("serving table (smallest shape that fits→shape run): "
         + serving_table_text(before["warm_stats"]["serving_table"]))
+    log("chunk plan (items→shapes run, where several launches cost less): "
+        + (serving_table_text(before["warm_stats"]["chunk_plan"]) or "none"))
 
     # One window of exactly each rung size, alone on the wire.
     for size in ladder:
@@ -300,7 +302,8 @@ def device_stage(target: str, seed: int, ladder=LADDER, trace_path=None) -> None
         log(f"coalesced launches (requests, items): {merged}")
     log(f"device stage: {sent} verdicts, all through the engine, "
         f"{after['promoted_launches'] - before['promoted_launches']} launches "
-        "on a larger shape than the smallest fit")
+        "on a larger shape than the smallest fit, "
+        f"{after['split_launches'] - before['split_launches']} run as chunks")
 
 
 # -- stage 2: the deployment ---------------------------------------------------

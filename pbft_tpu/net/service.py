@@ -243,12 +243,14 @@ class VerifierService:
         # sharded engine's five steps — and the slowest launch so far with
         # the step that held it (written under _cond by the launch threads).
         # promoted_launches: launches the engine ran on a larger shape than
-        # the smallest that fits (its span's ``promoted``).
+        # the smallest that fits (its span's ``promoted``); split_launches:
+        # windows it ran as several executables (its span's ``split``).
         # held_out_launches / in_step_launches: windows whose hold ran out,
         # and windows cut early because nobody in step was still out;
         # launches_by_rung: launches by the padded slots the engine ran.
         self.stage_seconds = {"queue_s": 0.0, "slot_s": 0.0}
         self.promoted_launches = 0
+        self.split_launches = 0
         self.held_out_launches = 0
         self.in_step_launches = 0
         self.launches_by_rung: dict = {}
@@ -529,6 +531,7 @@ class VerifierService:
         for name, took in steps.items():
             self.stage_seconds[name] = self.stage_seconds.get(name, 0.0) + took
         self.promoted_launches += bool(span.get("promoted"))
+        self.split_launches += bool(span.get("split"))
         self.held_out_launches += waits["held_out"]
         self.in_step_launches += waits["in_step"]
         if "rung" in span:
@@ -544,13 +547,14 @@ class VerifierService:
             }
 
     def launch_status(self) -> dict:
-        """The stage totals, the counts of launches (promoted, by exit of
-        the hold, by shape run) and the slowest launch, for the status JSON."""
+        """The stage totals, the counts of launches (promoted, split, by exit
+        of the hold, by shape run) and the slowest launch, for the status JSON."""
         with self._cond:
             slowest = dict(self._slowest) if self._slowest else None
             totals = {k: round(v, 6) for k, v in self.stage_seconds.items()}
             counts = {
                 "promoted_launches": self.promoted_launches,
+                "split_launches": self.split_launches,
                 "held_out_launches": self.held_out_launches,
                 "in_step_launches": self.in_step_launches,
                 "launches_by_rung": dict(self.launches_by_rung),

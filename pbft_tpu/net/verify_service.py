@@ -34,9 +34,12 @@ the all-pad window, keeps the least of a few timed launches as the shape's
 ``launch_s``, and :func:`serving_table` sends a window to a larger shape
 where that one measured clearly cheaper (on a TPU v5e the 256-slot program
 takes 5 ms and the 16- and 64-slot ones 42 ms). Where cost grows with size,
-as on a CPU, that is the smallest shape that fits. The same measurement
+as on a CPU, that is the smallest shape that fits. Where it grows FASTER
+than the size (the v5e's 4,096-slot program takes 53 ms, the 1,024-slot one
+16), :func:`chunk_plan` runs the window as a few launches of the smaller
+shapes instead (1,100 items on 1,024 + 256 slots). The same measurement
 bounds how long a window waits for company: while it leaves room on the
-shape it would run at, the dispatcher keeps it open for at most one
+last shape of its plan, the dispatcher keeps it open for at most one
 ``launch_s`` of that shape (:meth:`ShardedVerifyEngine.hold_s`), so
 replicas' batches that arrive a few ms apart share a launch.
 
@@ -58,6 +61,8 @@ import sys
 import threading
 import time
 import warnings
+from bisect import bisect_left
+from itertools import combinations_with_replacement
 from typing import Callable, List, Optional, Sequence, Tuple
 
 # The readiness wire format (STATUS_* / STATE_* / pack_status /
@@ -114,8 +119,92 @@ def serving_table(launch_s: dict) -> dict:
 
 
 def serving_table_text(table: dict) -> str:
-    """``16→256 64→256 …``: a window that fits 16 slots runs at 256."""
+    """``16→256 64→256 …``: a window that fits 16 slots runs at 256 (and, of
+    :func:`chunk_plan_words`, ``1025-1280→1024+256 …``)."""
     return " ".join(f"{fit}→{runs}" for fit, runs in table.items())
+
+
+# A window runs as several launches of smaller shapes only where their
+# ``launch_s`` add up to under the single fitting shape's by a QUARTER. The
+# sum is what the chunks cost one after another on an idle device, so it is
+# pessimistic (a chunk's staging hides behind the chunk before it), but it is
+# made of readings of the host's clock. On the TPU v5e (7.1 / 15.8 / 53.3 ms
+# at 256 / 1,024 / 4,096 slots, the same to 2% in every warm-up read) the
+# covers that matter stand at 2.3x, 1.8x and 1.4x (1,024 + 256, 1,024 + 2 x
+# 256, 2 x 1,024 + 256 slots against 4,096) and the ones that do not at 1.12x
+# and 1.11x (3 x 1,024 against 4,096, 2 x 256 against 1,024): 1.25 lies
+# midway between 1.4 and 1.12, a tenth from either, five times what a
+# reading moves. Where cost is flat in the slots no cover comes under the
+# margin and the plan is the one shape; where it is linear (a CPU: a pad
+# slot is real work) a window splits wherever smaller shapes cover it with a
+# fifth fewer slots.
+SPLIT_MARGIN = 1.25
+
+# More chunks than this are never worth their host work (a chunk is a pad,
+# three transfers, a dispatch and a read-back in the service's one Python
+# process), and the bound keeps the search a handful of sums.
+MAX_CHUNKS = 4
+
+
+def chunk_plan(n: int, launch_s: dict, serves: dict) -> tuple:
+    """The shapes a window of ``n`` items runs at, largest first, from what
+    one launch of each warmed shape cost and the serving table made of it.
+    As a rule that is the ONE shape the table gives for the smallest that
+    fits. Among the covers of ``n`` by two to ``MAX_CHUNKS`` shapes that
+    serve themselves (so every chunk is where the table would send it), the
+    one with the least summed ``launch_s`` (fewer chunks, then fewer slots,
+    on a tie) takes its place where that sum is under the one shape's cost
+    by ``SPLIT_MARGIN``. Beyond the largest shape: chunks of that shape, and
+    the rest (1 to a full one) by the same rule."""
+    if n <= 0 or not launch_s:
+        return ()
+    top = max(launch_s)
+    if n > top:
+        whole = (n - 1) // top
+        return (top,) * whole + chunk_plan(n - whole * top, launch_s, serves)
+    fit = min(s for s in launch_s if s >= n)
+    one = serves.get(fit, fit)
+    runs = sorted({serves.get(s, s) for s in launch_s}, reverse=True)
+    best = None
+    for k in range(2, MAX_CHUNKS + 1):
+        for cover in combinations_with_replacement(runs, k):
+            if sum(cover) >= n:
+                key = (sum(launch_s[s] for s in cover), k, sum(cover))
+                if best is None or key < best[0]:
+                    best = (key, cover)
+    if best is not None and best[0][0] * SPLIT_MARGIN < launch_s[one]:
+        return best[1]
+    return (one,)
+
+
+def plan_table(launch_s: dict, serves: dict) -> list:
+    """:func:`chunk_plan` for every window up to the largest shape, as
+    ascending ``[(largest n, plan)]`` with neighbours that differ: a plan
+    can change only where ``n`` passes the slots of some cover."""
+    runs = {serves.get(s, s) for s in launch_s}
+    edges = set(launch_s)
+    for k in range(2, MAX_CHUNKS + 1):
+        edges.update(map(sum, combinations_with_replacement(runs, k)))
+    top = max(launch_s, default=0)
+    table: list = []
+    for n in sorted(e for e in edges if e <= top):
+        plan = chunk_plan(n, launch_s, serves)
+        if table and table[-1][1] == plan:
+            table.pop()
+        table.append((n, plan))
+    return table
+
+
+def chunk_plan_words(table: list) -> dict:
+    """``{"1025-1280": "1024+256", …}``: the windows of a :func:`plan_table`
+    that run as several launches, for the status JSON (empty where none
+    does)."""
+    words, lo = {}, 1
+    for hi, plan in table:
+        if len(plan) > 1:
+            words[f"{lo}-{hi}"] = "+".join(map(str, plan))
+        lo = hi + 1
+    return words
 
 
 class ShardedVerifyEngine:
@@ -129,7 +218,8 @@ class ShardedVerifyEngine:
     lowered module, makes a restart over unchanged kernels a cache hit
     and a changed kernel a miss. ``warm()`` also launches each shape on
     the all-pad window and times it: ``verify()`` pads a window to the
-    shape :func:`serving_table` gives for the smallest one that fits.
+    shape :func:`serving_table` gives for the smallest one that fits, or
+    runs it as the chunks :func:`chunk_plan` gives where those cost less.
     """
 
     def __init__(
@@ -151,6 +241,7 @@ class ShardedVerifyEngine:
         self._compiled: dict = {}  # padded size -> jax.stages.Compiled
         self._launch_s: dict = {}  # padded size -> seconds, read at warm-up
         self._serves: dict = {}  # smallest fitting size -> size it runs at
+        self._plans: list = []  # plan_table(): [(largest n, shapes run)]
         self.platform: Optional[str] = None
         self.device_kind: Optional[str] = None
         self.devices_seen = 0  # len(jax.devices())
@@ -191,7 +282,8 @@ class ShardedVerifyEngine:
         launch of it costs (``launch_s``, :meth:`_measure`);
         ``cold_compile_s`` sums the shapes that traced+compiled,
         ``warm_load_s`` the shapes the cache answered; ``serving_table`` is
-        :func:`serving_table` of every shape's ``launch_s``.
+        :func:`serving_table` of every shape's ``launch_s`` and ``chunk_plan``
+        the windows that run as several launches (:func:`chunk_plan_words`).
         """
         if self._mesh is None:
             self.init_backend()
@@ -260,17 +352,25 @@ class ShardedVerifyEngine:
                     self._launch_s[size] = launch_s
                     self._compiled[size] = compiled
                     stats["shapes"].append(size)
-                self._serves = serving_table(self._launch_s)
-                # JSON has no integer keys.
-                stats["serving_table"] = {
-                    str(fit): runs for fit, runs in self._serves.items()
-                }
+                stats.update(self._route(self._launch_s))
                 stats["warm_load_s"] = round(stats["warm_load_s"], 3)
                 stats["cold_compile_s"] = round(stats["cold_compile_s"], 3)
                 self.stats = stats
         finally:
             jax.monitoring.unregister_event_listener(on_event)
         return stats
+
+    def _route(self, launch_s: dict) -> dict:
+        """Make the serving table and the chunk plans of what one launch of
+        each shape cost; returns both as ``warm()`` reports them."""
+        self._launch_s = dict(launch_s)
+        self._serves = serving_table(self._launch_s)
+        self._plans = plan_table(self._launch_s, self._serves)
+        return {
+            # JSON has no integer keys.
+            "serving_table": {str(fit): runs for fit, runs in self._serves.items()},
+            "chunk_plan": chunk_plan_words(self._plans),
+        }
 
     # Timed launches a shape at warm-up, after one that is not timed.
     WARM_LAUNCHES = 3
@@ -306,19 +406,28 @@ class ShardedVerifyEngine:
                 )
         return min(took[1:])
 
+    def _plan(self, n: int) -> tuple:
+        """:func:`chunk_plan` of ``n`` items, looked up in the table made at
+        warm-up (nothing before it)."""
+        if not self._plans or n <= 0:
+            return ()
+        top = self._plans[-1][0]
+        whole = (n - 1) // top
+        at = bisect_left(self._plans, (n - whole * top,))
+        return (top,) * whole + self._plans[at][1]
+
     def hold_s(self, n: int) -> float:
         """How long a window of ``n`` items may be held open for more, in
-        seconds: one launch of the shape it would run at, while that shape
-        has room. Below a shape's size an extra item costs the device
-        nothing and a launch of its own costs it ``launch_s``, so company
-        is worth waiting for, but never longer than the launch the wait
-        would save; a window that fills its shape goes at once. 0 before
-        warm-up has timed the shapes."""
-        fit = min((s for s in self._compiled if s >= n), default=None)
-        if fit is None:
+        seconds: one launch of the last shape of its plan, while that shape
+        has room (the chunks before it are full). Below a shape's size an
+        extra item costs the device nothing and a launch of its own costs
+        it ``launch_s``, so company is worth waiting for, but never longer
+        than the launch the wait would save; a window that fills its plan
+        goes at once. 0 before warm-up has timed the shapes."""
+        plan = self._plan(n)
+        if not plan or n >= sum(plan):
             return 0.0
-        run = self._serves.get(fit, fit)
-        return self._launch_s.get(run, 0.0) if n < run else 0.0
+        return self._launch_s.get(plan[-1], 0.0)
 
     def _round_to_mesh(self, size: int) -> int:
         d = max(1, self.device_count)
@@ -339,20 +448,25 @@ class ShardedVerifyEngine:
         The shape is what the serving table gives for the smallest one that
         fits: that one, or a larger one that warm-up measured clearly
         cheaper (``promoted`` counts such chunks; the pad slots verify True
-        and are sliced off, so the verdicts are the same). Oversized batches
-        chunk into top-of-ladder windows — the service never compiles a new
-        shape at runtime. Verdicts are bit-identical to the single-device
-        and CPU paths (pinned in tests/test_parallel and
-        tests/test_service_coalesce).
+        and are sliced off, so the verdicts are the same). Where
+        :func:`chunk_plan` says that a few launches of smaller shapes cost
+        less, and beyond the top of the ladder, the window runs as those
+        chunks (``chunks`` of them, ``split`` 1): every chunk is staged and
+        dispatched before the first verdict is read back, so a chunk's
+        staging hides behind the one before it on the device. The service
+        never compiles a new shape at runtime. Verdicts are bit-identical
+        to the single-device and CPU paths (pinned in tests/test_parallel
+        and tests/test_service_coalesce).
 
         The five steps of every chunk are timed (summed over chunks) into
         the caller's ``utils.trace.current_span()``, where one is open —
         the service's ``verify_batch`` line — and each is a profiler
         annotation (``verifyd.<step>``; free while no trace runs)."""
-        t_in = time.monotonic()
+        mark = time.monotonic()
         if not items:
             return []
-        if not self._compiled:
+        plan = self._plan(len(items))
+        if not plan:
             raise RuntimeError("engine not warmed")
         import numpy as np
         import jax
@@ -360,22 +474,26 @@ class ShardedVerifyEngine:
         from ..crypto.batch import pad_batch
 
         step = jax.profiler.TraceAnnotation
-        top = max(self._compiled)
-        out: List[bool] = []
         secs = dict.fromkeys(self.STEPS, 0.0)
-        rung = promoted = 0
+
+        def took(name: str) -> float:
+            """Charge ``name`` with the time since the step before it."""
+            nonlocal mark
+            now = time.monotonic()
+            secs[name] += now - mark
+            mark = now
+            return now
+
+        promoted = off = 0
         t_dev = None
-        for off in range(0, len(items), top):
-            chunk = items[off : off + top]
-            fit = min(
-                (s for s in self._compiled if s >= len(chunk)), default=top
-            )
-            size = self._serves.get(fit, fit)
-            promoted += size != fit
-            marks = [t_in]  # the chunk's start, then the end of each step
+        flying = []  # a chunk dispatched: (verdicts to come, its items, its buffers)
+        for size in plan:
+            chunk = items[off : off + size]
+            off += size
+            promoted += size != min(s for s in self._compiled if s >= len(chunk))
             with step("verifyd.pad"):
                 pubs, msgs, sigs, n = pad_batch(chunk, size)
-            marks.append(time.monotonic())
+            took("pad_s")
             # Host->device staging is async dispatch; with the service's
             # overlapped launches (inflight=2) window N+1 stages here
             # while window N computes. Donated inputs let XLA reuse the
@@ -384,31 +502,37 @@ class ShardedVerifyEngine:
                 dp = jax.device_put(pubs, self._spec)
                 dm = jax.device_put(msgs, self._spec)
                 ds = jax.device_put(sigs, self._spec)
-            marks.append(time.monotonic())
+            put = took("put_s")
+            if t_dev is None:
+                t_dev = put  # the first dispatch
             with step("verifyd.dispatch"):  # returns once enqueued
-                result = self._compiled[size](dp, dm, ds)
-            marks.append(time.monotonic())
+                flying.append((self._compiled[size](dp, dm, ds), n, (dp, dm, ds)))
+                del dp, dm, ds  # kept by the tuple alone, until its unpack
+            took("dispatch_s")
+        out: List[bool] = []
+        while flying:
+            result, n, staged = flying.pop(0)
             # Behind the other launch in flight, then the device, then the
             # read-back: np.asarray returns when the verdicts are here.
             with step("verifyd.wait"):
                 verdicts = np.asarray(result)
-            marks.append(time.monotonic())
+            took("wait_s")
             with step("verifyd.unpack"):
                 out.extend(bool(v) for v in verdicts[:n])
                 # Dropping the device buffers takes its time too (~0.1 ms):
                 # here, so that it is timed, not at the function's exit.
-                del dp, dm, ds, result, verdicts
-            marks.append(time.monotonic())
-            for name, start, end in zip(self.STEPS, marks, marks[1:]):
-                secs[name] += end - start
-            rung += size
-            if t_dev is None:
-                t_dev = marks[2]  # the first dispatch
-            t_in = marks[-1]
+                del staged, result, verdicts
+            took("unpack_s")
         span = current_span()
         if span is not None:
             span.update({k: round(v, 6) for k, v in secs.items()})
-            span.update(rung=rung, promoted=promoted, t_dev=round(t_dev, 6))
+            span.update(
+                rung=sum(plan),
+                promoted=promoted,
+                chunks=len(plan),
+                split=int(len(plan) > 1),
+                t_dev=round(t_dev, 6),
+            )
         return out
 
     def memory_peak_bytes(self) -> Optional[int]:

@@ -33,8 +33,10 @@ EVENT_SCHEMAS = {
     # steps summed over chunks (pad_s, put_s, dispatch_s, wait_s, unpack_s:
     # they add up to secs), rung (the padded slots the chunks really ran
     # at), promoted (chunks run on a larger shape than the smallest that
-    # fits, by the engine's serving table: 0 or 1 for all but oversized
-    # windows) and t_dev (absolute stamp at the first dispatch).
+    # fits, by the engine's serving table), chunks (executables run for the
+    # window) with the 0/1 field split (more than one: the engine's chunk
+    # plan, or a window beyond the largest shape) and t_dev (absolute stamp
+    # at the first dispatch).
     "verify_batch": {
         "required": {"ts", "ev", "replica", "size", "rejected", "secs"},
         "optional": {
@@ -42,7 +44,7 @@ EVENT_SCHEMAS = {
             "queue_s", "slot_s", "pending_at_cut", "pending_at_launch",
             "hold_s", "held_out", "in_step",
             "pad_s", "put_s", "dispatch_s", "wait_s", "unpack_s", "rung", "promoted",
-            "t_dev",
+            "chunks", "split", "t_dev",
         },
         "emitters": {"server.py", "service.py", "net.cc"},
     },
@@ -394,10 +396,11 @@ VERIFYD_STATUS_KEYS = {
     "warmed_shapes", "backend", "uptime_s", "requests",
     "engine_launches", "engine_items", "fallback_launches", "fallback_items",
     # Launches, without --trace: running totals of every stage, launches the
-    # engine ran on a larger shape than the smallest fit, windows whose hold
-    # ran out / ended early with everybody in step back, launches by the
-    # padded slots run ({"1024": n, ...}), the slowest one.
-    "stage_seconds", "promoted_launches", "held_out_launches",
+    # engine ran on a larger shape than the smallest fit, windows it ran as
+    # several executables, windows whose hold ran out / ended early with
+    # everybody in step back, launches by the padded slots run ({"1024": n,
+    # ...}), the slowest one.
+    "stage_seconds", "promoted_launches", "split_launches", "held_out_launches",
     "in_step_launches", "launches_by_rung", "slowest_launch",
     "memory_peak_bytes", "warm_stats", "warm_error",
 }
@@ -407,6 +410,9 @@ VERIFYD_WARM_STATS_KEYS = {
     # {smallest shape that fits: the shape such a window runs at}, from
     # every shape's launch_s (verify_service.serving_table).
     "serving_table",
+    # {"1025-1280": "1024+256", ...}: the windows that run as several
+    # launches of smaller shapes, and on which (verify_service.chunk_plan).
+    "chunk_plan",
 }
 VERIFYD_PER_SHAPE_KEYS = {
     "size", "seconds", "cache_hit", "devices", "rows_per_device",

@@ -9,7 +9,8 @@ persistent compile cache answered (warm) and which were traced+compiled
 (cold) — what one launch of each shape costs (``launch_s``, read at
 warm-up), the serving table made from those costs (``16→256`` = a window
 that fits 16 slots runs on the 256-slot program) and how many launches it
-promoted, the running total of each launch stage (queue, slot, pad, put,
+promoted, the chunk plan (``1025-1280→1024+256`` = a window of that many
+items runs as two launches) and how many windows it split, the running total of each launch stage (queue, slot, pad, put,
 dispatch, wait, unpack), the launches by shape run and by which exit of
 the hold cut their window, the slowest launch so far with the step that
 held it, and the device's peak memory.
@@ -93,8 +94,14 @@ def main(argv=None) -> int:
             print("  serving table   %s  (%d launches promoted)" % (
                 serving_table_text(table), status.get("promoted_launches", 0),
             ))
+        plan = warm.get("chunk_plan")
+        if plan is not None:
+            print("  chunk plan      %s  (%d launches split)" % (
+                serving_table_text(plan) or "one shape a window",
+                status.get("split_launches", 0),
+            ))
         for k in sorted(warm):
-            if k in ("cold_compile_s", "warm_load_s", "serving_table"):
+            if k in ("cold_compile_s", "warm_load_s", "serving_table", "chunk_plan"):
                 continue
             print(f"  {k:<15} {warm[k]}")
     # Where launches spend their time, for seeing a stall without --trace:
@@ -124,7 +131,7 @@ def main(argv=None) -> int:
     known = {
         "state", "devices", "uptime_s", "warmed_shapes", "warm_stats",
         "stage_seconds", "slowest_launch", "memory_peak_bytes",
-        "promoted_launches", "launches_by_rung", "held_out_launches",
+        "promoted_launches", "split_launches", "launches_by_rung", "held_out_launches",
         "in_step_launches",
     }
     for k in sorted(set(status) - known):

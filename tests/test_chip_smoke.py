@@ -43,6 +43,7 @@ class _NativeEngine:
         "cold_compile_s": 0.0,
         "warm_load_s": 0.0,
         "serving_table": {"16": 16, "64": 64},  # the stage logs it
+        "chunk_plan": {},  # and this
     }
 
     def __init__(self, lie=None):

@@ -184,6 +184,10 @@ def test_persistent_engine_matches_oracle_and_native():
         native_ok = False
     if native_ok:
         assert native.verify_batch(items) == want
+    # And as two chunks (costs injected so that the plan splits: 8 + 8
+    # slots, the boundary between items 7 and 8, both rejected).
+    assert eng._route({8: 0.001, 16: 0.004})["chunk_plan"] == {"9-16": "8+8"}
+    assert eng.verify(items) == want
 
     # Every shape's input sharding spans the whole virtual mesh, and the
     # smallest rung still gives each device at least one row.

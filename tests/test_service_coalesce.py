@@ -1105,16 +1105,68 @@ def test_engine_parity_when_a_window_runs_on_a_larger_shape():
     assert all(p["launch_s"] > 0 for p in stats["per_shape"])
     assert set(stats["serving_table"]) == {"8", "16"}
     sizes = (1, 8, 9, 16, 17)
-    for table, rungs, promoted in (
-        ({}, (8, 8, 16, 16, 16 + 8), (0, 0, 0, 0, 0)),
-        ({8: 16}, (16, 16, 16, 16, 16 + 16), (1, 1, 0, 0, 1)),
+    for costs, rungs, promoted in (
+        ({8: 0.001, 16: 0.001}, (8, 8, 16, 16, 16 + 8), (0, 0, 0, 0, 0)),
+        ({8: 0.001, 16: 0.0001}, (16, 16, 16, 16, 16 + 16), (1, 1, 0, 0, 1)),
     ):
-        eng._serves = table
+        eng._route(costs)
         for n, rung, chunks in zip(sizes, rungs, promoted):
             items = [_item(i + 1, i % 3 != 0) for i in range(n)]
             with open_span() as span:
                 assert eng.verify(items) == [i % 3 != 0 for i in range(n)]
             assert (span["rung"], span["promoted"]) == (rung, chunks)
+            assert (span["chunks"], span["split"]) == ((2, 1) if n == 17 else (1, 0))
+
+
+@pytest.mark.parametrize(
+    "costs, n, plan",
+    [
+        # Injected costs (what a CPU reads decides nothing here): a 32-slot
+        # program that costs four times the 8- and 16-slot ones.
+        ({8: 0.001, 16: 0.001, 32: 0.004}, 17, (16, 8)),
+        ({8: 0.001, 16: 0.001, 32: 0.004}, 24, (16, 8)),
+        ({8: 0.001, 16: 0.001, 32: 0.004}, 25, (16, 16)),
+        ({8: 0.001, 16: 0.001, 32: 0.004}, 32, (16, 16)),
+        # ... and ten times: three chunks are still under it.
+        ({8: 0.001, 32: 0.003, 128: 0.020}, 41, (32, 8, 8)),
+        ({8: 0.001, 32: 0.003, 128: 0.020}, 48, (32, 8, 8)),
+        ({8: 0.001, 16: 0.004, 32: 0.010}, 29, (8, 8, 8, 8)),
+        # Beyond the top: a chunk of it, and the rest by its plan.
+        ({8: 0.001, 16: 0.001, 32: 0.004}, 32 + 20, (32, 16, 8)),
+    ],
+)
+def test_engine_parity_when_a_window_runs_as_chunks(costs, n, plan):
+    """The chunk plan may run a window as several launches of smaller shapes
+    (ISSUE 29): the same verdicts, in item order, as one launch of the
+    smallest shape that fits and as the rule evaluated on the host, with
+    rejects planted on both sides of every chunk boundary."""
+    import itertools
+
+    from pbft_tpu.utils.trace import open_span
+
+    shapes = tuple(costs)
+    eng = ShardedVerifyEngine(shapes=shapes, kernel=_fake_kernel)
+    eng.warm()
+    whole = ShardedVerifyEngine(shapes=shapes, kernel=_fake_kernel)
+    whole.warm()
+    whole._route(dict.fromkeys(shapes, 0.001))  # one launch a window up to the top
+    eng._route(costs)
+    edges = set(itertools.accumulate(plan))
+    for planted in (
+        {e - 1 for e in edges} | set(edges),  # the last of a chunk and the first of the next
+        {e - 1 for e in edges},
+        set(edges),
+        set(),
+        set(range(n)),
+    ):
+        want = [i not in planted for i in range(n)]
+        items = [_item((i % 200) + 1, ok) for i, ok in enumerate(want)]
+        with open_span() as span:
+            got = eng.verify(items)
+        assert got == want == whole.verify(items)
+        assert got == [p[0] == s[0] for p, m, s in items]  # the rule, on the host
+        assert (span["chunks"], span["rung"], span["split"]) == (len(plan), sum(plan), 1)
+    assert eng._plan(n) == plan
 
 
 _WARM_TWICE = """

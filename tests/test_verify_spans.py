@@ -19,7 +19,15 @@ from pathlib import Path
 import pytest
 
 from pbft_tpu.net import ShardedVerifyEngine, VerifierService, VerifyServiceDaemon
-from pbft_tpu.net.verify_service import PROMOTE_MARGIN, serving_table
+from pbft_tpu.net.verify_service import (
+    MAX_CHUNKS,
+    PROMOTE_MARGIN,
+    SPLIT_MARGIN,
+    chunk_plan,
+    chunk_plan_words,
+    plan_table,
+    serving_table,
+)
 from pbft_tpu.utils import trace_schema
 from pbft_tpu.utils.trace import current_span, open_span
 
@@ -156,6 +164,8 @@ def test_engine_steps_sum_to_secs_with_two_launches_in_flight(tmp_path):
         fallback=lambda items: pytest.fail("the fallback ran"),
     ).start(wait_ready=True, timeout=300)
     assert daemon.state_name == "ready"
+    # Equal costs, as the kernel's are, whatever this host's clock read.
+    assert engine._route(dict.fromkeys(shapes, 0.001))["chunk_plan"] == {}
     errors = []
 
     def client(n_items, rounds):
@@ -177,20 +187,44 @@ def test_engine_steps_sum_to_secs_with_two_launches_in_flight(tmp_path):
             assert not t.is_alive()
         crowded = len(_lines(trace))
         client(7, 8)  # and one caller alone: nothing contends for the interpreter
+        whole = len(_lines(trace))
+        # The same again on a device whose 16-slot program costs four times
+        # the 8-slot one (injected: a CPU's own readings decide nothing
+        # here): 12 items run as 8 + 8 slots, both chunks dispatched before
+        # the first is read back, with two launches in flight.
+        assert engine._route({8: 0.001, 16: 0.004})["chunk_plan"] == {"9-16": "8+8"}
+        threads = [threading.Thread(target=client, args=(n, 8)) for n in (5, 12, 5)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+            assert not t.is_alive()
         status = daemon.status_json()
     finally:
         daemon.stop()
     assert not errors, errors
-    lines = _lines(trace)
+    lines, chunked = _lines(trace)[:whole], _lines(trace)[whole:]
     assert sum(e["size"] for e in lines) == 8 * (5 + 12 + 5 + 7)
+    assert sum(e["size"] for e in chunked) == 8 * (5 + 12 + 5)
+    for e in chunked:
+        # 1-8 items: 8 slots; 9-16: 8 + 8; 17-22 (merged): 16 + 8 as before.
+        assert (e["chunks"], e["rung"]) == ((1, 8) if e["size"] <= 8 else (2, 16) if e["size"] <= 16 else (2, 24))
+        assert e["split"] == (e["size"] > 8) and e["promoted"] == 0
+        assert sum(e[k] for k in STEPS) <= e["secs"] + 1e-5
+        assert e["ts"] - e["secs"] - 1e-3 <= e["t_dev"] <= e["ts"]
+    split = [e for e in chunked if e["split"]]
+    assert len(split) >= 8  # every 12-item request, alone or merged
+    gaps = [e["secs"] - sum(e[k] for k in STEPS) for e in split]
+    assert statistics.median(gaps) < max(0.0005, 0.05 * statistics.median(e["secs"] for e in split))
     spans = sorted((e["ts"] - e["secs"], e["ts"]) for e in lines)
     assert any(b_start < a_end for (_, a_end), (b_start, _) in zip(spans, spans[1:])), (
         "no two launches overlapped: the test did not exercise --inflight 2"
     )
     for e in lines:
-        assert set(STEPS) | {"rung", "promoted", "t_dev", "queue_s", "slot_s"} <= set(e)
+        assert set(STEPS) | {"rung", "promoted", "chunks", "split", "t_dev", "queue_s", "slot_s"} <= set(e)
         assert e["rung"] == _rung_of(e["size"], shapes)
         assert e["promoted"] == 0  # a flat-cost kernel: the tie keeps smallest-fit
+        assert (e["chunks"], e["split"]) == ((2, 1) if e["size"] > 16 else (1, 0))
         assert e["ts"] - e["secs"] - 1e-3 <= e["t_dev"] <= e["ts"]
         # The steps lie inside the interval `secs` times, one after another,
         # so they never add up to more than it.
@@ -209,28 +243,31 @@ def test_engine_steps_sum_to_secs_with_two_launches_in_flight(tmp_path):
     totals = status["stage_seconds"]
     assert set(totals) == {"queue_s", "slot_s", *STEPS}
     for k in STEPS:
-        assert totals[k] == pytest.approx(sum(e[k] for e in lines), abs=1e-4)
+        assert totals[k] == pytest.approx(sum(e[k] for e in lines + chunked), abs=1e-4)
     slowest = status["slowest_launch"]
     assert set(slowest) == {"secs", "size", "rung", "stage", "ago_s"}
-    assert slowest["secs"] == pytest.approx(max(e["secs"] for e in lines), abs=1e-3)
+    assert slowest["secs"] == pytest.approx(max(e["secs"] for e in lines + chunked), abs=1e-3)
     assert slowest["stage"] in STEPS and slowest["ago_s"] >= 0
     assert "memory_peak_bytes" in status
     assert status["memory_peak_bytes"] is None or status["memory_peak_bytes"] >= 0
     assert status["promoted_launches"] == 0
+    assert status["split_launches"] == sum(e["split"] for e in lines + chunked)
     assert status["warm_stats"]["serving_table"] == {"8": 8, "16": 16}
 
 
 # -- (b') which executable serves a window: the table, and an engine that promotes
 
 
-V5E_LAUNCH_S = {16: 0.04250, 64: 0.04202, 256: 0.00531, 1024: 0.01347, 4096: 0.060}
+# What warm-up read on the TPU v5e (PR 27's status JSON; 7.1 / 15.8 / 53.3 ms at
+# 256 / 1,024 / 4,096 in PR 28's): one launch through the host, not the
+# device time alone (5.31 / 13.47 / 50.69 ms, PERF.md section 5).
+V5E_LAUNCH_S = {16: 0.04460, 64: 0.04425, 256: 0.00727, 1024: 0.01553, 4096: 0.05314}
 IDENTITY = {s: s for s in V5E_LAUNCH_S}
 
 
 @pytest.mark.parametrize(
     "launch_s, want",
     [
-        # The TPU v5e's five device times (PERF.md section 5).
         (V5E_LAUNCH_S, {16: 256, 64: 256, 256: 256, 1024: 1024, 4096: 4096}),
         # Cost grows with size (a CPU): smallest-fit, whatever the steps.
         ({16: 0.001, 64: 0.004, 256: 0.016, 1024: 0.064, 4096: 0.256}, IDENTITY),
@@ -259,26 +296,132 @@ def test_serving_table(launch_s, want):
     assert list(serving_table(launch_s)) == sorted(launch_s)
 
 
+# -- (b'') a window as chunks of the cheaper shapes (ISSUE 29) --------------------
+
+FLAT = dict.fromkeys(V5E_LAUNCH_S, 0.005)
+LINEAR = {s: s * 1e-5 for s in V5E_LAUNCH_S}
+CHEAP_TOP = {16: 0.03, 64: 0.03, 256: 0.03, 1024: 0.03, 4096: 0.004}
+# Two 8-slot launches against one of 16 slots, at the margin's two sides.
+JUST_INSIDE = {8: 0.010, 16: 2 * 0.010 * SPLIT_MARGIN}
+JUST_PAST = {8: 0.010, 16: 2 * 0.010 * SPLIT_MARGIN + 1e-4}
+
+
+@pytest.mark.parametrize(
+    "launch_s, n, want",
+    [
+        # The TPU v5e: the 4,096-slot program costs 3.4 times the 1,024-slot
+        # one, so a window just above 1,024 items runs on the smaller shapes
+        (V5E_LAUNCH_S, 1025, (1024, 256)),
+        (V5E_LAUNCH_S, 1100, (1024, 256)),
+        (V5E_LAUNCH_S, 1280, (1024, 256)),
+        (V5E_LAUNCH_S, 1281, (1024, 256, 256)),  # 30.1 ms, under two 1,024-slot launches' 31.1
+        (V5E_LAUNCH_S, 1536, (1024, 256, 256)),
+        (V5E_LAUNCH_S, 1537, (1024, 1024)),
+        (V5E_LAUNCH_S, 2048, (1024, 1024)),
+        (V5E_LAUNCH_S, 2049, (1024, 1024, 256)),
+        (V5E_LAUNCH_S, 2304, (1024, 1024, 256)),
+        # ... and stays whole where the chunks come to 1.14x (3 x 1,024) and
+        # 1.07x (2 x 256 against 1,024), inside the margin.
+        (V5E_LAUNCH_S, 2305, (4096,)),
+        (V5E_LAUNCH_S, 3072, (4096,)),
+        (V5E_LAUNCH_S, 4095, (4096,)),
+        (V5E_LAUNCH_S, 257, (1024,)),
+        (V5E_LAUNCH_S, 512, (1024,)),
+        # n = 0, 1, an exact shape: one shape, by the serving table.
+        (V5E_LAUNCH_S, 0, ()),
+        (V5E_LAUNCH_S, 1, (256,)),
+        (V5E_LAUNCH_S, 16, (256,)),
+        (V5E_LAUNCH_S, 256, (256,)),
+        (V5E_LAUNCH_S, 1024, (1024,)),
+        (V5E_LAUNCH_S, 4096, (4096,)),
+        # A chunk goes where the serving table sends it: the 6 items left
+        # over run at 256 slots, never at 16 or 64.
+        (V5E_LAUNCH_S, 1030, (1024, 256)),
+        # Beyond the largest shape: chunks of it, and the rest by the rule.
+        (V5E_LAUNCH_S, 4097, (4096, 256)),
+        (V5E_LAUNCH_S, 5000, (4096, 1024)),
+        (V5E_LAUNCH_S, 4096 + 1100, (4096, 1024, 256)),
+        (V5E_LAUNCH_S, 2 * 4096, (4096, 4096)),
+        (V5E_LAUNCH_S, 2 * 4096 + 1, (4096, 4096, 256)),
+        # The margin's two sides.
+        (JUST_INSIDE, 9, (16,)),
+        (JUST_PAST, 9, (8, 8)),
+        (JUST_PAST, 8, (8,)),
+        (JUST_PAST, 17, (16, 8)),
+        # Flat cost: the identity, at every size.
+        (FLAT, 17, (64,)),
+        (FLAT, 300, (1024,)),
+        (FLAT, 1100, (4096,)),
+        (FLAT, 4097, (4096, 16)),
+        # Cost linear in the slots (pad slots are real work, as on a CPU):
+        # the least slots that cover, in at most four chunks.
+        (LINEAR, 300, (256, 16, 16, 16)),  # 304 slots, not 256 + 64 = 320
+        (LINEAR, 320, (256, 64)),
+        (LINEAR, 1100, (1024, 64, 16)),
+        (LINEAR, 1030, (1024, 16)),
+        (LINEAR, 2000, (1024, 1024)),  # 2,048 slots: 1,024 + 3 x 256 = 1,792 do not cover
+        (LINEAR, 1000, (1024,)),  # 4 x 256 cover it for the same cost: one launch
+        (LINEAR, 900, (1024,)),  # 3 x 256 + 2 x 64 + 16 would be six
+        (LINEAR, 50, (64,)),  # 16 x 4 = 64 slots for the same cost: one launch
+        # A cheap top shape serves everything and never splits.
+        (CHEAP_TOP, 1, (4096,)),
+        (CHEAP_TOP, 1100, (4096,)),
+        (CHEAP_TOP, 4097, (4096, 4096)),
+        ({}, 5, ()),
+    ],
+)
+def test_chunk_plan(launch_s, n, want):
+    serves = serving_table(launch_s)
+    got = chunk_plan(n, launch_s, serves)
+    assert got == want
+    assert sum(got) >= (n if launch_s else 0) and list(got) == sorted(got, reverse=True)
+    assert all(serves[s] == s for s in got)  # every chunk is where the table sends it
+    if launch_s and 0 < n <= max(launch_s):
+        assert len(got) <= MAX_CHUNKS
+        # The table the engine looks a window up in says the same.
+        hi, plan = next(row for row in plan_table(launch_s, serves) if row[0] >= n)
+        assert plan == got
+
+
+def test_chunk_plan_in_words():
+    def words(launch_s):
+        return chunk_plan_words(plan_table(launch_s, serving_table(launch_s)))
+
+    serves = serving_table(V5E_LAUNCH_S)
+    assert words(V5E_LAUNCH_S) == {
+        "1025-1280": "1024+256", "1281-1536": "1024+256+256",
+        "1537-2048": "1024+1024", "2049-2304": "1024+1024+256",
+    }
+    assert [hi for hi, _ in plan_table(V5E_LAUNCH_S, serves)] == [256, 1024, 1280, 1536, 2048, 2304, 4096]
+    assert words(FLAT) == words(CHEAP_TOP) == words(JUST_INSIDE) == words({}) == {}
+    assert words(JUST_PAST) == {"9-16": "8+8"}
+
+
 @pytest.mark.parametrize(
     "n,want",
     [
-        (1, 0.00531),  # fits 16, runs at 256: room for 255 more
-        (50, 0.00531),
-        (255, 0.00531),
+        (1, 0.00727),  # fits 16, runs at 256: room for 255 more
+        (50, 0.00727),
+        (255, 0.00727),
         (256, 0.0),  # fills the shape it runs at: goes at once
-        (257, 0.01347),
+        (257, 0.01553),
         (1024, 0.0),
-        (4095, 0.060),
+        (1025, 0.00727),  # 1,024 + 256 slots: the 256-slot chunk has the room
+        (1280, 0.0),  # fills its plan
+        (1300, 0.00727),  # 1,024 + 256 + 256
+        (1537, 0.01553),  # 1,024 + 1,024
+        (2048, 0.0),
+        (2305, 0.05314),  # one 4,096-slot launch again
+        (4095, 0.05314),
         (4096, 0.0),
-        (5000, 0.0),  # oversized: chunks at the top shape
+        (5000, 0.01553),  # oversized: 4,096 + 1,024 slots, 120 of them free
+        (2 * 4096, 0.0),
     ],
 )
 def test_hold_is_one_launch_of_the_shape_run_while_it_has_room(n, want):
     engine = ShardedVerifyEngine(shapes=tuple(V5E_LAUNCH_S))
     assert engine.hold_s(n) == 0.0  # before warm-up: nothing timed, nothing held
-    engine._compiled = dict.fromkeys(V5E_LAUNCH_S)
-    engine._launch_s = dict(V5E_LAUNCH_S)
-    engine._serves = serving_table(V5E_LAUNCH_S)
+    engine._route(V5E_LAUNCH_S)
     assert engine.hold_s(n) == want
 
 
@@ -305,7 +448,7 @@ def test_engine_serves_small_windows_on_the_cheaper_larger_shape(tmp_path):
     engine = ShardedVerifyEngine(shapes=shapes, kernel=_slow_below_32)
     plain = ShardedVerifyEngine(shapes=shapes, kernel=lambda p, m, s: p[:, 0] == s[:, 0])
     plain.warm()
-    plain._serves = {}  # smallest-fit, whatever its microsecond launches read
+    plain._route(dict.fromkeys(shapes, 0.001))  # smallest-fit, whatever its microsecond launches read
     trace = tmp_path / "verifyd.jsonl"
     daemon = VerifyServiceDaemon(
         backend="auto", engine=engine, trace_path=str(trace),
@@ -335,13 +478,19 @@ def test_engine_serves_small_windows_on_the_cheaper_larger_shape(tmp_path):
     assert [e["promoted"] for e in lines] == [1, 1, 1, 1, 0, 0, 0, 0, 1, 0]
     assert status["promoted_launches"] == 5
     assert status["launches_by_rung"] == {"32": 6, "64": 2, "96": 1, "128": 1}
-    # The hold each window was granted at its cut: one launch of the shape it
-    # runs at while that shape has room; every caller here is alone, so a
-    # granted hold ends at once (in step with nobody) unless the connection
-    # before it was slow to hang up (then it runs out).
-    assert [e["hold_s"] for e in lines] == [costs[32]] * 5 + [0.0, costs[64], 0.0, 0.0, 0.0]
-    assert [e["in_step"] + e["held_out"] for e in lines] == [1] * 5 + [0, 1, 0, 0, 0]
-    assert status["in_step_launches"] + status["held_out_launches"] == 6
+    # The hold each window was granted at its cut: one launch of the last
+    # shape of its plan while that shape has room (the two oversized windows:
+    # the 32-slot chunk with 5 items, the second 64-slot one with 40); every
+    # caller here is alone, so a granted hold ends at once (in step with
+    # nobody) unless the connection before it was slow to hang up (then it
+    # runs out).
+    assert [e["hold_s"] for e in lines] == (
+        [costs[32]] * 5 + [0.0, costs[64], 0.0, costs[32], costs[64]]
+    )
+    assert [e["in_step"] + e["held_out"] for e in lines] == [1] * 5 + [0, 1, 0, 1, 1]
+    assert status["in_step_launches"] + status["held_out_launches"] == 8
+    assert [e["chunks"] for e in lines] == [1] * 8 + [2, 2]
+    assert status["split_launches"] == 2 and status["warm_stats"]["chunk_plan"] == {}
     assert status["warmed_shapes"] == list(shapes)
     assert set(status) <= trace_schema.VERIFYD_STATUS_KEYS
     assert set(status["warm_stats"]) <= trace_schema.VERIFYD_WARM_STATS_KEYS
@@ -349,7 +498,7 @@ def test_engine_serves_small_windows_on_the_cheaper_larger_shape(tmp_path):
         assert set(shape) == trace_schema.VERIFYD_PER_SHAPE_KEYS
         assert shape["launch_s"] == costs[shape["size"]]
     assert status["warm_stats"]["serving_table"] == {"8": 32, "16": 32, "32": 32, "64": 64}
-    assert {"promoted", "hold_s", "held_out", "in_step"} <= (
+    assert {"promoted", "chunks", "split", "hold_s", "held_out", "in_step"} <= (
         trace_schema.EVENT_SCHEMAS["verify_batch"]["optional"]
     )
 
@@ -397,9 +546,11 @@ def test_verify_status_prints_the_serving_table(capsys):
         daemon.stop()
     out = capsys.readouterr().out
     assert "serving table   8→32 32→32  (1 launches promoted)" in out
+    assert "chunk plan      one shape a window  (0 launches split)" in out
     costs = out.split("launch cost     ")[1].splitlines()[0]
     assert [w for w in costs.split() if w.endswith(":")] == ["8:", "32:"] and costs.endswith(" ms")
     assert "promoted_launches" not in out  # printed once, with the table
+    assert "split_launches" not in out and "chunk_plan" not in out
 
 
 def test_verify_status_prints_the_stall_fields(capsys):
@@ -548,6 +699,8 @@ CLOSED_ONLY_METRICS = {
     "hold_ms_mean": ("ms", "verifyd dispatcher"),
     "held_out_share": ("ratio", "verifyd dispatcher"),
     "in_step_share": ("ratio", "verifyd dispatcher", "higher", "program_span"),
+    # PR 29: the share of windows the engine's chunk plan ran as several launches.
+    "split_share": ("ratio", "verifyd engine", "higher", "program_counter"),
 }
 FORMS = {
     ".closed": ("commit_rate", ["f1-sig-wal.closed", "f5-sig-wal.closed"]),
