@@ -37,7 +37,6 @@ class BenchResult:
     verifier: str
     byzantine: bool = False
     pipeline: int = 1  # in-flight requests per nominal client (native arms)
-    service_inflight: int = 1  # overlapped service launches (native-tpu arm)
     # Request batching (ISSUE 4): with batch_max_items > 1 the unit of
     # agreement is a batch, so requests/sec and rounds/sec diverge —
     # mean_batch (requests executed / rounds executed, from the replicas'
@@ -194,10 +193,9 @@ def run_native_config(
     signature corrupted); the honest 2f+1 must carry every round.
 
     ``verifier`` is the daemon's backend selector: "cpu" (in-process C++
-    Ed25519) or a "host:port" / unix-path address of a running
-    VerifierService — pass a jax-backed service to measure the full
-    deployment shape (N daemons -> coalescing service -> one XLA launch
-    per window)."""
+    Ed25519) or a "host:port" / unix-path address of a running verify
+    service — pass a warmed ``verifyd``'s to measure the full deployment
+    shape (N daemons -> coalescing service -> one XLA launch per window)."""
     import re
     import threading
     from pathlib import Path
@@ -243,10 +241,8 @@ def run_native_config(
     ) as cluster:
         f_val = cluster.config.f
         handles = [PbftClient(cluster.config) for _ in range(workers)]
-        # Generous warmup with retransmission: against a jax-backed
-        # verifier service the FIRST window triggers the XLA compile
-        # (tens of seconds to minutes on a cold cache), and the paper's
-        # client retry keeps the round alive through it.
+        # Warmup with retransmission: the paper's client retry keeps the
+        # round alive while the cluster's links come up.
         handles[0].request_with_retry("warmup", timeout=600, retry_every=5)
         t0 = time.perf_counter()
         t0_mono = time.monotonic()  # client stamps are monotonic-clock
@@ -393,25 +389,25 @@ def run_native_tpu_config(
     trace_dir: Optional[str] = None,
     secure: bool = False,
     pipeline: Optional[int] = None,
-    flush_us: int = 0,
-    flush_items: int = 0,
     service_backend: str = "jax",
-    service_inflight: int = 1,
     batch_max_items: int = 1,
     batch_flush_us: int = 0,
 ) -> BenchResult:
-    """run_native_config against one coalescing VerifierService shared by
-    every daemon — the TPU deployment shape (N replicas on one host, one
-    XLA launch per batching window). ``service_backend="native"`` swaps
-    the chip for the C++ batch verifier: same wire path and coalescing,
-    useful for measuring merged-window occupancy on a box without a TPU.
+    """run_native_config against one verify service daemon shared by every
+    pbftd — the TPU deployment shape (N replicas on one host, one XLA
+    launch per batching window), through the same ``VerifyServiceDaemon``
+    as ``verifyd``: with ``service_backend="jax"`` the run starts once the
+    engine has warmed every shape (and fails if it cannot), so no window
+    pays a compile. ``"native"`` swaps the chip for the C++ batch verifier:
+    same wire path and coalescing, useful for measuring merged-window
+    occupancy on a box without a TPU.
 
     The service's own per-dispatch trace (the honest items-per-LAUNCH
     measurement — per-replica traces only see each daemon's share of a
     merged window) lands in <trace_dir>-service/service.jsonl."""
     import os
 
-    from ..net import VerifierService
+    from ..net import VerifyServiceDaemon
 
     service_trace = None
     if trace_dir:
@@ -420,15 +416,16 @@ def run_native_tpu_config(
         service_trace = os.path.join(service_trace_dir, "service.jsonl")
         if os.path.exists(service_trace):
             os.unlink(service_trace)  # append mode; stale events corrupt
-    service = VerifierService(
-        backend=service_backend,
-        flush_us=flush_us,
-        flush_items=flush_items,
-        trace_path=service_trace,
-        inflight=service_inflight,
-    ).start()
+    service = VerifyServiceDaemon(
+        backend=service_backend, trace_path=service_trace
+    ).start(wait_ready=True)
     try:
-        res = run_native_config(
+        if service_backend == "jax" and service.state_name != "ready":
+            raise RuntimeError(
+                f"verify service is {service.state_name}, not ready: "
+                f"{service.fatal_error or 'still warming'}"
+            )
+        return run_native_config(
             index,
             requests=requests,
             verifier=service.address,
@@ -440,10 +437,6 @@ def run_native_tpu_config(
             batch_max_items=batch_max_items,
             batch_flush_us=batch_flush_us,
         )
-        # Recorded in the artifact: rows captured at different overlap
-        # settings must never be compared as like-for-like.
-        res.service_inflight = service_inflight
-        return res
     finally:
         service.stop()
 
@@ -456,8 +449,8 @@ def main() -> None:
         "--arm",
         default="cpu",
         choices=["cpu", "jax", "native", "native-tpu"],
-        help="native-tpu = real pbftd daemons -> coalescing jax-backed "
-        "VerifierService (the TPU deployment shape)",
+        help="native-tpu = real pbftd daemons -> one warmed verify service "
+        "daemon (the TPU deployment shape)",
     )
     parser.add_argument("--config", type=int, default=None, help="0-4; default all")
     parser.add_argument("--requests", type=int, default=None)
@@ -485,14 +478,14 @@ def main() -> None:
         "--flush-us",
         type=int,
         default=0,
-        help="bounded verify accumulation window, microseconds (native arm: "
-        "per-daemon via network.json; native-tpu arm: at the service)",
+        help="bounded verify accumulation window of each replica, "
+        "microseconds (native arm, via network.json)",
     )
     parser.add_argument(
         "--flush-items",
         type=int,
         default=0,
-        help="flush early once this many items are pending (0 = pad/window cap)",
+        help="...flushed early once this many items are pending (0 = batch pad)",
     )
     parser.add_argument(
         "--batch-max-items",
@@ -511,15 +504,8 @@ def main() -> None:
         "--service-backend",
         default="jax",
         choices=["jax", "cpu", "native"],
-        help="native-tpu arm: the VerifierService backend (native = C++ "
+        help="native-tpu arm: the verify service's backend (native = C++ "
         "batch verifier, for occupancy runs without a chip)",
-    )
-    parser.add_argument(
-        "--service-inflight",
-        type=int,
-        default=1,
-        help="native-tpu arm: overlapped service launches (ship window "
-        "N+1 while N executes; 1 = serial)",
     )
     args = parser.parse_args()
     if args.config is not None:
@@ -531,10 +517,7 @@ def main() -> None:
                     trace_dir=args.trace_dir,
                     secure=args.secure,
                     pipeline=args.pipeline,
-                    flush_us=args.flush_us,
-                    flush_items=args.flush_items,
                     service_backend=args.service_backend,
-                    service_inflight=args.service_inflight,
                     batch_max_items=args.batch_max_items,
                     batch_flush_us=args.batch_flush_us,
                 ).to_json()

@@ -52,11 +52,11 @@ def cpu_verifier(items: List[Tuple[bytes, bytes, bytes]]) -> List[bool]:
 
 
 def jax_verifier(items: List[Tuple[bytes, bytes, bytes]]) -> List[bool]:
-    """The batched XLA verifier (lazy import keeps sims jax-free on cpu
-    arm); auto-shards over a multi-device host like the serving paths."""
-    from ..parallel import verify_many_auto
+    """The batched XLA verifier, in-process on one device (lazy import
+    keeps sims jax-free on the cpu arm)."""
+    from ..crypto.batch import verify_many
 
-    return verify_many_auto(items)
+    return verify_many(items)
 
 
 @dataclasses.dataclass(frozen=True)

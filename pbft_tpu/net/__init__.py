@@ -1,14 +1,18 @@
 """pbft_tpu.net — the host-side runtime glue around the native daemon.
 
-- ``server``    — the asyncio replica runtime (in-process JAX verifier).
-- ``service``   — the JAX/TPU verifier service: the socket server the C++
-  ``pbftd`` ships signature batches to (core/verifier.h RemoteVerifier);
-  one vmap'd XLA launch per batch, coalesced across daemons.
-- ``verify_service`` — the persistent multi-chip daemon around it: owns
-  the accelerator, AOT-warms every pad-ladder window shape at startup,
-  answers the readiness handshake, and shards each merged window across
-  all local devices; plus the replica-side ``ServiceVerifier`` client
-  (short connect deadline, native-pool fallback).
+- ``server``    — the asyncio replica runtime (in-process verifier, or a
+  ``ServiceVerifier`` dialing ``verifyd``).
+- ``service``   — the verify service's wire protocol (the 128-byte-triple
+  batches ``pbftd``'s RemoteVerifier ships, core/verifier.h, and the two
+  status probes) and its dispatcher, which merges what every connection
+  queued into one backend call. It knows no shape and no device; a bare
+  ``VerifierService`` serves a host verifier and says ``cpu-only``.
+- ``verify_service`` — the engine, the one way a served window reaches
+  the chip (owns the accelerator, AOT-warms and times every pad-ladder
+  shape, shards each window across all local devices); ``verifyd``, the
+  one daemon, which joins engine and dispatcher and alone answers
+  ``ready``; plus the replica-side ``ServiceVerifier`` client (short
+  connect deadline, native-pool fallback).
 - ``secure``    — encrypted replica links + protocol versioning
   (signed-ephemeral-DH handshake, keyed-BLAKE2b AEAD; mirror of
   core/secure.cc — the reference's Noise-secured development_transport,

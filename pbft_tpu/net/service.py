@@ -1,23 +1,25 @@
-"""The JAX/TPU verifier service: the FFI boundary between the native replica
-runtime and the XLA crypto hot path (SURVEY.md §5 "Distributed communication
-backend": consensus-critical small messages stay on the host network; only
-signature *batches* cross into the JAX process).
+"""The verify service's wire protocol and its dispatcher: the FFI boundary
+between the replica runtimes and whoever verifies (SURVEY.md §5 "Distributed
+communication backend": consensus-critical small messages stay on the host
+network; only signature *batches* cross into the verifier's process).
 
 Protocol (mirrors core/verifier.h RemoteVerifier):
     request:  u32be count N, then N * 128 bytes (pub 32 | msg 32 | sig 64)
     response: N bytes, each 0/1
-
-Batches are padded to the next power of two (bounded set of compiled
-shapes); pad slots carry a known-good triple so padding cost is pure
-compute, never a false reject.
+    N = 0 / 0xFFFFFFFF: the two status probes (below)
 
 Cross-connection coalescing: when several colocated daemons (one per
-replica on a TPU host) submit batches concurrently, a dispatcher merges
-everything queued into ONE backend call — one XLA launch for the whole
-host's quorum traffic instead of one per daemon. The launch cost is paid
-once per *window*, which is the framework's batching-window thesis applied
-at the FFI boundary. No artificial delay: the window is exactly "whatever
-queued while the previous launch ran".
+replica on a TPU host) submit batches concurrently, the dispatcher merges
+everything queued into ONE backend call — one launch for the whole host's
+quorum traffic instead of one per daemon. A window is "whatever queued
+while the previous launch ran", held open for company only as long as
+``hold_s`` grants (a rule the owner of the backend sets from what a launch
+costs it; a bare service has none and cuts at once).
+
+This module knows no shape, no device and no kernel: ``backend`` is a
+callable on a list of items. The accelerator, its window shapes, their
+costs and the daemon (``verifyd``) that joins them to this dispatcher
+live in ``verify_service.py``, and only that daemon ever answers ``ready``.
 """
 
 from __future__ import annotations
@@ -66,6 +68,14 @@ STATE_NAMES = {
 # longer than a replica's connect deadline (PBFT_VERIFY_CONNECT_MS, 250).
 LISTEN_QUEUE = 128
 
+# Launch slots the daemon gives its dispatcher (``inflight``): with two,
+# window N+1 is staged and dispatched from a second launch thread while
+# window N computes, which hides the host's share of a launch behind the
+# device's. Every cell of the benchmark runs at 2 and none has measured
+# another value; a bare service defaults to 1 (one launch at a time: what
+# tests that count windows need).
+DAEMON_INFLIGHT = 2
+
 
 def pack_status(state: int, devices: int, warmed: int) -> bytes:
     """8 bytes: 'V' 'S' version state u16be devices u16be warmed-shapes."""
@@ -100,25 +110,18 @@ def _recv_exact(sock: socket.socket, n: int) -> bytes:
     return bytes(buf)
 
 
-def jax_backend(items: List[Item]) -> List[bool]:
-    # Single- or multi-chip is decided in one place (sharded over LOCAL
-    # devices when several; tests/test_parallel.py pins equivalence).
-    from ..parallel import verify_many_auto
-
-    return verify_many_auto(items)
-
-
 def cpu_backend(items: List[Item]) -> List[bool]:
-    from ..crypto import ref
+    """The host oracle, one item at a time (the simulator's control arm)."""
+    from ..consensus.simulation import cpu_verifier
 
-    return [ref.verify(p, m, s) for p, m, s in items]
+    return cpu_verifier(items)
 
 
 def native_backend(items: List[Item]) -> List[bool]:
     """The C++ batch verifier (core/ed25519.cc via ctypes): one fast host
-    verifier process serving every colocated daemon — the CPU-deployment
-    analogue of the jax backend, and the realistic control arm for
-    measuring coalesced window occupancy on a box without a chip."""
+    verifier process serving every colocated daemon — the chip-less
+    deployment, and the realistic control arm for measuring coalesced
+    window occupancy on a box without a chip."""
     from .. import native
 
     return [bool(v) for v in native.verify_batch(items)]
@@ -144,10 +147,7 @@ class VerifierService:
         host: str = "127.0.0.1",
         port: int = 0,
         unix_path: Optional[str] = None,
-        backend: Callable[[List[Item]], List[bool]] | str = "jax",
-        coalesce: bool = True,
-        flush_us: int = 0,
-        flush_items: int = 0,
+        backend: Callable[[List[Item]], List[bool]] | str = "native",
         trace_path: Optional[str] = None,
         inflight: int = 1,
         metrics_port: Optional[int] = None,
@@ -156,25 +156,13 @@ class VerifierService:
     ):
         backend_name = backend if isinstance(backend, str) else None
         if isinstance(backend, str):
-            backend = {
-                "jax": jax_backend,
-                "cpu": cpu_backend,
-                "native": native_backend,
-            }[backend]
+            backend = {"cpu": cpu_backend, "native": native_backend}[backend]
         self.backend = backend
         # Readiness handshake (verify_service.py): a bare VerifierService
-        # has no warmup lifecycle, so the default status is settled at
-        # construction — "ready" for the jax backend (it warms lazily on
-        # first traffic, the pre-daemon behavior), "cpu-only" for
-        # everything else (incl. test callables). The daemon overrides
+        # owns no accelerator and has no warm-up, so whatever its backend
+        # is, it says "cpu-only" and never "ready". The daemon overrides
         # both providers with its live state machine.
-        self._status_provider = status_provider or (
-            lambda: (
-                STATE_READY if backend_name == "jax" else STATE_CPU_ONLY,
-                0,
-                0,
-            )
-        )
+        self._status_provider = status_provider or (lambda: (STATE_CPU_ONLY, 0, 0))
         self._status_json_provider = status_json_provider or (
             lambda: {
                 "state": STATE_NAMES[self._status_provider()[0]],
@@ -185,23 +173,17 @@ class VerifierService:
                 "items": self.items,
             }
         )
-        # Bounded accumulation (the service-side analogue of the replicas'
-        # verify_flush_us): after the first request queues, the dispatcher
-        # waits until flush_items are pending (0 = MAX_WINDOW) or flush_us
-        # have passed, trading that much latency for a fatter merged
-        # window. 0 = dispatch as soon as the previous launch returns.
-        self._flush_s = flush_us / 1e6
-        self._flush_target = flush_items or self.MAX_WINDOW
-        # Hold for company (no flush_us given): ``hold_s(items)`` says how
+        # The window policy, hold for company: ``hold_s(items)`` says how
         # long a window of that many items may stay open for more, counted
         # from its oldest request's arrival; 0 cuts at once. None of a bare
-        # service's backends has an opinion; the daemon sets its engine's
-        # (one launch time of the shape the window would run at, while that
-        # shape has room: ``ShardedVerifyEngine.hold_s``). The hold ends
-        # early once nobody in step is still out: no launch is in flight
-        # and every connection the last one answered has its next request
-        # in the window (replicas keep one batch in flight each and come
-        # back together; a caller alone never waits for anybody).
+        # service's backends has an opinion (every window is cut at once);
+        # the daemon sets its engine's (one launch time of the shape the
+        # window would run at, while that shape has room:
+        # ``ShardedVerifyEngine.hold_s``). The hold ends early once nobody
+        # in step is still out: no launch is in flight and every connection
+        # the last one answered has its next request in the window (replicas
+        # keep one batch in flight each and come back together; a caller
+        # alone never waits for anybody).
         self.hold_s: Optional[Callable[[int], float]] = None
         self._flying = 0  # windows cut and not yet answered
         self._answered: set = set()  # connections the last launch answered
@@ -255,7 +237,6 @@ class VerifierService:
         self.in_step_launches = 0
         self.launches_by_rung: dict = {}
         self._slowest: Optional[dict] = None
-        self._coalesce = coalesce
         self._cond = threading.Condition()
         self._pending: List[_Pending] = []
         self._running = True
@@ -322,18 +303,14 @@ class VerifierService:
             self.server = TcpServer((host, port), Handler)
             self.address = "%s:%d" % self.server.server_address
         self._thread: Optional[threading.Thread] = None
-        self._dispatcher: Optional[threading.Thread] = None
-        if self._coalesce:
-            # Started here (not in start()) so the CLI's bare
-            # serve_forever() path coalesces too.
-            self._dispatcher = threading.Thread(
-                target=self._dispatch_loop, daemon=True
-            )
-            self._dispatcher.start()
+        self._dispatcher = threading.Thread(target=self._dispatch_loop, daemon=True)
+        self._dispatcher.start()
 
-    # Largest merged window, in items: the top of the pad ladder
-    # (crypto/batch.py _PAD_LADDER) — bigger merges would compile new XLA
-    # shapes at runtime. Overflow stays queued for the next window.
+    # Largest merged window, in items; overflow stays queued for the next
+    # window. A bound on one launch's latency and staging memory, set to the
+    # top of the pad ladder (crypto/batch.py _PAD_LADDER; the lint in
+    # analysis/constants.py holds the two equal) so that a full window is
+    # one launch of the largest shape. A constant: no option sets it.
     MAX_WINDOW = 4096
 
     def _gone(self, conn) -> None:
@@ -352,35 +329,6 @@ class VerifierService:
     def _submit(self, items: List[Item], conn=None) -> List[bool]:
         """Handler-thread entry: verify `items`, possibly merged with other
         connections' concurrent submissions into one backend call."""
-        if not self._coalesce:
-            with self._cond:
-                self.requests += 1
-                self.batches += 1
-                self.items += len(items)
-            t0 = time.monotonic()
-            verdicts = self._checked(self.backend, items)
-            if self.metrics_registry.enabled:
-                self.metrics_registry.counter("pbft_verify_batches_total").inc()
-                self.metrics_registry.counter("pbft_verify_items_total").inc(len(items))
-                self.metrics_registry.counter("pbft_verify_rejected_total").inc(
-                    verdicts.count(False)
-                )
-                self.metrics_registry.histogram("pbft_verify_batch_size").observe(len(items))
-                self.metrics_registry.histogram("pbft_verify_seconds").observe(
-                    time.monotonic() - t0
-                )
-                # Service-surface mirror (ISSUE 7): uncoalesced, every
-                # request is its own single-client launch window.
-                self.metrics_registry.counter(
-                    "pbft_verify_service_launches_total"
-                ).inc()
-                self.metrics_registry.histogram(
-                    "pbft_verify_service_window_size"
-                ).observe(len(items))
-                self.metrics_registry.histogram(
-                    "pbft_verify_service_coalesced_clients"
-                ).observe(1)
-            return verdicts
         p = _Pending(items, conn)
         with self._cond:
             self.requests += 1
@@ -388,10 +336,10 @@ class VerifierService:
                 raise ConnectionError("verifier service stopping")
             self._pending.append(p)
             self._cond.notify()
-        # No fixed deadline (a first XLA compile can legitimately take
-        # minutes), but a dead dispatcher must not strand the connection.
+        # No fixed deadline (the backend's time is its own), but a dead
+        # dispatcher must not strand the connection.
         while not p.event.wait(timeout=1.0):
-            if self._dispatcher is not None and not self._dispatcher.is_alive():
+            if not self._dispatcher.is_alive():
                 raise ConnectionError("verifier dispatcher died")
         if p.error is not None:
             raise ConnectionError(f"verification failed: {p.error!r}")
@@ -409,21 +357,7 @@ class VerifierService:
                 # its exits cut it (neither: it filled its shape, or no
                 # hold applies).
                 hold, held_out, in_step = 0.0, 0, 0
-                if self._flush_s > 0:
-                    # Bounded accumulation: hold the window open until the
-                    # item target or the deadline. _cond.wait releases the
-                    # lock, so handler threads keep enqueueing meanwhile.
-                    deadline = time.monotonic() + self._flush_s
-                    while (
-                        self._running
-                        and sum(len(p.items) for p in self._pending)
-                        < self._flush_target
-                    ):
-                        remaining = deadline - time.monotonic()
-                        if remaining <= 0:
-                            break
-                        self._cond.wait(remaining)
-                elif self.hold_s is not None:
+                if self.hold_s is not None:
                     while self._running:
                         hold = self.hold_s(self._pending_items())
                         remaining = (
@@ -594,7 +528,7 @@ class VerifierService:
                 **({**waits, **span} if verdicts is not None else {}),
             )
         with self._cond:
-            # Under the lock: with --inflight > 1 several launch threads
+            # Under the lock: with inflight > 1 several launch threads
             # finish concurrently (the replica runtimes' single-writer
             # discipline doesn't hold here).
             self.batches += 1
@@ -682,79 +616,17 @@ class VerifierService:
         self.server.server_close()
         if self._thread:
             self._thread.join(timeout=5)
-        if self._dispatcher:
-            self._dispatcher.join(timeout=5)
+        self._dispatcher.join(timeout=5)
         with self._cond:
             launch_threads = list(self._launch_threads)
         for t in launch_threads:
             t.join(timeout=5)
-        if self._tracer.sink is not None and (
-            (self._dispatcher is None or not self._dispatcher.is_alive())
-            and not any(t.is_alive() for t in launch_threads)
+        if self._tracer.sink is not None and not (
+            self._dispatcher.is_alive() or any(t.is_alive() for t in launch_threads)
         ):
             # Only close once the dispatcher is provably done with it: a
-            # join timeout (e.g. a minutes-long first XLA compile still in
-            # flight) must leak the fd rather than turn that window's
+            # join timeout (a launch still in flight) must leak the fd
+            # rather than turn that window's
             # successful verifications into I/O errors mid-write.
             self._tracer.sink.close()
             self._tracer = type(self._tracer)()  # disabled from here on
-
-
-def main() -> None:
-    """CLI: run the service for a pbftd cluster (TPU by default)."""
-    import argparse
-
-    parser = argparse.ArgumentParser(description=__doc__)
-    parser.add_argument("--host", default="127.0.0.1")
-    parser.add_argument("--port", type=int, default=7600)
-    parser.add_argument("--unix", default=None)
-    parser.add_argument(
-        "--backend", default="jax", choices=["jax", "cpu", "native"]
-    )
-    parser.add_argument(
-        "--flush-us",
-        type=int,
-        default=0,
-        help="bounded accumulation: hold each window up to this many "
-        "microseconds (0 = dispatch immediately)",
-    )
-    parser.add_argument(
-        "--flush-items",
-        type=int,
-        default=0,
-        help="...or until this many items are pending (0 = MAX_WINDOW)",
-    )
-    parser.add_argument(
-        "--trace", default=None, help="JSONL per-dispatch trace file"
-    )
-    parser.add_argument(
-        "--inflight",
-        type=int,
-        default=1,
-        help="overlapped launches: ship window N+1 while N executes "
-        "(hides host-side launch overhead; 1 = serial)",
-    )
-    parser.add_argument(
-        "--metrics-port",
-        type=int,
-        default=None,
-        help="serve Prometheus text format on this port (0 = ephemeral)",
-    )
-    args = parser.parse_args()
-    svc = VerifierService(
-        host=args.host,
-        port=args.port,
-        unix_path=args.unix,
-        backend=args.backend,
-        flush_us=args.flush_us,
-        flush_items=args.flush_items,
-        trace_path=args.trace,
-        inflight=args.inflight,
-        metrics_port=args.metrics_port,
-    )
-    print(f"verifier service on {svc.address} backend={args.backend}", flush=True)
-    svc.server.serve_forever()
-
-
-if __name__ == "__main__":
-    main()

@@ -1,5 +1,8 @@
 """The persistent multi-chip verify service: own the accelerator, pay
-compile once, shard every window.
+compile once, shard every window. This module holds the engine (the one
+way a served window reaches the chip), the daemon that joins it to
+``service.py``'s dispatcher, the replica-side client and the ``verifyd``
+CLI: the one entry point of the one daemon.
 
 One long-lived process per host initializes the JAX backend ONCE,
 AOT-compiles the sharded verify kernel for every fixed `_PAD_LADDER`
@@ -46,10 +49,10 @@ replicas' batches that arrive a few ms apart share a launch.
 Host↔device pipeline: every window is staged with an async
 ``jax.device_put`` against the batch sharding and launched through a
 precompiled executable with DONATED input buffers (XLA reuses the device
-memory window over window). With the dispatcher's ``inflight=2`` default
-the service ships window N+1 from a second launch thread while window N
-computes — the double-buffered transfer/compute overlap, with verdict
-slicing per connection untouched.
+memory window over window). With the two launch slots the daemon gives its
+dispatcher (``service.DAEMON_INFLIGHT``) the service ships window N+1 from
+a second launch thread while window N computes — the double-buffered
+transfer/compute overlap, with verdict slicing per connection untouched.
 """
 
 from __future__ import annotations
@@ -69,6 +72,7 @@ from typing import Callable, List, Optional, Sequence, Tuple
 # unpack_status) lives in service.py next to the protocol handler;
 # re-exported here as the deployment-facing surface.
 from .service import (  # noqa: F401 - re-exported API
+    DAEMON_INFLIGHT,
     Item,
     STATE_CPU_ONLY,
     STATE_NAMES,
@@ -495,7 +499,7 @@ class ShardedVerifyEngine:
                 pubs, msgs, sigs, n = pad_batch(chunk, size)
             took("pad_s")
             # Host->device staging is async dispatch; with the service's
-            # overlapped launches (inflight=2) window N+1 stages here
+            # overlapped launches (DAEMON_INFLIGHT) window N+1 stages here
             # while window N computes. Donated inputs let XLA reuse the
             # same device memory for every window of this shape.
             with step("verifyd.put"):
@@ -576,11 +580,6 @@ class VerifyServiceDaemon:
         unix_path: Optional[str] = None,
         backend: str = "auto",
         devices: Optional[int] = None,
-        warm_shapes: Optional[Sequence[int]] = None,
-        max_window: Optional[int] = None,
-        flush_us: int = 0,
-        flush_items: int = 0,
-        inflight: int = 2,
         trace_path: Optional[str] = None,
         metrics_port: Optional[int] = None,
         engine: Optional[ShardedVerifyEngine] = None,
@@ -602,9 +601,7 @@ class VerifyServiceDaemon:
         self.fallback_items = 0
         self.engine = engine
         if engine is None and backend in ("auto", "jax"):
-            self.engine = ShardedVerifyEngine(
-                shapes=warm_shapes, devices=devices
-            )
+            self.engine = ShardedVerifyEngine(devices=devices)
         if fallback is None:
             if backend == "cpu":
                 from .service import cpu_backend as fallback
@@ -616,16 +613,12 @@ class VerifyServiceDaemon:
             port=port,
             unix_path=unix_path,
             backend=self._dispatch,
-            flush_us=flush_us,
-            flush_items=flush_items,
             trace_path=trace_path,
-            inflight=inflight,
+            inflight=DAEMON_INFLIGHT,
             metrics_port=metrics_port,
             status_provider=self._status,
             status_json_provider=self.status_json,
         )
-        if max_window:
-            self.service.MAX_WINDOW = max_window
         if self.service.metrics_registry.enabled:
             # The warm/cold compile gauges exist from the first scrape
             # (service.py's preregister only covers its own emitter set).
@@ -1030,27 +1023,6 @@ def main(
         default=None,
         help="shard windows over this many local devices (default: all)",
     )
-    parser.add_argument(
-        "--warm-shapes",
-        default=None,
-        help="comma-separated window sizes to precompile (default: the "
-        "crypto pad ladder 16,64,256,1024,4096)",
-    )
-    parser.add_argument(
-        "--window",
-        type=int,
-        default=None,
-        help="largest merged window in items (default: top of the ladder)",
-    )
-    parser.add_argument("--flush-us", type=int, default=0)
-    parser.add_argument("--flush-items", type=int, default=0)
-    parser.add_argument(
-        "--inflight",
-        type=int,
-        default=2,
-        help="overlapped launches; 2 = double-buffer window N+1's "
-        "host->device transfer behind window N's compute",
-    )
     parser.add_argument("--trace", default=None)
     parser.add_argument("--metrics-port", type=int, default=None)
     parser.add_argument(
@@ -1060,22 +1032,12 @@ def main(
         "on stdout (the socket still answers status probes meanwhile)",
     )
     args = parser.parse_args(argv)
-    shapes = (
-        [int(s) for s in args.warm_shapes.split(",") if s]
-        if args.warm_shapes
-        else None
-    )
     daemon = VerifyServiceDaemon(
         host=args.host,
         port=args.port,
         unix_path=args.unix,
         backend=args.backend,
         devices=args.devices,
-        warm_shapes=shapes,
-        max_window=args.window,
-        flush_us=args.flush_us,
-        flush_items=args.flush_items,
-        inflight=args.inflight,
         trace_path=args.trace,
         metrics_port=args.metrics_port,
         engine=engine,

@@ -15,8 +15,6 @@ from .verifier import (
     compile_sharded,
     make_mesh,
     sharded_verify,
-    verify_many_auto,
-    verify_many_sharded,
     quorum_certify,
     round_step,
 )
@@ -33,8 +31,6 @@ __all__ = [
     "compile_sharded",
     "make_mesh",
     "sharded_verify",
-    "verify_many_auto",
-    "verify_many_sharded",
     "quorum_certify",
     "round_step",
     "global_mesh",

@@ -25,7 +25,6 @@ off), so changing batch occupancy never recompiles.
 from __future__ import annotations
 
 import dataclasses
-import threading
 from typing import Optional, Sequence
 
 import numpy as np
@@ -110,55 +109,6 @@ def compile_sharded(
         jax.ShapeDtypeStruct((size, 32), jnp.uint8, sharding=spec),
         jax.ShapeDtypeStruct((size, 64), jnp.uint8, sharding=spec),
     ).compile()
-
-
-# One compiled sharded verifier per process: the serving path below is
-# called per batching window, and rebuilding the jit per call would
-# retrace every window. Lock guards the lazy init — handler threads
-# (service.py) and executor threads (server.py) may race the first call.
-_SERVING = None  # (mesh, fn)
-_SERVING_LOCK = threading.Lock()
-
-
-def verify_many_sharded(items, pad_to: Optional[int] = None):
-    """Host serving API: list of (pub32, msg32, sig64) byte triples ->
-    list[bool], the padded batch sharded over this host's LOCAL devices
-    (multi-host slices shard per-process; the global-mesh path needs
-    make_array_from_process_local_data — see module docstring).
-
-    The multi-chip deployment path for the verifier service / asyncio
-    runtime: same call shape as crypto.batch.verify_many — and the same
-    body, via its ``launch`` hook — but the single XLA launch is
-    data-parallel across the mesh. NOTE: an explicit ``pad_to`` is
-    rounded UP to the nearest multiple of the local device count when not
-    already divisible. Verdicts are identical to the single-device path
-    (tests/test_parallel.py pins equivalence).
-    """
-    from ..crypto import batch as _batch
-
-    if not items:
-        return []
-    global _SERVING
-    with _SERVING_LOCK:
-        if _SERVING is None:
-            mesh = make_mesh(devices=jax.local_devices())
-            _SERVING = (mesh, sharded_verify(mesh))
-        mesh, fn = _SERVING
-    return _batch.verify_many(
-        items, pad_to=pad_to, launch=fn, size_multiple=mesh.devices.size
-    )
-
-
-def verify_many_auto(items, pad_to: Optional[int] = None):
-    """The serving-path selector: mesh-sharded over this host's local
-    devices when there are several, the plain single-device launch
-    otherwise. Every jax-arm consumer (verifier service, asyncio runtime,
-    simulation) routes here so the deployment choice lives in one place."""
-    if jax.local_device_count() > 1:
-        return verify_many_sharded(items, pad_to=pad_to)
-    from ..crypto import batch as _batch
-
-    return _batch.verify_many(items, pad_to=pad_to)
 
 
 @jax.tree_util.register_dataclass

@@ -44,34 +44,6 @@ def test_sharded_verify_matches_oracle():
     assert out.tolist() == expect
 
 
-def test_verify_many_sharded_serving_path():
-    """The host serving API (used by the verifier service / asyncio
-    runtime on multi-device hosts): same verdicts as the single-device
-    verify_many, including mixed validity, odd batch sizes (padded to a
-    mesh-divisible shape), and the empty batch."""
-    from pbft_tpu.parallel import verify_many_sharded
-
-    items = _signed_items(11, bad={2, 7})
-    out = verify_many_sharded(items)
-    assert out == [i not in {2, 7} for i in range(11)]
-    assert verify_many_sharded([]) == []
-    # Second call reuses the compiled mesh fn (no retrace): same verdicts.
-    assert verify_many_sharded(items[:5]) == [i not in {2} for i in range(5)]
-
-
-def test_verify_many_auto_selects_and_agrees(monkeypatch):
-    """The serving-path selector: sharded on this 8-device mesh, and the
-    single-device fallback (never reached naturally under conftest's
-    virtual mesh) produces identical verdicts when forced."""
-    from pbft_tpu.parallel import verifier as V
-
-    items = _signed_items(9, bad={4})
-    expect = [i != 4 for i in range(9)]
-    assert V.verify_many_auto(items) == expect  # sharded branch
-    monkeypatch.setattr(jax, "local_device_count", lambda: 1)
-    assert V.verify_many_auto(items) == expect  # single-device fallback
-
-
 def test_quorum_certify_counts_and_thresholds():
     mesh = make_mesh(8)
     R = 4

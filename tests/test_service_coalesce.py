@@ -95,19 +95,6 @@ def test_concurrent_requests_coalesce_into_fewer_launches():
         svc.stop()
 
 
-def test_uncoalesced_mode_still_works():
-    def backend(items):
-        return [p[0] == s[0] for p, m, s in items]
-
-    svc = VerifierService(backend=backend, coalesce=False).start()
-    try:
-        out = _send_batch(svc.address, [_item(7, True), _item(9, False)])
-        assert out == [True, False]
-        assert svc.batches == svc.requests == 1
-    finally:
-        svc.stop()
-
-
 def test_poison_batch_only_fails_its_own_connection(tmp_path):
     """A backend failure on a merged launch must not false-reject other
     clients' honest signatures: the window is retried per-request and only
@@ -195,18 +182,6 @@ def test_wrong_length_verdicts_fail_loudly():
     finally:
         svc.stop()
 
-    # Same contract without coalescing (the handler-thread direct path).
-    svc2 = VerifierService(backend=backend, coalesce=False).start()
-    try:
-        try:
-            out2 = _send_batch(svc2.address, [_item(3, True), _item(4, True)])
-            raised2 = False
-        except (ConnectionError, OSError, AssertionError):
-            raised2 = True
-        assert raised2, f"short verdicts accepted uncoalesced: {out2}"
-    finally:
-        svc2.stop()
-
 
 def test_window_respects_pad_ladder_cap():
     """Merged windows never exceed MAX_WINDOW items (the top of the XLA
@@ -246,77 +221,6 @@ def test_window_respects_pad_ladder_cap():
         assert sum(calls) == 14
     finally:
         gate.set()
-        svc.stop()
-
-
-def test_bounded_accumulation_merges_a_trickle():
-    """flush_us holds the window open so requests arriving a few ms apart
-    merge into ONE backend launch instead of one launch each — the f=1
-    occupancy lever (BASELINE north star): the window trades bounded
-    latency for items-per-launch."""
-    calls = []
-
-    def backend(items):
-        calls.append(len(items))
-        return [p[0] == s[0] for p, m, s in items]
-
-    svc = VerifierService(backend=backend, flush_us=1_500_000).start()
-    try:
-        results = {}
-
-        def client(cid: int, delay: float):
-            time.sleep(delay)
-            results[cid] = _send_batch(svc.address, [_item(cid, True)])
-
-        threads = [
-            threading.Thread(target=client, args=(c, 0.05 * c))
-            for c in range(1, 4)
-        ]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=15)
-        assert svc.requests == 3
-        # An instant backend would have dispatched each trickle item alone
-        # without the accumulation window.
-        assert svc.batches == 1, f"window did not hold: {calls}"
-        assert calls == [3]
-        for cid in range(1, 4):
-            assert results[cid] == [True]
-    finally:
-        svc.stop()
-
-
-def test_flush_items_short_circuits_the_deadline():
-    """Hitting the item target flushes immediately — the deadline is a
-    bound, not a tax on every window."""
-
-    def backend(items):
-        return [p[0] == s[0] for p, m, s in items]
-
-    # Deadline absurdly long: only the item target can explain a flush.
-    svc = VerifierService(
-        backend=backend, flush_us=60_000_000, flush_items=4
-    ).start()
-    try:
-        results = {}
-
-        def client(cid: int):
-            results[cid] = _send_batch(
-                svc.address, [_item(cid, True), _item(cid, False)]
-            )
-
-        t0 = time.monotonic()
-        threads = [threading.Thread(target=client, args=(c,)) for c in (1, 2)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=20)
-        elapsed = time.monotonic() - t0
-        assert elapsed < 20, "flush_items target never fired"
-        assert results[1] == [True, False] and results[2] == [True, False]
-        assert svc.items == 4
-    finally:
         svc.stop()
 
 
@@ -433,8 +337,8 @@ def test_a_caller_alone_never_pays_the_hold():
 def test_a_held_window_goes_when_it_fills_or_its_hold_runs_out(tmp_path):
     """The hold is a bound, counted from the oldest request's ARRIVAL (it
     shows as ``queue_s``); a window that fills the shape it would run at
-    (``hold_s`` says 0) goes at once; an explicit ``flush_us`` window takes
-    the hold's place."""
+    (``hold_s`` says 0) goes at once; and with no ``hold_s`` (a bare
+    service) every window is cut at once, whoever is still out."""
     import json
 
     calls = []
@@ -450,44 +354,42 @@ def test_a_held_window_goes_when_it_fills_or_its_hold_runs_out(tmp_path):
         t0 = time.monotonic()
         assert b.send([_item(3, True), _item(4, False)]) == [True, False]
         full = time.monotonic() - t0
+        svc.hold_s = None
+        t0 = time.monotonic()
+        assert a.send([_item(5, True)]) == [True]  # b is out: nobody asks
+        bare = time.monotonic() - t0
     finally:
         a.close()
         b.close()
         svc.stop()
-    assert 0.3 <= held < 5.0 and full < 0.25, (held, full)
+    assert 0.3 <= held < 5.0 and full < 0.25 and bare < 0.25, (held, full, bare)
     lines = [json.loads(ln) for ln in trace.read_text().splitlines()]
-    assert [e["size"] for e in lines] == [1, 1, 2]
+    assert [e["size"] for e in lines] == [1, 1, 2, 1]
     assert lines[1]["queue_s"] >= 0.29 and lines[2]["queue_s"] < 0.25
-
-    flushed = VerifierService(backend=_sizes_backend([]), flush_us=1).start()
-    flushed.hold_s = lambda n: 30.0
-    a, b = _Conn(flushed.address), _Conn(flushed.address)
-    try:
-        assert a.send([_item(5, True)]) == [True]
-        t0 = time.monotonic()
-        assert b.send([_item(6, True)]) == [True]
-        assert time.monotonic() - t0 < 20
-    finally:
-        a.close()
-        b.close()
-        flushed.stop()
+    assert (lines[3]["hold_s"], lines[3]["held_out"], lines[3]["in_step"]) == (0.0, 0, 0)
 
 
 @pytest.mark.parametrize(
-    "hold, b_sends, a_comes_back, want",
+    "hold, b_sends, c_sends, a_comes_back, want",
     [
         # b's window has room and a, whom the last launch answered, is out:
         # it is cut when a is back, long before its hold is over.
-        (30.0, 1, True, {"size": 2, "hold_s": 30.0, "held_out": 0, "in_step": 1}),
+        (30.0, 1, 0, True, {"size": 2, "hold_s": 30.0, "held_out": 0, "in_step": 1}),
         # a stays out: the hold runs out.
-        (0.3, 1, False, {"size": 1, "hold_s": 0.3, "held_out": 1, "in_step": 0}),
+        (0.3, 1, 0, False, {"size": 1, "hold_s": 0.3, "held_out": 1, "in_step": 0}),
         # the window fills the shape it would run at: no hold is granted.
-        (30.0, 3, False, {"size": 3, "hold_s": 0.0, "held_out": 0, "in_step": 0}),
+        (30.0, 4, 0, False, {"size": 4, "hold_s": 0.0, "held_out": 0, "in_step": 0}),
+        # a trickle: b, then c, then a, each on its own connection and a few
+        # ms apart, share ONE launch (alone, each would have been one).
+        (30.0, 1, 1, True, {"size": 3, "requests": 3, "hold_s": 30.0, "held_out": 0, "in_step": 1}),
+        # c fills the window b holds open: it goes at once, although a is
+        # still out and the hold it was granted is a minute.
+        (60.0, 1, 3, False, {"size": 4, "requests": 2, "hold_s": 0.0, "held_out": 0, "in_step": 0}),
     ],
-    ids=["in_step", "held_out", "full"],
+    ids=["in_step", "held_out", "full", "trickle", "fills_while_held"],
 )
 def test_the_line_says_which_exit_of_the_hold_cut_the_window(
-    tmp_path, hold, b_sends, a_comes_back, want
+    tmp_path, hold, b_sends, c_sends, a_comes_back, want
 ):
     """Every ``verify_batch`` line carries the hold its window was granted at
     the cut (``hold_s``) and which exit cut it: ``in_step`` (nobody in step
@@ -497,22 +399,32 @@ def test_the_line_says_which_exit_of_the_hold_cut_the_window(
 
     trace = tmp_path / "service.jsonl"
     svc = VerifierService(backend=_sizes_backend([]), trace_path=str(trace)).start()
-    svc.hold_s = lambda n: hold if n < 3 else 0.0
-    a, b = _Conn(svc.address), _Conn(svc.address)
+    svc.hold_s = lambda n: hold if n < 4 else 0.0
+    a, b, c = _Conn(svc.address), _Conn(svc.address), _Conn(svc.address)
     results = {}
     try:
         assert a.send([_item(1, True)]) == [True]  # a caller alone: in step with nobody
+        t0 = time.monotonic()
         t = b.send_later([_item(2 + k, True) for k in range(b_sends)], results, "b")
+        if c_sends:
+            t.join(0.05)
+            assert t.is_alive()
+            tc = c.send_later([_item(6 + k, k == 0) for k in range(c_sends)], results, "c")
         if a_comes_back:
             t.join(0.2)
             assert t.is_alive()
             assert a.send([_item(9, False)]) == [False]
         t.join(10)
         assert results["b"] == [True] * b_sends
+        if c_sends:
+            tc.join(10)
+            assert results["c"] == [k == 0 for k in range(c_sends)]
+        assert time.monotonic() - t0 < 20  # no case waits its hold out but held_out's 0.3 s
         status = svc.launch_status()
     finally:
         a.close()
         b.close()
+        c.close()
         svc.stop()
     first, second = [json.loads(ln) for ln in trace.read_text().splitlines()]
     assert {k: first[k] for k in ("hold_s", "held_out", "in_step")} == {
@@ -567,36 +479,30 @@ def test_service_trace_records_merged_windows(tmp_path):
     share of a merged window)."""
     import json
 
-    def backend(items):
-        return [p[0] == s[0] for p, m, s in items]
-
     trace = tmp_path / "service.jsonl"
-    svc = VerifierService(
-        backend=backend, flush_us=1_000_000, trace_path=str(trace)
-    ).start()
+    svc = VerifierService(backend=_sizes_backend([]), trace_path=str(trace)).start()
+    svc.hold_s = lambda n: 30.0  # b's window stays open until a is back
+    a, b = _Conn(svc.address), _Conn(svc.address)
+    results = {}
     try:
-        threads = [
-            threading.Thread(
-                target=lambda c=c: _send_batch(
-                    svc.address, [_item(c, True), _item(c, c % 2 == 0)]
-                )
-            )
-            for c in (2, 3)
-        ]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(timeout=15)
+        assert a.send([_item(1, True)]) == [True]
+        t = b.send_later([_item(2, True), _item(2, True)], results, "b")
+        t.join(0.2)
+        assert t.is_alive()
+        assert a.send([_item(3, True), _item(3, False)]) == [True, False]
+        t.join(10)
+        assert results["b"] == [True, True]
     finally:
+        a.close()
+        b.close()
         svc.stop()
     events = [json.loads(line) for line in trace.read_text().splitlines()]
     batches = [e for e in events if e["ev"] == "verify_batch"]
-    assert batches, "no verify_batch events traced"
-    assert sum(e["size"] for e in batches) == 4
-    assert sum(e["requests"] for e in batches) == 2
-    assert sum(e["rejected"] for e in batches) == 1
+    assert [(e["size"], e["requests"], e["rejected"]) for e in batches] == [
+        (1, 1, 0),
+        (4, 2, 1),  # one line for the merged launch, not one a connection
+    ]
     assert all(e["secs"] >= 0 and e["replica"] == "service" for e in batches)
-
 
 
 def test_overlapped_launches_hide_launch_latency():
@@ -687,13 +593,35 @@ def test_status_probe_reports_state_and_traffic_continues():
             assert sock.recv(1) == b"\x01"
     finally:
         svc.stop()
-    # The jax-string backend (no daemon lifecycle) reports ready: it warms
-    # lazily on first traffic, which is exactly the pre-daemon contract.
-    svc2 = VerifierService(backend="jax").start()
+
+
+@pytest.mark.parametrize(
+    "kwargs, named",
+    [
+        ({}, "native"),
+        ({"backend": "native"}, "native"),
+        ({"backend": "cpu"}, "cpu"),
+        ({"backend": lambda items: [False] * len(items)}, "custom"),
+    ],
+    ids=["default", "native", "cpu", "callable"],
+)
+def test_a_bare_service_never_says_ready(kwargs, named):
+    """``ready`` means a warmed engine, and only the daemon has one: a bare
+    ``VerifierService`` answers ``cpu-only`` on both probes whatever serves
+    it, before and after traffic, and "jax" is no backend it knows by name
+    (that one compiled at the first window, behind a ``ready``)."""
+    with pytest.raises(KeyError):
+        VerifierService(backend="jax")
+    svc = VerifierService(**kwargs).start()
     try:
-        assert probe_status(svc2.address) == (STATE_READY, 0, 0)
+        for _ in range(2):
+            assert probe_status(svc.address) == (STATE_CPU_ONLY, 0, 0)
+            js = probe_status_json(svc.address)
+            assert js["state"] == "cpu-only" and js["devices"] == 0
+            assert js["backend"] == named
+            assert _send_batch(svc.address, [(bytes(32), bytes(32), bytes(64))]) == [False]
     finally:
-        svc2.stop()
+        svc.stop()
 
 
 class _StubEngine:
@@ -830,6 +758,24 @@ def test_verifyd_backend_jax_exits_nonzero_off_tpu(monkeypatch, capfd):
     assert code == 1
     err = capfd.readouterr().err
     assert "needs a TPU" in err and "'cpu'" in err
+
+
+@pytest.mark.parametrize(
+    "flag", ["--flush-us", "--flush-items", "--window", "--warm-shapes", "--inflight"]
+)
+def test_verifyd_refuses_a_flag_that_is_gone(flag, capfd):
+    """A stale unit file must fail loudly, not run another policy: the
+    flags of the flush window and the knobs nobody turned are a usage
+    error (exit 2), and nothing is left listening."""
+    from pbft_tpu.net import verify_service
+    from pbft_tpu.net.launcher import free_ports
+
+    port = free_ports(1)[0]
+    with pytest.raises(SystemExit) as exit_:
+        verify_service.main(["--backend", "native", "--port", str(port), flag, "16"])
+    assert exit_.value.code == 2
+    assert f"unrecognized arguments: {flag}" in capfd.readouterr().err
+    assert probe_status(f"127.0.0.1:{port}", timeout=0.2) is None
 
 
 def test_backend_jax_on_cpu_is_allowed_when_platforms_names_cpu(monkeypatch):
@@ -1084,6 +1030,9 @@ def test_engine_parity_pad_slots_and_window_boundaries():
     items = [_item(i + 1, i % 3 != 0) for i in range(11)]
     want = [i % 3 != 0 for i in range(11)]
     assert eng.verify(items) == want
+    # The empty window, and a few items of mixed validity on the small shape.
+    assert eng.verify([]) == []
+    assert eng.verify(items[:5]) == want[:5] == [False, True, True, False, True]
     # Exactly one shape (8) and one item past it (9 -> 16).
     assert eng.verify(items[:8]) == want[:8]
     assert eng.verify(items[:9]) == want[:9]

@@ -160,7 +160,7 @@ def test_engine_steps_sum_to_secs_with_two_launches_in_flight(tmp_path):
     engine = ShardedVerifyEngine(shapes=shapes, kernel=_slow_kernel)
     trace = tmp_path / "verifyd.jsonl"
     daemon = VerifyServiceDaemon(
-        backend="auto", engine=engine, inflight=2, trace_path=str(trace),
+        backend="auto", engine=engine, trace_path=str(trace),
         fallback=lambda items: pytest.fail("the fallback ran"),
     ).start(wait_ready=True, timeout=300)
     assert daemon.state_name == "ready"
@@ -218,7 +218,7 @@ def test_engine_steps_sum_to_secs_with_two_launches_in_flight(tmp_path):
     assert statistics.median(gaps) < max(0.0005, 0.05 * statistics.median(e["secs"] for e in split))
     spans = sorted((e["ts"] - e["secs"], e["ts"]) for e in lines)
     assert any(b_start < a_end for (_, a_end), (b_start, _) in zip(spans, spans[1:])), (
-        "no two launches overlapped: the test did not exercise --inflight 2"
+        "no two launches overlapped: the test did not exercise the daemon's two launch slots"
     )
     for e in lines:
         assert set(STEPS) | {"rung", "promoted", "chunks", "split", "t_dev", "queue_s", "slot_s"} <= set(e)
