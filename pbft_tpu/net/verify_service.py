@@ -46,13 +46,17 @@ last shape of its plan, the dispatcher keeps it open for at most one
 ``launch_s`` of that shape (:meth:`ShardedVerifyEngine.hold_s`), so
 replicas' batches that arrive a few ms apart share a launch.
 
-Host↔device pipeline: every window is staged with an async
-``jax.device_put`` against the batch sharding and launched through a
-precompiled executable with DONATED input buffers (XLA reuses the device
-memory window over window). With the two launch slots the daemon gives its
-dispatcher (``service.DAEMON_INFLIGHT``) the service ships window N+1 from
-a second launch thread while window N computes — the double-buffered
-transfer/compute overlap, with verdict slicing per connection untouched.
+Host↔device pipeline: every window is staged as ONE ``(size, 128)`` uint8
+block of the triples as they came off the wire (``crypto.batch.pad_batch``: a
+copy of the shape's all-pad template and one assignment, nothing per item)
+and handed, still on the host, to a precompiled executable: its call moves
+the block in ONE async transfer against the batch sharding, cuts it into
+``pub | msg | sig`` on the device and DONATES the input buffer (XLA reuses
+the device memory window over window). With the two launch slots the daemon
+gives its dispatcher (``service.DAEMON_INFLIGHT``) the service ships window
+N+1 from a second launch thread while window N computes — the
+double-buffered transfer/compute overlap, with verdict slicing per
+connection untouched.
 """
 
 from __future__ import annotations
@@ -95,10 +99,10 @@ from ..utils.trace import current_span
 
 # A larger shape takes a smaller one's windows only where it measured under
 # a THIRD of the smaller one's time. Running on a larger shape is not free
-# (more pad slots to fill, three larger transfers, fewer rows of real work a
-# device), and ``launch_s`` is the least of a few launches read on the
-# host's clock: on a loaded host two programs of equal cost have read 1.8x
-# apart (tier-1 under eight-fold contention). The gap this is for is 8x
+# (a larger block to copy and move, fewer rows of real work a device), and
+# ``launch_s`` is the least of a few launches read on the host's clock: on a
+# loaded host two programs of equal cost have read 1.8x apart (tier-1 under
+# eight-fold contention). The gap this is for is 8x
 # (TPU v5e: 42 ms at 16 and 64 slots, 5.3 at 256, 5.5x with the host's
 # staging on both sides). Equal costs, and costs inside the margin, keep the
 # smaller shape, so wherever cost grows with size the table is the identity.
@@ -145,7 +149,7 @@ def serving_table_text(table: dict) -> str:
 SPLIT_MARGIN = 1.25
 
 # More chunks than this are never worth their host work (a chunk is a pad,
-# three transfers, a dispatch and a read-back in the service's one Python
+# a transfer, a dispatch and a read-back in the service's one Python
 # process), and the bound keeps the search a handful of sums.
 MAX_CHUNKS = 4
 
@@ -241,7 +245,6 @@ class ShardedVerifyEngine:
         self._kernel = kernel
         self._lock = threading.Lock()
         self._mesh = None
-        self._spec = None
         self._compiled: dict = {}  # padded size -> jax.stages.Compiled
         self._launch_s: dict = {}  # padded size -> seconds, read at warm-up
         self._serves: dict = {}  # smallest fitting size -> size it runs at
@@ -261,7 +264,7 @@ class ShardedVerifyEngine:
         cache_dir = configure_compile_cache()
         import jax
 
-        from ..parallel import batch_sharding, make_mesh
+        from ..parallel import make_mesh
 
         with self._lock:
             seen = jax.devices()
@@ -273,7 +276,6 @@ class ShardedVerifyEngine:
             self.devices_seen = len(seen)
             self.device_count = len(devs)
             self._mesh = make_mesh(devices=devs)
-            self._spec = batch_sharding(self._mesh)
             self.stats = {"cache_dir": cache_dir}
 
     def warm(self) -> dict:
@@ -320,9 +322,9 @@ class ShardedVerifyEngine:
                     hits.clear()
                     t0 = time.perf_counter()
                     with warnings.catch_warnings():
-                        # Donation cannot alias the (B,128B) inputs to the
+                        # Donation cannot alias the (B,128) input to the
                         # (B,) bool output, so XLA warns per shape; the
-                        # donation still releases the staged input buffers
+                        # donation still releases the staged input buffer
                         # eagerly, and the warning is pure noise here.
                         warnings.filterwarnings(
                             "ignore", message="Some donated buffers"
@@ -348,7 +350,7 @@ class ShardedVerifyEngine:
                                 d.id for d in in_sharding.device_set
                             ),
                             "rows_per_device": in_sharding.shard_shape(
-                                (size, 32)
+                                (size, 128)
                             )[0],
                             "launch_s": launch_s,
                         }
@@ -381,26 +383,24 @@ class ShardedVerifyEngine:
 
     def _measure(self, size: int, compiled) -> float:
         """What one launch of ``compiled`` costs here, in seconds: the
-        all-pad window through the path ``verify()`` takes (``device_put``,
-        the executable, ``np.asarray``), once untimed and then the least of
-        ``WARM_LAUNCHES`` timed ones, with nothing else in flight (the
-        daemon serves from its fallback until ``warm()`` returns). Every
+        all-pad window through the path ``verify()`` takes (``pad_batch``,
+        the executable on the host block, ``np.asarray``), once untimed and
+        then the least of ``WARM_LAUNCHES`` timed ones, with nothing else in
+        flight (the daemon serves from its fallback until ``warm()``
+        returns). Every
         slot holds the known-good triple, so the engine's own kernel has to
         answer True in every slot of every launch: a self-test of each
         executable, and a failure of warm-up like a compile failure. (A
         stand-in kernel decides by its own rule, which the pad triple need
         not satisfy.)"""
         import numpy as np
-        import jax
 
         from ..crypto.batch import pad_batch
 
-        window = pad_batch([], size)[:3]
         took = []
         for _ in range(1 + self.WARM_LAUNCHES):
             t0 = time.perf_counter()
-            staged = [jax.device_put(a, self._spec) for a in window]
-            verdicts = np.asarray(compiled(*staged))
+            verdicts = np.asarray(compiled(pad_batch([], size)[0]))
             took.append(time.perf_counter() - t0)
             if self._kernel is None and not verdicts.all():
                 raise RuntimeError(
@@ -444,11 +444,15 @@ class ShardedVerifyEngine:
     # -- serving -------------------------------------------------------------
 
     # The steps of a chunk, in order; each is timed and is a profiler span.
+    # ``put_s`` is 0 since the executable takes the host block (the transfer
+    # is inside its call, so inside ``dispatch_s``); the name stays on every
+    # line because the launch lines' readers sum ``pad_s + put_s + dispatch_s``.
     STEPS = ("pad_s", "put_s", "dispatch_s", "wait_s", "unpack_s")
 
     def verify(self, items: List[Item]) -> List[bool]:
-        """Pad to a warmed window shape, stage (async device_put against
-        the batch sharding), launch the precompiled executable, read back.
+        """Pad to a warmed window shape (one block, ``pad_batch``), call the
+        precompiled executable on it (which moves the block in ONE async
+        transfer against the batch sharding and launches), read back.
         The shape is what the serving table gives for the smallest one that
         fits: that one, or a larger one that warm-up measured clearly
         cheaper (``promoted`` counts such chunks; the pad slots verify True
@@ -490,42 +494,37 @@ class ShardedVerifyEngine:
 
         promoted = off = 0
         t_dev = None
-        flying = []  # a chunk dispatched: (verdicts to come, its items, its buffers)
+        flying = []  # a chunk dispatched: (verdicts to come, its items)
         for size in plan:
             chunk = items[off : off + size]
             off += size
             promoted += size != min(s for s in self._compiled if s >= len(chunk))
             with step("verifyd.pad"):
-                pubs, msgs, sigs, n = pad_batch(chunk, size)
-            took("pad_s")
-            # Host->device staging is async dispatch; with the service's
-            # overlapped launches (DAEMON_INFLIGHT) window N+1 stages here
-            # while window N computes. Donated inputs let XLA reuse the
-            # same device memory for every window of this shape.
-            with step("verifyd.put"):
-                dp = jax.device_put(pubs, self._spec)
-                dm = jax.device_put(msgs, self._spec)
-                ds = jax.device_put(sigs, self._spec)
-            put = took("put_s")
+                block, n = pad_batch(chunk, size)
+            pad = took("pad_s")
             if t_dev is None:
-                t_dev = put  # the first dispatch
+                t_dev = pad  # the first dispatch
+            # The executable's call is the chunk's ONE host->device transfer
+            # and its launch, both async; with the service's overlapped
+            # launches (DAEMON_INFLIGHT) window N+1 stages here while window
+            # N computes. The donated input lets XLA reuse the same device
+            # memory for every window of this shape.
             with step("verifyd.dispatch"):  # returns once enqueued
-                flying.append((self._compiled[size](dp, dm, ds), n, (dp, dm, ds)))
-                del dp, dm, ds  # kept by the tuple alone, until its unpack
+                flying.append((self._compiled[size](block), n))
             took("dispatch_s")
         out: List[bool] = []
         while flying:
-            result, n, staged = flying.pop(0)
+            result, n = flying.pop(0)
             # Behind the other launch in flight, then the device, then the
             # read-back: np.asarray returns when the verdicts are here.
             with step("verifyd.wait"):
                 verdicts = np.asarray(result)
             took("wait_s")
             with step("verifyd.unpack"):
-                out.extend(bool(v) for v in verdicts[:n])
-                # Dropping the device buffers takes its time too (~0.1 ms):
+                out.extend(verdicts[:n].tolist())
+                # Dropping the device buffer takes its time too (~0.1 ms):
                 # here, so that it is timed, not at the function's exit.
-                del staged, result, verdicts
+                del result, verdicts
             took("unpack_s")
         span = current_span()
         if span is not None:

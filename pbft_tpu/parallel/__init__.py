@@ -11,7 +11,6 @@ consensus core.
 
 from .verifier import (
     QuorumResult,
-    batch_sharding,
     compile_sharded,
     make_mesh,
     sharded_verify,
@@ -27,7 +26,6 @@ from .multihost import (
 
 __all__ = [
     "QuorumResult",
-    "batch_sharding",
     "compile_sharded",
     "make_mesh",
     "sharded_verify",

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..crypto.batch import split_block
 from ..crypto.ed25519 import verify_kernel
 
 
@@ -54,32 +55,29 @@ def make_mesh(
 def sharded_verify(
     mesh: Mesh, axis: str = "batch", donate: bool = False, kernel=None
 ):
-    """jit'd (B,32),(B,32),(B,64) uint8 -> (B,) bool, batch-sharded.
+    """jit'd (B,128) uint8 block of ``pub | msg | sig`` rows -> (B,) bool,
+    rows sharded over the batch axis.
 
-    Shard-local compute only — XLA partitions the vmapped kernel with no
-    collectives. B must be divisible by the mesh size. ``donate=True``
-    marks the three input buffers donated so XLA reuses their device
-    memory across launches (the verify service re-stages every window, so
-    its inputs are dead the moment the launch reads them). ``kernel``
-    overrides the Ed25519 kernel (tests substitute a cheap stand-in to
-    exercise the serving plumbing without a minutes-long compile).
+    The block (``crypto.batch.pad_batch``) is cut into its three columns
+    inside the jit, where the slices fuse into the kernel's first reads: one
+    array to stage, one transfer a window (the call's own, where it is
+    handed a host array). Shard-local compute only — XLA partitions the
+    vmapped kernel with no collectives. B must be divisible by the mesh
+    size. ``donate=True`` marks the input buffer donated so XLA reuses its
+    device memory across launches (the verify service re-stages every
+    window, so its input is dead the moment the launch reads it).
+    ``kernel`` overrides the Ed25519 kernel, with its ``(pubs, msgs, sigs)``
+    signature (tests substitute a cheap stand-in to exercise the serving
+    plumbing without a minutes-long compile).
     """
     spec = NamedSharding(mesh, P(axis))
     kern = kernel or verify_kernel
 
-    def fn(pubs, msgs, sigs):
-        pubs = jax.lax.with_sharding_constraint(pubs, spec)
-        msgs = jax.lax.with_sharding_constraint(msgs, spec)
-        sigs = jax.lax.with_sharding_constraint(sigs, spec)
-        return kern(pubs, msgs, sigs)
+    def fn(block):
+        block = jax.lax.with_sharding_constraint(block, spec)
+        return kern(*split_block(block))
 
-    return jax.jit(fn, donate_argnums=(0, 1, 2) if donate else ())
-
-
-def batch_sharding(mesh: Mesh, axis: str = "batch") -> NamedSharding:
-    """The (B, …) input sharding the verify launches expect — callers
-    ``jax.device_put`` against it to stage a window ahead of the launch."""
-    return NamedSharding(mesh, P(axis))
+    return jax.jit(fn, donate_argnums=(0,) if donate else ())
 
 
 def compile_sharded(
@@ -95,19 +93,19 @@ def compile_sharded(
     persistent verify service warms every `_PAD_LADDER` shape at startup
     so no request ever pays tracing or compilation (the persistent
     on-disk cache makes the warm-restart compile cache-hit cheap).
-    Returns a ``jax.stages.Compiled`` expecting inputs placed with
-    :func:`batch_sharding`.
+    Returns a ``jax.stages.Compiled`` that takes ONE ``(size, 128)`` uint8
+    block, rows sharded over ``axis``: called on a host array it moves the
+    block itself, in one transfer (what the verify service does).
     """
     if size % mesh.devices.size:
         raise ValueError(
             f"window {size} not divisible by mesh size {mesh.devices.size}"
         )
-    spec = NamedSharding(mesh, P(axis))
     fn = sharded_verify(mesh, axis, donate=donate, kernel=kernel)
     return fn.lower(
-        jax.ShapeDtypeStruct((size, 32), jnp.uint8, sharding=spec),
-        jax.ShapeDtypeStruct((size, 32), jnp.uint8, sharding=spec),
-        jax.ShapeDtypeStruct((size, 64), jnp.uint8, sharding=spec),
+        jax.ShapeDtypeStruct(
+            (size, 128), jnp.uint8, sharding=NamedSharding(mesh, P(axis))
+        )
     ).compile()
 
 

@@ -136,8 +136,16 @@ def test_batch_mixed_validity():
     assert got == want
 
 
+@pytest.mark.parametrize("n", [0, 1, 7, 8])
+def test_verify_many_block_path_matches_the_oracle(n):
+    from tests.test_verify_spans import _probe_items
+
+    items = _probe_items(n)
+    assert B.verify_many(items, pad_to=8) == [ref.verify(*item) for item in items]
+
+
 def test_batch_empty_and_padding_slots():
     assert B.verify_many([]) == []
-    pubs, msgs, sigs, n = B.pad_batch([], 4)
-    out = np.asarray(B.verify_batch(pubs, msgs, sigs))
+    block, n = B.pad_batch([], 4)
+    out = np.asarray(B.verify_batch(*B.split_block(block)))
     assert n == 0 and out.all(), "padding triple must verify"

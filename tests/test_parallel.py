@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import pytest
 
 from pbft_tpu.crypto import ref
-from pbft_tpu.crypto.batch import pad_batch
+from pbft_tpu.crypto.batch import pad_batch, split_block
 from pbft_tpu.parallel import make_mesh, sharded_verify, quorum_certify, round_step
 
 # Kernel-compile-heavy: slow tier (pytest -m slow).
@@ -38,8 +38,8 @@ def test_sharded_verify_matches_oracle():
     mesh = make_mesh(8)
     fn = sharded_verify(mesh)
     items = _signed_items(16, bad={3, 11})
-    pubs, msgs, sigs, n = pad_batch(items, 16)
-    out = np.asarray(fn(pubs, msgs, sigs))
+    block, n = pad_batch(items, 16)
+    out = np.asarray(fn(block))
     expect = [i not in {3, 11} for i in range(16)]
     assert out.tolist() == expect
 
@@ -51,7 +51,7 @@ def test_quorum_certify_counts_and_thresholds():
     # 16 signatures: rounds 0..3 get 4 each; corrupt one sig in round 1,
     # two in round 2. Pad rows -> round_id R.
     items = _signed_items(16, bad={5, 9, 10})
-    pubs, msgs, sigs, n = pad_batch(items, 16)
+    pubs, msgs, sigs = split_block(pad_batch(items, 16)[0])
     round_ids = np.arange(16) // 4
     thresholds = np.array([4, 4, 3, 3], np.int32)
     res = certify(pubs, msgs, sigs, round_ids, thresholds)
@@ -65,7 +65,7 @@ def test_quorum_certify_pad_slots_ignored():
     R = 2
     certify = quorum_certify(mesh, R)
     items = _signed_items(8)
-    pubs, msgs, sigs, n = pad_batch(items, 16)  # 8 pad rows (valid pad sig)
+    pubs, msgs, sigs = split_block(pad_batch(items, 16)[0])  # 8 pad rows (valid pad sig)
     round_ids = np.concatenate([np.arange(8) // 4, np.full(8, R)])
     thresholds = np.array([3, 3], np.int32)
     res = certify(pubs, msgs, sigs, round_ids, thresholds)
@@ -78,7 +78,7 @@ def test_round_step_runs_and_is_deterministic():
     R = 4
     step = round_step(mesh, R)
     items = _signed_items(16, bad={2})
-    pubs, msgs, sigs, n = pad_batch(items, 16)
+    pubs, msgs, sigs = split_block(pad_batch(items, 16)[0])
     round_ids = np.arange(16) // 4
     thresholds = np.full(R, 3, np.int32)
     state = jnp.zeros(8, jnp.int32)
@@ -119,10 +119,28 @@ def test_sharded_matches_unsharded():
     mesh = make_mesh(8)
     fn = sharded_verify(mesh)
     items = _signed_items(8, bad={1, 6})
-    pubs, msgs, sigs, n = pad_batch(items, 8)
-    assert np.asarray(fn(pubs, msgs, sigs)).tolist() == np.asarray(
-        verify_batch(pubs, msgs, sigs)
+    block, n = pad_batch(items, 8)
+    assert np.asarray(fn(block)).tolist() == np.asarray(
+        verify_batch(*split_block(block))
     ).tolist()
+
+
+@pytest.mark.parametrize("n", [0, 1, 15, 16])
+def test_block_path_gives_the_oracles_verdicts_item_by_item(n):
+    """ISSUE 31 parity: valid items plus one of every class the kernel
+    decides (the probe's seven rejects and its control), staged as ONE
+    block and cut into columns on the device, get ``ref.verify``'s verdict
+    item by item, at n = 0, 1, size - 1, size."""
+    from pbft_tpu.crypto import ref
+    from pbft_tpu.net import ShardedVerifyEngine
+    from tests.test_verify_spans import _probe_items
+
+    items = _probe_items(n)
+    want = [ref.verify(*item) for item in items]
+    assert want[:8] == ([False] * 7 + [True])[:n]
+    eng = ShardedVerifyEngine(shapes=(16,))
+    eng.warm()
+    assert eng.verify(items) == want
 
 
 def test_persistent_engine_matches_oracle_and_native():

@@ -149,15 +149,31 @@ def _slow_kernel(pubs, msgs, sigs):
     return (pubs[:, 0] == sigs[:, 0]) & (spin >= 0)
 
 
+class _Seen:
+    """An executable of the engine, noting what it is called on."""
+
+    def __init__(self, compiled):
+        self.compiled, self.input_shardings, self.args = compiled, compiled.input_shardings, []
+
+    def __call__(self, *args):
+        self.args.append(args)
+        return self.compiled(*args)
+
+
 def _rung_of(size, shapes):
     top = max(shapes)
     chunks = [min(top, size - off) for off in range(0, size, top)]
     return sum(min(s for s in shapes if s >= n) for n in chunks)
 
 
-def test_engine_steps_sum_to_secs_with_two_launches_in_flight(tmp_path):
+def test_engine_steps_sum_to_secs_with_two_launches_in_flight(tmp_path, monkeypatch):
+    import jax
+
     shapes = (8, 16)
     engine = ShardedVerifyEngine(shapes=shapes, kernel=_slow_kernel)
+    # One transfer a chunk, the executable's own: it is handed the host
+    # block, and nothing in the process calls ``device_put``.
+    monkeypatch.setattr(jax, "device_put", lambda *a, **kw: pytest.fail("a device_put"))
     trace = tmp_path / "verifyd.jsonl"
     daemon = VerifyServiceDaemon(
         backend="auto", engine=engine, trace_path=str(trace),
@@ -166,6 +182,7 @@ def test_engine_steps_sum_to_secs_with_two_launches_in_flight(tmp_path):
     assert daemon.state_name == "ready"
     # Equal costs, as the kernel's are, whatever this host's clock read.
     assert engine._route(dict.fromkeys(shapes, 0.001))["chunk_plan"] == {}
+    engine._compiled = {size: _Seen(c) for size, c in engine._compiled.items()}  # from here on: served chunks
     errors = []
 
     def client(n_items, rounds):
@@ -204,6 +221,14 @@ def test_engine_steps_sum_to_secs_with_two_launches_in_flight(tmp_path):
         daemon.stop()
     assert not errors, errors
     lines, chunked = _lines(trace)[:whole], _lines(trace)[whole:]
+    calls = [args for size in shapes for args in engine._compiled[size].args]
+    assert len(calls) == sum(e["chunks"] for e in lines + chunked)
+    assert {(type(b).__name__, b.shape, str(b.dtype)) for (b,) in calls} == {
+        ("ndarray", (8, 128), "uint8"), ("ndarray", (16, 128), "uint8")
+    }
+    assert all(e["put_s"] == 0 for e in lines + chunked)  # nothing to time: the transfer is the call's
+    keys = trace_schema.EVENT_SCHEMAS["verify_batch"]
+    assert all(keys["required"] <= set(e) <= keys["required"] | keys["optional"] for e in lines + chunked)
     assert sum(e["size"] for e in lines) == 8 * (5 + 12 + 5 + 7)
     assert sum(e["size"] for e in chunked) == 8 * (5 + 12 + 5)
     for e in chunked:
@@ -529,6 +554,153 @@ def test_warm_up_fails_where_an_executable_rejects_the_pad_triple(monkeypatch):
         assert "self-test" in daemon.status_json()["warm_error"]
     finally:
         daemon.stop()
+
+
+# -- (b''') a window is staged as one block of bytes (ISSUE 31) -------------------
+
+
+def _staged_item_by_item(items, size):
+    """The staging that ``pad_batch`` replaced, kept as its reference: three
+    tiled pad columns and three row stores an item."""
+    import numpy as np
+
+    from pbft_tpu.crypto import ref
+    from pbft_tpu.crypto.batch import _PAD_MSG, _PAD_SEED
+
+    pubs = np.tile(np.frombuffer(ref.public_key(_PAD_SEED), np.uint8), (size, 1))
+    msgs = np.tile(np.frombuffer(_PAD_MSG, np.uint8), (size, 1))
+    sigs = np.tile(np.frombuffer(ref.sign(_PAD_SEED, _PAD_MSG), np.uint8), (size, 1))
+    for i, (pub, msg, sig) in enumerate(items):
+        pubs[i] = np.frombuffer(pub, np.uint8)
+        msgs[i] = np.frombuffer(msg, np.uint8)
+        sigs[i] = np.frombuffer(sig, np.uint8)
+    return pubs, msgs, sigs
+
+
+def _probe_items(n, seed=31):
+    """``n`` items as the benchmark's probe mixes them: valid signatures
+    with one of each class the kernel decides (seven rejects and the
+    control) planted among the first."""
+    import random
+
+    sys.path.insert(0, str(ROOT))
+    try:
+        import chip_smoke
+    finally:
+        sys.path.pop(0)
+    rng = random.Random(seed)
+    pool = chip_smoke.signed_pool(rng, max(n, 1))
+    plants = [item for item, _want in chip_smoke.planted(rng, pool[0]).values()]
+    assert len(plants) == 8
+    return (plants + pool)[:n]
+
+
+@pytest.mark.parametrize("n", [0, 1, 15, 16])
+def test_a_staged_block_is_the_items_then_the_pad_triple(n):
+    """n = 0, 1, size - 1, size: the block's columns are what staging item
+    by item gave, and the kernel is handed exactly them."""
+    import numpy as np
+
+    from pbft_tpu.crypto import ref
+    from pbft_tpu.crypto.batch import _PAD_ROW, pad_batch, split_block
+
+    items = _probe_items(n)
+    block, got_n = pad_batch(items, 16)
+    assert got_n == n and block.shape == (16, 128) and block.dtype == np.uint8
+    assert block.flags.writeable and block.flags.c_contiguous
+    for got, want in zip(split_block(block), _staged_item_by_item(items, 16)):
+        assert np.array_equal(got, want)
+    assert ref.verify(*map(bytes, split_block(_PAD_ROW[None])))  # the pad row is a valid triple
+    assert [tuple(map(bytes, row)) for row in zip(*(c[:n] for c in split_block(block)))] == items
+    with pytest.raises(ValueError, match="exceeds padded size"):
+        pad_batch([_item(0, True)] * 17, 16)
+    if n:
+        with pytest.raises(ValueError, match="not 128-byte triples"):
+            pad_batch([(items[0][0], items[0][1], items[0][2][:63])] + items[1:], 16)
+
+
+def test_rows_past_a_chunk_are_the_pad_triple_whatever_was_staged_before():
+    import random
+
+    import numpy as np
+
+    from pbft_tpu.crypto.batch import _PAD_ROW, _pad_template, pad_batch
+
+    rng = random.Random(31)
+    items = [(rng.randbytes(32), rng.randbytes(32), rng.randbytes(64)) for _ in range(300)]
+    fresh = np.tile(_PAD_ROW, (1024, 1))
+    big, n_big = pad_batch(items, 1024)
+    big[:] = 0xAB  # a block is the caller's own: writing it reaches nobody else
+    small, n_small = pad_batch(items[:10], 1024)
+    assert (n_big, n_small) == (300, 10)
+    assert np.array_equal(small[10:], fresh[10:])
+    assert small[:10].tobytes() == b"".join(p + m + s for p, m, s in items[:10])
+    template = _pad_template(1024)
+    assert np.array_equal(template, fresh) and not template.flags.writeable
+    assert template is _pad_template(1024)  # made once a shape
+    assert not np.shares_memory(small, template) and not np.shares_memory(small, big)
+
+
+def test_the_executable_takes_one_block_and_hands_the_kernel_its_columns():
+    import jax
+    import numpy as np
+
+    from pbft_tpu.crypto.batch import pad_batch
+    from pbft_tpu.parallel import compile_sharded, make_mesh
+
+    items = _probe_items(11)
+    want = [np.asarray(c) for c in _staged_item_by_item(items, 16)]
+    seen = []
+
+    def kernel(pubs, msgs, sigs):
+        seen.append([(a.shape, str(a.dtype)) for a in (pubs, msgs, sigs)])
+        same = [(got == col).all(axis=1) for got, col in zip((pubs, msgs, sigs), want)]
+        return same[0] & same[1] & same[2]
+
+    mesh = make_mesh(devices=jax.local_devices())
+    compiled = compile_sharded(mesh, 16, kernel=kernel)
+    assert seen == [[((16, 32), "uint8"), ((16, 32), "uint8"), ((16, 64), "uint8")]]
+    args, kwargs = compiled.in_avals
+    assert [(a.shape, str(a.dtype)) for a in args] == [((16, 128), "uint8")] and not kwargs
+    assert len(compiled.input_shardings[0]) == 1
+    block, _n = pad_batch(items, 16)
+    assert np.asarray(compiled(block)).all()
+    block[3, 40] ^= 1  # one bit of one message: that row's columns differ, no other's
+    assert np.asarray(compiled(block)).tolist() == [i != 3 for i in range(16)]
+    with pytest.raises(TypeError):
+        compiled(*want)
+
+
+def test_warm_up_and_serving_stage_through_the_same_function(monkeypatch):
+    """``launch_s`` (and with it the serving table, the chunk plan and the
+    hold) is timed on the path that serves: a replaced ``pad_batch`` is
+    seen by ``_measure`` and by ``verify`` alike, and the executable gets
+    what it returned, untouched, from both."""
+    import numpy as np
+
+    import pbft_tpu.parallel as parallel
+    from pbft_tpu.crypto import batch
+
+    pads, blocks, real_pad, real_compile = [], [], batch.pad_batch, parallel.compile_sharded
+
+    def pad(items, size):
+        pads.append(len(items))
+        blocks.append(real_pad(items, size)[0])
+        return blocks[-1], len(items)
+
+    monkeypatch.setattr(batch, "pad_batch", pad)
+    monkeypatch.setattr(parallel, "compile_sharded", lambda *a, **kw: _Seen(real_compile(*a, **kw)))
+    engine = ShardedVerifyEngine(shapes=(8,), kernel=lambda p, m, s: p[:, 0] == s[:, 0])
+    engine.warm()
+    assert pads == [0] * (1 + engine.WARM_LAUNCHES)
+    items = [_item(i, i != 1) for i in range(3)]
+    assert engine.verify(items) == [True, False, True]
+    engine._route({8: 0.001})
+    assert engine.verify(items * 4) == [True, False, True] * 4  # 12 items: 8 + 8 slots
+    assert pads[1 + engine.WARM_LAUNCHES :] == [3, 8, 4]
+    seen = engine._compiled[8].args
+    assert len(seen) == len(blocks) and all(len(args) == 1 for args in seen)
+    assert all(args[0] is block and isinstance(block, np.ndarray) for args, block in zip(seen, blocks))
 
 
 def test_verify_status_prints_the_serving_table(capsys):
