@@ -202,6 +202,13 @@ void pbft_mac_tag(const uint8_t key[32], const uint8_t signable[32],
   pbft::mac_tag(key, signable, out_tag);
 }
 
+// Lane key derivation parity (net/secure.py derive_auth_keys).
+void pbft_derive_auth_keys(const uint8_t shared[32], const uint8_t eph_i[32],
+                           const uint8_t eph_r[32], uint8_t out_i2r[32],
+                           uint8_t out_r2i[32]) {
+  pbft::derive_auth_keys(out_i2r, out_r2i, shared, eph_i, eph_r);
+}
+
 // Signable digest derived from a framed payload (JSON sig-splice or
 // binary template) — the Python parity test compares this against the
 // parse -> re-serialize derivation for every message type. Returns 1 on

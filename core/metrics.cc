@@ -55,6 +55,10 @@ const char* kCounterNames[] = {
     // sent, sequences executed at PREPARED, tentative rollbacks.
     "pbft_mac_frames_total", "pbft_tentative_executions_total",
     "pbft_tentative_rollbacks_total",
+    // What the fast path adds (ISSUE 32): seals the primary was refused by
+    // a closed watermark window, and signatures checked on the host in
+    // the normal case (a checkpoint's embedded one, in MAC mode).
+    "pbft_seal_refused_total", "pbft_inline_verifies_total",
     // Durable-recovery surface (ISSUE 15): WAL records appended, group-
     // commit fsync syscalls, and file bytes written.
     "pbft_wal_appends_total", "pbft_wal_fsyncs_total",
@@ -112,6 +116,11 @@ const std::pair<const char*, bool> kHistogramNames[] = {
     // flush (write + fsync).
     {"pbft_verify_inbox_wait_seconds", false},
     {"pbft_wal_flush_seconds", false},
+    // The oldest request's wait at the primary until its batch is sealed
+    // (once a batch), and how long a tentative execution stayed revocable
+    // (once a sequence number, tentative mode).
+    {"pbft_request_wait_seconds", false},
+    {"pbft_tentative_commit_lag_seconds", false},
 };
 
 // JSONL trace events net.cc emits (trace_batch, trace_view_change,
@@ -128,6 +137,7 @@ const char* kTraceEventNames[] = {
     "view_timer_fired",
     "view_change_sent",
     "new_view_installed",
+    "commit_lag",
 };
 
 // Integer-valued samples print without a decimal point, matching the

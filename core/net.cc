@@ -302,6 +302,8 @@ ReplicaServer::ReplicaServer(ClusterConfig cfg, int64_t id,
   replica_->batch_hook = [this](int64_t n) {
     metrics_.observe("pbft_batch_size", (double)n);
   };
+  // How long a tentative execution stays revocable (ISSUE 32).
+  replica_->commit_hook = [this](int64_t seq) { on_commit_floor(seq); };
   // View-change spans (ISSUE 9): rare events, stamped into trace lines
   // + the flight recorder by on_view_event.
   replica_->view_hook = [this](const char* ev, int64_t v) {
@@ -1220,6 +1222,13 @@ std::string json_escape(const std::string& s) {
 }  // namespace
 
 void ReplicaServer::trace_request_rx(const ClientRequest& req) {
+  // The oldest request of the batch this one opens: the start of
+  // pbft_request_wait_seconds. A request the replica then drops as a
+  // duplicate leaves the batch empty and the next one stamps again.
+  if ((metrics_.enabled || trace_fp_) && replica_->is_primary() &&
+      replica_->open_batch_size() == 0) {
+    batch_oldest_at_ = trace_now();
+  }
   FlightRecorder& fl = global_flight();
   if (fl.enabled()) {
     fl.record(kFlightRequestRx, replica_->view(), req.timestamp, -1);
@@ -1237,12 +1246,7 @@ void ReplicaServer::trace_batch_sealed(const PrePrepare& pp) {
   // Flight coverage comes from the "request" phase transition (the seal
   // itself); this emitter only owns the JSONL join record.
   if (!trace_fp_) return;
-  double wait_s = pending_batch_wait_s_;
-  if (batch_window_open_) {
-    wait_s = std::chrono::duration<double>(std::chrono::steady_clock::now() -
-                                           batch_window_start_)
-                 .count();
-  }
+  const double wait_s = pending_batch_wait_s_;  // on_phase("request")
   pending_batch_wait_s_ = 0.0;
   std::string reqs;
   for (const auto& r : pp.requests) {
@@ -1255,8 +1259,8 @@ void ReplicaServer::trace_batch_sealed(const PrePrepare& pp) {
                "\"view\":%lld,\"seq\":%lld,\"batch\":%lld,\"wait_s\":%.6f,"
                "\"reqs\":[%s]}\n",
                trace_now(), (long long)id_, (long long)pp.view,
-               (long long)pp.seq, (long long)pp.requests.size(),
-               std::max(0.0, wait_s), reqs.c_str());
+               (long long)pp.seq, (long long)pp.requests.size(), wait_s,
+               reqs.c_str());
   std::fflush(trace_fp_);
 }
 
@@ -1333,8 +1337,17 @@ void ReplicaServer::on_phase(const char* phase, int64_t view, int64_t seq) {
               : !std::strcmp(phase, "prepared")    ? 2
                                                    : 3;
     if (std::isnan(it->second[idx])) it->second[idx] = now;
+    if (idx == 0) {
+      // The seal: the oldest request's wait at the primary, once a batch.
+      pending_batch_wait_s_ =
+          std::isnan(batch_oldest_at_) ? 0.0
+                                       : std::max(0.0, now - batch_oldest_at_);
+      batch_oldest_at_ = std::nan("");
+      metrics_.observe("pbft_request_wait_seconds", pending_batch_wait_s_);
+    }
     return;
   }
+  if (cfg_.tentative) tentative_exec_at_[seq] = now;
   if (it == open_spans_.end()) return;  // evicted or never opened
   const std::array<double, 4> s = it->second;
   open_spans_.erase(it);
@@ -1371,6 +1384,21 @@ void ReplicaServer::on_phase(const char* phase, int64_t view, int64_t seq) {
                          now);
   }
   std::fprintf(trace_fp_, "%s\n", buf);
+  std::fflush(trace_fp_);
+}
+
+void ReplicaServer::on_commit_floor(int64_t seq) {
+  auto it = tentative_exec_at_.find(seq);
+  if (it == tentative_exec_at_.end()) return;
+  const double now = trace_now();
+  const double lag_s = std::max(0.0, now - it->second);
+  tentative_exec_at_.erase(tentative_exec_at_.begin(), std::next(it));
+  metrics_.observe("pbft_tentative_commit_lag_seconds", lag_s);
+  if (!trace_fp_) return;
+  std::fprintf(trace_fp_,
+               "{\"ts\":%.6f,\"ev\":\"commit_lag\",\"replica\":%lld,"
+               "\"seq\":%lld,\"lag_s\":%.6f}\n",
+               now, (long long)id_, (long long)seq, lag_s);
   std::fflush(trace_fp_);
 }
 
@@ -1481,14 +1509,10 @@ void ReplicaServer::check_batch_flush(
     return;  // keep accumulating: more client requests may arrive
   }
   batch_window_open_ = false;
-  // Stash the measured batch wait for trace_batch_sealed (which runs
-  // inside the emit below, after the window was closed here).
-  pending_batch_wait_s_ =
-      std::chrono::duration<double>(now - batch_window_start_).count();
   emit(replica_->flush_open_batch());
-  pending_batch_wait_s_ = 0.0;
   // A seal refused by a closed watermark window leaves the batch open;
-  // re-arm so the next tick retries instead of spinning the deadline.
+  // re-arm so the next tick retries instead of spinning the deadline
+  // (the request wait runs on: batch_oldest_at_ is not touched).
   if (replica_->open_batch_size() > 0) {
     batch_window_open_ = true;
     batch_window_start_ = now;
@@ -1909,6 +1933,17 @@ void ReplicaServer::observe_execution_metrics() {
   if (t_exec > seen_tentative_) {
     metrics_.inc("pbft_tentative_executions_total", t_exec - seen_tentative_);
     seen_tentative_ = t_exec;
+  }
+  const int64_t refused = replica_->counters["seals_refused"];
+  if (refused > seen_seals_refused_) {
+    metrics_.inc("pbft_seal_refused_total", refused - seen_seals_refused_);
+    seen_seals_refused_ = refused;
+  }
+  const int64_t inline_v = replica_->counters["inline_verifies"];
+  if (inline_v > seen_inline_verifies_) {
+    metrics_.inc("pbft_inline_verifies_total",
+                 inline_v - seen_inline_verifies_);
+    seen_inline_verifies_ = inline_v;
   }
   // Deltas of the replica's own counters: "executed" counts per REQUEST,
   // "rounds_executed" per sequence number — the two together are the

@@ -37,6 +37,7 @@
 #include <array>
 #include <atomic>
 #include <chrono>
+#include <cmath>
 #include <cstdint>
 #include <cstdio>
 #include <deque>
@@ -570,6 +571,10 @@ class ReplicaServer {
   // transition; at "executed" observes the per-phase latency histograms
   // and emits one consensus_span trace event (utils/trace_schema.py).
   void on_phase(const char* phase, int64_t view, int64_t seq);
+  // Replica::commit_hook target (tentative mode): the committed floor
+  // passed seq; observes pbft_tentative_commit_lag_seconds against the
+  // stamp on_phase kept at that sequence number's "executed".
+  void on_commit_floor(int64_t seq);
   // Accept + answer scrapes (one-shot: write response, close). Routes on
   // the request line: "/status" serves metrics_json() as JSON, anything
   // else the Prometheus text rendering.
@@ -603,6 +608,8 @@ class ReplicaServer {
   // flight record.
   int64_t seen_tentative_ = 0;
   int64_t seen_rollbacks_ = 0;
+  int64_t seen_seals_refused_ = 0;
+  int64_t seen_inline_verifies_ = 0;
   // Chaos link state (set_chaos): seeded drop/delay on outbound peer
   // frames, a per-destination FIFO of delayed frames, and the injected
   // fault / dropped frame tallies surfaced in metrics_json.
@@ -725,10 +732,18 @@ class ReplicaServer {
   // the replica) or at the batch_flush_us deadline (here).
   bool batch_window_open_ = false;
   std::chrono::steady_clock::time_point batch_window_start_{};
-  // Batch wait stashed by check_batch_flush just before it seals (it
-  // closes the window before emit runs, so trace_batch_sealed would
-  // otherwise read an already-reset window).
+  // Arrival of the OLDEST request in the primary's open batch
+  // (trace_request_rx stamps it when a request finds the batch empty: one
+  // clock read a batch, none a message; NaN = no batch open). The seal
+  // (on_phase "request") observes pbft_request_wait_seconds against it
+  // and leaves the wait here for trace_batch_sealed's wait_s. A refused
+  // seal leaves the batch open and the stamp as it is.
+  double batch_oldest_at_ = std::nan("");
   double pending_batch_wait_s_ = 0.0;
+  // Tentative mode: seq -> its "executed" stamp, until the committed
+  // floor passes it (on_commit_floor). A rolled-back sequence number is
+  // stamped again when it re-executes; entries below the floor go with it.
+  std::map<int64_t, double> tentative_exec_at_;
   // Last-seen replica counters, for the executed/rounds metric deltas.
   int64_t seen_executed_ = 0;
   int64_t seen_rounds_ = 0;

@@ -72,6 +72,7 @@ Replica::Replica(ClusterConfig config, int64_t replica_id,
   for (const char* name :
        {"sig_verified", "sig_rejected", "mac_verified",
         "tentative_executions", "tentative_rollbacks",
+        "seals_refused", "inline_verifies",
         "pre_prepares_accepted", "prepares_accepted", "commits_accepted",
         "executed", "rounds_executed", "duplicate_requests",
         "checkpoints_stable", "state_transfers"}) {
@@ -157,7 +158,10 @@ Actions Replica::flush_open_batch() {
 }
 
 Actions Replica::seal_batch() {
-  if (seq_counter_ + 1 > high_mark()) return {};  // window closed: stay open
+  if (seq_counter_ + 1 > high_mark()) {
+    counters["seals_refused"] += 1;
+    return {};  // window closed: stay open
+  }
   if (wal_ != nullptr &&
       !wal_->note_vote(kWalVotePrePrepare, view_, seq_counter_ + 1,
                        batch_digest_hex(open_batch_))) {
@@ -624,6 +628,7 @@ Actions Replica::note_committed(int64_t seq) {
     const int64_t s = committed_upto_;
     committed_seqs_.erase(s);
     tentative_undo_.erase(s);
+    if (commit_hook) commit_hook(s);
     auto pit = pending_checkpoints_.find(s);
     if (pit != pending_checkpoints_.end()) {
       std::string payload = std::move(pit->second);
@@ -850,9 +855,9 @@ Actions Replica::insert_checkpoint(const Checkpoint& cp) {
   // CERTIFICATES are made of — admit only provable evidence, or one
   // sig-corrupting peer poisons every honest VIEW-CHANGE. Rare (one per
   // interval per replica): the inline verify is off the hot path.
-  if (config_.fastpath == "mac" &&
-      !verify_inline(cp.replica, Message(cp), cp.sig)) {
-    return {};
+  if (config_.fastpath == "mac") {
+    counters["inline_verifies"] += 1;  // the normal case's one host check
+    if (!verify_inline(cp.replica, Message(cp), cp.sig)) return {};
   }
   auto& slot = checkpoints_[cp.seq];
   if (slot.count(cp.replica)) return {};

@@ -222,6 +222,15 @@ class Replica {
   // costs one bool check per accept.
   std::function<void(int64_t)> batch_hook;
 
+  // Committed-floor observer (ISSUE 32, mirrors the Python replica's
+  // commit_hook): called with each sequence number the committed floor
+  // passes in note_committed, i.e. in tentative mode only. The net layer
+  // sets the time against the sequence number's "executed" stamp
+  // (pbft_tentative_commit_lag_seconds). NOT a phase: a "committed" stamp
+  // after "executed" would break the phase-order invariant. Unset costs
+  // one bool check per sequence number.
+  std::function<void(int64_t)> commit_hook;
+
   // View-change observer (ISSUE 9, mirrors the Python replica's
   // view_hook): hook("view_change_sent", pending_view) when this replica
   // broadcasts VIEW-CHANGE, hook("new_view_installed", view) when it

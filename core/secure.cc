@@ -159,6 +159,13 @@ bool ct_equal(const uint8_t* a, const uint8_t* b, size_t n) {
 
 }  // namespace
 
+void derive_auth_keys(uint8_t a_i2r[32], uint8_t a_r2i[32],
+                      const uint8_t shared[32], const uint8_t eph_i[32],
+                      const uint8_t eph_r[32]) {
+  derive_auth_key32(a_i2r, shared, "a-i2r", eph_i, eph_r);
+  derive_auth_key32(a_r2i, shared, "a-r2i", eph_i, eph_r);
+}
+
 std::string aead_seal(const uint8_t key[64], uint64_t ctr,
                       const std::string& plaintext) {
   uint8_t nonce[12];
@@ -304,8 +311,7 @@ bool SecureChannel::finish() {
   // distinct labels — lanes and frame sealing never share key bytes.
   // Byte-identical to net/secure.py derive_auth_keys.
   uint8_t a_i2r[32], a_r2i[32];
-  derive_auth_key32(a_i2r, shared, "a-i2r", eph_i, eph_r);
-  derive_auth_key32(a_r2i, shared, "a-r2i", eph_i, eph_r);
+  derive_auth_keys(a_i2r, a_r2i, shared, eph_i, eph_r);
   std::memcpy(auth_send_key_, initiator_ ? a_i2r : a_r2i, 32);
   std::memcpy(auth_recv_key_, initiator_ ? a_r2i : a_i2r, 32);
   established_ = true;

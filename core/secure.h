@@ -69,6 +69,12 @@ void mac_tag(const uint8_t key[32], const uint8_t signable[32],
              uint8_t out[kMacTagLen]);
 // Constant-time lane comparison.
 bool mac_tag_equal(const uint8_t a[kMacTagLen], const uint8_t b[kMacTagLen]);
+// The two directions' authenticator session keys of one link, from the
+// handshake's shared secret and ephemeral keys: keyed BLAKE2b-256 under
+// the labels "a-i2r" / "a-r2i" (net/secure.py derive_auth_keys).
+void derive_auth_keys(uint8_t a_i2r[32], uint8_t a_r2i[32],
+                      const uint8_t shared[32], const uint8_t eph_i[32],
+                      const uint8_t eph_r[32]);
 
 // Keystream/tag primitive: sealed = ciphertext || 16B tag. key is 64 bytes
 // (enc 32 || mac 32); ctr is the per-direction frame counter.

@@ -265,6 +265,12 @@ class Replica:
         # pre-prepare accept (feeds the pbft_batch_size histogram). Same
         # one-attribute-check-when-unset discipline as phase_hook.
         self.batch_hook: Optional[Callable[[int], None]] = None
+        # Committed-floor observer (ISSUE 32): called with each sequence
+        # number the committed floor passes in _note_committed (tentative
+        # mode only); the runtime sets the time against that sequence
+        # number's "executed" stamp. Not a phase: "committed" never
+        # follows "executed" in a span. Same unset discipline.
+        self.commit_hook: Optional[Callable[[int], None]] = None
         # View-change observer (ISSUE 9, ROADMAP item 4): called with
         # ("view_change_sent", pending_view) when this replica broadcasts
         # VIEW-CHANGE and with ("new_view_installed", view) when it enters
@@ -297,6 +303,8 @@ class Replica:
             "mac_verified": 0,
             "tentative_executions": 0,
             "tentative_rollbacks": 0,
+            "seals_refused": 0,
+            "inline_verifies": 0,
             "pre_prepares_accepted": 0,
             "prepares_accepted": 0,
             "commits_accepted": 0,
@@ -415,6 +423,7 @@ class Replica:
 
     def _seal_batch(self) -> List[Action]:
         if self.seq_counter + 1 > self.high_mark:
+            self.counters["seals_refused"] += 1
             return []  # out of window until a checkpoint advances it
         batch = tuple(self._open_batch)
         if self.wal is not None and not self.wal.note_vote(
@@ -879,6 +888,9 @@ class Replica:
             s = self.committed_upto
             self._committed_seqs.discard(s)
             self._tentative_undo.pop(s, None)
+            chook = self.commit_hook
+            if chook is not None:
+                chook(s)
             payload = self._pending_checkpoints.pop(s, None)
             if payload is not None:
                 self.snapshots[s] = payload
@@ -1095,10 +1107,10 @@ class Replica:
         # Checkpoints are rare (one per interval per replica), so the
         # inline verify costs nothing the fast path can feel; signature
         # mode already verified upstream (fastpath gate keeps it free).
-        if self.config.fastpath == "mac" and not self._verify_inline(
-            cp.replica, cp.signable(), cp.sig
-        ):
-            return []
+        if self.config.fastpath == "mac":
+            self.counters["inline_verifies"] += 1
+            if not self._verify_inline(cp.replica, cp.signable(), cp.sig):
+                return []
         slot = self.checkpoints.setdefault(cp.seq, {})
         if cp.replica in slot:
             return []

@@ -394,6 +394,15 @@ def mac_frame_lane(payload: bytes, rid: int) -> Optional[bytes]:
     return tag.raw
 
 
+def derive_auth_keys(shared: bytes, eph_i: bytes, eph_r: bytes):
+    """The C++ lane keys of one link, (a_i2r, a_r2i) (net/secure.py
+    derive_auth_keys parity)."""
+    assert len(shared) == len(eph_i) == len(eph_r) == 32
+    i2r, r2i = ctypes.create_string_buffer(32), ctypes.create_string_buffer(32)
+    lib().pbft_derive_auth_keys(shared, eph_i, eph_r, i2r, r2i)
+    return i2r.raw, r2i.raw
+
+
 def mac_tag(key: bytes, signable: bytes) -> bytes:
     """The C++ authenticator-lane tag (net/secure.py mac_tag parity)."""
     assert len(key) == 32 and len(signable) == 32

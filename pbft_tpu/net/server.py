@@ -323,8 +323,10 @@ class AsyncReplicaServer:
         self.flight = flight
         if self.metrics_registry.enabled or get_tracer().enabled:
             self.spans = ConsensusSpans(
-                self.metrics_registry, tracer=get_tracer(), replica=replica_id
+                self.metrics_registry, tracer=get_tracer(), replica=replica_id,
+                tentative=config.tentative,
             )
+            self.replica.commit_hook = self.spans.on_commit
             if flight is not None:
                 _spans_hook = self.spans.on_phase
                 _flight_hook = flight.record_phase
@@ -344,9 +346,6 @@ class AsyncReplicaServer:
         # new_view_installed are rare reconfiguration events — the hook is
         # always wired; the tracer/flight checks inside gate the cost.
         self.replica.view_hook = self._on_view_event
-        # When the primary's open batch first became non-empty (monotonic)
-        # — the "batch wait" waterfall segment measured at seal time.
-        self._batch_open_since: Optional[float] = None
         if self.metrics_registry.enabled:
             # Batch occupancy at every pre-prepare accept (ISSUE 4).
             _batch_hist = self.metrics_registry.histogram("pbft_batch_size")
@@ -400,6 +399,8 @@ class AsyncReplicaServer:
         self.mac_rejected = 0
         self._seen_tentative = 0
         self._seen_rollbacks = 0
+        self._seen_seals_refused = 0
+        self._seen_inline_verifies = 0
         self.discovery_target = discovery
         self._discovery = None
         self._warned_no_discovery = False
@@ -986,6 +987,17 @@ class AsyncReplicaServer:
             # Request-level waterfall anchor (ISSUE 9): when this replica
             # first saw the request — on the primary, the start of the
             # client-queue -> batch-wait handoff.
+            spans = self.spans
+            if (
+                spans is not None
+                and self.replica.is_primary
+                and self.replica.open_batch_size() == 0
+            ):
+                # The oldest request of the batch this one opens: the
+                # start of pbft_request_wait_seconds (one clock read a
+                # batch; a request then dropped as a duplicate leaves the
+                # batch empty and the next one stamps again).
+                spans.batch_oldest_at = spans.clock()
             if self.flight is not None:
                 self.flight.record(
                     "request_rx", view=self.replica.view, seq=msg.timestamp
@@ -1012,8 +1024,6 @@ class AsyncReplicaServer:
         if actions:
             self._emit(actions)
         if self.replica.open_batch_size() > 0:
-            if self._batch_open_since is None:
-                self._batch_open_since = time.monotonic()
             if self._batch_flush_handle is None:
                 self._batch_flush_handle = (
                     asyncio.get_running_loop().call_later(
@@ -1143,13 +1153,11 @@ class AsyncReplicaServer:
     def _trace_batch_sealed(self, pp: PrePrepare) -> None:
         """The primary sealed a batch (its own pre-prepare broadcast):
         emit the waterfall join record — (view, seq) plus the ordered
-        [client, req_ts] keys and how long the batch waited open."""
-        wait = 0.0
-        if self._batch_open_since is not None:
-            wait = max(0.0, time.monotonic() - self._batch_open_since)
-            self._batch_open_since = None
+        [client, req_ts] keys and how long its oldest request waited (the
+        span tracker's reading at the "request" transition)."""
         tracer = get_tracer()
         if tracer.enabled:
+            wait = self.spans.request_wait_s if self.spans is not None else 0.0
             tracer.event(
                 "batch_sealed",
                 replica=self.id,
@@ -1311,6 +1319,18 @@ class AsyncReplicaServer:
                     "pbft_tentative_executions_total"
                 ).inc(t_exec - self._seen_tentative)
                 self._seen_tentative = t_exec
+            refused = self.replica.counters["seals_refused"]
+            if refused > self._seen_seals_refused:
+                self.metrics_registry.counter(
+                    "pbft_seal_refused_total"
+                ).inc(refused - self._seen_seals_refused)
+                self._seen_seals_refused = refused
+            inline = self.replica.counters["inline_verifies"]
+            if inline > self._seen_inline_verifies:
+                self.metrics_registry.counter(
+                    "pbft_inline_verifies_total"
+                ).inc(inline - self._seen_inline_verifies)
+                self._seen_inline_verifies = inline
             # Deltas of the replica's own counters: "executed" counts per
             # REQUEST, "rounds_executed" per sequence number — together
             # the batch amplification (requests per three-phase instance).

@@ -230,6 +230,15 @@ class ConsensusSpans:
     Bounded: at most ``max_open`` open spans; a slot that never executes
     (view abandoned, replica crashed mid-protocol) is evicted oldest-first
     rather than leaking.
+
+    Two waits ride on the same stamps (ISSUE 32). The runtime sets
+    ``batch_oldest_at`` when a request finds the primary's open batch empty;
+    the "request" transition (the seal) observes ``pbft_request_wait_seconds``
+    against it and keeps the wait in ``request_wait_s`` for the
+    ``batch_sealed`` trace event. With ``tentative`` the "executed" stamp of
+    each sequence number is kept until ``on_commit`` (Replica.commit_hook:
+    the committed floor passed it) observes
+    ``pbft_tentative_commit_lag_seconds``.
     """
 
     def __init__(
@@ -239,6 +248,7 @@ class ConsensusSpans:
         replica: int = -1,
         clock: Callable[[], float] = time.monotonic,
         max_open: int = 4096,
+        tentative: bool = False,
     ):
         self.registry = registry
         self.tracer = tracer
@@ -252,9 +262,34 @@ class ConsensusSpans:
         }
         self._e2e = registry.histogram("pbft_request_reply_seconds")
         self._executed = registry.counter("pbft_executed_total")
+        self._request_wait = registry.histogram("pbft_request_wait_seconds")
+        self.batch_oldest_at: Optional[float] = None
+        self.request_wait_s = 0.0
+        self._commit_lag = registry.histogram("pbft_tentative_commit_lag_seconds")
+        self._tentative = tentative
+        self._executed_at: Dict[int, float] = {}  # seq -> stamp, tentative mode
+
+    def on_commit(self, seq: int) -> None:
+        at = self._executed_at.pop(seq, None)
+        if at is None:
+            return
+        lag = max(0.0, self.clock() - at)
+        self._commit_lag.observe(lag)
+        if self.tracer is not None and self.tracer.enabled:
+            self.tracer.event("commit_lag", replica=self.replica, seq=seq, lag_s=round(lag, 6))
 
     def on_phase(self, phase: str, view: int, seq: int) -> None:
         now = self.clock()
+        if phase == "request":
+            oldest, self.batch_oldest_at = self.batch_oldest_at, None
+            self.request_wait_s = 0.0 if oldest is None else max(0.0, now - oldest)
+            self._request_wait.observe(self.request_wait_s)
+        elif phase == "executed" and self._tentative:
+            # Re-stamped when a rolled-back sequence number re-executes; one
+            # that state transfer carried the floor past is evicted here.
+            self._executed_at[seq] = now
+            if len(self._executed_at) > self.max_open:
+                del self._executed_at[next(iter(self._executed_at))]
         key = (view, seq)
         span = self._open.get(key)
         if span is None:

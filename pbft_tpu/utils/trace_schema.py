@@ -80,6 +80,15 @@ EVENT_SCHEMAS = {
         "optional": set(),
         "emitters": {"net.cc"},
     },
+    # Tentative mode (ISSUE 32): the committed floor passed a sequence
+    # number this replica had executed at PREPARED, lag_s after it did. A
+    # line of its own, because the span closed at "executed" and carries
+    # no "committed" stamp (scripts/consensus_timeline.py prints the lag).
+    "commit_lag": {
+        "required": {"ts", "ev", "replica", "seq", "lag_s"},
+        "optional": set(),
+        "emitters": {"server.py", "net.cc"},
+    },
     # -- request-level latency waterfall (ISSUE 9) --------------------------
     #
     # Requests are uniquely keyed by (client, req_ts) and batches by
@@ -322,6 +331,23 @@ METRIC_SCHEMAS = {
     # group-commit flush (write + fsync) that had records pending.
     "pbft_verify_inbox_wait_seconds": ("histogram", {"net.cc"}),
     "pbft_wal_flush_seconds": ("histogram", {"net.cc"}),
+    # What the fast path adds to a reply's path, and what it takes off it
+    # (ISSUE 32); all four in both modes' series sets. Request wait: on the
+    # primary, once a batch at its seal (the "request" phase stamp), seal
+    # time minus the arrival of the OLDEST request in the batch; a seal
+    # refused by a closed watermark window (counted by
+    # pbft_seal_refused_total) leaves the start where it was. Commit lag:
+    # tentative mode, once a sequence number, from its execution at
+    # PREPARED to the committed floor passing it: how long its replies
+    # stay revocable and its undo record lives (a histogram of its own,
+    # not a phase stamp: "committed" never follows "executed" in a span).
+    # Inline verifies: signature checks done on the host in the normal
+    # case, i.e. a checkpoint's embedded signature in MAC mode (the view
+    # change's proofs are not counted); /status: inline_verifies.
+    "pbft_request_wait_seconds": ("histogram", {"server.py", "net.cc"}),
+    "pbft_seal_refused_total": ("counter", {"server.py", "net.cc"}),
+    "pbft_tentative_commit_lag_seconds": ("histogram", {"server.py", "net.cc"}),
+    "pbft_inline_verifies_total": ("counter", {"server.py", "net.cc"}),
 }
 
 # Fixed histogram bucket upper edges (le semantics: v <= edge). Shared by

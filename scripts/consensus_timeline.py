@@ -14,8 +14,16 @@ Two event sources, newest first:
   (view, seq) per replica. Coarser, but it localizes stragglers in
   pre-span traces without modification.
 
+Tentative runs (``tentative`` in network.json): a replica executes at
+PREPARED, so its span closes with no ``committed`` stamp, and the commit
+quorum that follows is a ``commit_lag`` line of its own (seq, lag_s). Such a
+span is read as what it is: ``tentative`` in the breakdown, its lag printed
+beside it, and a replica that had the commit quorum in hand before it
+executed is not a straggler against those that did not wait for one.
+
 Straggler detection: within one (view, seq), a replica whose executed
-stamp trails the cluster's fastest by more than --straggler-ms. Gap
+stamp trails the fastest replica that executed the same way (tentatively, or
+on a commit quorum) by more than --straggler-ms. Gap
 detection: sequences a replica never reported executing (holes in its
 coverage), and wall-clock stalls between consecutive cluster commits
 longer than --gap-ms.
@@ -83,6 +91,7 @@ def build_timeline(files) -> dict:
     Span events carry full stamps; legacy verify_batch events contribute
     an "executed" upper bound (span data wins when both exist)."""
     slots: dict = {}
+    lags: dict = {}  # (rid, seq) -> lag_s of the latest commit_lag line
 
     def slot(view, seq, rid):
         return slots.setdefault((view, seq), {}).setdefault(rid, {})
@@ -94,7 +103,12 @@ def build_timeline(files) -> dict:
             if rid is None:
                 continue
             ev = e.get("ev")
-            if ev == "consensus_span":
+            if ev == "commit_lag":
+                try:
+                    lags[(rid, int(e["seq"]))] = float(e["lag_s"])
+                except (KeyError, TypeError, ValueError):
+                    continue
+            elif ev == "consensus_span":
                 try:
                     key_view, key_seq = int(e["view"]), int(e["seq"])
                 except (KeyError, TypeError, ValueError):
@@ -115,7 +129,26 @@ def build_timeline(files) -> dict:
                             entry["executed"] = float(e["ts"])
                             entry["estimated"] = True
                 last_executed[rid] = cur
+    # A commit_lag line names no view: it belongs to the sequence number's
+    # last tentative execution by that replica (a rolled-back one ran again).
+    last: dict = {}  # (rid, seq) -> that execution's stamps
+    for (view, seq) in sorted(slots):
+        for rid, entry in slots[(view, seq)].items():
+            if _tentative(entry) and (rid, seq) in lags:
+                last[(rid, seq)] = entry
+    for key, entry in last.items():
+        entry["commit_lag"] = lags[key]
     return slots
+
+
+def _tentative(stamps: dict) -> bool:
+    """A span that closed at PREPARED: executed with no commit quorum yet."""
+    return (
+        "executed" in stamps
+        and "prepared" in stamps
+        and "committed" not in stamps
+        and not stamps.get("estimated")
+    )
 
 
 def analyze(
@@ -146,6 +179,10 @@ def analyze(
             }
             if stamps.get("estimated"):
                 rep["estimated"] = True
+            if _tentative(stamps):
+                rep["tentative"] = True
+                if "commit_lag" in stamps:
+                    rep["commit_lag_ms"] = round(stamps["commit_lag"] * 1e3, 3)
             durs = {}
             chain = [p for p in PHASE_ORDER if p in stamps]
             for a, b in zip(chain, chain[1:]):
@@ -161,10 +198,20 @@ def analyze(
             entry["executed_spread_ms"] = round(
                 (max(execed.values()) - first) * 1e3, 3
             )
+            # Like against like: a replica that executed on a commit
+            # quorum trails the tentative ones by the commit round, which
+            # is the mode and not a slow replica.
+            fastest = {
+                kind: min(
+                    (ts for rid, ts in execed.items() if _tentative(per[rid]) == kind),
+                    default=first,
+                )
+                for kind in (True, False)
+            }
             lagging = [
                 rid
                 for rid, ts in execed.items()
-                if (ts - first) * 1e3 > straggler_ms
+                if (ts - fastest[_tentative(per[rid])]) * 1e3 > straggler_ms
             ]
             if lagging:
                 entry["stragglers"] = sorted(lagging)
@@ -207,7 +254,21 @@ def analyze(
         for rid in entry.get("stragglers", ()):
             straggler_counts[str(rid)] = straggler_counts.get(str(rid), 0) + 1
     sized = [e["batch"] for e in breakdown if "batch" in e]
+    spans = [rep for e in breakdown for rep in e["replicas"].values()]
+    lag_ms = sorted(r["commit_lag_ms"] for r in spans if "commit_lag_ms" in r)
+    tentative = {
+        "spans": sum(1 for r in spans if r.get("tentative")),
+        "of": sum(1 for r in spans if "executed" in r and not r.get("estimated")),
+    }
+    if lag_ms:
+        tentative["commit_lag_ms"] = {
+            "n": len(lag_ms),
+            "p50": lag_ms[len(lag_ms) // 2],
+            "p90": lag_ms[min(len(lag_ms) - 1, int(0.9 * len(lag_ms)))],
+            "max": lag_ms[-1],
+        }
     return {
+        "tentative": tentative,
         "slots": breakdown,
         "replicas": replicas,
         "coverage_gaps": gaps,
@@ -245,6 +306,8 @@ def _fmt_slot(entry) -> str:
             seg = " ".join(
                 f"{k.split('->')[1]}+{v * 1e3:.1f}ms" for k, v in durs.items()
             )
+            if "commit_lag_ms" in rep:
+                seg += f" commit-quorum+{rep['commit_lag_ms']:.1f}ms"
             segs.append(f"r{rid}[{seg}]")
     if segs:
         parts.append(" ".join(segs))
@@ -337,6 +400,19 @@ def main(argv=None) -> dict:
         from pbft_tpu.utils import waterfall as wf_mod
 
         print(wf_mod.render(result["waterfall"]))
+    tent = result["tentative"]
+    if tent["spans"]:
+        line = (
+            f"tentative run: {tent['spans']} of {tent['of']} spans executed at "
+            "PREPARED (no committed stamp)"
+        )
+        lag = tent.get("commit_lag_ms")
+        if lag:
+            line += (
+                f"; commit lag p50={lag['p50']:.2f}ms p90={lag['p90']:.2f}ms "
+                f"max={lag['max']:.2f}ms over {lag['n']}"
+            )
+        print(line)
     if result["straggler_counts"]:
         worst = sorted(
             result["straggler_counts"].items(), key=lambda kv: -kv[1]

@@ -98,7 +98,29 @@ def _span_summary(spans, batches=None) -> str:
             f"e2e p50={_pct(e2e, 0.5) * 1e3:.2f}ms "
             f"p90={_pct(e2e, 0.9) * 1e3:.2f}ms"
         )
+    # Tentative runs: a span that closed at PREPARED has no committed stamp
+    # (so the two segments above are rightly absent for it); the commit
+    # quorum that followed is in the commit_lag lines.
+    tentative = sum(
+        1 for e in spans
+        if isinstance(e.get("prepared"), (int, float)) and "committed" not in e
+    )
+    if tentative:
+        parts.append(f"{tentative} executed at PREPARED (tentative)")
     return ", ".join(parts)
+
+
+def _commit_lag_summary(events) -> str:
+    lags = sorted(
+        e["lag_s"] for e in events
+        if e.get("ev") == "commit_lag" and isinstance(e.get("lag_s"), (int, float))
+    )
+    if not lags:
+        return ""
+    return (
+        f"commit lag p50={_pct(lags, 0.5) * 1e3:.2f}ms "
+        f"p90={_pct(lags, 0.9) * 1e3:.2f}ms over {len(lags)} sequence numbers"
+    )
 
 
 def report(files) -> dict:
@@ -157,6 +179,9 @@ def report(files) -> dict:
         if spans:
             print(f"{path.name}: {len(spans)} consensus spans: "
                   + _span_summary(spans, batches))
+        lag = _commit_lag_summary(events)
+        if lag:
+            print(f"{path.name}: {lag}")
         if vb:
             span = vb[-1]["ts"] - vb[0]["ts"] or 1e-9
             print(

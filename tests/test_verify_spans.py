@@ -884,7 +884,11 @@ FORMS = {
 # an entry pins its own digest here).
 ACCEPTED_PER_LAYER = 27
 ACCEPTED_DIGEST = "7f2b2c41f15ac07556e716381fd4915806724ddc56d4b01011fdd21d1e6e3800"
-ADDED_CONFIGS, ADDED_CELLS = ["f5-sig-wal"], ["f5-sig-wal.closed"]
+ADDED_CONFIGS = ["f5-sig-wal", "f1-mac-tentative"]
+ADDED_CELLS = ["f5-sig-wal.closed", "f1-mac-tentative.closed"]
+# The metrics of this table that PR 32's cell is listed under too (it reports
+# no verify trip, so none of the others).
+ALSO_IN_MAC_CELL = {"engine_idle_pct", "wal_flush_ms_mean"}
 
 
 @pytest.mark.parametrize(
@@ -897,6 +901,8 @@ def test_new_metric_has_its_reader_and_its_entry(name, form):
         *(NEW_METRICS | CLOSED_ONLY_METRICS)[name], "lower", "program_span"
     )[:4]
     moves, cells = FORMS[form]
+    if form == ".closed" and name in ALSO_IN_MAC_CELL:
+        cells = cells + ["f1-mac-tentative.closed"]
     spec = json.loads((CHIPBENCH / "metrics" / f"{name}{form}.json").read_text())
     assert spec["name"] == name + form
     assert (CHIPBENCH / "reducers" / f"{spec['reducer']}.py").is_file()
@@ -911,17 +917,18 @@ def test_new_metric_has_its_reader_and_its_entry(name, form):
 
 def test_accepted_benchmark_entries_are_unchanged():
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    # A configuration and a cell are appended, and the cell's name to the
-    # lists of the metrics it reports; taken off again, nothing has changed.
+    # Configurations and cells are appended, and a cell's name to the lists
+    # of the metrics it reports; taken off again, nothing has changed.
     assert [c["name"] for c in bench["configs"]][-len(ADDED_CONFIGS):] == ADDED_CONFIGS
     assert [c["name"] for c in bench["workloads"]][-len(ADDED_CELLS):] == ADDED_CELLS
 
     def as_accepted(metric: dict) -> dict:
         cells = metric.get("workloads")
-        if cells is None or cells[-1] not in ADDED_CELLS:
+        if cells is None or not set(cells) & set(ADDED_CELLS):
             return metric
-        assert not set(cells[:-1]) & set(ADDED_CELLS)
-        return dict(metric, workloads=cells[:-1])
+        kept = [c for c in cells if c not in ADDED_CELLS]
+        assert cells == kept + [c for c in ADDED_CELLS if c in cells]  # at the end, in order
+        return dict(metric, workloads=kept)
 
     accepted = dict(
         bench,
