@@ -40,7 +40,10 @@ from __future__ import annotations
 import argparse
 import asyncio
 import json
+import os
+import signal
 import socket
+import struct
 import threading
 import time
 from typing import Dict, List, Optional
@@ -75,9 +78,35 @@ _MAX_TOKENS = 1 << 17
 # socket and drops the connection instead of buffering without bound.
 MAX_CLIENT_LINE = 1 << 20
 
+# A frame above this drops the link it came on (corrupt length prefix).
+_MAX_FRAME = 1 << 24
+# The most one read takes (asyncio's own read size for a stream socket).
+_READ_SIZE = 256 << 10
+
+_U32 = struct.Struct(">I")
+_DECODER = json.JSONDecoder()
+
 
 def _frame_bytes(payload: bytes) -> bytes:
     return len(payload).to_bytes(4, "big") + payload
+
+
+def _parse(payload: bytes):
+    """What ``json.loads(payload)`` gives, or None where it raises. The
+    common case (UTF-8, exactly one value, nothing round it) is one
+    ``decode`` and one call of the one decoder; anything else goes to
+    ``json.loads`` itself, so what is accepted is what it accepts."""
+    try:
+        text = payload.decode()
+        obj, end = _DECODER.raw_decode(text)
+        if end == len(text):
+            return obj
+    except ValueError:
+        pass
+    try:
+        return json.loads(payload)
+    except ValueError:
+        return None
 
 
 def gateway_hello() -> dict:
@@ -92,19 +121,192 @@ def gateway_hello() -> dict:
     }
 
 
-class _UpstreamLink:
-    """One persistent framed link to a replica."""
+class _Peer(asyncio.BufferedProtocol):
+    """One connection of the gateway's. As a destination: the bytes bound
+    for it wait in ``pending``, in arrival order, for the loop turn's one
+    flush (``ClientGateway._flush``), which writes them as one block. As a
+    source: every connection reads into the gateway's ONE receive buffer
+    (a read and its handling are one uninterrupted step of the loop), and
+    ``data_received`` is given the bytes that came, copied out once. A
+    plain ``Protocol`` would have the transport allocate its whole
+    256 KiB read size anew for every read, ~10 us of a read that brings
+    a few hundred bytes."""
 
-    __slots__ = ("writer", "task")
+    __slots__ = ("gw", "transport", "pending")
 
-    def __init__(self, writer: asyncio.StreamWriter, task: asyncio.Task):
-        self.writer = writer
-        self.task = task
+    def __init__(self, gw: "ClientGateway"):
+        self.gw = gw
+        self.transport: Optional[asyncio.Transport] = None
+        self.pending: List[bytes] = []
+
+    def get_buffer(self, sizehint: int) -> bytearray:
+        return self.gw._read_buffer
+
+    def buffer_updated(self, nbytes: int) -> None:
+        self.data_received(self.gw._read_view[:nbytes].tobytes())
+
+
+class _ClientConn(_Peer):
+    """One downstream client connection: raw JSON lines in, reply lines
+    out."""
+
+    __slots__ = ("tail", "tokens")
+
+    def __init__(self, gw: "ClientGateway"):
+        super().__init__(gw)
+        self.tail = b""
+        self.tokens: List[str] = []
+
+    def connection_made(self, transport) -> None:
+        self.transport = transport
+        gw = self.gw
+        gw.clients_open += 1
+        gw._set_clients_gauge()
+        gw._inbound.add(self)
+
+    def data_received(self, data: bytes) -> None:
+        """Every complete line of the read in one split; the unfinished
+        tail is kept once."""
+        gw = self.gw
+        gw.reads += 1
+        if self.tail:
+            data = self.tail + data
+        *lines, self.tail = data.split(b"\n")
+        before = gw.forwarded
+        for line in lines:
+            line = line.strip()
+            if line:
+                gw._handle_line(line, self)
+        if gw.metrics_registry.enabled:
+            gw.metrics_registry.counter("pbft_gateway_forwarded_total").inc(
+                gw.forwarded - before
+            )
+        if len(self.tail) > MAX_CLIENT_LINE:
+            self.transport.close()  # oversized line: drop the connection
+
+    def connection_lost(self, exc) -> None:
+        gw = self.gw
+        gw.clients_open -= 1
+        gw._set_clients_gauge()
+        gw._inbound.discard(self)
+        for token in self.tokens:
+            if gw._routes.get(token) is self:
+                del gw._routes[token]
+
+
+class _UpstreamLink(_Peer):
+    """One persistent framed link to a replica. It exists from the moment
+    its dial starts: frames bound for it meanwhile are ``held`` (bounded
+    like a write buffer) and leave behind the hello."""
+
+    __slots__ = ("rid", "task", "tail", "need", "held")
+
+    def __init__(self, gw: "ClientGateway", rid: int):
+        super().__init__(gw)
+        self.rid = rid
+        self.task: Optional[asyncio.Task] = None
+        # An unfinished frame: what the reads have brought of it, and the
+        # size at which it is worth looking again.
+        self.tail = bytearray()
+        self.need = 0
+        self.held = bytearray()
+
+    def connection_made(self, transport) -> None:
+        self.transport = transport
+        hello = json.dumps(gateway_hello(), separators=(",", ":")).encode()
+        transport.write(_frame_bytes(hello) + self.held)
+        self.held = bytearray()
+
+    def data_received(self, data: bytes) -> None:
+        """Walk the read's frames by offset; the remainder is copied once.
+        Hello-acks are consumed, rejects are loud, and every reply frame
+        routes downstream by its token."""
+        gw = self.gw
+        gw.reads += 1
+        if self.tail:
+            self.tail += data
+            if len(self.tail) < self.need:
+                return
+            data = bytes(self.tail)
+            self.tail.clear()
+        routes = gw._routes
+        end = len(data)
+        off = 0
+        while end - off >= 4:
+            (n,) = _U32.unpack_from(data, off)
+            if n > _MAX_FRAME:
+                self.transport.close()  # corrupt frame: drop the link
+                return
+            stop = off + 4 + n
+            if stop > end:
+                break
+            payload = data[off + 4 : stop]
+            off = stop
+            obj = _parse(payload)
+            if not isinstance(obj, dict):
+                continue
+            kind = obj.get("type")
+            if kind == "hello":
+                continue  # the responder's version/codec ack
+            if kind == "reject":
+                print(
+                    f"gateway: replica {self.rid} rejected link: "
+                    f"{obj.get('reason')}",
+                    flush=True,
+                )
+                self.transport.close()
+                return
+            token = obj.get("client")
+            if not isinstance(token, str):
+                continue
+            view = obj.get("view")
+            if isinstance(view, int) and view > gw._view:
+                gw._view = view  # a view change re-aims fresh requests
+            if gw._admission:
+                ts = obj.get("timestamp")
+                if isinstance(ts, int):
+                    # Completion retires admission bookkeeping whether or
+                    # not the downstream client is still there to hear.
+                    gw._retire_inflight(token, ts)
+            conn = routes.get(token)
+            if conn is not None:  # else: not ours (fan-out copy) or gone
+                gw.replies_routed += 1
+                gw._queue(conn, payload + b"\n")
+        if off < end:
+            self.tail += data[off:]
+            # With four bytes or more left the walk stopped at a frame of
+            # n bytes that is not all here yet.
+            self.need = 4 + n if end - off >= 4 else 4
+
+    def connection_lost(self, exc) -> None:
+        gw = self.gw
+        if gw._links.get(self.rid) is self:
+            del gw._links[self.rid]
+        if not gw._stopping:
+            # Upstream replica link died mid-run (ISSUE 12): the keeper
+            # re-dials within a second — count the failover so a chaos
+            # arm can attribute the blip.
+            gw.upstream_failovers += 1
+            if gw.metrics_registry.enabled:
+                gw.metrics_registry.counter(
+                    "pbft_gateway_failovers_total"
+                ).inc()
+            if gw.flight is not None:
+                gw.flight.record(
+                    "gateway_failover", view=gw._view, peer=self.rid
+                )
 
 
 class ClientGateway:
     """One gateway process: a raw-JSON line server for clients in front
-    of n persistent framed replica links."""
+    of n persistent framed replica links.
+
+    Its unit of work is what one read delivered, not one message: a read
+    is split and parsed in one pass, and what it sends is appended to a
+    pending list of its destination; ONE flush a loop turn, scheduled
+    with ``call_soon`` by the turn's first append, writes each list as
+    one block. No timer, no hold, no threshold: a message alone in its
+    turn leaves in that turn's flush."""
 
     def __init__(
         self,
@@ -133,18 +335,23 @@ class ClientGateway:
         self.metrics_port = metrics_port
         self._metrics_server = None
         self.metrics_listen_port = 0
+        self._loop: Optional[asyncio.AbstractEventLoop] = None
         self._server: Optional[asyncio.Server] = None
         # Accepted client connections, so stop() can close them: since
-        # Python 3.12 Server.wait_closed() waits for every handler.
+        # Python 3.12 Server.wait_closed() waits for every one.
         self._inbound: set = set()
-        # token -> downstream writer (the reply route), and the per-token
-        # forwarded-timestamp high-water mark (retransmission detection).
-        self._routes: Dict[str, asyncio.StreamWriter] = {}
+        # token -> downstream connection (the reply route), and the
+        # per-token forwarded-timestamp high-water mark (retransmission
+        # detection).
+        self._routes: Dict[str, _ClientConn] = {}
         self._last_ts: Dict[str, int] = {}
-        # rid -> _UpstreamLink, each guarded by a per-rid lock so one
-        # dial+hello runs per replica.
+        # rid -> _UpstreamLink, dialing or up: one dial runs per replica.
         self._links: Dict[int, _UpstreamLink] = {}
-        self._link_locks: Dict[int, asyncio.Lock] = {}
+        # Where every connection's reads land (_Peer.get_buffer).
+        self._read_buffer = bytearray(_READ_SIZE)
+        self._read_view = memoryview(self._read_buffer)
+        # Destinations with pending bytes; non-empty = a flush is scheduled.
+        self._dirty: List[_Peer] = []
         # Current view, tracked from routed replies: fresh requests go to
         # view % n, so a view change re-aims the firehose without any
         # client knowing.
@@ -155,6 +362,11 @@ class ClientGateway:
         self.forwarded = 0
         self.replies_routed = 0
         self.backpressure_events = 0
+        # How often the mechanism engages: reads handled and flushes that
+        # reached a transport. Messages a write = (forwarded +
+        # replies_routed) / writes.
+        self.reads = 0
+        self.writes = 0
         # Admission control (ISSUE 12): per-token in-flight cap +
         # a global queue-depth watermark. A FRESH request past either
         # bound is answered with an explicit {"type": "overloaded"} line
@@ -162,9 +374,11 @@ class ClientGateway:
         # in-flight (token, ts) always pass — liveness is never
         # admission-gated. In-flight entries prune when a reply routes
         # (per-client execution is timestamp-ordered, so a reply for ts
-        # retires every entry at or below it). 0 disables either bound.
+        # retires every entry at or below it). 0 disables either bound,
+        # and with both at 0 nothing is kept in flight at all.
         self.max_inflight = max_inflight
         self.max_queue_depth = max_queue_depth
+        self._admission = max_inflight > 0 or max_queue_depth > 0
         self._inflight: Dict[str, set] = {}
         self._inflight_total = 0
         self.overload_rejections = 0
@@ -182,8 +396,9 @@ class ClientGateway:
     # -- lifecycle -----------------------------------------------------------
 
     async def start(self) -> "ClientGateway":
-        self._server = await asyncio.start_server(
-            self._on_client, host=self.host, port=self.port
+        self._loop = asyncio.get_running_loop()
+        self._server = await self._loop.create_server(
+            lambda: _ClientConn(self), host=self.host, port=self.port
         )
         self.listen_port = self._server.sockets[0].getsockname()[1]
         if self.metrics_port is not None:
@@ -199,18 +414,13 @@ class ClientGateway:
         # reply fan-back for requests it saw via pre-prepare), so lazy
         # dial-on-send would leave backup replies with nowhere to go and
         # the client short of its f+1 quorum.
-        self._keeper_task = asyncio.get_running_loop().create_task(
-            self._link_keeper()
-        )
+        self._keeper_task = self._loop.create_task(self._link_keeper())
         return self
 
     async def _link_keeper(self) -> None:
         while not self._stopping:
             for rid in range(self.config.n):
-                try:
-                    await self._ensure_link(rid)
-                except OSError:
-                    pass  # replica down: PBFT tolerates f of these
+                self._link(rid)
             await asyncio.sleep(1.0)
 
     async def stop(self) -> None:
@@ -222,12 +432,13 @@ class ClientGateway:
             self._metrics_server.server_close()
         if self._server:
             self._server.close()
-            for writer in list(self._inbound):
-                writer.close()
+            for conn in list(self._inbound):
+                conn.transport.close()
             await self._server.wait_closed()
-        for link in self._links.values():
-            link.writer.close()
+        for link in list(self._links.values()):
             link.task.cancel()
+            if link.transport is not None:
+                link.transport.close()
         self._links.clear()
 
     def metrics(self) -> dict:
@@ -242,13 +453,61 @@ class ClientGateway:
             "gateway_clients_open": self.clients_open,
             "gateway_forwarded": self.forwarded,
             "replies_routed": self.replies_routed,
+            "reads": self.reads,
+            "writes": self.writes,
             "backpressure_events": self.backpressure_events,
-            "upstream_links": len(self._links),
+            "upstream_links": sum(
+                link.transport is not None for link in self._links.values()
+            ),
             "overload_rejections": self.overload_rejections,
             "gateway_failovers": self.upstream_failovers,
             "inflight": self._inflight_total,
             "view": self._view,
         }
+
+    # -- the one write a destination a loop turn -----------------------------
+
+    def _queue(self, peer: _Peer, data: bytes) -> None:
+        pending = peer.pending
+        if not pending:
+            if not self._dirty:
+                self._loop.call_soon(self._flush)
+            self._dirty.append(peer)
+        pending.append(data)
+
+    def _flush(self) -> None:
+        """Write what the turn's reads left pending, one block a
+        destination. Bounded outbound against a slow reader, judged once
+        a destination against the size this write would reach
+        (drop-and-count): a dropped reply is re-fetched from the
+        replicas' reply caches on retransmission, a dropped request is
+        retransmission-covered."""
+        dirty, self._dirty = self._dirty, []
+        registry = self.metrics_registry
+        for peer in dirty:
+            chunks, peer.pending = peer.pending, []
+            data = b"".join(chunks)
+            transport = peer.transport
+            if transport is None:  # an upstream link still dialing
+                room = _MAX_WRITE_BUFFER - len(peer.held)
+            elif transport.is_closing():
+                continue  # replica down or client gone
+            else:
+                room = _MAX_WRITE_BUFFER - transport.get_write_buffer_size()
+            # Counters move before the write: a peer may ask at once.
+            if len(data) > room:
+                self.backpressure_events += len(chunks)
+                if registry.enabled:
+                    registry.counter(
+                        "pbft_write_backpressure_events_total"
+                    ).inc(len(chunks))
+            elif transport is None:
+                peer.held += data
+            else:
+                self.writes += 1
+                if registry.enabled:
+                    registry.counter("pbft_gateway_writes_total").inc()
+                transport.write(data)
 
     # -- downstream (clients) ------------------------------------------------
 
@@ -258,64 +517,8 @@ class ClientGateway:
                 self.clients_open
             )
 
-    def _writer_has_room(self, writer: asyncio.StreamWriter) -> bool:
-        """Bounded outbound against a slow reader (drop-and-count): the
-        dropped reply is re-fetched from the replicas' reply caches on
-        retransmission, a dropped request is retransmission-covered."""
-        try:
-            size = writer.transport.get_write_buffer_size()
-        except (AttributeError, RuntimeError):
-            return True
-        if size > _MAX_WRITE_BUFFER:
-            self.backpressure_events += 1
-            if self.metrics_registry.enabled:
-                self.metrics_registry.counter(
-                    "pbft_write_backpressure_events_total"
-                ).inc()
-            return False
-        return True
-
-    async def _on_client(
-        self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter
-    ) -> None:
-        self.clients_open += 1
-        self._set_clients_gauge()
-        self._inbound.add(writer)
-        owned_tokens: List[str] = []
-        try:
-            buf = b""
-            while True:
-                nl = buf.find(b"\n")
-                if nl >= 0:
-                    line, buf = buf[:nl], buf[nl + 1 :]
-                    await self._handle_line(line.strip(), writer, owned_tokens)
-                    continue
-                if len(buf) > MAX_CLIENT_LINE:
-                    return  # oversized line: drop the connection
-                chunk = await reader.read(65536)
-                if not chunk:
-                    return
-                buf += chunk
-        except (ConnectionError, OSError, asyncio.IncompleteReadError):
-            pass
-        finally:
-            self.clients_open -= 1
-            self._set_clients_gauge()
-            self._inbound.discard(writer)
-            for token in owned_tokens:
-                if self._routes.get(token) is writer:
-                    self._routes.pop(token, None)
-            writer.close()
-
-    async def _handle_line(
-        self, line: bytes, writer: asyncio.StreamWriter, owned_tokens: List[str]
-    ) -> None:
-        if not line:
-            return
-        try:
-            obj = json.loads(line)
-        except (ValueError, UnicodeDecodeError):
-            return
+    def _handle_line(self, line: bytes, conn: _ClientConn) -> None:
+        obj = _parse(line)
         if not isinstance(obj, dict):
             return
         token = obj.get("client")
@@ -326,51 +529,54 @@ class ClientGateway:
             # per-client-socket cost the tier exists to remove — and an
             # unauthenticated redirect channel. Drop it.
             return
-        if token not in self._routes:
-            owned_tokens.append(token)
-        if len(self._routes) >= _MAX_TOKENS:
-            self._routes.clear()
-        self._routes[token] = writer
+        routes = self._routes
+        if routes.get(token) is not conn:
+            if token not in routes:
+                conn.tokens.append(token)
+            if len(routes) >= _MAX_TOKENS:
+                routes.clear()
+            routes[token] = conn
         ts = obj.get("timestamp")
-        retransmission = (
-            isinstance(ts, int) and self._last_ts.get(token, -1) >= ts
-        )
-        if not retransmission and isinstance(ts, int):
-            # Admission control (ISSUE 12): a fresh request past the
-            # per-token in-flight cap or the global watermark is rejected
-            # with an explicit overloaded line instead of queueing into
-            # the cluster's tail. Retransmissions always pass.
-            pend = self._inflight.setdefault(token, set())
-            if ts not in pend and (
-                (self.max_inflight > 0 and len(pend) >= self.max_inflight)
-                or (
-                    self.max_queue_depth > 0
-                    and self._inflight_total >= self.max_queue_depth
-                )
-            ):
-                self._reject_overloaded(token, ts, writer)
-                return
-            if ts not in pend:
-                pend.add(ts)
-                self._inflight_total += 1
-        framed = _frame_bytes(bytes(line))
+        fresh = True
+        if isinstance(ts, int):
+            if self._last_ts.get(token, -1) >= ts:
+                fresh = False
+            else:
+                if self._admission and not self._admit(token, ts, conn):
+                    return
+                if len(self._last_ts) >= _MAX_TOKENS:
+                    self._last_ts.clear()
+                self._last_ts[token] = ts
         self.forwarded += 1
-        if self.metrics_registry.enabled:
-            self.metrics_registry.counter("pbft_gateway_forwarded_total").inc()
-        if isinstance(ts, int) and not retransmission:
-            if len(self._last_ts) >= _MAX_TOKENS:
-                self._last_ts.clear()
-            self._last_ts[token] = ts
-        if retransmission:
+        framed = _frame_bytes(line)
+        if fresh:
+            self._queue(self._link(self._view % self.config.n), framed)
+        else:
             # The paper's client liveness rule by proxy: a retransmitted
             # request broadcasts to every replica, forcing forwards and
             # eventually a view change on a faulty primary.
             for rid in range(self.config.n):
-                await self._send_upstream(rid, framed)
-        else:
-            await self._send_upstream(self._view % self.config.n, framed)
+                self._queue(self._link(rid), framed)
 
-    def _reject_overloaded(self, token: str, ts: int, writer) -> None:
+    def _admit(self, token: str, ts: int, conn: _ClientConn) -> bool:
+        """Admission control (ISSUE 12): a fresh request past the
+        per-token in-flight cap or the global watermark is rejected with
+        an explicit overloaded line instead of queueing into the
+        cluster's tail. Retransmissions always pass."""
+        pend = self._inflight.setdefault(token, set())
+        if ts in pend:
+            return True
+        if (self.max_inflight > 0 and len(pend) >= self.max_inflight) or (
+            self.max_queue_depth > 0
+            and self._inflight_total >= self.max_queue_depth
+        ):
+            self._reject_overloaded(token, ts, conn)
+            return False
+        pend.add(ts)
+        self._inflight_total += 1
+        return True
+
+    def _reject_overloaded(self, token: str, ts: int, conn: _ClientConn) -> None:
         """Answer a rejected request with an explicit overloaded line —
         the client backs off with jitter (request_with_retry) instead of
         interpreting silence as a faulty primary."""
@@ -381,23 +587,19 @@ class ClientGateway:
             ).inc()
         if self.flight is not None:
             self.flight.record("overload_rejected", view=self._view, seq=ts)
-        if writer.is_closing() or not self._writer_has_room(writer):
-            return
-        try:
-            writer.write(
-                json.dumps(
-                    {
-                        "type": "overloaded",
-                        "client": token,
-                        "timestamp": ts,
-                        "replica": -1,
-                    },
-                    separators=(",", ":"),
-                ).encode()
-                + b"\n"
-            )
-        except (ConnectionError, OSError, RuntimeError):
-            pass
+        self._queue(
+            conn,
+            json.dumps(
+                {
+                    "type": "overloaded",
+                    "client": token,
+                    "timestamp": ts,
+                    "replica": -1,
+                },
+                separators=(",", ":"),
+            ).encode()
+            + b"\n",
+        )
 
     def _retire_inflight(self, token: str, ts: int) -> None:
         """A reply for (token, ts) routed downstream: per-client execution
@@ -415,131 +617,26 @@ class ClientGateway:
 
     # -- upstream (replicas) -------------------------------------------------
 
-    async def _send_upstream(self, rid: int, framed: bytes) -> None:
-        link = await self._ensure_link(rid)
-        if link is None:
-            return  # replica down: PBFT tolerates f of these
-        if link.writer.is_closing() or not self._writer_has_room(link.writer):
-            return  # drop-and-count: retransmission absorbs the loss
-        try:
-            link.writer.write(framed)
-        except (ConnectionError, OSError, RuntimeError):
-            self._drop_link(rid, link)
-
-    async def _ensure_link(self, rid: int) -> Optional[_UpstreamLink]:
+    def _link(self, rid: int) -> _UpstreamLink:
+        """The link to replica ``rid``: a dict lookup where it is up or
+        dialing; only a dial makes a task."""
         link = self._links.get(rid)
-        if link is not None and not link.writer.is_closing():
-            return link
-        lock = self._link_locks.setdefault(rid, asyncio.Lock())
-        async with lock:
-            link = self._links.get(rid)
-            if link is not None and not link.writer.is_closing():
-                return link
-            ident = self.config.identity(rid)
-            try:
-                reader, writer = await asyncio.open_connection(
-                    ident.host, ident.port
-                )
-            except OSError:
-                return None
-            writer.write(
-                _frame_bytes(
-                    json.dumps(
-                        gateway_hello(), separators=(",", ":")
-                    ).encode()
-                )
-            )
-            task = asyncio.get_running_loop().create_task(
-                self._link_reader(rid, reader)
-            )
-            link = _UpstreamLink(writer, task)
-            self._links[rid] = link
-            return link
+        if link is None:
+            link = self._links[rid] = _UpstreamLink(self, rid)
+            link.task = self._loop.create_task(self._dial(link))
+        return link
 
-    def _drop_link(self, rid: int, link: _UpstreamLink) -> None:
-        if self._links.get(rid) is link:
-            self._links.pop(rid, None)
-        link.writer.close()
-
-    async def _link_reader(self, rid: int, reader: asyncio.StreamReader) -> None:
-        """Drain one upstream link: hello-acks are consumed, rejects are
-        loud, and every reply frame routes downstream by its token."""
-        buf = b""
+    async def _dial(self, link: _UpstreamLink) -> None:
+        ident = self.config.identity(link.rid)
         try:
-            while True:
-                while len(buf) < 4:
-                    chunk = await reader.read(65536)
-                    if not chunk:
-                        return
-                    buf += chunk
-                n = int.from_bytes(buf[:4], "big")
-                if n > (1 << 24):
-                    return  # corrupt frame: drop the link
-                while len(buf) < 4 + n:
-                    chunk = await reader.read(65536)
-                    if not chunk:
-                        return
-                    buf += chunk
-                payload, buf = buf[4 : 4 + n], buf[4 + n :]
-                try:
-                    obj = json.loads(payload)
-                except (ValueError, UnicodeDecodeError):
-                    continue
-                if not isinstance(obj, dict):
-                    continue
-                kind = obj.get("type")
-                if kind == "hello":
-                    continue  # the responder's version/codec ack
-                if kind == "reject":
-                    print(
-                        f"gateway: replica {rid} rejected link: "
-                        f"{obj.get('reason')}",
-                        flush=True,
-                    )
-                    return
-                self._route_reply(obj, payload)
-        except (ConnectionError, OSError, asyncio.IncompleteReadError):
-            pass
-        finally:
-            link = self._links.get(rid)
-            if link is not None and link.task is asyncio.current_task():
-                self._links.pop(rid, None)
-            if not self._stopping:
-                # Upstream replica link died mid-run (ISSUE 12): the
-                # keeper re-dials within a second — count the failover so
-                # a chaos arm can attribute the blip.
-                self.upstream_failovers += 1
-                if self.metrics_registry.enabled:
-                    self.metrics_registry.counter(
-                        "pbft_gateway_failovers_total"
-                    ).inc()
-                if self.flight is not None:
-                    self.flight.record(
-                        "gateway_failover", view=self._view, peer=rid
-                    )
-
-    def _route_reply(self, obj: dict, payload: bytes) -> None:
-        token = obj.get("client")
-        if not isinstance(token, str):
-            return
-        view = obj.get("view")
-        if isinstance(view, int) and view > self._view:
-            self._view = view  # a view change re-aims fresh requests
-        ts = obj.get("timestamp")
-        if isinstance(ts, int):
-            # Completion retires admission bookkeeping whether or not the
-            # downstream client is still connected to hear about it.
-            self._retire_inflight(token, ts)
-        w = self._routes.get(token)
-        if w is None or w.is_closing():
-            return  # token not ours (fan-out copy) or client gone
-        if not self._writer_has_room(w):
-            return  # slow client: drop; retransmission re-fetches
-        try:
-            w.write(payload + b"\n")
-            self.replies_routed += 1
-        except (ConnectionError, OSError, RuntimeError):
-            pass
+            await self._loop.create_connection(
+                lambda: link, ident.host, ident.port
+            )
+        except OSError:
+            # Replica down: PBFT tolerates f of these. What was held for
+            # it goes; retransmission absorbs the loss.
+            if self._links.get(link.rid) is link:
+                del self._links[link.rid]
 
 
 # -- the client side of the tier ---------------------------------------------
@@ -781,6 +878,20 @@ async def _amain(args, config_text: str, flight=None) -> None:
         flight=flight,
     )
     await gw.start()
+
+    def last_words(signum, frame):
+        """Told to stop, the gateway leaves its counters as one last
+        line (nothing scrapes a benchmark run's gateway; its log is
+        kept), then does what SIGTERM did before: the flight recorder's
+        dump-and-exit where there is one, else dies at once."""
+        try:
+            os.write(1, json.dumps(gw.metrics()).encode() + b"\n")
+            if callable(previous):
+                previous(signum, frame)
+        finally:
+            os._exit(128 + signum)
+
+    previous = signal.signal(signal.SIGTERM, last_words)
     print(f"gateway listening on {gw.listen_port}", flush=True)
     if gw.metrics_listen_port:
         # pbft_top / endurance_soak parse this to find /status (ISSUE 16).

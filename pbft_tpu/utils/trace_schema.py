@@ -250,6 +250,10 @@ METRIC_SCHEMAS = {
         "counter",
         {"gateway.py", "server.py", "net.cc"},
     ),
+    # The gateway works by the read (ISSUE 33): one write a destination a
+    # loop turn, so messages a write = (forwarded + replies routed) /
+    # writes says how many messages a flush carried (1.0 before it).
+    "pbft_gateway_writes_total": ("counter", {"gateway.py"}),
     # Perf-under-faults surface (ISSUE 12). Backoff level: the view
     # timer's current exponential multiplier (1 = fresh, doubles per
     # consecutive no-progress expiry, §4.5.2) — a sustained high level is
