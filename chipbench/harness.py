@@ -52,10 +52,18 @@ WORK = ROOT / ".chipbench_work"
 KEEP = ROOT / "chiprun_out" / "chipbench"
 WARM_BUDGET_S = 1000.0
 # One traced slice, a fifth into the window. Every operation of every launch
-# is an event (86,000 a launch), and verifyd takes some 11 s a launch in the
-# slice to write them out while the run goes on: a longer slice, a later one
-# or a second one would take the run past the time a run may take.
+# is an event on every device plane (83,500 a launch a plane), and verifyd
+# collects them into the xspace while the run goes on (PERF.md section 3 has
+# the seconds a launch a plane): a longer slice, a later one or a second one
+# would take the run past the time a run may take.
 TRACE_S = 0.2
+# How long the harness waits for the slice's ``done`` file once the replicas
+# have quiesced, which is some 41 s after the slice ended (the window's last
+# four fifths and the drain). The most a run can afford that has to end inside
+# the 360 s a run may take: 360 less 90 of set-up, the 51 of the window, 5 of
+# quiescence and 30 for reading the trace and stopping the children. One chip
+# needs 0 to 8 s of it (PERF.md section 3).
+TRACE_WAIT_S = 180.0
 TRACE_AT = 0.2
 REPLY_SAMPLE = 64
 
@@ -566,17 +574,24 @@ def serve_window(
             if trace:
                 import xplane
 
-                verifyd.await_file(trace_dir / "done", 300)
+                waited = time.monotonic()
+                verifyd.await_file(trace_dir / "done", TRACE_WAIT_S)
                 stamps = json.loads((trace_dir / "done").read_text())
                 log(f"traced slice of {TRACE_S}s ended {t1 - stamps['off']:.1f}s before the window "
-                    f"did and was written {stamps['stopped'] - stamps['off']:.1f}s after it ended")
+                    f"did and was written {stamps['stopped'] - stamps['off']:.1f}s after it ended "
+                    f"({stamps['xspace_bytes']} bytes, collected in "
+                    f"{stamps['collected'] - stamps['off']:.1f}s; the harness waited "
+                    f"{time.monotonic() - waited:.1f}s of {TRACE_WAIT_S:.0f}s for it)")
                 try:
                     # A slice beyond the window, or no operation on a TPU in
                     # it: fatal on the chip; the CPU rehearsal goes on
                     # without device numbers.
                     if stamps["off"] > t1:
                         raise ValueError("the traced slice ended after the window did")
-                    reduced = xplane.reduce_trace(xplane.find_xplane(trace_dir))
+                    reduced = xplane.reduce_trace(
+                        xplane.find_xplane(trace_dir), verifyd.launches(), config["ladder"],
+                        stamps["off"],
+                    )
                 except ValueError as e:
                     if ready.get("platform") == "tpu":
                         raise BenchFailure(str(e)) from None
@@ -706,6 +721,10 @@ def run_cell(
             f"devices={ready['devices_seen']} warmed={ready['warmed_shapes']} "
             f"compiled={warm.get('compiled')} cache_hits={warm.get('cache_hits')} "
             f"cache_dir={warm.get('cache_dir')} after {time.monotonic() - t_start:.1f}s")
+        log("one launch of each shape at warm-up, ms: "
+            f"{ {p['size']: round(1e3 * p['launch_s'], 3) for p in warm.get('per_shape', []) if 'launch_s' in p} }"
+            f" on {ready.get('devices')} device(s); serving table {warm.get('serving_table')}; "
+            f"chunk plan {warm.get('chunk_plan')}")
         if require_tpu:
             peaks = peaks_for(loaded["dir"], ready["platform"], ready["device_kind"])
             if ready["devices_seen"] < loaded["cell"]["chips"]:
@@ -757,19 +776,27 @@ def run_cell(
 
         device["busy_s"] = run["trace"]["busy_s"]
         device["window_s"] = run["trace"]["window_s"]
-        line["breakdown"] = xplane.breakdown(run["trace"], run["ladder"])
-        by_rung = xplane.device_seconds_by_rung(run["trace"], None, run["ladder"])
-        log("device time of a launch by rung in the slice: " + ", ".join(
-            f"{rung}: {1e3 * sum(v) / len(v):.2f} ms x{len(v)}" for rung, v in sorted(by_rung.items())
-        ) + f"; {sum(x['items'] is None for x in run['trace']['launches'])} launches in no span")
+        line["breakdown"] = xplane.breakdown(run["trace"])
+        seen = run["trace"]["launches"]
+        log(f"device time of a launch by the shape it ran at, in the slice, on {run['trace']['planes']} "
+            f"of {run['trace']['devices']} device plane(s): " + ", ".join(
+                f"{slots}: {1e3 * sum(v) / len(v):.2f} ms x{len(v)}"
+                for slots, v in sorted(xplane.launches_by_shape(run["trace"], None).items())
+            ) + f"; of {len(seen)} launches {sum(not x['slots'] for x in seen)} with no shape, "
+            f"{sum(not x['spans'] for x in seen)} in no span, {sum(x['spans'] > 1 for x in seen)} in two, "
+            f"{run['trace']['cut']} more left out because the trace's end cut them short; "
+            "in order: " + " ".join(f"{x['slots']}:{1e3 * x['seconds']:.2f}" for x in seen))
     log(f"window: {len(gen['due'])} requests sent in all, {len(due_in)} due in the window, "
         f"{failed} of them failed, {gen['rejected']} refused by the gateway; "
         f"setup_s {run['setup_s']:.1f}; cpu seconds in the window {run['cpu_window']}")
-    launch = stats.launch_stats(run["launches"], run["ladder"])
+    launch = stats.launch_stats(run["launches"])
     log(f"launches in the window: {launch.get('launches')} of {launch.get('items_per_launch', 0):.1f} "
         f"items, fill {launch.get('pad_fill', 0):.3f}, by rung {launch.get('rungs')}")
     for name, value, op, limit in comparisons:
         ok = "ok" if _OPS[op](value, limit) else "NOT OK"
         log(f"compare {name}: {value} (limit {op} {limit}) {ok}")
+    line["compared"] = {
+        name: {"value": value, "limit": f"{op} {limit}"} for name, value, op, limit in comparisons
+    }
     line["_run"] = run
     return line

@@ -1,27 +1,29 @@
 """What the two kernel readers share: the device time of a launch at each
-padded shape (rung), from the traced slice, set against the rungs that ALL
-the window's launches ran at, from ``verifyd --trace``'s lines.
+padded shape, from the traced slice, set against the shapes that ALL the
+window's launches ran at, from ``verifyd --trace``'s lines.
 
-The slice holds a dozen launches, the window some thousands, and an
-executable's device time hardly moves from launch to launch while the mix of
-rungs does from slice to slice. So the time per rung comes from the slice
-and the mix from the whole window; a rung the slice never saw is left out on
-both sides.
+A launch here is one executable on the device: a window that ran as chunks
+is as many launches, each at its own shape (``xplane.shapes_run`` reads them
+off the line's ``rung`` and ``chunks``; ``xplane.reduce_trace`` gives every
+launch of the slice the shape its executable ran at). The slice holds a dozen
+launches, the window some thousands, and an executable's device time hardly
+moves from launch to launch while the mix of shapes does from slice to slice.
+So the time per shape comes from the slice and the mix from the whole window;
+a shape the slice never saw is left out on both sides.
 """
 
 import statistics
+from collections import Counter
 
-import stats
 import xplane
 
 
 def weighted(run: dict, module: str) -> list:
-    """-> [(rung, launches in the window, mean device seconds)]"""
+    """-> [(slots, launches in the window, mean device seconds)]"""
     if not run["trace"]:
         return []
-    seen = xplane.device_seconds_by_rung(run["trace"], module, run["ladder"])
-    window: dict = {}
-    for e in run["launches"]:
-        rung = stats.rung_of(e["size"], run["ladder"])
-        window[rung] = window.get(rung, 0) + 1
-    return [(r, n, statistics.fmean(seen[r])) for r, n in sorted(window.items()) if r in seen]
+    seen = xplane.launches_by_shape(run["trace"], module)
+    window = Counter(
+        slots for e in run["launches"] for slots in xplane.shapes_run(e, run["ladder"])
+    )
+    return [(s, window[s], statistics.fmean(seen[s])) for s in sorted(seen) if window[s]]

@@ -1,10 +1,12 @@
-"""A kernel's share of its roofline: the least time the chip could take for
-the window's launches (operations over the peak rate, or bytes over the peak
-bandwidth, whichever is longer) over the device time the executable
-(``module``) takes for them, each launch at the device time its padded shape
-showed in the traced slice (``_rungs.py``). The operations and bytes come
-from ``kernel_ops.<ops>`` at the padded shape, the peaks from ``peaks.json``
-by device kind. Nothing without a trace."""
+"""A kernel's share of its roofline, per chip: the least time ONE chip could
+take for its part of the window's launches (operations over the peak rate,
+or bytes over the peak bandwidth, whichever is longer) over the device time
+the executable (``module``) takes for them, each launch at the device time its
+padded shape showed in the traced slice (``_rungs.py``). A launch sharded over
+N chips is ``slots / N`` rows a chip (the trace says on how many planes a
+launch ran) and its device time the longest of its planes, so the operations
+and bytes are ``kernel_ops.<ops>`` of ``slots / N`` rows, against the peaks
+of one chip from ``peaks.json`` by device kind. Nothing without a trace."""
 
 import kernel_ops
 from reducers import _rungs
@@ -16,7 +18,8 @@ def reduce(run: dict, args: dict):
     if not rows or not peaks:
         return None
     count = getattr(kernel_ops, args["ops"])
-    need = [(n, count(rung)) for rung, n, _ in rows]
+    chips = run["trace"]["planes"]
+    need = [(n, count(-(-slots // chips))) for slots, n, _ in rows]
     least = max(
         sum(n * c["ops"] for n, c in need) / peaks[args["ops_peak"]],
         sum(n * c["bytes"] for n, c in need) / peaks["hbm_bytes_per_s"],

@@ -1,7 +1,7 @@
 """Device time of one launch of a compiled executable (``module``), in
-milliseconds: the mean over the window's launches, each taken at the device
-time its padded shape showed in the traced slice (``_rungs.py``). Nothing
-without a trace."""
+milliseconds: the mean over the window's launches (a chunk of a split window
+is a launch), each taken at the device time its padded shape showed in the
+traced slice (``_rungs.py``). Nothing without a trace."""
 
 from reducers import _rungs
 

@@ -70,39 +70,34 @@ def counter_delta(before: dict, after: dict, name: str) -> float:
     return after.get((name, ""), 0.0) - before.get((name, ""), 0.0)
 
 
-def rung_of(size: int, ladder) -> int:
-    """The padded shape a launch of ``size`` items ran at: the smallest
-    rung that holds it; beyond the top it runs in top-rung chunks."""
-    for rung in ladder:
-        if size <= rung:
-            return rung
-    top = ladder[-1]
-    return -(-size // top) * top
-
-
-def launch_stats(launches, ladder) -> dict:
-    """From ``verify_batch`` records ({size, requests, secs}): items per
-    launch, useful items over padded slots, ``secs`` percentiles and the
-    histogram of rungs with the median ``secs`` on each."""
+def launch_stats(launches) -> dict:
+    """From ``verify_batch`` records ({size, requests, secs, rung}): items
+    per launch, useful items over the padded slots that RAN (the record's
+    ``rung``, summed over a window's chunks; records without it, from a
+    backend that is not the sharded engine, are left out of the fill),
+    ``secs`` percentiles and the histogram of slots run with the median
+    ``secs`` on each."""
     if not launches:
         return {}
     sizes = [e["size"] for e in launches]
-    rungs = [rung_of(s, ladder) for s in sizes]
-    by_rung: dict = {}
-    for rung, e in zip(rungs, launches):
-        by_rung.setdefault(rung, []).append(e["secs"])
-    return {
+    padded = [e for e in launches if e.get("rung")]
+    by_slots: dict = {}
+    for e in launches:
+        by_slots.setdefault(e.get("rung"), []).append(e["secs"])
+    out = {
         "launches": len(launches),
         "items": sum(sizes),
         "items_per_launch": sum(sizes) / len(launches),
-        "pad_fill": sum(sizes) / sum(rungs),
         "window_max_items": max(sizes),
         "launch_ms_p50": 1e3 * percentile([e["secs"] for e in launches], 50),
         "rungs": {
-            str(rung): {
+            str(slots): {
                 "launches": len(secs),
                 "launch_ms_p50": 1e3 * percentile(secs, 50),
             }
-            for rung, secs in sorted(by_rung.items())
+            for slots, secs in sorted(by_slots.items(), key=lambda kv: kv[0] or 0)
         },
     }
+    if padded:
+        out["pad_fill"] = sum(e["size"] for e in padded) / sum(e["rung"] for e in padded)
+    return out

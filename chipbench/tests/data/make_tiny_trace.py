@@ -7,8 +7,9 @@ A [1.0, 4.0] with 10 items, B [1.2, 8.6] with 80 (two launches in flight),
 C [8.8, 12.0] with 10. One TPU plane with four launches of ``jit_fn``:
 m0 [0.6, 0.9] in no span, m1 [1.5, 3.5] held by A and B (A ends first: A's),
 m2 [3.6, 8.4] in B, m3 [9.0, 10.5] across the slice's edge. Operations:
-fusion.1 [0.6, 0.9], [1.5, 2.0], [3.6, 4.0]; while.2 [2.1, 3.5], [4.0, 8.4],
-[9.0, 10.5]: busy 75 ms of the slice's 90.
+fusion.1 [0.6, 0.75], [0.75, 0.9], [1.5, 2.0], [3.6, 4.0]; while.2 [2.1, 3.5],
+[4.0, 8.4], [9.0, 10.5]: busy 75 ms of the slice's 90. Every launch inside
+the slice shows both operations of its executable (``xplane.WHOLE``).
 """
 
 from pathlib import Path
@@ -45,7 +46,8 @@ def main() -> None:
     plane(space, "/device:TPU:0", {
         "XLA Modules": [("jit_fn(123)", 0.6, 0.9, {}), ("jit_fn(123)", 1.5, 3.5, {}),
                         ("jit_fn(456)", 3.6, 8.4, {}), ("jit_fn(123)", 9.0, 10.5, {})],
-        "XLA Ops": [("%fusion.1 = s32[8,16,32]{2,1,0} fusion(s32[] %p)", 0.6, 0.9, {}),
+        "XLA Ops": [("%fusion.1 = s32[8,16,32]{2,1,0} fusion(s32[] %p)", 0.6, 0.75, {}),
+                    ("%fusion.1 = s32[8,16,32]{2,1,0} fusion(s32[] %p)", 0.75, 0.9, {}),
                     ("%fusion.1 = s32[8,16,32]{2,1,0} fusion(s32[] %p)", 1.5, 2.0, {}),
                     ("%while.2 = (s32[]) while((s32[]) %t)", 2.1, 3.5, {}),
                     ("%fusion.1 = s32[8,16,32]{2,1,0} fusion(s32[] %p)", 3.6, 4.0, {}),

@@ -64,27 +64,43 @@ def test_due_time_latency_lateness_and_rate_on_a_hand_made_schedule():
     assert metric("setup_s", run) == 12.5
 
 
-def test_pad_fill_and_rung_histogram_from_sample_launch_lines():
+def test_pad_fill_and_the_histogram_of_slots_run_from_sample_launch_lines():
+    """The label is the shape that RAN, the line's ``rung`` and ``chunks``
+    (``verifyd --trace`` since PRs 26 and 29), never the smallest shape the
+    item count would fit: 80 and 16 items run on 256 slots since PR 27, 1,100
+    as 1,024 + 256 since PR 29."""
     lines = [
-        '{"ts":1.0,"ev":"verify_batch","replica":"service","size":80,"requests":4,"rejected":0,"secs":0.046}',
-        '{"ts":1.1,"ev":"verify_batch","replica":"service","size":16,"requests":1,"rejected":7,"secs":0.020}',
-        '{"ts":1.2,"ev":"verify_batch","replica":"service","size":300,"requests":9,"rejected":0,"secs":0.080}',
-        '{"ts":1.3,"ev":"verify_batch","replica":"service","size":257,"requests":9,"rejected":0,"secs":0.090}',
-        '{"ts":1.4,"ev":"verify_batch","replica":"service","size":5000,"requests":30,"rejected":0,"secs":0.300}',
+        '{"ts":1.0,"ev":"verify_batch","size":80,"requests":4,"secs":0.046,"rung":256,"chunks":1}',
+        '{"ts":1.1,"ev":"verify_batch","size":16,"requests":1,"secs":0.020,"rung":256,"chunks":1}',
+        '{"ts":1.2,"ev":"verify_batch","size":300,"requests":9,"secs":0.080,"rung":1024,"chunks":1}',
+        '{"ts":1.3,"ev":"verify_batch","size":1100,"requests":9,"secs":0.090,"rung":1280,"chunks":2}',
+        '{"ts":1.4,"ev":"verify_batch","size":5000,"requests":30,"secs":0.300,"rung":5120,"chunks":2}',
+        '{"ts":1.5,"ev":"verify_batch","size":1400,"requests":16,"secs":0.030,"rung":1536,"chunks":3}',
+        '{"ts":1.6,"ev":"verify_batch","size":7,"requests":1,"secs":0.001}',
     ]
     ladder = [16, 64, 256, 1024, 4096]
     launches = [json.loads(ln) for ln in lines]
-    assert [stats.rung_of(e["size"], ladder) for e in launches] == [256, 16, 1024, 1024, 8192]
-    got = stats.launch_stats(launches, ladder)
-    assert got["items_per_launch"] == pytest.approx(5653 / 5)
-    assert got["pad_fill"] == pytest.approx(5653 / (256 + 16 + 1024 + 1024 + 8192))
-    assert got["launch_ms_p50"] == pytest.approx(80.0)
+    assert [xplane.shapes_run(e, ladder) for e in launches] == [
+        (256,), (256,), (1024,), (1024, 256), (4096, 1024), (1024, 256, 256), ()]
+    # two chunks that sum to 2,048 are 1,024 + 1,024; no two shapes sum to 300
+    assert xplane.shapes_run({"rung": 2048, "chunks": 2}, ladder) == (1024, 1024)
+    assert xplane.shapes_run({"rung": 300, "chunks": 2}, ladder) == ()
+    got = stats.launch_stats(launches)
+    assert got["items_per_launch"] == pytest.approx(7903 / 7)
+    # the line without a ``rung`` (no sharded engine behind it) is left out
+    slots = 256 + 256 + 1024 + 1280 + 5120 + 1536
+    assert got["pad_fill"] == pytest.approx(7896 / slots)
+    assert got["launch_ms_p50"] == pytest.approx(46.0)
     assert got["window_max_items"] == 5000
-    assert got["rungs"]["1024"] == {"launches": 2, "launch_ms_p50": pytest.approx(85.0)}
-    run = {"launches": launches, "ladder": ladder}
-    assert metric("pad_fill.closed", run) == got["pad_fill"]
-    assert metric("items_per_launch.rate", run) == got["items_per_launch"]
+    assert got["rungs"]["256"] == {"launches": 2, "launch_ms_p50": pytest.approx(33.0)}
+    run = {"launches": launches[:6], "ladder": ladder}
+    assert metric("pad_fill.closed", run) == pytest.approx(7896 / slots)
+    # ... which is items_per_launch over rung_slots_mean, to the digit
+    assert metric("pad_fill.closed", run) == pytest.approx(
+        metric("items_per_launch.closed", run) / metric("rung_slots_mean.closed", run), rel=1e-14)
+    assert metric("items_per_launch.rate", dict(run, launches=launches)) == got["items_per_launch"]
     assert metric("launch_ms_p50.closed", {"launches": [], "ladder": ladder}) is None
+    assert metric("pad_fill.rate", {"launches": launches[6:], "ladder": ladder}) is None
 
 
 SCRAPE_A = """# TYPE pbft_phase_prepare_seconds histogram
@@ -117,58 +133,93 @@ def test_histogram_delta_means_from_two_sample_scrapes():
     assert metric("fsyncs_per_req.closed", run) == pytest.approx(1.0)
 
 
+def tiny_lines():
+    """The launch lines of tiny.xplane.pb's three windows: the host's clock
+    reads 1000 s where the trace's reads 1,000 ns."""
+    return [
+        {"size": 10, "rung": 16, "chunks": 1, "t_dev": 1000.011},
+        {"size": 80, "rung": 256, "chunks": 1, "t_dev": 1000.013},
+        {"size": 10, "rung": 16, "chunks": 1, "t_dev": 1000.089},
+    ]
+
+
 def test_trace_reduction_on_the_small_recorded_trace():
     """tests/data/tiny.xplane.pb (make_tiny_trace.py says what is in it): a
-    slice of 90 ms with the device busy for 75; four launches of jit_fn, one
-    in no span, one held by two spans, one in one, one across the slice's
-    edge; host spans of engine.verify with 10, 80 and 10 items."""
-    r = xplane.reduce_trace(TESTS / "data" / "tiny.xplane.pb")
-    assert r["devices"] == 1
+    slice of 90 ms with the device busy for 75; four launches of two
+    executables: jit_fn(123) in no span, jit_fn(123) held by two spans,
+    jit_fn(456) in one, jit_fn(123) across the slice's edge; host spans of
+    engine.verify with 10, 80 and 10 items, whose lines say 16, 256 and 16
+    slots."""
+    ladder = [16, 64, 256]
+    r = xplane.reduce_trace(TESTS / "data" / "tiny.xplane.pb", tiny_lines(), ladder, 1000.095)
+    assert r["devices"] == 1 and r["planes"] == 1
     # the window is the slice's span, not first event to last: idle at the
     # edges counts, and what lies beyond the edge does not
     assert r["window_s"] == pytest.approx(90e-3) and r["busy_s"] == pytest.approx(75e-3)
-    assert [(x["items"], pytest.approx(x["seconds"])) for x in r["launches"]] == [
-        (None, 3e-3), (10, 20e-3), (80, 48e-3)]
+    # jit_fn(456) ran alone in the 80-item span: 256 slots; so jit_fn(123),
+    # which that span also holds once, is the 16-slot executable, in the span
+    # that two hold and in no span alike
+    assert [(x["slots"], x["spans"], pytest.approx(x["seconds"])) for x in r["launches"]] == [
+        (16, 0, 3e-3), (16, 2, 20e-3), (256, 1, 48e-3)]
     assert r["modules"]["jit_fn"] == {"launches": 3, "seconds": pytest.approx(71e-3)}
     assert r["idle"]["inside_an_executable_between_its_operations"] == pytest.approx(1e-3)
     assert r["idle"]["inside_engine.verify_host_staging_or_readback"] == pytest.approx(10e-3)
     assert r["idle"]["outside_engine.verify_waiting_for_a_window"] == pytest.approx(4e-3)
     assert r["ops"][0] == ("while.2", pytest.approx(63e-3))  # 5 ms of it beyond the edge
-    assert xplane.device_seconds_by_rung(r, "jit_fn", [16, 64, 256]) == {
-        16: [pytest.approx(20e-3)], 256: [pytest.approx(48e-3)]}
-    # the window's launches: three at the 16 rung, one at 256, one at 64,
-    # which the slice never saw and is left out on both sides
-    window = [{"size": 10}] * 3 + [{"size": 80}, {"size": 40}]
-    run = {"trace": r, "ladder": [16, 64, 256], "launches": window,
+    assert xplane.launches_by_shape(r, "jit_fn") == {
+        16: [pytest.approx(3e-3), pytest.approx(20e-3)], 256: [pytest.approx(48e-3)]}
+    assert xplane.launches_by_shape(r, "jit_other") == {}
+    # the window's launches: three on 16 slots, one on 256, one on 64, which
+    # the slice never saw and is left out on both sides
+    window = [{"size": 10, "rung": 16}] * 3 + [{"size": 80, "rung": 256}, {"size": 40, "rung": 64}]
+    run = {"trace": r, "ladder": ladder, "launches": window,
            "peaks": {"int8_ops_per_s": 393e12, "hbm_bytes_per_s": 819e9}}
     assert metric("device_idle_pct.closed", run) == pytest.approx(100 * 15 / 90)
-    assert metric("kernel_ms_per_launch.rate", run) == pytest.approx((3 * 20 + 48) / 4)
+    assert metric("kernel_ms_per_launch.rate", run) == pytest.approx((3 * 11.5 + 48) / 4)
     ops = 3 * kernel_ops.ed25519_verify(16)["ops"] + kernel_ops.ed25519_verify(256)["ops"]
     assert metric("verify_kernel_roofline.closed", run) == pytest.approx(
-        100 * (ops / 393e12) / 108e-3)
-    b = xplane.breakdown(r, run["ladder"])
+        100 * (ops / 393e12) / (3 * 11.5e-3 + 48e-3))
+    b = xplane.breakdown(r)
     assert b["device_ops"] == [["while.2", pytest.approx(63e-3)], ["fusion.1", pytest.approx(12e-3)],
-                               ["launches_at_16_slots_x1", pytest.approx(20e-3)],
+                               ["launches_at_16_slots_x2", pytest.approx(23e-3)],
                                ["launches_at_256_slots_x1", pytest.approx(48e-3)]]
-    assert len(b["idle_gaps"]) <= 10
+    assert len(b["idle_gaps"]) <= 10 and {g[0] for g in b["idle_gaps"][:3]} == {
+        f"total_{cls}" for cls in xplane.IDLE_CLASSES}
     # a reader that finds nothing to read returns nothing, never 0: no trace,
-    # or a slice that saw none of the rungs the window's launches ran at
-    unseen = dict(run, launches=[{"size": 40}])
+    # a slice that saw none of the shapes the window's launches ran at, or a
+    # trace read without the run's lines (no launch has a shape then)
+    unseen = dict(run, launches=[{"size": 40, "rung": 64}])
+    bare = dict(run, trace=xplane.reduce_trace(TESTS / "data" / "tiny.xplane.pb"))
+    assert [x["slots"] for x in bare["trace"]["launches"]] == [None] * 3
     for name in ("kernel_ms_per_launch.closed", "verify_kernel_roofline.rate"):
         assert metric(name, dict(run, trace=None)) is None
         assert metric(name, unseen) is None
+        assert metric(name, bare) is None
     assert metric("device_idle_pct.rate", dict(run, trace=None)) is None
 
 
-def test_a_launch_belongs_to_the_span_that_holds_it_and_ends_first():
+def test_a_launch_is_told_by_its_executable_not_by_the_span_that_ends_first():
+    """Up to PR 33 a launch that two spans held went to the one that ended
+    first and had none yet, so a second chunk went to the other span in
+    flight. Now every launch says which shapes it may have run at, and an
+    executable is one shape."""
     ms = 1e6  # stamps are nanoseconds
     spans = [(0, 50 * ms, 10), (5 * ms, 120 * ms, 300), (52 * ms, 130 * ms, 40)]
-    mods = [("m", 10 * ms, 45 * ms), ("m", 46 * ms, 110 * ms), ("m", 111 * ms, 125 * ms)]
-    assert [m[3] for m in xplane.match_launches(mods, spans)] == [10, 300, 40]
-    # a window beyond the top rung runs in chunks inside one span
-    assert [m[3] for m in xplane.match_launches(
-        [("m", 10 * ms, 20 * ms), ("m", 21 * ms, 30 * ms)], [(0, 40 * ms, 5000)])] == [5000, 5000]
-    assert xplane.match_launches([("m", 10 * ms, 20 * ms)], [(15 * ms, 40 * ms, 7)])[0][3] is None
+    mods = [("a(1)", 10 * ms, 45 * ms), ("a(2)", 46 * ms, 110 * ms), ("a(1)", 111 * ms, 125 * ms)]
+    launches = xplane.merge_planes([mods])
+    assert [xplane.holders(x, spans) for x in launches] == [[0, 1], [1], [2]]
+    plans = [(16,), (1024,), (16,)]
+    for x in launches:
+        x["may"] = sorted({s for i in xplane.holders(x, spans) for s in plans[i]})
+    assert xplane.shape_of_each_executable(launches) == {"a(1)": 16, "a(2)": 1024}
+    # a launch in no span, or in a span without a line, says nothing; with
+    # nothing said about an executable it has no shape
+    assert xplane.holders({"start": 10 * ms, "end": 20 * ms}, [(15 * ms, 40 * ms, 7)]) == []
+    assert xplane.shape_of_each_executable([{"name": "a(3)", "may": None}]) == {}
+    # two executables that could both only be the one shape: the lines are
+    # not this trace's, and nothing of that name is labelled
+    clash = [{"name": "a(1)", "may": [16]}, {"name": "a(2)", "may": [16]}, {"name": "b(1)", "may": [64]}]
+    assert xplane.shape_of_each_executable(clash) == {"b(1)": 64}
 
 
 def test_kernel_operation_count():

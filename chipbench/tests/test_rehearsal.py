@@ -18,7 +18,7 @@ TESTS = Path(__file__).resolve().parent
 BENCH_DIR = TESTS.parent
 ROOT = BENCH_DIR.parent
 ENV = dict(os.environ, JAX_PLATFORMS="cpu")
-KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+KEYS = {"correct", "attempted", "failed", "metrics", "device", "compared"}
 
 
 def rehearse(*args, timeout=240):
@@ -45,9 +45,12 @@ def test_result_line_has_exactly_the_contracts_keys(cell):
     assert set(line["metrics"]) == want and "setup_s" in want and len(want) >= 2
     assert all(v["value"] > 0 for v in line["metrics"].values())
     assert set(line["device"]) == {"platform", "kind", "count", "memory_peak_bytes"}
-    # every number compared stands beside its limit at the end of stderr
+    # every number compared stands beside its limit at the end of stderr,
+    # and under the line's last key
     tail = proc.stderr.strip().splitlines()[-13:]
     assert all("compare " in ln and "(limit " in ln for ln in tail), tail
+    assert list(line)[-1] == "compared" and len(line["compared"]) == 13
+    assert all(set(c) == {"value", "limit"} for c in line["compared"].values())
 
 
 def test_traced_run_reports_the_cells_per_layer_metrics():
@@ -57,10 +60,18 @@ def test_traced_run_reports_the_cells_per_layer_metrics():
     bench = bench_entries()
     listed = {m["name"] for m in bench["per_layer"] if cell in m["workloads"]}
     # On the CPU there is no device plane: the trace's readers find nothing
-    # to read and are left out; every other reader reports.
+    # to read and are left out; every other reader reports, those of the
+    # engine's own fields from what the stub engine writes in its place.
     from_trace = {m["name"] for m in bench["per_layer"] if m["source"] == "device_trace"}
-    assert set(line["metrics"]) == listed - from_trace
-    assert line["correct"] is True
+    assert set(line["metrics"]) == listed - from_trace and len(listed) == 24
+    assert line["correct"] is True and "breakdown" not in line
+    value = {k: v["value"] for k, v in line["metrics"].items()}
+    # the fill is items over the slots the lines say ran, to the digit
+    assert value["pad_fill.rate"] == pytest.approx(
+        value["items_per_launch.rate"] / value["rung_slots_mean.rate"], rel=1e-12)
+    assert 0 < value["pad_fill.rate"] <= 1 and value["staging_ms_mean.rate"] == 0
+    # the slice's xspace is written where the harness looks, and nothing else
+    assert "traced slice of" in proc.stderr and "trace without device numbers" in proc.stderr
 
 
 def test_accept_all_control_comes_out_not_correct():

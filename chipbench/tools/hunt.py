@@ -54,7 +54,7 @@ def summarize(run: dict) -> dict:
             bins[min(len(bins) - 1, int((done - t0) / BIN_S))] += 1
         if done is not None and t0 <= due < t1:
             lat.append((round(due - t0, 4), round(done - due, 5)))
-    launch = stats.launch_stats(run["launches"], run["ladder"])
+    launch = stats.launch_stats(run["launches"])
     done_n = sum(bins)
 
     def hist(name, replicas="primary", scale=1e3):
@@ -196,7 +196,7 @@ def hunt(args) -> int:
                 s["trace"] = {k2: run["trace"][k2] for k2 in ("window_s", "busy_s", "idle")}
                 s["trace"]["ms_by_rung"] = {
                     rung: [round(1e3 * v, 3) for v in secs] for rung, secs in sorted(
-                        xplane.device_seconds_by_rung(run["trace"], None, run["ladder"]).items())}
+                        xplane.launches_by_shape(run["trace"], None).items())}
                 s["trace"]["ops"] = run["trace"]["ops"][:10]
                 print(f"   trace: {json.dumps(s['trace'])}", flush=True)
             s["fallbacks"], s["not_ok"] = fb, bad

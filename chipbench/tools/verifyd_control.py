@@ -10,13 +10,17 @@ catch.
 
 ``--stub-engine``: no kernel is compiled, the program's native host
 verifier answers in the engine's place, and the status says platform
-``cpu``, so a run that looks for a chip prints no result.
+``cpu``, so a run that looks for a chip prints no result. It writes the
+fields the sharded engine writes on a launch line (one chunk a window on the
+smallest shape that fits it, the host verifier's time as ``wait_s``), so
+that in a rehearsal every reader of the lines has something to read.
 """
 
 from __future__ import annotations
 
 import argparse
 import sys
+import time
 from pathlib import Path
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
@@ -39,8 +43,21 @@ def stub(base):
 
         def verify(self, items):
             from pbft_tpu import native
+            from pbft_tpu.utils.trace import current_span
 
-            return [bool(v) for v in native.verify_batch(items)]
+            t_dev = time.monotonic()
+            verdicts = [bool(v) for v in native.verify_batch(items)]
+            span = current_span()
+            if span is not None:
+                top = max(self._want_shapes)
+                chunks = -(-len(items) // top)
+                last = len(items) - (chunks - 1) * top
+                fit = min(s for s in self._want_shapes if s >= last)
+                span.update(pad_s=0.0, dispatch_s=0.0, unpack_s=0.0,
+                            wait_s=round(time.monotonic() - t_dev, 6), t_dev=round(t_dev, 6),
+                            rung=(chunks - 1) * top + fit, promoted=0, chunks=chunks,
+                            split=int(chunks > 1))
+            return verdicts
 
     return StubEngine
 

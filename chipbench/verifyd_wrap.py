@@ -12,10 +12,12 @@ outside a launch; and a thread blocks on a FIFO for two commands from the
 harness:
 
     mem <path>              write {"memory_peak_bytes": ..} of the fullest chip
-    trace <seconds> <dir>   jax.profiler trace into <dir> with a span
-                            ``chipbench.slice`` of that length inside it (the
-                            slice's edges in the trace's own clock), then
-                            <dir>/done
+    trace <seconds> <dir>   a profiler session with a span ``chipbench.slice``
+                            of that length inside it (the slice's edges in
+                            the trace's own clock), its xspace written under
+                            <dir> where ``xplane.find_xplane`` looks and
+                            nothing else, then <dir>/done with the slice's
+                            stamps on the host's monotonic clock
 
 Nothing else: the controls of ``correct`` and the CPU rehearsal's stub
 engine live in ``tools/verifyd_control.py``, which no benchmark run starts.
@@ -34,7 +36,7 @@ from pathlib import Path
 HERE = Path(__file__).resolve().parent
 sys.path[:0] = [str(HERE), str(HERE.parent)]
 
-from xplane import ENGINE_SPAN, SLICE_SPAN  # noqa: E402
+from xplane import ENGINE_SPAN, SLICE_SPAN, write_xspace  # noqa: E402
 
 
 def _memory_peak_bytes() -> dict:
@@ -48,20 +50,28 @@ def _memory_peak_bytes() -> dict:
 
 
 def _trace(seconds: float, out_dir: str) -> None:
+    """The session's ``stop()`` gives the xspace as it was collected; what
+    ``jax.profiler.stop_trace`` adds to it, TensorBoard's directory with a
+    ``trace.json.gz`` of every event, nobody reads (``PERF.md`` section 3
+    has what each piece costs)."""
     import jax
+    from jax._src.lib import _profiler
 
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0
     options.host_tracer_level = 2
     t0 = time.monotonic()
-    jax.profiler.start_trace(out_dir, profiler_options=options)
+    session = _profiler.ProfilerSession(options)
     with jax.profiler.TraceAnnotation(SLICE_SPAN):
         time.sleep(seconds)
     t_off = time.monotonic()
-    jax.profiler.stop_trace()
-    Path(out_dir, "done").write_text(
-        json.dumps({"asked": t0, "off": t_off, "stopped": time.monotonic()})
-    )
+    blob = session.stop()
+    t_stopped = time.monotonic()
+    write_xspace(blob, out_dir)
+    Path(out_dir, "done").write_text(json.dumps(
+        {"asked": t0, "off": t_off, "collected": t_stopped, "stopped": time.monotonic(),
+         "xspace_bytes": len(blob)}
+    ))
 
 
 def _control_loop(fifo: str) -> None:

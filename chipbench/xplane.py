@@ -3,8 +3,7 @@
 One function, ``reduce_trace``, reads the traced slice of a run with
 ``jax.profiler.ProfileData`` and returns plain numbers; the per-layer readers
 in ``reducers/`` pick theirs out of it. Nothing here runs on a device, so the
-arithmetic has a test on a small hand-made trace (``tests/data/tiny.xplane.pb``,
-written by ``tests/data/make_tiny_trace.py``).
+arithmetic has tests on small hand-made traces (``tests/data/``).
 
 What is read:
 
@@ -14,21 +13,50 @@ What is read:
   as idle;
 - device planes, ``/device:TPU:<n>``: the ``XLA Ops`` line holds one event
   per operation that ran on the chip, the ``XLA Modules`` line one event per
-  launch of a compiled executable;
+  launch of a compiled executable, named ``jit_fn(<fingerprint>)``;
 - the host plane's ``engine.verify`` spans, which ``verifyd_wrap.py`` puts
   round every ``ShardedVerifyEngine.verify`` call, each with its ``items``.
 
-Busy time is the union of the operations' intervals. A launch on the device
-belongs to the ``engine.verify`` span that holds it, so it is known how many
-items it carried and hence which padded shape ran. An idle gap of a device is
-named by what the host was doing in it: inside an ``engine.verify`` span
+Busy time is the union of the operations' intervals. An idle gap of a device
+is named by what the host was doing in it: inside an ``engine.verify`` span
 (staging a window, or reading verdicts back), outside every span (waiting
 for a window), or inside an executable between two of its operations.
+
+**One launch, N planes.** An executable sharded over N chips shows as one
+event on each of N device planes. ``merge_planes`` makes one launch of them,
+its device time the longest of its planes; ``planes`` says how many carried
+it, so that a reader can set the work of ``slots / planes`` rows against one
+chip's peak. Busy and idle time are averaged over the device planes.
+
+**The shape a launch ran at** is what the program says it ran, never what
+its item count would fit: ``verifyd --trace`` writes a line a window with
+``size`` (items), ``rung`` (the padded slots run, summed over the window's
+chunks), ``chunks`` and ``t_dev`` (the first dispatch, on the host's clock).
+``label`` finds each ``engine.verify`` span's line (the same ``size``, its
+``t_dev`` inside the span: the slice's end is known on both clocks), hence
+the shapes the span's chunks ran at (``shapes_run``). A compiled shape is one
+executable and so one fingerprint: every launch in the slice says which
+shapes it may have run at (those of the spans that hold it), an executable
+is what all its launches allow, and a shape that one executable has taken
+is no other's; a span that alone holds all its chunks ran them in the order
+of its plan. So a second chunk is never handed to the other span in flight,
+and a launch that two spans hold is still told by its fingerprint.
+
+**A launch the trace's end cut.** The device stops recording some
+milliseconds before the host's slice span closes, so a launch in flight there
+is an event that ends inside the slice with part of its operations and part
+of its time (1.29 and 2.19 ms of a 5.32 ms executable, PR 35). An executable
+runs the same operations in every launch: a launch that shows under ``WHOLE``
+of the operations its executable's fullest launch shows is left out, like one
+that crosses the slice's edge, and counted under ``cut``.
 """
 
 from __future__ import annotations
 
 import re
+import socket
+import time
+from bisect import bisect_left
 from pathlib import Path
 
 DEVICE_PLANE = re.compile(r"/device:TPU:\d+")
@@ -37,6 +65,22 @@ MODULES_LINE = "XLA Modules"
 ENGINE_SPAN = "engine.verify"  # verifyd_wrap.py opens both
 SLICE_SPAN = "chipbench.slice"
 SLACK_NS = 1e6  # the host's clock and the device's, against launches of 5 ms and more
+WHOLE = 0.98  # of the operations the fullest launch of its executable shows
+IDLE_CLASSES = (
+    "inside_an_executable_between_its_operations",
+    "inside_engine.verify_host_staging_or_readback",
+    "outside_engine.verify_waiting_for_a_window",
+)
+
+
+def write_xspace(blob: bytes, trace_dir) -> Path:
+    """The serialised xspace of a profiler session where TensorBoard's
+    export would have put it, and nothing beside it."""
+    run = Path(trace_dir, "plugins", "profile", time.strftime("%Y_%m_%d_%H_%M_%S"))
+    run.mkdir(parents=True, exist_ok=True)
+    path = run / f"{socket.gethostname()}.xplane.pb"
+    path.write_bytes(blob)
+    return path
 
 
 def find_xplane(trace_dir) -> Path:
@@ -94,34 +138,152 @@ def _short(name: str) -> str:
 
 
 def _events(line):
-    return [(_short(e.name), e.start_ns, e.start_ns + e.duration_ns) for e in line.events]
+    short: dict = {}  # some hundred names for some hundred thousand events
+    out = []
+    for e in line.events:
+        name = e.name
+        if name not in short:
+            short[name] = _short(name)
+        out.append((short[name], e.start_ns, e.start_ns + e.duration_ns))
+    return out
+
+
+def _plain(name: str) -> str:
+    """"jit_fn(1234567890)" -> "jit_fn": the number is a fingerprint."""
+    return name.split("(")[0]
 
 
 def _clip(intervals, lo: float, hi: float) -> list:
     return [(max(s, lo), min(e, hi)) for s, e in intervals if min(e, hi) > max(s, lo)]
 
 
-def match_launches(modules, spans) -> list:
-    """-> [(module, start, end, items or None)]. A launch belongs to the
-    span that holds it. Where two spans hold it (two launches in flight),
-    it is the one that ends first and has no launch yet: the device runs
-    launches in the order they were dispatched, and a span ends when its
-    verdicts are back. A span that has one may take more (a window beyond
-    the top rung runs in chunks). ``SLACK_NS`` allows for the two clocks."""
-    taken: set = set()
+# -- the shapes a window ran at, from its launch line ---------------------------------
+
+
+def shapes_run(line: dict, ladder) -> tuple:
+    """The padded shapes the window of a ``verify_batch`` line ran at, one
+    a chunk, largest first (the order the engine dispatches them in): the
+    ``chunks`` shapes of the ladder that sum to ``rung``. With up to four
+    chunks on a ladder that grows fourfold there is one such cover; beyond
+    that the one with the most of the largest shapes, which is how the
+    engine cuts a window above its top shape. Nothing for a line without
+    the two fields (a backend that is not the sharded engine)."""
+    if "rung" not in line:
+        return ()
+    return _cover(int(line["rung"]), int(line.get("chunks", 1)), sorted(ladder, reverse=True)) or ()
+
+
+def _cover(slots: int, chunks: int, shapes: list):
+    if chunks == 0 or not shapes:
+        return () if slots == 0 and chunks == 0 else None
+    for k, shape in enumerate(shapes):
+        if shape * chunks < slots:
+            return None  # not even the largest shape left reaches the sum
+        if shape <= slots:
+            rest = _cover(slots - shape, chunks - 1, shapes[k:])
+            if rest is not None:
+                return (shape,) + rest
+    return None
+
+
+def label(spans, lines, ladder, instant) -> list:
+    """-> per span, the shapes its window ran at (``()`` where no line is
+    its). ``spans``: [(start ns, end ns, items)] on the trace's clock;
+    ``lines``: the run's ``verify_batch`` records; ``instant``: one moment
+    as (the trace's nanoseconds, the host's monotonic seconds)."""
+    at_ns, at_s = instant
+    by_size: dict = {}
+    for rec in lines:
+        if "t_dev" in rec:
+            by_size.setdefault(rec["size"], []).append((rec["t_dev"], rec))
+    for recs in by_size.values():
+        recs.sort(key=lambda r: r[0])
+    slack = SLACK_NS * 1e-9
     out = []
-    for name, start, end in sorted(modules, key=lambda m: m[2]):
-        holding = [
-            (s_end, i) for i, (s_start, s_end, _) in enumerate(spans)
-            if s_start - SLACK_NS <= start and end <= s_end + SLACK_NS
-        ]
-        free = [h for h in holding if h[1] not in taken]
-        items = None
-        if holding:
-            _, i = min(free or holding)
-            taken.add(i)
-            items = spans[i][2]
-        out.append((name, start, end, items))
+    for start, end, items in spans:
+        lo = at_s + (start - at_ns) * 1e-9 - slack
+        hi = at_s + (end - at_ns) * 1e-9 + slack
+        recs = by_size.get(items, [])
+        at = bisect_left(recs, lo, key=lambda r: r[0])
+        found = recs[at][1] if at < len(recs) and recs[at][0] <= hi else None
+        out.append(shapes_run(found, ladder) if found else ())
+    return out
+
+
+# -- launches ----------------------------------------------------------------------------
+
+
+def merge_planes(planes: list) -> list:
+    """[[(name, start, end)] a plane] -> [{name, start, end, seconds,
+    planes}], by start: an executable sharded over N chips is one event a
+    plane and ONE launch, from the first plane's start to the last one's end,
+    its device time the longest a plane took. Events of two planes are the
+    same launch where they carry the same name and share at least half of
+    the shorter one's length (the chips of a mesh start a launch within
+    microseconds of each other, and a plane runs one launch at a time)."""
+    open_: list = []
+    out: list = []
+    events = sorted(
+        ((s, e, name, p) for p, modules in enumerate(planes) for name, s, e in modules)
+    )
+    for s, e, name, p in events:
+        home = None
+        for launch in open_:
+            if launch["name"] == name and p not in launch["_on"]:
+                shared = min(e, launch["end"]) - max(s, launch["start"])
+                if 2 * shared >= min(e - s, launch["end"] - launch["start"]):
+                    home = launch
+                    break
+        if home is None:
+            home = {"name": name, "start": s, "end": e, "seconds": 0.0, "_on": set()}
+            open_.append(home)
+            out.append(home)
+        home["_on"].add(p)
+        home["end"] = max(home["end"], e)
+        home["seconds"] = max(home["seconds"], e - s)
+        open_ = [x for x in open_ if x["end"] > s]
+    for launch in out:
+        launch["planes"] = len(launch.pop("_on"))
+    return out
+
+
+def holders(launch: dict, spans) -> list:
+    """Indices of the spans that hold the launch, ``SLACK_NS`` allowed for
+    the two clocks."""
+    return [
+        i for i, (s_start, s_end, _) in enumerate(spans)
+        if s_start - SLACK_NS <= launch["start"] and launch["end"] <= s_end + SLACK_NS
+    ]
+
+
+def shape_of_each_executable(launches) -> dict:
+    """{fingerprinted name: slots}, from each launch's ``may`` (the shapes of
+    the spans that hold it; None where one of them has no line). An
+    executable is one shape, so what ALL its launches allow, and a shape
+    that one executable has taken is no other's: settled one by one. Kept
+    apart by the executables' plain name (``jit_fn``), so that another
+    program on the device takes no shape from this one. A name whose
+    executables come out with no shape, or two with the same, is left out
+    whole: its lines or spans are not this trace's."""
+    groups: dict = {}
+    for launch in launches:
+        if launch["may"]:
+            may = groups.setdefault(_plain(launch["name"]), {})
+            allowed = set(launch["may"])
+            may[launch["name"]] = may.get(launch["name"], allowed) & allowed
+    out: dict = {}
+    for may in groups.values():
+        settled: dict = {}
+        while True:
+            new = {n: next(iter(c)) for n, c in may.items() if len(c) == 1 and n not in settled}
+            if not new:
+                break
+            settled.update(new)
+            for name, c in may.items():
+                if name not in settled:
+                    c -= set(new.values())
+        if all(may.values()) and len(set(settled.values())) == len(settled):
+            out.update(settled)
     return out
 
 
@@ -138,7 +300,7 @@ def _read_slice(path):
                 if line.name == OPS_LINE:
                     ops = _events(line)
                 elif line.name == MODULES_LINE:
-                    modules = _events(line)
+                    modules = [(e.name, e.start_ns, e.start_ns + e.duration_ns) for e in line.events]
             devices.append((plane.name, ops, modules))
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
@@ -155,20 +317,22 @@ def _read_slice(path):
     return edges[0][0], edges[0][1], devices, spans
 
 
-def reduce_trace(path) -> dict:
-    """The slice -> {window_s, busy_s, devices, launches [{module, items,
-    seconds}] (those wholly inside the slice), modules {name: {launches,
-    seconds}}, ops [(name, seconds)], idle {class: seconds}, longest_gaps
-    [(class, seconds)]}; seconds throughout, busy and idle averaged over the
-    device planes."""
+def reduce_trace(path, lines=(), ladder=(), slice_end_s: float = None) -> dict:
+    """The slice -> {window_s, busy_s, devices, planes (how many of them a
+    launch runs on), launches [{module, slots, seconds, spans}] (those wholly
+    inside the slice, on every one of those planes and with all their
+    operations recorded; ``spans``: how many ``engine.verify`` spans hold
+    it), cut (launches left out because the trace's end cut their
+    operations short), modules {name: {launches, seconds}}, ops
+    [(name, seconds)], idle {class: seconds}, longest_gaps [(class,
+    seconds)]}; seconds throughout; busy, idle and the operations' seconds
+    averaged over the device planes. ``lines`` (the run's ``verify_batch``
+    records), ``ladder`` and ``slice_end_s`` (the slice's end on the host's
+    monotonic clock) give each launch its ``slots``; None without them."""
     lo, hi, devices, spans = _read_slice(path)
     busy_s = 0.0
     op_seconds: dict = {}
-    module_stats: dict = {}
-    launches: list = []
-    idle = {"inside_an_executable_between_its_operations": 0.0,
-            "inside_engine.verify_host_staging_or_readback": 0.0,
-            "outside_engine.verify_waiting_for_a_window": 0.0}
+    idle = dict.fromkeys(IDLE_CLASSES, 0.0)
     longest: list = []
     span_union = union(_clip(((s, e) for s, e, _ in spans), lo, hi))
     for _, ops, modules in devices:
@@ -178,43 +342,65 @@ def reduce_trace(path) -> dict:
             inside = min(e, hi) - max(s, lo)
             if inside > 0:
                 op_seconds[name] = op_seconds.get(name, 0.0) + inside
-        for name, s, e, items in match_launches(modules, spans):
-            if s < lo or e > hi:
-                continue
-            # "jit_fn(1234567890)" -> "jit_fn": the number is a fingerprint.
-            short = name.split("(")[0]
-            stat = module_stats.setdefault(short, {"launches": 0, "seconds": 0.0})
-            stat["launches"] += 1
-            stat["seconds"] += e - s
-            launches.append({"module": short, "items": items, "seconds": (e - s) * 1e-9})
         module_union = union(_clip(((s, e) for _, s, e in modules), lo, hi))
         for gap in gaps(busy, lo, hi):
             g = [gap]
             in_module = overlap(g, module_union)
             rest = (gap[1] - gap[0]) - in_module
             in_span = min(rest, max(0.0, overlap(g, span_union) - in_module))
-            parts = {
-                "inside_an_executable_between_its_operations": in_module,
-                "inside_engine.verify_host_staging_or_readback": in_span,
-                "outside_engine.verify_waiting_for_a_window": rest - in_span,
-            }
+            parts = dict(zip(IDLE_CLASSES, (in_module, in_span, rest - in_span)))
             for cls, ns in parts.items():
                 idle[cls] += ns
-            cls = max(parts, key=parts.get)
-            longest.append((cls, gap[1] - gap[0]))
-    n = len(devices)
+            longest.append((max(parts, key=parts.get), gap[1] - gap[0]))
+    merged = merge_planes([modules for _, _, modules in devices])
+    width = max((x["planes"] for x in merged), default=1)
+    shapes = label(spans, lines, ladder, (hi, slice_end_s or 0.0))
+    for x in merged:
+        x["held_by"] = holders(x, spans)
+        held = [shapes[i] for i in x["held_by"]]
+        x["may"] = sorted({s for plan in held for s in plan}) if held and all(held) else None
+    for i, plan in enumerate(shapes):
+        # A span that alone holds as many launches as its window had chunks
+        # ran them in the order it dispatched them, which is the plan's.
+        own = [x for x in merged if x["held_by"] == [i]]
+        if plan and len(own) == len(plan):
+            for x, slots in zip(own, plan):
+                x["may"] = [slots]
+    shape_of = shape_of_each_executable(merged)
+    op_starts = [sorted(s for _, s, _ in ops) for _, ops, _ in devices]
+    most: dict = {}
+    for x in merged:
+        x["ops"] = min(bisect_left(at, x["end"]) - bisect_left(at, x["start"]) for at in op_starts)
+        most[x["name"]] = max(most.get(x["name"], 0), x["ops"])
+    launches: list = []
+    module_stats: dict = {}
+    cut = 0
     ns = 1e-9
+    for x in merged:
+        if x["start"] < lo or x["end"] > hi or x["planes"] < width:
+            continue
+        if x["ops"] < WHOLE * most[x["name"]]:
+            cut += 1
+            continue
+        short = _plain(x["name"])
+        stat = module_stats.setdefault(short, {"launches": 0, "seconds": 0.0})
+        stat["launches"] += 1
+        stat["seconds"] += x["seconds"] * ns
+        launches.append({
+            "module": short, "slots": shape_of.get(x["name"]), "seconds": x["seconds"] * ns,
+            "spans": len(x["held_by"]),
+        })
+    n = len(devices)
     return {
         "window_s": (hi - lo) * ns,
         "busy_s": busy_s * ns / n,
         "devices": n,
+        "planes": width,
         "launches": launches,
-        "modules": {
-            name: {"launches": st["launches"], "seconds": st["seconds"] * ns}
-            for name, st in module_stats.items()
-        },
+        "cut": cut,
+        "modules": module_stats,
         "ops": sorted(
-            ((name, sec * ns) for name, sec in op_seconds.items()),
+            ((name, sec * ns / n) for name, sec in op_seconds.items()),
             key=lambda kv: -kv[1],
         ),
         "idle": {cls: sec * ns / n for cls, sec in idle.items()},
@@ -224,27 +410,25 @@ def reduce_trace(path) -> dict:
     }
 
 
-def device_seconds_by_rung(reduced: dict, module, ladder) -> dict:
-    """{rung: [device seconds of each launch of ``module`` that ran at it]}
-    from the launches whose item count is known; every module for None."""
-    import stats
-
+def launches_by_shape(reduced: dict, module) -> dict:
+    """{slots: [device seconds of each launch of ``module`` that ran at
+    it]} from the launches whose shape is known; every module for None."""
     out: dict = {}
     for launch in reduced["launches"]:
-        if launch["items"] and module in (None, launch["module"]):
-            out.setdefault(stats.rung_of(launch["items"], ladder), []).append(launch["seconds"])
+        if launch["slots"] and module in (None, launch["module"]):
+            out.setdefault(launch["slots"], []).append(launch["seconds"])
     return out
 
 
-def breakdown(reduced: dict, ladder=()) -> dict:
+def breakdown(reduced: dict) -> dict:
     """The result line's ``breakdown``: at most 10 entries a list. The
     device's operations by time, then the launches by the padded shape they
     ran at (seconds of all of them; the count is in the name)."""
     idle = [[f"total_{cls}", sec] for cls, sec in reduced["idle"].items()]
     idle += [[f"longest_{cls}", sec] for cls, sec in reduced["longest_gaps"]]
-    by_rung = [
-        [f"launches_at_{rung}_slots_x{len(secs)}", sum(secs)]
-        for rung, secs in sorted(device_seconds_by_rung(reduced, None, ladder).items())
-    ] if ladder else []
-    ops = [[name, sec] for name, sec in reduced["ops"][: 10 - len(by_rung[:5])]]
-    return {"device_ops": ops + by_rung[:5], "idle_gaps": idle[:10]}
+    by_shape = [
+        [f"launches_at_{slots}_slots_x{len(secs)}", sum(secs)]
+        for slots, secs in sorted(launches_by_shape(reduced, None).items())
+    ][:5]
+    ops = [[name, sec] for name, sec in reduced["ops"][: 10 - len(by_shape)]]
+    return {"device_ops": ops + by_shape, "idle_gaps": idle[:10]}
