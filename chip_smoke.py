@@ -13,8 +13,10 @@ process):
    window of exactly each rung size, three 4096 windows at once, and
    four connections x 1,024 items that the dispatcher coalesces into the
    4096 shape. Items are signed from ``--seed``; every window carries a
-   planted reject of every class the kernel decides. Every verdict is
-   compared, item by item, with ``pbft_tpu.crypto.ref.verify`` (RFC 8032).
+   planted reject of every class the kernel decides, and on a mesh of
+   several chips a window that fills the shape it runs at (1,024 and 4,096
+   slots on the 2x2 host) carries one in EVERY chip's rows. Every verdict
+   is compared, item by item, with ``pbft_tpu.crypto.ref.verify`` (RFC 8032).
 2. The deployment (ROADMAP B0's durable default, the shape of
    benchmarks/wal_r15.jsonl "scale f=1"): n=4, signature mode, WAL with
    fsync, batch_max_items=32, batch_flush_us=2000, one gateway process,
@@ -160,21 +162,31 @@ def planted(rng: random.Random, base) -> dict:
     }
 
 
-def make_window(rng: random.Random, pool: list, size: int, oracle: Oracle):
+N_CLASSES = 8  # what planted() makes: seven the oracle rejects and its control
+
+
+def make_window(
+    rng: random.Random, pool: list, size: int, oracle: Oracle, shards: int = 1
+):
     """``size`` items drawn from the pool with one of every planted class
-    at seeded positions. Returns (items, {position: class name})."""
+    at seeded positions in EACH of ``shards`` equal parts of the window:
+    where the window fills the shape it runs at, a part is the rows ONE chip
+    of the mesh decides. Returns (items, {position: class name})."""
     items = rng.sample(pool, size)
-    plants = planted(rng, items[0])
+    rows = size // shards
     classes = {}
-    for (name, (item, expect)), pos in zip(
-        plants.items(), rng.sample(range(size), len(plants))
-    ):
-        check(
-            oracle(item) is expect,
-            f"generator: oracle says {not expect} for planted {name!r}",
-        )
-        items[pos] = item
-        classes[pos] = name
+    for shard in range(shards):
+        first = shard * rows
+        plants = planted(rng, items[first])
+        for (name, (item, expect)), pos in zip(
+            plants.items(), rng.sample(range(first, first + rows), len(plants))
+        ):
+            check(
+                oracle(item) is expect,
+                f"generator: oracle says {not expect} for planted {name!r}",
+            )
+            items[pos] = item
+            classes[pos] = name
     return items, classes
 
 
@@ -232,20 +244,39 @@ def device_stage(target: str, seed: int, ladder=LADDER, trace_path=None) -> None
     log("chunk plan (items→shapes run, where several launches cost less): "
         + (serving_table_text(before["warm_stats"]["chunk_plan"]) or "none"))
 
-    # One window of exactly each rung size, alone on the wire.
+    # One window of exactly each rung size, alone on the wire. A window that
+    # runs at its own size fills that executable, so item i is row i and a
+    # quarter of the window is ONE chip's rows on a mesh of four: there every
+    # class is planted in every chip's rows (a probe's few items sit together
+    # in one chip's), wherever a chip's rows have room for them.
+    chips = before["devices"]
+    serves = before["warm_stats"]["serving_table"]
+    shards_of = {
+        size: chips if serves.get(str(size)) == size and size // chips >= N_CLASSES else 1
+        for size in ladder
+    }
     for size in ladder:
-        items, classes = make_window(rng, pool, size, oracle)
+        shards = shards_of[size]
+        items, classes = make_window(rng, pool, size, oracle, shards)
         verdicts = verify_over_socket(target, items)
         rejected = compare(f"rung {size}", items, classes, verdicts, oracle)
         log(f"rung {size}: {size}/{size} verdicts agree with the oracle "
-            f"({rejected} rejected, every planted class among them)")
+            f"({rejected} rejected, every planted class among them"
+            + (f", in each of the {shards} chips' rows: {size // shards} rows a chip)"
+               if shards > 1 else ")"))
+    every_chip = [size for size in ladder if shards_of[size] > 1]
+    check(
+        chips == 1 or every_chip,
+        f"a mesh of {chips} chips and no window of {ladder} fills a shape "
+        f"with {N_CLASSES} rows a chip: serving table {serves}",
+    )
 
     # Three top-rung windows at once fill both launch slots and the
     # dispatcher's hand (inflight=2 + the window it holds while it waits
     # for a slot); the four 1,024-item requests sent next then queue
     # TOGETHER and leave as one merged window of the 4096 shape.
     part = top // 4
-    blockers = [make_window(rng, pool, top, oracle) for _ in range(3)]
+    blockers = [make_window(rng, pool, top, oracle, shards_of[top]) for _ in range(3)]
     parts = [make_window(rng, pool, part, oracle) for _ in range(4)]
     results: dict = {}
 
@@ -289,12 +320,23 @@ def device_stage(target: str, seed: int, ladder=LADDER, trace_path=None) -> None
         f"{after['fallback_items'] - before['fallback_items']})",
     )
     if trace_path is not None:
-        # What the dispatcher really merged, from its own per-launch trace.
-        merged = [
-            (e["requests"], e["size"])
+        lines = [
+            e
             for e in map(json.loads, Path(trace_path).read_text().splitlines())
-            if e.get("ev") == "verify_batch" and e["requests"] > 1
+            if e.get("ev") == "verify_batch"
         ]
+        # The windows planted a chip at a time did run as ONE executable of
+        # their own size over every chip: the engine's line says so.
+        for size in every_chip:
+            want = {"size": size, "rung": size, "chunks": 1, "devices": chips,
+                    "rows_per_chip": size // chips}
+            check(
+                any(all(e.get(k) == v for k, v in want.items()) for e in lines),
+                f"no launch line says {want}: the {size}-item window did not "
+                f"run as {size // chips} rows on each of {chips} chips",
+            )
+        # What the dispatcher really merged, from its own per-launch trace.
+        merged = [(e["requests"], e["size"]) for e in lines if e["requests"] > 1]
         check(
             any(size > part for _, size in merged),
             f"no coalesced window reached the {top} shape: merged={merged}",
@@ -303,7 +345,10 @@ def device_stage(target: str, seed: int, ladder=LADDER, trace_path=None) -> None
     log(f"device stage: {sent} verdicts, all through the engine, "
         f"{after['promoted_launches'] - before['promoted_launches']} launches "
         "on a larger shape than the smallest fit, "
-        f"{after['split_launches'] - before['split_launches']} run as chunks")
+        f"{after['split_launches'] - before['split_launches']} run as chunks; "
+        f"every class planted in every chip's rows at {every_chip or 'no'} slots "
+        f"over {chips} chip(s); launches by rows a chip "
+        f"{after.get('launches_by_rows_per_chip')}")
 
 
 # -- stage 2: the deployment ---------------------------------------------------

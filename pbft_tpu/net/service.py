@@ -229,13 +229,16 @@ class VerifierService:
         # windows it ran as several executables (its span's ``split``).
         # held_out_launches / in_step_launches: windows whose hold ran out,
         # and windows cut early because nobody in step was still out;
-        # launches_by_rung: launches by the padded slots the engine ran.
+        # launches_by_rung: launches by the padded slots the engine ran;
+        # launches_by_rows_per_chip: by the rows a chip of the window's
+        # thinnest chunk (its span's ``rows_per_chip``).
         self.stage_seconds = {"queue_s": 0.0, "slot_s": 0.0}
         self.promoted_launches = 0
         self.split_launches = 0
         self.held_out_launches = 0
         self.in_step_launches = 0
         self.launches_by_rung: dict = {}
+        self.launches_by_rows_per_chip: dict = {}
         self._slowest: Optional[dict] = None
         self._cond = threading.Condition()
         self._pending: List[_Pending] = []
@@ -468,9 +471,13 @@ class VerifierService:
         self.split_launches += bool(span.get("split"))
         self.held_out_launches += waits["held_out"]
         self.in_step_launches += waits["in_step"]
-        if "rung" in span:
-            rung = str(span["rung"])  # JSON has no integer keys
-            self.launches_by_rung[rung] = self.launches_by_rung.get(rung, 0) + 1
+        for field, counts in (
+            ("rung", self.launches_by_rung),
+            ("rows_per_chip", self.launches_by_rows_per_chip),
+        ):
+            if field in span:
+                key = str(span[field])  # JSON has no integer keys
+                counts[key] = counts.get(key, 0) + 1
         if self._slowest is None or secs > self._slowest["secs"]:
             self._slowest = {
                 "secs": round(secs, 6),
@@ -482,7 +489,8 @@ class VerifierService:
 
     def launch_status(self) -> dict:
         """The stage totals, the counts of launches (promoted, split, by exit
-        of the hold, by shape run) and the slowest launch, for the status JSON."""
+        of the hold, by shape run, by rows a chip) and the slowest launch, for
+        the status JSON."""
         with self._cond:
             slowest = dict(self._slowest) if self._slowest else None
             totals = {k: round(v, 6) for k, v in self.stage_seconds.items()}
@@ -492,6 +500,7 @@ class VerifierService:
                 "held_out_launches": self.held_out_launches,
                 "in_step_launches": self.in_step_launches,
                 "launches_by_rung": dict(self.launches_by_rung),
+                "launches_by_rows_per_chip": dict(self.launches_by_rows_per_chip),
             }
         if slowest:
             slowest["ago_s"] = round(time.monotonic() - slowest.pop("at"), 3)

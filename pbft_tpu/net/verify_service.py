@@ -246,6 +246,7 @@ class ShardedVerifyEngine:
         self._lock = threading.Lock()
         self._mesh = None
         self._compiled: dict = {}  # padded size -> jax.stages.Compiled
+        self._chips: dict = {}  # padded size -> devices its input is sharded over
         self._launch_s: dict = {}  # padded size -> seconds, read at warm-up
         self._serves: dict = {}  # smallest fitting size -> size it runs at
         self._plans: list = []  # plan_table(): [(largest n, shapes run)]
@@ -341,14 +342,13 @@ class ShardedVerifyEngine:
                     # rows each one holds — not what we asked for.
                     in_sharding = compiled.input_shardings[0][0]
                     launch_s = round(self._measure(size, compiled), 6)
+                    chips = sorted(d.id for d in in_sharding.device_set)
                     stats["per_shape"].append(
                         {
                             "size": size,
                             "seconds": round(secs, 3),
                             "cache_hit": hit,
-                            "devices": sorted(
-                                d.id for d in in_sharding.device_set
-                            ),
+                            "devices": chips,
                             "rows_per_device": in_sharding.shard_shape(
                                 (size, 128)
                             )[0],
@@ -356,6 +356,7 @@ class ShardedVerifyEngine:
                         }
                     )
                     self._launch_s[size] = launch_s
+                    self._chips[size] = len(chips)
                     self._compiled[size] = compiled
                     stats["shapes"].append(size)
                 stats.update(self._route(self._launch_s))
@@ -469,7 +470,12 @@ class ShardedVerifyEngine:
         The five steps of every chunk are timed (summed over chunks) into
         the caller's ``utils.trace.current_span()``, where one is open —
         the service's ``verify_batch`` line — and each is a profiler
-        annotation (``verifyd.<step>``; free while no trace runs)."""
+        annotation (``verifyd.<step>``; free while no trace runs). Beside
+        them the line says over how many chips the window's executables are
+        sharded (``devices``: what each executable's input sharding said at
+        warm-up, not what was asked for) and how many rows a chip its
+        thinnest chunk gave (``rows_per_chip``: the number to set against
+        the rows under which the kernel runs in its slow regime)."""
         mark = time.monotonic()
         if not items:
             return []
@@ -529,12 +535,16 @@ class ShardedVerifyEngine:
         span = current_span()
         if span is not None:
             span.update({k: round(v, 6) for k, v in secs.items()})
+            thinnest = min(plan)
+            chips = self._chips[thinnest]
             span.update(
                 rung=sum(plan),
                 promoted=promoted,
                 chunks=len(plan),
                 split=int(len(plan) > 1),
                 t_dev=round(t_dev, 6),
+                devices=chips,
+                rows_per_chip=thinnest // chips,
             )
         return out
 

@@ -35,8 +35,10 @@ EVENT_SCHEMAS = {
     # at), promoted (chunks run on a larger shape than the smallest that
     # fits, by the engine's serving table), chunks (executables run for the
     # window) with the 0/1 field split (more than one: the engine's chunk
-    # plan, or a window beyond the largest shape) and t_dev (absolute stamp
-    # at the first dispatch).
+    # plan, or a window beyond the largest shape), t_dev (absolute stamp
+    # at the first dispatch), devices (the chips the window's executables
+    # are sharded over, as their input sharding said at warm-up) and
+    # rows_per_chip (the slots of the window's smallest chunk over devices).
     "verify_batch": {
         "required": {"ts", "ev", "replica", "size", "rejected", "secs"},
         "optional": {
@@ -44,7 +46,7 @@ EVENT_SCHEMAS = {
             "queue_s", "slot_s", "pending_at_cut", "pending_at_launch",
             "hold_s", "held_out", "in_step",
             "pad_s", "put_s", "dispatch_s", "wait_s", "unpack_s", "rung", "promoted",
-            "chunks", "split", "t_dev",
+            "chunks", "split", "t_dev", "devices", "rows_per_chip",
         },
         "emitters": {"server.py", "service.py", "net.cc"},
     },
@@ -429,9 +431,11 @@ VERIFYD_STATUS_KEYS = {
     # engine ran on a larger shape than the smallest fit, windows it ran as
     # several executables, windows whose hold ran out / ended early with
     # everybody in step back, launches by the padded slots run ({"1024": n,
-    # ...}), the slowest one.
+    # ...}) and by the rows a chip of their thinnest chunk ({"256": n, ...}),
+    # the slowest one.
     "stage_seconds", "promoted_launches", "split_launches", "held_out_launches",
-    "in_step_launches", "launches_by_rung", "slowest_launch",
+    "in_step_launches", "launches_by_rung", "launches_by_rows_per_chip",
+    "slowest_launch",
     "memory_peak_bytes", "warm_stats", "warm_error",
 }
 VERIFYD_WARM_STATS_KEYS = {

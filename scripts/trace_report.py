@@ -14,6 +14,7 @@ from __future__ import annotations
 import json
 import pathlib
 import sys
+from collections import Counter
 
 
 def _pct(sorted_vals, q: float):
@@ -194,6 +195,18 @@ def report(files) -> dict:
             )
         else:
             print(f"{path.name}: no verify_batch events")
+        # The verify service's lines say where each window ran: over how
+        # many chips, and how many rows a chip its thinnest chunk gave.
+        sharded = [e for e in vb if "devices" in e and "rows_per_chip" in e]
+        if sharded:
+            chips = sorted({e["devices"] for e in sharded})
+            by_rows = Counter(e["rows_per_chip"] for e in sharded)
+            print(
+                f"{path.name}: {len(sharded)} launches sharded over "
+                f"{'/'.join(map(str, chips))} chip(s); rows a chip of the "
+                "thinnest chunk: "
+                + "  ".join(f"{rows}: {n}" for rows, n in sorted(by_rows.items()))
+            )
     if total["batches"]:
         print(
             f"cluster: {total['items']} verifications in {total['batches']} "

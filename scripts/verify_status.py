@@ -11,9 +11,10 @@ warm-up), the serving table made from those costs (``16→256`` = a window
 that fits 16 slots runs on the 256-slot program) and how many launches it
 promoted, the chunk plan (``1025-1280→1024+256`` = a window of that many
 items runs as two launches) and how many windows it split, the running total of each launch stage (queue, slot, pad, put,
-dispatch, wait, unpack), the launches by shape run and by which exit of
-the hold cut their window, the slowest launch so far with the step that
-held it, and the device's peak memory.
+dispatch, wait, unpack), the launches by shape run, by which exit of
+the hold cut their window and by the rows a chip their thinnest chunk
+gave, the slowest launch so far with the step that held it, and the
+device's peak memory.
 
     python scripts/verify_status.py                      # default target
     python scripts/verify_status.py 127.0.0.1:7600
@@ -118,6 +119,11 @@ def main(argv=None) -> int:
             f"{rung} slots: {n}" for rung, n in sorted(by_rung.items(), key=lambda kv: int(kv[0]))
         ) + "  (hold ran out %d, in step %d)" % (
             status.get("held_out_launches", 0), status.get("in_step_launches", 0)))
+    by_rows = status.get("launches_by_rows_per_chip") or {}
+    if by_rows:
+        print("  rows a chip     " + "  ".join(
+            f"{rows}: {n}" for rows, n in sorted(by_rows.items(), key=lambda kv: int(kv[0]))
+        ) + "  (the thinnest chunk of each launch, over %d chip(s))" % status.get("devices", 0))
     slowest = status.get("slowest_launch")
     if slowest:
         print(
@@ -132,7 +138,7 @@ def main(argv=None) -> int:
         "state", "devices", "uptime_s", "warmed_shapes", "warm_stats",
         "stage_seconds", "slowest_launch", "memory_peak_bytes",
         "promoted_launches", "split_launches", "launches_by_rung", "held_out_launches",
-        "in_step_launches",
+        "in_step_launches", "launches_by_rows_per_chip",
     }
     for k in sorted(set(status) - known):
         print(f"  {k:<15} {status[k]}")

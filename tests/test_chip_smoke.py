@@ -114,6 +114,68 @@ def test_device_stage_catches_one_wrong_verdict(service):
         chip_smoke.device_stage(daemon.address, seed=7, ladder=(16, 64))
 
 
+def _four_chips(shapes, lie=None):
+    """The real engine over four virtual devices, its arithmetic the native
+    host verifier's (``_f5_x4_rehearse.host_arithmetic``), saying ``tpu`` so
+    that the stage takes it."""
+    from _f5_x4_rehearse import host_arithmetic
+
+    from pbft_tpu.net import ShardedVerifyEngine
+
+    class Engine(ShardedVerifyEngine):
+        def init_backend(self):
+            super().init_backend()
+            self.platform = "tpu"
+            self.devices_seen = self.device_count
+
+    return Engine(shapes=shapes, devices=4, kernel=host_arithmetic(lie))
+
+
+def test_device_stage_plants_every_class_in_every_chips_rows(service, monkeypatch):
+    """On a mesh of four a window that fills its shape carries the seven
+    rejects and the control in EACH quarter, and its launch line has to say
+    that it ran as a quarter of its rows on each of four chips."""
+    engine = _four_chips((32, 64))
+    daemon, trace = service(engine)
+    engine._route({32: 0.001, 64: 0.002})  # smallest-fit, nothing split, whatever this host read
+    windows = []
+    real = chip_smoke.make_window
+
+    def noting(rng, pool, size, oracle, shards=1):
+        items, classes = real(rng, pool, size, oracle, shards)
+        windows.append((size, shards, classes))
+        return items, classes
+
+    monkeypatch.setattr(chip_smoke, "make_window", noting)
+    chip_smoke.device_stage(daemon.address, seed=11, ladder=(32, 64), trace_path=trace)
+    # 32 slots are 8 rows a chip: just room for the eight classes; 16-item
+    # quarter-windows (merged into the 64 shape) are planted as before.
+    assert [(size, shards) for size, shards, _ in windows] == (
+        [(32, 4), (64, 4)] + [(64, 4)] * 3 + [(16, 1)] * 4
+    )
+    for size, shards, classes in windows:
+        rows = size // shards
+        for shard in range(shards):
+            names = [name for pos, name in classes.items() if pos // rows == shard]
+            assert len(names) == len(set(names)) == chip_smoke.N_CLASSES
+    assert daemon.status_json()["launches_by_rows_per_chip"].keys() == {"8", "16"}
+
+
+def test_device_stage_catches_a_chip_that_decides_one_class_wrongly(service):
+    """The fourth chip's rows alone accept S >= L: a window's few planted
+    items need not sit there, one of every class in every chip's rows does."""
+    from pbft_tpu.crypto import ref
+
+    def last_chip_accepts_big_s(row, item, verdict):
+        # rows 24-31 of the 32-slot executable, 48-63 of the 64-slot one
+        return verdict or (row >= 24 and int.from_bytes(item[2][32:], "little") >= ref.L)
+
+    engine = _four_chips((32,), lie=last_chip_accepts_big_s)
+    daemon, trace = service(engine)
+    with pytest.raises(chip_smoke.SmokeFailure, match=r"rung 32: item (2[4-9]|3[01]) \(S >= L\): device says True"):
+        chip_smoke.device_stage(daemon.address, seed=11, ladder=(32,))
+
+
 def test_deployment_stage_fails_when_the_service_dies_mid_run(
     service, tmp_path, monkeypatch
 ):

@@ -14,6 +14,7 @@ import sys
 import threading
 import time
 import urllib.request
+from collections import Counter
 from pathlib import Path
 
 import pytest
@@ -235,6 +236,8 @@ def test_engine_steps_sum_to_secs_with_two_launches_in_flight(tmp_path, monkeypa
         # 1-8 items: 8 slots; 9-16: 8 + 8; 17-22 (merged): 16 + 8 as before.
         assert (e["chunks"], e["rung"]) == ((1, 8) if e["size"] <= 8 else (2, 16) if e["size"] <= 16 else (2, 24))
         assert e["split"] == (e["size"] > 8) and e["promoted"] == 0
+        # every plan here has an 8-slot chunk, an eighth of it a virtual device
+        assert (e["devices"], e["rows_per_chip"]) == (engine.device_count, 8 // engine.device_count)
         assert sum(e[k] for k in STEPS) <= e["secs"] + 1e-5
         assert e["ts"] - e["secs"] - 1e-3 <= e["t_dev"] <= e["ts"]
     split = [e for e in chunked if e["split"]]
@@ -250,6 +253,9 @@ def test_engine_steps_sum_to_secs_with_two_launches_in_flight(tmp_path, monkeypa
         assert e["rung"] == _rung_of(e["size"], shapes)
         assert e["promoted"] == 0  # a flat-cost kernel: the tie keeps smallest-fit
         assert (e["chunks"], e["split"]) == ((2, 1) if e["size"] > 16 else (1, 0))
+        # the thinnest executable the window ran: 16 slots alone, else 8
+        thinnest = 16 if 8 < e["size"] <= 16 else 8
+        assert (e["devices"], e["rows_per_chip"]) == (engine.device_count, thinnest // engine.device_count)
         assert e["ts"] - e["secs"] - 1e-3 <= e["t_dev"] <= e["ts"]
         # The steps lie inside the interval `secs` times, one after another,
         # so they never add up to more than it.
@@ -277,7 +283,58 @@ def test_engine_steps_sum_to_secs_with_two_launches_in_flight(tmp_path, monkeypa
     assert status["memory_peak_bytes"] is None or status["memory_peak_bytes"] >= 0
     assert status["promoted_launches"] == 0
     assert status["split_launches"] == sum(e["split"] for e in lines + chunked)
+    by_rows = Counter(str(e["rows_per_chip"]) for e in lines + chunked)
+    assert status["launches_by_rows_per_chip"] == by_rows and set(by_rows) <= {"1", "2"}
+    assert sum(by_rows.values()) == status["engine_launches"]
     assert status["warm_stats"]["serving_table"] == {"8": 8, "16": 16}
+
+
+def test_a_one_device_engine_says_so_on_every_launch_line(tmp_path, capsys):
+    """``devices`` is what the executables' input sharding spans, kept at
+    warm-up, and ``rows_per_chip`` the slots of the window's smallest chunk
+    over it: on one device 1, and the chunk's own slots. The status JSON
+    counts launches by it, and the two scripts print both."""
+    sys.path.insert(0, str(ROOT / "scripts"))
+    try:
+        import trace_report
+        import verify_status
+    finally:
+        sys.path.pop(0)
+    shapes = (8, 16)
+    engine = ShardedVerifyEngine(shapes=shapes, devices=1, kernel=lambda p, m, s: p[:, 0] == s[:, 0])
+    trace = tmp_path / "verifyd.jsonl"
+    daemon = VerifyServiceDaemon(
+        backend="auto", engine=engine, trace_path=str(trace),
+        fallback=lambda items: pytest.fail("the fallback ran"),
+    ).start(wait_ready=True, timeout=300)
+    try:
+        assert daemon.state_name == "ready" and engine.device_count == 1
+        assert [p["devices"] for p in engine.stats["per_shape"]] == [[0], [0]]
+        engine._route({8: 0.001, 16: 0.004})  # 9-16 items run as 8 + 8 slots
+        for n in (3, 8, 12, 16 + 2):
+            assert _send_batch(daemon.address, [_item(n, True)] * n) == [True] * n
+        engine._route(dict.fromkeys(shapes, 0.001))  # and as one shape
+        assert _send_batch(daemon.address, [_item(12, True)] * 12) == [True] * 12
+        status = daemon.status_json()
+        capsys.readouterr()
+        assert verify_status.main([daemon.address]) == 0
+    finally:
+        daemon.stop()
+    lines = _lines(trace)
+    assert [(e["size"], e["rung"], e["devices"], e["rows_per_chip"]) for e in lines] == [
+        (3, 8, 1, 8), (8, 8, 1, 8), (12, 16, 1, 8), (18, 24, 1, 8), (12, 16, 1, 16),
+    ]
+    assert status["launches_by_rows_per_chip"] == {"8": 4, "16": 1}
+    assert sum(status["launches_by_rows_per_chip"].values()) == status["engine_launches"]
+    assert set(status) <= trace_schema.VERIFYD_STATUS_KEYS
+    assert {"devices", "rows_per_chip"} <= trace_schema.EVENT_SCHEMAS["verify_batch"]["optional"]
+    out = capsys.readouterr().out
+    assert "rows a chip     8: 4  16: 1  (the thinnest chunk of each launch, over 1 chip(s))" in out
+    assert "launches_by_rows_per_chip" not in out  # printed once, as that line
+    trace_report.report([trace])
+    assert "5 launches sharded over 1 chip(s); rows a chip of the thinnest chunk: 8: 4  16: 1" in (
+        capsys.readouterr().out
+    )
 
 
 # -- (b') which executable serves a window: the table, and an engine that promotes
@@ -884,11 +941,13 @@ FORMS = {
 # an entry pins its own digest here).
 ACCEPTED_PER_LAYER = 27
 ACCEPTED_DIGEST = "7f2b2c41f15ac07556e716381fd4915806724ddc56d4b01011fdd21d1e6e3800"
-ADDED_CONFIGS = ["f5-sig-wal", "f1-mac-tentative"]
-ADDED_CELLS = ["f5-sig-wal.closed", "f1-mac-tentative.closed"]
+ADDED_CONFIGS = ["f5-sig-wal", "f1-mac-tentative", "f5-sig-wal-x4"]
+ADDED_CELLS = ["f5-sig-wal.closed", "f1-mac-tentative.closed", "f5-sig-wal-x4.closed"]
 # The metrics of this table that PR 32's cell is listed under too (it reports
 # no verify trip, so none of the others).
 ALSO_IN_MAC_CELL = {"engine_idle_pct", "wal_flush_ms_mean"}
+# PR 36's four-chip cell reports whatever its one-chip twin reports.
+X4_CELL, X4_TWIN = "f5-sig-wal-x4.closed", "f5-sig-wal.closed"
 
 
 @pytest.mark.parametrize(
@@ -903,6 +962,8 @@ def test_new_metric_has_its_reader_and_its_entry(name, form):
     moves, cells = FORMS[form]
     if form == ".closed" and name in ALSO_IN_MAC_CELL:
         cells = cells + ["f1-mac-tentative.closed"]
+    if form == ".closed":
+        cells = cells + [X4_CELL]
     spec = json.loads((CHIPBENCH / "metrics" / f"{name}{form}.json").read_text())
     assert spec["name"] == name + form
     assert (CHIPBENCH / "reducers" / f"{spec['reducer']}.py").is_file()
@@ -913,6 +974,40 @@ def test_new_metric_has_its_reader_and_its_entry(name, form):
         "layer": layer, "moves": moves, "workloads": cells,
     }]
     assert bench["per_layer"].index(entry[0]) >= ACCEPTED_PER_LAYER
+
+
+def _read(metric, run):
+    """``chipbench/metrics/<metric>.json`` on ``run``, as the harness reads it."""
+    spec = json.loads((CHIPBENCH / "metrics" / f"{metric}.json").read_text())
+    return _reducer(spec["reducer"])(run, spec.get("args", {}))
+
+
+def test_the_four_chip_cell_is_listed_wherever_its_twin_is_and_brings_two_readers():
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        cells = m.get("workloads", [])
+        assert (X4_CELL in cells) == (X4_TWIN in cells), m["name"]
+    # Two readings of the launch lines' new fields, on the reducer that is
+    # there, in the two f=5 cells: the chips a window's executables are
+    # sharded over (1.0 and 4.0) and the rows a chip of its thinnest chunk.
+    for name, field, unit in (("mesh_chips.closed", "devices", "chips"),
+                              ("rows_per_chip_mean.closed", "rows_per_chip", "rows")):
+        spec = json.loads((CHIPBENCH / "metrics" / f"{name}.json").read_text())
+        assert spec == {"name": name, "reducer": "launch_field_stat",
+                        "args": {"fields": [field], "stat": "mean"}}
+        assert [m for m in bench["per_layer"] if m["name"] == name] == [{
+            "name": name, "unit": unit, "better": "higher", "source": "program_counter",
+            "layer": "verifyd engine", "moves": "commit_rate", "workloads": [X4_TWIN, X4_CELL],
+        }]
+    assert [m["name"] for m in bench["per_layer"]][-2:] == ["mesh_chips.closed", "rows_per_chip_mean.closed"]
+    one = [{"devices": 1, "rows_per_chip": r} for r in (256, 1024, 256)]
+    four = [{"devices": 4, "rows_per_chip": r} for r in (256, 256, 1024, 256)]
+    assert _read("mesh_chips.closed", {"launches": one}) == 1.0
+    assert _read("mesh_chips.closed", {"launches": four}) == 4.0
+    assert _read("rows_per_chip_mean.closed", {"launches": four}) == 448.0
+    # a program from before the fields, as the parent commit is: nothing, and no error
+    assert _read("mesh_chips.closed", {"launches": [{"rung": 256}]}) is None
+    assert _read("rows_per_chip_mean.closed", {"launches": []}) is None
 
 
 def test_accepted_benchmark_entries_are_unchanged():
