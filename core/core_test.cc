@@ -476,6 +476,121 @@ void test_secure_channel_native() {
   CHECK(d.error().find("plaintext peer rejected") != std::string::npos);
 }
 
+// --- ISSUE 37: a verify batch is a SPAN of the inbox ------------------------
+//
+// The runtime ships the span behind a batch before it works through that
+// batch's verdicts, so several spans may be cut (pending_items) before the
+// first is delivered (deliver_verdicts). Order is the safety property.
+
+pbft::Prepare span_prepare(int64_t seq, int64_t from,
+                           const std::vector<std::vector<uint8_t>>& seeds) {
+  pbft::Prepare p;
+  p.view = 0;
+  p.seq = seq;
+  p.digest = std::string(64, 'a');
+  p.replica = from;
+  return test_sign(p, seeds[from]);
+}
+
+void test_span_delivered_while_next_on_wire() {
+  std::vector<std::vector<uint8_t>> seeds;
+  auto cfg = test_config(&seeds);
+  pbft::Replica r(cfg, 1, seeds[1].data());
+  pbft::CpuVerifier cpu;
+  r.receive(pbft::Message(span_prepare(1, 2, seeds)));
+  r.receive(pbft::Message(span_prepare(1, 3, seeds)));
+  auto span1 = r.pending_items();
+  CHECK(span1.size() == 2);
+  CHECK(r.unlaunched_count() == 0 && r.pending_count() == 2);
+  // Span 1's verdicts are in hand; what arrived behind it goes on the wire
+  // BEFORE they are applied, and holds none of span 1's entries.
+  auto third = span_prepare(2, 2, seeds);
+  r.receive(pbft::Message(third));
+  CHECK(r.unlaunched_count() == 1);
+  auto span2 = r.pending_items();
+  CHECK(span2.size() == 1);
+  uint8_t want[32];
+  pbft::message_signable(pbft::Message(third), want);
+  CHECK(std::memcmp(span2[0].msg, want, 32) == 0);
+  CHECK(r.pending_items().empty());  // nothing is cut twice
+  // A message the replica gets while both are undelivered (a self-
+  // delivered vote, say) queues behind span 2.
+  r.receive(pbft::Message(span_prepare(2, 3, seeds)));
+  CHECK(r.unlaunched_count() == 1 && r.pending_count() == 4);
+  r.deliver_verdicts(cpu.verify_batch(span1));
+  CHECK(r.counters["prepares_accepted"] == 2);
+  CHECK(r.pending_count() == 2 && r.unlaunched_count() == 1);
+  r.deliver_verdicts(cpu.verify_batch(span2));
+  CHECK(r.counters["prepares_accepted"] == 3);
+  CHECK(r.pending_count() == 1 && r.unlaunched_count() == 1);
+  auto span3 = r.pending_items();
+  CHECK(span3.size() == 1);
+  r.deliver_verdicts(cpu.verify_batch(span3));
+  CHECK(r.counters["prepares_accepted"] == 4);
+  CHECK(r.counters["sig_verified"] == 4 && r.counters["sig_rejected"] == 0);
+  CHECK(r.pending_count() == 0 && r.unlaunched_count() == 0);
+}
+
+void test_pre_authenticated_waits_for_span_on_wire() {
+  // ISSUE 14's wedge, with spans: a MAC-accepted entry drains only up to
+  // the first entry that awaits a verdict — also when that entry's span
+  // is on the wire and its verdict simply has not come back yet.
+  std::vector<std::vector<uint8_t>> seeds;
+  auto cfg = test_config(&seeds);
+  pbft::Replica r(cfg, 1, seeds[1].data());
+  r.receive(pbft::Message(span_prepare(1, 2, seeds)));
+  auto span1 = r.pending_items();
+  // Behind span 1: a pre-authenticated entry, then a signed one (span 2,
+  // on the wire), then two more pre-authenticated ones.
+  r.receive_authenticated(pbft::Message(span_prepare(2, 2, seeds)));
+  r.receive(pbft::Message(span_prepare(1, 3, seeds)));
+  auto span2 = r.pending_items();
+  CHECK(span1.size() == 1 && span2.size() == 1);
+  r.receive_authenticated(pbft::Message(span_prepare(2, 3, seeds)));
+  r.receive_authenticated(pbft::Message(span_prepare(3, 2, seeds)));
+  CHECK(r.counters["mac_verified"] == 3);
+  CHECK(r.counters["prepares_accepted"] == 0);  // all queued, none overtook
+  CHECK(r.unlaunched_count() == 2);
+  CHECK(r.pending_items().empty());  // they await no verdict: no launch
+  r.deliver_verdicts({1});
+  // Span 1's entry and the pre-authenticated one right behind it; the two
+  // behind the on-the-wire entry stay where they are.
+  CHECK(r.counters["prepares_accepted"] == 2);
+  CHECK(r.pending_count() == 3);
+  r.deliver_verdicts({1});
+  CHECK(r.counters["prepares_accepted"] == 5);
+  CHECK(r.pending_count() == 0 && r.unlaunched_count() == 0);
+  // Inbox empty again: the fast path dispatches at once.
+  r.receive_authenticated(pbft::Message(span_prepare(3, 3, seeds)));
+  CHECK(r.counters["prepares_accepted"] == 6);
+}
+
+void test_rejected_signature_in_kept_span() {
+  std::vector<std::vector<uint8_t>> seeds;
+  auto cfg = test_config(&seeds);
+  pbft::Replica r(cfg, 1, seeds[1].data());
+  pbft::CpuVerifier cpu;
+  auto forged = span_prepare(1, 2, seeds);
+  forged.digest = std::string(64, 'b');  // signed over another digest
+  r.receive(pbft::Message(forged));
+  r.receive(pbft::Message(span_prepare(1, 3, seeds)));
+  auto span1 = r.pending_items();
+  r.receive(pbft::Message(span_prepare(2, 2, seeds)));
+  auto span2 = r.pending_items();
+  auto v1 = cpu.verify_batch(span1);
+  CHECK(v1 == (std::vector<uint8_t>{0, 1}));
+  r.deliver_verdicts(v1);
+  // The forgery is dropped, the entry behind it in the span dispatched,
+  // and span 2's entry still waits for its own verdict.
+  CHECK(r.counters["sig_rejected"] == 1 && r.counters["sig_verified"] == 1);
+  CHECK(r.counters["prepares_accepted"] == 1);
+  CHECK(r.pending_count() == 1);
+  r.deliver_verdicts(cpu.verify_batch(span2));
+  CHECK(r.counters["prepares_accepted"] == 2);
+  CHECK(r.pending_count() == 0);
+}
+
+
 void test_batch_verify_rlc() {
   // The RLC + Pippenger batch path must agree with per-item verify:
   // honest windows all-accept, corrupted items are isolated by the
@@ -773,6 +888,28 @@ int parity_listen_ephemeral(int* port_out) {
   return fd;
 }
 
+// Four identities on loopback ports that were free a moment ago (seed of
+// replica i: 32 bytes of seed_base + i).
+pbft::ClusterConfig loopback_config(uint8_t seed_base, int* ports,
+                                    std::vector<std::vector<uint8_t>>* seeds) {
+  pbft::ClusterConfig cfg;
+  int hold[4];
+  for (int i = 0; i < 4; ++i) {
+    hold[i] = parity_listen_ephemeral(&ports[i]);
+    CHECK(hold[i] >= 0);
+    std::vector<uint8_t> seed(32, (uint8_t)(seed_base + i));
+    pbft::ReplicaIdentity ident;
+    ident.replica_id = i;
+    ident.host = "127.0.0.1";
+    ident.port = ports[i];
+    pbft::ed25519_public_key(ident.pubkey, seed.data());
+    cfg.replicas.push_back(ident);
+    seeds->push_back(seed);
+  }
+  for (int i = 0; i < 4; ++i) ::close(hold[i]);
+  return cfg;
+}
+
 // Send `req` to the replicas in turn (a retransmission every 400 ms, as a
 // client would) until `want` DISTINCT replicas have dialed back with a
 // reply, or `seconds` pass; returns who replied. Every replica dials back
@@ -837,24 +974,8 @@ double prometheus_sample(const std::string& text, const std::string& name) {
 // one), one flush observation per WAL flush that had records pending.
 void parity_round(const char* want_backend) {
   int ports[4];
-  int hold[4];
-  for (int i = 0; i < 4; ++i) {
-    hold[i] = parity_listen_ephemeral(&ports[i]);
-    CHECK(hold[i] >= 0);
-  }
-  pbft::ClusterConfig cfg;
   std::vector<std::vector<uint8_t>> seeds;
-  for (int i = 0; i < 4; ++i) {
-    std::vector<uint8_t> seed(32, (uint8_t)(i + 41));
-    pbft::ReplicaIdentity ident;
-    ident.replica_id = i;
-    ident.host = "127.0.0.1";
-    ident.port = ports[i];
-    pbft::ed25519_public_key(ident.pubkey, seed.data());
-    cfg.replicas.push_back(ident);
-    seeds.push_back(seed);
-  }
-  for (int i = 0; i < 4; ++i) ::close(hold[i]);
+  pbft::ClusterConfig cfg = loopback_config(41, ports, &seeds);
   const char* tmp = std::getenv("TMPDIR");
   std::string wal_dir = std::string(tmp ? tmp : "/tmp") + "/pbft-parity-wal-XXXXXX";
   CHECK(::mkdtemp(wal_dir.data()) != nullptr);
@@ -917,27 +1038,11 @@ void test_net_backend_parity() {
 int64_t multicore_round(int net_threads, bool fastpath_mac = false,
                         bool tentative = false) {
   int ports[4];
-  int hold[4];
-  for (int i = 0; i < 4; ++i) {
-    hold[i] = parity_listen_ephemeral(&ports[i]);
-    CHECK(hold[i] >= 0);
-  }
-  pbft::ClusterConfig cfg;
+  std::vector<std::vector<uint8_t>> seeds;
+  pbft::ClusterConfig cfg = loopback_config(73, ports, &seeds);
   cfg.net_threads = net_threads;
   if (fastpath_mac) cfg.fastpath = "mac";
   cfg.tentative = tentative;
-  std::vector<std::vector<uint8_t>> seeds;
-  for (int i = 0; i < 4; ++i) {
-    std::vector<uint8_t> seed(32, (uint8_t)(i + 73));
-    pbft::ReplicaIdentity ident;
-    ident.replica_id = i;
-    ident.host = "127.0.0.1";
-    ident.port = ports[i];
-    pbft::ed25519_public_key(ident.pubkey, seed.data());
-    cfg.replicas.push_back(ident);
-    seeds.push_back(seed);
-  }
-  for (int i = 0; i < 4; ++i) ::close(hold[i]);
   std::vector<std::unique_ptr<pbft::ReplicaServer>> servers;
   for (int i = 0; i < 4; ++i) {
     servers.push_back(std::make_unique<pbft::ReplicaServer>(
@@ -999,6 +1104,187 @@ void test_multicore_parity() {
   CHECK(e1 == 2);
   CHECK(e2 == e1);
   CHECK(e4 == e1);
+}
+
+// --- ISSUE 37: the order of a pass on the async branch ----------------------
+//
+// A verifier the test scripts: begin_batch records the span it was given
+// (and how many verdicts the replica had applied by then), the test makes
+// the fd readable and says what poll_result returns.
+struct ScriptedVerifier : pbft::Verifier {
+  int fds[2] = {-1, -1};
+  bool inflight = false;
+  bool refuse = false;      // begin_batch: transport down
+  bool fail_next = false;   // poll_result: the service died mid-launch
+  pbft::Replica* replica = nullptr;
+  std::vector<std::vector<pbft::VerifyItem>> launched, blocking;
+  std::vector<int64_t> applied_at_launch, applied_at_blocking;
+  int cancelled = 0;
+
+  ScriptedVerifier() { CHECK(::socketpair(AF_UNIX, SOCK_STREAM, 0, fds) == 0); }
+  ~ScriptedVerifier() override {
+    ::close(fds[0]);
+    ::close(fds[1]);
+  }
+  int64_t applied() {
+    return replica->counters["sig_verified"] + replica->counters["sig_rejected"];
+  }
+  std::vector<uint8_t> verify_batch(
+      const std::vector<pbft::VerifyItem>& items) override {
+    blocking.push_back(items);
+    applied_at_blocking.push_back(applied());
+    return pbft::CpuVerifier().verify_batch(items);
+  }
+  int async_fd() const override { return inflight ? fds[0] : -1; }
+  bool begin_batch(const std::vector<pbft::VerifyItem>& items) override {
+    if (refuse || inflight) return false;
+    launched.push_back(items);
+    applied_at_launch.push_back(applied());
+    inflight = true;
+    return true;
+  }
+  bool poll_result(std::vector<uint8_t>* out, bool* failed) override {
+    char b;
+    CHECK(::read(fds[0], &b, 1) == 1);
+    inflight = false;
+    *failed = fail_next;
+    if (!fail_next) *out = pbft::CpuVerifier().verify_batch(launched.back());
+    return true;
+  }
+  void cancel_inflight() override {
+    inflight = false;
+    ++cancelled;
+  }
+  void answer() { CHECK(::write(fds[1], "v", 1) == 1); }
+};
+
+struct SpanLoop {
+  std::vector<std::vector<uint8_t>> seeds;
+  ScriptedVerifier* sv = nullptr;
+  std::unique_ptr<pbft::ReplicaServer> srv;
+
+  SpanLoop() {
+    int ports[4];
+    pbft::ClusterConfig cfg = loopback_config(1, ports, &seeds);
+    auto v = std::make_unique<ScriptedVerifier>();
+    sv = v.get();
+    srv = std::make_unique<pbft::ReplicaServer>(cfg, 1, seeds[1].data(),
+                                                std::move(v));
+    sv->replica = &srv->replica();
+    srv->metrics().enabled = true;
+    CHECK(srv->start());
+  }
+  // A signed PREPARE from `from` straight into the verify inbox.
+  void queue(int64_t seq, int64_t from, bool forged = false) {
+    auto p = span_prepare(seq, from, seeds);
+    if (forged) p.digest = std::string(64, 'b');
+    srv->replica().receive(pbft::Message(p));
+  }
+  double sample(const std::string& name) {
+    return prometheus_sample("\n" + srv->metrics().render_prometheus("1"), name);
+  }
+};
+
+void test_loop_launches_ahead_of_kept_verdicts() {
+  SpanLoop t;
+  t.queue(1, 2);
+  t.queue(1, 3);
+  t.srv->poll_once(0);
+  CHECK(t.sv->launched.size() == 1 && t.sv->launched[0].size() == 2);
+  // Span 2 accumulates during the trip; nothing more is launched.
+  t.queue(2, 2);
+  t.queue(2, 3, /*forged=*/true);
+  t.queue(2, 0);
+  t.srv->poll_once(0);
+  CHECK(t.sv->launched.size() == 1);
+  CHECK(t.sample("pbft_verify_launched_ahead_total") == 0);
+  // The verdicts come back: the SAME pass ships span 2 first (none of span
+  // 1's verdicts applied at that moment), then works through span 1.
+  t.sv->answer();
+  t.srv->poll_once(50);
+  CHECK(t.sv->launched.size() == 2 && t.sv->launched[1].size() == 3);
+  CHECK(t.sv->applied_at_launch[1] == 0);
+  CHECK(t.srv->replica().counters["prepares_accepted"] == 2);
+  CHECK(t.srv->replica().pending_count() == 3);
+  CHECK(t.sample("pbft_verify_launched_ahead_total") == 1);
+  CHECK(t.sample("pbft_verify_batches_total") == 1);
+  CHECK(t.sample("pbft_verdict_held_seconds_count") == 1);
+  // Span 2 comes back with nothing behind it: no launch, its forgery is
+  // dropped, the clock on held verdicts still runs once a batch.
+  t.sv->answer();
+  t.srv->poll_once(50);
+  CHECK(t.sv->launched.size() == 2 && t.sv->blocking.empty());
+  CHECK(t.srv->replica().counters["prepares_accepted"] == 4);
+  CHECK(t.srv->replica().counters["sig_rejected"] == 1);
+  CHECK(t.srv->replica().pending_count() == 0);
+  CHECK(t.sample("pbft_verify_launched_ahead_total") == 1);
+  CHECK(t.sample("pbft_verify_batches_total") == 2);
+  CHECK(t.sample("pbft_verdict_held_seconds_count") == 2);
+  CHECK(t.sample("pbft_verify_seconds_count") == 2);
+  CHECK(t.sample("pbft_verify_service_fallbacks_total") == 0);
+}
+
+void test_loop_wedge_deadline_keeps_order() {
+  // The wedged trip: the clock is the wire's, the safety net runs on the
+  // span that is on the wire and on nothing behind it, and its verdicts
+  // are kept like any others — the span behind goes out first.
+  SpanLoop t;
+  t.srv->set_verify_deadline_ms(40);
+  t.queue(1, 2);
+  t.srv->poll_once(0);
+  t.queue(1, 3);
+  t.queue(2, 2, /*forged=*/true);
+  t.sv->answer();
+  t.srv->poll_once(50);  // span 2 launched ahead, span 1 applied
+  CHECK(t.sv->launched.size() == 2 && t.sv->applied_at_launch[1] == 0);
+  CHECK(t.srv->replica().counters["prepares_accepted"] == 1);
+  t.queue(2, 3);  // span 3, behind the wedged trip
+  std::this_thread::sleep_for(std::chrono::milliseconds(60));
+  t.srv->poll_once(0);  // no answer ever comes: the deadline fires
+  CHECK(t.sv->cancelled == 1);
+  CHECK(t.sample("pbft_verify_deadline_fired_total") == 1);
+  CHECK(t.sample("pbft_verify_service_fallbacks_total") == 1);
+  // Span 3 went out before the safety net's verdicts for span 2 were
+  // applied (one applied so far: span 1's), holding span 3's entry only.
+  CHECK(t.sv->launched.size() == 3 && t.sv->launched[2].size() == 1);
+  CHECK(t.sv->applied_at_launch[2] == 1);
+  CHECK(t.sv->blocking.empty());
+  CHECK(t.srv->replica().counters["prepares_accepted"] == 2);
+  CHECK(t.srv->replica().counters["sig_rejected"] == 1);
+  CHECK(t.srv->replica().pending_count() == 1);
+  CHECK(t.sample("pbft_verify_launched_ahead_total") == 2);
+  t.sv->answer();
+  t.srv->poll_once(50);
+  CHECK(t.srv->replica().counters["prepares_accepted"] == 3);
+  CHECK(t.srv->replica().pending_count() == 0);
+}
+
+void test_loop_transport_failure_keeps_order() {
+  // The service dies mid-launch and stays down: the safety net verifies
+  // the wire's span only; the span behind it cannot be shipped, so the
+  // kept verdicts are applied BEFORE its blocking verify, never after.
+  SpanLoop t;
+  t.queue(1, 2);
+  t.queue(1, 3, /*forged=*/true);
+  t.srv->poll_once(0);
+  CHECK(t.sv->launched.size() == 1);
+  t.queue(2, 2);
+  t.queue(2, 3);
+  t.sv->fail_next = true;
+  t.sv->refuse = true;
+  t.sv->answer();
+  t.srv->poll_once(50);
+  CHECK(t.sv->launched.size() == 1);
+  CHECK(t.sv->blocking.size() == 1 && t.sv->blocking[0].size() == 2);
+  CHECK(t.sv->applied_at_blocking[0] == 2);  // span 1 whole, forgery included
+  CHECK(t.srv->replica().counters["sig_rejected"] == 1);
+  CHECK(t.srv->replica().counters["prepares_accepted"] == 3);
+  CHECK(t.srv->replica().pending_count() == 0);
+  CHECK(t.sample("pbft_verify_service_fallbacks_total") == 1);
+  CHECK(t.sample("pbft_verify_launched_ahead_total") == 0);
+  CHECK(t.sample("pbft_verify_batches_total") == 2);
+  CHECK(t.sample("pbft_verdict_held_seconds_count") == 1);  // async branch only
+  CHECK(t.sample("pbft_verify_inbox_wait_seconds_count") == 0);  // no emit() stamped one
 }
 
 // ISSUE 14: MAC-vector codec units + the authenticator/tentative mode
@@ -1262,12 +1548,18 @@ int main() {
   test_view_change_native();
   test_stable_digest_majority_native();
   test_state_transfer_native();
+  test_span_delivered_while_next_on_wire();
+  test_pre_authenticated_waits_for_span_on_wire();
+  test_rejected_signature_in_kept_span();
   test_batch_verify_rlc();
   test_verify_pool_native();
   test_remote_verifier_async();
   test_remote_verifier_readiness();
   test_net_backend_parity();
   test_multicore_parity();
+  test_loop_launches_ahead_of_kept_verdicts();
+  test_loop_wedge_deadline_keeps_order();
+  test_loop_transport_failure_keeps_order();
   test_mac_codec_native();
   test_fastpath_mac_parity();
   test_flight_recorder();

@@ -59,6 +59,9 @@ const char* kCounterNames[] = {
     // a closed watermark window, and signatures checked on the host in
     // the normal case (a checkpoint's embedded one, in MAC mode).
     "pbft_seal_refused_total", "pbft_inline_verifies_total",
+    // Verify batches launched while the verdicts of the batch before were
+    // kept (ISSUE 37): that trip runs behind the replica's own pass.
+    "pbft_verify_launched_ahead_total",
     // Durable-recovery surface (ISSUE 15): WAL records appended, group-
     // commit fsync syscalls, and file bytes written.
     "pbft_wal_appends_total", "pbft_wal_fsyncs_total",
@@ -116,6 +119,9 @@ const std::pair<const char*, bool> kHistogramNames[] = {
     // flush (write + fsync).
     {"pbft_verify_inbox_wait_seconds", false},
     {"pbft_wal_flush_seconds", false},
+    // Verdicts read -> their delivery began (once a batch on the async
+    // branch): what launching the next span first adds to a batch.
+    {"pbft_verdict_held_seconds", false},
     // The oldest request's wait at the primary until its batch is sealed
     // (once a batch), and how long a tentative execution stayed revocable
     // (once a sequence number, tentative mode).

@@ -499,9 +499,14 @@ void ReplicaServer::poll_once(int timeout_ms) {
   // self-delivered protocol messages ride this pass's verifier launch.
   check_batch_flush(std::chrono::steady_clock::now());
   // The batching window: everything that arrived this iteration verifies
-  // as one batch (one XLA launch on the TPU backend). With an async
-  // verifier this immediately dispatches the window that accumulated
-  // during the launch that just completed.
+  // as one batch (one XLA launch on the TPU backend). The order of a pass
+  // with an async verifier: the verifier's event above only READ the
+  // verdicts of the batch that came back and kept them; here the span of
+  // the inbox behind that batch (what accumulated during its trip, this
+  // pass's reads included) is launched FIRST, and only then are the kept
+  // verdicts worked through — dispatch, execute, sign, WAL flush, sends —
+  // while the next trip is already under way. One batch on the wire, one
+  // span of verdicts kept, and spans are delivered in inbox order.
   run_verify_batch();
   // Group-commit straggler sweep (ISSUE 15): emit() already flushed
   // before its sends; this covers records noted on paths that produced
@@ -1179,15 +1184,16 @@ double trace_now() {
 
 // Event schemas match the Python tracer's (pbft_tpu/net/server.py) so a
 // mixed-runtime cluster's traces merge without per-runtime special cases.
-void ReplicaServer::trace_batch(int64_t size, int64_t rejected, double secs) {
+void ReplicaServer::trace_batch(int64_t size, int64_t rejected, double secs,
+                                bool ahead) {
   if (!trace_fp_) return;
   std::fprintf(trace_fp_,
                "{\"ts\":%.6f,\"ev\":\"verify_batch\",\"replica\":%lld,"
                "\"size\":%lld,\"rejected\":%lld,\"secs\":%.6f,\"view\":%lld,"
-               "\"executed\":%lld}\n",
+               "\"executed\":%lld,\"ahead\":%d}\n",
                trace_now(), (long long)id_, (long long)size,
                (long long)rejected, secs, (long long)replica_->view(),
-               (long long)replica_->executed_upto());
+               (long long)replica_->executed_upto(), ahead ? 1 : 0);
   std::fflush(trace_fp_);
 }
 
@@ -1479,18 +1485,7 @@ void ReplicaServer::check_verify_deadline(
   CpuVerifier safety_net;
   auto verdicts = safety_net.verify_batch(inflight_items_);
   ++safety_net_batches_;
-  auto dispatched_at = inflight_start_;
-  size_t n_items = inflight_items_.size();
-  verify_inflight_ = false;
-  inflight_items_.clear();
-  inbox_launched_ = 0;
-  deliver_verified(n_items, dispatched_at, std::move(verdicts));
-  if (cfg_.verify_flush_us > 0 && replica_->pending_count() > 0) {
-    // Same backdating as finish_verify_async: what queued during the
-    // wedge has already over-waited — flush it on the next pass.
-    verify_window_open_ = true;
-    verify_window_start_ = dispatched_at;
-  }
+  keep_verdicts(std::move(verdicts));
 }
 
 void ReplicaServer::check_batch_flush(
@@ -1520,8 +1515,18 @@ void ReplicaServer::check_batch_flush(
 }
 
 void ReplicaServer::run_verify_batch() {
-  if (verify_inflight_) return;  // accumulate; finish_verify_async delivers
-  size_t pending = replica_->pending_count();
+  if (verify_inflight_) return;  // accumulate; finish_verify_async keeps
+  launch_verify_span();          // ahead of the verdicts this pass kept
+  if (kept_) {
+    apply_kept_verdicts();
+    // What that delivery queued for the replica itself has nothing on the
+    // wire in front of it unless a span went ahead: launch it now.
+    if (!verify_inflight_) launch_verify_span();
+  }
+}
+
+void ReplicaServer::launch_verify_span() {
+  size_t pending = replica_->unlaunched_count();
   metrics_.set_gauge("pbft_verify_queue_depth", (double)pending);
   if (pending == 0) {
     verify_window_open_ = false;
@@ -1540,8 +1545,12 @@ void ReplicaServer::run_verify_batch() {
     target *= (int64_t)std::max<size_t>(1, verifier_->parallel_capacity());
     auto now = std::chrono::steady_clock::now();
     if (!verify_window_open_) {
+      // Items that queued DURING the trip whose verdicts are kept have
+      // already waited up to that round-trip: backdate the window to its
+      // dispatch so the accumulation hold and the launch overlap instead
+      // of serializing (an item's extra hold stays <= max(flush_us, RTT)).
       verify_window_open_ = true;
-      verify_window_start_ = now;
+      verify_window_start_ = kept_ ? kept_->dispatched_at : now;
     }
     if ((int64_t)pending < target &&
         now - verify_window_start_ <
@@ -1551,6 +1560,12 @@ void ReplicaServer::run_verify_batch() {
     verify_window_open_ = false;
   }
   auto items = replica_->pending_items();
+  if (items.empty()) {
+    // Only pre-authenticated entries (MAC mode): they await no verdict
+    // and drain behind the verdicts in front of them.
+    inbox_waiting_ = false;
+    return;
+  }
   if (inbox_waiting_) {
     // How long the oldest item of this batch sat in the inbox: a message
     // that arrives while a batch is in flight waits out that whole trip.
@@ -1568,22 +1583,31 @@ void ReplicaServer::run_verify_batch() {
   if (verifier_->begin_batch(items)) {
     verify_inflight_ = true;
     inflight_items_ = std::move(items);
-    inbox_launched_ = pending;
     inflight_start_ = std::chrono::steady_clock::now();
     register_verifier_fd();
+    if (kept_) {
+      kept_->launched_ahead = true;
+      ++launched_ahead_;
+      metrics_.inc("pbft_verify_launched_ahead_total");
+    }
     return;
   }
+  apply_kept_verdicts();  // in inbox order: never behind a blocking verify
   auto t0 = std::chrono::steady_clock::now();
-  deliver_verified(items.size(), t0, verifier_->verify_batch(items));
+  auto verdicts = verifier_->verify_batch(items);
+  double secs =
+      std::chrono::duration<double>(std::chrono::steady_clock::now() - t0)
+          .count();
+  deliver_verified(secs, /*ahead=*/false, std::move(verdicts));
 }
 
 int64_t ReplicaServer::verify_service_fallbacks() const {
   return safety_net_batches_ + verifier_->host_fallbacks();
 }
 
-void ReplicaServer::deliver_verified(size_t n_items,
-                                     std::chrono::steady_clock::time_point t0,
+void ReplicaServer::deliver_verified(double secs, bool ahead,
                                      std::vector<uint8_t> verdicts) {
+  const size_t n_items = verdicts.size();
   ++batches_run_;
   // Every host-fallback path ends here, so the counter metric follows
   // the total without a hook in each of them.
@@ -1603,9 +1627,6 @@ void ReplicaServer::deliver_verified(size_t n_items,
   if (metrics_.enabled || trace_fp_) {  // batch boundaries only
     int64_t rejected = 0;
     for (uint8_t v : verdicts) rejected += v ? 0 : 1;
-    double secs =
-        std::chrono::duration<double>(std::chrono::steady_clock::now() - t0)
-            .count();
     metrics_.inc("pbft_verify_batches_total");
     metrics_.inc("pbft_verify_items_total", (int64_t)n_items);
     metrics_.inc("pbft_verify_rejected_total", rejected);
@@ -1625,7 +1646,7 @@ void ReplicaServer::deliver_verified(size_t n_items,
                          (double)ps.last_window_items);
       }
     }
-    if (trace_fp_) trace_batch((int64_t)n_items, rejected, secs);
+    if (trace_fp_) trace_batch((int64_t)n_items, rejected, secs, ahead);
   }
   emit(replica_->deliver_verdicts(verdicts));
 }
@@ -1637,25 +1658,38 @@ void ReplicaServer::finish_verify_async() {
   unregister_verifier_fd();
   if (failed) {
     // Service died mid-launch: a verifier outage degrades throughput,
-    // never safety/liveness — re-verify this batch in-process.
+    // never safety/liveness — re-verify this batch (the span that was on
+    // the wire, nothing behind it) in-process.
     CpuVerifier safety_net;
     verdicts = safety_net.verify_batch(inflight_items_);
     ++safety_net_batches_;
   }
-  auto dispatched_at = inflight_start_;
-  size_t n_items = inflight_items_.size();
+  keep_verdicts(std::move(verdicts));
+}
+
+void ReplicaServer::keep_verdicts(std::vector<uint8_t> verdicts) {
+  apply_kept_verdicts();
+  kept_ = KeptVerdicts{inflight_start_, std::chrono::steady_clock::now(),
+                       /*launched_ahead=*/false, std::move(verdicts)};
   verify_inflight_ = false;
   inflight_items_.clear();
-  inbox_launched_ = 0;
-  deliver_verified(n_items, dispatched_at, std::move(verdicts));
-  // Items that queued DURING the launch have already waited up to the
-  // round-trip: backdate the next flush window to the dispatch time so
-  // the accumulation hold and the launch overlap instead of serializing
-  // (an item's extra hold stays <= max(flush_us, launch RTT)).
-  if (cfg_.verify_flush_us > 0 && replica_->pending_count() > 0) {
-    verify_window_open_ = true;
-    verify_window_start_ = dispatched_at;
+}
+
+void ReplicaServer::apply_kept_verdicts() {
+  if (!kept_) return;
+  KeptVerdicts k = std::move(*kept_);
+  kept_.reset();
+  if (metrics_.enabled) {
+    // What keeping costs a batch: verdicts read -> their delivery begins
+    // (the rest of the pass's events, the batch flush, the launch ahead).
+    metrics_.observe("pbft_verdict_held_seconds",
+                     std::chrono::duration<double>(
+                         std::chrono::steady_clock::now() - k.read_at)
+                         .count());
   }
+  deliver_verified(
+      std::chrono::duration<double>(k.read_at - k.dispatched_at).count(),
+      k.launched_ahead, std::move(k.verdicts));
 }
 
 namespace {
@@ -1799,7 +1833,7 @@ void ReplicaServer::emit(Actions&& actions) {
   // first pass that finds an item no launch has taken stamps its arrival
   // — one clock read per wait, none per message.
   if (metrics_.enabled && !inbox_waiting_ &&
-      replica_->pending_count() > inbox_launched_) {
+      replica_->unlaunched_count() > 0) {
     inbox_waiting_ = true;
     inbox_since_ = std::chrono::steady_clock::now();
   }
@@ -2554,6 +2588,7 @@ std::string ReplicaServer::metrics_json() {
   o["gateway_failovers"] = Json(gateway_failovers_);
   o["view_timer_backoff"] = Json((int64_t)timer_backoff_);
   o["verify_batches"] = Json(batches_run_);
+  o["verify_launched_ahead"] = Json(launched_ahead_);
   o["broadcasts"] = Json(broadcasts_);
   o["broadcast_encodes"] =
       Json(broadcast_encodes_ +

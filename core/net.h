@@ -43,6 +43,7 @@
 #include <deque>
 #include <map>
 #include <memory>
+#include <optional>
 #include <random>
 #include <set>
 #include <string>
@@ -462,16 +463,27 @@ class ReplicaServer {
   // Log + close (no reject frame: the link is beyond a polite refusal).
   bool fail_conn(Conn& c, const std::string& reason);
   void flush(Conn& c);
+  // The verify step of a pass, at its end: launch the span of the inbox
+  // that no launch has taken, THEN work through the verdicts the pass
+  // kept (and launch what that delivery queued for the replica itself,
+  // if nothing went ahead of it).
   void run_verify_batch();
+  // Cut the unlaunched span and ship it (async), or verify it here
+  // (blocking branch: kept verdicts are applied before it, never after).
+  void launch_verify_span();
   // Drain verdict bytes from an async (RemoteVerifier) launch; on
-  // completion deliver + emit, on transport failure re-verify the
-  // in-flight batch via the CPU safety net.
+  // completion keep the verdicts for the pass's end, on transport failure
+  // keep the CPU safety net's for the span that was on the wire.
   void finish_verify_async();
-  // Shared verdict accounting for the sync and async paths: counter,
-  // trace (duration measured from t0), deliver + emit.
-  void deliver_verified(size_t n_items,
-                        std::chrono::steady_clock::time_point t0,
-                        std::vector<uint8_t> verdicts);
+  // The span on the wire has its verdicts: take it off the wire and keep
+  // them until run_verify_batch (anything still kept is applied first:
+  // spans are delivered in inbox order).
+  void keep_verdicts(std::vector<uint8_t> verdicts);
+  void apply_kept_verdicts();
+  // Shared verdict accounting for the sync and async paths: counters,
+  // trace (secs: launch -> verdicts in hand; ahead: a launch went out
+  // while these verdicts were kept), deliver + emit. One verdict an item.
+  void deliver_verified(double secs, bool ahead, std::vector<uint8_t> verdicts);
   void emit(Actions&& actions);
   void send_to(int64_t dest, const Message& m);
   // Shared by send_to and the broadcast fan-out: pick the link codec,
@@ -554,7 +566,7 @@ class ReplicaServer {
   // Group-commit point: write+fsync everything noted since the last
   // flush, then fold the wal counters into the metrics registry.
   void flush_wal();
-  void trace_batch(int64_t size, int64_t rejected, double secs);
+  void trace_batch(int64_t size, int64_t rejected, double secs, bool ahead);
   void trace_view_change(int backoff);
   // Request-level waterfall events (ISSUE 9; schemas in
   // pbft_tpu/utils/trace_schema.py): request arrival, the primary's batch
@@ -753,10 +765,19 @@ class ReplicaServer {
   bool verify_inflight_ = false;
   std::vector<VerifyItem> inflight_items_;
   std::chrono::steady_clock::time_point inflight_start_{};
-  // pbft_verify_inbox_wait_seconds: inbox entries the launch in flight
-  // took (they stay queued until its verdicts come), and since when an
-  // entry beyond them — one no launch has taken — has been waiting.
-  size_t inbox_launched_ = 0;
+  // One span of verdicts in hand and not yet applied: read by
+  // finish_verify_async (or made by the safety net) in this pass, applied
+  // at its end behind the launch of the next span. read_at less
+  // dispatched_at is pbft_verify_seconds' reading.
+  struct KeptVerdicts {
+    std::chrono::steady_clock::time_point dispatched_at, read_at;
+    bool launched_ahead;
+    std::vector<uint8_t> verdicts;
+  };
+  std::optional<KeptVerdicts> kept_;
+  int64_t launched_ahead_ = 0;  // launches made while a span was kept
+  // pbft_verify_inbox_wait_seconds: since when an entry no launch has
+  // taken (Replica::unlaunched_count) has been waiting.
   bool inbox_waiting_ = false;
   std::chrono::steady_clock::time_point inbox_since_{};
   int verify_deadline_ms_ = 15000;

@@ -253,10 +253,12 @@ const std::string* sig_of(const Message& m) {
 }
 }  // namespace
 
-std::vector<VerifyItem> Replica::pending_items() const {
+std::vector<VerifyItem> Replica::pending_items() {
   std::vector<VerifyItem> items;
-  items.reserve(inbox_.size());
-  for (const InboxEntry& e : inbox_) {
+  items.reserve(inbox_.size() - inbox_taken_);
+  for (auto it = inbox_.begin() + (std::ptrdiff_t)inbox_taken_;
+       it != inbox_.end(); ++it) {
+    const InboxEntry& e = *it;
     if (e.pre_authenticated) continue;  // passes without a verdict
     const Message& msg = e.msg;
     VerifyItem item{};
@@ -277,7 +279,13 @@ std::vector<VerifyItem> Replica::pending_items() const {
     }
     items.push_back(item);
   }
+  inbox_taken_ = inbox_.size();
   return items;
+}
+
+void Replica::pop_inbox_front() {
+  inbox_.pop_front();
+  if (inbox_taken_ > 0) --inbox_taken_;
 }
 
 Actions Replica::deliver_verdicts(const std::vector<uint8_t>& verdicts) {
@@ -285,28 +293,26 @@ Actions Replica::deliver_verdicts(const std::vector<uint8_t>& verdicts) {
   // for free — they queued behind the signed types purely for ordering
   // and were counted at receive; verification-needing entries consume
   // one verdict each, and trailing pre-authenticated entries drain
-  // greedily once the verdicts run out.
+  // greedily once the verdicts run out: up to the first entry that still
+  // awaits a verdict, whether a later span holds it (on the wire) or none
+  // does. What dispatch queues for this replica itself goes to the back.
   Actions out;
   size_t vi = 0;
   while (!inbox_.empty()) {
     InboxEntry& front = inbox_.front();
-    bool ok;
-    if (front.pre_authenticated) {
-      ok = true;
-    } else {
+    if (!front.pre_authenticated) {
       if (vi >= verdicts.size()) break;
-      ok = verdicts[vi] != 0;
+      const bool ok = verdicts[vi] != 0;
       ++vi;
+      counters[ok ? "sig_verified" : "sig_rejected"] += 1;
       if (!ok) {
-        counters["sig_rejected"] += 1;
-        inbox_.pop_front();
+        pop_inbox_front();
         continue;
       }
-      counters["sig_verified"] += 1;
     }
     Message msg = std::move(front.msg);
-    inbox_.pop_front();
-    if (ok) out.merge(dispatch(msg));
+    pop_inbox_front();
+    out.merge(dispatch(msg));
   }
   return out;
 }

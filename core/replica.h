@@ -7,7 +7,12 @@
 // commit log (src/state.rs:23), watermarks + checkpoints
 // (src/behavior.rs:154,:192), in-order execution with per-client
 // exactly-once timestamps (src/behavior.rs:391-398), and batched signature
-// gating via pending_items()/deliver_verdicts().
+// gating via pending_items()/deliver_verdicts(): pending_items() cuts a
+// SPAN off the verify inbox (every entry no earlier call took), and
+// deliver_verdicts() applies one span's verdicts, front first. Several
+// spans may be cut before the first is delivered — the runtime ships the
+// span behind a batch before it works through that batch's verdicts —
+// and they are delivered in the order they were cut.
 #pragma once
 
 #include <cstdint>
@@ -162,10 +167,17 @@ class Replica {
   // queue, no signature check — the caller proved the sender and
   // checked the claimed replica id against the link's peer.
   Actions receive_authenticated(const Message& msg);
-  std::vector<VerifyItem> pending_items() const;
-  // Queue depth without building the items — the event loop's bounded
-  // accumulation (verify_flush_us) checks this every pass.
+  // Cut the next span: one item per entry that awaits a verdict, from the
+  // first entry no earlier call took to the inbox's end.
+  std::vector<VerifyItem> pending_items();
+  // Queue depths without building the items: the whole inbox, and the
+  // entries no span has taken yet — the event loop's launch decision and
+  // its bounded accumulation (verify_flush_us) check the latter every pass.
   size_t pending_count() const { return inbox_.size(); }
+  size_t unlaunched_count() const { return inbox_.size() - inbox_taken_; }
+  // Apply the verdicts of the OLDEST undelivered span, in arrival order.
+  // Stops at the first entry that still awaits a verdict, be it in a
+  // later span (on the wire) or in none.
   Actions deliver_verdicts(const std::vector<uint8_t>& verdicts);
 
   // View change (PBFT §4.4): called by the runtime when its request timer
@@ -271,6 +283,7 @@ class Replica {
 
   Actions seal_batch();
   Actions dispatch(const Message& msg);
+  void pop_inbox_front();  // keeps inbox_taken_ in step
   Actions on_pre_prepare(const PrePrepare& pp);
   Actions accept_pre_prepare(const PrePrepare& pp);
   Actions on_prepare(const Prepare& p);
@@ -388,6 +401,10 @@ class Replica {
     uint8_t signable[32];
   };
   std::deque<InboxEntry> inbox_;
+  // Entries at the inbox's front that pending_items() has cut into spans:
+  // their verdicts are in the runtime's hands or on the wire. Behind them,
+  // entries no launch has taken.
+  size_t inbox_taken_ = 0;
   // Checkpoint payloads we can serve to lagging peers, and the
   // (seq, digest) we are ourselves waiting to fetch after a watermark jump.
   std::map<int64_t, std::string> snapshots_;

@@ -39,6 +39,8 @@ EVENT_SCHEMAS = {
     # at the first dispatch), devices (the chips the window's executables
     # are sharded over, as their input sharding said at warm-up) and
     # rows_per_chip (the slots of the window's smallest chunk over devices).
+    # pbftd's line is one BATCH of one replica; its 0/1 field ahead says
+    # the next batch was launched before this one's verdicts were applied.
     "verify_batch": {
         "required": {"ts", "ev", "replica", "size", "rejected", "secs"},
         "optional": {
@@ -46,7 +48,7 @@ EVENT_SCHEMAS = {
             "queue_s", "slot_s", "pending_at_cut", "pending_at_launch",
             "hold_s", "held_out", "in_step",
             "pad_s", "put_s", "dispatch_s", "wait_s", "unpack_s", "rung", "promoted",
-            "chunks", "split", "t_dev", "devices", "rows_per_chip",
+            "chunks", "split", "t_dev", "devices", "rows_per_chip", "ahead",
         },
         "emitters": {"server.py", "service.py", "net.cc"},
     },
@@ -337,6 +339,17 @@ METRIC_SCHEMAS = {
     # group-commit flush (write + fsync) that had records pending.
     "pbft_verify_inbox_wait_seconds": ("histogram", {"net.cc"}),
     "pbft_wal_flush_seconds": ("histogram", {"net.cc"}),
+    # The order of a pass on the async branch (pbftd only; ISSUE 37): the
+    # verifier's event reads a batch's verdicts and keeps them, the pass's
+    # end launches the span of the inbox behind that batch and only then
+    # works through the kept verdicts. Launched ahead: launches made while
+    # a span of verdicts was kept (over pbft_verify_batches_total: how
+    # often a trip runs behind the replica's own pass; /status:
+    # verify_launched_ahead). Verdict held: once a batch on the async
+    # branch, whether or not a launch went ahead, from the verdicts read
+    # to their delivery beginning (one clock read a batch, none a message).
+    "pbft_verify_launched_ahead_total": ("counter", {"net.cc"}),
+    "pbft_verdict_held_seconds": ("histogram", {"net.cc"}),
     # What the fast path adds to a reply's path, and what it takes off it
     # (ISSUE 32); all four in both modes' series sets. Request wait: on the
     # primary, once a batch at its seal (the "request" phase stamp), seal

@@ -364,6 +364,11 @@ def main(argv=None) -> dict:
         batches=batches,
     )
     result["view_events"] = len(view_events)
+    # pbftd's verify_batch lines carry a 0/1 field: the replica launched its
+    # next batch before it worked through this one's verdicts.
+    ahead = [e["ahead"] for e in collect_events(files, ("verify_batch",)) if "ahead" in e]
+    if ahead:
+        result["verify_batches"] = {"n": len(ahead), "launched_ahead": sum(ahead)}
     if args.waterfall:
         from pbft_tpu.utils import waterfall as wf_mod
 
@@ -385,6 +390,12 @@ def main(argv=None) -> dict:
         f"{n} (view, seq) slots from {len(files)} trace files, "
         f"replicas={result['replicas']}"
     )
+    if "verify_batches" in result:
+        vb = result["verify_batches"]
+        print(
+            f"verify batches: {vb['n']}, launched ahead of the verdicts kept: "
+            f"{vb['launched_ahead']} ({vb['launched_ahead'] / vb['n']:.0%})"
+        )
     if result.get("mean_batch"):
         print(
             f"mean batch per sealed window: {result['mean_batch']} "

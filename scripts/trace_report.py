@@ -124,6 +124,15 @@ def _commit_lag_summary(events) -> str:
     )
 
 
+def _ahead_summary(batches) -> str:
+    """pbftd's batch lines say (0/1 field ``ahead``) whether the replica
+    launched its next batch before it worked through this one's verdicts."""
+    said = [e["ahead"] for e in batches if "ahead" in e]
+    if not said:
+        return ""
+    return f", launched ahead {sum(said)}/{len(said)} ({sum(said) / len(said):.0%})"
+
+
 def report(files) -> dict:
     total = {
         "batches": 0,
@@ -191,7 +200,7 @@ def report(files) -> dict:
                 f"max={sizes[-1]}), verify p50={_pct(secs, 0.5) * 1e3:.2f}ms "
                 f"p90={_pct(secs, 0.9) * 1e3:.2f}ms, "
                 f"{sum(sizes) / span:.0f} items/s, rejected={rejected}, "
-                f"view_changes={len(vcs)}"
+                f"view_changes={len(vcs)}" + _ahead_summary(vb)
             )
         else:
             print(f"{path.name}: no verify_batch events")

@@ -60,6 +60,9 @@ def test_a_traced_rehearsal_reports_every_per_layer_metric_of_the_cell():
     from_trace = {m["name"] for m in bench["per_layer"] if m["source"] == "device_trace"}
     assert from_trace & listed == {"kernel_ms_per_launch.closed", "device_idle_pct.closed"}
     assert set(line["metrics"]) == listed - from_trace and len(listed) == 19
+    # No verify trip on a reply's path, so no launch to make ahead: the cell
+    # is not listed under ISSUE 37's readers and its line carries none of them.
+    assert not [k for k in line["metrics"] if k.startswith(("launched_ahead", "verdict_held"))]
     value = {k: v["value"] for k, v in line["metrics"].items()}
     assert all(isinstance(v, (int, float)) for v in value.values())
     # The mode, as numbers: no item sent for verification, every execution at

@@ -97,7 +97,7 @@ def test_a_traced_rehearsal_reports_every_per_layer_metric_of_the_cell():
     assert from_trace & listed == {
         "kernel_ms_per_launch.closed", "verify_kernel_roofline.closed", "device_idle_pct.closed",
     }
-    assert set(line["metrics"]) == listed - from_trace and len(listed) == 33
+    assert set(line["metrics"]) == listed - from_trace and len(listed) == 35
     value = {k: v["value"] for k, v in line["metrics"].items()}
     assert all(isinstance(v, (int, float)) for v in value.values())
     # The reading that says the cell ran over four chips, and the one that
@@ -113,3 +113,7 @@ def test_a_traced_rehearsal_reports_every_per_layer_metric_of_the_cell():
     assert value["pad_fill.closed"] == pytest.approx(
         value["items_per_launch.closed"] / value["rung_slots_mean.closed"], rel=1e-12)
     assert value["engine_idle_pct.closed"] >= 0 and value["fsyncs_per_req.closed"] > 0
+    # Sixteen replicas under 256 outstanding requests launch ahead of the
+    # verdicts they keep, and keeping them costs a batch milliseconds at most.
+    assert 0 < value["launched_ahead_share.closed"] <= 1
+    assert 0 <= value["verdict_held_ms_mean.closed"] < 1000

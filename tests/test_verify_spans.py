@@ -802,6 +802,20 @@ def test_verify_status_prints_the_stall_fields(capsys):
 # -- the replica's two histograms, async branch --------------------------------
 
 
+def _chipbench_stats():
+    sys.path.insert(0, str(CHIPBENCH))
+    try:
+        import stats
+    finally:
+        sys.path.pop(0)
+    return stats
+
+
+def _fetch(port: int, path: str) -> str:
+    with urllib.request.urlopen(f"http://127.0.0.1:{port}{path}", timeout=10) as r:
+        return r.read().decode()
+
+
 def test_replica_histograms_on_the_async_branch():
     """pbftd behind a verify service (RemoteVerifier, the async branch of
     run_verify_batch): one inbox-wait observation per verify batch, and a
@@ -812,11 +826,7 @@ def test_replica_histograms_on_the_async_branch():
         pytest.skip("native core not built")
     from pbft_tpu.net import LocalCluster, PbftClient
 
-    sys.path.insert(0, str(CHIPBENCH))
-    try:
-        import stats
-    finally:
-        sys.path.pop(0)
+    stats = _chipbench_stats()
     daemon = VerifyServiceDaemon(backend="native").start()
     try:
         with LocalCluster(
@@ -830,10 +840,7 @@ def test_replica_histograms_on_the_async_branch():
             finally:
                 client.close()
             time.sleep(0.5)  # trailing commits and checkpoints
-            scrapes = []
-            for port in cluster.metrics_ports:
-                with urllib.request.urlopen(f"http://127.0.0.1:{port}/metrics", timeout=10) as r:
-                    scrapes.append(stats.parse_prometheus(r.read().decode()))
+            scrapes = [stats.parse_prometheus(_fetch(port, "/metrics")) for port in cluster.metrics_ports]
     finally:
         daemon.stop()
     assert daemon.fallback_items > 0  # the service (native backend) verified them
@@ -846,6 +853,109 @@ def test_replica_histograms_on_the_async_branch():
         assert m[("pbft_wal_flush_seconds_count", "")] >= 3
         assert m[("pbft_wal_flush_seconds_sum", "")] > 0
         assert m[("pbft_verify_service_fallbacks_total", "")] == 0
+
+
+def test_a_served_cluster_launches_ahead_of_kept_verdicts(tmp_path, capsys):
+    """The order of a pass on the async branch (ISSUE 37): under concurrent
+    requests a replica ships the span of its inbox behind a batch BEFORE it
+    works through that batch's verdicts. Every replica counts launches made
+    that way, clocks what keeping the verdicts costs once a batch, and the
+    cluster still has one history with nothing verified on the host."""
+    from pbft_tpu import native
+
+    if not native.available():  # pragma: no cover - unbuilt container
+        pytest.skip("native core not built")
+    from pbft_tpu.net import LocalCluster, PbftClient
+
+    stats = _chipbench_stats()
+    clients, each = 4, 12
+    errors: list = []
+    daemon = VerifyServiceDaemon(backend="native").start()
+    try:
+        with LocalCluster(
+            n=4, verifier=daemon.address, impl="cxx", metrics_ports=True, wal=True,
+            trace_dir=str(tmp_path),
+        ) as cluster:
+
+            def serve(k: int) -> None:
+                client = PbftClient(cluster.config)
+                try:
+                    sent = [client.request(f"ahead-{k}-{i}") for i in range(each)]
+                    for req in sent:
+                        assert client.wait_result(req.timestamp, timeout=60) == "awesome!"
+                except Exception as e:  # noqa: BLE001 - shown by the main thread
+                    errors.append(e)
+                finally:
+                    client.close()
+
+            threads = [threading.Thread(target=serve, args=(k,)) for k in range(clients)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(120)
+            assert not errors, errors
+            deadline = time.monotonic() + 30
+            while True:  # trailing commits and checkpoints land
+                final = [json.loads(_fetch(port, "/status")) for port in cluster.metrics_ports]
+                if len({d["chain_digest"] for d in final}) == 1 and all(
+                    d["inbox_depth"] == 0 for d in final
+                ):
+                    break
+                assert time.monotonic() < deadline, [d["executed"] for d in final]
+                time.sleep(0.2)
+            scrapes = [stats.parse_prometheus(_fetch(port, "/metrics")) for port in cluster.metrics_ports]
+    finally:
+        daemon.stop()
+    assert daemon.fallback_items > 0  # the service (native backend) verified them
+    assert {d["executed"] for d in final} == {clients * each} and {d["view"] for d in final} == {0}
+    assert sum(d["verify_service_fallbacks"] + d["verify_deadline_fired"] for d in final) == 0
+    for m, d in zip(scrapes, final):
+        batches = m[("pbft_verify_batches_total", "")]
+        ahead = m[("pbft_verify_launched_ahead_total", "")]
+        assert 0 < ahead <= batches and d["verify_launched_ahead"] == ahead
+        # Once a batch on the async branch, whether or not a launch went ahead.
+        assert m[("pbft_verdict_held_seconds_count", "")] == batches
+        assert 0 <= m[("pbft_verdict_held_seconds_sum", "")] < 30
+        assert m[("pbft_verify_seconds_count", "")] == batches
+        assert batches <= m[("pbft_verify_inbox_wait_seconds_count", "")] <= batches + 1
+    # Every batch line of the trace says whether a launch went ahead of it
+    # (the last batches may be delivered after the scrape), and both scripts
+    # print the share where they print the verify batches.
+    said = 0
+    for i, m in enumerate(scrapes):
+        lines = _lines(tmp_path / f"replica-{i}.jsonl")
+        assert all(e["ahead"] in (0, 1) for e in lines)
+        assert sum(e["ahead"] for e in lines) >= m[("pbft_verify_launched_ahead_total", "")]
+        said += sum(e["ahead"] for e in lines)
+    assert "ahead" in trace_schema.EVENT_SCHEMAS["verify_batch"]["optional"]
+    sys.path.insert(0, str(ROOT / "scripts"))
+    try:
+        import consensus_timeline
+        import trace_report
+    finally:
+        sys.path.pop(0)
+    capsys.readouterr()
+    trace_report.report([tmp_path / "replica-0.jsonl"])
+    first = _lines(tmp_path / "replica-0.jsonl")
+    assert f", launched ahead {sum(e['ahead'] for e in first)}/{len(first)} (" in capsys.readouterr().out
+    consensus_timeline.main([str(tmp_path), "--limit", "1"])
+    assert f"launched ahead of the verdicts kept: {said} (" in capsys.readouterr().out
+    # The benchmark's two readers on those scrapes (an empty scrape before):
+    # a share of the batches, and milliseconds; on a program from before the
+    # counter and the histogram, as the parent commit is, nothing and no error.
+    status = [{"view": 0}] * 4
+    run = {"edge_a": {"metrics": [{}] * 4, "status": status},
+           "edge_b": {"metrics": scrapes, "status": status}}
+    share = _read("launched_ahead_share.closed", run)
+    assert 0 < share <= 1 and _read("launched_ahead_share.rate", run) == share
+    assert 0 <= _read("verdict_held_ms_mean.closed", run) < 30e3
+    old = [{k: v for k, v in m.items() if "launched_ahead" not in k[0] and "verdict_held" not in k[0]}
+           for m in scrapes]
+    before = {"edge_a": {"metrics": [{}] * 4, "status": status},
+              "edge_b": {"metrics": old, "status": status}}
+    for name in ("launched_ahead_share.closed", "launched_ahead_share.rate",
+                 "verdict_held_ms_mean.closed", "verdict_held_ms_mean.rate"):
+        assert _read(name, before) is None
 
 
 # -- (c) the two reducers, on hand-made runs -----------------------------------
@@ -920,6 +1030,10 @@ NEW_METRICS = {
     # PR 27: how often, and to what, the engine's serving table promotes.
     "promoted_share": ("ratio", "verifyd engine", "higher", "program_counter"),
     "rung_slots_mean": ("slots", "verifyd engine", "lower", "program_counter"),
+    # PR 37: how often a replica launches its next batch ahead of the verdicts
+    # it keeps, and what keeping them costs a batch.
+    "launched_ahead_share": ("ratio", "verify inbox to RemoteVerifier", "higher", "program_counter"),
+    "verdict_held_ms_mean": ("ms", "verify inbox to RemoteVerifier"),
 }
 # PR 28: what the hold did to each window, in the closed cells only.
 CLOSED_ONLY_METRICS = {
@@ -999,7 +1113,9 @@ def test_the_four_chip_cell_is_listed_wherever_its_twin_is_and_brings_two_reader
             "name": name, "unit": unit, "better": "higher", "source": "program_counter",
             "layer": "verifyd engine", "moves": "commit_rate", "workloads": [X4_TWIN, X4_CELL],
         }]
-    assert [m["name"] for m in bench["per_layer"]][-2:] == ["mesh_chips.closed", "rows_per_chip_mean.closed"]
+    names = [m["name"] for m in bench["per_layer"]]
+    at = names.index("mesh_chips.closed")  # appended by PR 36, as a pair
+    assert at >= ACCEPTED_PER_LAYER and names[at + 1] == "rows_per_chip_mean.closed"
     one = [{"devices": 1, "rows_per_chip": r} for r in (256, 1024, 256)]
     four = [{"devices": 4, "rows_per_chip": r} for r in (256, 256, 1024, 256)]
     assert _read("mesh_chips.closed", {"launches": one}) == 1.0
