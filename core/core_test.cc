@@ -1183,6 +1183,11 @@ struct SpanLoop {
   double sample(const std::string& name) {
     return prometheus_sample("\n" + srv->metrics().render_prometheus("1"), name);
   }
+  // Through the server's own rendering, which first folds the counters
+  // the loop keeps as plain integers (the loop clock's among them).
+  double scraped(const std::string& name) {
+    return prometheus_sample("\n" + srv->metrics_prometheus(), name);
+  }
 };
 
 void test_loop_launches_ahead_of_kept_verdicts() {
@@ -1209,6 +1214,7 @@ void test_loop_launches_ahead_of_kept_verdicts() {
   CHECK(t.sample("pbft_verify_launched_ahead_total") == 1);
   CHECK(t.sample("pbft_verify_batches_total") == 1);
   CHECK(t.sample("pbft_verdict_held_seconds_count") == 1);
+  CHECK(t.sample("pbft_verdict_apply_seconds_count") == 1);
   // Span 2 comes back with nothing behind it: no launch, its forgery is
   // dropped, the clock on held verdicts still runs once a batch.
   t.sv->answer();
@@ -1220,6 +1226,10 @@ void test_loop_launches_ahead_of_kept_verdicts() {
   CHECK(t.sample("pbft_verify_launched_ahead_total") == 1);
   CHECK(t.sample("pbft_verify_batches_total") == 2);
   CHECK(t.sample("pbft_verdict_held_seconds_count") == 2);
+  // The span that closes the cycle: once a kept batch, and no shorter
+  // than nothing (delivery began -> deliver_verified returned).
+  CHECK(t.sample("pbft_verdict_apply_seconds_count") == 2);
+  CHECK(t.sample("pbft_verdict_apply_seconds_sum") > 0);
   CHECK(t.sample("pbft_verify_seconds_count") == 2);
   CHECK(t.sample("pbft_verify_service_fallbacks_total") == 0);
 }
@@ -1284,7 +1294,151 @@ void test_loop_transport_failure_keeps_order() {
   CHECK(t.sample("pbft_verify_launched_ahead_total") == 0);
   CHECK(t.sample("pbft_verify_batches_total") == 2);
   CHECK(t.sample("pbft_verdict_held_seconds_count") == 1);  // async branch only
+  CHECK(t.sample("pbft_verdict_apply_seconds_count") == 1);  // kept spans only
   CHECK(t.sample("pbft_verify_inbox_wait_seconds_count") == 0);  // no emit() stamped one
+}
+
+// --- ISSUE 38: the loop's stage clock ---------------------------------------
+void spin_for(std::chrono::microseconds d) {
+  const auto until = std::chrono::steady_clock::now() + d;
+  while (std::chrono::steady_clock::now() < until) {
+  }
+}
+
+void test_loop_clock_unit() {
+  using pbft::LoopClock;
+  // Off: a Scope is one branch; no stage, total or switch count moves.
+  LoopClock off;
+  {
+    LoopClock::Scope a(off, pbft::kLoopRead);
+    LoopClock::Scope b(off, pbft::kLoopProtocol);
+    spin_for(std::chrono::microseconds(200));
+  }
+  CHECK(off.total_ns() == 0 && off.switches == 0);
+  CHECK(off.stage == pbft::kLoopOther);
+
+  // On: nested scopes charge EXCLUSIVE time, and the seven sum to the
+  // elapsed time between the clock coming on and its last sync.
+  LoopClock c;
+  const auto t0 = std::chrono::steady_clock::now();
+  c.set_on(true);
+  spin_for(std::chrono::microseconds(300));  // other
+  {
+    LoopClock::Scope read(c, pbft::kLoopRead);
+    spin_for(std::chrono::microseconds(500));
+    {
+      LoopClock::Scope proto(c, pbft::kLoopProtocol);
+      spin_for(std::chrono::microseconds(2000));
+      {
+        LoopClock::Scope wal(c, pbft::kLoopWal);
+        spin_for(std::chrono::microseconds(700));
+      }
+      {
+        LoopClock::Scope send(c, pbft::kLoopSend);
+        spin_for(std::chrono::microseconds(400));
+        LoopClock::Scope same(c, pbft::kLoopSend);  // no switch, no read
+        spin_for(std::chrono::microseconds(100));
+      }
+    }
+    spin_for(std::chrono::microseconds(500));
+  }
+  c.sync();
+  const auto t1 = std::chrono::steady_clock::now();
+  const int64_t elapsed =
+      std::chrono::duration_cast<std::chrono::nanoseconds>(t1 - t0).count();
+  CHECK(c.stage == pbft::kLoopOther);
+  CHECK(c.switches == 8);  // four scopes that switched, in and out
+  CHECK(c.ns[pbft::kLoopWait] == 0 && c.ns[pbft::kLoopVerify] == 0);
+  CHECK(c.ns[pbft::kLoopOther] >= 300 * 1000);
+  CHECK(c.ns[pbft::kLoopRead] >= 1000 * 1000);
+  CHECK(c.ns[pbft::kLoopRead] < 1400 * 1000);  // the 3.2 ms nested are not its
+  CHECK(c.ns[pbft::kLoopProtocol] >= 2000 * 1000);
+  CHECK(c.ns[pbft::kLoopProtocol] < 2400 * 1000);
+  CHECK(c.ns[pbft::kLoopWal] >= 700 * 1000);
+  CHECK(c.ns[pbft::kLoopSend] >= 500 * 1000);
+  CHECK(c.total_ns() <= elapsed);
+  CHECK(c.total_ns() > elapsed - 200 * 1000);  // set_on .. sync, to 0.2 ms
+
+  // What a switch costs (PERF.md carries the figure): one clock read and
+  // three integer operations; in and out of a scope is two.
+  LoopClock bench;
+  bench.set_on(true);
+  const int kPairs = 200000;
+  const auto b0 = std::chrono::steady_clock::now();
+  for (int i = 0; i < kPairs; ++i) {
+    LoopClock::Scope in(bench, pbft::kLoopSend);
+  }
+  const double ns_a_switch =
+      std::chrono::duration<double, std::nano>(
+          std::chrono::steady_clock::now() - b0)
+          .count() /
+      (2.0 * kPairs);
+  CHECK(bench.switches == 2 * kPairs);
+  std::printf("loop clock: %.1f ns a stage switch\n", ns_a_switch);
+  CHECK(ns_a_switch < 2000);  // a vDSO clock read, not a system call gone wrong
+
+  // What one signature costs the host (pbft_signs_total counts them).
+  uint8_t seed[32] = {7}, digest[32] = {9}, sig[64];
+  const int kSigns = 200;
+  const auto s0 = std::chrono::steady_clock::now();
+  for (int i = 0; i < kSigns; ++i) {
+    digest[0] = (uint8_t)i;
+    pbft::ed25519_sign(sig, seed, digest, 32);
+  }
+  std::printf("ed25519_sign: %.1f us a signature\n",
+              std::chrono::duration<double, std::micro>(
+                  std::chrono::steady_clock::now() - s0)
+                      .count() /
+                  kSigns);
+}
+
+void test_loop_clock_on_the_loop() {
+  // Metrics and trace both off: passes run, nothing is timed.
+  {
+    SpanLoop t;
+    t.srv->metrics().enabled = false;
+    t.queue(1, 2);
+    t.srv->poll_once(0);
+    t.sv->answer();
+    t.srv->poll_once(50);
+    CHECK(t.srv->replica().counters["prepares_accepted"] == 1);
+    CHECK(t.srv->loop_clock().total_ns() == 0);
+    CHECK(t.srv->loop_clock().switches == 0);
+  }
+  // On: the scrape folds the stages, they sum to the total to the
+  // microsecond and to the wall time the passes took, `wait` holds the
+  // poller's timeout and `verify` / `protocol` the work of the batches.
+  SpanLoop t;
+  const auto t0 = std::chrono::steady_clock::now();
+  t.queue(1, 2);
+  t.queue(1, 3);
+  t.srv->poll_once(0);
+  t.sv->answer();
+  t.srv->poll_once(50);
+  t.srv->poll_once(30);  // nothing to do: 30 ms inside wait
+  const double total = t.scraped("pbft_loop_us_total");
+  const double wall_us = std::chrono::duration<double, std::micro>(
+                             std::chrono::steady_clock::now() - t0)
+                             .count();
+  CHECK(total > 30000 && total <= wall_us);
+  CHECK(t.scraped("pbft_loop_wait_us_total") >= 29000);
+  CHECK(t.scraped("pbft_loop_verify_us_total") > 0);
+  CHECK(t.scraped("pbft_loop_protocol_us_total") > 0);
+  CHECK(t.scraped("pbft_epoll_wakeups_total") == 3);
+  CHECK(t.scraped("pbft_verdict_apply_seconds_count") == 1);
+  // One rendering is one instant: there the eight agree to the microsecond.
+  const std::string text = "\n" + t.srv->metrics_prometheus();
+  double one_sum = 0;
+  for (const char* stage : pbft::kLoopStageNames) {
+    one_sum += prometheus_sample(
+        text, std::string("pbft_loop_") + stage + "_us_total");
+  }
+  CHECK(one_sum == prometheus_sample(text, "pbft_loop_us_total"));
+  // /status carries the same seven, the passes and the applied batches.
+  const std::string status = t.srv->metrics_json();
+  CHECK(status.find("\"loop_us\":{") != std::string::npos);
+  CHECK(status.find("\"passes\":3") != std::string::npos);
+  CHECK(status.find("\"verify_apply\":{\"batches\":1") != std::string::npos);
 }
 
 // ISSUE 14: MAC-vector codec units + the authenticator/tentative mode
@@ -1560,6 +1714,8 @@ int main() {
   test_loop_launches_ahead_of_kept_verdicts();
   test_loop_wedge_deadline_keeps_order();
   test_loop_transport_failure_keeps_order();
+  test_loop_clock_unit();
+  test_loop_clock_on_the_loop();
   test_mac_codec_native();
   test_fastpath_mac_parity();
   test_flight_recorder();

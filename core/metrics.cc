@@ -24,10 +24,9 @@ const char* kCounterNames[] = {
     // Batches verified on the host although a verify service is
     // configured (service warming / unreachable / dead / past deadline).
     "pbft_verify_service_fallbacks_total",
-    // Wire-codec surface: outbound frames per payload codec, plus the
-    // serialize-once invariant counter (encodes per broadcast, never per
-    // peer — tests compare it against the broadcast count).
-    "pbft_codec_binary_frames_total", "pbft_codec_json_frames_total",
+    // Wire-codec surface: the serialize-once invariant counter (encodes
+    // per broadcast, never per peer — tests compare it against the
+    // broadcast count).
     "pbft_broadcast_encodes_total",
     // Batching surface (ISSUE 4): requests executed vs three-phase
     // instances executed — their ratio is the batch amplification.
@@ -62,6 +61,17 @@ const char* kCounterNames[] = {
     // Verify batches launched while the verdicts of the batch before were
     // kept (ISSUE 37): that trip runs behind the replica's own pass.
     "pbft_verify_launched_ahead_total",
+    // The loop thread's wall time by kind of work, microseconds (ISSUE
+    // 38): the seven stages of net.h's LoopClock and their sum, folded
+    // from plain integers where a scrape or /status is rendered.
+    // pbft_epoll_wakeups_total is the count of passes they were spent in.
+    "pbft_loop_us_total", "pbft_loop_wait_us_total",
+    "pbft_loop_read_us_total", "pbft_loop_protocol_us_total",
+    "pbft_loop_wal_us_total", "pbft_loop_send_us_total",
+    "pbft_loop_verify_us_total", "pbft_loop_other_us_total",
+    // Signatures this replica made (Replica::sign): every reply carries
+    // one, so it is the largest countable item inside `protocol`.
+    "pbft_signs_total",
     // Durable-recovery surface (ISSUE 15): WAL records appended, group-
     // commit fsync syscalls, and file bytes written.
     "pbft_wal_appends_total", "pbft_wal_fsyncs_total",
@@ -122,6 +132,9 @@ const std::pair<const char*, bool> kHistogramNames[] = {
     // Verdicts read -> their delivery began (once a batch on the async
     // branch): what launching the next span first adds to a batch.
     {"pbft_verdict_held_seconds", false},
+    // Their delivery began -> its last send left (once a kept batch):
+    // dispatch, execute, sign, WAL flush, sends for one batch's verdicts.
+    {"pbft_verdict_apply_seconds", false},
     // The oldest request's wait at the primary until its batch is sealed
     // (once a batch), and how long a tentative execution stayed revocable
     // (once a sequence number, tentative mode).

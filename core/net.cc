@@ -401,6 +401,9 @@ void ReplicaServer::run() {
 }
 
 void ReplicaServer::poll_once(int timeout_ms) {
+  // A pass starts (and ends) in the loop clock's `other` stage; the work
+  // below switches it by kind, nested (net.h LoopClock).
+  loop_clock_.set_on(metrics_.enabled || trace_fp_ != nullptr);
   if (verify_window_open_) {
     // An open accumulation window caps how long we may sit in poll():
     // the flush deadline is a latency promise, not a hint.
@@ -454,10 +457,13 @@ void ReplicaServer::poll_once(int timeout_ms) {
   // registered at creation — the wait is one syscall over the backend's
   // standing table, no per-iteration pollfd rebuild.
   events_.clear();
-  int n = poller_->wait(&events_, timeout_ms);
+  int n;
+  {
+    LoopClock::Scope in(loop_clock_, kLoopWait);
+    n = poller_->wait(&events_, timeout_ms);
+  }
   if (n < 0) return;
-  ++event_wakeups_;
-  metrics_.inc("pbft_epoll_wakeups_total");
+  ++event_wakeups_;  // pbft_epoll_wakeups_total: folded at the scrape
   for (const PollerEvent& ev : events_) {
     if (ev.tag == kTagListener) {
       if (ev.readable) accept_ready();
@@ -581,6 +587,7 @@ inline uint64_t shard_link_key(int shard, uint64_t conn_id) {
 }  // namespace
 
 void ReplicaServer::process_shard_inbound() {
+  LoopClock::Scope in_read(loop_clock_, kLoopRead);
   std::deque<KInbound> in;
   shards_->drain_inbox(&in);
   for (auto& k : in) {
@@ -603,52 +610,41 @@ void ReplicaServer::process_shard_inbound() {
     }
     if (!k.msg) continue;
     ++frames_in_;
-    metrics_.inc("pbft_frames_in_total");
     if (auto* req = std::get_if<ClientRequest>(&*k.msg)) {
       if (k.from_gateway) {
         note_gateway_route(req->client, key);
         ++gateway_forwarded_;
-        metrics_.inc("pbft_gateway_forwarded_total");
       }
       if (!maybe_reject_overload(*req)) {
         trace_request_rx(*req);
-        emit(replica_->receive(*k.msg));
+        emit(in_protocol([&] { return replica_->receive(*k.msg); }));
       }
     } else if (k.pre_authenticated) {
       // The pipeline verified this frame's MAC lane (ISSUE 14): no
       // verify queue, straight dispatch.
-      emit(replica_->receive_authenticated(*k.msg));
+      emit(in_protocol(
+          [&] { return replica_->receive_authenticated(*k.msg); }));
     } else if (k.has_signable) {
-      emit(replica_->receive(*k.msg, k.signable));
+      emit(in_protocol(
+          [&] { return replica_->receive(*k.msg, k.signable); }));
     } else {
-      emit(replica_->receive(*k.msg));
+      emit(in_protocol([&] { return replica_->receive(*k.msg); }));
     }
   }
 }
 
 void ReplicaServer::aggregate_shard_metrics() {
   if (!shards_) return;
-  auto delta = [&](int64_t now_abs, int64_t* seen, const char* name) {
-    if (now_abs > *seen) {
-      metrics_.inc(name, now_abs - *seen);
-      *seen = now_abs;
-    }
-  };
-  delta(shards_->total_wakeups(), &seen_shard_wakeups_,
-        "pbft_epoll_wakeups_total");
-  delta(shards_->cross_thread_wakes(), &seen_cross_wakes_,
-        "pbft_cross_thread_wakes_total");
-  delta(shards_->codec_binary_frames(), &seen_codec_bin_,
-        "pbft_codec_binary_frames_total");
-  delta(shards_->codec_json_frames(), &seen_codec_json_,
-        "pbft_codec_json_frames_total");
-  delta(shards_->mac_frames(), &seen_shard_mac_, "pbft_mac_frames_total");
-  delta(shards_->backpressure_events(), &seen_shard_backpressure_,
-        "pbft_write_backpressure_events_total");
-  delta(shards_->chaos_dropped(), &seen_shard_chaos_,
-        "pbft_chaos_dropped_total");
-  delta(shards_->broadcast_encodes(), &seen_shard_encodes_,
-        "pbft_broadcast_encodes_total");
+  // The shards' wakeups and MAC frames go with this thread's own, in
+  // fold_counters.
+  fold_delta(shards_->cross_thread_wakes(), &seen_cross_wakes_,
+             "pbft_cross_thread_wakes_total");
+  fold_delta(shards_->backpressure_events(), &seen_shard_backpressure_,
+             "pbft_write_backpressure_events_total");
+  fold_delta(shards_->chaos_dropped(), &seen_shard_chaos_,
+             "pbft_chaos_dropped_total");
+  fold_delta(shards_->broadcast_encodes(), &seen_shard_encodes_,
+             "pbft_broadcast_encodes_total");
   metrics_.set_gauge("pbft_crypto_offload_queue_depth",
                      (double)shards_->crypto_queue_depth());
 }
@@ -687,6 +683,7 @@ void ReplicaServer::unregister_verifier_fd() {
 }
 
 void ReplicaServer::accept_ready() {
+  LoopClock::Scope in(loop_clock_, kLoopRead);
   for (;;) {
     int fd = accept(listen_fd_, nullptr, nullptr);
     if (fd < 0) return;
@@ -701,6 +698,7 @@ void ReplicaServer::accept_ready() {
 }
 
 void ReplicaServer::handle_readable(Conn& c) {
+  LoopClock::Scope in(loop_clock_, kLoopRead);
   // Drains to EAGAIN — REQUIRED under the edge-triggered backend: a
   // partial drain would leave buffered bytes with no further edge.
   char buf[65536];
@@ -770,11 +768,10 @@ void ReplicaServer::process_buffer(Conn& c) {
       auto msg = from_payload(payload);
       if (msg) {
         ++frames_in_;
-        metrics_.inc("pbft_frames_in_total");
         auto* req = std::get_if<ClientRequest>(&*msg);
         if (req == nullptr || !maybe_reject_overload(*req)) {
           if (req != nullptr) trace_request_rx(*req);
-          emit(replica_->receive(*msg));
+          emit(in_protocol([&] { return replica_->receive(*msg); }));
         }
       }
       if (c.rbuf.empty()) return;
@@ -1037,13 +1034,12 @@ bool ReplicaServer::handle_peer_frame(Conn& c, std::string payload) {
           return true;
         }
         ++frames_in_;
-        metrics_.inc("pbft_frames_in_total");
-        emit(replica_->receive_authenticated(*msg));
+        emit(in_protocol(
+            [&] { return replica_->receive_authenticated(*msg); }));
         return true;
       }
     }
     ++frames_in_;
-    metrics_.inc("pbft_frames_in_total");
     if (std::holds_alternative<ClientRequest>(*msg)) {
       const auto& req = std::get<ClientRequest>(*msg);
       if (c.gateway) {
@@ -1053,11 +1049,10 @@ bool ReplicaServer::handle_peer_frame(Conn& c, std::string payload) {
         // BEFORE admission so an overloaded line can route back too.
         note_gateway_route(req.client, c.link_id);
         ++gateway_forwarded_;
-        metrics_.inc("pbft_gateway_forwarded_total");
       }
       if (!maybe_reject_overload(req)) {
         trace_request_rx(req);
-        emit(replica_->receive(*msg));
+        emit(in_protocol([&] { return replica_->receive(*msg); }));
       }
     } else {
       // Receive-side canonical reuse: derive the signable digest from
@@ -1065,7 +1060,7 @@ bool ReplicaServer::handle_peer_frame(Conn& c, std::string payload) {
       // template for binary) so the verify queue never re-serializes.
       uint8_t signable[32];
       message_signable_from_payload(payload, *msg, signable);
-      emit(replica_->receive(*msg, signable));
+      emit(in_protocol([&] { return replica_->receive(*msg, signable); }));
     }
   }
   return true;
@@ -1124,6 +1119,7 @@ void ReplicaServer::finish_connect(Conn& c) {
 
 void ReplicaServer::flush(Conn& c) {
   if (c.connecting) return;  // nothing sendable until the connect lands
+  LoopClock::Scope in(loop_clock_, kLoopSend);  // from wherever it is called
   SendQueue& q = c.out;
   while (!q.blocks.empty()) {
     std::string& b = q.blocks.front();
@@ -1184,16 +1180,35 @@ double trace_now() {
 
 // Event schemas match the Python tracer's (pbft_tpu/net/server.py) so a
 // mixed-runtime cluster's traces merge without per-runtime special cases.
+// The line is written when the batch's verdicts have been worked through
+// (its ts is the end of the apply). loop_us: the loop clock's seven
+// running totals at that instant, in kLoopStageNames' order, so any two
+// lines of a replica bracket an interval with its split by kind of work
+// (scripts/trace_report.py sets them against verifyd's launch log: every
+// stamp is CLOCK_MONOTONIC).
 void ReplicaServer::trace_batch(int64_t size, int64_t rejected, double secs,
-                                bool ahead) {
+                                bool ahead, double apply_s) {
   if (!trace_fp_) return;
+  const auto at = loop_clock_.on ? loop_clock_.sync()
+                                 : std::chrono::steady_clock::now();
+  const double now =
+      std::chrono::duration<double>(at.time_since_epoch()).count();
+  char apply[48] = "";
+  if (apply_s >= 0) {
+    std::snprintf(apply, sizeof(apply), ",\"apply_s\":%.6f", apply_s);
+  }
   std::fprintf(trace_fp_,
                "{\"ts\":%.6f,\"ev\":\"verify_batch\",\"replica\":%lld,"
                "\"size\":%lld,\"rejected\":%lld,\"secs\":%.6f,\"view\":%lld,"
-               "\"executed\":%lld,\"ahead\":%d}\n",
-               trace_now(), (long long)id_, (long long)size,
-               (long long)rejected, secs, (long long)replica_->view(),
-               (long long)replica_->executed_upto(), ahead ? 1 : 0);
+               "\"executed\":%lld,\"ahead\":%d%s,\"loop_us\":[",
+               now, (long long)id_, (long long)size, (long long)rejected, secs,
+               (long long)replica_->view(),
+               (long long)replica_->executed_upto(), ahead ? 1 : 0, apply);
+  for (int i = 0; i < kLoopStages; ++i) {
+    std::fprintf(trace_fp_, i ? ",%lld" : "%lld",
+                 (long long)(loop_clock_.ns[i] / 1000));
+  }
+  std::fputs("]}\n", trace_fp_);
   std::fflush(trace_fp_);
 }
 
@@ -1408,7 +1423,8 @@ void ReplicaServer::on_commit_floor(int64_t seq) {
   std::fflush(trace_fp_);
 }
 
-std::string ReplicaServer::metrics_prometheus() const {
+std::string ReplicaServer::metrics_prometheus() {
+  refresh_health();
   return metrics_.render_prometheus(std::to_string(id_));
 }
 
@@ -1431,8 +1447,7 @@ void ReplicaServer::serve_metrics_ready() {
       sink[got] = '\0';
       want_status = std::strstr(sink, " /status") != nullptr;
     }
-    refresh_health();
-    std::string body;
+    std::string body;  // either rendering refreshes health + folds first
     const char* content_type;
     if (want_status) {
       body = metrics_json();
@@ -1458,6 +1473,7 @@ void ReplicaServer::serve_metrics_ready() {
 void ReplicaServer::check_verify_deadline(
     std::chrono::steady_clock::time_point now) {
   if (!verify_inflight_) return;
+  LoopClock::Scope in(loop_clock_, kLoopVerify);
   const double age =
       std::chrono::duration<double>(now - inflight_start_).count();
   metrics_.set_gauge("pbft_verify_inflight_age_seconds", age);
@@ -1504,7 +1520,7 @@ void ReplicaServer::check_batch_flush(
     return;  // keep accumulating: more client requests may arrive
   }
   batch_window_open_ = false;
-  emit(replica_->flush_open_batch());
+  emit(in_protocol([&] { return replica_->flush_open_batch(); }));
   // A seal refused by a closed watermark window leaves the batch open;
   // re-arm so the next tick retries instead of spinning the deadline
   // (the request wait runs on: batch_oldest_at_ is not touched).
@@ -1533,6 +1549,9 @@ void ReplicaServer::launch_verify_span() {
     inbox_waiting_ = false;
     return;
   }
+  // From here the pass works on the verify inbox (a pass that finds it
+  // empty, as every pass in MAC mode does, charges `verify` nothing).
+  LoopClock::Scope in(loop_clock_, kLoopVerify);
   if (cfg_.verify_flush_us > 0) {
     // Bounded accumulation: hold the queue until the item target or the
     // deadline so one verifier launch carries a whole window instead of
@@ -1598,16 +1617,22 @@ void ReplicaServer::launch_verify_span() {
   double secs =
       std::chrono::duration<double>(std::chrono::steady_clock::now() - t0)
           .count();
-  deliver_verified(secs, /*ahead=*/false, std::move(verdicts));
+  deliver_verified(secs, /*ahead=*/false, std::move(verdicts),
+                   /*began=*/nullptr);
 }
 
 int64_t ReplicaServer::verify_service_fallbacks() const {
   return safety_net_batches_ + verifier_->host_fallbacks();
 }
 
-void ReplicaServer::deliver_verified(double secs, bool ahead,
-                                     std::vector<uint8_t> verdicts) {
+void ReplicaServer::deliver_verified(
+    double secs, bool ahead, std::vector<uint8_t> verdicts,
+    const std::chrono::steady_clock::time_point* began) {
+  // The batch's own accounting is the verify inbox's; what the verdicts
+  // set off below switches to protocol, wal and send.
+  LoopClock::Scope in(loop_clock_, kLoopVerify);
   const size_t n_items = verdicts.size();
+  int64_t rejected = 0;
   ++batches_run_;
   // Every host-fallback path ends here, so the counter metric follows
   // the total without a hook in each of them.
@@ -1625,7 +1650,6 @@ void ReplicaServer::deliver_verified(double secs, bool ahead,
     }
   }
   if (metrics_.enabled || trace_fp_) {  // batch boundaries only
-    int64_t rejected = 0;
     for (uint8_t v : verdicts) rejected += v ? 0 : 1;
     metrics_.inc("pbft_verify_batches_total");
     metrics_.inc("pbft_verify_items_total", (int64_t)n_items);
@@ -1646,12 +1670,26 @@ void ReplicaServer::deliver_verified(double secs, bool ahead,
                          (double)ps.last_window_items);
       }
     }
-    if (trace_fp_) trace_batch((int64_t)n_items, rejected, secs, ahead);
   }
-  emit(replica_->deliver_verdicts(verdicts));
+  emit(in_protocol([&] { return replica_->deliver_verdicts(verdicts); }));
+  if (!metrics_.enabled && !trace_fp_) return;
+  double apply_s = -1.0;
+  if (began) {
+    // Dispatch, execute, sign, WAL flush, sends for ONE batch's verdicts:
+    // the piece of the verify cycle that is the replica's own (one more
+    // clock read a batch).
+    apply_s = std::chrono::duration<double>(
+                  std::chrono::steady_clock::now() - *began)
+                  .count();
+    ++verdict_applies_;
+    verdict_apply_s_ += apply_s;
+    metrics_.observe("pbft_verdict_apply_seconds", apply_s);
+  }
+  trace_batch((int64_t)n_items, rejected, secs, ahead, apply_s);
 }
 
 void ReplicaServer::finish_verify_async() {
+  LoopClock::Scope in(loop_clock_, kLoopVerify);
   std::vector<uint8_t> verdicts;
   bool failed = false;
   if (!verifier_->poll_result(&verdicts, &failed)) return;  // partial read
@@ -1679,17 +1717,20 @@ void ReplicaServer::apply_kept_verdicts() {
   if (!kept_) return;
   KeptVerdicts k = std::move(*kept_);
   kept_.reset();
-  if (metrics_.enabled) {
+  // One clock read ends pbft_verdict_held_seconds and begins
+  // pbft_verdict_apply_seconds.
+  const bool timed = metrics_.enabled || trace_fp_;
+  std::chrono::steady_clock::time_point began{};
+  if (timed) {
+    began = std::chrono::steady_clock::now();
     // What keeping costs a batch: verdicts read -> their delivery begins
     // (the rest of the pass's events, the batch flush, the launch ahead).
     metrics_.observe("pbft_verdict_held_seconds",
-                     std::chrono::duration<double>(
-                         std::chrono::steady_clock::now() - k.read_at)
-                         .count());
+                     std::chrono::duration<double>(began - k.read_at).count());
   }
   deliver_verified(
       std::chrono::duration<double>(k.read_at - k.dispatched_at).count(),
-      k.launched_ahead, std::move(k.verdicts));
+      k.launched_ahead, std::move(k.verdicts), timed ? &began : nullptr);
 }
 
 namespace {
@@ -1802,7 +1843,10 @@ bool ReplicaServer::enable_wal(const std::string& dir) {
 }
 
 void ReplicaServer::flush_wal() {
+  // A call that finds nothing pending costs this compare and no clock
+  // read; it stays with the stage that made it.
   if (!wal_ || wal_->pending() == 0) return;
+  LoopClock::Scope in(loop_clock_, kLoopWal);
   const auto t0 = std::chrono::steady_clock::now();
   wal_->flush();
   metrics_.observe(
@@ -1829,6 +1873,10 @@ void ReplicaServer::emit(Actions&& actions) {
   // pass (a verify batch's worth of votes), keeping fsync off the
   // per-message path.
   if (wal_) flush_wal();
+  // Everything after the flush is the sending side: encode, MAC tags,
+  // queue, send(), the reply's way back. (What a self-delivered message
+  // sets off switches to protocol again, nested.)
+  LoopClock::Scope in(loop_clock_, kLoopSend);
   // Verify-inbox wait: every receive() comes back through here, so the
   // first pass that finds an item no launch has taken stamps its arrival
   // — one clock read per wait, none per message.
@@ -1979,6 +2027,10 @@ void ReplicaServer::observe_execution_metrics() {
                  inline_v - seen_inline_verifies_);
     seen_inline_verifies_ = inline_v;
   }
+  if (const int64_t signs = replica_->signs(); signs > seen_signs_) {
+    metrics_.inc("pbft_signs_total", signs - seen_signs_);
+    seen_signs_ = signs;
+  }
   // Deltas of the replica's own counters: "executed" counts per REQUEST,
   // "rounds_executed" per sequence number — the two together are the
   // batching amplification factor (requests per three-phase instance).
@@ -1996,6 +2048,8 @@ void ReplicaServer::observe_execution_metrics() {
 
 void ReplicaServer::check_progress_timer() {
   if (vc_timeout_ms_ <= 0) return;
+  // The protocol's timers: what they find in Replica and what they set off.
+  LoopClock::Scope in(loop_clock_, kLoopProtocol);
   auto now = std::chrono::steady_clock::now();
   // Expire stale forwarded-request entries (a superseded request never
   // produces a reply here) after 10 timeouts.
@@ -2158,7 +2212,7 @@ void ReplicaServer::send_to(int64_t dest, const Message& m) {
   if (dest == id_) {
     // Self-delivery bypasses the wire AND the fault modes: a Byzantine
     // replica trusts its own messages; only its peers see the behavior.
-    emit(replica_->receive(m));
+    emit(in_protocol([&] { return replica_->receive(m); }));
     return;
   }
   if (fault_mode_ == FaultMode::kMute) {
@@ -2206,14 +2260,8 @@ void ReplicaServer::send_encoded(int64_t dest, EncodedOut& enc) {
     mac_frame = payload != nullptr;
   }
   if (payload == nullptr && c.codec_binary) payload = enc.binary_payload();
-  const bool bin = payload != nullptr;
-  if (!bin) payload = &enc.json_payload();
-  metrics_.inc(bin ? "pbft_codec_binary_frames_total"
-                   : "pbft_codec_json_frames_total");
-  if (mac_frame) {
-    ++mac_frames_;
-    metrics_.inc("pbft_mac_frames_total");
-  }
+  if (payload == nullptr) payload = &enc.json_payload();
+  if (mac_frame) ++mac_frames_;  // pbft_mac_frames_total: folded at the scrape
   if (c.chan && !c.chan->established()) {
     // Handshake in flight: queue (bounded — a wedged handshake must not
     // buffer without limit; PBFT tolerates the loss via retransmission).
@@ -2466,6 +2514,8 @@ void ReplicaServer::start_reply_dial(const std::string& addr,
 }
 
 void ReplicaServer::pump_reply_backlog() {
+  if (reply_backlog_.empty()) return;
+  LoopClock::Scope in(loop_clock_, kLoopSend);
   // Per-entry scan (no head-of-line blocking): TTL-expired entries drop,
   // entries whose address already has a dial in flight stay queued, the
   // rest launch while the budget lasts.
@@ -2531,7 +2581,42 @@ int64_t file_size_bytes(const std::string& path) {
 
 }  // namespace
 
+void ReplicaServer::fold_counters() {
+  // Up to this instant, for /status too (which reads the clock itself).
+  if (loop_clock_.on) loop_clock_.sync();
+  if (!metrics_.enabled) return;
+  fold_delta(event_wakeups_ + (shards_ ? shards_->total_wakeups() : 0),
+             &seen_wakeups_, "pbft_epoll_wakeups_total");
+  fold_delta(frames_in_, &seen_frames_in_, "pbft_frames_in_total");
+  fold_delta(mac_frames_ + (shards_ ? shards_->mac_frames() : 0),
+             &seen_mac_frames_, "pbft_mac_frames_total");
+  fold_delta(gateway_forwarded_, &seen_gateway_forwarded_,
+             "pbft_gateway_forwarded_total");
+  // The loop clock, in whole microseconds a stage (pbft_loop_<stage>_us_total);
+  // the total is the sum of the seven AS FOLDED, so the eight counters
+  // agree to the microsecond.
+  int64_t total_us = 0;
+  for (int i = 0; i < kLoopStages; ++i) {
+    char name[48];
+    std::snprintf(name, sizeof(name), "pbft_loop_%s_us_total",
+                  kLoopStageNames[i]);
+    const int64_t us = loop_clock_.ns[i] / 1000;
+    fold_delta(us, &seen_loop_us_[i], name);
+    total_us += us;
+  }
+  fold_delta(total_us, &seen_loop_total_us_, "pbft_loop_us_total");
+}
+
+void ReplicaServer::fold_delta(int64_t now_abs, int64_t* seen,
+                               const char* name) {
+  if (now_abs > *seen) {
+    metrics_.inc(name, now_abs - *seen);
+    *seen = now_abs;
+  }
+}
+
 void ReplicaServer::refresh_health() {
+  fold_counters();
   const auto now = std::chrono::steady_clock::now();
   const int64_t executed = replica_->executed_upto();
   if (executed != progress_seen_executed_) {
@@ -2589,6 +2674,23 @@ std::string ReplicaServer::metrics_json() {
   o["view_timer_backoff"] = Json((int64_t)timer_backoff_);
   o["verify_batches"] = Json(batches_run_);
   o["verify_launched_ahead"] = Json(launched_ahead_);
+  {
+    // Kept spans worked through so far and the seconds that took
+    // (pbft_verdict_apply_seconds' count and sum).
+    JsonObject apply;
+    apply["batches"] = Json(verdict_applies_);
+    apply["seconds"] = Json(verdict_apply_s_);
+    o["verify_apply"] = Json(std::move(apply));
+    // The loop thread's microseconds by kind of work, the passes they
+    // were spent in and the stage switches that measured them.
+    JsonObject loop;
+    for (int i = 0; i < kLoopStages; ++i) {
+      loop[kLoopStageNames[i]] = Json(loop_clock_.ns[i] / 1000);
+    }
+    loop["passes"] = Json(event_wakeups_);
+    loop["switches"] = Json(loop_clock_.switches);
+    o["loop_us"] = Json(std::move(loop));
+  }
   o["broadcasts"] = Json(broadcasts_);
   o["broadcast_encodes"] =
       Json(broadcast_encodes_ +

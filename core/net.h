@@ -323,6 +323,83 @@ enum class FaultMode { kNone, kSigCorrupt, kMute, kStutter, kEquivocate };
 // Returns false on an unknown mode name.
 bool fault_mode_from_string(const std::string& s, FaultMode* out);
 
+// Where the loop thread's wall time goes, by KIND OF WORK (ISSUE 38). The
+// stages are exclusive and nested: a frame read inside handle_readable is
+// dispatched, executed, signed, flushed and answered before that call
+// returns, so a Scope charges what has elapsed to the stage that was
+// running, switches, and switches back when it ends. One clock read a
+// switch, plain integers, no registry lookup: the registry's counters are
+// brought up to date where a scrape or /status is rendered
+// (ReplicaServer::fold_counters). `on` follows metrics_.enabled ||
+// trace_fp_ (poll_once sets it once a pass); off, a Scope is one branch.
+// The seven stages sum to the elapsed monotonic time since the clock came
+// on. With --net-threads above 1 it covers the consensus thread alone
+// (the socket work is the shards').
+enum LoopStage : int {
+  kLoopWait,      // inside poller_->wait
+  kLoopRead,      // socket reads, frame decode, link authentication
+  kLoopProtocol,  // every call into Replica: state machine, digests, signing
+  kLoopWal,       // flush_wal with records pending: write + fsync
+  kLoopSend,      // emit after the flush: encode, MAC tags, queue, send()
+  kLoopVerify,    // the verify inbox: pending_items, begin_batch, verdicts
+  kLoopOther,     // the rest of a pass: scrapes, sweeps, timers' arithmetic
+  kLoopStages
+};
+inline constexpr const char* kLoopStageNames[kLoopStages] = {
+    "wait", "read", "protocol", "wal", "send", "verify", "other"};
+
+struct LoopClock {
+  bool on = false;
+  int stage = kLoopOther;
+  std::chrono::steady_clock::time_point since{};
+  std::array<int64_t, kLoopStages> ns{};
+  int64_t switches = 0;
+
+  // Follow the switch that turns the clock on; time before it is nobody's.
+  void set_on(bool want) {
+    if (want == on) return;
+    on = want;
+    if (on) since = std::chrono::steady_clock::now();
+  }
+  // Charge what has elapsed to the running stage; returns the instant.
+  std::chrono::steady_clock::time_point sync() {
+    const auto now = std::chrono::steady_clock::now();
+    ns[stage] +=
+        std::chrono::duration_cast<std::chrono::nanoseconds>(now - since)
+            .count();
+    since = now;
+    return now;
+  }
+  int64_t total_ns() const {
+    int64_t t = 0;
+    for (int64_t v : ns) t += v;
+    return t;
+  }
+  // One stage switch: returns the stage that was running.
+  int switch_to(int s) {
+    sync();
+    ++switches;
+    const int prev = stage;
+    stage = s;
+    return prev;
+  }
+
+  class Scope {
+   public:
+    Scope(LoopClock& c, int s)
+        : c_(c), prev_(c.on && c.stage != s ? c.switch_to(s) : -1) {}
+    ~Scope() {
+      if (prev_ >= 0) c_.switch_to(prev_);
+    }
+    Scope(const Scope&) = delete;
+    Scope& operator=(const Scope&) = delete;
+
+   private:
+    LoopClock& c_;
+    int prev_;
+  };
+};
+
 class NetShards;  // multi-core front end (core/net_shard.h)
 
 class ReplicaServer {
@@ -361,7 +438,11 @@ class ReplicaServer {
   void set_metrics_port(int port) { metrics_port_ = port; }
   int metrics_listen_port() const { return metrics_listen_port_; }
   Metrics& metrics() { return metrics_; }
-  std::string metrics_prometheus() const;
+  // Non-const like metrics_json(): rendering first brings the counters
+  // that are kept as plain integers up to date (fold_counters).
+  std::string metrics_prometheus();
+  // Where the loop thread's time has gone so far (ISSUE 38).
+  const LoopClock& loop_clock() const { return loop_clock_; }
 
   // Wedged-async-verifier bound: an inflight remote launch
   // older than this is abandoned — connection dropped, batch re-verified
@@ -481,9 +562,19 @@ class ReplicaServer {
   void keep_verdicts(std::vector<uint8_t> verdicts);
   void apply_kept_verdicts();
   // Shared verdict accounting for the sync and async paths: counters,
-  // trace (secs: launch -> verdicts in hand; ahead: a launch went out
-  // while these verdicts were kept), deliver + emit. One verdict an item.
-  void deliver_verified(double secs, bool ahead, std::vector<uint8_t> verdicts);
+  // deliver + emit, then the trace line (secs: launch -> verdicts in
+  // hand; ahead: a launch went out while these verdicts were kept). One
+  // verdict an item. `began`: when the delivery of a KEPT span began
+  // (apply_kept_verdicts' clock read); from there to the last send is
+  // pbft_verdict_apply_seconds, once a batch. Null on the blocking branch.
+  void deliver_verified(double secs, bool ahead, std::vector<uint8_t> verdicts,
+                        const std::chrono::steady_clock::time_point* began);
+  // A call into Replica, charged to the loop clock's `protocol` stage.
+  template <class F>
+  auto in_protocol(F&& call) {
+    LoopClock::Scope in(loop_clock_, kLoopProtocol);
+    return call();
+  }
   void emit(Actions&& actions);
   void send_to(int64_t dest, const Message& m);
   // Shared by send_to and the broadcast fan-out: pick the link codec,
@@ -566,7 +657,9 @@ class ReplicaServer {
   // Group-commit point: write+fsync everything noted since the last
   // flush, then fold the wal counters into the metrics registry.
   void flush_wal();
-  void trace_batch(int64_t size, int64_t rejected, double secs, bool ahead);
+  // apply_s < 0: not measured (the blocking branch).
+  void trace_batch(int64_t size, int64_t rejected, double secs, bool ahead,
+                   double apply_s);
   void trace_view_change(int backoff);
   // Request-level waterfall events (ISSUE 9; schemas in
   // pbft_tpu/utils/trace_schema.py): request arrival, the primary's batch
@@ -596,6 +689,13 @@ class ReplicaServer {
   // health gauges into the registry. Called whenever the status surface
   // renders (metrics_json / Prometheus scrape).
   void refresh_health();
+  // Bring the registry's counters that the loop keeps as plain integers up
+  // to date (ISSUE 38): the loop clock's stages, passes, frames in, MAC
+  // frames, requests over gateway links. Called with refresh_health, so
+  // no per-frame or per-stage-switch path looks a name up.
+  void fold_counters();
+  // A monotonic total the loop keeps -> the registry counter's increment.
+  void fold_delta(int64_t now_abs, int64_t* seen, const char* name);
   // Abandon an over-deadline inflight async verify (see
   // set_verify_deadline_ms); no-op unless wedged.
   void check_verify_deadline(std::chrono::steady_clock::time_point now);
@@ -622,6 +722,7 @@ class ReplicaServer {
   int64_t seen_rollbacks_ = 0;
   int64_t seen_seals_refused_ = 0;
   int64_t seen_inline_verifies_ = 0;
+  int64_t seen_signs_ = 0;
   // Chaos link state (set_chaos): seeded drop/delay on outbound peer
   // frames, a per-destination FIFO of delayed frames, and the injected
   // fault / dropped frame tallies surfaced in metrics_json.
@@ -710,11 +811,7 @@ class ReplicaServer {
   std::set<uint64_t> sharded_gateways_;
   // Last-seen shard counter snapshots: shard counters are absolute
   // relaxed atomics, prometheus counters are monotonic increments.
-  int64_t seen_shard_wakeups_ = 0;
   int64_t seen_cross_wakes_ = 0;
-  int64_t seen_codec_bin_ = 0;
-  int64_t seen_codec_json_ = 0;
-  int64_t seen_shard_mac_ = 0;
   int64_t seen_shard_backpressure_ = 0;
   int64_t seen_shard_chaos_ = 0;
   int64_t seen_shard_encodes_ = 0;
@@ -776,6 +873,11 @@ class ReplicaServer {
   };
   std::optional<KeptVerdicts> kept_;
   int64_t launched_ahead_ = 0;  // launches made while a span was kept
+  // Kept spans worked through, and the seconds from their delivery
+  // beginning to its last send (pbft_verdict_apply_seconds; /status
+  // verify_apply).
+  int64_t verdict_applies_ = 0;
+  double verdict_apply_s_ = 0.0;
   // pbft_verify_inbox_wait_seconds: since when an entry no launch has
   // taken (Replica::unlaunched_count) has been waiting.
   bool inbox_waiting_ = false;
@@ -801,6 +903,16 @@ class ReplicaServer {
 
   // Metrics registry + scrape listener (enabled by set_metrics_port).
   Metrics metrics_;
+  // The loop thread's stage clock and what fold_counters has already fed
+  // to the registry (microseconds a stage; passes, frames in, MAC frames
+  // and gateway requests as the integers above count them).
+  LoopClock loop_clock_;
+  std::array<int64_t, kLoopStages> seen_loop_us_{};
+  int64_t seen_loop_total_us_ = 0;
+  int64_t seen_wakeups_ = 0;
+  int64_t seen_frames_in_ = 0;
+  int64_t seen_mac_frames_ = 0;
+  int64_t seen_gateway_forwarded_ = 0;
   int metrics_port_ = -1;
   int metrics_listen_fd_ = -1;
   int metrics_listen_port_ = 0;

@@ -279,10 +279,7 @@ void CryptoPipeline::handle(CryptoCmd& c) {
       if (payload == nullptr && p.codec_binary) {
         payload = c.enc->binary_payload();
       }
-      const bool bin = payload != nullptr;
-      if (!bin) payload = &c.enc->json_payload();
-      (bin ? bin_frames : json_frames)
-          .fetch_add(1, std::memory_order_relaxed);
+      if (payload == nullptr) payload = &c.enc->json_payload();
       if (mac_frame) mac_frames.fetch_add(1, std::memory_order_relaxed);
       seal_and_ship(c.dest, *payload);
       return;
@@ -1234,20 +1231,6 @@ int64_t NetShards::crypto_queue_depth() const {
   int64_t t = 0;
   for (auto& p : pipelines_) {
     t += p->queue_depth.load(std::memory_order_relaxed);
-  }
-  return t;
-}
-
-int64_t NetShards::codec_binary_frames() const {
-  int64_t t = 0;
-  for (auto& p : pipelines_) t += p->bin_frames.load(std::memory_order_relaxed);
-  return t;
-}
-
-int64_t NetShards::codec_json_frames() const {
-  int64_t t = 0;
-  for (auto& p : pipelines_) {
-    t += p->json_frames.load(std::memory_order_relaxed);
   }
   return t;
 }

@@ -200,8 +200,6 @@ class CryptoPipeline {
   void run();  // thread body
 
   std::atomic<int64_t> queue_depth{0};  // pbft_crypto_offload_queue_depth
-  std::atomic<int64_t> bin_frames{0};
-  std::atomic<int64_t> json_frames{0};
   std::atomic<int64_t> mac_frames{0};    // MAC-vector frames sent
   std::atomic<int64_t> mac_rejected{0};  // inbound lane mismatches
   std::atomic<int64_t> chaos_dropped{0};
@@ -347,8 +345,6 @@ class NetShards {
   int64_t cross_thread_wakes() const;
   int64_t connections_open() const;
   int64_t crypto_queue_depth() const;
-  int64_t codec_binary_frames() const;
-  int64_t codec_json_frames() const;
   int64_t mac_frames() const;
   int64_t mac_rejected() const;
   int64_t backpressure_events() const;

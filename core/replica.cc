@@ -85,6 +85,7 @@ M Replica::sign(M msg) const {
   uint8_t digest[32], sig[64];
   message_signable(Message(msg), digest);
   ed25519_sign(sig, seed_, digest, 32);
+  ++signs_;
   msg.sig = to_hex(sig, 64);
   return msg;
 }

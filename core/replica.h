@@ -175,6 +175,8 @@ class Replica {
   // its bounded accumulation (verify_flush_us) check the latter every pass.
   size_t pending_count() const { return inbox_.size(); }
   size_t unlaunched_count() const { return inbox_.size() - inbox_taken_; }
+  // Signatures this replica has made (pbft_signs_total).
+  int64_t signs() const { return signs_; }
   // Apply the verdicts of the OLDEST undelivered span, in arrival order.
   // Stops at the first entry that still awaits a verdict, be it in a
   // later span (on the wire) or in none.
@@ -280,6 +282,7 @@ class Replica {
 
   template <typename M>
   M sign(M msg) const;
+  mutable int64_t signs_ = 0;  // signatures made; no clock, no I/O
 
   Actions seal_batch();
   Actions dispatch(const Message& msg);

@@ -1547,10 +1547,6 @@ class AsyncReplicaServer:
                 payload = enc.binary_payload()
             if payload is not None:
                 self.codec_binary_frames += 1
-                if self.metrics_registry.enabled:
-                    self.metrics_registry.counter(
-                        "pbft_codec_binary_frames_total"
-                    ).inc()
                 if mac_frame:
                     self.mac_frames += 1
                     if self.metrics_registry.enabled:
@@ -1560,10 +1556,6 @@ class AsyncReplicaServer:
             else:
                 payload = enc.json_payload()
                 self.codec_json_frames += 1
-                if self.metrics_registry.enabled:
-                    self.metrics_registry.counter(
-                        "pbft_codec_json_frames_total"
-                    ).inc()
             # Bounded-outbound admission BEFORE the seal (ISSUE 10): a
             # black-holed peer whose drain() never completes must not
             # grow the transport buffer (or the task queue behind the

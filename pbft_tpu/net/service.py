@@ -25,11 +25,14 @@ live in ``verify_service.py``, and only that daemon ever answers ``ready``.
 from __future__ import annotations
 
 import json
+import os
 import socket
 import socketserver
 import struct
+import sys
 import threading
 import time
+import traceback
 from typing import Callable, List, Optional, Tuple
 
 from ..utils.trace import Tracer, open_span
@@ -68,6 +71,17 @@ STATE_NAMES = {
 # longer than a replica's connect deadline (PBFT_VERIFY_CONNECT_MS, 250).
 LISTEN_QUEUE = 128
 
+# A launch in flight this long is a STALL, and the watcher writes down what
+# the process is doing (``launch_stalled``): every launch above 1 s in the
+# 176 launch logs kept over PRs 28-33 was one (1.96 to 11.46 s, four runs),
+# and the longest healthy launch in the benchmark's ledger waited 132 ms for
+# its device (``device_wait_ms_max``). The watcher wakes twice a second (and
+# at the mark of a launch it has seen in flight).
+STALL_S = 1.0
+STALL_POLL_S = 0.5
+# The innermost frames kept of each Python thread's stack in that record.
+STALL_FRAMES = 12
+
 # Launch slots the daemon gives its dispatcher (``inflight``): with two,
 # window N+1 is staged and dispatched from a second launch thread while
 # window N computes, which hides the host's share of a launch behind the
@@ -75,6 +89,46 @@ LISTEN_QUEUE = 128
 # another value; a bare service defaults to 1 (one launch at a time: what
 # tests that count windows need).
 DAEMON_INFLIGHT = 2
+
+
+def _python_stacks() -> dict:
+    """{thread id: {"name", "frames": innermost-last "file:line function"}}
+    of every Python thread, from ``sys._current_frames``."""
+    names = {t.ident: t.name for t in threading.enumerate()}
+    return {
+        str(ident): {
+            "name": names.get(ident, "?"),
+            "frames": [
+                f"{os.path.basename(fs.filename)}:{fs.lineno} {fs.name}"
+                for fs in traceback.extract_stack(frame)[-STALL_FRAMES:]
+            ],
+        }
+        for ident, frame in sys._current_frames().items()
+    }
+
+
+def _os_threads() -> list:
+    """[tid, name, state, wchan] of every thread of this process, the
+    runtime's own among them (``pjrt-tpu-tasks``, ``EventFDAsyncWor``,
+    ``py_xla_execute``), from ``/proc/self/task``; [] where there is none."""
+    out = []
+    try:
+        tids = sorted(os.listdir("/proc/self/task"), key=int)
+    except OSError:
+        return out
+    for tid in tids:
+        try:
+            with open(f"/proc/self/task/{tid}/stat") as fh:
+                stat = fh.read()
+            # "tid (comm) S ...": comm may hold spaces and parentheses.
+            name = stat[stat.index("(") + 1 : stat.rindex(")")]
+            state = stat[stat.rindex(")") + 2 :].split(" ", 1)[0]
+            with open(f"/proc/self/task/{tid}/wchan") as fh:
+                wchan = fh.read().strip()
+        except (OSError, ValueError):
+            continue  # the thread ended between the listing and the read
+        out.append([int(tid), name, state, wchan])
+    return out
 
 
 def pack_status(state: int, devices: int, warmed: int) -> bytes:
@@ -240,6 +294,16 @@ class VerifierService:
         self.launches_by_rung: dict = {}
         self.launches_by_rows_per_chip: dict = {}
         self._slowest: Optional[dict] = None
+        # Backend calls in flight ({t0, size, span, thread, stalled}), read by
+        # the stall watcher; stalls / longest_stall_s go into the status JSON.
+        # ``stall_probe`` is the owner of the backend's to set: what it can
+        # say about its device in a stall (the daemon: every local device's
+        # ``memory_stats()``); a bare service knows no device.
+        self._flights: List[dict] = []
+        self._flight_lock = threading.Lock()
+        self.stall_probe: Optional[Callable[[], object]] = None
+        self.stalls = 0
+        self.longest_stall_s = 0.0
         self._cond = threading.Condition()
         self._pending: List[_Pending] = []
         self._running = True
@@ -308,6 +372,7 @@ class VerifierService:
         self._thread: Optional[threading.Thread] = None
         self._dispatcher = threading.Thread(target=self._dispatch_loop, daemon=True)
         self._dispatcher.start()
+        threading.Thread(target=self._stall_watch, daemon=True).start()
 
     # Largest merged window, in items; overflow stays queued for the next
     # window. A bound on one launch's latency and staging memory, set to the
@@ -456,9 +521,88 @@ class VerifierService:
 
     def _spanned(self, items: List[Item]) -> Tuple[List[bool], dict]:
         """One backend call -> (verdicts, what the backend wrote into the
-        span opened round it: the engine's steps, or nothing)."""
+        span opened round it: the engine's steps, or nothing). While it
+        runs the call is on the stall watcher's list."""
         with open_span() as span:
-            return self._checked(self.backend, items), span
+            flight = {
+                "t0": time.monotonic(), "size": len(items), "span": span,
+                "thread": threading.get_ident(), "stalled": False,
+            }
+            with self._flight_lock:
+                self._flights.append(flight)
+            try:
+                return self._checked(self.backend, items), span
+            finally:
+                with self._flight_lock:
+                    self._flights.remove(flight)
+                if flight["stalled"]:
+                    self._stall_ended(flight)
+
+    # -- what the process does in a stall (ISSUE 38) --------------------------
+
+    def _stall_watch(self) -> None:
+        """Twice a second: a launch in flight longer than STALL_S gets ONE
+        ``launch_stalled`` record (the tracer's line, and stderr)."""
+        nap = STALL_POLL_S
+        while self._running:
+            time.sleep(nap)
+            now = time.monotonic()
+            with self._flight_lock:
+                late = [
+                    f for f in self._flights
+                    if not f["stalled"] and now - f["t0"] > STALL_S
+                ]
+                for f in late:
+                    f["stalled"] = True
+                # Twice a second, and sooner where a launch seen in flight
+                # is about to pass the mark.
+                due = [f["t0"] + STALL_S - now for f in self._flights if not f["stalled"]]
+            nap = min([STALL_POLL_S] + [max(d, 0.0) + 0.01 for d in due])
+            for f in late:
+                with self._cond:
+                    self.stalls += 1
+                record = dict(
+                    replica="service",
+                    size=f["size"],
+                    rung=f["span"].get("rung"),  # None until the engine says
+                    age_s=round(now - f["t0"], 3),
+                    thread=f["thread"],
+                    stacks=_python_stacks(),
+                    tasks=_os_threads(),
+                    memory=self._probe_device(),
+                )
+                self._tracer.event("launch_stalled", **record)
+                print("[verify-service] launch_stalled "
+                      + json.dumps(record, separators=(",", ":"), default=str),
+                      file=sys.stderr, flush=True)
+
+    def _probe_device(self):
+        """``stall_probe()`` with half a second to answer: the runtime that
+        hangs a launch may hang this call too, and the record must get out."""
+        if self.stall_probe is None:
+            return None
+        box: list = []
+
+        def ask() -> None:
+            try:
+                box.append(self.stall_probe())
+            except Exception as e:  # noqa: BLE001 - goes into the record
+                box.append(f"failed: {e!r}")
+
+        t = threading.Thread(target=ask, daemon=True)
+        t.start()
+        t.join(STALL_POLL_S)
+        return box[0] if box else f"no answer in {STALL_POLL_S} s"
+
+    def _stall_ended(self, flight: dict) -> None:
+        secs = round(time.monotonic() - flight["t0"], 3)
+        with self._cond:
+            self.longest_stall_s = max(self.longest_stall_s, secs)
+        self._tracer.event(
+            "launch_stall_ended", replica="service", size=flight["size"], secs=secs
+        )
+        print(f"[verify-service] launch_stall_ended size={flight['size']} secs={secs}",
+              file=sys.stderr, flush=True)
 
     def _account(self, secs: float, size: int, waits: dict, span: dict) -> None:
         """Fold one finished launch into the status totals (under _cond)."""
@@ -489,8 +633,9 @@ class VerifierService:
 
     def launch_status(self) -> dict:
         """The stage totals, the counts of launches (promoted, split, by exit
-        of the hold, by shape run, by rows a chip) and the slowest launch, for
-        the status JSON."""
+        of the hold, by shape run, by rows a chip), the slowest launch, and
+        the launches that stalled (above STALL_S in flight) with the longest
+        of them that has ended, for the status JSON."""
         with self._cond:
             slowest = dict(self._slowest) if self._slowest else None
             totals = {k: round(v, 6) for k, v in self.stage_seconds.items()}
@@ -501,6 +646,8 @@ class VerifierService:
                 "in_step_launches": self.in_step_launches,
                 "launches_by_rung": dict(self.launches_by_rung),
                 "launches_by_rows_per_chip": dict(self.launches_by_rows_per_chip),
+                "stalls": self.stalls,
+                "longest_stall_s": self.longest_stall_s,
             }
         if slowest:
             slowest["ago_s"] = round(time.monotonic() - slowest.pop("at"), 3)

@@ -551,16 +551,20 @@ class ShardedVerifyEngine:
     def memory_peak_bytes(self) -> Optional[int]:
         """The fullest local device's ``peak_bytes_in_use``; None before the
         backend is up and where the backend reports no memory statistics."""
+        peaks = [
+            (stats or {}).get("peak_bytes_in_use") for stats in self.memory_stats() or []
+        ]
+        peaks = [int(p) for p in peaks if p is not None]
+        return max(peaks) if peaks else None
+
+    def memory_stats(self) -> Optional[list]:
+        """Every local device's ``memory_stats()`` as it stands: what the
+        service's stall record carries of the device (``stall_probe``)."""
         if self._mesh is None:
             return None
         import jax
 
-        peaks = [
-            (dev.memory_stats() or {}).get("peak_bytes_in_use")
-            for dev in jax.local_devices()
-        ]
-        peaks = [int(p) for p in peaks if p is not None]
-        return max(peaks) if peaks else None
+        return [dev.memory_stats() for dev in jax.local_devices()]
 
 
 # -- the daemon --------------------------------------------------------------
@@ -628,6 +632,8 @@ class VerifyServiceDaemon:
             status_provider=self._status,
             status_json_provider=self.status_json,
         )
+        if self.engine is not None:
+            self.service.stall_probe = getattr(self.engine, "memory_stats", None)
         if self.service.metrics_registry.enabled:
             # The warm/cold compile gauges exist from the first scrape
             # (service.py's preregister only covers its own emitter set).

@@ -39,8 +39,14 @@ EVENT_SCHEMAS = {
     # at the first dispatch), devices (the chips the window's executables
     # are sharded over, as their input sharding said at warm-up) and
     # rows_per_chip (the slots of the window's smallest chunk over devices).
-    # pbftd's line is one BATCH of one replica; its 0/1 field ahead says
-    # the next batch was launched before this one's verdicts were applied.
+    # pbftd's line is one BATCH of one replica, written when its verdicts
+    # have been worked through; its 0/1 field ahead says the next batch was
+    # launched before this one's verdicts were applied, apply_s (kept spans
+    # only) how long working through them took (pbft_verdict_apply_seconds'
+    # reading, ending at ts), and loop_us the loop clock's seven running
+    # totals at ts, microseconds, in LOOP_STAGES' order: two lines of one
+    # replica bracket an interval with its split by kind of work
+    # (scripts/trace_report.py, against verifyd's t_dev on the same clock).
     "verify_batch": {
         "required": {"ts", "ev", "replica", "size", "rejected", "secs"},
         "optional": {
@@ -49,6 +55,7 @@ EVENT_SCHEMAS = {
             "hold_s", "held_out", "in_step",
             "pad_s", "put_s", "dispatch_s", "wait_s", "unpack_s", "rung", "promoted",
             "chunks", "split", "t_dev", "devices", "rows_per_chip", "ahead",
+            "apply_s", "loop_us",
         },
         "emitters": {"server.py", "service.py", "net.cc"},
     },
@@ -58,6 +65,26 @@ EVENT_SCHEMAS = {
         "emitters": {"service.py"},
     },
     "verify_batch_error": {
+        "required": {"ts", "ev", "replica", "size", "secs"},
+        "optional": set(),
+        "emitters": {"service.py"},
+    },
+    # What the chip process does in a stall (ISSUE 38; ROADMAP A7): ONE
+    # record for a launch in flight longer than service.STALL_S, written by
+    # a watcher thread while the launch still hangs: its size, rung (None
+    # until the engine has said), age, the launch thread's id, every Python
+    # thread's innermost frames (stacks), every OS thread's [tid, name,
+    # state, wchan] from /proc/self/task (tasks: the runtime's own threads
+    # are there), and what the backend's owner can say about its device
+    # (memory: every local device's memory_stats(); None on a bare
+    # service). launch_stall_ended follows when the launch returns, with
+    # its whole length. Also on stderr, with or without --trace.
+    "launch_stalled": {
+        "required": {"ts", "ev", "replica", "size", "age_s", "thread", "stacks", "tasks"},
+        "optional": {"rung", "memory"},
+        "emitters": {"service.py"},
+    },
+    "launch_stall_ended": {
         "required": {"ts", "ev", "replica", "size", "secs"},
         "optional": set(),
         "emitters": {"service.py"},
@@ -183,13 +210,13 @@ METRIC_SCHEMAS = {
     "pbft_verify_pool_queue_depth": ("gauge", {"net.cc"}),
     "pbft_verify_pool_utilization": ("gauge", {"net.cc"}),
     "pbft_verify_pool_window_size": ("histogram", {"net.cc"}),
-    # Wire-codec surface (ISSUE 3): outbound frames per payload codec,
-    # plus the serialize-once invariant counter — encodes are counted per
-    # BROADCAST (lazy, at most once per codec), never per peer, so in a
-    # single-codec cluster pbft_broadcast_encodes_total tracks the
-    # broadcast count instead of broadcasts x peers.
-    "pbft_codec_binary_frames_total": ("counter", {"server.py", "net.cc"}),
-    "pbft_codec_json_frames_total": ("counter", {"server.py", "net.cc"}),
+    # Wire-codec surface (ISSUE 3): the serialize-once invariant counter —
+    # encodes are counted per BROADCAST (lazy, at most once per codec),
+    # never per peer, so in a single-codec cluster
+    # pbft_broadcast_encodes_total tracks the broadcast count instead of
+    # broadcasts x peers. (The two outbound-frames-per-codec counters went
+    # in ISSUE 38: nothing read them; the Python runtime's metrics() dict
+    # keeps codec_binary_frames / codec_json_frames.)
     "pbft_broadcast_encodes_total": ("counter", {"server.py", "net.cc"}),
     # Batching surface (ISSUE 4): requests executed vs three-phase
     # instances executed (their ratio is the batch amplification), and
@@ -350,6 +377,40 @@ METRIC_SCHEMAS = {
     # to their delivery beginning (one clock read a batch, none a message).
     "pbft_verify_launched_ahead_total": ("counter", {"net.cc"}),
     "pbft_verdict_held_seconds": ("histogram", {"net.cc"}),
+    # The span that closes the verify cycle (pbftd only; ISSUE 38): once a
+    # KEPT batch, from the delivery of its verdicts beginning (the clock
+    # read that ends pbft_verdict_held_seconds) to deliver_verified
+    # returning: dispatch, execute, sign, WAL flush, sends for one batch's
+    # verdicts. One more clock read a batch; /status: verify_apply.
+    "pbft_verdict_apply_seconds": ("histogram", {"net.cc"}),
+    # The net loop's stage clock (pbftd only; ISSUE 38; core/net.h
+    # LoopClock): exclusive wall time of the loop thread by kind of work,
+    # microseconds, nested (a frame is read, authenticated, dispatched,
+    # executed, signed, flushed and answered inside one handle_readable
+    # call): wait (inside the poller's wait), read (socket reads, frame
+    # decode, link authentication, accepts), protocol (every call into
+    # Replica, its timers), wal (flush_wal with records pending), send
+    # (emit after the flush: encode, MAC tags, queue, send(), reply
+    # dial-backs), verify (the verify inbox: pending_items, the begin_batch
+    # write, the blocking verify, the verdicts' read, a batch's
+    # accounting), other (the rest of a pass: scrapes, sweeps, discovery).
+    # The seven sum to pbft_loop_us_total to the microsecond and to the
+    # thread's elapsed CLOCK_MONOTONIC time; pbft_epoll_wakeups_total is
+    # the passes they were spent in. One clock read a stage switch; the
+    # registry is brought up to date where a scrape or /status is rendered
+    # (fold_counters), never on a per-frame path. With --net-threads above
+    # 1 the clock covers the consensus thread alone. /status: loop_us.
+    "pbft_loop_us_total": ("counter", {"net.cc"}),
+    "pbft_loop_wait_us_total": ("counter", {"net.cc"}),
+    "pbft_loop_read_us_total": ("counter", {"net.cc"}),
+    "pbft_loop_protocol_us_total": ("counter", {"net.cc"}),
+    "pbft_loop_wal_us_total": ("counter", {"net.cc"}),
+    "pbft_loop_send_us_total": ("counter", {"net.cc"}),
+    "pbft_loop_verify_us_total": ("counter", {"net.cc"}),
+    "pbft_loop_other_us_total": ("counter", {"net.cc"}),
+    # Signatures the replica made (Replica::sign; pbftd only): every reply
+    # carries one, so it is the largest countable item inside `protocol`.
+    "pbft_signs_total": ("counter", {"net.cc"}),
     # What the fast path adds to a reply's path, and what it takes off it
     # (ISSUE 32); all four in both modes' series sets. Request wait: on the
     # primary, once a batch at its seal (the "request" phase stamp), seal
@@ -376,6 +437,10 @@ LATENCY_BUCKETS_S = (
     0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
 )
 BATCH_SIZE_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
+
+# The loop clock's stages, in the order a verify_batch line's loop_us lists
+# them (core/net.h kLoopStageNames).
+LOOP_STAGES = ("wait", "read", "protocol", "wal", "send", "verify", "other")
 
 # The consensus phases in protocol order. "request" exists only on the
 # primary (it assigns the sequence number); every replica sees the rest.
@@ -449,6 +514,9 @@ VERIFYD_STATUS_KEYS = {
     "stage_seconds", "promoted_launches", "split_launches", "held_out_launches",
     "in_step_launches", "launches_by_rung", "launches_by_rows_per_chip",
     "slowest_launch",
+    # Launches that were in flight longer than service.STALL_S (each left a
+    # launch_stalled record) and the longest of them that has ended.
+    "stalls", "longest_stall_s",
     "memory_peak_bytes", "warm_stats", "warm_error",
 }
 VERIFYD_WARM_STATS_KEYS = {

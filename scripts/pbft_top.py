@@ -3,8 +3,9 @@
 
 Polls every replica's /status endpoint (the versioned health document
 both runtimes serve next to /metrics; optionally a gateway's too) on an
-interval, renders a one-screen view — view/seq/floor, req/s, RSS, fds,
-WAL size, backoff level per replica — and continuously runs the
+interval, renders a one-screen view — view/seq/floor, req/s, the net
+loop's busy share, RSS, fds, WAL size, backoff level per replica — and
+continuously runs the
 detector library (pbft_tpu/analysis/health.py) over the accumulated
 snapshot history.
 
@@ -79,6 +80,30 @@ def _rate(history, rid, key, span_snapshots=5):
     return max(0.0, (series[-1][1] - series[0][1]) / dt)
 
 
+def loop_busy(history, rid, span_snapshots=5):
+    """The share of the last few snapshots' span that the replica's net
+    loop spent OUTSIDE its poller's wait: 1 - (wait gained) / (all seven
+    stages gained), from /status ``loop_us`` (pbftd; ISSUE 38). None where
+    a runtime has no loop clock (the asyncio replica) or the span is one
+    snapshot. A loop near 1.0 is a stage standing at a full core."""
+    series = [
+        s["replicas"][rid]["loop_us"]
+        for s in list(history)[-span_snapshots:]
+        if isinstance(s.get("replicas", {}).get(rid, {}).get("loop_us"), dict)
+    ]
+    if len(series) < 2:
+        return None
+
+    def total(doc):
+        return sum(v for k, v in doc.items() if k not in ("passes", "switches"))
+
+    spent = total(series[-1]) - total(series[0])
+    if spent <= 0:
+        return None
+    waited = series[-1].get("wait", 0) - series[0].get("wait", 0)
+    return min(1.0, max(0.0, 1.0 - waited / spent))
+
+
 def render(history, verdicts, gateway_doc=None) -> str:
     latest = history[-1]
     lines = [
@@ -88,14 +113,15 @@ def render(history, verdicts, gateway_doc=None) -> str:
             len(history),
             history[-1]["t"] - history[0]["t"],
         ),
-        "%3s %5s %9s %9s %7s %8s %9s %5s %9s %4s %7s"
-        % ("id", "view", "executed", "committed", "floor", "req/s",
+        "%3s %5s %9s %9s %7s %8s %5s %9s %5s %9s %4s %7s"
+        % ("id", "view", "executed", "committed", "floor", "req/s", "loop",
            "rss", "fds", "wal", "bkff", "stall_s"),
     ]
     for rid in sorted(latest["replicas"]):
         doc = latest["replicas"][rid]
+        busy = loop_busy(history, rid)
         lines.append(
-            "%3s %5d %9d %9d %7d %8.1f %8.1fM %5d %8.1fK %4d %7.1f"
+            "%3s %5d %9d %9d %7d %8.1f %5s %8.1fM %5d %8.1fK %4d %7.1f"
             % (
                 rid,
                 doc.get("view", 0),
@@ -103,6 +129,7 @@ def render(history, verdicts, gateway_doc=None) -> str:
                 doc.get("committed_upto", 0),
                 doc.get("low_mark", 0),
                 _rate(history, rid, "executed"),
+                "-" if busy is None else "%.2f" % busy,
                 doc.get("rss_bytes", 0) / 1e6,
                 doc.get("open_fds", 0),
                 doc.get("wal_disk_bytes", 0) / 1e3,

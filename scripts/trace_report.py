@@ -6,15 +6,35 @@ cluster-wide: verify-batch count/size/time percentiles, batching-window
 efficiency (items per launch — the number the TPU batching design exists
 to maximize), rejected-signature totals, and view-change events.
 
+Given the replicas' traces AND verifyd's launch log (``verifyd --trace``),
+it also prints the idle-interval table (ISSUE 38): for every interval of
+5 ms or more in which verifyd had nothing in flight (the complement of the
+union of ``[t_dev, t_dev + dispatch_s + wait_s]``, what the benchmark's
+``engine_idle_pct`` reads), what each replica's net loop spent between the
+two of its ``verify_batch`` lines that bracket the interval (their
+``loop_us``: the loop clock's running totals by kind of work), and the
+``queue_s`` / ``hold_s`` of the launch that ended it: the device's
+"waiting for a window" put down to verifyd's hold, to replicas at work
+(which stage), or to replicas themselves in ``wait`` (the chip is then
+waiting for the clients). Every stamp is CLOCK_MONOTONIC on one host.
+
 Usage: python scripts/trace_report.py /path/to/trace-dir-or-files...
 """
 
 from __future__ import annotations
 
+import bisect
 import json
 import pathlib
 import sys
 from collections import Counter
+
+sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
+
+from pbft_tpu.utils.trace_schema import LOOP_STAGES  # noqa: E402
+
+# The shortest interval with nothing in flight that gets a row.
+IDLE_GAP_S = 0.005
 
 
 def _pct(sorted_vals, q: float):
@@ -133,7 +153,122 @@ def _ahead_summary(batches) -> str:
     return f", launched ahead {sum(said)}/{len(said)} ({sum(said) / len(said):.0%})"
 
 
+def idle_intervals(launches, min_gap: float = IDLE_GAP_S) -> list:
+    """[(start, end, the launch that ended it)]: the intervals of at least
+    ``min_gap`` seconds, between the first launch and the last, in which
+    no launch of verifyd's log was in flight."""
+    spans = sorted(
+        (
+            (e["t_dev"], e["t_dev"] + e["dispatch_s"] + e["wait_s"], e)
+            for e in launches
+            if all(isinstance(e.get(k), (int, float)) for k in ("t_dev", "dispatch_s", "wait_s"))
+        ),
+        key=lambda s: s[0],
+    )
+    gaps = []
+    busy_until = spans[0][1] if spans else 0.0
+    for start, end, e in spans[1:]:
+        if start - busy_until >= min_gap:
+            gaps.append((busy_until, start, e))
+        busy_until = max(busy_until, end)
+    return gaps
+
+
+def loop_between(lines, start: float, end: float):
+    """What one replica's loop spent, by stage, between the last of its
+    lines at or before ``start`` and the first at or after ``end``
+    (``lines``: its verify_batch lines that carry ``loop_us``, by ts):
+    (bracket's length in seconds, {stage: microseconds}); None where the
+    interval lies before its first line or after its last."""
+    stamps = [e["ts"] for e in lines]
+    lo = bisect.bisect_right(stamps, start) - 1
+    hi = bisect.bisect_left(stamps, end)
+    if lo < 0 or hi >= len(lines):
+        return None
+    a, b = lines[lo], lines[hi]
+    return b["ts"] - a["ts"], {
+        stage: after - before
+        for stage, before, after in zip(LOOP_STAGES, a["loop_us"], b["loop_us"])
+    }
+
+
+def idle_table(launches, by_replica, top: int = 0) -> list:
+    """One row an idle interval (``idle_intervals``), longest first (the
+    ``top`` longest where given): its start and length, the launch that
+    ended it, each replica's loop between its bracketing lines, and to
+    what the interval is put down."""
+    rows = []
+    for start, end, ender in idle_intervals(launches):
+        gap = end - start
+        loops = {
+            rid: loop_between(lines, start, end) for rid, lines in sorted(by_replica.items())
+        }
+        pooled = Counter()
+        for found in loops.values():
+            if found:
+                pooled.update(found[1])
+        # The part of the gap during which verifyd HAD a request and sat
+        # on it: the oldest request of the ending launch's window arrived
+        # queue_s before the cut, and the cut came slot_s + pad_s before
+        # the first dispatch.
+        held = min(gap, sum(ender.get(k, 0.0) for k in ("queue_s", "slot_s", "pad_s")))
+        spent = sum(pooled.values())
+        if held >= gap / 2:
+            verdict = "verifyd's hold"
+        elif not spent:
+            verdict = "no replica line brackets it"
+        elif pooled["wait"] >= spent / 2:
+            verdict = "replicas in wait (the chip waits for the clients)"
+        else:
+            work = max((s for s in LOOP_STAGES if s != "wait"), key=lambda s: pooled[s])
+            verdict = f"replicas at work: {work}"
+        rows.append({
+            "start": start, "gap_s": gap, "held_s": held, "verdict": verdict,
+            "ender": {k: ender.get(k) for k in ("size", "requests", "queue_s", "hold_s")},
+            "loops": loops, "pooled": dict(pooled),
+        })
+    rows.sort(key=lambda r: -r["gap_s"])
+    return rows[:top] if top else rows
+
+
+def print_idle_table(launches, by_replica, top: int = 12) -> list:
+    rows = idle_table(launches, by_replica)
+    if not rows:
+        return rows
+    t_first = min(e["t_dev"] for e in launches if "t_dev" in e)
+    t_last = max(e["ts"] for e in launches)
+    by_verdict = Counter()
+    for r in rows:
+        by_verdict[r["verdict"]] += r["gap_s"]
+    print(
+        f"verifyd idle: {len(rows)} intervals of {1e3 * IDLE_GAP_S:.0f} ms or more with nothing "
+        f"in flight, {sum(r['gap_s'] for r in rows):.3f}s of {t_last - t_first:.3f}s; put down to: "
+        + "; ".join(f"{v} {s:.3f}s" for v, s in by_verdict.most_common())
+    )
+    print(f"the {min(top, len(rows))} longest (a replica's columns: ms between its two "
+          "bracketing lines, then the share of each stage): " + " ".join(LOOP_STAGES))
+    for r in rows[:top]:
+        e = r["ender"]
+        print(
+            f"  at {r['start'] - t_first:9.3f}s idle {1e3 * r['gap_s']:7.2f} ms, ended by a launch of "
+            f"{e['size']} items from {e['requests']} (queue {1e3 * (e['queue_s'] or 0):.2f} ms, hold "
+            f"{1e3 * (e['hold_s'] or 0):.2f} ms, a request waiting {1e3 * r['held_s']:.2f} ms of it): "
+            f"{r['verdict']}"
+        )
+        for rid, found in r["loops"].items():
+            if not found:
+                print(f"      replica {rid}: no bracketing lines")
+                continue
+            secs, split = found
+            all_us = sum(split.values()) or 1
+            print(f"      replica {rid}: {1e3 * secs:7.2f} ms  " + " ".join(
+                f"{split[s] / all_us:.2f}" for s in LOOP_STAGES))
+    return rows
+
+
 def report(files) -> dict:
+    launches: list = []  # verifyd's launch lines, where a log of its is given
+    by_replica: dict = {}  # replica -> its batch lines that carry loop_us
     total = {
         "batches": 0,
         "items": 0,
@@ -145,6 +280,18 @@ def report(files) -> dict:
     for path in files:
         events = load(path)
         vb = [e for e in events if e.get("ev") == "verify_batch"]
+        for e in vb:
+            if "t_dev" in e:
+                launches.append(e)
+            elif isinstance(e.get("loop_us"), list):
+                by_replica.setdefault(e["replica"], []).append(e)
+        applied = sorted(e["apply_s"] for e in vb if "apply_s" in e)
+        if applied:
+            print(
+                f"{path.name}: a batch's verdicts worked through in p50="
+                f"{_pct(applied, 0.5) * 1e3:.2f}ms p90={_pct(applied, 0.9) * 1e3:.2f}ms "
+                f"(apply_s, {len(applied)} kept batches)"
+            )
         # Failed merged windows (service trace): their per-request retries
         # are the verify_batch events; surface the failure count so a run
         # with backend trouble reads as such.
@@ -236,6 +383,10 @@ def report(files) -> dict:
             f"cluster: {total['spans']} consensus spans "
             "(per-(view,seq) breakdowns: scripts/consensus_timeline.py)"
         )
+    if launches and by_replica:
+        for lines in by_replica.values():
+            lines.sort(key=lambda e: e["ts"])
+        total["idle_intervals"] = print_idle_table(launches, by_replica)
     return total
 
 

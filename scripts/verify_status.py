@@ -13,8 +13,9 @@ promoted, the chunk plan (``1025-1280→1024+256`` = a window of that many
 items runs as two launches) and how many windows it split, the running total of each launch stage (queue, slot, pad, put,
 dispatch, wait, unpack), the launches by shape run, by which exit of
 the hold cut their window and by the rows a chip their thinnest chunk
-gave, the slowest launch so far with the step that held it, and the
-device's peak memory.
+gave, the slowest launch so far with the step that held it, the launches
+that stalled (over a second in flight: each left a ``launch_stalled``
+record in the trace and on stderr), and the device's peak memory.
 
     python scripts/verify_status.py                      # default target
     python scripts/verify_status.py 127.0.0.1:7600
@@ -130,6 +131,10 @@ def main(argv=None) -> int:
             "  slowest launch  {secs:.3f}s, {size} items at rung {rung}, longest "
             "step {stage}, {ago_s:.0f}s ago".format(**slowest)
         )
+    if "stalls" in status:
+        print("  stalls          %d launch(es) over a second in flight, the longest "
+              "%.3fs (launch_stalled records: --trace file and stderr)"
+              % (status["stalls"], status.get("longest_stall_s", 0.0)))
     peak = status.get("memory_peak_bytes")
     if peak is not None:
         print(f"  device memory   peak {peak / 2**20:.1f} MiB on the fullest device")
@@ -138,7 +143,7 @@ def main(argv=None) -> int:
         "state", "devices", "uptime_s", "warmed_shapes", "warm_stats",
         "stage_seconds", "slowest_launch", "memory_peak_bytes",
         "promoted_launches", "split_launches", "launches_by_rung", "held_out_launches",
-        "in_step_launches", "launches_by_rows_per_chip",
+        "in_step_launches", "launches_by_rows_per_chip", "stalls", "longest_stall_s",
     }
     for k in sorted(set(status) - known):
         print(f"  {k:<15} {status[k]}")

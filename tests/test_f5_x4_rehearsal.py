@@ -97,7 +97,7 @@ def test_a_traced_rehearsal_reports_every_per_layer_metric_of_the_cell():
     assert from_trace & listed == {
         "kernel_ms_per_launch.closed", "verify_kernel_roofline.closed", "device_idle_pct.closed",
     }
-    assert set(line["metrics"]) == listed - from_trace and len(listed) == 35
+    assert set(line["metrics"]) == listed - from_trace and len(listed) == 44
     value = {k: v["value"] for k, v in line["metrics"].items()}
     assert all(isinstance(v, (int, float)) for v in value.values())
     # The reading that says the cell ran over four chips, and the one that
@@ -117,3 +117,10 @@ def test_a_traced_rehearsal_reports_every_per_layer_metric_of_the_cell():
     # verdicts they keep, and keeping them costs a batch milliseconds at most.
     assert 0 < value["launched_ahead_share.closed"] <= 1
     assert 0 <= value["verdict_held_ms_mean.closed"] < 1000
+    # The primary's loop by kind of work, the span that closes the verify
+    # cycle and the signatures a request costs (ISSUE 38): numbers, not None.
+    assert 0 < value["loop_wait_share.closed"] < 1
+    for stage in ("read", "protocol", "wal", "send", "verify", "other"):
+        assert value[f"loop_{stage}_us_per_req.closed"] > 0, stage
+    assert 0 < value["verdict_apply_ms_mean.closed"] < 1000
+    assert value["signs_per_req.closed"] >= 1.0

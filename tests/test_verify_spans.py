@@ -797,6 +797,78 @@ def test_verify_status_prints_the_stall_fields(capsys):
     out = capsys.readouterr().out
     assert "stage seconds   queue " in out and " slot " in out
     assert "slowest launch" in out and "1 items at rung None" in out
+    assert "stalls          0 launch(es) over a second in flight, the longest 0.000s" in out
+    assert "longest_stall_s" not in out  # printed once, in words
+
+
+def _blocks_for(seconds):
+    def backend(items):
+        time.sleep(seconds)  # the frame the stall record has to show
+        return [True] * len(items)
+
+    return backend
+
+
+@pytest.mark.parametrize("blocked_s, records", [(1.3, 1), (0.2, 0)])
+def test_a_launch_over_a_second_in_flight_leaves_one_stall_record(
+    tmp_path, capfd, blocked_s, records
+):
+    """What the chip process does in a stall (ISSUE 38): a watcher thread
+    writes ONE ``launch_stalled`` record for a launch in flight longer than
+    ``STALL_S``, with the blocked thread's stack in it, every OS thread of
+    the process and what the backend's owner says of its device; one
+    ``launch_stall_ended`` when it returns; counts in the status JSON. A
+    healthy launch leaves nothing."""
+    from pbft_tpu.net import service as service_module
+
+    assert service_module.STALL_S == 1.0 and service_module.STALL_POLL_S == 0.5
+    trace = tmp_path / "launches.jsonl"
+    svc = VerifierService(backend=_blocks_for(blocked_s), trace_path=str(trace)).start()
+    svc.stall_probe = lambda: [{"bytes_in_use": 123}]
+    try:
+        assert _send_batch(svc.address, [_item(1, True), _item(2, True)]) == [True, True]
+        status = svc.launch_status()
+    finally:
+        svc.stop()
+    lines = [json.loads(line) for line in trace.read_text().splitlines()]
+    stalled = [e for e in lines if e["ev"] == "launch_stalled"]
+    ended = [e for e in lines if e["ev"] == "launch_stall_ended"]
+    assert len(stalled) == len(ended) == records
+    assert [e["ev"] for e in lines].count("verify_batch") == 1
+    assert status["stalls"] == records
+    err = capfd.readouterr().err
+    assert err.count("launch_stalled {") == err.count("launch_stall_ended size=2") == records
+    if not records:
+        assert status["longest_stall_s"] == 0.0
+        return
+    (rec,), (end,) = stalled, ended
+    assert set(rec) == trace_schema.EVENT_SCHEMAS["launch_stalled"]["required"] | {"rung", "memory"}
+    assert rec["size"] == 2 and rec["rung"] is None and 1.0 < rec["age_s"] < blocked_s + 0.3
+    # The launch's own thread, blocked where the backend blocks.
+    mine = rec["stacks"][str(rec["thread"])]
+    assert any(f.endswith(" backend") and f.startswith("test_verify_spans.py:") for f in mine["frames"])
+    assert any(" _spanned" in f for f in mine["frames"])
+    assert len(rec["stacks"]) >= 3  # the handler's and the watcher's own are there too
+    # Every OS thread of the process: [tid, name, state, wchan].
+    assert len(rec["tasks"]) >= len(rec["stacks"])
+    assert all(len(t) == 4 and t[2] in "RSDZTtXxKWPI" for t in rec["tasks"])
+    assert rec["memory"] == [{"bytes_in_use": 123}]
+    assert end["size"] == 2 and blocked_s <= end["secs"] < blocked_s + 0.5
+    assert status["longest_stall_s"] == end["secs"]
+
+
+def test_a_device_that_does_not_answer_cannot_hold_the_stall_record_back():
+    svc = VerifierService(backend="cpu").start()
+    try:
+        assert svc._probe_device() is None  # a bare service knows no device
+        svc.stall_probe = lambda: time.sleep(5)
+        t0 = time.monotonic()
+        assert svc._probe_device() == "no answer in 0.5 s"
+        assert time.monotonic() - t0 < 1.5
+        svc.stall_probe = lambda: 1 / 0
+        assert svc._probe_device().startswith("failed: ZeroDivisionError")
+    finally:
+        svc.stop()
 
 
 # -- the replica's two histograms, async branch --------------------------------
@@ -925,6 +997,18 @@ def test_a_served_cluster_launches_ahead_of_kept_verdicts(tmp_path, capsys):
     for i, m in enumerate(scrapes):
         lines = _lines(tmp_path / f"replica-{i}.jsonl")
         assert all(e["ahead"] in (0, 1) for e in lines)
+        # ... how long working through its verdicts took, and where the loop's
+        # time had gone by then: seven running totals that never fall, the
+        # line written when the apply ended (ISSUE 38).
+        assert all(0 <= e["apply_s"] < 30 and len(e["loop_us"]) == 7 for e in lines)
+        for before, after in zip(lines, lines[1:]):
+            assert all(x <= y for x, y in zip(before["loop_us"], after["loop_us"]))
+            grew = 1e-6 * (sum(after["loop_us"]) - sum(before["loop_us"]))
+            assert abs(grew - (after["ts"] - before["ts"])) < 0.002  # two lines bracket an interval
+        assert m[("pbft_verdict_apply_seconds_count", "")] == m[("pbft_verify_batches_total", "")]
+        assert sum(m[(f"pbft_loop_{s}_us_total", "")] for s in trace_schema.LOOP_STAGES) == (
+            m[("pbft_loop_us_total", "")]
+        )
         assert sum(e["ahead"] for e in lines) >= m[("pbft_verify_launched_ahead_total", "")]
         said += sum(e["ahead"] for e in lines)
     assert "ahead" in trace_schema.EVENT_SCHEMAS["verify_batch"]["optional"]
