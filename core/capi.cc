@@ -124,6 +124,27 @@ void pbft_test_force_entropy_exhaustion(int on) {
   pbft::ed25519_test_force_entropy_exhaustion(on != 0);
 }
 
+// Test hooks: the scalar arithmetic mod L (core/ed25519.h), held to
+// Python's `int % L` by tests/test_native_crypto.py.
+void pbft_test_sc_reduce512(uint8_t out[32], const uint8_t in[64]) {
+  pbft::ed25519_test_sc_reduce512(out, in);
+}
+
+void pbft_test_sc_muladd(uint8_t out[32], const uint8_t a[32],
+                         const uint8_t b[32], const uint8_t c[32]) {
+  pbft::ed25519_test_sc_muladd(out, a, b, c);
+}
+
+void pbft_test_sc_muladd128(uint8_t out[32], const uint8_t a[16],
+                            const uint8_t b[32], const uint8_t c[32]) {
+  pbft::ed25519_test_sc_muladd128(out, a, b, c);
+}
+
+void pbft_test_sc_add(uint8_t out[32], const uint8_t a[32],
+                      const uint8_t b[32]) {
+  pbft::ed25519_test_sc_add(out, a, b);
+}
+
 // Per-key decompressed-point cache controls (window-prep memoization):
 // clear drops entries; disable forces the cold path. The Python parity
 // test pins warm/cold verdict equality through these.

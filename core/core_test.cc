@@ -9,6 +9,7 @@
 #include <sys/stat.h>
 #include <unistd.h>
 
+#include <array>
 #include <chrono>
 #include <cstdio>
 #include <cstdlib>
@@ -77,6 +78,248 @@ void test_ed25519_rfc8032() {
   CHECK(pbft::ed25519_verify(pub, nullptr, 0, sig));
   sig[0] ^= 1;
   CHECK(!pbft::ed25519_verify(pub, nullptr, 0, sig));
+}
+
+// --- ISSUE 39: the scalar arithmetic mod L ----------------------------------
+// The plain reference: the bit-serial long division that WAS the library's
+// reduction up to PR 38 (one compare-and-subtract of L << shift a bit of the
+// quotient), kept here to hold the word-level reduction to, bit for bit.
+using u64 = uint64_t;
+using u128 = unsigned __int128;
+
+constexpr u64 kRefL[4] = {0x5812631a5cf5d3edULL, 0x14def9dea2f79cd6ULL, 0ULL,
+                          0x1000000000000000ULL};
+
+// x -= L << bitshift when that keeps x >= 0 (x: n 64-bit LE limbs).
+bool ref_sub_l_shifted_if_ge(u64* x, int n, int bitshift) {
+  u64 tmp[12];
+  std::memcpy(tmp, x, n * 8);
+  int limb = bitshift / 64, off = bitshift % 64;
+  u128 borrow = 0;
+  for (int i = 0; i < n; ++i) {
+    u128 sub = borrow;
+    int j = i - limb;
+    u64 part = 0;
+    if (j >= 0 && j < 4) part = kRefL[j] << off;
+    if (off && j - 1 >= 0 && j - 1 < 4) part |= kRefL[j - 1] >> (64 - off);
+    sub += part;
+    u128 cur = (u128)tmp[i];
+    if (cur >= sub) {
+      tmp[i] = (u64)(cur - sub);
+      borrow = 0;
+    } else {
+      tmp[i] = (u64)(cur + (((u128)1) << 64) - sub);
+      borrow = 1;
+    }
+  }
+  if (borrow) return false;
+  std::memcpy(x, tmp, n * 8);
+  return true;
+}
+
+// 512-bit value mod L: L's top bit is 2^252, so shifts 259..0 suffice.
+void ref_reduce512(u64 out[4], const u64 in[8]) {
+  u64 x[12] = {0};
+  std::memcpy(x, in, 64);
+  for (int shift = 259; shift >= 0; --shift) ref_sub_l_shifted_if_ge(x, 12, shift);
+  std::memcpy(out, x, 32);
+}
+
+// (a*b + c) mod L, a of na limbs: schoolbook product, then the division.
+void ref_muladd(u64 out[4], const u64* a, int na, const u64 b[4],
+                const u64 c[4]) {
+  u64 wide[9] = {0};
+  for (int i = 0; i < na; ++i) {
+    for (int j = 0; j < 4; ++j) {
+      u128 cur = (u128)a[i] * b[j];
+      for (int k = i + j; cur; ++k) {
+        cur += wide[k];
+        wide[k] = (u64)cur;
+        cur >>= 64;
+      }
+    }
+  }
+  u128 carry = 0;
+  for (int k = 0; k < 9; ++k) {
+    carry += (u128)wide[k] + (k < 4 ? c[k] : 0);
+    wide[k] = (u64)carry;
+    carry >>= 64;
+  }
+  CHECK(wide[8] == 0);  // a*b + c < 2^512 for any operands
+  ref_reduce512(out, wide);
+}
+
+struct SplitMix {  // seeded, so a failure repeats
+  u64 s;
+  u64 next() {
+    u64 z = (s += 0x9e3779b97f4a7c15ULL);
+    z = (z ^ (z >> 30)) * 0xbf58476d1ce4e5b9ULL;
+    z = (z ^ (z >> 27)) * 0x94d049bb133111ebULL;
+    return z ^ (z >> 31);
+  }
+};
+
+// One value through the library's reduction and the reference's.
+void check_reduce512(const u64 x[8]) {
+  u64 want[4], got[4];
+  uint8_t in[64], out[32];
+  ref_reduce512(want, x);
+  std::memcpy(in, x, 64);
+  pbft::ed25519_test_sc_reduce512(out, in);
+  std::memcpy(got, out, 32);
+  CHECK(std::memcmp(got, want, 32) == 0);
+}
+
+// x += 1 (up) or x -= 1 over n limbs, wrapping.
+void limbs_step(u64* x, int n, bool up) {
+  for (int i = 0; i < n; ++i) {
+    if (up ? ++x[i] != 0 : x[i]-- != 0) return;
+  }
+}
+
+void test_scalar_reduction_vs_long_division() {
+  // The edges: around every multiple of L that a limb boundary or a bound
+  // in the library's comments names.
+  std::vector<std::array<u64, 8>> edges;
+  auto around = [&](std::array<u64, 8> x) {  // x - 1, x, x + 1
+    limbs_step(x.data(), 8, false);
+    for (int k = 0; k < 3; ++k) {
+      edges.push_back(x);
+      limbs_step(x.data(), 8, true);
+    }
+  };
+  std::array<u64, 8> zero{}, l{}, l2{}, ones;
+  ones.fill(~0ULL);
+  std::memcpy(l.data(), kRefL, 32);
+  for (int i = 0; i < 4; ++i) {
+    l2[i] = kRefL[i] << 1 | (i ? kRefL[i - 1] >> 63 : 0);
+  }
+  around(zero);  // 2^512 - 1, 0, 1
+  around(l);
+  around(l2);
+  for (int bit : {64, 128, 192, 252, 253, 256, 320, 383, 384, 448, 511}) {
+    std::array<u64, 8> p{};
+    p[bit / 64] = 1ULL << (bit % 64);
+    around(p);
+  }
+  {  // k*L for the largest k that fits 512 bits
+    u64 r[4];
+    ref_reduce512(r, ones.data());
+    std::array<u64, 8> kl = ones;
+    u64 borrow = 0;
+    for (int i = 0; i < 8; ++i) {
+      u128 cur = (u128)kl[i] - (i < 4 ? r[i] : 0) - borrow;
+      kl[i] = (u64)cur;
+      borrow = (u64)(cur >> 64) & 1;
+    }
+    around(kl);
+  }
+  for (int hi = 1; hi <= 8; ++hi) {  // all-ones limbs, low and high runs
+    std::array<u64, 8> lo_run{}, hi_run{};
+    for (int i = 0; i < hi; ++i) lo_run[i] = hi_run[7 - i] = ~0ULL;
+    edges.push_back(lo_run);
+    edges.push_back(hi_run);
+  }
+  for (const auto& e : edges) check_reduce512(e.data());
+
+  SplitMix rng{0x39};
+  for (int i = 0; i < 20000; ++i) {
+    u64 x[8];
+    for (u64& w : x) w = rng.next();
+    if (i % 4 == 1) x[7] = x[6] = 0;            // a 384-bit product
+    if (i % 4 == 2) x[7] = ~0ULL;               // the largest quotients
+    if (i % 4 == 3) x[rng.next() % 8] = 0;      // a zero limb in the chain
+    check_reduce512(x);
+  }
+
+  // a*b + c in both widths of a, and a + b, on operands at the edges of
+  // 256 bits and at random.
+  for (int i = 0; i < 6000; ++i) {
+    u64 a[4], b[4], c[4], want[4];
+    uint8_t ab[32], bb[32], cb[32], out[32];
+    for (int k = 0; k < 4; ++k) {
+      a[k] = rng.next();
+      b[k] = rng.next();
+      c[k] = rng.next();
+    }
+    if (i % 5 == 1) std::memset(a, 0xff, 32);
+    if (i % 5 == 2) std::memset(b, 0xff, 32), std::memset(c, 0xff, 32);
+    if (i < 5) std::memset(a, 0xff, 32), std::memset(b, 0xff, 32);
+    if (i == 0) std::memset(c, 0xff, 32);  // (2^256-1)^2 + 2^256-1: the bound
+    std::memcpy(ab, a, 32);
+    std::memcpy(bb, b, 32);
+    std::memcpy(cb, c, 32);
+    ref_muladd(want, a, 4, b, c);
+    pbft::ed25519_test_sc_muladd(out, ab, bb, cb);
+    CHECK(std::memcmp(out, want, 32) == 0);
+    ref_muladd(want, a, 2, b, c);
+    pbft::ed25519_test_sc_muladd128(out, ab, bb, cb);
+    CHECK(std::memcmp(out, want, 32) == 0);
+    // a + b for reduced operands (the library's precondition): the
+    // reference is the 512-bit division of the plain sum.
+    u64 ar[8] = {0}, br[8] = {0}, sum[8] = {0};
+    std::memcpy(ar, a, 32);
+    std::memcpy(br, b, 32);
+    if (i % 7 == 3) {  // L - 1 + L - 1: the largest sum
+      std::memcpy(ar, kRefL, 32);
+      std::memcpy(br, kRefL, 32);
+      limbs_step(ar, 8, false);
+      limbs_step(br, 8, false);
+    }
+    ref_reduce512(ar, ar);
+    ref_reduce512(br, br);
+    u128 carry = 0;
+    for (int k = 0; k < 5; ++k) {
+      carry += (u128)ar[k] + br[k];
+      sum[k] = (u64)carry;
+      carry >>= 64;
+    }
+    ref_reduce512(want, sum);
+    std::memcpy(ab, ar, 32);
+    std::memcpy(bb, br, 32);
+    pbft::ed25519_test_sc_add(out, ab, bb);
+    CHECK(std::memcmp(out, want, 32) == 0);
+  }
+}
+
+// What the host crypto costs on the host at hand (PERF.md section 5
+// carries the chip host's figures; pbft_signs_total counts the first).
+void test_crypto_yardstick() {
+  using clock = std::chrono::steady_clock;
+  auto us_each = [](clock::time_point t0, int n) {
+    return std::chrono::duration<double, std::micro>(clock::now() - t0)
+               .count() /
+           n;
+  };
+  uint8_t seed[32] = {7}, pub[32], digest[32] = {9}, sig[64];
+  pbft::ed25519_public_key(pub, seed);
+  const int kSigns = 2000;
+  auto t0 = clock::now();
+  for (int i = 0; i < kSigns; ++i) {
+    digest[0] = (uint8_t)i;
+    pbft::ed25519_sign(sig, seed, digest, 32);
+  }
+  std::printf("ed25519_sign: %.1f us a signature\n", us_each(t0, kSigns));
+  const int kVerifies = 500;
+  int ok = 0;
+  t0 = clock::now();
+  for (int i = 0; i < kVerifies; ++i) {
+    ok += pbft::ed25519_verify(pub, digest, 32, sig);
+  }
+  std::printf("ed25519_verify: %.1f us a verification\n",
+              us_each(t0, kVerifies));
+  CHECK(ok == kVerifies);
+  const int kReductions = 20000;
+  uint8_t wide[64], out[32] = {1};
+  std::memset(wide, 0xa5, sizeof(wide));
+  t0 = clock::now();
+  for (int i = 0; i < kReductions; ++i) {
+    std::memcpy(wide, out, 32);  // each input hangs on the last output
+    pbft::ed25519_test_sc_reduce512(out, wide);
+  }
+  std::printf("sc_reduce512: %.3f us a 512-bit reduction mod L\n",
+              us_each(t0, kReductions));
+  CHECK((out[31] & 0xe0) == 0);  // under 2^253
 }
 
 void test_canonical_json() {
@@ -1376,20 +1619,6 @@ void test_loop_clock_unit() {
   CHECK(bench.switches == 2 * kPairs);
   std::printf("loop clock: %.1f ns a stage switch\n", ns_a_switch);
   CHECK(ns_a_switch < 2000);  // a vDSO clock read, not a system call gone wrong
-
-  // What one signature costs the host (pbft_signs_total counts them).
-  uint8_t seed[32] = {7}, digest[32] = {9}, sig[64];
-  const int kSigns = 200;
-  const auto s0 = std::chrono::steady_clock::now();
-  for (int i = 0; i < kSigns; ++i) {
-    digest[0] = (uint8_t)i;
-    pbft::ed25519_sign(sig, seed, digest, 32);
-  }
-  std::printf("ed25519_sign: %.1f us a signature\n",
-              std::chrono::duration<double, std::micro>(
-                  std::chrono::steady_clock::now() - s0)
-                      .count() /
-                  kSigns);
 }
 
 void test_loop_clock_on_the_loop() {
@@ -1695,6 +1924,8 @@ int main() {
   test_sha512_vectors();
   test_blake2b_vector();
   test_ed25519_rfc8032();
+  test_scalar_reduction_vs_long_division();
+  test_crypto_yardstick();
   test_canonical_json();
   test_secure_channel_native();
   test_four_replica_commit();

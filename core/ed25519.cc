@@ -314,45 +314,58 @@ void ge_compress(uint8_t s[32], const ge& p) {
 
 constexpr u64 kL[4] = {0x5812631a5cf5d3edULL, 0x14def9dea2f79cd6ULL, 0ULL,
                        0x1000000000000000ULL};
+// floor(2^512 / L), 260 bits: sc_reduce512's Barrett reciprocal.
+constexpr u64 kMu[5] = {0xed9ce5a30a2c131bULL, 0x2106215d086329a7ULL,
+                        0xffffffffffffffebULL, 0xffffffffffffffffULL, 0xfULL};
 
-// x -= L << bitshift when that keeps x >= 0 (x: n 64-bit LE limbs).
-// Returns whether the subtraction happened.
-bool sub_l_shifted_if_ge(u64* x, int n, int bitshift) {
-  u64 tmp[12];
-  std::memcpy(tmp, x, n * 8);
-  int limb = bitshift / 64, off = bitshift % 64;
-  u128 borrow = 0;
-  for (int i = 0; i < n; ++i) {
-    u128 sub = borrow;
-    int j = i - limb;
-    u64 part = 0;
-    if (j >= 0 && j < 4) part = kL[j] << off;
-    if (off && j - 1 >= 0 && j - 1 < 4) part |= kL[j - 1] >> (64 - off);
-    sub += part;
-    u128 cur = (u128)tmp[i];
-    if (cur >= sub) {
-      tmp[i] = (u64)(cur - sub);
-      borrow = 0;
-    } else {
-      tmp[i] = (u64)(cur + (((u128)1) << 64) - sub);
-      borrow = 1;
+// out = a * b, 64-bit LE limbs: na + nb of them, all written.
+inline void limbs_mul(u64* out, const u64* a, int na, const u64* b, int nb) {
+  for (int j = 0; j < nb; ++j) out[j] = 0;
+  for (int i = 0; i < na; ++i) {
+    u128 carry = 0;
+    for (int j = 0; j < nb; ++j) {
+      u128 cur = (u128)out[i + j] + (u128)a[i] * b[j] + carry;
+      out[i + j] = (u64)cur;
+      carry = cur >> 64;
     }
+    out[i + nb] = (u64)carry;
   }
-  if (borrow) return false;
-  std::memcpy(x, tmp, n * 8);
-  return true;
 }
 
-// 512-bit (8 limb) value -> 256-bit scalar mod L (4 limbs). Binary long
-// division: L's top bit is 2^252, input < 2^512, so shifts 259..0 suffice.
-void sc_reduce512(u64 out[4], const u64 in[8]) {
-  u64 x[12];
-  std::memcpy(x, in, 64);
-  std::memset(x + 8, 0, 32);
-  for (int shift = 259; shift >= 0; --shift) {
-    sub_l_shifted_if_ge(x, 12, shift);
+// out = a - b over four limbs; returns the borrow out of the top one.
+inline u64 limbs_sub4(u64 out[4], const u64 a[4], const u64 b[4]) {
+  u64 borrow = 0;
+  for (int i = 0; i < 4; ++i) {
+    u128 d = (u128)a[i] - b[i] - borrow;
+    out[i] = (u64)d;
+    borrow = (u64)(d >> 64) & 1;
   }
-  std::memcpy(out, x, 32);
+  return borrow;
+}
+
+// x -= L when that keeps x >= 0 (x: 4 limbs), by a mask: no branch on
+// the value.
+inline void sc_sub_l_if_ge(u64 x[4]) {
+  u64 t[4];
+  const u64 keep = 0 - limbs_sub4(t, x, kL);  // all ones when x < L
+  for (int i = 0; i < 4; ++i) x[i] = (x[i] & keep) | (t[i] & ~keep);
+}
+
+// 512-bit (8 limb) value -> 256-bit scalar mod L (4 limbs). Barrett
+// reduction (Handbook of Applied Cryptography 14.42) in base b = 2^64 with
+// k = 4 limbs (b^3 <= L < b^4): q3 = floor(floor(x / b^3) * mu / b^5) is
+// floor(x / L) or up to 2 under it for every x < b^8, so r = x - q3 * L
+// lies in [0, 3L). 3L < 2^254, so r is exact in its low four limbs (the
+// higher limbs of x and of q3 * L cancel: q3's fifth limb is not even
+// multiplied), and two conditional subtractions of L finish. A 5x5 and a
+// 4x4 limb product, whatever the value.
+void sc_reduce512(u64 out[4], const u64 in[8]) {
+  u64 q2[10], ql[8];
+  limbs_mul(q2, in + 3, 5, kMu, 5);
+  limbs_mul(ql, q2 + 5, 4, kL, 4);  // q3 * L; its low four limbs are used
+  limbs_sub4(out, in, ql);
+  sc_sub_l_if_ge(out);
+  sc_sub_l_if_ge(out);
 }
 
 bool sc_lt_l(const u64 s[4]) {
@@ -369,77 +382,45 @@ void sc_from_bytes(u64 out[4], const uint8_t b[32]) {
 
 void sc_to_bytes(uint8_t out[32], const u64 s[4]) { std::memcpy(out, s, 32); }
 
-// (a*b + c) mod L with a < 2^128 (the batch-verification coefficient
-// path): the 384-bit product needs half the division shifts of the
-// general 512-bit reduction, and it runs three times per batched item.
-void sc_muladd128(u64 out[4], const u64 a[2], const u64 b[4],
-                  const u64 c[4]) {
-  u64 wide[7] = {0};
-  for (int i = 0; i < 2; ++i) {
-    u128 carry = 0;
-    for (int j = 0; j < 4; ++j) {
-      u128 cur = (u128)wide[i + j] + (u128)a[i] * b[j] + carry;
-      wide[i + j] = (u64)cur;
-      carry = cur >> 64;
-    }
-    wide[i + 4] += (u64)carry;
-  }
-  u128 carry = 0;
-  for (int i = 0; i < 4; ++i) {
-    u128 cur = (u128)wide[i] + c[i] + carry;
+// (a*b + c) mod L, a of na <= 4 limbs, b and c of 4. The intermediate is
+// at most (2^256 - 1)^2 + 2^256 - 1 < 2^512: it fits sc_reduce512's eight
+// limbs for any operands, reduced or not.
+void sc_muladd_limbs(u64 out[4], const u64* a, int na, const u64 b[4],
+                     const u64 c[4]) {
+  u64 wide[8] = {0};
+  limbs_mul(wide, a, na, b, 4);
+  u64 carry = 0;
+  for (int i = 0; i < 8; ++i) {
+    u128 cur = (u128)wide[i] + (i < 4 ? c[i] : 0) + carry;
     wide[i] = (u64)cur;
-    carry = cur >> 64;
+    carry = (u64)(cur >> 64);
   }
-  for (int i = 4; i < 7 && carry; ++i) {
-    u128 cur = (u128)wide[i] + carry;
-    wide[i] = (u64)cur;
-    carry = cur >> 64;
-  }
-  // wide < 2^382 + 2^253 < 2^383; L's top bit is 2^252.
-  for (int shift = 131; shift >= 0; --shift) {
-    sub_l_shifted_if_ge(wide, 7, shift);
-  }
-  std::memcpy(out, wide, 32);
-}
-
-// (a + b) mod L, both inputs < L.
-void sc_add(u64 out[4], const u64 a[4], const u64 b[4]) {
-  u64 x[5];
-  u128 carry = 0;
-  for (int i = 0; i < 4; ++i) {
-    u128 cur = (u128)a[i] + b[i] + carry;
-    x[i] = (u64)cur;
-    carry = cur >> 64;
-  }
-  x[4] = (u64)carry;
-  sub_l_shifted_if_ge(x, 5, 0);  // sum < 2L: one conditional subtract
-  std::memcpy(out, x, 32);
+  sc_reduce512(out, wide);
 }
 
 // (a*b + c) mod L for signing.
 void sc_muladd(u64 out[4], const u64 a[4], const u64 b[4], const u64 c[4]) {
-  u64 wide[8] = {0};
+  sc_muladd_limbs(out, a, 4, b, c);
+}
+
+// (a*b + c) mod L with a < 2^128 (the batch-verification coefficient
+// path, three times per batched item): half the product's multiplications;
+// a*b + c < 2^384 + 2^256 goes through the same reduction.
+void sc_muladd128(u64 out[4], const u64 a[2], const u64 b[4],
+                  const u64 c[4]) {
+  sc_muladd_limbs(out, a, 2, b, c);
+}
+
+// (a + b) mod L, both inputs < L: the sum is under 2L < 2^254, four limbs
+// and one conditional subtraction.
+void sc_add(u64 out[4], const u64 a[4], const u64 b[4]) {
+  u64 carry = 0;
   for (int i = 0; i < 4; ++i) {
-    u128 carry = 0;
-    for (int j = 0; j < 4; ++j) {
-      u128 cur = (u128)wide[i + j] + (u128)a[i] * b[j] + carry;
-      wide[i + j] = (u64)cur;
-      carry = cur >> 64;
-    }
-    wide[i + 4] += (u64)carry;
+    u128 cur = (u128)a[i] + b[i] + carry;
+    out[i] = (u64)cur;
+    carry = (u64)(cur >> 64);
   }
-  u128 carry = 0;
-  for (int i = 0; i < 4; ++i) {
-    u128 cur = (u128)wide[i] + c[i] + carry;
-    wide[i] = (u64)cur;
-    carry = cur >> 64;
-  }
-  for (int i = 4; i < 8 && carry; ++i) {
-    u128 cur = (u128)wide[i] + carry;
-    wide[i] = (u64)cur;
-    carry = cur >> 64;
-  }
-  sc_reduce512(out, wide);
+  sc_sub_l_if_ge(out);
 }
 
 // ---------------------------------------------------------------------------
@@ -476,7 +457,9 @@ ge double_scalar_mult(const u64 s1[4], const ge& q, const u64 s2[4]) {
 
 // kComb[i][v] = [v * 2^(8i)]B: fixed-base scalar multiplication as 31
 // table additions and zero doublings. ~1.3 MB, built once on first use
-// (~8k additions, a few ms); sign/keygen go from a full ladder to ~10 us.
+// (~8k additions, a few ms); the multiplication goes from a full ladder to
+// ~10 us, which with ge_compress's inversion (~6 us) is nearly all of a
+// signature (core_test prints what one costs on the host at hand).
 const ge* comb_table() {
   static const std::vector<ge> t = [] {
     std::vector<ge> v(32 * 256);
@@ -513,15 +496,23 @@ void expand_seed(u64 a_sc[4], uint8_t prefix[32], const uint8_t seed[32]) {
 
 void hash_to_scalar(u64 out[4], const uint8_t* p1, const uint8_t* p2,
                     const uint8_t* p3, size_t n3) {
-  // SHA512(p1 || p2 || p3) mod L, p1/p2 32 bytes each (or p2 null).
-  // The message length is caller-controlled (public C ABI) — heap buffer.
-  std::vector<uint8_t> buf;
-  buf.reserve(64 + n3);
-  buf.insert(buf.end(), p1, p1 + 32);
-  if (p2) buf.insert(buf.end(), p2, p2 + 32);
-  buf.insert(buf.end(), p3, p3 + n3);
+  // SHA512(p1 || p2 || p3) mod L, p1/p2 32 bytes each (or p2 null). What
+  // the protocol signs is a 32-byte digest, 64 or 96 bytes here: the
+  // stack. The message length is caller-controlled (public C ABI): the
+  // heap beyond that.
+  const size_t head = p2 ? 64 : 32, n = head + n3;
+  uint8_t stack[128];
+  std::vector<uint8_t> heap;
+  uint8_t* buf = stack;
+  if (n > sizeof(stack)) {
+    heap.resize(n);
+    buf = heap.data();
+  }
+  std::memcpy(buf, p1, 32);
+  if (p2) std::memcpy(buf + 32, p2, 32);
+  if (n3) std::memcpy(buf + head, p3, n3);
   uint8_t h[64];
-  sha512(h, buf.data(), buf.size());
+  sha512(h, buf, n);
   u64 wide[8];
   std::memcpy(wide, h, 64);
   sc_reduce512(out, wide);
@@ -895,6 +886,42 @@ void batch_bisect(const std::vector<BatchPrep>& prep,
 
 void ed25519_test_force_entropy_exhaustion(bool on) {
   g_force_entropy_exhaustion.store(on, std::memory_order_relaxed);
+}
+
+void ed25519_test_sc_reduce512(uint8_t out[32], const uint8_t in[64]) {
+  u64 x[8], r[4];
+  std::memcpy(x, in, 64);
+  sc_reduce512(r, x);
+  sc_to_bytes(out, r);
+}
+
+void ed25519_test_sc_muladd(uint8_t out[32], const uint8_t a[32],
+                            const uint8_t b[32], const uint8_t c[32]) {
+  u64 x[4], y[4], z[4], r[4];
+  sc_from_bytes(x, a);
+  sc_from_bytes(y, b);
+  sc_from_bytes(z, c);
+  sc_muladd(r, x, y, z);
+  sc_to_bytes(out, r);
+}
+
+void ed25519_test_sc_muladd128(uint8_t out[32], const uint8_t a[16],
+                               const uint8_t b[32], const uint8_t c[32]) {
+  u64 x[2], y[4], z[4], r[4];
+  std::memcpy(x, a, 16);
+  sc_from_bytes(y, b);
+  sc_from_bytes(z, c);
+  sc_muladd128(r, x, y, z);
+  sc_to_bytes(out, r);
+}
+
+void ed25519_test_sc_add(uint8_t out[32], const uint8_t a[32],
+                         const uint8_t b[32]) {
+  u64 x[4], y[4], r[4];
+  sc_from_bytes(x, a);
+  sc_from_bytes(y, b);
+  sc_add(r, x, y);
+  sc_to_bytes(out, r);
 }
 
 void ed25519_pubkey_cache_clear() {

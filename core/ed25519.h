@@ -59,6 +59,20 @@ constexpr size_t kEd25519RlcWindowItems = 256;
 // production.
 void ed25519_test_force_entropy_exhaustion(bool on);
 
+// Test hooks: the scalar arithmetic mod L behind sign, verify and the batch
+// path, on little-endian bytes, for the differential tests against
+// Python's `int % L` (tests/test_native_crypto.py) and against bit-serial
+// long division (core_test.cc). reduce512: a 64-byte value mod L.
+// muladd: (a*b + c) mod L, any 256-bit operands. muladd128: the same with a
+// 16-byte a (the batch coefficients). add: (a + b) mod L, a and b < L.
+void ed25519_test_sc_reduce512(uint8_t out[32], const uint8_t in[64]);
+void ed25519_test_sc_muladd(uint8_t out[32], const uint8_t a[32],
+                            const uint8_t b[32], const uint8_t c[32]);
+void ed25519_test_sc_muladd128(uint8_t out[32], const uint8_t a[16],
+                               const uint8_t b[32], const uint8_t c[32]);
+void ed25519_test_sc_add(uint8_t out[32], const uint8_t a[32],
+                         const uint8_t b[32]);
+
 // Per-key decompressed-point cache controls (window-prep memoization of
 // pubkey decompression; see ed25519.cc). Clear drops all entries; the
 // disable hook forces the cold path — tests/test_verify_pool.py pins

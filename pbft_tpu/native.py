@@ -284,6 +284,33 @@ def force_entropy_exhaustion(on: bool) -> None:
     lib().pbft_test_force_entropy_exhaustion(ctypes.c_int(1 if on else 0))
 
 
+def _sc_call(name: str, *operands: Tuple[int, int]) -> int:
+    """One scalar test hook on (value, width in bytes) operands."""
+    out = ctypes.create_string_buffer(32)
+    getattr(lib(), name)(out, *(v.to_bytes(n, "little") for v, n in operands))
+    return int.from_bytes(out.raw, "little")
+
+
+def sc_reduce512(x: int) -> int:
+    """TEST hook: a 512-bit value mod L through the native reduction."""
+    return _sc_call("pbft_test_sc_reduce512", (x, 64))
+
+
+def sc_muladd(a: int, b: int, c: int) -> int:
+    """TEST hook: (a*b + c) mod L, any 256-bit operands (signing's form)."""
+    return _sc_call("pbft_test_sc_muladd", (a, 32), (b, 32), (c, 32))
+
+
+def sc_muladd128(a: int, b: int, c: int) -> int:
+    """TEST hook: (a*b + c) mod L with a < 2^128 (the batch coefficients)."""
+    return _sc_call("pbft_test_sc_muladd128", (a, 16), (b, 32), (c, 32))
+
+
+def sc_add(a: int, b: int) -> int:
+    """TEST hook: (a + b) mod L for a, b < L."""
+    return _sc_call("pbft_test_sc_add", (a, 32), (b, 32))
+
+
 def pubkey_cache_clear() -> None:
     """Drop every entry in the native per-key decompressed-point cache."""
     lib().pbft_pubkey_cache_clear()

@@ -2,6 +2,8 @@
 oracle — SURVEY.md §4 item 3, native edition."""
 
 import hashlib
+import itertools
+import random
 import secrets
 
 import subprocess
@@ -53,17 +55,120 @@ def test_rfc8032(seed, pub, msg, sig):
     assert not native.verify(pub, msg + b"x", sig)
 
 
-def test_native_vs_oracle_random():
-    for i in range(6):
-        seed, pub = ref.keygen()
-        msg = secrets.token_bytes(32)
-        assert native.public_key(seed) == pub
+# Message lengths that meet both branches of the native hash_to_scalar (a
+# stack buffer up to 128 bytes of prefix/R/A and message, the heap beyond)
+# and both SHA-512 block counts on either side of 111/112 bytes.
+_ORACLE_MSG_LENS = (0, 1, 32, 111, 112, 300)
+_ORACLE_PAIRS_A_LEN = 34  # 6 x 34 = 204 seed/message pairs
+
+
+@pytest.mark.parametrize("msg_len", _ORACLE_MSG_LENS)
+def test_native_vs_oracle_random(msg_len):
+    """native.sign equals the pure-Python RFC 8032 oracle byte for byte on
+    seeded seed/message pairs; the first pair of each length also goes
+    through key derivation and both verifiers (the oracle is slow)."""
+    rng = random.Random(0x39000 + msg_len)
+    for i in range(_ORACLE_PAIRS_A_LEN):
+        seed, msg = rng.randbytes(32), rng.randbytes(msg_len)
         sig_native = native.sign(seed, msg)
-        assert sig_native == ref.sign(seed, msg)
+        assert sig_native == ref.sign(seed, msg), (seed.hex(), msg.hex())
+        if i:
+            continue
+        pub = ref.public_key(seed)
+        assert native.public_key(seed) == pub
         assert native.verify(pub, msg, sig_native)
         bad = bytes([sig_native[0] ^ 1]) + sig_native[1:]
         assert not native.verify(pub, msg, bad)
         assert native.verify(pub, msg, sig_native) == ref.verify(pub, msg, sig_native)
+
+
+# --- The scalar arithmetic mod L under sign, verify and the batch path, held
+# to Python's `int % L` through the library's test hooks (core/ed25519.h).
+
+_L = ref.L
+_K_MAX = (2**512 - 1) // _L  # the largest multiple of L in 512 bits
+_ONES = 2**64 - 1
+_REDUCE_EDGES = {
+    "0": 0, "1": 1, "L-1": _L - 1, "L": _L, "L+1": _L + 1,
+    "2L-1": 2 * _L - 1, "2L": 2 * _L, "2L+1": 2 * _L + 1,
+    "2^252-1": 2**252 - 1, "2^252": 2**252, "2^253": 2**253,
+    "2^256-1": 2**256 - 1, "2^256": 2**256,
+    "2^383-1": 2**383 - 1,  # the bound sc_muladd128 stated
+    "2^384+2^256": 2**384 + 2**256,
+    "2^512-1": 2**512 - 1,
+    "kL-1": _K_MAX * _L - 1, "kL": _K_MAX * _L, "kL+1": _K_MAX * _L + 1,
+    "(2^256-1)^2+2^256-1": (2**256 - 1) ** 2 + 2**256 - 1,  # a*b + c at most
+    **{f"ones-limbs-0-{n}": 2 ** (64 * n) - 1 for n in range(1, 8)},
+    **{f"ones-limbs-{n}-7": 2**512 - 2 ** (64 * n) for n in range(1, 8)},
+    "ones-odd-limbs": sum(_ONES << (64 * i) for i in (1, 3, 5, 7)),
+    "ones-even-limbs": sum(_ONES << (64 * i) for i in (0, 2, 4, 6)),
+}
+# 256-bit operands at the edges, for a*b + c and a + b.
+_OPERAND_EDGES = (0, 1, 2, _L - 1, _L, _L + 1, 2**252 - 1, 2**252, 2**255 - 1,
+                  2**255, 2**256 - 1, _ONES, _ONES << 192, 2**128 - 1, 2**128)
+
+
+@pytest.mark.parametrize("x", _REDUCE_EDGES.values(), ids=_REDUCE_EDGES.keys())
+def test_sc_reduce512_edge(x):
+    assert native.sc_reduce512(x) == x % _L
+
+
+@pytest.mark.parametrize("near", [1, 2, 3, 2**64, 2**128, 2**192, 2**252, 2**259, _K_MAX // 3, _K_MAX // 2, _K_MAX - 1])
+def test_sc_reduce512_around_multiples_of_l(near):
+    """k*L - 1, k*L, k*L + 1 around every k where the quotient estimate
+    could be one or two short."""
+    for k in range(max(near - 2, 0), min(near + 3, _K_MAX + 1)):
+        for d in (-1, 0, 1):
+            x = k * _L + d
+            if 0 <= x < 2**512:
+                assert native.sc_reduce512(x) == x % _L, (k, d)
+
+
+# primitive -> (operands, their width in bits, operands -> (native, Python)).
+_SC_PRIMITIVES = {
+    "reduce512": (1, 512, lambda x: (native.sc_reduce512(x), x % _L)),
+    "muladd": (3, 256, lambda a, b, c: (native.sc_muladd(a, b, c), (a * b + c) % _L)),
+    "muladd128": (3, 256, lambda a, b, c: (
+        native.sc_muladd128(a % 2**128, b, c), (a % 2**128 * b + c) % _L)),
+    # add's contract is reduced operands.
+    "add": (2, 256, lambda a, b: (native.sc_add(a % _L, b % _L), (a + b) % _L)),
+}
+
+
+@pytest.mark.parametrize("primitive", ["muladd", "muladd128", "add"])
+def test_sc_operand_edges(primitive):
+    arity, _bits, run = _SC_PRIMITIVES[primitive]
+    for operands in itertools.product(_OPERAND_EDGES, repeat=arity):
+        got, want = run(*operands)
+        assert got == want, (primitive, [hex(v) for v in operands])
+
+
+@pytest.mark.parametrize("primitive", _SC_PRIMITIVES)
+def test_sc_random_matches_python_int(primitive):
+    """10,000 seeded random values a primitive, a quarter of them shaped:
+    short, with a run of ones, or with a zero limb."""
+    arity, bits, run = _SC_PRIMITIVES[primitive]
+    rng = random.Random(f"pr39-{primitive}")
+
+    def value(i):
+        v = rng.getrandbits(bits)
+        shape = i % 16
+        if shape == 1:
+            v >>= rng.randrange(bits)
+        elif shape == 2:
+            v |= (2 ** rng.randrange(1, bits) - 1) << rng.randrange(bits)
+            v %= 2**bits
+        elif shape == 3:
+            v &= ~(_ONES << (64 * rng.randrange(bits // 64)))
+        elif shape == 4 and bits == 512:
+            v = rng.randrange(_K_MAX + 1) * _L + rng.randrange(-2, 3)
+            v = min(max(v, 0), 2**512 - 1)
+        return v
+
+    for i in range(10_000):
+        operands = [value(i) for _ in range(arity)]
+        got, want = run(*operands)
+        assert got == want, (primitive, [hex(v) for v in operands])
 
 
 def test_native_rejects_malleated_s():
