@@ -5,6 +5,7 @@
 // including a view change.
 #include <netinet/in.h>
 #include <poll.h>
+#include <sched.h>
 #include <sys/socket.h>
 #include <sys/stat.h>
 #include <unistd.h>
@@ -28,6 +29,7 @@
 #include "messages.h"
 #include "metrics.h"
 #include "net.h"
+#include "net_shard.h"
 #include "replica.h"
 #include "secure.h"
 #include "sha512.h"
@@ -1338,6 +1340,213 @@ int64_t multicore_round(int net_threads, bool fastpath_mac = false,
   return max_executed;
 }
 
+// ISSUE 40: the front end under a BURST. The test is the gateway: one
+// persistent link a replica (a role=gateway hello, then framed raw-JSON
+// payloads both ways), 1,024 requests of 32 clients written to the
+// primary's link in one go, batches of 32. Up to PR 39 the sharded front
+// end stopped after one or two sequence numbers of such a burst
+// (WakeFd::drain cleared its flag before it emptied the fd, so its read()
+// could swallow the write of a producer that had seen the flag cleared:
+// the flag then stood set over an empty fd, every later wake() returned
+// early, and the consensus thread was never handed the rest). Every
+// request must come back from all four replicas, on one history.
+void multicore_burst(int net_threads) {
+  constexpr int kClients = 32, kEach = 32, kAll = kClients * kEach;
+  int ports[4];
+  std::vector<std::vector<uint8_t>> seeds;
+  pbft::ClusterConfig cfg = loopback_config(91, ports, &seeds);
+  cfg.net_threads = net_threads;
+  cfg.batch_max_items = 32;
+  cfg.batch_flush_us = 2000;
+  std::vector<std::unique_ptr<pbft::ReplicaServer>> servers;
+  for (int i = 0; i < 4; ++i) {
+    servers.push_back(std::make_unique<pbft::ReplicaServer>(
+        cfg, i, seeds[i].data(), std::make_unique<pbft::CpuVerifier>()));
+    servers[i]->metrics().enabled = true;
+    CHECK(servers[i]->start());
+  }
+  std::vector<std::thread> loops;
+  for (int i = 0; i < 4; ++i) {
+    loops.emplace_back([srv = servers[i].get()] { srv->run(); });
+  }
+  auto hello = pbft::Json::parse(pbft::SecureChannel::plain_hello(-1));
+  CHECK(hello.has_value());
+  pbft::JsonObject ho = hello->as_object();
+  ho["role"] = pbft::Json("gateway");
+  const std::string hello_frame = pbft::frame_payload(pbft::Json(ho).dump());
+  int links[4];
+  for (int i = 0; i < 4; ++i) {
+    links[i] = pbft::dial_tcp("127.0.0.1:" + std::to_string(ports[i]));
+    CHECK(links[i] >= 0);
+    CHECK(::send(links[i], hello_frame.data(), hello_frame.size(),
+                 MSG_NOSIGNAL) == (ssize_t)hello_frame.size());
+  }
+  // A send() a request, as a gateway forwards them: the primary's shard
+  // reads them in many small pieces, its pipeline pushes and wakes the
+  // consensus thread while that thread is draining, which is where the
+  // wake was lost.
+  for (int ts = 1; ts <= kEach; ++ts) {
+    for (int c = 0; c < kClients; ++c) {
+      const std::string frame = pbft::frame_payload(
+          "{\"type\":\"client-request\",\"operation\":\"b" +
+          std::to_string(c) + "-" + std::to_string(ts) +
+          "\",\"timestamp\":" + std::to_string(ts) +
+          ",\"client\":\"gw/burst-" + std::to_string(c) + "\"}");
+      CHECK(::send(links[0], frame.data(), frame.size(), MSG_NOSIGNAL) ==
+            (ssize_t)frame.size());
+    }
+  }
+  // (client, timestamp) -> the replicas that answered, read off all four
+  // links (each replica answers on the link its gateway route names, or
+  // fans out over its one gateway link).
+  std::map<std::pair<std::string, int64_t>, std::set<int64_t>> answered;
+  size_t complete = 0;
+  std::string rbuf[4];
+  const auto deadline =
+      std::chrono::steady_clock::now() + std::chrono::seconds(60);
+  while (complete < (size_t)kAll &&
+         std::chrono::steady_clock::now() < deadline) {
+    pollfd pfds[4];
+    for (int i = 0; i < 4; ++i) pfds[i] = pollfd{links[i], POLLIN, 0};
+    if (::poll(pfds, 4, 100) <= 0) continue;
+    for (int i = 0; i < 4; ++i) {
+      if (!(pfds[i].revents & (POLLIN | POLLHUP))) continue;
+      char buf[65536];
+      ssize_t got = ::recv(links[i], buf, sizeof(buf), 0);
+      if (got <= 0) continue;
+      rbuf[i].append(buf, (size_t)got);
+      for (;;) {
+        if (rbuf[i].size() < 4) break;
+        const uint32_t len = ((uint32_t)(uint8_t)rbuf[i][0] << 24) |
+                             ((uint32_t)(uint8_t)rbuf[i][1] << 16) |
+                             ((uint32_t)(uint8_t)rbuf[i][2] << 8) |
+                             (uint32_t)(uint8_t)rbuf[i][3];
+        if (rbuf[i].size() < 4 + (size_t)len) break;
+        auto j = pbft::Json::parse(rbuf[i].substr(4, len));
+        rbuf[i].erase(0, 4 + (size_t)len);
+        if (!j) continue;
+        const pbft::Json* t = j->find("type");
+        if (!t || !t->is_string() || t->as_string() != "client-reply") continue;
+        auto& who = answered[{j->find("client")->as_string(),
+                              j->find("timestamp")->as_int()}];
+        who.insert(j->find("replica")->as_int());
+        if (who.size() == 4) ++complete;
+      }
+    }
+  }
+  CHECK(complete == (size_t)kAll);
+  if (complete != (size_t)kAll) {
+    std::fprintf(stderr, "  net_threads=%d: %zu of %d requests answered by all four\n",
+                 net_threads, complete, kAll);
+  }
+  for (auto& s : servers) s->stop();
+  for (auto& t : loops) t.join();
+  for (int i = 0; i < 4; ++i) ::close(links[i]);
+  std::set<int64_t> upto;
+  std::set<std::string> chains;
+  for (auto& s : servers) {
+    upto.insert(s->replica().executed_upto());
+    chains.insert(s->replica().committed_chain_hex());
+    CHECK(s->replica().view() == 0);
+    CHECK(s->replica().counters["executed"] == kAll);
+  }
+  CHECK(upto.size() == 1 && *upto.begin() >= kAll / 32);
+  CHECK(chains.size() == 1);
+  if (net_threads > 1) {
+    // Nothing was lost at a thread boundary, and the front-end threads'
+    // clocks ran: /status says so by kind and by thread.
+    auto st = pbft::Json::parse(servers[0]->metrics_json());
+    CHECK(st.has_value());
+    const pbft::Json* dropped = st->find("shard_dropped");
+    CHECK(dropped != nullptr);
+    for (const char* kind : {"pipeline", "inbox", "replies"}) {
+      CHECK(dropped->find(kind)->as_int() == 0);
+    }
+    CHECK(st->find("net_threads")->as_int() == net_threads);
+    CHECK((int)st->find("shard_us")->as_array().size() == net_threads);
+    CHECK((int)st->find("pipe_us")->as_array().size() == net_threads);
+  }
+}
+
+// The hand-off's one primitive, alone: a producer that pushes and wakes
+// every microsecond or so (a pipeline parsing a frame between two pushes),
+// a consumer that waits on the fd, drains it and then the queue, each on
+// a CPU of its own where the process may use two. A wake is lost when the
+// consumer's wait times out over a queue that holds something. With
+// drain() clearing its flag BEFORE it empties the fd (the order up to PR
+// 39) that happens within the first cycles on two CPUs (a producer that
+// sees the cleared flag writes the fd, the consumer's read() swallows the
+// write, the flag stands set over an empty fd and every later wake()
+// returns early); in the replica it was the consensus thread never woken
+// again. Two threads the scheduler keeps on ONE CPU never show it.
+void test_wake_fd_keeps_every_wake() {
+  pbft::WakeFd wake;
+  CHECK(wake.open_fds());
+  pbft::CmdQueue<int> q(1u << 22);
+  constexpr int kItems = 200000;
+  cpu_set_t allowed;
+  CPU_ZERO(&allowed);
+  std::vector<int> cpus;
+  if (sched_getaffinity(0, sizeof(allowed), &allowed) == 0) {
+    for (int c = 0; c < CPU_SETSIZE && cpus.size() < 2; ++c) {
+      if (CPU_ISSET(c, &allowed)) cpus.push_back(c);
+    }
+  }
+  auto pin = [&](size_t k) {
+    if (cpus.size() < 2) return;
+    cpu_set_t one;
+    CPU_ZERO(&one);
+    CPU_SET(cpus[k], &one);
+    sched_setaffinity(0, sizeof(one), &one);
+  };
+  std::thread producer([&] {
+    pin(0);
+    for (int i = 0; i < kItems; ++i) {
+      q.push(int(i), /*force=*/true);
+      wake.wake();
+      for (volatile int spin = 0; spin < 300; ++spin) {
+      }
+    }
+  });
+  pin(1);
+  int taken = 0, lost = 0;
+  std::deque<int> out;
+  while (taken < kItems && lost == 0) {
+    pollfd p{wake.fd(), POLLIN, 0};
+    if (::poll(&p, 1, 1000) == 0 && q.size() > 0) ++lost;
+    wake.drain();
+    q.drain(&out);
+    taken += (int)out.size();
+    out.clear();
+  }
+  producer.join();
+  if (cpus.size() == 2) sched_setaffinity(0, sizeof(allowed), &allowed);
+  CHECK(lost == 0);
+  CHECK(wake.wakes() >= 1 && wake.wakes() <= kItems);
+  // The stamp of a drain's oldest entry: set by the push that found the
+  // queue empty, handed back once, cleared by the drain.
+  pbft::CmdQueue<int> stamped(8);
+  using Stamp = pbft::CmdQueue<int>::Stamp;
+  const auto before = std::chrono::steady_clock::now();
+  stamped.push(1, false, /*stamp=*/true);
+  stamped.push(2, false, /*stamp=*/true);
+  Stamp oldest = Stamp::max();
+  stamped.drain(&out, &oldest);
+  CHECK(out.size() == 2 && oldest >= before &&
+        oldest <= std::chrono::steady_clock::now());
+  out.clear();
+  stamped.push(3, false, /*stamp=*/false);
+  oldest = Stamp::max();
+  stamped.drain(&out, &oldest);
+  CHECK(out.size() == 1 && oldest == Stamp::max());
+}
+
+void test_multicore_burst() {
+  multicore_burst(1);
+  multicore_burst(2);
+  multicore_burst(4);
+}
+
 void test_multicore_parity() {
   const int64_t e1 = multicore_round(1);
   const int64_t e2 = multicore_round(2);
@@ -1942,6 +2151,8 @@ int main() {
   test_remote_verifier_readiness();
   test_net_backend_parity();
   test_multicore_parity();
+  test_wake_fd_keeps_every_wake();
+  test_multicore_burst();
   test_loop_launches_ahead_of_kept_verdicts();
   test_loop_wedge_deadline_keeps_order();
   test_loop_transport_failure_keeps_order();

@@ -50,6 +50,15 @@ const char* kCounterNames[] = {
     // Multi-core surface (ISSUE 13): eventfd/pipe wakes crossing the
     // loop-shard / crypto-pipeline / consensus thread boundaries.
     "pbft_cross_thread_wakes_total",
+    // The front-end threads (ISSUE 40): each shard thread's and each
+    // pipeline thread's wall time by kind of work, microseconds, summed
+    // over a replica's shards / pipelines (net_shard.h FrontClock), and
+    // the messages lost at a thread boundary (0 in a healthy run).
+    "pbft_shard_wait_us_total", "pbft_shard_read_us_total",
+    "pbft_shard_send_us_total", "pbft_shard_other_us_total",
+    "pbft_pipe_wait_us_total", "pbft_pipe_decode_us_total",
+    "pbft_pipe_encode_us_total", "pbft_pipe_other_us_total",
+    "pbft_shard_dropped_total",
     // Fast-path surface (ISSUE 14): MAC-vector authenticated frames
     // sent, sequences executed at PREPARED, tentative rollbacks.
     "pbft_mac_frames_total", "pbft_tentative_executions_total",
@@ -135,6 +144,9 @@ const std::pair<const char*, bool> kHistogramNames[] = {
     // Their delivery began -> its last send left (once a kept batch):
     // dispatch, execute, sign, WAL flush, sends for one batch's verdicts.
     {"pbft_verdict_apply_seconds", false},
+    // Pipeline -> consensus thread, once a drain that found something:
+    // the drain's instant minus the push of the oldest entry it took.
+    {"pbft_shard_handoff_seconds", false},
     // The oldest request's wait at the primary until its batch is sealed
     // (once a batch), and how long a tentative execution stayed revocable
     // (once a sequence number, tentative mode).

@@ -589,7 +589,20 @@ inline uint64_t shard_link_key(int shard, uint64_t conn_id) {
 void ReplicaServer::process_shard_inbound() {
   LoopClock::Scope in_read(loop_clock_, kLoopRead);
   std::deque<KInbound> in;
-  shards_->drain_inbox(&in);
+  auto oldest = std::chrono::steady_clock::time_point::max();
+  shards_->drain_inbox(&in, &oldest);
+  if (metrics_.enabled &&
+      oldest != std::chrono::steady_clock::time_point::max()) {
+    // The hand-off's latency, once a drain that found something: this
+    // drain's instant minus the push of the OLDEST entry it took (the
+    // pipeline stamps a push that finds its queue empty).
+    const double waited = std::chrono::duration<double>(
+                              std::chrono::steady_clock::now() - oldest)
+                              .count();
+    metrics_.observe("pbft_shard_handoff_seconds", waited);
+    ++shard_handoffs_;
+    shard_handoff_s_ += waited;
+  }
   for (auto& k : in) {
     const uint64_t key = shard_link_key(k.shard, k.conn_id);
     if (k.kind == KInbound::kGatewayUp) {
@@ -635,8 +648,9 @@ void ReplicaServer::process_shard_inbound() {
 
 void ReplicaServer::aggregate_shard_metrics() {
   if (!shards_) return;
+  shards_->set_clocks_on(loop_clock_.on);
   // The shards' wakeups and MAC frames go with this thread's own, in
-  // fold_counters.
+  // fold_counters; so do the front-end threads' stage clocks.
   fold_delta(shards_->cross_thread_wakes(), &seen_cross_wakes_,
              "pbft_cross_thread_wakes_total");
   fold_delta(shards_->backpressure_events(), &seen_shard_backpressure_,
@@ -645,6 +659,9 @@ void ReplicaServer::aggregate_shard_metrics() {
              "pbft_chaos_dropped_total");
   fold_delta(shards_->broadcast_encodes(), &seen_shard_encodes_,
              "pbft_broadcast_encodes_total");
+  fold_delta(shards_->pipeline_dropped() + shards_->inbox_dropped() +
+                 shards_->replies_dropped(),
+             &seen_shard_dropped_, "pbft_shard_dropped_total");
   metrics_.set_gauge("pbft_crypto_offload_queue_depth",
                      (double)shards_->crypto_queue_depth());
 }
@@ -1207,6 +1224,20 @@ void ReplicaServer::trace_batch(int64_t size, int64_t rejected, double secs,
   for (int i = 0; i < kLoopStages; ++i) {
     std::fprintf(trace_fp_, i ? ",%lld" : "%lld",
                  (long long)(loop_clock_.ns[i] / 1000));
+  }
+  if (shards_) {
+    // The front-end threads' running totals, summed over this replica's
+    // shards and pipelines (kShardStageNames' / kPipeStageNames' order),
+    // and the hand-offs observed so far with the seconds they took.
+    for (const bool pipes : {false, true}) {
+      std::fputs(pipes ? "],\"pipe_us\":[" : "],\"shard_us\":[", trace_fp_);
+      for (int i = 0; i < kFrontStages; ++i) {
+        std::fprintf(trace_fp_, i ? ",%lld" : "%lld",
+                     (long long)shards_->front_stage_us(pipes, i));
+      }
+    }
+    std::fprintf(trace_fp_, "],\"handoff\":[%lld,%.6f", (long long)shard_handoffs_,
+                 shard_handoff_s_);
   }
   std::fputs("]}\n", trace_fp_);
   std::fflush(trace_fp_);
@@ -2605,6 +2636,18 @@ void ReplicaServer::fold_counters() {
     total_us += us;
   }
   fold_delta(total_us, &seen_loop_total_us_, "pbft_loop_us_total");
+  if (!shards_) return;
+  // The front-end threads' clocks (ISSUE 40), summed over this replica's
+  // shards and over its pipelines, as each thread last published them.
+  for (int i = 0; i < kFrontStages; ++i) {
+    char name[48];
+    std::snprintf(name, sizeof(name), "pbft_shard_%s_us_total",
+                  kShardStageNames[i]);
+    fold_delta(shards_->front_stage_us(false, i), &seen_shard_us_[i], name);
+    std::snprintf(name, sizeof(name), "pbft_pipe_%s_us_total",
+                  kPipeStageNames[i]);
+    fold_delta(shards_->front_stage_us(true, i), &seen_pipe_us_[i], name);
+  }
 }
 
 void ReplicaServer::fold_delta(int64_t now_abs, int64_t* seen,
@@ -2655,6 +2698,33 @@ std::string ReplicaServer::metrics_json() {
       sw.push_back(Json(shards_->shard_wakeups(i)));
     }
     o["shard_wakeups"] = Json(std::move(sw));
+    // Each shard thread's and each pipeline thread's microseconds by kind
+    // of work (net_shard.h FrontClock), and what was lost at a thread
+    // boundary, by kind: a healthy run reads 0 of each.
+    JsonArray shard_us, pipe_us;
+    for (int k = 0; k < shards_->n_shards(); ++k) {
+      JsonObject su, pu;
+      for (int i = 0; i < kFrontStages; ++i) {
+        su[kShardStageNames[i]] = Json(shards_->front_stage_us(false, k, i));
+        pu[kPipeStageNames[i]] = Json(shards_->front_stage_us(true, k, i));
+      }
+      shard_us.push_back(Json(std::move(su)));
+      pipe_us.push_back(Json(std::move(pu)));
+    }
+    o["shard_us"] = Json(std::move(shard_us));
+    o["pipe_us"] = Json(std::move(pipe_us));
+    // Drains of the shard inbox that found something and the seconds
+    // their oldest entries had waited (pbft_shard_handoff_seconds' count
+    // and sum).
+    JsonObject handoff;
+    handoff["drains"] = Json(shard_handoffs_);
+    handoff["seconds"] = Json(shard_handoff_s_);
+    o["shard_handoff"] = Json(std::move(handoff));
+    JsonObject dropped;
+    dropped["pipeline"] = Json(shards_->pipeline_dropped());
+    dropped["inbox"] = Json(shards_->inbox_dropped());
+    dropped["replies"] = Json(shards_->replies_dropped());
+    o["shard_dropped"] = Json(std::move(dropped));
   }
   o["connections_open"] =
       Json(shards_ ? shards_->connections_open()

@@ -347,6 +347,10 @@ enum LoopStage : int {
 };
 inline constexpr const char* kLoopStageNames[kLoopStages] = {
     "wait", "read", "protocol", "wal", "send", "verify", "other"};
+// Of these, the slots a front-end thread's clock uses (net_shard.h
+// FrontClock: a shard thread's wait/read/send/other, a pipeline thread's
+// wait/decode/encode/other).
+constexpr int kFrontStages = 4;
 
 struct LoopClock {
   bool on = false;
@@ -382,6 +386,13 @@ struct LoopClock {
     const int prev = stage;
     stage = s;
     return prev;
+  }
+
+  // Run on in stage s until the next enter or Scope: for a thread that
+  // works through a queue of mixed commands (net_shard.cc), one clock read
+  // where two neighbours differ in kind and none where they do not.
+  void enter(int s) {
+    if (on && stage != s) switch_to(s);
   }
 
   class Scope {
@@ -813,6 +824,11 @@ class ReplicaServer {
   // relaxed atomics, prometheus counters are monotonic increments.
   int64_t seen_cross_wakes_ = 0;
   int64_t seen_shard_backpressure_ = 0;
+  int64_t seen_shard_dropped_ = 0;
+  int64_t shard_handoffs_ = 0;    // pbft_shard_handoff_seconds' count ...
+  double shard_handoff_s_ = 0.0;  // ... and sum, for /status and --trace
+  std::array<int64_t, kFrontStages> seen_shard_us_{};
+  std::array<int64_t, kFrontStages> seen_pipe_us_{};
   int64_t seen_shard_chaos_ = 0;
   int64_t seen_shard_encodes_ = 0;
   int64_t gateway_forwarded_ = 0;  // requests received over gateway links

@@ -72,9 +72,8 @@ bool WakeFd::open_fds() {
 }
 
 void WakeFd::wake() {
-  // Coalesce: one write per un-drained episode. The consumer clears
-  // signaled_ BEFORE draining its queues, so a push racing the drain
-  // still triggers a fresh write — a wake can coalesce but never vanish.
+  // Coalesce: one write per un-drained episode. A producer that finds
+  // signaled_ set leaves its item to the drain under way (see drain()).
   if (signaled_.exchange(true, std::memory_order_acq_rel)) return;
   wakes_.fetch_add(1, std::memory_order_relaxed);
   const uint64_t one = 1;
@@ -82,10 +81,19 @@ void WakeFd::wake() {
 }
 
 void WakeFd::drain() {
-  signaled_.store(false, std::memory_order_release);
+  // The ORDER is the invariant: empty the fd, THEN clear signaled_, THEN
+  // (the caller) drain the queues. A producer whose exchange comes before
+  // the clear pushed before it too, and the queue drain that follows the
+  // clear takes its item; one whose exchange comes after the clear writes
+  // the fd afresh, and nothing reads the fd again before the next wait.
+  // Clearing first (the order up to PR 39) let this read() swallow the
+  // write of a producer that saw the cleared flag: signaled_ then stood
+  // true over an empty fd and every later wake() returned early — the
+  // consumer was never woken again.
   uint64_t buf[16];
   while (read(rfd_, buf, sizeof(buf)) > 0) {
   }
+  signaled_.store(false, std::memory_order_release);
 }
 
 // -- ShardEncoded ------------------------------------------------------------
@@ -158,10 +166,13 @@ void CryptoPipeline::notify() { cv_.notify_one(); }
 
 void CryptoPipeline::run() {
   rng_.seed(chaos_seed);
+  LoopClock& ck = clock.clock;
   while (!owner_->stopping()) {
+    ck.set_on(owner_->clocks_on());
     {
       std::unique_lock<std::mutex> lk(mu_);
       if (q_.empty()) {
+        LoopClock::Scope waiting(ck, kLoopWait);
         auto timeout = std::chrono::milliseconds(100);
         if (!chaos_queue_.empty()) {
           // A held chaos frame's release deadline bounds the sleep.
@@ -188,13 +199,34 @@ void CryptoPipeline::run() {
       local_.swap(q_);
       queue_depth.store(0, std::memory_order_relaxed);
     }
+    clock.publish();  // the wait has just been charged: no clock read here
     for (auto& c : local_) handle(c);
     local_.clear();
+    ck.enter(kLoopOther);
     pump_chaos(std::chrono::steady_clock::now());
   }
 }
 
+namespace {
+// The pipeline stage a command is worked in.
+constexpr int pipe_stage_of(CryptoCmd::Kind kind) {
+  switch (kind) {
+    case CryptoCmd::kInboundFrame:
+    case CryptoCmd::kInboundLine:
+      return kPipeDecode;
+    case CryptoCmd::kSendPeer:
+    case CryptoCmd::kSendClientLine:
+      return kPipeEncode;
+    default:
+      return kLoopOther;
+  }
+}
+}  // namespace
+
 void CryptoPipeline::handle(CryptoCmd& c) {
+  // The stage follows the command's kind and stays until a neighbour of
+  // another kind: a run of inbound frames costs one clock read.
+  clock.clock.enter(pipe_stage_of(c.kind));
   switch (c.kind) {
     case CryptoCmd::kInboundFrame:
       open_and_forward(c.conn_id, c.dest, std::move(c.bytes));
@@ -507,22 +539,34 @@ bool NetShard::bind_listener(int port, bool reuseport, int* bound_port) {
 
 void NetShard::push(LoopCmd&& c, bool force) {
   if (!cmds_.push(std::move(c), force)) {
+    // The one command that is not forced is a reply's dial-back.
     backpressure.fetch_add(1, std::memory_order_relaxed);
+    replies_dropped.fetch_add(1, std::memory_order_relaxed);
     return;
   }
   wake_.wake();
 }
 
 void NetShard::run() {
+  LoopClock& ck = clock.clock;
   while (!owner_->stopping()) {
+    ck.set_on(owner_->clocks_on());
     int timeout_ms = connecting_count_ > 0 ? 50 : 100;
     events_.clear();
-    int n = poller_->wait(&events_, timeout_ms);
+    int n;
+    {
+      LoopClock::Scope waiting(ck, kLoopWait);
+      n = poller_->wait(&events_, timeout_ms);
+    }
+    clock.publish();  // the wait has just been charged: no clock read here
     if (n < 0) continue;
     wakeups.fetch_add(1, std::memory_order_relaxed);
     for (const PollerEvent& ev : events_) {
       if (ev.tag == kShardTagListener) {
-        if (ev.readable) accept_ready();
+        if (ev.readable) {
+          LoopClock::Scope reading(ck, kLoopRead);
+          accept_ready();
+        }
         continue;
       }
       if (ev.tag == kShardTagWake) {
@@ -546,7 +590,12 @@ void NetShard::run() {
 
 void NetShard::process_cmds() {
   cmds_.drain(&local_);
+  LoopClock& ck = clock.clock;
   for (LoopCmd& c : local_) {
+    // As in the pipeline: a run of writes is one stretch of `send`.
+    const bool write =
+        c.kind == LoopCmd::kWriteConn || c.kind == LoopCmd::kWritePeer;
+    ck.enter(write ? kLoopSend : kLoopOther);
     switch (c.kind) {
       case LoopCmd::kWriteConn: {
         auto it = by_token_.find(c.conn_id);
@@ -584,6 +633,7 @@ void NetShard::process_cmds() {
       }
     }
   }
+  ck.enter(kLoopOther);
   local_.clear();
 }
 
@@ -612,6 +662,7 @@ void NetShard::register_conn(Conn& c) {
 }
 
 void NetShard::handle_readable(Conn& c) {
+  LoopClock::Scope reading(clock.clock, kLoopRead);
   char buf[65536];
   for (;;) {
     ssize_t r = read(c.fd, buf, sizeof(buf));
@@ -891,6 +942,7 @@ void NetShard::queue_bytes(Conn& c, const std::string& framed) {
 
 void NetShard::flush(Conn& c) {
   if (c.connecting) return;
+  LoopClock::Scope sending(clock.clock, kLoopSend);
   SendQueue& q = c.out;
   while (!q.blocks.empty()) {
     std::string& b = q.blocks.front();
@@ -1163,14 +1215,15 @@ void NetShards::stop_join() {
   joined_ = true;
 }
 
-void NetShards::drain_inbox(std::deque<KInbound>* out) {
+void NetShards::drain_inbox(std::deque<KInbound>* out,
+                            CmdQueue<KInbound>::Stamp* oldest) {
   k_wake_.drain();
-  for (auto& q : inbox_) q->drain(out);
+  for (auto& q : inbox_) q->drain(out, oldest);
 }
 
 void NetShards::push_inbound(int shard, KInbound&& in) {
   const bool control = in.kind != KInbound::kMsg;
-  if (!inbox_[shard]->push(std::move(in), control)) {
+  if (!inbox_[shard]->push(std::move(in), control, /*stamp=*/clocks_on())) {
     inbox_dropped_.fetch_add(1, std::memory_order_relaxed);
     return;
   }
@@ -1273,6 +1326,20 @@ int64_t NetShards::backpressure_events() const {
   int64_t t = inbox_dropped_.load(std::memory_order_relaxed);
   for (auto& s : shards_) t += s->backpressure.load(std::memory_order_relaxed);
   for (auto& p : pipelines_) t += p->drops.load(std::memory_order_relaxed);
+  return t;
+}
+
+int64_t NetShards::pipeline_dropped() const {
+  int64_t t = 0;
+  for (auto& p : pipelines_) t += p->drops.load(std::memory_order_relaxed);
+  return t;
+}
+
+int64_t NetShards::replies_dropped() const {
+  int64_t t = 0;
+  for (auto& s : shards_) {
+    t += s->replies_dropped.load(std::memory_order_relaxed);
+  }
   return t;
 }
 

@@ -28,7 +28,10 @@
 // control messages (connection lifecycle) always enqueue. There is no
 // shared mutable protocol state: cfg/seed are read-only after start, and
 // the only non-queue sharing is per-connection relaxed atomics
-// (outbound-bytes gauges, stats counters).
+// (outbound-bytes gauges, stats counters) and each front-end thread's
+// published stage clock (FrontClock below). Order is kept a CONNECTION
+// (one owner a socket, one pipeline a shard, FIFO queues), not across
+// connections: two connections of one client may land on two shards.
 #pragma once
 
 #include <atomic>
@@ -50,16 +53,19 @@ namespace pbft {
 
 // Cross-thread wake: eventfd on Linux, a nonblocking pipe elsewhere. The
 // producer side is writable from any thread (and is async-signal-safe);
-// the consumer registers fd() with its poller and calls drain() before
-// consuming its queues — any push after the drain triggers a fresh wake,
-// so a wake is never lost. `wakes` feeds pbft_cross_thread_wakes_total.
+// the consumer registers fd() level-triggered with its poller and calls
+// drain() before consuming its queues. drain() empties the fd FIRST and
+// clears the coalescing flag SECOND (net_shard.cc has why the other order
+// loses every later wake), so a push after the clear writes the fd afresh
+// and a push before it is taken by the queue drain that follows: a wake
+// can coalesce, never vanish. `wakes` feeds pbft_cross_thread_wakes_total.
 class WakeFd {
  public:
   ~WakeFd();
   bool open_fds();
   int fd() const { return rfd_; }
   void wake();   // counted; coalesces while the consumer hasn't drained
-  void drain();  // consumer: clear the signal BEFORE draining queues
+  void drain();  // consumer: empty the fd, clear the flag, THEN drain queues
   int64_t wakes() const { return wakes_.load(std::memory_order_relaxed); }
 
  private:
@@ -103,15 +109,27 @@ class ShardEncoded {
 template <typename T>
 class CmdQueue {
  public:
+  using Stamp = std::chrono::steady_clock::time_point;
   explicit CmdQueue(size_t cap) : cap_(cap) {}
-  bool push(T&& v, bool force) {
+  // `stamp`: a push that finds the queue empty notes the instant, which
+  // the drain that takes it hands back as the age of its OLDEST entry
+  // (pbft_shard_handoff_seconds): one clock read a drain's worth of
+  // pushes, none a message.
+  bool push(T&& v, bool force, bool stamp = false) {
     std::lock_guard<std::mutex> lk(mu_);
     if (!force && q_.size() >= cap_) return false;
+    if (stamp && q_.empty()) oldest_ = std::chrono::steady_clock::now();
     q_.push_back(std::move(v));
     return true;
   }
-  void drain(std::deque<T>* out) {
+  // `oldest` (optional) is lowered to this queue's stamp where it holds
+  // a stamped entry.
+  void drain(std::deque<T>* out, Stamp* oldest = nullptr) {
     std::lock_guard<std::mutex> lk(mu_);
+    if (oldest && !q_.empty() && oldest_ != Stamp{}) {
+      *oldest = std::min(*oldest, oldest_);
+    }
+    oldest_ = Stamp{};
     if (out->empty()) {
       out->swap(q_);
     } else {
@@ -130,6 +148,41 @@ class CmdQueue {
   mutable std::mutex mu_;
   std::deque<T> q_;
   size_t cap_;
+  Stamp oldest_{};
+};
+
+// A front-end thread's stage clock (ISSUE 40): the class the consensus
+// thread's loop has (net.h LoopClock: one steady_clock read where the
+// stage changes, plain integers), one instance a shard thread and one a
+// pipeline thread, owned by that thread alone. Of LoopClock's seven slots
+// such a thread uses four; it copies them into relaxed atomics once a
+// pass, where its wait has just ended (no clock read of its own), and the
+// consensus thread reads those at a scrape. So a reading lags by what the
+// thread has done since its last wait began, at most one wait's timeout.
+// LoopClock slots behind the four (net.h kFrontStages), in the names' order.
+inline constexpr int kFrontStageSlot[kFrontStages] = {kLoopWait, kLoopRead,
+                                                      kLoopSend, kLoopOther};
+// Shard: wait (the poller), read (recv, framing, the link prologue,
+// accepts), send (queue_bytes, flush, the send() calls), other.
+inline constexpr const char* kShardStageNames[kFrontStages] = {
+    "wait", "read", "send", "other"};
+// Pipeline: wait (the condition variable), decode (open, parse, signable,
+// MAC check, the push to the consensus inbox), encode (encode, MAC tags,
+// seal, frame, the push to the shard), other.
+inline constexpr const char* kPipeStageNames[kFrontStages] = {
+    "wait", "decode", "encode", "other"};
+constexpr int kPipeDecode = kLoopRead;
+constexpr int kPipeEncode = kLoopSend;
+
+struct FrontClock {
+  LoopClock clock;
+  std::array<std::atomic<int64_t>, kFrontStages> ns{};
+  void publish() {
+    for (int i = 0; i < kFrontStages; ++i) {
+      ns[i].store(clock.ns[kFrontStageSlot[i]], std::memory_order_relaxed);
+    }
+  }
+  int64_t stage_ns(int i) const { return ns[i].load(std::memory_order_relaxed); }
 };
 
 // Consensus thread -> pipeline i, and loop shard i -> pipeline i.
@@ -204,6 +257,7 @@ class CryptoPipeline {
   std::atomic<int64_t> mac_rejected{0};  // inbound lane mismatches
   std::atomic<int64_t> chaos_dropped{0};
   std::atomic<int64_t> drops{0};  // bounded-queue / admission drops
+  FrontClock clock;               // pbft_pipe_<stage>_us_total
 
   // Per-shard chaos bookkeeping (ISSUE 13 satellite): the same knobs as
   // the single-loop runtime, seeded per shard so the stream stays
@@ -265,6 +319,7 @@ class NetShard {
   std::atomic<int64_t> conns_open{0};
   std::atomic<int64_t> backpressure{0};  // drops + backed-up episodes
   std::atomic<int64_t> replies_dropped{0};
+  FrontClock clock;                      // pbft_shard_<stage>_us_total
 
  private:
   void process_cmds();
@@ -330,7 +385,18 @@ class NetShards {
   void set_chaos(double drop_pct, int delay_ms, uint64_t seed);
 
   int wake_fd() const { return k_wake_.fd(); }
-  void drain_inbox(std::deque<KInbound>* out);
+  // `oldest` (optional): lowered to the push stamp of the oldest entry
+  // drained, where the clocks are on.
+  void drain_inbox(std::deque<KInbound>* out,
+                   CmdQueue<KInbound>::Stamp* oldest = nullptr);
+  // The front-end threads' stage clocks follow the consensus thread's
+  // (each looks once a pass).
+  void set_clocks_on(bool on) {
+    if (clocks_on_.load(std::memory_order_relaxed) != on) {
+      clocks_on_.store(on, std::memory_order_relaxed);
+    }
+  }
+  bool clocks_on() const { return clocks_on_.load(std::memory_order_relaxed); }
 
   // Consensus-thread send entry points.
   void send_peer(int64_t dest, const std::string& addr,
@@ -351,6 +417,22 @@ class NetShards {
   int64_t chaos_dropped() const;
   int64_t inbox_dropped() const {
     return inbox_dropped_.load(std::memory_order_relaxed);
+  }
+  // Messages lost at a thread boundary, by kind (pbft_shard_dropped_total
+  // is their sum; a healthy run reads 0 of each).
+  int64_t pipeline_dropped() const;
+  int64_t replies_dropped() const;
+  // Stage i of kShardStageNames (kPipeStageNames where `pipes`) as shard
+  // k's thread (its pipeline's) last published it, whole microseconds;
+  // and the same summed over this replica's shards (pipelines).
+  int64_t front_stage_us(bool pipes, int k, int i) const {
+    return (pipes ? pipelines_[k]->clock : shards_[k]->clock).stage_ns(i) /
+           1000;
+  }
+  int64_t front_stage_us(bool pipes, int i) const {
+    int64_t us = 0;
+    for (int k = 0; k < n_shards(); ++k) us += front_stage_us(pipes, k, i);
+    return us;
   }
   int64_t broadcast_encodes() const {
     return encodes_total.load(std::memory_order_relaxed);
@@ -388,6 +470,7 @@ class NetShards {
   std::vector<std::unique_ptr<CmdQueue<KInbound>>> inbox_;  // SPSC per shard
   WakeFd k_wake_;
   std::atomic<int64_t> inbox_dropped_{0};
+  std::atomic<bool> clocks_on_{false};
   std::vector<std::thread> threads_;
   bool started_ = false;
   bool joined_ = false;

@@ -47,6 +47,10 @@ EVENT_SCHEMAS = {
     # totals at ts, microseconds, in LOOP_STAGES' order: two lines of one
     # replica bracket an interval with its split by kind of work
     # (scripts/trace_report.py, against verifyd's t_dev on the same clock).
+    # With --net-threads above 1 the line also carries shard_us and pipe_us
+    # (the front-end threads' running totals summed over the replica's
+    # shards / pipelines, SHARD_STAGES' / PIPE_STAGES' order) and handoff
+    # ([drains observed, seconds]: pbft_shard_handoff_seconds so far).
     "verify_batch": {
         "required": {"ts", "ev", "replica", "size", "rejected", "secs"},
         "optional": {
@@ -55,7 +59,7 @@ EVENT_SCHEMAS = {
             "hold_s", "held_out", "in_step",
             "pad_s", "put_s", "dispatch_s", "wait_s", "unpack_s", "rung", "promoted",
             "chunks", "split", "t_dev", "devices", "rows_per_chip", "ahead",
-            "apply_s", "loop_us",
+            "apply_s", "loop_us", "shard_us", "pipe_us", "handoff",
         },
         "emitters": {"server.py", "service.py", "net.cc"},
     },
@@ -408,6 +412,38 @@ METRIC_SCHEMAS = {
     "pbft_loop_send_us_total": ("counter", {"net.cc"}),
     "pbft_loop_verify_us_total": ("counter", {"net.cc"}),
     "pbft_loop_other_us_total": ("counter", {"net.cc"}),
+    # The multi-core front end's own clocks (pbftd only; ISSUE 40;
+    # core/net_shard.h FrontClock): with --net-threads N each of the N
+    # shard threads and N pipeline threads runs one LoopClock of its own
+    # (one clock read where the stage changes) and publishes it once a
+    # pass; the consensus thread sums them over a replica's shards /
+    # pipelines where a scrape or /status is rendered. Shard: wait (the
+    # poller), read (recv, framing, the link prologue, accepts), send
+    # (queue_bytes, flush, the send() calls), other. Pipeline: wait (its
+    # condition variable), decode (open, parse, signable, MAC check, the
+    # push to the consensus inbox), encode (encode, MAC tags, seal, frame,
+    # the push to the shard), other. Each four sum to the threads' elapsed
+    # time since the clocks came on. /status: shard_us, pipe_us (a list,
+    # one object a thread). All zero at --net-threads 1.
+    "pbft_shard_wait_us_total": ("counter", {"net.cc", "net_shard.cc"}),
+    "pbft_shard_read_us_total": ("counter", {"net.cc", "net_shard.cc"}),
+    "pbft_shard_send_us_total": ("counter", {"net.cc", "net_shard.cc"}),
+    "pbft_shard_other_us_total": ("counter", {"net.cc", "net_shard.cc"}),
+    "pbft_pipe_wait_us_total": ("counter", {"net.cc", "net_shard.cc"}),
+    "pbft_pipe_decode_us_total": ("counter", {"net.cc", "net_shard.cc"}),
+    "pbft_pipe_encode_us_total": ("counter", {"net.cc", "net_shard.cc"}),
+    "pbft_pipe_other_us_total": ("counter", {"net.cc", "net_shard.cc"}),
+    # The hand-off pipeline -> consensus thread: once a drain of the shard
+    # inbox that found something, the drain's instant minus the push of
+    # the OLDEST entry it took (a push that finds its queue empty stamps
+    # it: one clock read a drain on each side, none a message).
+    "pbft_shard_handoff_seconds": ("histogram", {"net.cc", "net_shard.cc"}),
+    # Messages lost at a thread boundary (a full pipeline queue or an
+    # over-budget connection, a full consensus inbox, a reply's dial-back
+    # refused or expired in a shard); /status: shard_dropped, by kind. A
+    # healthy run reads 0: a run that drops votes between its own threads
+    # is a slower protocol, not a faster front end.
+    "pbft_shard_dropped_total": ("counter", {"net.cc", "net_shard.cc"}),
     # Signatures the replica made (Replica::sign; pbftd only): every reply
     # carries one, so it is the largest countable item inside `protocol`.
     "pbft_signs_total": ("counter", {"net.cc"}),
@@ -441,6 +477,10 @@ BATCH_SIZE_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048, 4096)
 # The loop clock's stages, in the order a verify_batch line's loop_us lists
 # them (core/net.h kLoopStageNames).
 LOOP_STAGES = ("wait", "read", "protocol", "wal", "send", "verify", "other")
+# A shard thread's and a pipeline thread's stages (core/net_shard.h
+# kShardStageNames, kPipeStageNames): /status shard_us and pipe_us.
+SHARD_STAGES = ("wait", "read", "send", "other")
+PIPE_STAGES = ("wait", "decode", "encode", "other")
 
 # The consensus phases in protocol order. "request" exists only on the
 # primary (it assigns the sequence number); every replica sees the rest.

@@ -94,14 +94,52 @@ def loop_busy(history, rid, span_snapshots=5):
     if len(series) < 2:
         return None
 
-    def total(doc):
-        return sum(v for k, v in doc.items() if k not in ("passes", "switches"))
+    return _busy_share([series[0]], [series[-1]])
 
-    spent = total(series[-1]) - total(series[0])
+
+def _busy_share(first, last):
+    """1 - (wait gained) / (all stages gained) between two readings, each a
+    list of one thread's stage microseconds; None where nothing was gained."""
+    def total(docs, only=None):
+        return sum(v for doc in docs for k, v in doc.items()
+                   if k not in ("passes", "switches") and only in (None, k))
+
+    spent = total(last) - total(first)
     if spent <= 0:
         return None
-    waited = series[-1].get("wait", 0) - series[0].get("wait", 0)
+    waited = total(last, "wait") - total(first, "wait")
     return min(1.0, max(0.0, 1.0 - waited / spent))
+
+
+def front_busy(history, rid, key, span_snapshots=5):
+    """The same share for a sharded replica's front-end threads (pbftd
+    --net-threads above 1; ISSUE 40): ``key`` is /status ``shard_us`` or
+    ``pipe_us``, a list with one object of stage microseconds a thread,
+    pooled here over the threads. None where /status has no such list."""
+    series = [
+        s["replicas"][rid][key]
+        for s in list(history)[-span_snapshots:]
+        if isinstance(s.get("replicas", {}).get(rid, {}).get(key), list)
+    ]
+    if len(series) < 2:
+        return None
+    return _busy_share(series[0], series[-1])
+
+
+def handoff_ms(history, rid, span_snapshots=5):
+    """Mean wait of the oldest entry of a drain of the shard inbox over the
+    last few snapshots, milliseconds (/status ``shard_handoff``), or None."""
+    series = [
+        s["replicas"][rid]["shard_handoff"]
+        for s in list(history)[-span_snapshots:]
+        if isinstance(s.get("replicas", {}).get(rid, {}).get("shard_handoff"), dict)
+    ]
+    if len(series) < 2:
+        return None
+    drains = series[-1]["drains"] - series[0]["drains"]
+    if drains <= 0:
+        return None
+    return 1e3 * (series[-1]["seconds"] - series[0]["seconds"]) / drains
 
 
 def render(history, verdicts, gateway_doc=None) -> str:
@@ -135,6 +173,25 @@ def render(history, verdicts, gateway_doc=None) -> str:
                 doc.get("wal_disk_bytes", 0) / 1e3,
                 doc.get("view_timer_backoff", 1),
                 doc.get("last_progress_seconds", 0.0),
+            )
+        )
+    for rid in sorted(latest["replicas"]):
+        # A sharded replica's front end, where /status carries it: the
+        # loop column above is then its consensus thread alone.
+        shards, pipes = front_busy(history, rid, "shard_us"), front_busy(history, rid, "pipe_us")
+        if shards is None and pipes is None:
+            continue
+        wait = handoff_ms(history, rid)
+        dropped = latest["replicas"][rid].get("shard_dropped") or {}
+        lines.append(
+            "  %s: net_threads=%s shards busy %s pipelines busy %s hand-off %s dropped %d"
+            % (
+                rid,
+                latest["replicas"][rid].get("net_threads", "?"),
+                "-" if shards is None else "%.2f" % shards,
+                "-" if pipes is None else "%.2f" % pipes,
+                "-" if wait is None else "%.2fms" % wait,
+                sum(dropped.values()),
             )
         )
     if gateway_doc:

@@ -17,6 +17,10 @@ two of its ``verify_batch`` lines that bracket the interval (their
 "waiting for a window" put down to verifyd's hold, to replicas at work
 (which stage), or to replicas themselves in ``wait`` (the chip is then
 waiting for the clients). Every stamp is CLOCK_MONOTONIC on one host.
+For a replica run with ``--net-threads`` above 1 (ISSUE 40) a line says how
+busy its shard threads and its pipeline threads were and what the hand-off
+to the consensus thread took (the batch lines' ``shard_us``, ``pipe_us``,
+``handoff``).
 
 Usage: python scripts/trace_report.py /path/to/trace-dir-or-files...
 """
@@ -266,6 +270,29 @@ def print_idle_table(launches, by_replica, top: int = 12) -> list:
     return rows
 
 
+def front_end_summary(lines) -> str:
+    """A sharded replica's front end between its first and last batch line
+    (``pbftd --net-threads`` above 1; ISSUE 40): the busy share of its shard
+    threads and of its pipeline threads (1 - wait over all four stages, from
+    the lines' ``shard_us`` / ``pipe_us``) and the hand-off's mean (the
+    lines' ``handoff``: drains and their seconds). "" where the lines carry
+    none of it."""
+    have = [e for e in lines if "shard_us" in e and "pipe_us" in e]
+    if len(have) < 2:
+        return ""
+    a, b = have[0], have[-1]
+    parts = []
+    for key, what in (("shard_us", "shards"), ("pipe_us", "pipelines")):
+        spent = sum(b[key]) - sum(a[key])
+        if spent > 0:
+            parts.append(f"{what} busy {1 - (b[key][0] - a[key][0]) / spent:.2f}")
+    drains = b["handoff"][0] - a["handoff"][0]
+    if drains > 0:
+        parts.append(f"hand-off mean {1e3 * (b['handoff'][1] - a['handoff'][1]) / drains:.2f}ms "
+                     f"over {drains} drains")
+    return ", ".join(parts)
+
+
 def report(files) -> dict:
     launches: list = []  # verifyd's launch lines, where a log of its is given
     by_replica: dict = {}  # replica -> its batch lines that carry loop_us
@@ -285,6 +312,9 @@ def report(files) -> dict:
                 launches.append(e)
             elif isinstance(e.get("loop_us"), list):
                 by_replica.setdefault(e["replica"], []).append(e)
+        front = front_end_summary(vb)
+        if front:
+            print(f"{path.name}: front end: {front}")
         applied = sorted(e["apply_s"] for e in vb if "apply_s" in e)
         if applied:
             print(

@@ -6,8 +6,11 @@ host's native verifier, no kernel compiled) that also times itself into the
 launch's span as the sharded engine does, so that the readers of the engine's
 spans have something to read.
 
-    python3 _f1_mac_rehearse.py run WORKLOAD SECONDS TRACE     prints the result line
-    python3 _f1_mac_rehearse.py verifyd --control-fifo F ...   what the harness starts
+    python3 _f1_mac_rehearse.py run WORKLOAD SECONDS TRACE [WORK]   prints the result line
+    python3 _f1_mac_rehearse.py verifyd --control-fifo F ...        what the harness starts
+
+``WORK`` names a work directory of the run's own (``.chipbench_work_<WORK>``),
+so that two test files may rehearse at once.
 """
 
 import time
@@ -42,9 +45,11 @@ def serve(argv: list) -> None:
     verifyd_wrap.main(argv, engine=verifyd_wrap.traced(SpannedStub))
 
 
-def run(workload: str, seconds: str, trace: str) -> int:
+def run(workload: str, seconds: str, trace: str, work: str = "") -> int:
     import harness
 
+    if work:
+        harness.WORK = harness.ROOT / f".chipbench_work_{work}"
     try:
         line = harness.run_cell(
             workload, 3200000033, float(seconds), bool(int(trace)), t_start=T_START,
@@ -55,7 +60,8 @@ def run(workload: str, seconds: str, trace: str) -> int:
         return 1
     status = line.pop("_run")["final"]["status"]
     line["replicas"] = [{k: d[k] for k in ("mode", "mac_rejected", "tentative_rollbacks",
-                                            "executed_upto", "committed_upto")} for d in status]
+                                            "executed_upto", "committed_upto", "net_threads")}
+                        for d in status]
     print(json.dumps(line), flush=True)
     return 0
 
@@ -64,4 +70,4 @@ if __name__ == "__main__":
     if sys.argv[1] == "verifyd":
         serve(sys.argv[2:])
     else:
-        sys.exit(run(*sys.argv[2:5]))
+        sys.exit(run(*sys.argv[2:6]))

@@ -1139,13 +1139,18 @@ FORMS = {
 # an entry pins its own digest here).
 ACCEPTED_PER_LAYER = 27
 ACCEPTED_DIGEST = "7f2b2c41f15ac07556e716381fd4915806724ddc56d4b01011fdd21d1e6e3800"
-ADDED_CONFIGS = ["f5-sig-wal", "f1-mac-tentative", "f5-sig-wal-x4"]
-ADDED_CELLS = ["f5-sig-wal.closed", "f1-mac-tentative.closed", "f5-sig-wal-x4.closed"]
+ADDED_CONFIGS = ["f5-sig-wal", "f1-mac-tentative", "f5-sig-wal-x4", "f1-sig-wal-mt"]
+ADDED_CELLS = ["f5-sig-wal.closed", "f1-mac-tentative.closed", "f5-sig-wal-x4.closed",
+               "f1-sig-wal-mt.closed"]
 # The metrics of this table that PR 32's cell is listed under too (it reports
 # no verify trip, so none of the others).
 ALSO_IN_MAC_CELL = {"engine_idle_pct", "wal_flush_ms_mean"}
 # PR 36's four-chip cell reports whatever its one-chip twin reports.
 X4_CELL, X4_TWIN = "f5-sig-wal-x4.closed", "f5-sig-wal.closed"
+# PR 40's: the multi-core replica's cell reports whatever its one-thread twin
+# reports. (The f5-sig-wal.rate cell of the same PR was measured and LEFT
+# OUT: its second set of six runs did not hold half the bound, PERF.md §7.)
+MT_CELL, MT_TWIN = "f1-sig-wal-mt.closed", "f1-sig-wal.closed"
 
 
 @pytest.mark.parametrize(
@@ -1161,7 +1166,7 @@ def test_new_metric_has_its_reader_and_its_entry(name, form):
     if form == ".closed" and name in ALSO_IN_MAC_CELL:
         cells = cells + ["f1-mac-tentative.closed"]
     if form == ".closed":
-        cells = cells + [X4_CELL]
+        cells = cells + [X4_CELL, MT_CELL]
     spec = json.loads((CHIPBENCH / "metrics" / f"{name}{form}.json").read_text())
     assert spec["name"] == name + form
     assert (CHIPBENCH / "reducers" / f"{spec['reducer']}.py").is_file()
@@ -1208,6 +1213,16 @@ def test_the_four_chip_cell_is_listed_wherever_its_twin_is_and_brings_two_reader
     # a program from before the fields, as the parent commit is: nothing, and no error
     assert _read("mesh_chips.closed", {"launches": [{"rung": 256}]}) is None
     assert _read("rows_per_chip_mean.closed", {"launches": []}) is None
+
+
+def test_the_multicore_cell_is_listed_wherever_its_twin_is():
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        cells = m.get("workloads", [])
+        if cells == [MT_CELL]:  # the ten readers of what the deployment adds
+            continue
+        assert (MT_CELL in cells) == (MT_TWIN in cells), m["name"]
+        assert "f5-sig-wal.rate" not in cells  # measured in PR 40 and left out
 
 
 def test_accepted_benchmark_entries_are_unchanged():
