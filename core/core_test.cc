@@ -3,18 +3,23 @@
 // the pieces a pure-C++ build must guarantee on its own: crypto known
 // answers, canonical JSON, and a full in-process 4-replica consensus round
 // including a view change.
+#include <arpa/inet.h>
 #include <netinet/in.h>
 #include <poll.h>
 #include <sched.h>
 #include <sys/socket.h>
 #include <sys/stat.h>
+#include <sys/syscall.h>
 #include <unistd.h>
 
 #include <array>
+#include <atomic>
 #include <chrono>
+#include <cerrno>
 #include <cstdio>
 #include <cstdlib>
 #include <cstring>
+#include <functional>
 #include <map>
 #include <memory>
 #include <set>
@@ -358,6 +363,9 @@ struct MiniCluster {
   std::vector<pbft::ClientReply> replies;
   pbft::CpuVerifier verifier;
   std::set<int> crashed;  // crash-stop: no messages in or out
+  // Sees every message on its way from `src` (-1: the test's own) to
+  // `dst`, a crashed one's included.
+  std::function<void(int src, int dst, const pbft::Message&)> tap;
 
   explicit MiniCluster(const pbft::ClusterConfig& cfg,
                        const std::vector<std::vector<uint8_t>>& seeds) {
@@ -371,14 +379,15 @@ struct MiniCluster {
     if (crashed.count(src)) return;
     for (auto& b : acts.broadcasts) {
       for (int d = 0; d < 4; ++d) {
-        if (d != src) route(d, b.msg);
+        if (d != src) route(d, b.msg, src);
       }
     }
-    for (auto& s : acts.sends) route((int)s.dest, s.msg);
+    for (auto& s : acts.sends) route((int)s.dest, s.msg, src);
     for (auto& r : acts.replies) replies.push_back(r.msg);
   }
 
-  void route(int dst, const pbft::Message& m) {
+  void route(int dst, const pbft::Message& m, int src = -1) {
+    if (tap) tap(src, dst, m);
     if (crashed.count(dst)) return;
     // byte-faithful hop
     auto back = pbft::from_payload(pbft::message_canonical(m));
@@ -1115,22 +1124,45 @@ void test_remote_verifier_readiness() {
 
 // --- ISSUE 10: epoll-ET loop vs the poll() fallback ------------------------
 
-int parity_listen_ephemeral(int* port_out) {
+// A listening loopback socket on `port` (0: any free one).
+int listen_on_port(int port) {
   int fd = ::socket(AF_INET, SOCK_STREAM, 0);
   if (fd < 0) return -1;
   pbft::tune_listen_socket(fd);
   sockaddr_in addr{};
   addr.sin_family = AF_INET;
   addr.sin_addr.s_addr = htonl(INADDR_LOOPBACK);
+  addr.sin_port = htons((uint16_t)port);
   if (::bind(fd, (sockaddr*)&addr, sizeof(addr)) != 0 ||
       ::listen(fd, 64) != 0) {
     ::close(fd);
     return -1;
   }
-  socklen_t len = sizeof(addr);
-  ::getsockname(fd, (sockaddr*)&addr, &len);
-  *port_out = ntohs(addr.sin_port);
   return fd;
+}
+
+// The port at this end of a socket, or at its far end.
+int socket_port(int fd, bool far_end) {
+  sockaddr_in addr{};
+  socklen_t len = sizeof(addr);
+  const int rc = far_end ? ::getpeername(fd, (sockaddr*)&addr, &len)
+                         : ::getsockname(fd, (sockaddr*)&addr, &len);
+  return rc == 0 ? ntohs(addr.sin_port) : -1;
+}
+
+int parity_listen_ephemeral(int* port_out) {
+  const int fd = listen_on_port(0);
+  if (fd >= 0) *port_out = socket_port(fd, /*far_end=*/false);
+  return fd;
+}
+
+// The hello a gateway opens its link to a replica with, framed.
+std::string gateway_hello_frame() {
+  auto hello = pbft::Json::parse(pbft::SecureChannel::plain_hello(-1));
+  CHECK(hello.has_value());
+  pbft::JsonObject ho = hello->as_object();
+  ho["role"] = pbft::Json("gateway");
+  return pbft::frame_payload(pbft::Json(ho).dump());
 }
 
 // Four identities on loopback ports that were free a moment ago (seed of
@@ -1369,11 +1401,7 @@ void multicore_burst(int net_threads) {
   for (int i = 0; i < 4; ++i) {
     loops.emplace_back([srv = servers[i].get()] { srv->run(); });
   }
-  auto hello = pbft::Json::parse(pbft::SecureChannel::plain_hello(-1));
-  CHECK(hello.has_value());
-  pbft::JsonObject ho = hello->as_object();
-  ho["role"] = pbft::Json("gateway");
-  const std::string hello_frame = pbft::frame_payload(pbft::Json(ho).dump());
+  const std::string hello_frame = gateway_hello_frame();
   int links[4];
   for (int i = 0; i < 4; ++i) {
     links[i] = pbft::dial_tcp("127.0.0.1:" + std::to_string(ports[i]));
@@ -1572,6 +1600,9 @@ struct ScriptedVerifier : pbft::Verifier {
   std::vector<std::vector<pbft::VerifyItem>> launched, blocking;
   std::vector<int64_t> applied_at_launch, applied_at_blocking;
   int cancelled = 0;
+  // Runs inside the pass that reads the verdicts, after its poller wait
+  // and before the verdicts are worked through.
+  std::function<void()> on_poll;
 
   ScriptedVerifier() { CHECK(::socketpair(AF_UNIX, SOCK_STREAM, 0, fds) == 0); }
   ~ScriptedVerifier() override {
@@ -1598,6 +1629,7 @@ struct ScriptedVerifier : pbft::Verifier {
   bool poll_result(std::vector<uint8_t>* out, bool* failed) override {
     char b;
     CHECK(::read(fds[0], &b, 1) == 1);
+    if (on_poll) on_poll();
     inflight = false;
     *failed = fail_next;
     if (!fail_next) *out = pbft::CpuVerifier().verify_batch(launched.back());
@@ -1879,6 +1911,674 @@ void test_loop_clock_on_the_loop() {
   CHECK(status.find("\"verify_apply\":{\"batches\":1") != std::string::npos);
 }
 
+// --- ISSUE 41: one flush a connection an emit --------------------------------
+//
+// The witness of what the send side did, and in which order, is outside the
+// program: core_test defines send() and fsync() itself. An executable's
+// symbols come before libc's, so the dynamic linker binds libpbftcore's
+// calls to these two; each passes its call on to the kernel and, while a
+// test has set g_witness, tells it. No hook in ReplicaServer or NetShard.
+struct SyscallWitness {
+  virtual ~SyscallWitness() = default;
+  virtual void sent(int fd, const void* buf, size_t n, ssize_t ret) = 0;
+  virtual void synced(int fd) = 0;
+};
+std::atomic<SyscallWitness*> g_witness{nullptr};
+
+extern "C" ssize_t send(int fd, const void* buf, size_t n, int flags) {
+  const ssize_t ret = ::sendto(fd, buf, n, flags, nullptr, 0);
+  if (SyscallWitness* w = g_witness.load(std::memory_order_acquire)) {
+    const int err = errno;
+    w->sent(fd, buf, n, ret);
+    errno = err;
+  }
+  return ret;
+}
+
+extern "C" int fsync(int fd) {
+  const int ret = (int)::syscall(SYS_fsync, fd);
+  if (SyscallWitness* w = g_witness.load(std::memory_order_acquire)) {
+    const int err = errno;
+    w->synced(fd);
+    errno = err;
+  }
+  return ret;
+}
+
+// The payloads of a stream of length-prefixed frames; *rest: what is left
+// behind the last whole frame.
+std::vector<std::string> split_frames(const std::string& stream, size_t* rest) {
+  std::vector<std::string> out;
+  size_t at = 0;
+  while (stream.size() - at >= 4) {
+    const uint32_t len = ((uint32_t)(uint8_t)stream[at] << 24) |
+                         ((uint32_t)(uint8_t)stream[at + 1] << 16) |
+                         ((uint32_t)(uint8_t)stream[at + 2] << 8) |
+                         (uint32_t)(uint8_t)stream[at + 3];
+    if (stream.size() - at - 4 < len) break;
+    out.push_back(stream.substr(at + 4, len));
+    at += 4 + (size_t)len;
+  }
+  *rest = stream.size() - at;
+  return out;
+}
+
+// Replica 1 (a backup in view 0) as a real ReplicaServer behind the scripted
+// verifier; the test is the rest of the world: its three peers (listening
+// sockets on their configured ports, which the server dials), its one
+// gateway (a link with a role=gateway hello, which every "gw/" reply fans
+// back over) and the witness of its system calls. The rounds it is fed are
+// real ones: a shadow cluster of plain Replica objects runs each batch to
+// completion; what that addressed to replica 1 is queued into the server's
+// verify inbox, and what ITS replica 1 sent (the same seed signs the same
+// bytes) is what the far ends have to receive, byte for byte. ONE
+// deliver_verdicts then works through a pre-prepare, its prepares and its
+// commits: two broadcasts (PREPARE, COMMIT) and a reply a request, out of
+// one emit().
+struct SendRig : SyscallWitness {
+  static constexpr int kMe = 1;
+  static constexpr int kGateway = -1;  // the gateway's key beside 0, 2, 3
+  struct Ev {
+    bool sync;          // an fsync(); else a send()
+    int fd;
+    std::string bytes;  // send(): the bytes the kernel took
+    ssize_t ret;        // send(): what it returned
+  };
+  // What a stretch of the log did to one of the server's connections.
+  struct Use {
+    int sends = 0, failed = 0;
+    int first_send = -1, last_send = -1;
+    std::string bytes;  // taken by the kernel, in order
+    size_t frames = 0;  // whole frames in them
+  };
+
+  std::vector<std::vector<uint8_t>> seeds;
+  pbft::ClusterConfig cfg;
+  int ports[4];
+  int listeners[4] = {-1, -1, -1, -1};
+  int peers[4] = {-1, -1, -1, -1};  // the accepted ends of the server's dials
+  int gateway = -1;
+  ScriptedVerifier* sv = nullptr;
+  std::unique_ptr<pbft::ReplicaServer> srv;
+  std::unique_ptr<MiniCluster> shadow;
+  std::string wal_dir, wal_path;
+  std::vector<Ev> log;
+  std::map<int, int> far_port;     // the server's fd -> its far end's port
+  std::map<int, std::string> got;  // the test's fd -> every byte read
+  // What the shadow's replica 1 sent each far end (a peer's id, kGateway),
+  // framed, and how many bytes the link carried before the first of it
+  // (a peer's: the server's hello).
+  std::map<int, std::string> want;
+  std::map<int, size_t> skip;
+  int64_t rounds = 0;
+  int sends_outside_the_send_stage = 0;  // by the loop's stage clock
+  // With a WAL: the log file's size at its last fsync, and the votes that
+  // were looked up in the file at the instant send() was handed them.
+  int64_t synced_size = -1;
+  int votes_found_on_disk = 0;
+
+  explicit SendRig(bool wal = false) {
+    cfg = loopback_config(57, ports, &seeds);
+    cfg.batch_max_items = 1024;
+    auto v = std::make_unique<ScriptedVerifier>();
+    sv = v.get();
+    srv = std::make_unique<pbft::ReplicaServer>(cfg, kMe, seeds[kMe].data(),
+                                                std::move(v));
+    sv->replica = &srv->replica();
+    srv->metrics().enabled = true;
+    if (wal) {
+      const char* tmp = std::getenv("TMPDIR");
+      wal_dir = std::string(tmp ? tmp : "/tmp") + "/pbft-send-wal-XXXXXX";
+      CHECK(::mkdtemp(wal_dir.data()) != nullptr);
+      wal_path = wal_dir + "/replica-1.wal";
+      CHECK(srv->enable_wal(wal_dir));
+    }
+    CHECK(srv->start());
+    for (int k : {0, 2, 3}) {
+      listeners[k] = listen_on_port(ports[k]);
+      CHECK(listeners[k] >= 0);
+    }
+    shadow = std::make_unique<MiniCluster>(cfg, seeds);
+    gateway = pbft::dial_tcp("127.0.0.1:" + std::to_string(ports[kMe]));
+    CHECK(gateway >= 0);
+    const std::string hello = gateway_hello_frame();
+    CHECK(::write(gateway, hello.data(), hello.size()) == (ssize_t)hello.size());
+    g_witness.store(this, std::memory_order_release);
+    srv->poll_once(50);
+    srv->poll_once(0);
+  }
+  ~SendRig() override {
+    g_witness.store(nullptr, std::memory_order_release);
+    for (int fd : listeners) {
+      if (fd >= 0) ::close(fd);
+    }
+    for (int fd : peers) {
+      if (fd >= 0) ::close(fd);
+    }
+    if (gateway >= 0) ::close(gateway);
+    srv.reset();
+    if (!wal_dir.empty()) {
+      ::unlink(wal_path.c_str());
+      ::rmdir(wal_dir.c_str());
+    }
+  }
+
+  int stub(int k) const { return k == kGateway ? gateway : peers[k]; }
+
+  void sent(int fd, const void* buf, size_t n, ssize_t ret) override {
+    for (int k : {kGateway, 0, 2, 3}) {
+      if (fd == stub(k)) return;  // the test's own end of a link
+    }
+    if (!far_port.count(fd)) {
+      const int port = socket_port(fd, /*far_end=*/true);
+      if (port > 0) far_port[fd] = port;
+    }
+    if (srv->loop_clock().stage != pbft::kLoopSend) {
+      ++sends_outside_the_send_stage;
+    }
+    if (!wal_path.empty()) votes_are_on_disk(std::string((const char*)buf, n));
+    log.push_back(Ev{false, fd,
+                     ret > 0 ? std::string((const char*)buf, (size_t)ret) : "",
+                     ret});
+  }
+  void synced(int fd) override {
+    struct stat st{};
+    if (::fstat(fd, &st) == 0 && S_ISREG(st.st_mode)) synced_size = st.st_size;
+    log.push_back(Ev{true, fd, "", 0});
+  }
+  // The guarantee, at the instant a vote is handed to the kernel: its
+  // record is in the log file, and nothing was written there since the
+  // last fsync.
+  void votes_are_on_disk(const std::string& handed) {
+    std::string image;
+    if (FILE* f = std::fopen(wal_path.c_str(), "rb")) {
+      char buf[65536];
+      for (size_t r; (r = std::fread(buf, 1, sizeof(buf), f)) > 0;) {
+        image.append(buf, r);
+      }
+      std::fclose(f);
+    }
+    pbft::WalState on_disk;
+    const bool readable = pbft::wal_decode(image, &on_disk);
+    size_t rest = 0;
+    for (const std::string& payload : split_frames(handed, &rest)) {
+      auto m = pbft::from_payload(payload);
+      if (!m) continue;  // the link's hello
+      uint8_t kind = 0;
+      int64_t view = 0, seq = 0;
+      std::string digest;
+      if (auto* p = std::get_if<pbft::Prepare>(&*m)) {
+        kind = pbft::kWalVotePrepare, view = p->view, seq = p->seq, digest = p->digest;
+      } else if (auto* c = std::get_if<pbft::Commit>(&*m)) {
+        kind = pbft::kWalVoteCommit, view = c->view, seq = c->seq, digest = c->digest;
+      } else {
+        continue;
+      }
+      CHECK(readable && (int64_t)image.size() == synced_size);
+      auto it = on_disk.votes.find({kind, view, seq});
+      CHECK(it != on_disk.votes.end() && it->second == digest);
+      if (it != on_disk.votes.end() && it->second == digest) ++votes_found_on_disk;
+    }
+  }
+
+  // A batch of n requests ("gw/" clients, one request a client) run to its
+  // end by the shadow cluster: what replicas 0, 2 and 3 sent replica 1.
+  // What replica 1 sent there is added to what the far ends must receive.
+  std::vector<pbft::Message> script(int n) {
+    std::vector<pbft::Message> to_me;
+    shadow->tap = [&](int src, int dst, const pbft::Message& m) {
+      if (dst == kMe) to_me.push_back(m);
+      if (src == kMe) want[dst] += pbft::frame_payload(pbft::message_canonical(m));
+    };
+    const size_t replies_before = shadow->replies.size();
+    ++rounds;
+    for (int i = 0; i < n; ++i) {
+      pbft::ClientRequest req;
+      req.operation = "op-" + std::to_string(rounds) + "-" + std::to_string(i);
+      req.timestamp = rounds;
+      req.client = "gw/c" + std::to_string(i);
+      shadow->emit(0, shadow->replicas[0].on_client_request(req));
+    }
+    shadow->emit(0, shadow->replicas[0].flush_open_batch());
+    shadow->run();
+    shadow->tap = nullptr;
+    for (size_t i = replies_before; i < shadow->replies.size(); ++i) {
+      const pbft::ClientReply& r = shadow->replies[i];
+      if (r.replica == kMe) want[kGateway] += pbft::frame_payload(r.to_json().dump());
+    }
+    return to_me;
+  }
+  void feed(const std::vector<pbft::Message>& msgs) {
+    for (const auto& m : msgs) srv->replica().receive(m);
+  }
+  // The inbox goes out as one span; its verdicts come back and are worked
+  // through by one deliver_verdicts (one emit).
+  void cycle() {
+    srv->poll_once(0);
+    sv->answer();
+    srv->poll_once(50);
+  }
+  void accept_peers() {
+    for (int k : {0, 2, 3}) {
+      if (peers[k] >= 0) continue;
+      pollfd p{listeners[k], POLLIN, 0};
+      for (int tries = 0; tries < 40 && ::poll(&p, 1, 0) == 0; ++tries) {
+        srv->poll_once(50);
+      }
+      peers[k] = ::accept(listeners[k], nullptr, nullptr);
+      CHECK(peers[k] >= 0);
+    }
+  }
+  void pump() {
+    for (int k : {kGateway, 0, 2, 3}) {
+      if (stub(k) < 0) continue;
+      char buf[65536];
+      for (;;) {
+        const ssize_t r = ::recv(stub(k), buf, sizeof(buf), MSG_DONTWAIT);
+        if (r <= 0) break;
+        got[stub(k)].append(buf, (size_t)r);
+      }
+    }
+  }
+  // A link that was just made: passes and reads until its far end holds
+  // all it is owed behind what the link itself opened with (at most 5 s).
+  bool learn_skip(int k) {
+    const auto deadline =
+        std::chrono::steady_clock::now() + std::chrono::seconds(5);
+    for (;;) {
+      pump();
+      const std::string& g = got[stub(k)];
+      const std::string& w = want[k];
+      const size_t opened_with = k == kGateway ? 0 : 1;
+      if (g.size() >= w.size() + opened_with &&
+          g.compare(g.size() - w.size(), w.size(), w) == 0) {
+        skip[k] = g.size() - w.size();
+        size_t rest = 1;
+        const auto opening = split_frames(g.substr(0, skip[k]), &rest);
+        return rest == 0 && opening.size() >= opened_with;
+      }
+      if (std::chrono::steady_clock::now() > deadline) return false;
+      srv->poll_once(10);
+    }
+  }
+  // A first round of one request: the server dials its three peers, the
+  // test accepts, every link has carried what it opens with and a frame.
+  void warm_up() {
+    feed(script(1));
+    cycle();
+    accept_peers();
+    for (int k : {kGateway, 0, 2, 3}) CHECK(learn_skip(k));
+    CHECK(srv->replica().counters["executed"] == 1);
+  }
+  // Every far end holds, byte for byte and in order, what replica 1 was to
+  // send it: read now, with no further pass of the loop. True after an
+  // emit means the emit left nothing behind for a later one to flush.
+  bool arrived() {
+    pump();
+    for (const auto& [k, bytes] : want) {
+      if (stub(k) < 0) return false;
+      const std::string& g = got[stub(k)];
+      if (g.size() != skip[k] + bytes.size() ||
+          g.compare(skip[k], bytes.size(), bytes) != 0) {
+        return false;
+      }
+    }
+    return true;
+  }
+  // The same after passes that are given nothing to emit (at most 5 s):
+  // what a full socket left queued goes out on write readiness alone.
+  bool settle_streams() {
+    const auto deadline =
+        std::chrono::steady_clock::now() + std::chrono::seconds(5);
+    while (!arrived()) {
+      if (std::chrono::steady_clock::now() > deadline) return false;
+      srv->poll_once(10);
+    }
+    return true;
+  }
+  int server_fd_of(int stub_fd) {
+    for (const auto& [fd, port] : far_port) {
+      for (int k : {0, 2, 3}) {
+        if (port == ports[k] && peers[k] == stub_fd) return fd;
+      }
+      if (stub_fd == gateway && port == socket_port(gateway, false)) return fd;
+    }
+    return -1;
+  }
+  std::map<int, Use> uses(size_t from) const {
+    std::map<int, Use> out;
+    for (size_t i = from; i < log.size(); ++i) {
+      const Ev& e = log[i];
+      if (e.sync) continue;
+      Use& u = out[e.fd];
+      ++u.sends;
+      if (e.ret < 0) ++u.failed;
+      u.bytes += e.bytes;
+      if (u.first_send < 0) u.first_send = (int)i;
+      u.last_send = (int)i;
+    }
+    for (auto& [_, u] : out) {
+      size_t rest = 0;
+      u.frames = split_frames(u.bytes, &rest).size();
+    }
+    return out;
+  }
+  double scraped(const std::string& name) {
+    return prometheus_sample("\n" + srv->metrics_prometheus(), name);
+  }
+};
+
+// (a) N replies to one gateway link and the votes of two sequence numbers
+// to three peers, out of ONE deliver_verdicts: a send() a connection.
+void test_emit_sends_once_a_connection() {
+  SendRig t;
+  t.warm_up();
+  const size_t mark = t.log.size();
+  const double frames0 = t.scraped("pbft_frames_out_total");
+  const double sends0 = t.scraped("pbft_send_calls_total");
+  CHECK(frames0 > 0 && sends0 > 0);
+  const auto first = t.script(16), second = t.script(16);
+  t.feed(first);
+  t.feed(second);
+  t.cycle();
+  CHECK(t.srv->replica().counters["executed"] == 33);
+  auto use = t.uses(mark);
+  CHECK(use.size() == 4);
+  const int gw = t.server_fd_of(t.gateway);
+  CHECK(use[gw].frames == 32 && use[gw].sends == 1 && use[gw].failed == 0);
+  for (int k : {0, 2, 3}) {
+    const SendRig::Use& u = use[t.server_fd_of(t.peers[k])];
+    // PREPARE and COMMIT of each of the two sequence numbers, one send().
+    CHECK(u.frames == 4 && u.sends == 1 && u.failed == 0);
+    // The votes leave before the replies do.
+    CHECK(u.last_send < use[gw].first_send);
+  }
+  CHECK(t.scraped("pbft_frames_out_total") == frames0 + 32 + 12);
+  CHECK(t.scraped("pbft_send_calls_total") == sends0 + 4);
+  CHECK(t.arrived());
+  // The flush that ends an emit is the `send` stage's, like the queueing.
+  CHECK(t.srv->loop_clock().on && t.sends_outside_the_send_stage == 0);
+}
+
+// (b) What a peer and the gateway receive over a scripted run is what
+// replica 1 had to send them, byte for byte and in order; past 64 KiB a
+// connection takes a send() a block.
+void test_flushed_streams_are_the_queued_frames() {
+  SendRig t;
+  t.warm_up();
+  for (int n : {1, 32, 5}) {
+    t.feed(t.script(n));
+    t.cycle();
+    CHECK(t.arrived());
+  }
+  const size_t mark = t.log.size();
+  t.feed(t.script(300));  // ~100 KB of replies
+  t.feed(t.script(2));
+  t.cycle();
+  auto use = t.uses(mark);
+  const SendRig::Use& g = use[t.server_fd_of(t.gateway)];
+  const int blocks = (int)(g.bytes.size() / pbft::max_send_block()) + 1;
+  CHECK(g.frames == 302 && blocks >= 2);
+  CHECK(g.sends >= blocks && g.sends <= 3 * blocks && g.failed == 0);
+  for (int k : {0, 2, 3}) {
+    CHECK(use[t.server_fd_of(t.peers[k])].sends == 1);
+  }
+  CHECK(t.arrived());
+  CHECK(t.srv->replica().counters["executed"] == 1 + 38 + 302);
+  // What was compared: a reply a request on the gateway's link, and on a
+  // peer's PREPARE and COMMIT of every sequence number.
+  size_t rest = 1;
+  CHECK(split_frames(t.want[SendRig::kGateway], &rest).size() == 1 + 38 + 302);
+  CHECK(rest == 0);
+  for (int k : {0, 2, 3}) {
+    CHECK(split_frames(t.want[k], &rest).size() == 2 * 6 && rest == 0);
+    CHECK(t.want[k] == t.want[0] && t.skip[k] > 0);
+  }
+}
+
+// (c) One frame in an emit is one send(), as before.
+void test_one_frame_in_an_emit_is_one_send() {
+  SendRig t;
+  t.warm_up();
+  const auto round = t.script(4);
+  CHECK(std::holds_alternative<pbft::PrePrepare>(round[0]));
+  const size_t mark = t.log.size();
+  const double frames0 = t.scraped("pbft_frames_out_total");
+  const double sends0 = t.scraped("pbft_send_calls_total");
+  t.feed({round[0]});  // the pre-prepare alone: one PREPARE to each peer
+  t.cycle();
+  auto use = t.uses(mark);
+  CHECK(use.size() == 3);
+  for (const auto& [fd, u] : use) {
+    CHECK(fd != t.server_fd_of(t.gateway));
+    CHECK(u.frames == 1 && u.sends == 1 && u.failed == 0);
+  }
+  CHECK(t.scraped("pbft_frames_out_total") == frames0 + 3);
+  CHECK(t.scraped("pbft_send_calls_total") == sends0 + 3);
+  // The rest of the round: one COMMIT a peer and four replies.
+  const size_t mark2 = t.log.size();
+  t.feed({round.begin() + 1, round.end()});
+  t.cycle();
+  use = t.uses(mark2);
+  CHECK(use.size() == 4);
+  for (const auto& [fd, u] : use) {
+    CHECK(u.frames == (fd == t.server_fd_of(t.gateway) ? 4u : 1u));
+    CHECK(u.sends == 1);
+  }
+  CHECK(t.scraped("pbft_send_calls_total") == sends0 + 3 + 4);
+  CHECK(t.arrived());
+}
+
+// (d) When an emit returns, and so when a pass does, no connection is left
+// for a later one to flush: what it queued is at the far end, or waits on
+// write readiness that is armed and goes out on that alone. Seen from the
+// far ends, also while a gateway that does not read backs its link up.
+void test_no_byte_waits_for_a_later_emit() {
+  SendRig t;
+  t.warm_up();
+  for (int n : {3, 1, 20}) {
+    t.feed(t.script(n));
+    t.cycle();
+    CHECK(t.arrived());  // no pass since the emit's
+  }
+  const int gw = t.server_fd_of(t.gateway);
+  int small = 4096;
+  CHECK(::setsockopt(gw, SOL_SOCKET, SO_SNDBUF, &small, sizeof(small)) == 0);
+  CHECK(::setsockopt(t.gateway, SOL_SOCKET, SO_RCVBUF, &small,
+                     sizeof(small)) == 0);
+  const double backed_up0 = t.scraped("pbft_write_backpressure_events_total");
+  const double frames0 = t.scraped("pbft_frames_out_total");
+  const size_t mark = t.log.size();
+  const auto big = t.script(900);  // ~300 KB of replies
+  t.feed(big);
+  // Nobody reads the gateway's end while the emit runs.
+  t.srv->poll_once(0);
+  t.sv->answer();
+  t.srv->poll_once(50);
+  auto use = t.uses(mark);
+  CHECK(use[gw].failed == 1);  // EAGAIN: the flush stops there
+  CHECK(t.log[(size_t)use[gw].last_send].ret < 0);
+  size_t rest = 0;
+  CHECK(use[gw].frames < 900);
+  // Write readiness was armed once for the episode; what the next emit
+  // queues behind it waits for the same edge.
+  CHECK(t.scraped("pbft_write_backpressure_events_total") == backed_up0 + 1);
+  t.feed(t.script(8));
+  t.srv->poll_once(0);
+  t.sv->answer();
+  t.srv->poll_once(50);
+  CHECK(t.scraped("pbft_write_backpressure_events_total") == backed_up0 + 1);
+  CHECK(t.scraped("pbft_frames_out_total") == frames0 + 908 + 2 * 2 * 3);
+  CHECK(t.srv->replica().counters["executed"] == 1 + 24 + 908);
+  CHECK(!t.arrived());
+  // The gateway reads: the flush resumes on the edge, with no emit to help
+  // it, and every byte arrives, in order.
+  CHECK(t.settle_streams());
+  CHECK(split_frames(t.want[SendRig::kGateway], &rest).size() == 1 + 24 + 908);
+}
+
+// (e) The WAL is flushed before the first byte of an emit leaves: when a
+// vote is handed to send(), its record is in the file and fsynced.
+void test_wal_flush_precedes_an_emits_first_byte() {
+  SendRig t(/*wal=*/true);
+  t.warm_up();
+  const int found0 = t.votes_found_on_disk;
+  CHECK(found0 == 2 * 3);  // the first round's PREPARE and COMMIT, a peer
+  const double fsyncs0 = t.scraped("pbft_wal_fsyncs_total");
+  const size_t mark = t.log.size();
+  t.feed(t.script(16));
+  t.feed(t.script(16));
+  t.cycle();
+  int synced_at = -1, sent_at = -1, syncs = 0;
+  for (size_t i = mark; i < t.log.size(); ++i) {
+    if (t.log[i].sync) ++syncs;
+    if (t.log[i].sync && synced_at < 0) synced_at = (int)i;
+    if (!t.log[i].sync && sent_at < 0) sent_at = (int)i;
+  }
+  // The votes this emit carries were noted by the deliver_verdicts that
+  // produced it: one group commit, then the first send().
+  CHECK(syncs == 1 && synced_at >= 0 && sent_at > synced_at);
+  CHECK(t.scraped("pbft_wal_fsyncs_total") == fsyncs0 + 1);
+  // Each of the 12 votes was looked up in the file as send() was handed it
+  // (SendRig::votes_are_on_disk holds every one to it).
+  CHECK(t.votes_found_on_disk == found0 + 2 * 2 * 3);
+  CHECK(t.arrived());
+}
+
+// (f) A connection that a failed send() closes in the middle of an emit's
+// flush is passed over from there on; the emit serves the others.
+void test_connection_closed_by_failed_send_is_passed_over() {
+  SendRig t;
+  t.warm_up();
+  const int victim = t.server_fd_of(t.peers[2]);
+  CHECK(victim >= 0);
+  // Peer 2 resets its link after the pass's poller wait and before the
+  // verdicts are worked through: the loop learns of it from send().
+  t.sv->on_poll = [&t, victim] {
+    linger lg{1, 0};
+    CHECK(::setsockopt(t.peers[2], SOL_SOCKET, SO_LINGER, &lg, sizeof(lg)) == 0);
+    ::close(t.peers[2]);
+    t.got.erase(t.peers[2]);  // the number may come back as the next link's
+    t.peers[2] = -1;
+    pollfd p{victim, 0, 0};  // POLLERR / POLLHUP need not be asked for
+    CHECK(::poll(&p, 1, 2000) == 1);
+  };
+  const size_t mark = t.log.size();
+  t.feed(t.script(8));
+  t.cycle();
+  t.sv->on_poll = nullptr;
+  auto use = t.uses(mark);
+  // PREPARE and COMMIT were queued on it: one send(), which failed, and
+  // none after it to the end of the pass.
+  CHECK(use[victim].sends == 1 && use[victim].failed == 1);
+  const int gw = t.server_fd_of(t.gateway);
+  CHECK(use[gw].frames == 8 && use[gw].sends == 1 && use[gw].failed == 0);
+  CHECK(use[gw].first_send > use[victim].last_send);
+  for (int k : {0, 3}) {
+    const SendRig::Use& u = use[t.server_fd_of(t.peers[k])];
+    CHECK(u.frames == 2 && u.sends == 1 && u.failed == 0);
+  }
+  // That link is gone, with what it was owed; the others hold theirs.
+  t.far_port.erase(victim);
+  t.want.erase(2);
+  CHECK(t.arrived());
+  // The next round dials peer 2 again and reaches all three.
+  t.feed(t.script(1));
+  t.cycle();
+  t.accept_peers();
+  CHECK(t.peers[2] >= 0 && t.learn_skip(2));
+  CHECK(t.settle_streams());
+  CHECK(!t.got[t.peers[2]].empty());
+}
+
+// (g) The same stage in a loop shard: K writes to one connection in one
+// drained stretch are one send(), and a close that follows writes in the
+// same stretch delivers them first. The shard's thread body runs on a
+// thread of the test's, a stretch at a time: what was pushed before it
+// started is the ONE drain of its first pass.
+void test_shard_sends_once_a_drained_stretch() {
+  int ports[4];
+  std::vector<std::vector<uint8_t>> seeds;
+  pbft::ClusterConfig cfg = loopback_config(63, ports, &seeds);
+  std::atomic<bool> stopping{false};
+  pbft::NetShards shards(cfg, 1, seeds[1].data(), &stopping, 1);
+  pbft::NetShard& shard = shards.shard(0);
+  shards.set_clocks_on(true);
+  int bound = 0;
+  CHECK(shard.bind_listener(ports[1], /*reuseport=*/false, &bound));
+  const int link = pbft::dial_tcp("127.0.0.1:" + std::to_string(bound));
+  CHECK(link >= 0);
+  auto run_until = [&](const std::function<bool()>& done) {
+    stopping.store(false);
+    std::thread loop([&] { shard.run(); });
+    const auto deadline =
+        std::chrono::steady_clock::now() + std::chrono::seconds(2);
+    bool ok = false;
+    while (!(ok = done()) && std::chrono::steady_clock::now() < deadline) {
+      std::this_thread::sleep_for(std::chrono::milliseconds(1));
+    }
+    stopping.store(true);  // seen within the poller's 100 ms
+    loop.join();
+    return ok;
+  };
+  std::string got;
+  bool ended = false;
+  auto read_link = [&] {
+    char buf[4096];
+    for (ssize_t r; (r = ::recv(link, buf, sizeof(buf), MSG_DONTWAIT)) >= 0;) {
+      if (r == 0) {
+        ended = true;
+        break;
+      }
+      got.append(buf, (size_t)r);
+    }
+  };
+  // Accepts the link: the shard's first token.
+  CHECK(run_until([&] { return shard.conns_open.load() == 1; }));
+  auto push = [&](pbft::LoopCmd::Kind kind, const std::string& bytes) {
+    pbft::LoopCmd c;
+    c.kind = kind;
+    c.conn_id = 1;
+    c.bytes = bytes;
+    shard.push(std::move(c), /*force=*/true);
+  };
+  std::string want;
+  constexpr int kWrites = 40;
+  for (int i = 0; i < kWrites; ++i) {
+    const std::string framed = pbft::frame_payload(
+        "{\"type\":\"client-reply\",\"n\":" + std::to_string(i) + "}");
+    want += framed;
+    push(pbft::LoopCmd::kWriteConn, framed);
+  }
+  CHECK(run_until([&] {
+    read_link();
+    return got.size() >= want.size();
+  }));
+  CHECK(got == want && !ended);
+  CHECK(shards.frames_out() == kWrites);
+  CHECK(shards.send_calls() == 1);
+  // The flush behind the stretch is the shard's `send` stage's too.
+  const int64_t send_ns = shard.clock.clock.ns[pbft::kLoopSend];
+  CHECK(send_ns > 0);
+  // Writes and then a close in one stretch: the bytes, then the end.
+  for (int i = 0; i < 3; ++i) {
+    const std::string framed = pbft::frame_payload("last-" + std::to_string(i));
+    want += framed;
+    push(pbft::LoopCmd::kWriteConn, framed);
+  }
+  push(pbft::LoopCmd::kCloseConn, "");
+  push(pbft::LoopCmd::kWriteConn, pbft::frame_payload("too late"));
+  CHECK(run_until([&] {
+    read_link();
+    return ended;
+  }));
+  CHECK(got == want);  // closed behind them
+  CHECK(shards.frames_out() == kWrites + 3);
+  CHECK(shards.send_calls() == 2);
+  CHECK(shard.clock.clock.ns[pbft::kLoopSend] > send_ns);
+  ::close(link);
+}
+
 // ISSUE 14: MAC-vector codec units + the authenticator/tentative mode
 // on a real-socket cluster — single loop AND the sharded front end —
 // must reach the same executed state as signature mode.
@@ -2129,39 +2829,63 @@ void test_wal_roundtrip() {
   }
 }
 
-int main() {
-  test_sha512_vectors();
-  test_blake2b_vector();
-  test_ed25519_rfc8032();
-  test_scalar_reduction_vs_long_division();
-  test_crypto_yardstick();
-  test_canonical_json();
-  test_secure_channel_native();
-  test_four_replica_commit();
-  test_batched_round_native();
-  test_view_change_native();
-  test_stable_digest_majority_native();
-  test_state_transfer_native();
-  test_span_delivered_while_next_on_wire();
-  test_pre_authenticated_waits_for_span_on_wire();
-  test_rejected_signature_in_kept_span();
-  test_batch_verify_rlc();
-  test_verify_pool_native();
-  test_remote_verifier_async();
-  test_remote_verifier_readiness();
-  test_net_backend_parity();
-  test_multicore_parity();
-  test_wake_fd_keeps_every_wake();
-  test_multicore_burst();
-  test_loop_launches_ahead_of_kept_verdicts();
-  test_loop_wedge_deadline_keeps_order();
-  test_loop_transport_failure_keeps_order();
-  test_loop_clock_unit();
-  test_loop_clock_on_the_loop();
-  test_mac_codec_native();
-  test_fastpath_mac_parity();
-  test_flight_recorder();
-  test_wal_roundtrip();
+// Every test, in order; with arguments, only the tests named (their names
+// without the test_ prefix), so that a case can count on its own in tier-1.
+int main(int argc, char** argv) {
+  const std::pair<const char*, void (*)()> tests[] = {
+      {"sha512_vectors", test_sha512_vectors},
+      {"blake2b_vector", test_blake2b_vector},
+      {"ed25519_rfc8032", test_ed25519_rfc8032},
+      {"scalar_reduction_vs_long_division", test_scalar_reduction_vs_long_division},
+      {"crypto_yardstick", test_crypto_yardstick},
+      {"canonical_json", test_canonical_json},
+      {"secure_channel_native", test_secure_channel_native},
+      {"four_replica_commit", test_four_replica_commit},
+      {"batched_round_native", test_batched_round_native},
+      {"view_change_native", test_view_change_native},
+      {"stable_digest_majority_native", test_stable_digest_majority_native},
+      {"state_transfer_native", test_state_transfer_native},
+      {"span_delivered_while_next_on_wire", test_span_delivered_while_next_on_wire},
+      {"pre_authenticated_waits_for_span_on_wire", test_pre_authenticated_waits_for_span_on_wire},
+      {"rejected_signature_in_kept_span", test_rejected_signature_in_kept_span},
+      {"batch_verify_rlc", test_batch_verify_rlc},
+      {"verify_pool_native", test_verify_pool_native},
+      {"remote_verifier_async", test_remote_verifier_async},
+      {"remote_verifier_readiness", test_remote_verifier_readiness},
+      {"net_backend_parity", test_net_backend_parity},
+      {"multicore_parity", test_multicore_parity},
+      {"wake_fd_keeps_every_wake", test_wake_fd_keeps_every_wake},
+      {"multicore_burst", test_multicore_burst},
+      {"loop_launches_ahead_of_kept_verdicts", test_loop_launches_ahead_of_kept_verdicts},
+      {"loop_wedge_deadline_keeps_order", test_loop_wedge_deadline_keeps_order},
+      {"loop_transport_failure_keeps_order", test_loop_transport_failure_keeps_order},
+      {"loop_clock_unit", test_loop_clock_unit},
+      {"loop_clock_on_the_loop", test_loop_clock_on_the_loop},
+      {"emit_sends_once_a_connection", test_emit_sends_once_a_connection},
+      {"flushed_streams_are_the_queued_frames", test_flushed_streams_are_the_queued_frames},
+      {"one_frame_in_an_emit_is_one_send", test_one_frame_in_an_emit_is_one_send},
+      {"no_byte_waits_for_a_later_emit", test_no_byte_waits_for_a_later_emit},
+      {"wal_flush_precedes_an_emits_first_byte", test_wal_flush_precedes_an_emits_first_byte},
+      {"connection_closed_by_failed_send_is_passed_over", test_connection_closed_by_failed_send_is_passed_over},
+      {"shard_sends_once_a_drained_stretch", test_shard_sends_once_a_drained_stretch},
+      {"mac_codec_native", test_mac_codec_native},
+      {"fastpath_mac_parity", test_fastpath_mac_parity},
+      {"flight_recorder", test_flight_recorder},
+      {"wal_roundtrip", test_wal_roundtrip},
+  };
+  for (int i = 1; i < argc; ++i) {
+    bool known = false;
+    for (const auto& t : tests) known = known || std::strcmp(t.first, argv[i]) == 0;
+    if (!known) {
+      std::fprintf(stderr, "no test named %s\n", argv[i]);
+      return 2;
+    }
+  }
+  for (const auto& [name, run] : tests) {
+    bool wanted = argc == 1;
+    for (int i = 1; i < argc; ++i) wanted = wanted || std::strcmp(name, argv[i]) == 0;
+    if (wanted) run();
+  }
   if (g_failures) {
     std::fprintf(stderr, "%d failure(s)\n", g_failures);
     return 1;

@@ -78,6 +78,11 @@ const char* kCounterNames[] = {
     "pbft_loop_read_us_total", "pbft_loop_protocol_us_total",
     "pbft_loop_wal_us_total", "pbft_loop_send_us_total",
     "pbft_loop_verify_us_total", "pbft_loop_other_us_total",
+    // One flush a connection an emit (ISSUE 41): frames handed to a
+    // connection's send queue and send() calls made, the loop's and with
+    // --net-threads the shards' added up; their ratio is how many frames
+    // a system call carries.
+    "pbft_frames_out_total", "pbft_send_calls_total",
     // Signatures this replica made (Replica::sign): every reply carries
     // one, so it is the largest countable item inside `protocol`.
     "pbft_signs_total",

@@ -850,6 +850,7 @@ void ReplicaServer::queue_bytes(Conn& c, const std::string& framed) {
     q.blocks.push_back(std::move(b));
   }
   q.bytes += framed.size();
+  ++frames_out_;  // pbft_frames_out_total: folded at the scrape
 }
 
 bool ReplicaServer::reject_conn(Conn& c, const std::string& reason) {
@@ -1148,6 +1149,7 @@ void ReplicaServer::flush(Conn& c) {
       continue;
     }
     ssize_t w = send(c.fd, b.data() + q.front_pos, avail, MSG_NOSIGNAL);
+    ++send_calls_;  // pbft_send_calls_total: folded at the scrape
     if (w > 0) {
       q.front_pos += (size_t)w;
       q.bytes -= (size_t)w;
@@ -1174,6 +1176,27 @@ void ReplicaServer::flush(Conn& c) {
   if (c.close_when_flushed) {  // one-shot dial-back reply delivered
     mark_closed(c);
   }
+}
+
+void ReplicaServer::flush_once_an_emit(Conn& c) {
+  if (emit_depth_ == 0) {
+    flush(c);
+  } else if (!c.touched) {
+    c.touched = true;
+    touched_.push_back(&c);
+  }
+}
+
+// One send() a connection for everything the emit queued on it (more only
+// past a 64 KiB block or a full socket). A Conn lives until the end-of-
+// pass sweep, so the pointers hold; one that closed since it was touched
+// (its fd is gone, perhaps already another socket's) is passed over.
+void ReplicaServer::flush_touched() {
+  for (Conn* c : touched_) {  // flush() never adds one
+    c->touched = false;
+    if (!c->closed) flush(*c);
+  }
+  touched_.clear();
 }
 
 bool ReplicaServer::set_trace_file(const std::string& path) {
@@ -1908,6 +1931,12 @@ void ReplicaServer::emit(Actions&& actions) {
   // queue, send(), the reply's way back. (What a self-delivered message
   // sets off switches to protocol again, nested.)
   LoopClock::Scope in(loop_clock_, kLoopSend);
+  // A connection is flushed once for all that this emit queues on it, not
+  // once a frame (ISSUE 41): send_encoded and send_gateway_reply put it on
+  // touched_ while emit_depth_ > 0, the OUTERMOST emit flushes the list
+  // where the votes are queued and again where the replies are. One frame
+  // in an emit is one send(), as before.
+  ++emit_depth_;
   // Verify-inbox wait: every receive() comes back through here, so the
   // first pass that finds an item no launch has taken stamps its arrival
   // — one clock read per wait, none per message.
@@ -2008,6 +2037,8 @@ void ReplicaServer::emit(Actions&& actions) {
     }
     send_to(s.dest, s.msg);
   }
+  // The votes leave before the replies' JSON is built.
+  if (emit_depth_ == 1) flush_touched();
   for (auto& r : actions.replies) {
     waiting_requests_.erase({r.msg.client, r.msg.timestamp});
     if (mute) {  // a mute replica never dials the client back either
@@ -2025,6 +2056,7 @@ void ReplicaServer::emit(Actions&& actions) {
     }
     dial_reply(r.client, r.msg);
   }
+  if (--emit_depth_ == 0) flush_touched();
   observe_execution_metrics();
 }
 
@@ -2316,7 +2348,7 @@ void ReplicaServer::send_encoded(int64_t dest, EncodedOut& enc) {
     if (!outbound_has_room(c)) return;
     queue_bytes(c, framed);
   }
-  flush(c);
+  flush_once_an_emit(c);
 }
 
 bool ReplicaServer::chaos_pass(int64_t dest, const std::string& framed) {
@@ -2374,7 +2406,7 @@ void ReplicaServer::note_gateway_route(const std::string& client,
 void ReplicaServer::send_gateway_reply(Conn& g, const std::string& payload) {
   if (g.closed || !outbound_has_room(g)) return;  // drop-and-count
   queue_bytes(g, frame_payload(payload));
-  flush(g);
+  flush_once_an_emit(g);
 }
 
 void ReplicaServer::dial_reply(const std::string& client_addr,
@@ -2623,6 +2655,10 @@ void ReplicaServer::fold_counters() {
              &seen_mac_frames_, "pbft_mac_frames_total");
   fold_delta(gateway_forwarded_, &seen_gateway_forwarded_,
              "pbft_gateway_forwarded_total");
+  fold_delta(frames_out_ + (shards_ ? shards_->frames_out() : 0),
+             &seen_frames_out_, "pbft_frames_out_total");
+  fold_delta(send_calls_ + (shards_ ? shards_->send_calls() : 0),
+             &seen_send_calls_, "pbft_send_calls_total");
   // The loop clock, in whole microseconds a stage (pbft_loop_<stage>_us_total);
   // the total is the sum of the seven AS FOLDED, so the eight counters
   // agree to the microsecond.

@@ -256,6 +256,10 @@ struct Conn {
   // Latch for pbft_write_backpressure_events_total: one count per
   // backed-up episode, cleared when the queue drains.
   bool backpressured = false;
+  // Frames were queued here by a stretch of work that flushes ONCE at its
+  // end (ReplicaServer::emit, NetShard::process_cmds): the conn is on
+  // that stretch's list of touched connections until the flush.
+  bool touched = false;
   std::unique_ptr<SecureChannel> chan;
   std::vector<std::string> pending;  // outbound payloads queued pre-handshake
   // Multi-core mode only (core/net_shard.h). shard_token keys the conn in
@@ -519,9 +523,9 @@ class ReplicaServer {
   // arm write readiness for connect completion on the fallback backend).
   void register_conn(Conn& c);
   // Append framed bytes to c's outbound queue, coalescing into pooled
-  // blocks. Callers flush() afterwards (edge-triggered discipline: the
-  // eager flush IS the common write path; poller write events only
-  // resume after a partial write).
+  // blocks. Callers flush() afterwards, or flush_once_an_emit() on the
+  // hot path (edge-triggered discipline: the eager flush IS the common
+  // write path; poller write events only resume after a partial write).
   void queue_bytes(Conn& c, const std::string& framed);
   // Bounded-outbound admission (ISSUE 10 satellite): false when the
   // conn's queue is over budget — the frame is dropped and counted
@@ -555,6 +559,11 @@ class ReplicaServer {
   // Log + close (no reject frame: the link is beyond a polite refusal).
   bool fail_conn(Conn& c, const std::string& reason);
   void flush(Conn& c);
+  // The hot path's flush (votes, gateway replies): inside an emit() the
+  // conn is put on the list of touched connections and flushed once when
+  // the emit has queued everything for it; outside one it is flushed now.
+  void flush_once_an_emit(Conn& c);
+  void flush_touched();
   // The verify step of a pass, at its end: launch the span of the inbox
   // that no launch has taken, THEN work through the verdicts the pass
   // kept (and launch what that delivery queued for the replica itself,
@@ -805,6 +814,14 @@ class ReplicaServer {
   size_t connecting_count_ = 0;  // nonblocking dials awaiting completion
   int64_t event_wakeups_ = 0;        // poller wait() returns (metrics_json)
   int64_t backpressure_events_ = 0;  // drops + backed-up episodes
+  // One flush a connection an emit (ISSUE 41): how deep emit() is nested
+  // (a self-delivered message emits from inside one), the connections the
+  // running emit queued frames for, and the two tallies that show it:
+  // frames handed to queue_bytes, send() calls made (folded at the scrape).
+  int emit_depth_ = 0;
+  std::vector<Conn*> touched_;
+  int64_t frames_out_ = 0;
+  int64_t send_calls_ = 0;
   // Gateway tier (ISSUE 10): live gateway links by id, and which link
   // forwarded for each client token. Routes are a bounded cache — on
   // overflow the map clears and un-routed "gw/" replies fall back to a
@@ -920,8 +937,9 @@ class ReplicaServer {
   // Metrics registry + scrape listener (enabled by set_metrics_port).
   Metrics metrics_;
   // The loop thread's stage clock and what fold_counters has already fed
-  // to the registry (microseconds a stage; passes, frames in, MAC frames
-  // and gateway requests as the integers above count them).
+  // to the registry (microseconds a stage; passes, frames in and out, MAC
+  // frames, gateway requests and send() calls as the integers above count
+  // them).
   LoopClock loop_clock_;
   std::array<int64_t, kLoopStages> seen_loop_us_{};
   int64_t seen_loop_total_us_ = 0;
@@ -929,6 +947,8 @@ class ReplicaServer {
   int64_t seen_frames_in_ = 0;
   int64_t seen_mac_frames_ = 0;
   int64_t seen_gateway_forwarded_ = 0;
+  int64_t seen_frames_out_ = 0;
+  int64_t seen_send_calls_ = 0;
   int metrics_port_ = -1;
   int metrics_listen_fd_ = -1;
   int metrics_listen_port_ = 0;

@@ -591,24 +591,41 @@ void NetShard::run() {
 void NetShard::process_cmds() {
   cmds_.drain(&local_);
   LoopClock& ck = clock.clock;
+  // A connection is flushed once for all the frames this drained stretch
+  // holds for it, not once a frame (ISSUE 41): a write queues and puts the
+  // conn on touched_, the flush follows the stretch. A close finds the
+  // frames written before it in the stretch already sent.
+  auto write = [&](Conn& conn, const std::string& framed) {
+    queue_bytes(conn, framed);
+    if (!conn.touched) {
+      conn.touched = true;
+      touched_.push_back(&conn);
+    }
+  };
+  auto close_conn = [&](Conn& conn) {
+    if (conn.touched) {
+      ck.enter(kLoopSend);
+      flush(conn);  // may close it: close_when_flushed
+      ck.enter(kLoopOther);
+    }
+    if (!conn.closed) mark_closed(conn);
+  };
   for (LoopCmd& c : local_) {
     // As in the pipeline: a run of writes is one stretch of `send`.
-    const bool write =
+    const bool writes =
         c.kind == LoopCmd::kWriteConn || c.kind == LoopCmd::kWritePeer;
-    ck.enter(write ? kLoopSend : kLoopOther);
+    ck.enter(writes ? kLoopSend : kLoopOther);
     switch (c.kind) {
       case LoopCmd::kWriteConn: {
         auto it = by_token_.find(c.conn_id);
         if (it == by_token_.end() || it->second->closed) break;
-        queue_bytes(*it->second, c.bytes);
-        flush(*it->second);
+        write(*it->second, c.bytes);
         break;
       }
       case LoopCmd::kWritePeer: {
         auto it = peers_.find(c.dest);
         if (it == peers_.end() || it->second->closed) break;  // loss is ok
-        queue_bytes(*it->second, c.bytes);
-        flush(*it->second);
+        write(*it->second, c.bytes);
         break;
       }
       case LoopCmd::kDialPeer:
@@ -621,20 +638,33 @@ void NetShard::process_cmds() {
         if (c.dest >= 0) {
           auto it = peers_.find(c.dest);
           if (it != peers_.end() && !it->second->closed) {
-            mark_closed(*it->second);
+            close_conn(*it->second);
           }
           break;
         }
         auto it = by_token_.find(c.conn_id);
         if (it != by_token_.end() && !it->second->closed) {
-          mark_closed(*it->second);
+          close_conn(*it->second);
         }
         break;
       }
     }
   }
+  ck.enter(kLoopSend);
+  flush_touched();
   ck.enter(kLoopOther);
   local_.clear();
+}
+
+// A Conn lives until sweep() (a closed peer link that was redialed waits
+// in graveyard_), so the pointers hold; one that closed since it was
+// touched is passed over.
+void NetShard::flush_touched() {
+  for (Conn* c : touched_) {
+    c->touched = false;
+    if (!c->closed) flush(*c);
+  }
+  touched_.clear();
 }
 
 void NetShard::accept_ready() {
@@ -938,6 +968,8 @@ void NetShard::queue_bytes(Conn& c, const std::string& framed) {
   if (c.out_gauge) {
     c.out_gauge->store((int64_t)q.bytes, std::memory_order_relaxed);
   }
+  frames_out.store(frames_out.load(std::memory_order_relaxed) + 1,
+                   std::memory_order_relaxed);
 }
 
 void NetShard::flush(Conn& c) {
@@ -954,6 +986,8 @@ void NetShard::flush(Conn& c) {
       continue;
     }
     ssize_t w = send(c.fd, b.data() + q.front_pos, avail, MSG_NOSIGNAL);
+    send_calls.store(send_calls.load(std::memory_order_relaxed) + 1,
+                     std::memory_order_relaxed);
     if (w > 0) {
       q.front_pos += (size_t)w;
       q.bytes -= (size_t)w;
@@ -1293,6 +1327,18 @@ int64_t NetShards::mac_frames() const {
   for (auto& p : pipelines_) {
     t += p->mac_frames.load(std::memory_order_relaxed);
   }
+  return t;
+}
+
+int64_t NetShards::frames_out() const {
+  int64_t t = 0;
+  for (auto& s : shards_) t += s->frames_out.load(std::memory_order_relaxed);
+  return t;
+}
+
+int64_t NetShards::send_calls() const {
+  int64_t t = 0;
+  for (auto& s : shards_) t += s->send_calls.load(std::memory_order_relaxed);
   return t;
 }
 

@@ -316,6 +316,10 @@ class NetShard {
   void run();  // thread body
 
   std::atomic<int64_t> wakeups{0};       // per-shard epoll wakeups
+  // Frames handed to queue_bytes and send() calls made (this thread is
+  // the one writer; NetShards adds the shards' up for the scrape).
+  std::atomic<int64_t> frames_out{0};
+  std::atomic<int64_t> send_calls{0};
   std::atomic<int64_t> conns_open{0};
   std::atomic<int64_t> backpressure{0};  // drops + backed-up episodes
   std::atomic<int64_t> replies_dropped{0};
@@ -368,6 +372,10 @@ class NetShard {
   size_t reply_dials_in_flight_ = 0;
   std::set<std::string> reply_addrs_in_flight_;
   std::deque<LoopCmd> local_;
+  // The connections a drained stretch of commands queued frames for
+  // (Conn::touched): flushed once, after the stretch.
+  std::vector<Conn*> touched_;
+  void flush_touched();
 
   friend class NetShards;
 };
@@ -412,6 +420,8 @@ class NetShards {
   int64_t connections_open() const;
   int64_t crypto_queue_depth() const;
   int64_t mac_frames() const;
+  int64_t frames_out() const;
+  int64_t send_calls() const;
   int64_t mac_rejected() const;
   int64_t backpressure_events() const;
   int64_t chaos_dropped() const;

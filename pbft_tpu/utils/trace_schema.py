@@ -444,6 +444,17 @@ METRIC_SCHEMAS = {
     # healthy run reads 0: a run that drops votes between its own threads
     # is a slower protocol, not a faster front end.
     "pbft_shard_dropped_total": ("counter", {"net.cc", "net_shard.cc"}),
+    # One flush a connection an emit (pbftd only; ISSUE 41): frames (and
+    # dial-back lines) handed to a connection's send queue, and send()
+    # system calls made, by the net loop and, with --net-threads, by its
+    # shard threads, added into the same two names where a scrape or
+    # /status is rendered. Their ratio is the frames a system call carries:
+    # the loop queues everything one emit() holds for a connection and
+    # flushes it once (a shard: everything one drained stretch of commands
+    # holds), so it follows the bunches the input produced; it read 1.0 by
+    # construction while every frame was followed by its own flush.
+    "pbft_frames_out_total": ("counter", {"net.cc", "net_shard.cc"}),
+    "pbft_send_calls_total": ("counter", {"net.cc", "net_shard.cc"}),
     # Signatures the replica made (Replica::sign; pbftd only): every reply
     # carries one, so it is the largest countable item inside `protocol`.
     "pbft_signs_total": ("counter", {"net.cc"}),

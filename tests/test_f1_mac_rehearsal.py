@@ -59,7 +59,7 @@ def test_a_traced_rehearsal_reports_every_per_layer_metric_of_the_cell():
     # find nothing and are left out; every other reader reports a number.
     from_trace = {m["name"] for m in bench["per_layer"] if m["source"] == "device_trace"}
     assert from_trace & listed == {"kernel_ms_per_launch.closed", "device_idle_pct.closed"}
-    assert set(line["metrics"]) == listed - from_trace and len(listed) == 27
+    assert set(line["metrics"]) == listed - from_trace and len(listed) == 28
     # No verify trip on a reply's path, so no launch to make ahead: the cell
     # is not listed under ISSUE 37's readers nor under the apply's clock, and
     # its line carries none of them.
@@ -75,6 +75,9 @@ def test_a_traced_rehearsal_reports_every_per_layer_metric_of_the_cell():
         assert value[f"loop_{stage}_us_per_req.closed"] > 0, stage
     assert value["loop_verify_us_per_req.closed"] < 1.0
     assert value["signs_per_req.closed"] >= 1.0
+    # A sequence number's replies leave in one send() (ISSUE 41): a system
+    # call carries several frames, where it carried one.
+    assert value["frames_per_send.closed"] > 2.0
     # The mode, as numbers: no item sent for verification, every execution at
     # PREPARED, none undone, MAC frames on the wire, the probe alone in a launch.
     assert value["sig_checks_per_req.closed"] == 0 and value["tentative_rollbacks.closed"] == 0

@@ -97,7 +97,7 @@ def test_a_traced_rehearsal_reports_every_per_layer_metric_of_the_cell():
     assert from_trace & listed == {
         "kernel_ms_per_launch.closed", "verify_kernel_roofline.closed", "device_idle_pct.closed",
     }
-    assert set(line["metrics"]) == listed - from_trace and len(listed) == 44
+    assert set(line["metrics"]) == listed - from_trace and len(listed) == 45
     value = {k: v["value"] for k, v in line["metrics"].items()}
     assert all(isinstance(v, (int, float)) for v in value.values())
     # The reading that says the cell ran over four chips, and the one that

@@ -145,6 +145,12 @@ def test_the_stage_clock_accounts_for_a_served_replicas_whole_loop(mode):
             )
         else:
             assert gain["verify"] == 0 and batches == 0 and applied[1] == 0, (rid, gain, batches)
+        # One flush a connection an emit (ISSUE 41): every send() carried a
+        # frame or more, and the flush that ends an emit is inside `send`
+        # (the seven still sum to the total, above).
+        frames = stats.counter_delta(a["metrics"], b["metrics"], "pbft_frames_out_total")
+        calls = stats.counter_delta(a["metrics"], b["metrics"], "pbft_send_calls_total")
+        assert frames >= calls > 0 and gain["send"] > 0, (rid, frames, calls, gain)
     primary = last[0]["status"]["view"] % 4
     sends = stats.counter_delta(
         first[primary]["metrics"], last[primary]["metrics"], "pbft_loop_send_us_total")
@@ -216,8 +222,9 @@ def test_a_new_reader_names_what_exists_and_reads_the_hand_made_run(name):
         "name": name, "unit": unit, "better": better, "source": source,
         "layer": "net loop (core/net.cc)", "moves": moves, "workloads": cells,
     }]
-    # (PR 40's ten readers of the shard tier came behind them.)
-    assert bench["per_layer"].index(entry[0]) >= len(bench["per_layer"]) - len(NEW) - 10
+    # (PR 40's ten readers of the shard tier came behind them, and PR 41's
+    # frames_per_send.closed behind those.)
+    assert bench["per_layer"].index(entry[0]) >= len(bench["per_layer"]) - len(NEW) - 11
     known = {c["name"] for c in bench["workloads"]}
     reporting = next(m for m in bench["end_to_end"] if m["name"] == moves)["workloads"]
     assert set(cells) <= known and set(cells) <= set(reporting)
@@ -241,6 +248,30 @@ def test_a_new_reader_names_what_exists_and_reads_the_hand_made_run(name):
         "loop_other_us_per_req": 200.0, "verdict_apply_ms_mean": 20.0, "signs_per_req": 1.25,
     }[name.rsplit(".", 1)[0]]
     assert _read(name, _hand_run()) == pytest.approx(want, rel=1e-12)
+    assert _read(name, _hand_run(old=True)) is None
+
+
+def test_frames_per_send_is_a_data_file_on_the_reducer_that_is_there():
+    """ISSUE 41's one reader: appended last, in the five closed cells, the
+    gain of one counter over the gain of the other on the primary; nothing
+    (and no error) on a program that has neither, as the parent commit is."""
+    name = "frames_per_send.closed"
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    assert bench["per_layer"][-1] == {
+        "name": name, "unit": "count", "better": "higher", "source": "program_counter",
+        "layer": "net loop (core/net.cc)", "moves": "commit_rate", "workloads": CLOSED4,
+    }
+    spec = json.loads((CHIPBENCH / "metrics" / f"{name}.json").read_text())
+    assert spec == {"name": name, "reducer": "counter_delta_ratio", "args": {
+        "counter": "pbft_frames_out_total", "over": "pbft_send_calls_total", "replicas": "primary"}}
+    for series in ("pbft_frames_out_total", "pbft_send_calls_total"):
+        assert trace_schema.METRIC_SCHEMAS[series] == ("counter", {"net.cc", "net_shard.cc"})
+    run = _hand_run()
+    for edge, frames, calls in (("edge_a", 500.0, 400.0), ("edge_b", 500.0 + 2_400, 400.0 + 200)):
+        run[edge]["metrics"] = [{**run[edge]["metrics"][0], ("pbft_frames_out_total", ""): frames,
+                                 ("pbft_send_calls_total", ""): calls}] * 4
+    assert _read(name, run) == pytest.approx(12.0, rel=1e-12)
+    assert _read(name, _hand_run()) is None  # a scrape without the counters
     assert _read(name, _hand_run(old=True)) is None
 
 
