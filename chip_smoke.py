@@ -348,7 +348,10 @@ def device_stage(target: str, seed: int, ladder=LADDER, trace_path=None) -> None
         f"{after['split_launches'] - before['split_launches']} run as chunks; "
         f"every class planted in every chip's rows at {every_chip or 'no'} slots "
         f"over {chips} chip(s); launches by rows a chip "
-        f"{after.get('launches_by_rows_per_chip')}")
+        f"{after.get('launches_by_rows_per_chip')}; "
+        f"{after.get('windows_cut_full', 0) - before.get('windows_cut_full', 0)} windows cut at "
+        f"the largest window with requests left queued (at most "
+        f"{after.get('overflow_items_max', 0)} items behind a cut)")
 
 
 # -- stage 2: the deployment ---------------------------------------------------

@@ -126,7 +126,7 @@ class LocalCluster:
         # __enter__ (pre-allocated — pbftd logs its ephemeral port to
         # stderr, but pre-allocation keeps revive() on the same port).
         self.want_metrics_ports = metrics_ports
-        self.metrics_ports: List[int] = []
+        self.metrics_ports: List[int] = []  # reserved with the listen ports, below
         self.chaos_drop_pct = chaos_drop_pct
         self.chaos_delay_ms = chaos_delay_ms
         self.chaos_seed = chaos_seed
@@ -136,7 +136,15 @@ class LocalCluster:
             # Discovery mode: every replica binds an ephemeral port and
             # finds peers via multicast beacons (the mDNS-equivalent);
             # otherwise pre-allocate loopback ports in the config.
-            ports = [0] * n if discovery else free_ports(n)
+            # ONE reservation for the listen ports and the scrape ports: a
+            # second free_ports call can be handed a port the first one gave
+            # and released (a replica's scrape port on another's listen
+            # port: 31 x 31 chances in some 14,000 ports a cluster, one n=31
+            # cluster in fourteen; the loser of the two binds ends or serves
+            # no /metrics, and the peers that dial it reach the other).
+            reserved = free_ports((0 if discovery else n) + (n if metrics_ports else 0))
+            ports = [0] * n if discovery else reserved[:n]
+            self.metrics_ports = reserved[len(reserved) - n:] if metrics_ports else []
             config = dataclasses.replace(
                 config,
                 replicas=[
@@ -244,7 +252,7 @@ class LocalCluster:
             if self.metrics_every:
                 cmd += ["--metrics-every", str(self.metrics_every)]
             if self.want_metrics_ports:
-                if not self.metrics_ports:
+                if not self.metrics_ports:  # a config of the caller's, with ports of its own
                     self.metrics_ports = free_ports(self.config.n)
                 cmd += ["--metrics-port", str(self.metrics_ports[i])]
             if not self._batch_scalar:

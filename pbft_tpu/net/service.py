@@ -283,6 +283,9 @@ class VerifierService:
         # windows it ran as several executables (its span's ``split``).
         # held_out_launches / in_step_launches: windows whose hold ran out,
         # and windows cut early because nobody in step was still out;
+        # windows_cut_full: windows cut at MAX_WINDOW with requests left
+        # queued behind them, and overflow_items_max the most items any cut
+        # left queued (the backlog the service carried at its deepest);
         # launches_by_rung: launches by the padded slots the engine ran;
         # launches_by_rows_per_chip: by the rows a chip of the window's
         # thinnest chunk (its span's ``rows_per_chip``).
@@ -291,6 +294,8 @@ class VerifierService:
         self.split_launches = 0
         self.held_out_launches = 0
         self.in_step_launches = 0
+        self.windows_cut_full = 0
+        self.overflow_items_max = 0
         self.launches_by_rung: dict = {}
         self.launches_by_rows_per_chip: dict = {}
         self._slowest: Optional[dict] = None
@@ -427,7 +432,12 @@ class VerifierService:
                 hold, held_out, in_step = 0.0, 0, 0
                 if self.hold_s is not None:
                     while self._running:
-                        hold = self.hold_s(self._pending_items())
+                        # Asked about what this window can carry: a backlog
+                        # beyond MAX_WINDOW is cut there whatever arrives, so
+                        # the room on the backlog's LAST shape is not this
+                        # window's to wait for (the engine says 0 for a
+                        # window that fills the largest shape).
+                        hold = self.hold_s(min(self._pending_items(), self.MAX_WINDOW))
                         remaining = (
                             self._pending[0].arrived + hold - time.monotonic()
                         )
@@ -467,6 +477,9 @@ class VerifierService:
                 "slot_s": round(got_slot - cut_at, 6),
                 "pending_at_cut": left,
                 "pending_at_launch": arrived_since,
+                # The cut loop stops short of an empty queue only at
+                # MAX_WINDOW: whatever is left stayed behind a full window.
+                "cut_full": int(left > 0),
                 "hold_s": round(hold, 6),
                 "held_out": held_out,
                 "in_step": in_step,
@@ -615,6 +628,8 @@ class VerifierService:
         self.split_launches += bool(span.get("split"))
         self.held_out_launches += waits["held_out"]
         self.in_step_launches += waits["in_step"]
+        self.windows_cut_full += waits["cut_full"]
+        self.overflow_items_max = max(self.overflow_items_max, waits["pending_at_cut"])
         for field, counts in (
             ("rung", self.launches_by_rung),
             ("rows_per_chip", self.launches_by_rows_per_chip),
@@ -633,7 +648,8 @@ class VerifierService:
 
     def launch_status(self) -> dict:
         """The stage totals, the counts of launches (promoted, split, by exit
-        of the hold, by shape run, by rows a chip), the slowest launch, and
+        of the hold, cut at MAX_WINDOW, by shape run, by rows a chip), the
+        deepest backlog a cut left queued, the slowest launch, and
         the launches that stalled (above STALL_S in flight) with the longest
         of them that has ended, for the status JSON."""
         with self._cond:
@@ -644,6 +660,8 @@ class VerifierService:
                 "split_launches": self.split_launches,
                 "held_out_launches": self.held_out_launches,
                 "in_step_launches": self.in_step_launches,
+                "windows_cut_full": self.windows_cut_full,
+                "overflow_items_max": self.overflow_items_max,
                 "launches_by_rung": dict(self.launches_by_rung),
                 "launches_by_rows_per_chip": dict(self.launches_by_rows_per_chip),
                 "stalls": self.stalls,

@@ -26,9 +26,11 @@ EVENT_SCHEMAS = {
     # on time.monotonic(): queue_s (window cut minus the arrival of the
     # oldest request in it), slot_s (launch slot acquired minus window
     # cut), pending_at_cut / pending_at_launch (items queued at those two
-    # moments), hold_s (the hold for company the window was granted at its
-    # cut, 0 where none) and the 0/1 pair held_out (cut because that hold
-    # ran out) / in_step (cut early because nobody in step was still out);
+    # moments), cut_full (0/1: the window was cut at service.MAX_WINDOW with
+    # requests left queued behind it, i.e. pending_at_cut above 0), hold_s
+    # (the hold for company the window was granted at its cut, 0 where
+    # none) and the 0/1 pair held_out (cut because that hold ran out) /
+    # in_step (cut early because nobody in step was still out);
     # and, where the sharded engine ran it, the engine's five
     # steps summed over chunks (pad_s, put_s, dispatch_s, wait_s, unpack_s:
     # they add up to secs), rung (the padded slots the chunks really ran
@@ -56,7 +58,7 @@ EVENT_SCHEMAS = {
         "optional": {
             "view", "executed", "requests",
             "queue_s", "slot_s", "pending_at_cut", "pending_at_launch",
-            "hold_s", "held_out", "in_step",
+            "hold_s", "held_out", "in_step", "cut_full",
             "pad_s", "put_s", "dispatch_s", "wait_s", "unpack_s", "rung", "promoted",
             "chunks", "split", "t_dev", "devices", "rows_per_chip", "ahead",
             "apply_s", "loop_us", "shard_us", "pipe_us", "handoff",
@@ -559,12 +561,13 @@ VERIFYD_STATUS_KEYS = {
     # Launches, without --trace: running totals of every stage, launches the
     # engine ran on a larger shape than the smallest fit, windows it ran as
     # several executables, windows whose hold ran out / ended early with
-    # everybody in step back, launches by the padded slots run ({"1024": n,
-    # ...}) and by the rows a chip of their thinnest chunk ({"256": n, ...}),
-    # the slowest one.
+    # everybody in step back, windows cut at MAX_WINDOW with requests left
+    # queued and the most items any cut left queued, launches by the padded
+    # slots run ({"1024": n, ...}) and by the rows a chip of their thinnest
+    # chunk ({"256": n, ...}), the slowest one.
     "stage_seconds", "promoted_launches", "split_launches", "held_out_launches",
-    "in_step_launches", "launches_by_rung", "launches_by_rows_per_chip",
-    "slowest_launch",
+    "in_step_launches", "windows_cut_full", "overflow_items_max",
+    "launches_by_rung", "launches_by_rows_per_chip", "slowest_launch",
     # Launches that were in flight longer than service.STALL_S (each left a
     # launch_stalled record) and the longest of them that has ended.
     "stalls", "longest_stall_s",

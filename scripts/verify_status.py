@@ -120,6 +120,10 @@ def main(argv=None) -> int:
             f"{rung} slots: {n}" for rung, n in sorted(by_rung.items(), key=lambda kv: int(kv[0]))
         ) + "  (hold ran out %d, in step %d)" % (
             status.get("held_out_launches", 0), status.get("in_step_launches", 0)))
+    if "windows_cut_full" in status:
+        print("  full windows    %d cut at the largest window with requests left queued, "
+              "at most %d items behind a cut"
+              % (status["windows_cut_full"], status.get("overflow_items_max", 0)))
     by_rows = status.get("launches_by_rows_per_chip") or {}
     if by_rows:
         print("  rows a chip     " + "  ".join(
@@ -144,6 +148,7 @@ def main(argv=None) -> int:
         "stage_seconds", "slowest_launch", "memory_peak_bytes",
         "promoted_launches", "split_launches", "launches_by_rung", "held_out_launches",
         "in_step_launches", "launches_by_rows_per_chip", "stalls", "longest_stall_s",
+        "windows_cut_full", "overflow_items_max",
     }
     for k in sorted(set(status) - known):
         print(f"  {k:<15} {status[k]}")

@@ -313,6 +313,22 @@ def test_chaos_soak_smoke_f2():
     assert res["ok"], res
 
 
+@pytest.mark.parametrize("n, f, seed", [(16, 5, 5), (31, 10, 12), (31, 10, 13)])
+def test_chaos_soak_smoke_at_the_benchmark_cluster_sizes(n, f, seed):
+    """S1-S3, L1 and (every recovery a restart from the write-ahead log) S5
+    on the seeded simulator at the sizes the benchmark's deployments run:
+    n=16 (f=5) and n=31 (f=10, ISSUE 42). Seed 12 crashes and restarts a
+    replica across a partition under link chaos, seed 13 a crash, a restart
+    and two Byzantine replicas (inside the budget of 10); seed 5 drops
+    messages at n=16."""
+    res = run_one(seed, n, 80, crash_restart=True)
+    assert res["ok"], res
+    assert res["n"] == n == 3 * f + 1 and res["executed"] >= res["submitted"] >= 10
+    assert res["faults_injected"] + res["chaos_dropped"] > 0  # the schedule did something
+    if seed != 5:
+        assert {"crash", "restart"} <= {e.action for e in res["schedule"].events}
+
+
 @pytest.mark.slow
 def test_chaos_soak_long():
     """The acceptance-criteria soak: 25 seeds x 400 steps at f=1 and f=2."""

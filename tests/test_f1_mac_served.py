@@ -86,14 +86,21 @@ def _serve(fastpath: str, tentative: bool) -> dict:
                     t.join(120)
                 assert not errors and len(acked) == CLIENTS * EACH, errors
                 deadline = time.monotonic() + 30
-                while True:  # trailing commits land
+                while True:  # trailing commits land, and the last verdicts with them
                     final = [_status(port) for port in cluster.metrics_ports]
+                    service = daemon.status_json()
+                    # A replica counts an item when it has read its verdict,
+                    # the engine when it has run it: a batch on its way back
+                    # is in one count and not yet in the other. The cluster
+                    # is quiet once the two agree (the tests hold them equal).
                     if len({d["chain_digest"] for d in final}) == 1 and all(
                         d["inbox_depth"] == 0 and d["executed_upto"] == d["committed_upto"]
                         for d in final
-                    ):
+                    ) and service["engine_items"] == sum(d["verify_items"] for d in final):
                         break
-                    assert time.monotonic() < deadline, [d["executed"] for d in final]
+                    assert time.monotonic() < deadline, (
+                        [d["executed"] for d in final], service["engine_items"],
+                        [d["verify_items"] for d in final])
                     time.sleep(0.2)
                 metrics = [stats.parse_prometheus(_fetch(port, "/metrics"))
                            for port in cluster.metrics_ports]
@@ -103,7 +110,7 @@ def _serve(fastpath: str, tentative: bool) -> dict:
                 "acked": acked, "final": final, "metrics": metrics,
                 "items_before": items_before, "seeds": list(cluster.seeds),
                 "pubkeys": [bytes.fromhex(r.pubkey) for r in cluster.config.replicas],
-                "service": daemon.status_json(),
+                "service": service,
             }
     finally:
         daemon.stop()

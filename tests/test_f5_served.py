@@ -134,15 +134,17 @@ def test_a_served_f5_cluster_commits_and_every_ack_passes_the_reference_quorum()
                 deadline = time.monotonic() + 30
                 while True:  # trailing commits land; a straggler catches up
                     final = [_status(port) for port in cluster.metrics_ports]
+                    status = daemon.status_json()
+                    # (and the last verdicts are read: a batch on its way
+                    # back is in the engine's count and not yet in a replica's)
                     if len({d["chain_digest"] for d in final}) == 1 and all(
                         d["inbox_depth"] == 0 for d in final
-                    ):
+                    ) and status["engine_items"] == sum(d["verify_items"] for d in final):
                         break
                     assert time.monotonic() < deadline, [d["executed"] for d in final]
                     time.sleep(0.2)
             finally:
                 _stop(proc)
-        status = daemon.status_json()
     finally:
         daemon.stop()
 

@@ -109,7 +109,10 @@ def test_a_traced_rehearsal_reports_every_per_layer_metric_of_the_cell():
     # The twin on one chip is listed under the same two readers, and the
     # cell under everything its twin reports.
     twin = {m["name"] for m in bench["per_layer"] if "f5-sig-wal.closed" in m["workloads"]}
-    assert twin == listed
+    # (but for the four readers PR 42 brought for the two one-chip f=5 / f=10 cells)
+    assert twin - listed == {"pending_at_cut_mean.closed", "full_window_share.closed",
+                             "gateway_cpu_share.closed", "verifyd_cpu_share.closed"}
+    assert listed <= twin
     assert value["pad_fill.closed"] == pytest.approx(
         value["items_per_launch.closed"] / value["rung_slots_mean.closed"], rel=1e-12)
     assert value["engine_idle_pct.closed"] >= 0 and value["fsyncs_per_req.closed"] > 0

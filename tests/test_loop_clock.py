@@ -168,9 +168,10 @@ def test_the_stage_clock_accounts_for_a_served_replicas_whole_loop(mode):
 # -- the benchmark's readers ------------------------------------------------------
 
 # The cells of PR 38, and behind them the one PR 40 appended to every list
-# its twin is on (f1-sig-wal-mt.closed).
+# its twin is on (f1-sig-wal-mt.closed), and the one PR 42 appended wherever
+# f5-sig-wal.closed is (f10-sig-wal.closed).
 CLOSED4 = ["f1-sig-wal.closed", "f5-sig-wal.closed", "f1-mac-tentative.closed", "f5-sig-wal-x4.closed",
-           "f1-sig-wal-mt.closed"]
+           "f1-sig-wal-mt.closed", "f10-sig-wal.closed"]
 SIG3 = [c for c in CLOSED4 if "mac" not in c]
 RATE = ["f1-sig-wal.rate"]
 NEW = {
@@ -223,8 +224,8 @@ def test_a_new_reader_names_what_exists_and_reads_the_hand_made_run(name):
         "layer": "net loop (core/net.cc)", "moves": moves, "workloads": cells,
     }]
     # (PR 40's ten readers of the shard tier came behind them, and PR 41's
-    # frames_per_send.closed behind those.)
-    assert bench["per_layer"].index(entry[0]) >= len(bench["per_layer"]) - len(NEW) - 11
+    # frames_per_send.closed behind those, and PR 42's four behind that.)
+    assert bench["per_layer"].index(entry[0]) >= len(bench["per_layer"]) - len(NEW) - 15
     known = {c["name"] for c in bench["workloads"]}
     reporting = next(m for m in bench["end_to_end"] if m["name"] == moves)["workloads"]
     assert set(cells) <= known and set(cells) <= set(reporting)
@@ -252,12 +253,12 @@ def test_a_new_reader_names_what_exists_and_reads_the_hand_made_run(name):
 
 
 def test_frames_per_send_is_a_data_file_on_the_reducer_that_is_there():
-    """ISSUE 41's one reader: appended last, in the five closed cells, the
+    """ISSUE 41's one reader: appended last (PR 42's four came behind it), in the closed cells, the
     gain of one counter over the gain of the other on the primary; nothing
     (and no error) on a program that has neither, as the parent commit is."""
     name = "frames_per_send.closed"
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    assert bench["per_layer"][-1] == {
+    assert bench["per_layer"][-5] == {
         "name": name, "unit": "count", "better": "higher", "source": "program_counter",
         "layer": "net loop (core/net.cc)", "moves": "commit_rate", "workloads": CLOSED4,
     }

@@ -437,6 +437,66 @@ def test_the_line_says_which_exit_of_the_hold_cut_the_window(
     assert status["launches_by_rung"] == {}  # this backend runs no shape
 
 
+def test_a_backlog_beyond_the_largest_window_is_cut_there_and_not_held(tmp_path):
+    """ISSUE 42: the hold is asked about the window that will be cut, not
+    about everything queued. With more queued than the largest window holds
+    the engine's rule finds room on the backlog's LAST shape and grants its
+    hold (up to PR 41 the chip-paced n=31 cell read ``hold_s`` 29.6 ms and
+    ``held_out`` 1 on 998 launches in 1,000, every one of them a window that
+    was full): a window that is cut at ``MAX_WINDOW`` cannot grow, so it goes
+    at once, with ``cut_full`` 1 and what is left behind in
+    ``pending_at_cut``."""
+    import json
+
+    trace = tmp_path / "service.jsonl"
+    gate = threading.Event()
+    svc = VerifierService(
+        backend=_sizes_backend([], gate), trace_path=str(trace), inflight=2
+    ).start()
+    svc.MAX_WINDOW = 4  # the largest window, for this service alone
+    svc.hold_s = lambda n: 0.0 if n % 4 == 0 else 30.0  # room on the last shape: its hold
+    a, b, c = _Conn(svc.address), _Conn(svc.address), _Conn(svc.address)
+    results = {}
+    try:
+        ta = a.send_later([_item(1, True)], results, "a")  # in flight until the gate opens
+        deadline = time.monotonic() + 10
+        while svc._flying == 0:
+            assert time.monotonic() < deadline
+            time.sleep(0.005)
+        # b's window has room and a is out: it is held (for 30 s, were it
+        # alone) until c's request takes what is queued past the largest window.
+        tb = b.send_later([_item(2 + k, True) for k in range(3)], results, "b")
+        deadline = time.monotonic() + 10
+        while not svc._pending:
+            assert time.monotonic() < deadline
+            time.sleep(0.005)
+        tb.join(0.05)
+        assert tb.is_alive()
+        tc = c.send_later([_item(6 + k, k == 0) for k in range(4)], results, "c")
+        tb.join(10)
+        tc.join(10)
+        # Both came back while a's launch, with whom nobody is in step, was
+        # still out: neither window waited for it, nor for a hold.
+        assert not gate.is_set() and ta.is_alive()
+        assert results["b"] == [True] * 3 and results["c"] == [True, False, False, False]
+        status = svc.launch_status()
+    finally:
+        gate.set()
+        ta.join(10)
+        for conn in (a, b, c):
+            conn.close()
+        svc.stop()
+    lines = {e["size"]: e for e in map(json.loads, trace.read_text().splitlines())}
+    want = {"hold_s": 0.0, "held_out": 0, "in_step": 0}
+    assert {k: lines[3][k] for k in (*want, "cut_full", "pending_at_cut")} == {
+        **want, "cut_full": 1, "pending_at_cut": 4}
+    assert {k: lines[4][k] for k in (*want, "cut_full", "pending_at_cut")} == {
+        **want, "cut_full": 0, "pending_at_cut": 0}
+    assert lines[3]["queue_s"] < 5 and lines[4]["queue_s"] < 5
+    assert (status["windows_cut_full"], status["overflow_items_max"]) == (1, 4)
+    assert (status["held_out_launches"], status["in_step_launches"]) == (0, 0)
+
+
 @pytest.mark.parametrize("family", ["tcp", "unix"])
 def test_a_whole_cluster_dialing_at_once_is_accepted_without_a_retry(tmp_path, family):
     """32 replicas dial in the same instant, before the server has accepted

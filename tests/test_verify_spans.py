@@ -1139,9 +1139,9 @@ FORMS = {
 # an entry pins its own digest here).
 ACCEPTED_PER_LAYER = 27
 ACCEPTED_DIGEST = "7f2b2c41f15ac07556e716381fd4915806724ddc56d4b01011fdd21d1e6e3800"
-ADDED_CONFIGS = ["f5-sig-wal", "f1-mac-tentative", "f5-sig-wal-x4", "f1-sig-wal-mt"]
+ADDED_CONFIGS = ["f5-sig-wal", "f1-mac-tentative", "f5-sig-wal-x4", "f1-sig-wal-mt", "f10-sig-wal"]
 ADDED_CELLS = ["f5-sig-wal.closed", "f1-mac-tentative.closed", "f5-sig-wal-x4.closed",
-               "f1-sig-wal-mt.closed"]
+               "f1-sig-wal-mt.closed", "f10-sig-wal.closed"]
 # The metrics of this table that PR 32's cell is listed under too (it reports
 # no verify trip, so none of the others).
 ALSO_IN_MAC_CELL = {"engine_idle_pct", "wal_flush_ms_mean"}
@@ -1151,6 +1151,9 @@ X4_CELL, X4_TWIN = "f5-sig-wal-x4.closed", "f5-sig-wal.closed"
 # reports. (The f5-sig-wal.rate cell of the same PR was measured and LEFT
 # OUT: its second set of six runs did not hold half the bound, PERF.md §7.)
 MT_CELL, MT_TWIN = "f1-sig-wal-mt.closed", "f1-sig-wal.closed"
+# PR 42's: the n=31 cluster's cell reports whatever its n=16 sibling reports,
+# and the two of them the four readers that PR brought.
+F10_CELL, F10_TWIN = "f10-sig-wal.closed", "f5-sig-wal.closed"
 
 
 @pytest.mark.parametrize(
@@ -1166,7 +1169,7 @@ def test_new_metric_has_its_reader_and_its_entry(name, form):
     if form == ".closed" and name in ALSO_IN_MAC_CELL:
         cells = cells + ["f1-mac-tentative.closed"]
     if form == ".closed":
-        cells = cells + [X4_CELL, MT_CELL]
+        cells = cells + [X4_CELL, MT_CELL, F10_CELL]
     spec = json.loads((CHIPBENCH / "metrics" / f"{name}{form}.json").read_text())
     assert spec["name"] == name + form
     assert (CHIPBENCH / "reducers" / f"{spec['reducer']}.py").is_file()
@@ -1189,6 +1192,8 @@ def test_the_four_chip_cell_is_listed_wherever_its_twin_is_and_brings_two_reader
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     for m in bench["end_to_end"] + bench["per_layer"]:
         cells = m.get("workloads", [])
+        if cells == [F10_TWIN, F10_CELL]:  # PR 42's four readers, in the two one-chip cells
+            continue
         assert (X4_CELL in cells) == (X4_TWIN in cells), m["name"]
     # Two readings of the launch lines' new fields, on the reducer that is
     # there, in the two f=5 cells: the chips a window's executables are
@@ -1200,7 +1205,8 @@ def test_the_four_chip_cell_is_listed_wherever_its_twin_is_and_brings_two_reader
                         "args": {"fields": [field], "stat": "mean"}}
         assert [m for m in bench["per_layer"] if m["name"] == name] == [{
             "name": name, "unit": unit, "better": "higher", "source": "program_counter",
-            "layer": "verifyd engine", "moves": "commit_rate", "workloads": [X4_TWIN, X4_CELL],
+            "layer": "verifyd engine", "moves": "commit_rate",
+            "workloads": [X4_TWIN, X4_CELL, F10_CELL],  # PR 42's cell behind them: one chip, 1.0
         }]
     names = [m["name"] for m in bench["per_layer"]]
     at = names.index("mesh_chips.closed")  # appended by PR 36, as a pair

@@ -264,7 +264,10 @@ def test_kill9_restart_from_disk(impl):
         client = PbftClient(cluster.config)
         _drive(client, 1, 41)  # checkpoints at 16 and 32
         wal_path = Path(cluster.tmpdir.name) / "wal" / "replica-3.wal"
-        time.sleep(0.6)
+        # Killed once its first checkpoint is stable (the metrics line says
+        # so: low_mark), not 0.6 s after the last request: on a busy machine
+        # the asyncio replica had not got there and the log held none.
+        _wait_metric(cluster, 3, lambda m: m["low_mark"] >= 16)
         cluster.kill(3, hard=True)
         st = W.replay(str(wal_path))
         assert st.checkpoint is not None and st.checkpoint[0] >= 16
