@@ -32,7 +32,11 @@ Any failed check, a child that exits early, a service that is not
 non-zero exit; with no TPU it fails fast and prints no result. The last
 line of stdout is ``{"ok": true, "device": {...}}`` with the device as
 JAX reports it (through verifyd's status). Compile seconds and device
-facts above it are set-up facts, not performance.
+facts above it are set-up facts, not performance. Beside each shape's
+launch cost the log says where its long multiply chains run (``vmem`` /
+``xla``, as the engine read it from the executable's own HLO), and a shape
+whose executable did not take the chains ``ed25519.chains_for`` gives a TPU
+shape of its rows a chip (``vmem`` from 256 rows) fails the run.
 """
 
 from __future__ import annotations
@@ -566,17 +570,26 @@ def main() -> int:
               "the mesh does not span every device JAX sees")
         check(status["warmed_shapes"] == list(LADDER),
               f"warmed shapes {status['warmed_shapes']} != ladder {LADDER}")
+        # The rule a TPU shape is compiled by (imports jax, touches no backend),
+        # held against what each executable says of itself (parallel.chains_of).
+        from pbft_tpu.crypto.ed25519 import chains_for
+
         for shape in warm["per_shape"]:
             check(
                 len(shape["devices"]) == status["devices_seen"]
                 and shape["rows_per_device"] * len(shape["devices"]) == shape["size"],
                 f"shape {shape['size']} is not sharded over every device: {shape}",
             )
+            check(
+                shape["chains"] == chains_for(shape["rows_per_device"], "tpu"),
+                f"shape {shape['size']} ({shape['rows_per_device']} rows a chip) runs its "
+                f"multiply chains on {shape['chains']!r}, not as a TPU shape of its rows does",
+            )
             log(f"shape {shape['size']}: {shape['seconds']}s "
                 f"({'cache hit' if shape['cache_hit'] else 'compiled'}), input "
                 f"sharded over devices {shape['devices']}, "
                 f"{shape['rows_per_device']} rows each, one launch "
-                f"{1e3 * shape['launch_s']:.2f} ms")
+                f"{1e3 * shape['launch_s']:.2f} ms, multiply chains: {shape['chains']}")
         log(f"warm-up: cold_compile_s={warm['cold_compile_s']} "
             f"warm_load_s={warm['warm_load_s']} compiled={warm['compiled']} "
             f"cache_hits={warm['cache_hits']} cache_dir={warm['cache_dir']} "

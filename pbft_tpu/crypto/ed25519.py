@@ -25,6 +25,8 @@ field.py). All control flow is static; everything vmaps/jits.
 
 from __future__ import annotations
 
+import math
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -102,34 +104,44 @@ def point_neg(p):
     return (F.neg(x), y, z, F.neg(t))
 
 
-def _use_pallas() -> bool:
-    """PBFT_PALLAS=1 routes the three long multiply chains (pow_p58, inv,
-    Shamir ladder) through the fused Pallas kernels in pallas_kernels.py.
-    Read at trace time — set it before the first verify of a given batch
-    shape. The kernels compile for the TPU only; anywhere else they run
-    under the Pallas INTERPRETER (orders of magnitude slower than the XLA
-    path), and only when PBFT_PALLAS_INTERPRET=1 asks for it by name
-    (equivalence tests do) — PBFT_PALLAS=1 alone off-TPU is an error, not
-    a silent return to the XLA path."""
-    import os
+# The rows a chip under which a compiled shape keeps the XLA chains. A tile
+# of pallas_kernels is 1,024 items (a limb a whole vector register) and costs
+# the same part full as full, so the line is where a part-full tile beats
+# the XLA chains: at 256 rows a chip it does (3.61 ms on the device for the
+# 256-slot program against 5.318, one v5e, PR 43); at 16 and 64 rows nothing
+# is served (the serving table sends those windows to the 256-slot program).
+VMEM_CHAIN_ROWS = 256
 
-    if os.environ.get("PBFT_PALLAS") != "1":
-        return False
-    if os.environ.get("PBFT_PALLAS_INTERPRET") == "1":
-        return True
-    import jax
 
-    if jax.default_backend() != "tpu":
-        raise RuntimeError(
-            f"PBFT_PALLAS=1 on backend {jax.default_backend()!r}: the Pallas "
-            "kernels compile for the TPU only (set PBFT_PALLAS_INTERPRET=1 "
-            "to run them under the interpreter)"
-        )
-    return True
+def chains_for(rows: int, backend: str | None = None) -> str:
+    """``"vmem"`` or ``"xla"``: how a program compiled for ``rows`` rows a
+    chip runs its three long multiply chains (pow_p58, inv, the Shamir
+    ladder). On a TPU backend a shape of ``VMEM_CHAIN_ROWS`` rows or more
+    runs them out of VMEM (pallas_kernels.py, in tiles of 1,024 padded with
+    zeros); a smaller shape, and every other backend,
+    runs the XLA chains. One algorithm, two lowerings, chosen by what the
+    code can see: the backend and the static row count, as field._pick_mul
+    chooses by the backend. No environment variable, flag or key enters.
+
+    Measured and sized for ONE chip, the v5e: the row line (3.61 ms against
+    5.318), the 9.9 MiB a tile holds and pallas_kernels' 19.8 MiB
+    ``vmem_limit_bytes`` are that chip's. Any backend named ``tpu`` gets
+    ``"vmem"``; a generation that gives a kernel less VMEM fails at Mosaic's
+    compile in warm-up (``verifyd --backend jax`` then exits non-zero) and
+    does not fall back to XLA. What a shape DID take is
+    ``parallel.chains_of`` of its executable, not this rule."""
+    backend = backend or jax.default_backend()
+    return "vmem" if backend == "tpu" and rows >= VMEM_CHAIN_ROWS else "xla"
+
+
+def _use_pallas(x) -> bool:
+    """Whether the program being traced for ``x`` (…, k) takes the VMEM
+    chains: :func:`chains_for` of its rows."""
+    return chains_for(math.prod(x.shape[:-1])) == "vmem"
 
 
 def _impl_pow_p58(z):
-    if _use_pallas():
+    if _use_pallas(z):
         from . import pallas_kernels
 
         return pallas_kernels.pow_p58(z)
@@ -137,7 +149,7 @@ def _impl_pow_p58(z):
 
 
 def _impl_inv(z):
-    if _use_pallas():
+    if _use_pallas(z):
         from . import pallas_kernels
 
         return pallas_kernels.inv(z)
@@ -270,7 +282,7 @@ def verify_kernel(pub, msg, sig):
     with jax.named_scope("decompress"):
         ok_a, a_pt = decompress(pub)
     with jax.named_scope("ladder"):
-        if _use_pallas():
+        if _use_pallas(pub):
             from . import pallas_kernels
 
             p = pallas_kernels.ladder(

@@ -34,6 +34,7 @@ control flow, so it jits and vmaps cleanly onto TPU.
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 P = 2**255 - 19
@@ -166,17 +167,20 @@ def _mul_conv(a, b):
 def _pick_mul():
     import os
 
-    impl = os.environ.get("PBFT_FIELD_MUL", "auto")
-    if impl == "conv":
-        return _mul_conv
-    if impl == "schoolbook":
-        return _mul_schoolbook
     import jax
 
     # conv wins on TPU-class backends; the shifted-accumulate loop wins on
     # XLA:CPU (measured ~2x each way). A backend that fails to initialize
-    # raises here: answering "cpu" would hide a dead chip.
-    return _mul_schoolbook if jax.default_backend() == "cpu" else _mul_conv
+    # raises here: answering "cpu" would hide a dead chip. On a TPU nothing
+    # else is consulted; off it PBFT_FIELD_MUL may name either lowering (the
+    # CPU dry run asks for conv, which compiles faster there).
+    backend = jax.default_backend()
+    impl = "auto" if backend == "tpu" else os.environ.get("PBFT_FIELD_MUL", "auto")
+    if impl == "conv":
+        return _mul_conv
+    if impl == "schoolbook":
+        return _mul_schoolbook
+    return _mul_schoolbook if backend == "cpu" else _mul_conv
 
 
 def mul(a, b):
@@ -184,7 +188,7 @@ def mul(a, b):
 
     Columns |col| < 32 * 2^18 = 2^23; the 38-fold keeps the reduced
     columns < 39 * 2^23 < 2^28.3 — inside int32 with margin. Two
-    implementations (picked per backend, override with PBFT_FIELD_MUL)."""
+    implementations (picked per backend; off the TPU PBFT_FIELD_MUL overrides)."""
     global _MUL_IMPL
     if _MUL_IMPL is None:
         _MUL_IMPL = _pick_mul()
@@ -258,8 +262,12 @@ def pow_p58(z):
     return mul(pow2k(z_250_0, 2), z)
 
 
+@jax.jit
 def canon(x):
-    """Canonical form: limbs in [0, 2^8), value in [0, p)."""
+    """Canonical form: limbs in [0, 2^8), value in [0, p). Jitted so that a
+    program that canonicalizes seven times (a verification does) traces and
+    lowers these ~800 limb operations once, as a function it calls: what
+    XLA compiles is the same, what warm-up pays for each shape is not."""
     x = carry_seq(carry_seq(x))
     # Force non-negativity: add 2p (== 0 mod p); the value may have been a
     # small negative after signed folds.

@@ -280,7 +280,9 @@ class VerifierService:
         # the step that held it (written under _cond by the launch threads).
         # promoted_launches: launches the engine ran on a larger shape than
         # the smallest that fits (its span's ``promoted``); split_launches:
-        # windows it ran as several executables (its span's ``split``).
+        # windows it ran as several executables (its span's ``split``);
+        # fused_launches: windows with slots on executables that run the
+        # multiply chains out of VMEM (its span's ``fused`` above 0).
         # held_out_launches / in_step_launches: windows whose hold ran out,
         # and windows cut early because nobody in step was still out;
         # windows_cut_full: windows cut at MAX_WINDOW with requests left
@@ -292,6 +294,7 @@ class VerifierService:
         self.stage_seconds = {"queue_s": 0.0, "slot_s": 0.0}
         self.promoted_launches = 0
         self.split_launches = 0
+        self.fused_launches = 0
         self.held_out_launches = 0
         self.in_step_launches = 0
         self.windows_cut_full = 0
@@ -626,6 +629,7 @@ class VerifierService:
             self.stage_seconds[name] = self.stage_seconds.get(name, 0.0) + took
         self.promoted_launches += bool(span.get("promoted"))
         self.split_launches += bool(span.get("split"))
+        self.fused_launches += bool(span.get("fused"))
         self.held_out_launches += waits["held_out"]
         self.in_step_launches += waits["in_step"]
         self.windows_cut_full += waits["cut_full"]
@@ -647,7 +651,7 @@ class VerifierService:
             }
 
     def launch_status(self) -> dict:
-        """The stage totals, the counts of launches (promoted, split, by exit
+        """The stage totals, the counts of launches (promoted, split, fused, by exit
         of the hold, cut at MAX_WINDOW, by shape run, by rows a chip), the
         deepest backlog a cut left queued, the slowest launch, and
         the launches that stalled (above STALL_S in flight) with the longest
@@ -658,6 +662,7 @@ class VerifierService:
             counts = {
                 "promoted_launches": self.promoted_launches,
                 "split_launches": self.split_launches,
+                "fused_launches": self.fused_launches,
                 "held_out_launches": self.held_out_launches,
                 "in_step_launches": self.in_step_launches,
                 "windows_cut_full": self.windows_cut_full,

@@ -247,6 +247,7 @@ class ShardedVerifyEngine:
         self._mesh = None
         self._compiled: dict = {}  # padded size -> jax.stages.Compiled
         self._chips: dict = {}  # padded size -> devices its input is sharded over
+        self._chains: dict = {}  # padded size -> "vmem" | "xla" (parallel.chains_of)
         self._launch_s: dict = {}  # padded size -> seconds, read at warm-up
         self._serves: dict = {}  # smallest fitting size -> size it runs at
         self._plans: list = []  # plan_table(): [(largest n, shapes run)]
@@ -286,7 +287,10 @@ class ShardedVerifyEngine:
         Returns (and stores in ``self.stats``) the warm-up accounting, as
         set-up facts: per shape the seconds spent, whether the persistent
         cache answered, the devices its input sharding spans and what one
-        launch of it costs (``launch_s``, :meth:`_measure`);
+        launch of it costs (``launch_s``, :meth:`_measure`) and how it runs
+        its long multiply chains (``chains``: ``"vmem"`` or ``"xla"``, read
+        from the executable's own HLO by ``parallel.chains_of``, not from the
+        rule ``ed25519.chains_for`` that shaped it);
         ``cold_compile_s`` sums the shapes that traced+compiled,
         ``warm_load_s`` the shapes the cache answered; ``serving_table`` is
         :func:`serving_table` of every shape's ``launch_s`` and ``chunk_plan``
@@ -296,7 +300,7 @@ class ShardedVerifyEngine:
             self.init_backend()
         import jax
 
-        from ..parallel import compile_sharded
+        from ..parallel import chains_of, lower_sharded
 
         hits: list = []
 
@@ -330,9 +334,10 @@ class ShardedVerifyEngine:
                         warnings.filterwarnings(
                             "ignore", message="Some donated buffers"
                         )
-                        compiled = compile_sharded(
+                        lowered = lower_sharded(
                             self._mesh, size, kernel=self._kernel
                         )
+                        compiled = lowered.compile()
                     secs = time.perf_counter() - t0
                     hit = bool(hits)
                     stats["cache_hits" if hit else "compiled"] += 1
@@ -343,20 +348,22 @@ class ShardedVerifyEngine:
                     in_sharding = compiled.input_shardings[0][0]
                     launch_s = round(self._measure(size, compiled), 6)
                     chips = sorted(d.id for d in in_sharding.device_set)
+                    rows = in_sharding.shard_shape((size, 128))[0]
+                    chains = chains_of(compiled, lowered)
                     stats["per_shape"].append(
                         {
                             "size": size,
                             "seconds": round(secs, 3),
                             "cache_hit": hit,
                             "devices": chips,
-                            "rows_per_device": in_sharding.shard_shape(
-                                (size, 128)
-                            )[0],
+                            "rows_per_device": rows,
                             "launch_s": launch_s,
+                            "chains": chains,
                         }
                     )
                     self._launch_s[size] = launch_s
                     self._chips[size] = len(chips)
+                    self._chains[size] = chains
                     self._compiled[size] = compiled
                     stats["shapes"].append(size)
                 stats.update(self._route(self._launch_s))
@@ -545,6 +552,9 @@ class ShardedVerifyEngine:
                 t_dev=round(t_dev, 6),
                 devices=chips,
                 rows_per_chip=thinnest // chips,
+                fused=round(
+                    sum(s for s in plan if self._chains.get(s) == "vmem") / sum(plan), 4
+                ),
             )
         return out
 

@@ -11,7 +11,9 @@ consensus core.
 
 from .verifier import (
     QuorumResult,
+    chains_of,
     compile_sharded,
+    lower_sharded,
     make_mesh,
     sharded_verify,
     quorum_certify,
@@ -26,7 +28,9 @@ from .multihost import (
 
 __all__ = [
     "QuorumResult",
+    "chains_of",
     "compile_sharded",
+    "lower_sharded",
     "make_mesh",
     "sharded_verify",
     "quorum_certify",

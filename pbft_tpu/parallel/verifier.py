@@ -61,23 +61,51 @@ def sharded_verify(
     The block (``crypto.batch.pad_batch``) is cut into its three columns
     inside the jit, where the slices fuse into the kernel's first reads: one
     array to stage, one transfer a window (the call's own, where it is
-    handed a host array). Shard-local compute only — XLA partitions the
-    vmapped kernel with no collectives. B must be divisible by the mesh
-    size. ``donate=True`` marks the input buffer donated so XLA reuses its
+    handed a host array). Shard-local compute only, under ``shard_map`` over
+    the batch axis: each chip runs the kernel on its ``B / chips`` rows with
+    no collectives, and the kernel is traced for THAT row count (XLA cannot
+    partition a Mosaic call, and ``ed25519.chains_for`` decides by the rows
+    a chip holds); a mesh of one runs the same code. B must be divisible by
+    the mesh size. ``donate=True`` marks the input buffer donated so XLA reuses its
     device memory across launches (the verify service re-stages every
     window, so its input is dead the moment the launch reads it).
     ``kernel`` overrides the Ed25519 kernel, with its ``(pubs, msgs, sigs)``
     signature (tests substitute a cheap stand-in to exercise the serving
     plumbing without a minutes-long compile).
     """
-    spec = NamedSharding(mesh, P(axis))
     kern = kernel or verify_kernel
 
-    def fn(block):
-        block = jax.lax.with_sharding_constraint(block, spec)
+    def fn(block):  # the executable's name, ``jit_fn``, is how a trace finds it
         return kern(*split_block(block))
 
-    return jax.jit(fn, donate_argnums=(0,) if donate else ())
+    # check_vma=False: the crypto kernel's lax loops carry broadcast curve
+    # constants whose varying-axis annotation the checker can't infer.
+    local = shard_map(
+        fn, mesh=mesh, in_specs=P(axis), out_specs=P(axis), check_vma=False
+    )
+    return jax.jit(local, donate_argnums=(0,) if donate else ())
+
+
+def lower_sharded(
+    mesh: Mesh,
+    size: int,
+    axis: str = "batch",
+    donate: bool = True,
+    kernel=None,
+):
+    """The sharded verifier traced and lowered for one fixed window size: a
+    ``jax.stages.Lowered`` that takes ONE ``(size, 128)`` uint8 block, rows
+    sharded over ``axis``. :func:`compile_sharded` compiles it."""
+    if size % mesh.devices.size:
+        raise ValueError(
+            f"window {size} not divisible by mesh size {mesh.devices.size}"
+        )
+    fn = sharded_verify(mesh, axis, donate=donate, kernel=kernel)
+    return fn.lower(
+        jax.ShapeDtypeStruct(
+            (size, 128), jnp.uint8, sharding=NamedSharding(mesh, P(axis))
+        )
+    )
 
 
 def compile_sharded(
@@ -93,20 +121,25 @@ def compile_sharded(
     persistent verify service warms every `_PAD_LADDER` shape at startup
     so no request ever pays tracing or compilation (the persistent
     on-disk cache makes the warm-restart compile cache-hit cheap).
-    Returns a ``jax.stages.Compiled`` that takes ONE ``(size, 128)`` uint8
-    block, rows sharded over ``axis``: called on a host array it moves the
+    Returns a ``jax.stages.Compiled``: called on a host array it moves the
     block itself, in one transfer (what the verify service does).
     """
-    if size % mesh.devices.size:
-        raise ValueError(
-            f"window {size} not divisible by mesh size {mesh.devices.size}"
-        )
-    fn = sharded_verify(mesh, axis, donate=donate, kernel=kernel)
-    return fn.lower(
-        jax.ShapeDtypeStruct(
-            (size, 128), jnp.uint8, sharding=NamedSharding(mesh, P(axis))
-        )
-    ).compile()
+    return lower_sharded(mesh, size, axis, donate, kernel).compile()
+
+
+def chains_of(compiled, lowered=None) -> str:
+    """``"vmem"`` or ``"xla"``: where a compiled program runs its long
+    multiply chains, read from the program and not from the rule that was
+    meant to shape it (``ed25519.chains_for``): a Mosaic kernel is a
+    ``tpu_custom_call`` in the executable's HLO, and the XLA chains have
+    none. So a caller that vmaps the kernel, another leading shape or a
+    later edit to the rule cannot compile one thing while the counters
+    report the other. Where the runtime hands no text back for an
+    executable, the ``lowered`` module it was compiled from is read."""
+    text = compiled.as_text() or (lowered.as_text() if lowered else None)
+    if not text:
+        raise RuntimeError("neither the executable nor its module gives text to read")
+    return "vmem" if "tpu_custom_call" in text else "xla"
 
 
 @jax.tree_util.register_dataclass

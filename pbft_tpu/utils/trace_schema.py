@@ -39,8 +39,10 @@ EVENT_SCHEMAS = {
     # window) with the 0/1 field split (more than one: the engine's chunk
     # plan, or a window beyond the largest shape), t_dev (absolute stamp
     # at the first dispatch), devices (the chips the window's executables
-    # are sharded over, as their input sharding said at warm-up) and
-    # rows_per_chip (the slots of the window's smallest chunk over devices).
+    # are sharded over, as their input sharding said at warm-up),
+    # rows_per_chip (the slots of the window's smallest chunk over devices)
+    # and fused (the share of rung that ran on executables whose multiply
+    # chains live in VMEM, 0 to 1: warm_stats.per_shape[].chains "vmem").
     # pbftd's line is one BATCH of one replica, written when its verdicts
     # have been worked through; its 0/1 field ahead says the next batch was
     # launched before this one's verdicts were applied, apply_s (kept spans
@@ -60,7 +62,7 @@ EVENT_SCHEMAS = {
             "queue_s", "slot_s", "pending_at_cut", "pending_at_launch",
             "hold_s", "held_out", "in_step", "cut_full",
             "pad_s", "put_s", "dispatch_s", "wait_s", "unpack_s", "rung", "promoted",
-            "chunks", "split", "t_dev", "devices", "rows_per_chip", "ahead",
+            "chunks", "split", "t_dev", "devices", "rows_per_chip", "fused", "ahead",
             "apply_s", "loop_us", "shard_us", "pipe_us", "handoff",
         },
         "emitters": {"server.py", "service.py", "net.cc"},
@@ -564,8 +566,10 @@ VERIFYD_STATUS_KEYS = {
     # everybody in step back, windows cut at MAX_WINDOW with requests left
     # queued and the most items any cut left queued, launches by the padded
     # slots run ({"1024": n, ...}) and by the rows a chip of their thinnest
-    # chunk ({"256": n, ...}), the slowest one.
-    "stage_seconds", "promoted_launches", "split_launches", "held_out_launches",
+    # chunk ({"256": n, ...}), the slowest one; fused_launches: windows with
+    # slots on executables that run the multiply chains out of VMEM.
+    "stage_seconds", "promoted_launches", "split_launches", "fused_launches",
+    "held_out_launches",
     "in_step_launches", "windows_cut_full", "overflow_items_max",
     "launches_by_rung", "launches_by_rows_per_chip", "slowest_launch",
     # Launches that were in flight longer than service.STALL_S (each left a
@@ -588,6 +592,9 @@ VERIFYD_PER_SHAPE_KEYS = {
     # Seconds one launch of the shape takes on the engine's own device(s):
     # the least of a few timed launches of the all-pad window at warm-up.
     "launch_s",
+    # "vmem" or "xla": where the shape's three long multiply chains run
+    # (crypto.ed25519.chains_for of its rows a device).
+    "chains",
 }
 
 # -- health document (ISSUE 16) ----------------------------------------------

@@ -91,6 +91,14 @@ def main(argv=None) -> int:
         ]
         if costs:
             print("  launch cost     " + "  ".join(costs))
+        chains = [
+            f"{shape['size']}: {shape['chains']}"
+            for shape in warm.get("per_shape") or []
+            if "chains" in shape
+        ]
+        if chains:
+            print("  multiply chains " + "  ".join(chains) + "  (%d launches with slots "
+                  "on the VMEM chains)" % status.get("fused_launches", 0))
         table = warm.get("serving_table")
         if table:
             print("  serving table   %s  (%d launches promoted)" % (
@@ -146,7 +154,8 @@ def main(argv=None) -> int:
     known = {
         "state", "devices", "uptime_s", "warmed_shapes", "warm_stats",
         "stage_seconds", "slowest_launch", "memory_peak_bytes",
-        "promoted_launches", "split_launches", "launches_by_rung", "held_out_launches",
+        "promoted_launches", "split_launches", "fused_launches", "launches_by_rung",
+        "held_out_launches",
         "in_step_launches", "launches_by_rows_per_chip", "stalls", "longest_stall_s",
         "windows_cut_full", "overflow_items_max",
     }

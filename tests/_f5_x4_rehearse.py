@@ -36,11 +36,16 @@ sys.path[:0] = [str(BENCH), str(BENCH / "tools"), str(TESTS), str(TESTS.parent)]
 CHIPS = 4
 
 
-def host_arithmetic(lie=None):
+def host_arithmetic(lie=None, delay_s: float = 0.0):
     """A kernel that stands where ``crypto.ed25519.verify_kernel`` stands:
     the same columns in, one verdict a row out, decided by the native host
     verifier. ``lie(row, item, verdict)`` may alter a verdict by the row of
-    the executable its item sat in (``test_chip_smoke.py``'s faulty chip)."""
+    the executable its item sat in (``test_chip_smoke.py``'s faulty chip):
+    the kernel is traced for one chip's rows (``shard_map``), so that is the
+    row it sees behind the rows of the chips before its own. ``delay_s``:
+    what a chip's share of a launch takes at least (the chips' callbacks run
+    side by side, so a launch of a few items is over in a millisecond: too
+    soon for a test that needs launches in flight while requests queue)."""
 
     def kernel(pubs, msgs, sigs):
         import jax
@@ -49,16 +54,22 @@ def host_arithmetic(lie=None):
 
         from pbft_tpu import native
 
-        def decide(p, m, s):
+        def decide(first_row, p, m, s):
+            time.sleep(delay_s)
             p, m, s = (np.asarray(a) for a in (p, m, s))
             items = [(p[i].tobytes(), m[i].tobytes(), s[i].tobytes()) for i in range(len(p))]
             out = [bool(v) for v in native.verify_batch(items)]
             if lie is not None:
-                out = [lie(row, item, v) for row, (item, v) in enumerate(zip(items, out))]
+                out = [
+                    lie(row, item, v)
+                    for row, (item, v) in enumerate(zip(items, out), int(first_row))
+                ]
             return np.asarray(out, dtype=np.bool_)
 
+        rows = pubs.shape[0]
         return jax.pure_callback(
-            decide, jax.ShapeDtypeStruct((pubs.shape[0],), jnp.bool_), pubs, msgs, sigs
+            decide, jax.ShapeDtypeStruct((rows,), jnp.bool_),
+            jax.lax.axis_index("batch") * rows, pubs, msgs, sigs,
         )
 
     return kernel

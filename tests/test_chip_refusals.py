@@ -1,7 +1,7 @@
 """The ways the served path could quietly run on the CPU instead of the
 chip, each pinned to fail loudly (all on CPU, no device needed): bench.py's
 default arm, the native build's staleness check, the one-process-per-chip
-launcher rule, and PBFT_PALLAS off the TPU. (verifyd's own refusals live in
+launcher rule, and that no switch chooses a multiply path. (verifyd's own refusals live in
 test_service_coalesce.py, chip_smoke.py's in test_chip_smoke.py.)"""
 
 import json
@@ -170,17 +170,37 @@ def test_launcher_refuses_several_inprocess_jax_replicas_off_the_cpu_arm(
     LocalCluster(n=4, verifier="jax", impl="py")  # the CPU test arm
 
 
-# -- the Pallas arm never falls back in silence --------------------------------
+# -- no switch chooses a multiply path ------------------------------------------
 
 
-def test_pbft_pallas_off_the_tpu_is_an_error_not_a_silent_xla_run(monkeypatch):
-    from pbft_tpu.crypto import ed25519
+def test_no_switch_chooses_a_multiply_path(monkeypatch):
+    """Up to PR 42 ``PBFT_PALLAS=1`` chose the Pallas kernels and was an
+    error off the TPU. Since PR 43 nothing in the environment chooses: the
+    VMEM chains are taken by the backend and the rows a chip alone
+    (``ed25519.chains_for``), so the old switches are inert, on a CPU every
+    shape runs the XLA chains, and on a TPU ``PBFT_FIELD_MUL`` is not read."""
+    from pbft_tpu.crypto import ed25519, field
+    import jax.numpy as jnp
 
-    monkeypatch.setenv("PBFT_PALLAS", "1")
-    monkeypatch.delenv("PBFT_PALLAS_INTERPRET", raising=False)
-    with pytest.raises(RuntimeError, match="PBFT_PALLAS=1 on backend 'cpu'"):
-        ed25519._use_pallas()
-    monkeypatch.setenv("PBFT_PALLAS_INTERPRET", "1")
-    assert ed25519._use_pallas() is True  # asked for by name
-    monkeypatch.delenv("PBFT_PALLAS")
-    assert ed25519._use_pallas() is False
+    for name in ("PBFT_PALLAS", "PBFT_PALLAS_INTERPRET"):
+        monkeypatch.setenv(name, "1")
+    monkeypatch.setenv("PBFT_PALLAS_TB", "8")
+    assert ed25519._use_pallas(jnp.zeros((4096, 32), jnp.uint8)) is False
+    assert ed25519.chains_for(4096) == "xla" and ed25519.chains_for(4096, "tpu") == "vmem"
+    # The XLA multiply: PBFT_FIELD_MUL may name either lowering off the TPU
+    # (the CPU dry run asks for conv); on a TPU backend it is conv, unasked.
+    import jax
+
+    monkeypatch.setenv("PBFT_FIELD_MUL", "conv")
+    assert field._pick_mul() is field._mul_conv
+    monkeypatch.setenv("PBFT_FIELD_MUL", "schoolbook")
+    assert field._pick_mul() is field._mul_schoolbook
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert field._pick_mul() is field._mul_conv
+    monkeypatch.delenv("PBFT_FIELD_MUL")
+    assert field._pick_mul() is field._mul_conv
+    # and no file of the package or the scripts reads the old switches
+    root = Path(__file__).resolve().parent.parent
+    for path in [*root.glob("pbft_tpu/**/*.py"), *root.glob("scripts/*.py"),
+                 root / "chip_smoke.py", root / "bench.py", root / "__graft_entry__.py"]:
+        assert "PBFT_PALLAS" not in path.read_text(), path

@@ -128,7 +128,9 @@ def _four_chips(shapes, lie=None):
             self.platform = "tpu"
             self.devices_seen = self.device_count
 
-    return Engine(shapes=shapes, devices=4, kernel=host_arithmetic(lie))
+    # 20 ms a launch: the stage's three blocker windows have to hold both
+    # launch slots while its four part-windows arrive and queue together.
+    return Engine(shapes=shapes, devices=4, kernel=host_arithmetic(lie, delay_s=0.02))
 
 
 def test_device_stage_plants_every_class_in_every_chips_rows(service, monkeypatch):

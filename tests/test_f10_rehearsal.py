@@ -32,7 +32,7 @@ BROUGHT = {
 # Fields that only the sharded engine writes into a launch's span (the double
 # here is the benchmark's stub); ``test_f5_x4_rehearsal.py`` holds their
 # readers with the real engine.
-ENGINE_ONLY = {"mesh_chips.closed", "rows_per_chip_mean.closed"}
+ENGINE_ONLY = {"mesh_chips.closed", "rows_per_chip_mean.closed", "fused_launch_share.closed"}
 
 
 def _rehearse(cell: str, seconds: int, trace: int):
@@ -139,12 +139,13 @@ def test_the_configuration_is_f5_sig_wal_key_for_key_at_31_replicas():
 def test_the_cell_is_listed_wherever_its_sibling_is_and_brings_four_readers():
     bench = _bench()
     names = [m["name"] for m in bench["per_layer"]]
-    assert names[-4:] == list(BROUGHT)  # appended, behind everything the benchmark had
-    for m in bench["per_layer"][-4:]:
+    at = names.index(next(iter(BROUGHT)))  # appended by PR 42, as four; PR 43's two stand behind
+    assert names[at : at + 4] == list(BROUGHT) and at == 89
+    for m in bench["per_layer"][at : at + 4]:
         unit, source, layer = BROUGHT[m["name"]]
         assert m == {"name": m["name"], "unit": unit, "better": "lower", "source": source,
                      "layer": layer, "moves": "commit_rate", "workloads": [TWIN, CELL]}
-        assert layer in {x["layer"] for x in bench["per_layer"][:-4]}  # a layer the benchmark names
+        assert layer in {x["layer"] for x in bench["per_layer"][:at]}  # a layer the benchmark names
         spec = json.loads((ROOT / "chipbench" / "metrics" / f"{m['name']}.json").read_text())
         assert (ROOT / "chipbench" / "reducers" / f"{spec['reducer']}.py").is_file()
     for m in bench["end_to_end"] + bench["per_layer"]:

@@ -59,7 +59,9 @@ def test_a_traced_rehearsal_reports_every_per_layer_metric_of_the_cell():
     # find nothing and are left out; every other reader reports a number.
     from_trace = {m["name"] for m in bench["per_layer"] if m["source"] == "device_trace"}
     assert from_trace & listed == {"kernel_ms_per_launch.closed", "device_idle_pct.closed"}
-    assert set(line["metrics"]) == listed - from_trace and len(listed) == 28
+    # (nor does the benchmark's stub engine write the sharded engine's `fused`)
+    assert set(line["metrics"]) == listed - from_trace - {"fused_launch_share.closed"}
+    assert len(listed) == 29
     # No verify trip on a reply's path, so no launch to make ahead: the cell
     # is not listed under ISSUE 37's readers nor under the apply's clock, and
     # its line carries none of them.

@@ -97,12 +97,13 @@ def test_a_traced_rehearsal_reports_every_per_layer_metric_of_the_cell():
     assert from_trace & listed == {
         "kernel_ms_per_launch.closed", "verify_kernel_roofline.closed", "device_idle_pct.closed",
     }
-    assert set(line["metrics"]) == listed - from_trace and len(listed) == 45
+    assert set(line["metrics"]) == listed - from_trace and len(listed) == 46
     value = {k: v["value"] for k, v in line["metrics"].items()}
     assert all(isinstance(v, (int, float)) for v in value.values())
     # The reading that says the cell ran over four chips, and the one that
     # explains its cost table; both from the launch lines of the window.
     assert value["mesh_chips.closed"] == 4.0
+    assert value["fused_launch_share.closed"] == 0.0  # a stand-in kernel has no VMEM chains
     rows = [e["rows_per_chip"] for e in line["launches"]]
     assert value["rows_per_chip_mean.closed"] == pytest.approx(sum(rows) / len(rows), rel=1e-12)
     assert 4 <= value["rows_per_chip_mean.closed"] <= 1024

@@ -224,8 +224,9 @@ def test_a_new_reader_names_what_exists_and_reads_the_hand_made_run(name):
         "layer": "net loop (core/net.cc)", "moves": moves, "workloads": cells,
     }]
     # (PR 40's ten readers of the shard tier came behind them, and PR 41's
-    # frames_per_send.closed behind those, and PR 42's four behind that.)
-    assert bench["per_layer"].index(entry[0]) >= len(bench["per_layer"]) - len(NEW) - 15
+    # frames_per_send.closed behind those, PR 42's four behind that, and
+    # PR 43's two fused_launch_share readers last.)
+    assert bench["per_layer"].index(entry[0]) >= len(bench["per_layer"]) - len(NEW) - 17
     known = {c["name"] for c in bench["workloads"]}
     reporting = next(m for m in bench["end_to_end"] if m["name"] == moves)["workloads"]
     assert set(cells) <= known and set(cells) <= set(reporting)
@@ -258,7 +259,7 @@ def test_frames_per_send_is_a_data_file_on_the_reducer_that_is_there():
     (and no error) on a program that has neither, as the parent commit is."""
     name = "frames_per_send.closed"
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
-    assert bench["per_layer"][-5] == {
+    assert bench["per_layer"][-7] == {  # PR 42's four and PR 43's two stand behind it
         "name": name, "unit": "count", "better": "higher", "source": "program_counter",
         "layer": "net loop (core/net.cc)", "moves": "commit_rate", "workloads": CLOSED4,
     }

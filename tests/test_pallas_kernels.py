@@ -1,14 +1,13 @@
-"""Equivalence of the fused Pallas kernels against the XLA field/ed25519
+"""Equivalence of the VMEM chain kernels against the XLA field/ed25519
 pipeline and the RFC 8032 oracle (interpret mode on the CPU backend; the
-same kernels compile under Mosaic on TPU).
+same kernels compile under Mosaic on TPU). A tile is (32, 8, 128): a limb a
+whole vector register, 1,024 items; a batch is padded to whole tiles.
 
-The Pallas path must be bit-identical to the XLA path: verifier results
+The VMEM path must be bit-identical to the XLA path: verifier results
 feed consensus quorums, and any divergence between backends would split
-replicas (SURVEY.md §7 "Determinism at the FFI boundary")."""
-
-import os
-
-os.environ.setdefault("PBFT_PALLAS_TB", "8")  # before pallas_kernels import
+replicas (SURVEY.md §7 "Determinism at the FFI boundary"). The arithmetic
+itself is held to field.py without the interpreter, in tier-1:
+tests/test_vmem_chains.py."""
 
 import numpy as np
 import pytest
@@ -27,9 +26,9 @@ _RNG = np.random.default_rng(0xED25519)
 
 @pytest.fixture(autouse=True)
 def _interpret_mode(monkeypatch):
-    # Off the TPU the kernels run only under the interpreter, and only
-    # when asked for by name (read at trace time).
-    monkeypatch.setenv("PBFT_PALLAS_INTERPRET", "1")
+    # Off the TPU the kernels run only under the interpreter, which only a
+    # test asks for (read at trace time; no environment variable does).
+    monkeypatch.setattr(PK, "_INTERPRET", True)
 
 
 def _rand_field(batch, lo=-(2**9) + 1, hi=2**9):
@@ -58,9 +57,9 @@ def test_pow_p58_matches_field():
 
 
 def test_ladder_matches_xla_ladder():
-    # Batch 1: the ladder math is per-element, so extra batch rows only
-    # replicate work in the minutes-slow interpreter.
-    batch = 1
+    # Three items in a tile of 1,024 (the rest is padding): the ladder math
+    # is per-element.
+    batch = 3
     pubs, s_list, h_list = [], [], []
     for i in range(batch):
         seed = bytes([i + 9]) * 32
@@ -92,9 +91,10 @@ def test_ladder_matches_xla_ladder():
 
 
 def test_full_verify_pallas_path(monkeypatch):
-    """verify_kernel with PBFT_PALLAS=1: same accept/reject set as the
-    oracle, including a corrupted signature and a corrupted message."""
-    monkeypatch.setenv("PBFT_PALLAS", "1")
+    """verify_kernel where the shape rule says "vmem" (as on a TPU for a
+    shape that fills a tile): same accept/reject set as the oracle,
+    including a corrupted signature and a corrupted message."""
+    monkeypatch.setattr(E, "chains_for", lambda rows, backend=None: "vmem")
     # One valid + one corrupt-R + one corrupt-message row: full coverage
     # of the accept/reject branches at the smallest interpreter cost
     # (each row re-runs the whole ladder in the Python interpreter).

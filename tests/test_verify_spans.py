@@ -155,6 +155,7 @@ class _Seen:
 
     def __init__(self, compiled):
         self.compiled, self.input_shardings, self.args = compiled, compiled.input_shardings, []
+        self.as_text = compiled.as_text
 
     def __call__(self, *args):
         self.args.append(args)
@@ -337,6 +338,78 @@ def test_a_one_device_engine_says_so_on_every_launch_line(tmp_path, capsys):
     )
 
 
+def test_every_launch_line_says_what_share_of_its_slots_ran_the_vmem_chains(tmp_path, capsys):
+    """``fused`` is the share of the window's slots (``rung``) that ran on
+    executables whose multiply chains live in VMEM: what each executable's
+    own HLO says (``parallel.chains_of``: a Mosaic call or none), kept at
+    warm-up as ``per_shape[].chains``. A stand-in kernel has no Mosaic call
+    (``xla``); on the CPU the real kernel has none either."""
+    sys.path.insert(0, str(ROOT / "scripts"))
+    try:
+        import verify_status
+    finally:
+        sys.path.pop(0)
+    shapes = (8, 16)
+    engine = ShardedVerifyEngine(shapes=shapes, devices=1, kernel=lambda p, m, s: p[:, 0] == s[:, 0])
+    trace = tmp_path / "verifyd.jsonl"
+    daemon = VerifyServiceDaemon(
+        backend="auto", engine=engine, trace_path=str(trace),
+        fallback=lambda items: pytest.fail("the fallback ran"),
+    ).start(wait_ready=True, timeout=300)
+    try:
+        assert [p["chains"] for p in engine.stats["per_shape"]] == ["xla", "xla"]
+        assert _send_batch(daemon.address, [_item(3, True)] * 3) == [True] * 3
+        # As on a TPU whose 16-slot shape fills a tile and whose 8-slot one does not.
+        engine._chains[16] = "vmem"
+        engine.stats["per_shape"][1]["chains"] = "vmem"
+        for n in (3, 12, 16 + 2):  # 8 slots; 16; 16 + 8
+            assert _send_batch(daemon.address, [_item(n, True)] * n) == [True] * n
+        status = daemon.status_json()
+        capsys.readouterr()
+        assert verify_status.main([daemon.address]) == 0
+    finally:
+        daemon.stop()
+    lines = _lines(trace)
+    assert [(e["rung"], e["fused"]) for e in lines] == [(8, 0.0), (8, 0.0), (16, 1.0), (24, 0.6667)]
+    assert status["fused_launches"] == 2 and status["engine_launches"] == 4
+    assert set(status) <= trace_schema.VERIFYD_STATUS_KEYS
+    assert "fused" in trace_schema.EVENT_SCHEMAS["verify_batch"]["optional"]
+    assert "fused_launches" in trace_schema.VERIFYD_STATUS_KEYS
+    assert "chains" in trace_schema.VERIFYD_PER_SHAPE_KEYS
+    out = capsys.readouterr().out
+    assert "multiply chains 8: xla  16: vmem  (2 launches with slots on the VMEM chains)" in out
+    assert "fused_launches" not in out  # printed once, as that line
+
+
+def test_the_real_kernel_s_shapes_say_xla_on_a_cpu():
+    from pbft_tpu.crypto.ed25519 import chains_for
+
+    assert [chains_for(rows) for rows in (16, 256, 1024, 4096)] == ["xla"] * 4
+
+
+def test_chains_are_read_from_the_program_not_from_the_rule(monkeypatch):
+    """``chains_of`` looks for the Mosaic call in the executable's text, and
+    in the lowered module's where an executable hands none back; the engine
+    records THAT, so a rule that says ``vmem`` of a program compiled without
+    the kernels does not reach ``per_shape[].chains`` or ``fused``."""
+    from pbft_tpu.crypto import ed25519
+    from pbft_tpu.parallel import chains_of
+
+    class Text:
+        def __init__(self, text):
+            self.as_text = lambda: text
+
+    assert chains_of(Text("... custom_call_target=\"tpu_custom_call\" ...")) == "vmem"
+    assert chains_of(Text("fusion.1 { convolution }")) == "xla"
+    assert chains_of(Text(None), Text("stablehlo.custom_call @tpu_custom_call(")) == "vmem"
+    assert chains_of(Text(None), Text("stablehlo.convolution")) == "xla"
+    with pytest.raises(RuntimeError, match="gives text"):
+        chains_of(Text(None))
+    engine = ShardedVerifyEngine(shapes=(8,), devices=1, kernel=lambda p, m, s: p[:, 0] == s[:, 0])
+    monkeypatch.setattr(ed25519, "chains_for", lambda rows, backend=None: "vmem")
+    assert [p["chains"] for p in engine.warm()["per_shape"]] == ["xla"]
+
+
 # -- (b') which executable serves a window: the table, and an engine that promotes
 
 
@@ -509,7 +582,9 @@ def test_hold_is_one_launch_of_the_shape_run_while_it_has_room(n, want):
 
 def _slow_below_32(pubs, msgs, sigs):
     """``_slow_kernel``'s rule on a device whose small programs are the slow
-    ones: twenty times the spin where the window has fewer than 32 slots.
+    ones: twenty times the spin where the window has fewer than 32 slots
+    (the kernel is traced for one device's rows, under ``shard_map``: the
+    window's slots are those times the mesh).
     (The shape is static under jit: each executable is slow or fast outright.
     The fast ones spin too, for a millisecond: the timer tells two launches
     of microseconds apart by whatever else the host was doing.)"""
@@ -518,7 +593,7 @@ def _slow_below_32(pubs, msgs, sigs):
 
     spin = jax.lax.fori_loop(
         0,
-        2_000_000 if pubs.shape[0] < 32 else 100_000,
+        2_000_000 if pubs.shape[0] * jax.lax.axis_size("batch") < 32 else 100_000,
         lambda i, acc: acc + (i & 1),
         pubs[0, 0].astype(jnp.int32),
     )
@@ -590,16 +665,24 @@ def test_warm_up_fails_where_an_executable_rejects_the_pad_triple(monkeypatch):
     own kernel has to accept the known-good triple in every pad slot."""
     import pbft_tpu.parallel as parallel
 
-    real = parallel.compile_sharded
+    real = parallel.lower_sharded
 
     def broken(mesh, size, kernel=None):
+        import jax
         import jax.numpy as jnp
 
         assert kernel is None  # the engine's own kernel was asked for
-        # A program that rejects its last slot, in the real kernel's place.
-        return real(mesh, size, kernel=lambda p, m, s: jnp.arange(p.shape[0]) < p.shape[0] - 1)
 
-    monkeypatch.setattr(parallel, "compile_sharded", broken)
+        def rejects_the_last_slot(p, m, s):
+            # The kernel is traced for one device's rows (shard_map): a slot's
+            # place in the window is its row behind the devices before this one.
+            rows = p.shape[0]
+            return jax.lax.axis_index("batch") * rows + jnp.arange(rows) < size - 1
+
+        # A program that rejects its last slot, in the real kernel's place.
+        return real(mesh, size, kernel=rejects_the_last_slot)
+
+    monkeypatch.setattr(parallel, "lower_sharded", broken)
     engine = ShardedVerifyEngine(shapes=(8,))
     with pytest.raises(RuntimeError, match="8-slot executable rejected .* 1 of 8 slots"):
         engine.warm()
@@ -710,13 +793,21 @@ def test_the_executable_takes_one_block_and_hands_the_kernel_its_columns():
     seen = []
 
     def kernel(pubs, msgs, sigs):
+        # Traced for ONE device's rows of the block (shard_map over the batch
+        # axis): the rows behind those of the devices before it.
         seen.append([(a.shape, str(a.dtype)) for a in (pubs, msgs, sigs)])
-        same = [(got == col).all(axis=1) for got, col in zip((pubs, msgs, sigs), want)]
+        rows = pubs.shape[0]
+        start = jax.lax.axis_index("batch") * rows
+        same = [
+            (got == jax.lax.dynamic_slice_in_dim(jax.numpy.asarray(col), start, rows)).all(axis=1)
+            for got, col in zip((pubs, msgs, sigs), want)
+        ]
         return same[0] & same[1] & same[2]
 
     mesh = make_mesh(devices=jax.local_devices())
+    rows = 16 // mesh.devices.size
     compiled = compile_sharded(mesh, 16, kernel=kernel)
-    assert seen == [[((16, 32), "uint8"), ((16, 32), "uint8"), ((16, 64), "uint8")]]
+    assert seen == [[((rows, 32), "uint8"), ((rows, 32), "uint8"), ((rows, 64), "uint8")]]
     args, kwargs = compiled.in_avals
     assert [(a.shape, str(a.dtype)) for a in args] == [((16, 128), "uint8")] and not kwargs
     assert len(compiled.input_shardings[0]) == 1
@@ -738,7 +829,11 @@ def test_warm_up_and_serving_stage_through_the_same_function(monkeypatch):
     import pbft_tpu.parallel as parallel
     from pbft_tpu.crypto import batch
 
-    pads, blocks, real_pad, real_compile = [], [], batch.pad_batch, parallel.compile_sharded
+    pads, blocks, real_pad, real_lower = [], [], batch.pad_batch, parallel.lower_sharded
+
+    class SeenLowered:  # what lower_sharded gives, its executable wrapped
+        def __init__(self, lowered):
+            self.as_text, self.compile = lowered.as_text, lambda: _Seen(lowered.compile())
 
     def pad(items, size):
         pads.append(len(items))
@@ -746,7 +841,7 @@ def test_warm_up_and_serving_stage_through_the_same_function(monkeypatch):
         return blocks[-1], len(items)
 
     monkeypatch.setattr(batch, "pad_batch", pad)
-    monkeypatch.setattr(parallel, "compile_sharded", lambda *a, **kw: _Seen(real_compile(*a, **kw)))
+    monkeypatch.setattr(parallel, "lower_sharded", lambda *a, **kw: SeenLowered(real_lower(*a, **kw)))
     engine = ShardedVerifyEngine(shapes=(8,), kernel=lambda p, m, s: p[:, 0] == s[:, 0])
     engine.warm()
     assert pads == [0] * (1 + engine.WARM_LAUNCHES)
@@ -1219,6 +1314,49 @@ def test_the_four_chip_cell_is_listed_wherever_its_twin_is_and_brings_two_reader
     # a program from before the fields, as the parent commit is: nothing, and no error
     assert _read("mesh_chips.closed", {"launches": [{"rung": 256}]}) is None
     assert _read("rows_per_chip_mean.closed", {"launches": []}) is None
+
+
+FUSED = {
+    "fused_launch_share.closed": ("commit_rate", ["f1-sig-wal.closed", "f5-sig-wal.closed",
+                                                  "f1-mac-tentative.closed", X4_CELL, MT_CELL, F10_CELL]),
+    "fused_launch_share.rate": ("reply_p50_ms", ["f1-sig-wal.rate"]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(FUSED))
+def test_the_fused_share_has_its_reader_and_its_entry(name):
+    """PR 43's two readers: data only, on the reducer that is there, the
+    last two entries of ``per_layer``, layer ``kernel``."""
+    moves, cells = FUSED[name]
+    spec = json.loads((CHIPBENCH / "metrics" / f"{name}.json").read_text())
+    assert spec == {"name": name, "reducer": "launch_field_stat",
+                    "args": {"fields": ["fused"], "stat": "mean"}}
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    assert [m["name"] for m in bench["per_layer"]][-2:] == sorted(FUSED)
+    assert [m for m in bench["per_layer"] if m["name"] == name] == [{
+        "name": name, "unit": "ratio", "better": "higher", "source": "program_counter",
+        "layer": "kernel", "moves": moves, "workloads": cells,
+    }]
+    assert "kernel" in {m["layer"] for m in bench["per_layer"][:ACCEPTED_PER_LAYER]}
+
+
+@pytest.mark.parametrize("name", sorted(FUSED))
+@pytest.mark.parametrize(
+    "launches, want",
+    [
+        ([{"rung": 4096, "fused": 1.0}] * 3 + [{"rung": 256, "fused": 0.0}], 0.75),
+        ([{"rung": 1280, "fused": 0.8}, {"rung": 256, "fused": 0.0}], 0.4),
+        ([{"rung": 256, "fused": 0.0}] * 5, 0.0),  # every launch the 256-slot program
+        # a window the fallback ran has no engine fields: left out
+        ([{"rung": 4096, "fused": 1.0}, {"size": 7, "secs": 0.01}], 1.0),
+        # a program from before the field, as the parent commit is: nothing, and no error
+        ([{"rung": 4096, "split": 0}, {"rung": 256, "split": 0}], None),
+        ([], None),
+    ],
+)
+def test_the_fused_share_over_launch_lines_with_and_without_the_field(name, launches, want):
+    got = _read(name, {"launches": launches})
+    assert got == want if want is None else got == pytest.approx(want)
 
 
 def test_the_multicore_cell_is_listed_wherever_its_twin_is():
