@@ -10,8 +10,9 @@ gates phase transitions on the returned bitmap (BASELINE.json north_star).
 Shapes are static per batch size; use padded power-of-two batches to bound
 the number of XLA compilations (pad slots are filled with a known-good
 self-signed triple so padding never fails a batch). A padded batch is staged
-as ONE (B, 128) uint8 block of ``pub | msg | sig`` rows (`pad_batch`), the
-layout the triples have on the verify service's wire.
+as ONE (B, 128) uint8 block of ``pub | msg | sig`` rows, the layout the
+triples have on the verify service's wire: `pad_rows` from rows that are in
+it already (what the service carries), `pad_batch` from a list of triples.
 """
 
 from __future__ import annotations
@@ -65,24 +66,35 @@ def _pad_template(size: int) -> np.ndarray:
     return template
 
 
-def pad_batch(items, size: int):
-    """items: list of (pub32, msg32, sig64) bytes -> one (size, 128) uint8
-    block of ``pub | msg | sig`` rows, and n.
+def pad_rows(segments, size: int):
+    """segments: (k, 128) uint8 arrays of ``pub | msg | sig`` rows, in item
+    order (the requests of a verify-service window, as they came off the
+    wire) -> one (size, 128) uint8 block, and n, the rows they hold.
 
     Rows >= n are the known-good pad triple (they verify True and are
     sliced off by the caller). The block is a fresh copy of the shape's
     template every time, so two windows in flight never share a row, and
-    the items land in it in ONE assignment: no work per item in Python.
+    the rows land in it in ONE assignment a segment: no work per item in
+    Python.
     """
+    block = _pad_template(size).copy()
+    n = 0
+    for rows in segments:
+        if n + len(rows) > size:
+            raise ValueError(f"batch of {n + len(rows)} exceeds padded size {size}")
+        block[n : n + len(rows)] = rows
+        n += len(rows)
+    return block, n
+
+
+def pad_batch(items, size: int):
+    """items: list of (pub32, msg32, sig64) bytes -> :func:`pad_rows` of
+    their rows, joined once."""
     n = len(items)
-    if n > size:
-        raise ValueError(f"batch of {n} exceeds padded size {size}")
     rows = np.frombuffer(b"".join(chain.from_iterable(items)), np.uint8)
     if rows.size != n * 128:
         raise ValueError(f"{n} items of {rows.size} bytes: not 128-byte triples")
-    block = _pad_template(size).copy()
-    block[:n] = rows.reshape(n, 128)
-    return block, n
+    return pad_rows([rows.reshape(n, 128)], size)
 
 
 # Padded sizes are drawn from a short ladder so the whole system compiles

@@ -16,8 +16,20 @@ while the previous launch ran", held open for company only as long as
 ``hold_s`` grants (a rule the owner of the backend sets from what a launch
 costs it; a bare service has none and cuts at once).
 
+What a pending request IS: the ``(n, 128)`` uint8 block of rows it came off
+the wire as (the handler reads the socket into a buffer of its own and never
+cuts it up), and what it gets back is ONE ``bytes`` of its 0/1 verdicts. A
+window is a :class:`Window`: its requests' blocks in order, with ``len()``
+its items, which yields ``(pub, msg, sig)`` triples to whoever iterates,
+indexes or slices it (the host verifiers, a test's callable) and hands its
+rows as they are to whoever stages them (the engine). No Python object is
+made for an item on the way in or out. The protocol is unchanged. Every
+launch line says ``block_items``: the items of its window whose rows reached
+an executable as they came off the wire (the engine says so in the span; 0
+where a backend took triples), summed in the status beside ``listed_items``.
+
 This module knows no shape, no device and no kernel: ``backend`` is a
-callable on a list of items. The accelerator, its window shapes, their
+callable on a sequence of items. The accelerator, its window shapes, their
 costs and the daemon (``verifyd``) that joins them to this dispatcher
 live in ``verify_service.py``, and only that daemon ever answers ``ready``.
 """
@@ -33,11 +45,16 @@ import sys
 import threading
 import time
 import traceback
+from collections.abc import Sequence
+from itertools import chain
 from typing import Callable, List, Optional, Tuple
+
+import numpy as np
 
 from ..utils.trace import Tracer, open_span
 
 Item = Tuple[bytes, bytes, bytes]
+ROW = 128  # bytes an item on the wire: pub 32 | msg 32 | sig 64
 
 # -- readiness handshake wire format (ISSUE 7) -------------------------------
 #
@@ -148,11 +165,11 @@ def unpack_status(blob: bytes) -> Optional[Tuple[int, int, int]]:
     return state, devices, warmed
 
 
-def _recv_exact(sock: socket.socket, n: int) -> bytes:
+def _recv_buffer(sock: socket.socket, n: int) -> bytearray:
     # Preallocated buffer + recv_into: the n*128-byte blob read is on the
     # coalesced-window hot path, and the old `bytes += chunk` accumulation
     # re-copied the whole prefix per chunk (quadratic across a large
-    # window split into MTU-sized reads).
+    # window split into MTU-sized reads). The buffer is the caller's own.
     buf = bytearray(n)
     view = memoryview(buf)
     got = 0
@@ -161,35 +178,97 @@ def _recv_exact(sock: socket.socket, n: int) -> bytes:
         if not r:
             raise ConnectionError("peer closed mid-message")
         got += r
-    return bytes(buf)
+    return buf
 
 
-def cpu_backend(items: List[Item]) -> List[bool]:
+def _recv_exact(sock: socket.socket, n: int) -> bytes:
+    return bytes(_recv_buffer(sock, n))
+
+
+def as_rows(items: Sequence) -> np.ndarray:
+    """A list of ``(pub, msg, sig)`` triples -> their ``(n, 128)`` uint8
+    rows, joined once: what an in-process caller's list becomes at the door."""
+    rows = np.frombuffer(b"".join(chain.from_iterable(items)), np.uint8)
+    if rows.size != len(items) * ROW:
+        raise ValueError(
+            f"{len(items)} items of {rows.size} bytes: not 128-byte triples"
+        )
+    return rows.reshape(len(items), ROW)
+
+
+class Window(Sequence):
+    """The items of ONE backend call, held as the ``(n, 128)`` uint8 blocks
+    their requests came off the wire as, in order. A sequence of
+    ``(pub, msg, sig)`` triples to a backend that iterates, indexes or
+    slices it (each triple made on demand); :meth:`rows` to one that stages
+    rows (the engine), which then makes nothing per item."""
+
+    __slots__ = ("blocks", "_n")
+
+    def __init__(self, blocks: List[np.ndarray]):
+        self.blocks = blocks
+        self._n = sum(map(len, blocks))
+
+    def __len__(self) -> int:
+        return self._n
+
+    def __iter__(self):
+        for block in self.blocks:
+            blob = block.tobytes()
+            for at in range(0, len(blob), ROW):
+                yield blob[at : at + 32], blob[at + 32 : at + 64], blob[at + 64 : at + ROW]
+
+    def __getitem__(self, i):
+        at = range(self._n)[i]  # range's own rules for a slice or a negative index
+        if isinstance(i, slice):
+            return [self[j] for j in at]
+        for block in self.blocks:
+            if at < len(block):
+                row = block[at].tobytes()
+                return row[:32], row[32:64], row[64:]
+            at -= len(block)
+
+    def rows(self, start: int, stop: int) -> List[np.ndarray]:
+        """The rows of items ``start`` to ``stop``: views of the blocks, in
+        order, a request that straddles either edge cut there."""
+        out = []
+        for block in self.blocks:
+            if stop <= 0:
+                break
+            if start < len(block):
+                out.append(block[max(start, 0) : stop])
+            start -= len(block)
+            stop -= len(block)
+        return out
+
+
+def cpu_backend(items: Sequence) -> List[bool]:
     """The host oracle, one item at a time (the simulator's control arm)."""
     from ..consensus.simulation import cpu_verifier
 
     return cpu_verifier(items)
 
 
-def native_backend(items: List[Item]) -> List[bool]:
+def native_backend(items: Sequence) -> List[bool]:
     """The C++ batch verifier (core/ed25519.cc via ctypes): one fast host
     verifier process serving every colocated daemon — the chip-less
     deployment, and the realistic control arm for measuring coalesced
     window occupancy on a box without a chip."""
     from .. import native
 
-    return [bool(v) for v in native.verify_batch(items)]
+    return native.verify_batch(list(items))  # the triples made once, not a pass
 
 
 class _Pending:
-    __slots__ = ("items", "conn", "arrived", "event", "verdicts", "error")
+    __slots__ = ("rows", "n", "conn", "arrived", "event", "verdicts", "error")
 
-    def __init__(self, items: List[Item], conn=None):
-        self.items = items
+    def __init__(self, rows: np.ndarray, conn=None):
+        self.rows = rows  # the request's (n, 128) uint8 block
+        self.n = len(rows)
         self.conn = conn  # the connection it came over (None: not known)
         self.arrived = time.monotonic()
         self.event = threading.Event()
-        self.verdicts: Optional[List[bool]] = None
+        self.verdicts: Optional[bytes] = None  # a 0/1 byte an item
         self.error: Optional[Exception] = None
 
 
@@ -201,7 +280,7 @@ class VerifierService:
         host: str = "127.0.0.1",
         port: int = 0,
         unix_path: Optional[str] = None,
-        backend: Callable[[List[Item]], List[bool]] | str = "native",
+        backend: Callable[[Sequence], Sequence] | str = "native",
         trace_path: Optional[str] = None,
         inflight: int = 1,
         metrics_port: Optional[int] = None,
@@ -283,6 +362,10 @@ class VerifierService:
         # windows it ran as several executables (its span's ``split``);
         # fused_launches: windows with slots on executables that run the
         # multiply chains out of VMEM (its span's ``fused`` above 0).
+        # block_items / listed_items: items whose rows reached an executable
+        # as the blocks they came off the wire as (the engine's span says
+        # ``block_items``), and items a backend took as a list of triples
+        # instead (the host verifiers, the daemon's fallback before ready).
         # held_out_launches / in_step_launches: windows whose hold ran out,
         # and windows cut early because nobody in step was still out;
         # windows_cut_full: windows cut at MAX_WINDOW with requests left
@@ -295,6 +378,8 @@ class VerifierService:
         self.promoted_launches = 0
         self.split_launches = 0
         self.fused_launches = 0
+        self.block_items = 0
+        self.listed_items = 0
         self.held_out_launches = 0
         self.in_step_launches = 0
         self.windows_cut_full = 0
@@ -314,6 +399,7 @@ class VerifierService:
         self.longest_stall_s = 0.0
         self._cond = threading.Condition()
         self._pending: List[_Pending] = []
+        self._queued = 0  # items in _pending (under _cond)
         self._running = True
         service = self
 
@@ -344,17 +430,8 @@ class VerifierService:
                             ).encode()
                             sock.sendall(len(blob).to_bytes(4, "big") + blob)
                             continue
-                        blob = _recv_exact(sock, n * 128)
-                        items = [
-                            (
-                                blob[i * 128 : i * 128 + 32],
-                                blob[i * 128 + 32 : i * 128 + 64],
-                                blob[i * 128 + 64 : i * 128 + 128],
-                            )
-                            for i in range(n)
-                        ]
-                        verdicts = service._submit(items, conn=self)
-                        sock.sendall(bytes(1 if v else 0 for v in verdicts))
+                        rows = np.frombuffer(_recv_buffer(sock, n * ROW), np.uint8)
+                        sock.sendall(service._submit(rows.reshape(n, ROW), conn=self))
                 except (ConnectionError, OSError):
                     return
                 finally:
@@ -402,15 +479,20 @@ class VerifierService:
             p.conn for p in self._pending
         }
 
-    def _submit(self, items: List[Item], conn=None) -> List[bool]:
+    def _submit(self, items, conn=None):
         """Handler-thread entry: verify `items`, possibly merged with other
-        connections' concurrent submissions into one backend call."""
-        p = _Pending(items, conn)
+        connections' concurrent submissions into one backend call. A
+        handler's ``(n, 128)`` block is answered with its verdict bytes; an
+        in-process caller's list of triples is packed here, once, and
+        answered with a list of bools."""
+        listed = not isinstance(items, np.ndarray)
+        p = _Pending(as_rows(items) if listed else items, conn)
         with self._cond:
             self.requests += 1
             if not self._running:  # dispatcher gone: fail this connection
                 raise ConnectionError("verifier service stopping")
             self._pending.append(p)
+            self._queued += p.n
             self._cond.notify()
         # No fixed deadline (the backend's time is its own), but a dead
         # dispatcher must not strand the connection.
@@ -420,7 +502,7 @@ class VerifierService:
         if p.error is not None:
             raise ConnectionError(f"verification failed: {p.error!r}")
         assert p.verdicts is not None
-        return p.verdicts
+        return list(map(bool, p.verdicts)) if listed else p.verdicts
 
     def _dispatch_loop(self) -> None:
         while True:
@@ -440,7 +522,7 @@ class VerifierService:
                         # the room on the backlog's LAST shape is not this
                         # window's to wait for (the engine says 0 for a
                         # window that fills the largest shape).
-                        hold = self.hold_s(min(self._pending_items(), self.MAX_WINDOW))
+                        hold = self.hold_s(min(self._queued, self.MAX_WINDOW))
                         remaining = (
                             self._pending[0].arrived + hold - time.monotonic()
                         )
@@ -458,14 +540,15 @@ class VerifierService:
                 window: List[_Pending] = []
                 size = 0
                 while self._pending:
-                    nxt = len(self._pending[0].items)
+                    nxt = self._pending[0].n
                     if window and size + nxt > self.MAX_WINDOW:
                         break
                     size += nxt
                     window.append(self._pending.pop(0))
+                self._queued -= size
                 self._flying += 1
                 cut_at = time.monotonic()
-                left = self._pending_items()  # queued past MAX_WINDOW
+                left = self._queued  # queued past MAX_WINDOW
                 if self.metrics_registry.enabled:
                     self.metrics_registry.gauge("pbft_verify_queue_depth").set(left)
             # The window is cut BEFORE a launch slot is free and cannot grow
@@ -474,7 +557,7 @@ class VerifierService:
             self._inflight_sem.acquire()
             got_slot = time.monotonic()
             with self._cond:
-                arrived_since = self._pending_items()
+                arrived_since = self._queued
             waits = {
                 "queue_s": round(cut_at - min(p.arrived for p in window), 6),
                 "slot_s": round(got_slot - cut_at, 6),
@@ -502,9 +585,6 @@ class VerifierService:
                     self._launch_threads.append(t)
                 t.start()
 
-    def _pending_items(self) -> int:
-        return sum(len(p.items) for p in self._pending)
-
     def _dispatch_guarded(self, window: List[_Pending], waits: dict) -> None:
         try:
             self._dispatch_window(window, waits)
@@ -524,22 +604,26 @@ class VerifierService:
             self._inflight_sem.release()
 
     @staticmethod
-    def _checked(backend, items: List[Item]) -> List[bool]:
+    def _checked(backend, items: Window) -> np.ndarray:
         """Run the backend and validate the verdict count — a wrong-length
-        result would otherwise mis-slice silently across connections."""
+        result would otherwise mis-slice silently across connections. The
+        verdicts leave as ONE uint8 array of 0/1 (the engine's bool array
+        seen as bytes; a host verifier's list converted here, once)."""
         verdicts = backend(items)
         if verdicts is None or len(verdicts) != len(items):
             got = "None" if verdicts is None else str(len(verdicts))
             raise ValueError(
                 f"backend returned {got} verdicts for {len(items)} items"
             )
-        return verdicts
+        return np.asarray(verdicts, dtype=bool).view(np.uint8)
 
-    def _spanned(self, items: List[Item]) -> Tuple[List[bool], dict]:
+    def _spanned(self, items: Window) -> Tuple[np.ndarray, dict]:
         """One backend call -> (verdicts, what the backend wrote into the
-        span opened round it: the engine's steps, or nothing). While it
-        runs the call is on the stall watcher's list."""
+        span opened round it: the engine's steps, or nothing; every span
+        says ``block_items``, 0 unless the backend says otherwise). While
+        it runs the call is on the stall watcher's list."""
         with open_span() as span:
+            span["block_items"] = 0
             flight = {
                 "t0": time.monotonic(), "size": len(items), "span": span,
                 "thread": threading.get_ident(), "stalled": False,
@@ -630,6 +714,8 @@ class VerifierService:
         self.promoted_launches += bool(span.get("promoted"))
         self.split_launches += bool(span.get("split"))
         self.fused_launches += bool(span.get("fused"))
+        self.block_items += span["block_items"]
+        self.listed_items += size - span["block_items"]
         self.held_out_launches += waits["held_out"]
         self.in_step_launches += waits["in_step"]
         self.windows_cut_full += waits["cut_full"]
@@ -653,7 +739,8 @@ class VerifierService:
     def launch_status(self) -> dict:
         """The stage totals, the counts of launches (promoted, split, fused, by exit
         of the hold, cut at MAX_WINDOW, by shape run, by rows a chip), the
-        deepest backlog a cut left queued, the slowest launch, and
+        items that reached an executable as block rows and those a backend
+        took as a list, the deepest backlog a cut left queued, the slowest launch, and
         the launches that stalled (above STALL_S in flight) with the longest
         of them that has ended, for the status JSON."""
         with self._cond:
@@ -663,6 +750,8 @@ class VerifierService:
                 "promoted_launches": self.promoted_launches,
                 "split_launches": self.split_launches,
                 "fused_launches": self.fused_launches,
+                "block_items": self.block_items,
+                "listed_items": self.listed_items,
                 "held_out_launches": self.held_out_launches,
                 "in_step_launches": self.in_step_launches,
                 "windows_cut_full": self.windows_cut_full,
@@ -677,13 +766,14 @@ class VerifierService:
         return {"stage_seconds": totals, **counts, "slowest_launch": slowest}
 
     def _dispatch_window(self, window: List[_Pending], waits: dict) -> None:
-        merged: List[Item] = []
-        for p in window:
-            merged.extend(p.items)
+        merged = Window([p.rows for p in window])
+        size = len(merged)
+        rejected = -1  # of a window that failed
         t0 = time.monotonic()
         span: dict = {}
         try:
             verdicts, span = self._spanned(merged)
+            rejected = size - int(np.count_nonzero(verdicts))
         except Exception:
             # One launch failing must not reject every client's honest
             # signatures ("never a false reject"): retry each request
@@ -698,11 +788,9 @@ class VerifierService:
             self._tracer.event(
                 "verify_batch" if verdicts is not None else "verify_window_failed",
                 replica="service",
-                size=len(merged),
+                size=size,
                 requests=len(window),
-                rejected=(
-                    verdicts.count(False) if verdicts is not None else -1
-                ),
+                rejected=rejected,
                 secs=round(secs, 6),
                 **({**waits, **span} if verdicts is not None else {}),
             )
@@ -711,15 +799,13 @@ class VerifierService:
             # finish concurrently (the replica runtimes' single-writer
             # discipline doesn't hold here).
             self.batches += 1
-            self.items += len(merged)
+            self.items += size
             if verdicts is not None:
-                self._account(secs, len(merged), waits, span)
+                self._account(secs, size, waits, span)
             if self.metrics_registry.enabled:
                 self.metrics_registry.counter("pbft_verify_batches_total").inc()
-                self.metrics_registry.counter("pbft_verify_items_total").inc(len(merged))
-                self.metrics_registry.histogram("pbft_verify_batch_size").observe(
-                    len(merged)
-                )
+                self.metrics_registry.counter("pbft_verify_items_total").inc(size)
+                self.metrics_registry.histogram("pbft_verify_batch_size").observe(size)
                 self.metrics_registry.histogram("pbft_verify_seconds").observe(secs)
                 # Service launch surface (ISSUE 7): items per XLA launch
                 # and how many connections each merged window carried —
@@ -729,20 +815,20 @@ class VerifierService:
                 ).inc()
                 self.metrics_registry.histogram(
                     "pbft_verify_service_window_size"
-                ).observe(len(merged))
+                ).observe(size)
                 self.metrics_registry.histogram(
                     "pbft_verify_service_coalesced_clients"
                 ).observe(len(window))
                 if verdicts is not None:
                     self.metrics_registry.counter("pbft_verify_rejected_total").inc(
-                        verdicts.count(False)
+                        rejected
                     )
         if verdicts is None:
             for p in window:
                 t1 = time.monotonic()
-                span: dict = {}
                 try:
-                    p.verdicts, span = self._spanned(p.items)
+                    alone, span = self._spanned(Window([p.rows]))
+                    p.verdicts = alone.tobytes()
                 except Exception as e:  # noqa: BLE001 - handed to submitter
                     p.error = e
                 if self._tracer.enabled:
@@ -750,9 +836,9 @@ class VerifierService:
                         self._tracer.event(
                             "verify_batch",
                             replica="service",
-                            size=len(p.items),
+                            size=p.n,
                             requests=1,
-                            rejected=p.verdicts.count(False),
+                            rejected=p.n - int(np.count_nonzero(alone)),
                             secs=round(time.monotonic() - t1, 6),
                             **span,
                         )
@@ -763,15 +849,15 @@ class VerifierService:
                         self._tracer.event(
                             "verify_batch_error",
                             replica="service",
-                            size=len(p.items),
+                            size=p.n,
                             secs=round(time.monotonic() - t1, 6),
                         )
                 p.event.set()
             return
         off = 0
         for p in window:
-            p.verdicts = verdicts[off : off + len(p.items)]
-            off += len(p.items)
+            p.verdicts = verdicts[off : off + p.n].tobytes()
+            off += p.n
             p.event.set()
 
     def start(self) -> "VerifierService":

@@ -47,8 +47,9 @@ last shape of its plan, the dispatcher keeps it open for at most one
 replicas' batches that arrive a few ms apart share a launch.
 
 Host↔device pipeline: every window is staged as ONE ``(size, 128)`` uint8
-block of the triples as they came off the wire (``crypto.batch.pad_batch``: a
-copy of the shape's all-pad template and one assignment, nothing per item)
+block of the triples as they came off the wire (``crypto.batch.pad_rows``: a
+copy of the shape's all-pad template and one assignment a request, from the
+blocks the dispatcher's ``service.Window`` holds; nothing per item)
 and handed, still on the host, to a precompiled executable: its call moves
 the block in ONE async transfer against the batch sharding, cuts it into
 ``pub | msg | sig`` on the device and DONATES the input buffer (XLA reuses
@@ -88,7 +89,9 @@ from .service import (  # noqa: F401 - re-exported API
     STATUS_PROBE,
     STATUS_VERSION,
     VerifierService,
+    Window,
     _recv_exact,
+    as_rows,
     pack_status,
     unpack_status,
 )
@@ -391,7 +394,7 @@ class ShardedVerifyEngine:
 
     def _measure(self, size: int, compiled) -> float:
         """What one launch of ``compiled`` costs here, in seconds: the
-        all-pad window through the path ``verify()`` takes (``pad_batch``,
+        all-pad window through the path ``verify()`` takes (``pad_rows``,
         the executable on the host block, ``np.asarray``), once untimed and
         then the least of ``WARM_LAUNCHES`` timed ones, with nothing else in
         flight (the daemon serves from its fallback until ``warm()``
@@ -403,12 +406,12 @@ class ShardedVerifyEngine:
         not satisfy.)"""
         import numpy as np
 
-        from ..crypto.batch import pad_batch
+        from ..crypto.batch import pad_rows
 
         took = []
         for _ in range(1 + self.WARM_LAUNCHES):
             t0 = time.perf_counter()
-            verdicts = np.asarray(compiled(pad_batch([], size)[0]))
+            verdicts = np.asarray(compiled(pad_rows([], size)[0]))
             took.append(time.perf_counter() - t0)
             if self._kernel is None and not verdicts.all():
                 raise RuntimeError(
@@ -457,8 +460,11 @@ class ShardedVerifyEngine:
     # line because the launch lines' readers sum ``pad_s + put_s + dispatch_s``.
     STEPS = ("pad_s", "put_s", "dispatch_s", "wait_s", "unpack_s")
 
-    def verify(self, items: List[Item]) -> List[bool]:
-        """Pad to a warmed window shape (one block, ``pad_batch``), call the
+    def verify(self, items: Sequence[Item]):
+        """Pad to a warmed window shape (one block, ``pad_rows``: one slice
+        assignment a request of the dispatcher's ``Window``, two for one that
+        straddles a chunk's edge; a plain list of triples is packed into a
+        window here, and answered with a list), call the
         precompiled executable on it (which moves the block in ONE async
         transfer against the batch sharding and launches), read back.
         The shape is what the serving table gives for the smallest one that
@@ -472,7 +478,7 @@ class ShardedVerifyEngine:
         staging hides behind the one before it on the device. The service
         never compiles a new shape at runtime. Verdicts are bit-identical
         to the single-device and CPU paths (pinned in tests/test_parallel
-        and tests/test_service_coalesce).
+        and tests/test_service_coalesce), and leave as ONE bool array.
 
         The five steps of every chunk are timed (summed over chunks) into
         the caller's ``utils.trace.current_span()``, where one is open —
@@ -482,17 +488,22 @@ class ShardedVerifyEngine:
         sharded (``devices``: what each executable's input sharding said at
         warm-up, not what was asked for) and how many rows a chip its
         thinnest chunk gave (``rows_per_chip``: the number to set against
-        the rows under which the kernel runs in its slow regime)."""
+        the rows under which the kernel runs in its slow regime), and how
+        many of the window's items reached the executables as the rows they
+        came off the wire as (``block_items``: all of a ``Window``'s, none
+        of a list's)."""
         mark = time.monotonic()
-        if not items:
+        if not len(items):
             return []
-        plan = self._plan(len(items))
+        listed = not isinstance(items, Window)
+        window = Window([as_rows(items)]) if listed else items
+        plan = self._plan(len(window))
         if not plan:
             raise RuntimeError("engine not warmed")
         import numpy as np
         import jax
 
-        from ..crypto.batch import pad_batch
+        from ..crypto.batch import pad_rows
 
         step = jax.profiler.TraceAnnotation
         secs = dict.fromkeys(self.STEPS, 0.0)
@@ -507,13 +518,11 @@ class ShardedVerifyEngine:
 
         promoted = off = 0
         t_dev = None
-        flying = []  # a chunk dispatched: (verdicts to come, its items)
+        flying = []  # a chunk dispatched: (verdicts to come, its first item, its items)
         for size in plan:
-            chunk = items[off : off + size]
-            off += size
-            promoted += size != min(s for s in self._compiled if s >= len(chunk))
             with step("verifyd.pad"):
-                block, n = pad_batch(chunk, size)
+                block, n = pad_rows(window.rows(off, off + size), size)
+            promoted += size != min(s for s in self._compiled if s >= n)
             pad = took("pad_s")
             if t_dev is None:
                 t_dev = pad  # the first dispatch
@@ -523,18 +532,19 @@ class ShardedVerifyEngine:
             # N computes. The donated input lets XLA reuse the same device
             # memory for every window of this shape.
             with step("verifyd.dispatch"):  # returns once enqueued
-                flying.append((self._compiled[size](block), n))
+                flying.append((self._compiled[size](block), off, n))
             took("dispatch_s")
-        out: List[bool] = []
+            off += size
+        out = np.empty(len(window), bool)
         while flying:
-            result, n = flying.pop(0)
+            result, at, n = flying.pop(0)
             # Behind the other launch in flight, then the device, then the
             # read-back: np.asarray returns when the verdicts are here.
             with step("verifyd.wait"):
                 verdicts = np.asarray(result)
             took("wait_s")
             with step("verifyd.unpack"):
-                out.extend(verdicts[:n].tolist())
+                out[at : at + n] = verdicts[:n]
                 # Dropping the device buffer takes its time too (~0.1 ms):
                 # here, so that it is timed, not at the function's exit.
                 del result, verdicts
@@ -555,8 +565,9 @@ class ShardedVerifyEngine:
                 fused=round(
                     sum(s for s in plan if self._chains.get(s) == "vmem") / sum(plan), 4
                 ),
+                block_items=0 if listed else len(window),
             )
-        return out
+        return out.tolist() if listed else out
 
     def memory_peak_bytes(self) -> Optional[int]:
         """The fullest local device's ``peak_bytes_in_use``; None before the
@@ -711,7 +722,7 @@ class VerifyServiceDaemon:
 
     # -- serving -------------------------------------------------------------
 
-    def _dispatch(self, items: List[Item]) -> List[bool]:
+    def _dispatch(self, items: Sequence[Item]):
         """The service backend: the warmed sharded engine when ready, the
         native-pool fallback otherwise — a request never waits on warmup.
         Counted apart, after the verdicts exist, so ``engine_items`` is

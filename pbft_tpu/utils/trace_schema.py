@@ -30,7 +30,11 @@ EVENT_SCHEMAS = {
     # requests left queued behind it, i.e. pending_at_cut above 0), hold_s
     # (the hold for company the window was granted at its cut, 0 where
     # none) and the 0/1 pair held_out (cut because that hold ran out) /
-    # in_step (cut early because nobody in step was still out);
+    # in_step (cut early because nobody in step was still out), and
+    # block_items (the items of the window whose rows reached an executable
+    # as the blocks they came off the wire as: all of them where the sharded
+    # engine took the dispatcher's window, 0 where a backend took the items
+    # as a list of triples, i.e. a host verifier or the fallback before ready);
     # and, where the sharded engine ran it, the engine's five
     # steps summed over chunks (pad_s, put_s, dispatch_s, wait_s, unpack_s:
     # they add up to secs), rung (the padded slots the chunks really ran
@@ -60,7 +64,7 @@ EVENT_SCHEMAS = {
         "optional": {
             "view", "executed", "requests",
             "queue_s", "slot_s", "pending_at_cut", "pending_at_launch",
-            "hold_s", "held_out", "in_step", "cut_full",
+            "hold_s", "held_out", "in_step", "cut_full", "block_items",
             "pad_s", "put_s", "dispatch_s", "wait_s", "unpack_s", "rung", "promoted",
             "chunks", "split", "t_dev", "devices", "rows_per_chip", "fused", "ahead",
             "apply_s", "loop_us", "shard_us", "pipe_us", "handoff",
@@ -567,8 +571,11 @@ VERIFYD_STATUS_KEYS = {
     # queued and the most items any cut left queued, launches by the padded
     # slots run ({"1024": n, ...}) and by the rows a chip of their thinnest
     # chunk ({"256": n, ...}), the slowest one; fused_launches: windows with
-    # slots on executables that run the multiply chains out of VMEM.
+    # slots on executables that run the multiply chains out of VMEM;
+    # block_items / listed_items: items that reached an executable as the
+    # rows they came off the wire as, and items a backend took as a list.
     "stage_seconds", "promoted_launches", "split_launches", "fused_launches",
+    "block_items", "listed_items",
     "held_out_launches",
     "in_step_launches", "windows_cut_full", "overflow_items_max",
     "launches_by_rung", "launches_by_rows_per_chip", "slowest_launch",

@@ -128,6 +128,10 @@ def main(argv=None) -> int:
             f"{rung} slots: {n}" for rung, n in sorted(by_rung.items(), key=lambda kv: int(kv[0]))
         ) + "  (hold ran out %d, in step %d)" % (
             status.get("held_out_launches", 0), status.get("in_step_launches", 0)))
+    if "block_items" in status:
+        print("  block path      %d items reached an executable as the rows they came off "
+              "the wire as, %d went to a backend as a list of triples"
+              % (status["block_items"], status.get("listed_items", 0)))
     if "windows_cut_full" in status:
         print("  full windows    %d cut at the largest window with requests left queued, "
               "at most %d items behind a cut"
@@ -157,7 +161,7 @@ def main(argv=None) -> int:
         "promoted_launches", "split_launches", "fused_launches", "launches_by_rung",
         "held_out_launches",
         "in_step_launches", "launches_by_rows_per_chip", "stalls", "longest_stall_s",
-        "windows_cut_full", "overflow_items_max",
+        "windows_cut_full", "overflow_items_max", "block_items", "listed_items",
     }
     for k in sorted(set(status) - known):
         print(f"  {k:<15} {status[k]}")

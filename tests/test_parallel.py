@@ -143,6 +143,51 @@ def test_block_path_gives_the_oracles_verdicts_item_by_item(n):
     assert eng.verify(items) == want
 
 
+@pytest.fixture(scope="module")
+def real_engine():
+    from pbft_tpu.net import ShardedVerifyEngine
+
+    eng = ShardedVerifyEngine(shapes=(8, 16))
+    eng.warm()
+    return eng
+
+
+@pytest.mark.parametrize(
+    "costs, sizes",
+    [
+        ({8: 0.001, 16: 0.004}, [3, 7, 1]),  # 8 + 8 slots: the second request straddles the edge
+        ({8: 0.001, 16: 0.004}, [8, 3]),  # the first request fills the first chunk exactly
+        ({8: 0.001, 16: 0.001}, [16]),  # one request fills the shape
+        ({8: 0.001, 16: 0.001}, [5, 4, 2]),  # one shape, pad rows behind three requests
+    ],
+    ids=["straddle", "fills-first-chunk", "fills-16", "padded"],
+)
+def test_a_window_of_wire_blocks_gets_the_oracles_verdicts_from_the_real_kernel(real_engine, costs, sizes):
+    """ISSUE 45 parity: the REAL kernel on the dispatcher's ``Window`` (the
+    requests' blocks as they came off the wire, staged one slice a request):
+    the probe's seven rejects and its control first, last and at every
+    request's edge, ``ref.verify``'s verdict item by item, the same from the
+    same items as a list."""
+    from itertools import accumulate
+
+    from pbft_tpu.net.service import Window
+    from tests.test_service_coalesce import _wire_blocks
+    from tests.test_verify_spans import _probe_items
+
+    n = sum(sizes)
+    pool = _probe_items(8 + n)
+    plants, items = pool[:8], pool[8:]
+    edges = sorted({0, n - 1} | {e - 1 for e in accumulate(sizes)} | {e for e in accumulate(sizes) if e < n})
+    for k, at in enumerate(edges):
+        items[at] = plants[k % 8]
+    want = [ref.verify(*item) for item in items]
+    assert not all(want) and any(want)
+    real_engine._route(costs)
+    got = real_engine.verify(Window(_wire_blocks(items, sizes)))
+    assert isinstance(got, np.ndarray) and got.tolist() == want
+    assert real_engine.verify(items) == want
+
+
 def test_persistent_engine_matches_oracle_and_native():
     """ISSUE 7 parity pin: the persistent service's AOT-compiled,
     donated-buffer engine must produce the SAME accept set as the Python

@@ -95,23 +95,33 @@ def test_concurrent_requests_coalesce_into_fewer_launches():
         svc.stop()
 
 
-def test_poison_batch_only_fails_its_own_connection(tmp_path):
+@pytest.mark.parametrize("path", ["list", "block"])
+def test_poison_batch_only_fails_its_own_connection(tmp_path, path):
     """A backend failure on a merged launch must not false-reject other
     clients' honest signatures: the window is retried per-request and only
     the poisoned connection errors out. The trace must stay honest too:
     the failed merge is verify_window_failed (NOT verify_batch, whose
     sizes the launch-cost model reads as items-per-launch) and the
-    retries are traced as singleton launches."""
+    retries are traced as singleton launches. ``list``: a callable that
+    iterates the window's triples; ``block``: the engine, staging the
+    requests' rows (ISSUE 45), the window and every retry alike."""
     import json
 
     gate = threading.Event()
     first = threading.Event()
+    engine = ShardedVerifyEngine(shapes=(8,), kernel=_fake_kernel)
+    if path == "block":
+        engine.warm()
 
     def backend(items):
         if not first.is_set():
             first.set()
             gate.wait(10)
             # fall through: the held first request itself verifies fine
+        if path == "block":
+            if any((rows[:, 0] == 66).any() for rows in items.blocks):
+                raise RuntimeError("poison")
+            return engine.verify(items)
         if any(p[0] == 66 for p, m, s in items):
             raise RuntimeError("poison")
         return [p[0] == s[0] for p, m, s in items]
@@ -161,6 +171,7 @@ def test_poison_batch_only_fails_its_own_connection(tmp_path):
     assert sum(e["rejected"] for e in vb) == 0, vb
     assert len(errored) == 1 and errored[0]["size"] == 1, errored
     assert all(e["requests"] == 1 for e in vb if e["size"] == 1), vb
+    assert all(e["block_items"] == (e["size"] if path == "block" else 0) for e in vb), vb
 
 
 def test_wrong_length_verdicts_fail_loudly():
@@ -1176,6 +1187,226 @@ def test_engine_parity_when_a_window_runs_as_chunks(costs, n, plan):
         assert got == [p[0] == s[0] for p, m, s in items]  # the rule, on the host
         assert (span["chunks"], span["rung"], span["split"]) == (len(plan), sum(plan), 1)
     assert eng._plan(n) == plan
+
+
+# -- a request is the block it came off the wire as (ISSUE 45) ------------------
+
+
+def _wire_blocks(items, sizes):
+    """``items`` cut into requests of ``sizes`` as the handler holds them: one
+    ``(n, 128)`` uint8 block each, over a buffer of its own."""
+    import numpy as np
+
+    blocks, off = [], 0
+    for n in sizes:
+        wire = bytearray(b"".join(p + m + s for p, m, s in items[off : off + n]))
+        blocks.append(np.frombuffer(wire, np.uint8).reshape(n, 128))
+        off += n
+    return blocks
+
+
+def _blocks(sizes, planted=()):
+    """Requests of ``sizes`` items as wire blocks, with the items (rejects at
+    the window's positions ``planted``) and the fake kernel's verdicts."""
+    want = [i not in planted for i in range(sum(sizes))]
+    items = [_item((i % 200) + 1, ok) for i, ok in enumerate(want)]
+    return _wire_blocks(items, sizes), items, want
+
+
+def test_a_window_is_a_sequence_of_triples_over_its_requests_blocks():
+    import numpy as np
+
+    from pbft_tpu.net.service import Window, as_rows
+
+    blocks, items, _want = _blocks([5, 14, 4])
+    window = Window(blocks)
+    assert len(window) == 23 and list(window) == items and list(window) == items  # twice
+    assert [window[i] for i in range(23)] == items and window[-1] == items[-1]
+    assert window[3:7] == items[3:7] and window[::-5] == items[::-5] and window[21:99] == items[21:]
+    assert window[:0] == [] and window.index(items[6]) == 6 and items[19] in window
+    for wild in (23, -24):
+        with pytest.raises(IndexError):
+            window[wild]
+    # rows(): views of the requests' own buffers, cut where asked and nowhere else
+    for (a, b), cuts in {(0, 16): [5, 11], (16, 24): [3, 4], (5, 19): [14], (4, 20): [1, 14, 1],
+                         (0, 23): [5, 14, 4], (19, 19): [], (23, 40): []}.items():
+        got = window.rows(a, b)
+        assert [len(r) for r in got] == cuts, (a, b)
+        assert b"".join(r.tobytes() for r in got) == b"".join(p + m + s for p, m, s in items[a:b])
+        assert all(any(np.shares_memory(r, blk) for blk in blocks) for r in got)
+    assert len(Window([])) == 0 and list(Window([])) == [] and Window([]).rows(0, 8) == []
+    # a list of triples becomes ONE block at the door, or is refused there
+    assert np.array_equal(as_rows(items), np.concatenate(blocks)) and as_rows([]).shape == (0, 128)
+    with pytest.raises(ValueError, match="not 128-byte triples"):
+        as_rows([(items[0][0], items[0][1], items[0][2][:63])])
+
+
+@pytest.mark.parametrize(
+    "costs, sizes, plan, writes",
+    [
+        # (a) a request straddles a chunk's edge: it is staged in two pieces
+        ({8: 0.001, 16: 0.001, 32: 0.004}, [5, 14, 4], (16, 8), [2, 2]),
+        ({8: 0.001, 32: 0.003, 128: 0.020}, [30, 1, 1, 9, 7], (32, 8, 8), [3, 1, 2]),
+        # (b) a request fills a shape exactly, alone and as the first of two chunks
+        ({8: 0.001, 16: 0.001}, [16], (16,), [1]),
+        ({8: 0.001, 16: 0.001, 32: 0.004}, [16, 8], (16, 8), [1, 1]),
+        ({8: 0.001, 16: 0.001}, [3, 5], (8,), [2]),
+        # one request beyond the largest shape: a piece a chunk
+        ({8: 0.001, 16: 0.001}, [40], (16, 16, 8), [1, 1, 1]),
+    ],
+    ids=["straddle", "straddle-twice", "fills-16", "fills-16-then-8", "two-fill-8", "oversized"],
+)
+def test_a_window_of_blocks_is_staged_a_request_a_slice(monkeypatch, costs, sizes, plan, writes):
+    """The engine on the dispatcher's ``Window``: one assignment a request
+    segment into each chunk's block (``writes`` a chunk), nothing made an
+    item, the verdicts ONE bool array equal to the rule on the host and to
+    what the same items give as a list, with rejects planted first, last,
+    at every request's edge and on both sides of every chunk's."""
+    import itertools
+
+    import numpy as np
+
+    from pbft_tpu.crypto import batch
+    from pbft_tpu.net.service import Window
+    from pbft_tpu.utils.trace import open_span
+
+    eng = ShardedVerifyEngine(shapes=tuple(costs), kernel=_fake_kernel)
+    eng.warm()
+    eng._route(costs)
+    n = sum(sizes)
+    assert eng._plan(n) == plan
+    staged, real_pad = [], batch.pad_rows
+    monkeypatch.setattr(
+        batch, "pad_rows", lambda segs, size: staged.append([len(r) for r in segs]) or real_pad(segs, size)
+    )
+    monkeypatch.setattr(Window, "__iter__", lambda self: pytest.fail("an item was made"))
+    monkeypatch.setattr(Window, "__getitem__", lambda self, i: pytest.fail("an item was made"))
+    edges = set(itertools.accumulate(sizes)) | set(itertools.accumulate(plan))
+    for planted in (
+        {0, n - 1} | {e - 1 for e in edges} | {e for e in edges if e < n},
+        set(),
+        set(range(n)),
+    ):
+        blocks, items, want = _blocks(sizes, planted)
+        staged.clear()
+        with open_span() as span:
+            got = eng.verify(Window(blocks))
+        assert isinstance(got, np.ndarray) and got.dtype == bool and got.tolist() == want
+        assert [len(segs) for segs in staged] == writes and sum(map(sum, staged)) == n
+        assert (span["block_items"], span["chunks"], span["rung"]) == (n, len(plan), sum(plan))
+        with open_span() as span:
+            assert eng.verify(items) == want  # a list in, a list out, packed at the engine's door
+        assert span["block_items"] == 0
+        assert want == [p[0] == s[0] for p, m, s in items]  # the rule, on the host
+
+
+def _real_items(n):
+    """``n`` really signed items, the first one's signature broken."""
+    from pbft_tpu.crypto import ref
+
+    items = []
+    for i in range(n):
+        seed, msg = bytes([i + 1]) * 32, bytes([0xA0 ^ i]) * 32
+        items.append((ref.public_key(seed), msg, ref.sign(seed, msg)))
+    items[0] = (*items[0][:2], bytes([items[0][2][0] ^ 1]) + items[0][2][1:])
+    return items
+
+
+@pytest.mark.parametrize("backend", ["callable", "native", "cpu", "fallback", "engine"])
+def test_every_backend_is_served_by_the_one_dispatcher_and_the_line_says_how(tmp_path, backend):
+    """A merged window reaches a list-returning callable, the ``native`` and
+    ``cpu`` backends and the daemon's fallback before ``ready`` as a sequence
+    of triples (``block_items`` 0 on the line, ``listed_items`` in the
+    status) and the engine as its requests' blocks (``block_items`` = size),
+    through the same handler, queue and cut; the verdicts are the same
+    bytes on the wire either way."""
+    import json
+
+    gate, calls = threading.Event(), []
+    real = backend in ("native", "cpu", "fallback")
+    items = _real_items(5) if real else [_item(i + 1, i != 0) for i in range(5)]
+    want = [False, True, True, True, True]
+    engine = ShardedVerifyEngine(shapes=(8,), kernel=_fake_kernel)
+
+    def held(verify):
+        def run(window):
+            calls.append(len(window))
+            if len(calls) == 1:
+                gate.wait(20)
+            return verify(window)
+
+        return run
+
+    trace = tmp_path / "service.jsonl"
+    if backend == "fallback":  # a daemon whose engine never gets warm
+        never = threading.Event()
+        from pbft_tpu.consensus.replica import host_batch_verify
+
+        daemon = VerifyServiceDaemon(
+            backend="auto", engine=_StubEngine(never), trace_path=str(trace),
+            fallback=held(host_batch_verify),
+        ).start()
+        svc, stop = daemon.service, lambda: (never.set(), daemon.stop())
+    else:
+        if backend == "engine":
+            engine.warm()
+        verify = {
+            "callable": lambda window: [p[0] == s[0] for p, m, s in window],
+            "engine": engine.verify,
+        }.get(backend)
+        if verify is None:
+            from pbft_tpu.net import service
+
+            verify = {"native": service.native_backend, "cpu": service.cpu_backend}[backend]
+        svc = VerifierService(backend=held(verify), trace_path=str(trace)).start()
+        stop = svc.stop
+    try:
+        results, conns = {}, [_Conn(svc.address) for _ in range(3)]
+        threads = [conns[0].send_later(items[:1], results, 0)]
+        while not calls:
+            time.sleep(0.005)
+        for k, part in ((1, items[1:3]), (2, items[3:])):  # queued behind it, in this order
+            threads.append(conns[k].send_later(part, results, k))
+            while svc.requests < k + 1:
+                time.sleep(0.005)
+        gate.set()
+        for t in threads:
+            t.join(timeout=20)
+            assert not t.is_alive()
+        assert results == {0: want[:1], 1: want[1:3], 2: want[3:]}
+        status = svc.launch_status()
+    finally:
+        gate.set()
+        for c in conns:
+            c.close()
+        stop()
+    lines = [json.loads(ln) for ln in trace.read_text().splitlines()]
+    if backend != "fallback":  # (the daemon's second launch slot takes the second request at once)
+        assert [(e["size"], e["requests"], e["rejected"]) for e in lines] == [(1, 1, 1), (4, 2, 0)]
+    assert (sum(e["size"] for e in lines), sum(e["rejected"] for e in lines)) == (5, 1)
+    assert all(e["block_items"] == (e["size"] if backend == "engine" else 0) for e in lines)
+    assert (status["block_items"], status["listed_items"]) == ((5, 0) if backend == "engine" else (0, 5))
+
+
+def test_an_in_process_caller_s_list_is_packed_once_at_the_door():
+    """``_submit`` with a list of triples (no socket): the same queue and
+    window, rows to a backend that stages rows, and a list of bools back."""
+    seen = []
+
+    def backend(window):
+        seen.append([rows.shape for rows in window.blocks])
+        return [p[0] == s[0] for p, m, s in window]
+
+    svc = VerifierService(backend=backend).start()
+    try:
+        items = [_item(i + 1, i % 2 == 0) for i in range(3)]
+        assert svc._submit(items) == [True, False, True]
+        assert svc._submit([]) == []
+        with pytest.raises(ValueError, match="not 128-byte triples"):
+            svc._submit([(b"short", b"", b"")])
+    finally:
+        svc.stop()
+    assert seen == [[(3, 128)], [(0, 128)]]
 
 
 _WARM_TWICE = """

@@ -821,7 +821,7 @@ def test_the_executable_takes_one_block_and_hands_the_kernel_its_columns():
 
 def test_warm_up_and_serving_stage_through_the_same_function(monkeypatch):
     """``launch_s`` (and with it the serving table, the chunk plan and the
-    hold) is timed on the path that serves: a replaced ``pad_batch`` is
+    hold) is timed on the path that serves: a replaced ``pad_rows`` is
     seen by ``_measure`` and by ``verify`` alike, and the executable gets
     what it returned, untouched, from both."""
     import numpy as np
@@ -829,18 +829,19 @@ def test_warm_up_and_serving_stage_through_the_same_function(monkeypatch):
     import pbft_tpu.parallel as parallel
     from pbft_tpu.crypto import batch
 
-    pads, blocks, real_pad, real_lower = [], [], batch.pad_batch, parallel.lower_sharded
+    pads, blocks, real_pad, real_lower = [], [], batch.pad_rows, parallel.lower_sharded
 
     class SeenLowered:  # what lower_sharded gives, its executable wrapped
         def __init__(self, lowered):
             self.as_text, self.compile = lowered.as_text, lambda: _Seen(lowered.compile())
 
-    def pad(items, size):
-        pads.append(len(items))
-        blocks.append(real_pad(items, size)[0])
-        return blocks[-1], len(items)
+    def pad(segments, size):
+        block, n = real_pad(segments, size)
+        pads.append(n)
+        blocks.append(block)
+        return block, n
 
-    monkeypatch.setattr(batch, "pad_batch", pad)
+    monkeypatch.setattr(batch, "pad_rows", pad)
     monkeypatch.setattr(parallel, "lower_sharded", lambda *a, **kw: SeenLowered(real_lower(*a, **kw)))
     engine = ShardedVerifyEngine(shapes=(8,), kernel=lambda p, m, s: p[:, 0] == s[:, 0])
     engine.warm()
