@@ -1,18 +1,20 @@
-"""Static-analysis passes over BOTH runtimes (ISSUE 8 tentpole).
+"""Static-analysis passes over the C++ core and the Python round it
+(ISSUE 8 tentpole).
 
-The codebase is two concurrent implementations of one protocol — a C++
-core and an asyncio runtime — held together by hand-mirrored constants
-and a shared metrics/trace manifest. Runtime fuzz (test_wire_codec.py)
-guards the dynamic behavior; this package is the static complement:
+One protocol is written down twice: the C++ core that serves, and the
+Python reference (state machine, codec, WAL, handshake) with the gateway,
+client and verify service that speak its wire — held together by
+hand-mirrored constants and a shared metrics/trace manifest. Runtime fuzz
+(test_wire_codec.py) guards the dynamic behavior; this package is the static complement:
 
-    constants       cross-runtime constant conformance (wire magic,
+    constants       C++ / Python constant conformance (wire magic,
                     message tags, protocol versions, config defaults,
                     RLC window, pad ladder, status handshake)
     async-blocking  no blocking calls inside ``async def`` in pbft_tpu/net
     metrics         every metric/trace emitter matches the manifest
                     (generalized successor of scripts/check_trace_schema)
     sockets         TCP_NODELAY / SO_REUSEADDR at every stream-socket
-                    creation site in both runtimes (ISSUE 10)
+                    creation site, C++ and Python (ISSUE 10)
 
 Entry point: ``scripts/pbft_lint.py`` (wired into tier-1 by
 tests/test_lint.py). Every pass takes a ``root`` so the tests can run

@@ -1,9 +1,8 @@
 """No blocking calls inside ``async def`` (ISSUE 8 tentpole, leg 3a).
 
-The asyncio runtime's event loop is the Python twin of net.cc's poll()
-loop: ONE blocking call inside a coroutine stalls every replica duty —
-verify batching, view-change timers, the chaos delay pump — exactly the
-wedge class the C++ side guards with deadlines. This pass walks the AST
+The gateway's asyncio event loop carries every client of a cluster: ONE
+blocking call inside a coroutine stalls them all — exactly the wedge
+class the C++ side guards with deadlines. This pass walks the AST
 of every module in ``pbft_tpu/net/`` and flags calls that are known to
 block when they appear inside an ``async def`` body:
 

@@ -1,8 +1,11 @@
-"""Cross-runtime constant conformance (ISSUE 8 tentpole, leg 2).
+"""Constant conformance between the C++ core and the Python reference
+(ISSUE 8 tentpole, leg 2).
 
-The C++ core and the asyncio runtime must agree on every hand-mirrored
-wire and protocol constant — the 0xB2 binary magic, the message type
-tags, the protocol version set, the ClusterConfig defaults, the RLC
+The C++ core and the Python side it is held to (the reference state
+machine, codec, WAL and handshake in ``consensus/`` and ``net/secure.py``;
+the gateway, client and verify service that speak its wire) must agree on
+every hand-mirrored wire and protocol constant — the 0xB2 binary magic,
+the message type tags, the protocol version set, the ClusterConfig defaults, the RLC
 window width, the verify-service pad ladder. Castro & Liskov's safety
 argument assumes replicas compute identical digests; a one-byte drift in
 any of these forks the accept set silently. tests/test_wire_codec.py
@@ -10,9 +13,9 @@ fuzzes the DYNAMIC behavior; this pass is the static complement — it
 parses both source trees (C++ by regex over declarations, Python by AST)
 and fails the build when the values diverge.
 
-Policy (README "Static analysis & sanitizers"): a new cross-runtime
-constant is added to BOTH runtimes and to ``PAIRS`` below in the same
-commit, or the lint fails the build.
+Policy (README "Static analysis & sanitizers"): a new mirrored constant
+is added to BOTH sides and to ``PAIRS`` below in the same commit, or the
+lint fails the build.
 
 Every check reads files relative to ``root`` so tests/test_lint.py can
 run the pass against a shadow tree with one deliberately divergent value.
@@ -64,7 +67,7 @@ PAIRS: List[Tuple[str, Tuple[str, str], Tuple[str, str]]] = [
     # MAC-vector frame variants (ISSUE 14): the five authenticated
     # codes, the lane-vector bound, the tag length, the KDF/domain
     # labels, and the auth-mode offer name — one byte of drift here and
-    # a mixed-runtime mac link rejects every frame.
+    # the reference's MAC vectors stop matching pbftd's.
     ("binary tag: pre-prepare (MAC)",
      ("core/messages.cc", "kBinPrePrepareMac"),
      ("pbft_tpu/consensus/messages.py", "_BIN_PRE_PREPARE_MAC")),
@@ -117,7 +120,7 @@ PAIRS: List[Tuple[str, Tuple[str, str], Tuple[str, str]]] = [
      ("core/ed25519.h", "kEd25519RlcWindowItems"),
      ("tests/test_verify_pool.py", "WINDOW")),
     # ClusterConfig defaults: a replica constructed from a sparse
-    # network.json must behave identically in either runtime.
+    # network.json must mean the same to pbftd and to the reference.
     ("ClusterConfig default: watermark_window",
      ("core/replica.h", "watermark_window"),
      ("pbft_tpu/consensus/config.py", "watermark_window")),
@@ -141,7 +144,7 @@ PAIRS: List[Tuple[str, Tuple[str, str], Tuple[str, str]]] = [
      ("pbft_tpu/consensus/config.py", "batch_flush_us")),
     # Admission control (ISSUE 12): per-client in-flight cap + global
     # backlog watermark — a sparse network.json must disable both
-    # identically in either runtime.
+    # identically in pbftd and in the reference.
     ("ClusterConfig default: admission_inflight",
      ("core/replica.h", "admission_inflight"),
      ("pbft_tpu/consensus/config.py", "admission_inflight")),
@@ -149,12 +152,12 @@ PAIRS: List[Tuple[str, Tuple[str, str], Tuple[str, str]]] = [
      ("core/replica.h", "admission_backlog"),
      ("pbft_tpu/consensus/config.py", "admission_backlog")),
     # Multi-core replica core (ISSUE 13): a sparse network.json must mean
-    # the classic single-threaded loop in both runtimes.
+    # the classic single-threaded loop.
     ("ClusterConfig default: net_threads",
      ("core/replica.h", "net_threads"),
      ("pbft_tpu/consensus/config.py", "net_threads")),
     # Fast-path modes (ISSUE 14): a sparse network.json must mean
-    # signature mode + committed-only replies in both runtimes.
+    # signature mode + committed-only replies on both sides.
     ("ClusterConfig default: fastpath",
      ("core/replica.h", "fastpath"),
      ("pbft_tpu/consensus/config.py", "fastpath")),
@@ -162,8 +165,8 @@ PAIRS: List[Tuple[str, Tuple[str, str], Tuple[str, str]]] = [
      ("core/replica.h", "tentative"),
      ("pbft_tpu/consensus/config.py", "tentative")),
     # Durable replica recovery (ISSUE 15): the WAL's on-disk format is
-    # byte-identical across runtimes (a pbftd-written log must replay in
-    # the asyncio runtime's tooling and vice versa) — magic, version,
+    # byte-identical on both sides (a pbftd-written log must replay in
+    # consensus/wal.py and vice versa) — magic, version,
     # record tags, and vote kinds are all hand-mirrored; and a sparse
     # network.json must mean no-WAL + fsync-on identically in both.
     ("WAL file magic",
@@ -197,7 +200,7 @@ PAIRS: List[Tuple[str, Tuple[str, str], Tuple[str, str]]] = [
      ("core/replica.h", "wal_fsync"),
      ("pbft_tpu/consensus/config.py", "wal_fsync")),
     # ISSUE 12: forwarded-request retention (view-change re-aim) bound —
-    # same eviction point in both runtimes or their storm behavior forks.
+    # same eviction point as the reference or their storm behavior forks.
     ("forwarded-request retention bound",
      ("core/replica.h", "kMaxForwardedRetained"),
      ("pbft_tpu/consensus/replica.py", "MAX_FORWARDED_RETAINED")),
@@ -205,23 +208,19 @@ PAIRS: List[Tuple[str, Tuple[str, str], Tuple[str, str]]] = [
     ("verify-service status version",
      ("core/verifier.cc", "kStatusVersionLint"),  # custom, see below
      ("pbft_tpu/net/service.py", "STATUS_VERSION")),
-    # Gateway tier (ISSUE 10): the routing-token prefix both runtimes
-    # switch the reply path on, and the bounded-queue/route-cache sizes
-    # the backpressure and fan-back fallback policies share.
+    # Gateway tier (ISSUE 10): the routing-token prefix pbftd switches
+    # the reply path on, and the bounded outbound queue both ends of a
+    # gateway link hold a slow reader to.
     ("gateway client-token prefix",
      ("core/net.h", "kGatewayClientPrefix"),
      ("pbft_tpu/net/gateway.py", "GATEWAY_CLIENT_PREFIX")),
     ("max per-connection outbound bytes",
      ("core/net.cc", "kMaxConnOutbound"),
-     ("pbft_tpu/net/server.py", "MAX_CONN_OUTBOUND")),
-    ("gateway route-cache bound",
-     ("core/net.cc", "kMaxGatewayRoutes"),
-     ("pbft_tpu/net/server.py", "MAX_GATEWAY_ROUTES")),
+     ("pbft_tpu/net/gateway.py", "_MAX_WRITE_BUFFER")),
     # ISSUE 16 health introspection: the health-document schema version
-    # both runtimes stamp into their /status surface, and the detector
+    # pbftd and the gateway stamp into /status, and the detector
     # thresholds every gate (pbft_top, endurance_soak, chaos harnesses)
-    # shares — a fork here makes a mixed-runtime cluster's health reads
-    # incomparable.
+    # shares.
     ("health document version",
      ("core/net.h", "kHealthDocVersion"),
      ("pbft_tpu/utils/trace_schema.py", "HEALTH_DOC_VERSION")),

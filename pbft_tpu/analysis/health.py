@@ -2,10 +2,9 @@
 
 Pure functions — no sockets, no clocks. The input is a *history*: a list
 of snapshots, each ``{"t": <seconds, monotonic-ish float>, "replicas":
-{<rid>: <health document>}}`` where the health document is the dict both
-runtimes serve on ``/status`` (core/net.cc ``metrics_json`` /
-pbft_tpu/net/server.py ``metrics()``; shape stamped by
-``health_version``). Collectors — ``scripts/pbft_top.py``,
+{<rid>: <health document>}}`` where the health document is the dict
+``pbftd`` serves on ``/status`` (core/net.cc ``metrics_json``; shape
+stamped by ``health_version``). Collectors — ``scripts/pbft_top.py``,
 ``scripts/endurance_soak.py``, the chaos harnesses' ``--health-gate`` —
 build histories however they like (live HTTP polls, simulator state,
 parsed logs) and hand them here, so every gate in the repo trips on the
@@ -52,7 +51,7 @@ from typing import Dict, List, Optional
 
 # Shared thresholds/defaults (constants lint pairs with core/net.h:
 # kHealthStallSeconds, kHealthSnapshotIntervalS). The stall threshold is
-# deliberately whole seconds: last-progress clocks on both runtimes are
+# deliberately whole seconds: pbftd's last-progress clock is
 # quantized to the observation cadence.
 HEALTH_STALL_SECONDS = 5
 HEALTH_SNAPSHOT_INTERVAL_S = 2

@@ -1,14 +1,15 @@
 """Metrics/trace-event conformance lint (ISSUE 8 tentpole, leg 3b).
 
 The generalized successor of scripts/check_trace_schema.py (now a thin
-shim over this module): every trace-event and metric emitter in BOTH
-runtimes is statically extracted and diffed against the single manifest,
+shim over this module): every trace-event and metric emitter, pbftd and
+the Python processes round it, is statically extracted and diffed against
+the single manifest,
 ``pbft_tpu/utils/trace_schema.py``.
 
 Per emitter:
 
-- Python emitters (net/server.py, net/service.py, net/verify_service.py,
-  utils/metrics.py): every ``tracer.event("name", field=...)`` call is
+- Python emitters (net/service.py, net/verify_service.py, net/client.py,
+  net/gateway.py): every ``tracer.event("name", field=...)`` call is
   parsed from the AST — the event name must be in the manifest with this
   file listed as an emitter, its keyword fields a subset of
   required|optional, every required field present. Every
@@ -45,21 +46,15 @@ from typing import Dict, List
 REPO = pathlib.Path(__file__).resolve().parent.parent.parent
 
 PY_EMITTERS = {
-    "server.py": pathlib.Path("pbft_tpu/net/server.py"),
     "service.py": pathlib.Path("pbft_tpu/net/service.py"),
     "verify_service.py": pathlib.Path("pbft_tpu/net/verify_service.py"),
     # The client emits its half of the latency waterfall (client_request
     # send/first-reply/quorum stamps, ISSUE 9) — held to the same
-    # manifest contract as the replica runtimes.
+    # manifest contract as pbftd.
     "client.py": pathlib.Path("pbft_tpu/net/client.py"),
     # The gateway tier (ISSUE 10): clients-open gauge, forwarded counter,
     # and the shared backpressure counter — same manifest contract.
     "gateway.py": pathlib.Path("pbft_tpu/net/gateway.py"),
-}
-# utils/metrics.py emits consensus_span on behalf of server.py (the spans
-# object is wired there); lint it under the server.py emitter identity.
-PY_EMITTER_ALIASES = {
-    pathlib.Path("pbft_tpu/utils/metrics.py"): "server.py",
 }
 NET_CC = pathlib.Path("core/net.cc")
 METRICS_CC = pathlib.Path("core/metrics.cc")
@@ -80,7 +75,6 @@ def load_manifest(root: pathlib.Path):
 
 def files_scanned(root: pathlib.Path = REPO) -> List[pathlib.Path]:
     fixed = [root / p for p in PY_EMITTERS.values()]
-    fixed += [root / p for p in PY_EMITTER_ALIASES]
     fixed += [root / p for p in (NET_CC, METRICS_CC, PY_REPLICA, CC_REPLICA,
                                  MANIFEST)]
     return fixed + _sweep_files(root)
@@ -90,7 +84,6 @@ def _sweep_files(root: pathlib.Path) -> List[pathlib.Path]:
     """The generalized-sweep targets: every pbft_tpu module that is not
     already a declared emitter (those get the stricter per-emitter lint)."""
     known = {root / p for p in PY_EMITTERS.values()}
-    known |= {root / p for p in PY_EMITTER_ALIASES}
     out = []
     for path in sorted((root / "pbft_tpu").rglob("*.py")):
         if path in known or "__pycache__" in path.parts:
@@ -164,9 +157,7 @@ def check(root: pathlib.Path = REPO) -> List[str]:
 
     # -- Python trace events -------------------------------------------------
     py_seen: Dict[str, set] = {}  # emitter -> set of event names
-    files = [(em, root / p) for em, p in PY_EMITTERS.items()] + [
-        (em, root / p) for p, em in PY_EMITTER_ALIASES.items()
-    ]
+    files = [(em, root / p) for em, p in PY_EMITTERS.items()]
     for emitter, path in files:
         for name, fields, dynamic, line in _event_calls(path):
             loc = f"{path.name}:{line}"
@@ -219,13 +210,6 @@ def check(root: pathlib.Path = REPO) -> List[str]:
                     f"{loc}: {emitter} is not a manifest emitter of {name!r}"
                 )
             py_metrics_seen.setdefault(emitter, set()).add(name)
-    # ConsensusSpans (utils/metrics.py, wired into server.py) records the
-    # phase histograms through the PHASE_HISTOGRAMS table rather than
-    # string literals — credit those to server.py from the manifest table
-    # itself (drift there is drift in the manifest, not the emitter).
-    py_metrics_seen.setdefault("server.py", set()).update(
-        trace_schema.PHASE_HISTOGRAMS.values()
-    )
     for name, (kind, emitters) in metrics.items():
         for emitter in emitters & set(PY_EMITTERS):
             if name not in py_metrics_seen.get(emitter, set()):

@@ -298,7 +298,7 @@ def run_native_config(
         for c in handles:
             c.close()
         # Cluster-wide counters from each replica's last metrics line
-        # (core/net.cc metrics_json / server.py metrics): signature
+        # (core/net.cc metrics_json): signature
         # verifications, plus requests vs rounds executed — their ratio
         # is the measured batch occupancy.
         sig_total = 0

@@ -63,11 +63,9 @@ class ClusterConfig:
     admission_inflight: int = 0
     admission_backlog: int = 0
     # Multi-core replica core (ISSUE 13): event-loop shard threads (each
-    # with a companion crypto pipeline thread) the NATIVE runtime runs;
-    # 1 = the classic single-threaded loop. The asyncio runtime accepts
-    # the key and stays single-loop (it logs as much at startup) — its
-    # parallelism lives in the JAX mesh, not the socket layer. The
-    # default is constants-linted against core/replica.h.
+    # with a companion crypto pipeline thread) pbftd runs; 1 = the classic
+    # single-threaded loop. The default is constants-linted against
+    # core/replica.h.
     net_threads: int = 1
     # Fast-path modes (ISSUE 14, protocol 1.3.0; defaults constants-linted
     # against core/replica.h). fastpath = "mac" makes this node OFFER the

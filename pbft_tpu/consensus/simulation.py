@@ -36,8 +36,8 @@ from .messages import (
 from .replica import Broadcast, Replica, Reply, Send, _host_sign
 from .wal import WriteAheadLog
 
-# Replica-level Byzantine behavior modes (the sim arm of the cross-runtime
-# --fault flag; core/pbftd.cc and net/server.py accept the same names).
+# Replica-level Byzantine behavior modes (the sim arm of pbftd's --fault
+# flag; core/pbftd.cc accepts the same names).
 FAULT_MODES = ("sig-corrupt", "mute", "stutter", "equivocate")
 
 # Deterministic equivocation transform: variant B of a batch mutates every

@@ -1,7 +1,9 @@
 """pbft_tpu.net — the host-side runtime glue around the native daemon.
 
-- ``server``    — the asyncio replica runtime (in-process verifier, or a
-  ``ServiceVerifier`` dialing ``verifyd``).
+``pbftd`` (core/) is the one program that is a replica on sockets; the
+Python state machine in ``pbft_tpu.consensus`` under the simulator is the
+reference it is held to.
+
 - ``service``   — the verify service's wire protocol (the 128-byte-triple
   batches ``pbftd``'s RemoteVerifier ships, core/verifier.h, and the two
   status probes) and its dispatcher, which merges what every connection
@@ -11,20 +13,20 @@
   the chip (owns the accelerator, AOT-warms and times every pad-ladder
   shape, shards each window across all local devices); ``verifyd``, the
   one daemon, which joins engine and dispatcher and alone answers
-  ``ready``; plus the replica-side ``ServiceVerifier`` client (short
-  connect deadline, native-pool fallback).
+  ``ready``.
 - ``secure``    — encrypted replica links + protocol versioning
-  (signed-ephemeral-DH handshake, keyed-BLAKE2b AEAD; mirror of
-  core/secure.cc — the reference's Noise-secured development_transport,
-  reference src/main.rs:42).
-- ``discovery`` — UDP-multicast peer discovery (mirror of
-  core/discovery.cc; the reference's mDNS layer, src/main.rs:46).
+  (signed-ephemeral-DH handshake, keyed-BLAKE2b AEAD; what
+  core/secure.cc is held to byte for byte — the reference's
+  Noise-secured development_transport, reference src/main.rs:42), and the
+  version the gateway's hello carries.
+- ``gateway``   — the client-gateway tier: thousands of client
+  connections onto one framed link a replica.
 - ``client``    — the PBFT client: sends a raw-JSON request to the primary
   and collects dialed-back replies until f+1 match (PBFT §4.1; the
   reference's manual telnet + ``nc -kl`` walkthrough, README.md:5-43,
   scripted).
-- ``launcher``  — spawns a localhost cluster of ``pbftd`` and/or asyncio
-  replicas from a ClusterConfig (the reference ran 4 shells by hand).
+- ``launcher``  — spawns a localhost cluster of ``pbftd`` from a
+  ClusterConfig (the reference ran 4 shells by hand).
 """
 
 from .client import PbftClient
@@ -32,7 +34,6 @@ from .launcher import LocalCluster, pbftd_path
 from .secure import PROTOCOL_VERSION, SecureChannel
 from .service import VerifierService
 from .verify_service import (
-    ServiceVerifier,
     ShardedVerifyEngine,
     VerifyServiceDaemon,
     probe_status,
@@ -45,7 +46,6 @@ __all__ = [
     "VerifierService",
     "VerifyServiceDaemon",
     "ShardedVerifyEngine",
-    "ServiceVerifier",
     "probe_status",
     "probe_status_json",
     "SecureChannel",

@@ -8,8 +8,8 @@ exhaustion long before it is a throughput problem. The gateway keeps the
 telnet-able downstream contract (raw JSON lines in, raw JSON reply lines
 out, all on ONE connection) and swaps the upstream shape: one framed,
 persistent link per replica, announced by a ``role=gateway`` hello, over
-which client requests flow up and replies fan BACK (both runtimes trust
-the link instead of dialing the client; core/net.cc + net/server.py).
+which client requests flow up and replies fan BACK (pbftd trusts the
+link instead of dialing the client; core/net.cc).
 10k concurrent clients then cost the cluster ~n·gateways sockets.
 
 Identity: a gateway-routed client addresses itself with a ROUTING TOKEN,
@@ -66,7 +66,7 @@ from .client import PbftClient
 GATEWAY_CLIENT_PREFIX = "gw/"
 
 # Bounded outbound per downstream/upstream connection (mirrors
-# server.py MAX_CONN_OUTBOUND / core/net.cc kMaxConnOutbound).
+# core/net.cc kMaxConnOutbound; constants lint).
 _MAX_WRITE_BUFFER = 8 << 20
 # Token bookkeeping bound: on overflow the maps clear — a cleared route
 # re-registers on the client's next request, a cleared high-water mark
@@ -111,7 +111,7 @@ def _parse(payload: bytes):
 
 def gateway_hello() -> dict:
     """The version-carrying hello that opens every upstream link. The
-    ``role`` field is the trust switch: both runtimes mark the link as a
+    ``role`` field is the trust switch: pbftd marks the link as a
     gateway link (requests arrive on it, replies fan back over it)."""
     return {
         "type": "hello",

@@ -38,13 +38,11 @@ def free_ports(n: int) -> List[int]:
 
 
 class LocalCluster:
-    """N replica processes on loopback ephemeral ports.
+    """N ``pbftd`` processes on loopback ephemeral ports.
 
-    ``impl`` selects the runtime per replica: "cxx" spawns the native
-    pbftd daemon, "py" spawns the asyncio runtime
-    (python -m pbft_tpu.net.server). The two are wire-compatible (framed
-    canonical JSON), so mixed clusters interoperate — the strongest form
-    of the cross-implementation determinism requirement (SURVEY.md §7)."""
+    ``verifier`` is what ``pbftd --verifier`` takes: "cpu" (the native
+    pool) or the address of a verify service (``host:port`` or a unix
+    path). The chip is reached through ``verifyd`` alone."""
 
     def __init__(
         self,
@@ -52,7 +50,6 @@ class LocalCluster:
         verifier: str = "cpu",
         metrics_every: int = 0,
         vc_timeout_ms: int = 0,
-        impl: "str | List[str]" = "cxx",
         discovery: bool = False,
         config: Optional[ClusterConfig] = None,
         seeds: Optional[List[bytes]] = None,
@@ -102,8 +99,8 @@ class LocalCluster:
             isinstance(batch_max_items, list) or isinstance(batch_flush_us, list)
         )
         # Replica ids whose daemons corrupt every outgoing signature
-        # (--byzantine, both runtimes; the real-daemon analogue of the
-        # simulation's outbound mutator).
+        # (--byzantine; the real-daemon analogue of the simulation's
+        # outbound mutator).
         self.byzantine = set(byzantine or [])
         # Generalized fault injection (ISSUE 5): {replica_id: mode} maps
         # to --fault on the daemon (sig-corrupt|mute|stutter|equivocate),
@@ -112,18 +109,16 @@ class LocalCluster:
         # one scalar still gives each daemon its own stream).
         self.faults = dict(faults or {})
         # Durable recovery (ISSUE 15): wal=True gives every replica a
-        # write-ahead log under {tmpdir}/wal (--wal-dir on both
-        # runtimes); kill(hard=True) + revive(from_disk=True) then
-        # exercises the kill -9 -> replay-from-disk path. wal_fsync=False
-        # keeps the writes but skips the fsync (the A/B durability-cost
-        # lever).
+        # write-ahead log under {tmpdir}/wal (--wal-dir); kill(hard=True)
+        # + revive(from_disk=True) then exercises the kill -9 ->
+        # replay-from-disk path. wal_fsync=False keeps the writes but skips
+        # the fsync (the A/B durability-cost lever).
         self.wal = wal
         self.wal_fsync = wal_fsync
         # Health introspection (ISSUE 16): metrics_ports=True gives every
-        # replica a loopback scrape listener (--metrics-port, both
-        # runtimes) serving Prometheus + the /status health document;
-        # self.metrics_ports maps replica id -> bound port after
-        # __enter__ (pre-allocated — pbftd logs its ephemeral port to
+        # replica a loopback scrape listener (--metrics-port) serving
+        # Prometheus + the /status health document; self.metrics_ports maps
+        # replica id -> bound port after __enter__ (pre-allocated — pbftd logs its ephemeral port to
         # stderr, but pre-allocation keeps revive() on the same port).
         self.want_metrics_ports = metrics_ports
         self.metrics_ports: List[int] = []  # reserved with the listen ports, below
@@ -161,17 +156,14 @@ class LocalCluster:
                 batch_flush_us=(
                     batch_flush_us if self._batch_scalar else 0
                 ),
-                # Admission control (ISSUE 12): network.json knobs, read
-                # identically by both runtimes.
+                # Admission control (ISSUE 12): network.json knobs.
                 admission_inflight=admission_inflight,
                 admission_backlog=admission_backlog,
                 # Multi-core replica core (ISSUE 13): pbftd shards its
-                # event loop; the asyncio runtime accepts the key and
-                # stays single-loop.
+                # event loop.
                 net_threads=net_threads,
                 # Fast-path modes (ISSUE 14): the MAC authenticator
-                # offer and tentative execution, read identically by
-                # both runtimes from network.json.
+                # offer and tentative execution.
                 fastpath=fastpath,
                 tentative=tentative,
                 # Durable recovery (ISSUE 15): wal_fsync rides in
@@ -179,27 +171,17 @@ class LocalCluster:
                 # --wal-dir flag (set in __enter__, where tmpdir exists).
                 wal_fsync=wal_fsync,
             )
+        if verifier == "jax":
+            raise ValueError(
+                'verifier="jax" named the in-process arm of a Python replica '
+                "that no longer exists: start scripts/verifyd.py (--backend "
+                "jax owns the chip) and pass its address as verifier"
+            )
         self.config = config
         self.seeds = seeds
         self.verifier = verifier
         self.metrics_every = metrics_every
         self.vc_timeout_ms = vc_timeout_ms
-        self.impl = [impl] * self.config.n if isinstance(impl, str) else list(impl)
-        # One process per chip: a "py" replica with verifier="jax"
-        # initializes JAX in-process, and on an accelerator only the first
-        # such process gets the device. That arm is the CPU test arm; on
-        # the chip every replica of either runtime points at verifyd's
-        # address instead.
-        if (
-            verifier == "jax"
-            and self.impl.count("py") > 1
-            and os.environ.get("JAX_PLATFORMS") != "cpu"
-        ):
-            raise ValueError(
-                f"{self.impl.count('py')} in-process jax replicas would "
-                "each claim the accelerator; pin JAX_PLATFORMS=cpu (the "
-                "test arm) or pass verifier=<verifyd host:port>"
-            )
         # Per-replica environment overrides (e.g. PBFT_WIRE_CODEC=json to
         # force a JSON-only 1.0.0 peer in a mixed-codec interop test).
         self.extra_env = extra_env or [None] * self.config.n
@@ -209,7 +191,6 @@ class LocalCluster:
 
     def __enter__(self) -> "LocalCluster":
         import random
-        import sys
 
         if self.discovery:
             # Unique group:port per cluster so parallel tests don't hear
@@ -219,27 +200,15 @@ class LocalCluster:
                 random.randint(1, 254),
                 free_ports(1)[0],
             )
-        daemon = pbftd_path() if "cxx" in self.impl else None
+        daemon = pbftd_path()
         self.tmpdir = tempfile.TemporaryDirectory(prefix="pbftd-")
         cfg_path = Path(self.tmpdir.name) / "network.json"
         cfg_path.write_text(self.config.to_json())
-        repo_root = str(Path(__file__).resolve().parent.parent.parent)
         for i in range(self.config.n):
             log = open(Path(self.tmpdir.name) / f"replica-{i}.log", "wb")
-            if self.impl[i] == "cxx":
-                cmd = [str(daemon)]
-                env = None
-            else:
-                cmd = [sys.executable, "-m", "pbft_tpu.net.server"]
-                env = dict(os.environ, PYTHONPATH=repo_root)
-                if self.verifier != "jax":
-                    # Keep a cpu-verifier replica from initializing any
-                    # accelerator backend at import time.
-                    env["JAX_PLATFORMS"] = "cpu"
-            if self.extra_env[i]:
-                env = dict(env if env is not None else os.environ)
-                env.update(self.extra_env[i])
-            cmd += [
+            env = dict(os.environ, **self.extra_env[i]) if self.extra_env[i] else None
+            cmd = [
+                str(daemon),
                 "--config",
                 str(cfg_path),
                 "--id",

@@ -4,7 +4,7 @@ The reference secures every libp2p link with ``development_transport``
 (Noise encryption + yamux muxing, reference src/main.rs:42) and names its
 protocol ``/ackintosh/pbft/1.0.0`` (reference src/protocol_config.rs:24).
 This module is the rebuild's equivalent, designed around the primitives
-both runtimes already ship (Ed25519 point arithmetic + BLAKE2b) instead
+pbftd and this package already ship (Ed25519 point arithmetic + BLAKE2b) instead
 of pulling in a Noise stack:
 
 - **Handshake**: signed ephemeral Diffie-Hellman on edwards25519 (the
@@ -114,15 +114,6 @@ def wire_offer_mac(fastpath_mac: bool) -> bool:
     cluster config asked for it (fastpath == "mac") AND nothing capped
     the advertised protocol below 1.3.0."""
     return fastpath_mac and not _wire_json_forced() and not _proto_capped_12()
-
-
-def hello_offers_binary(obj: dict) -> bool:
-    """True when a peer's hello offers the binary-v2 codec (and this node
-    offers it too): the sender may then encode hot messages as binary."""
-    if not wire_offer_binary():
-        return False
-    codecs = obj.get("codecs")
-    return isinstance(codecs, list) and CODEC_BINARY2 in codecs
 
 
 def hello_offers_mac(obj: dict) -> bool:
@@ -454,10 +445,6 @@ class SecureChannel:
             )
         self._recv_ctr += 1
         return payload
-
-
-def reject_payload(reason: str) -> dict:
-    return {"type": "reject", "reason": reason, "ver": wire_hello_version()}
 
 
 def plain_hello(my_id: int, offer_mac: bool = False) -> dict:

@@ -64,7 +64,7 @@ ROW = 128  # bytes an item on the wire: pub 32 | msg 32 | sig 64
 # Real batches are capped far below (MAX_WINDOW / the C++ async write
 # budget), so neither value can collide with traffic; pre-handshake
 # clients never sent count 0 (an empty batch was short-circuited before
-# the socket on both runtimes).
+# the socket).
 
 STATUS_PROBE = 0
 STATUS_JSON_PROBE = 0xFFFFFFFF

@@ -1,8 +1,8 @@
 """The persistent multi-chip verify service: own the accelerator, pay
 compile once, shard every window. This module holds the engine (the one
 way a served window reaches the chip), the daemon that joins it to
-``service.py``'s dispatcher, the replica-side client and the ``verifyd``
-CLI: the one entry point of the one daemon.
+``service.py``'s dispatcher and the ``verifyd`` CLI: the one entry point
+of the one daemon.
 
 One long-lived process per host initializes the JAX backend ONCE,
 AOT-compiles the sharded verify kernel for every fixed `_PAD_LADDER`
@@ -19,12 +19,11 @@ status record (state warming|ready|cpu-only + device count + warmed
 shape count); count 0xFFFFFFFF returns a length-prefixed JSON status
 (platform, device kind, devices seen and in the mesh, per-shape compile
 seconds, engine and fallback dispatch counts) for humans, the bench and
-``chip_smoke.py``. Replicas — ``core/verifier.cc`` RemoteVerifier and the
-asyncio runtime via :class:`ServiceVerifier` — dial with a SHORT connect
-deadline, consume the handshake, and fall back to the native pool
-(``consensus.replica.host_batch_verify``) while the service is warming
-or gone: a cold accelerator can never block consensus. Both ends count
-those fallbacks, so a run that never reached the device shows it.
+``chip_smoke.py``. Replicas (``core/verifier.cc`` RemoteVerifier) dial
+with a SHORT connect deadline, consume the handshake, and fall back to
+their native pool while the service is warming or gone: a cold
+accelerator can never block consensus. Both ends count those fallbacks,
+so a run that never reached the device shows it.
 
 ``--backend jax`` is the chip deployment: a warm-up failure, or a
 backend that is not a TPU (unless ``JAX_PLATFORMS`` itself names cpu,
@@ -909,124 +908,6 @@ def wait_for_tpu_service(target: str, proc=None, budget_s: float = 900.0) -> dic
         f"verify service at {target} not ready on a TPU after "
         f"{budget_s:.0f}s (last status: {status})"
     )
-
-
-class ServiceVerifier:
-    """The asyncio runtime's remote-verifier client (Python mirror of
-    ``core/verifier.cc`` RemoteVerifier): dial the colocated verify
-    service with a SHORT connect deadline, consume the readiness
-    handshake, and ship (pub, digest, sig) batches over the 128-byte
-    protocol. Any failure — connect refused, service warming, killed
-    mid-stream, wrong-length reply — degrades to the PR-2 native pool
-    (``consensus.replica.host_batch_verify``) for that batch and backs
-    off reconnecting, so the replica's verify loop NEVER stalls on the
-    service's lifecycle. ``verify_batch`` never raises."""
-
-    def __init__(
-        self,
-        target: str,
-        fallback: Optional[Callable[[List[Item]], List[bool]]] = None,
-        connect_timeout: float = 0.25,
-        io_timeout: float = 30.0,
-        retry_s: float = 1.0,
-    ):
-        self.target = target
-        if fallback is None:
-            from ..consensus.replica import host_batch_verify as fallback
-        self._fallback = fallback
-        self._connect_timeout = connect_timeout
-        self._io_timeout = io_timeout
-        self._retry_s = retry_s
-        self._sock: Optional[socket.socket] = None
-        self._lock = threading.Lock()
-        self._retry_after = 0.0
-        self.state: Optional[int] = None
-        self.devices = 0
-        self.used_fallback = 0  # batches the local pool absorbed
-
-    def _drop(self) -> None:
-        if self._sock is not None:
-            try:
-                self._sock.close()
-            except OSError:
-                pass
-            self._sock = None
-        self.state = None
-        self._retry_after = time.monotonic() + self._retry_s
-
-    def _ensure_connected(self) -> bool:
-        if self._sock is not None:
-            # Re-probe a warming service at the retry cadence; ready and
-            # cpu-only connections are settled.
-            if self.state != STATE_WARMING:
-                return self.state in (STATE_READY, STATE_CPU_ONLY)
-            if time.monotonic() < self._retry_after:
-                return False
-            self._retry_after = time.monotonic() + self._retry_s
-            try:
-                self._sock.sendall(STATUS_PROBE.to_bytes(4, "big"))
-                st = unpack_status(_recv_exact(self._sock, STATUS_LEN))
-            except (OSError, ConnectionError):
-                st = None
-            if st is None:
-                self._drop()
-                return False
-            self.state, self.devices, _ = st
-            return self.state in (STATE_READY, STATE_CPU_ONLY)
-        if time.monotonic() < self._retry_after:
-            return False
-        try:
-            sock = _dial(self.target, self._connect_timeout)
-            sock.settimeout(self._io_timeout)
-            sock.sendall(STATUS_PROBE.to_bytes(4, "big"))
-            st = unpack_status(_recv_exact(sock, STATUS_LEN))
-        except (OSError, ConnectionError):
-            self._retry_after = time.monotonic() + self._retry_s
-            return False
-        if st is None:
-            sock.close()
-            self._retry_after = time.monotonic() + self._retry_s
-            return False
-        self._sock = sock
-        self.state, self.devices, _ = st
-        # Warming: keep the connection (the handshake was answered) but
-        # serve from the fallback until a later probe reports ready.
-        return self.state in (STATE_READY, STATE_CPU_ONLY)
-
-    def verify_batch(self, items: List[Item]) -> List[bool]:
-        if not items:
-            return []
-        with self._lock:
-            if not self._ensure_connected():
-                self.used_fallback += 1
-                return self._fallback(items)
-            try:
-                payload = b"".join(p + m + s for p, m, s in items)
-                self._sock.sendall(
-                    len(items).to_bytes(4, "big") + payload
-                )
-                out = _recv_exact(self._sock, len(items))
-                return [bool(b) for b in out]
-            except (OSError, ConnectionError):
-                # Killed mid-stream: drop the link (partial verdict bytes
-                # must never pair with the next batch) and verify THIS
-                # batch locally — the liveness contract.
-                self._drop()
-                self.used_fallback += 1
-                return self._fallback(items)
-
-    # API parity with the verdict-list contract used by the server's
-    # verify loop (callable style).
-    __call__ = verify_batch
-
-    def close(self) -> None:
-        with self._lock:
-            if self._sock is not None:
-                try:
-                    self._sock.close()
-                except OSError:
-                    pass
-                self._sock = None
 
 
 def main(

@@ -1,9 +1,8 @@
 """Multi-host scaling for the batch verifier (ICI/DCN; scaling-book recipe).
 
-The consensus transport stays on the host network (C++ asio-style TCP /
-the asyncio runtime — SURVEY.md §5: consensus-critical small messages
-never route through the TPU fabric). What scales over the accelerator
-fabric is the *verification burden*: when a cluster's signature volume
+The consensus transport stays on the host network (pbftd's TCP —
+SURVEY.md §5: consensus-critical small messages never route through the
+TPU fabric). What scales over the accelerator fabric is the *verification burden*: when a cluster's signature volume
 exceeds one host, hosts feed process-local shards of the global
 (pubkey, digest, sig) batch and the same `quorum_certify` psum produces
 globally-replicated per-round verdicts — XLA routes the all-reduce over

@@ -5,8 +5,8 @@ poll hot loop (SURVEY.md §5 — a real throughput hazard); here tracing is
 structured JSONL events behind a level check, off by default, and never
 in the per-signature hot path (batch boundaries only), and metrics are a
 Prometheus-style registry with the same one-attribute-check-when-disabled
-discipline (utils/metrics.py). Event/metric names are contracted across
-both runtimes by utils/trace_schema.py.
+discipline (utils/metrics.py). Event/metric names are contracted between
+pbftd and the Python processes by utils/trace_schema.py.
 """
 
 from .flight import FlightRecorder
@@ -14,11 +14,10 @@ from .metrics import (
     ConsensusSpans,
     MetricsRegistry,
     count_open_fds,
-    file_size_bytes,
     read_rss_bytes,
     start_metrics_server,
 )
-from .trace import Tracer, get_tracer, set_trace_file
+from .trace import Tracer
 
 __all__ = [
     "ConsensusSpans",
@@ -26,9 +25,6 @@ __all__ = [
     "MetricsRegistry",
     "Tracer",
     "count_open_fds",
-    "file_size_bytes",
-    "get_tracer",
     "read_rss_bytes",
-    "set_trace_file",
     "start_metrics_server",
 ]

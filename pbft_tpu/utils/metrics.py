@@ -1,9 +1,9 @@
-"""Metrics registry + consensus-phase spans for the replica runtimes.
+"""Metrics registry + consensus-phase spans for the Python processes.
 
 Same discipline as ``Tracer`` (trace.py): every record path is a plain
 attribute check when disabled, and the enabled fast path is lock-free for
-the single writer that owns the runtime (the asyncio loop in server.py,
-the dispatcher in service.py, the poll thread in pbftd). A concurrent
+the single writer that owns the process's hot loop (the asyncio loop in
+gateway.py, the dispatcher in service.py). A concurrent
 scrape thread reads ints/floats that are each updated atomically under
 CPython's GIL; a scrape may observe a histogram mid-update (count ahead of
 sum by one observation) — Prometheus tolerates that, a lock in the hot
@@ -53,16 +53,6 @@ def count_open_fds() -> int:
     """Open file descriptors for this process (/proc/self/fd entries)."""
     try:
         return len(os.listdir("/proc/self/fd"))
-    except OSError:
-        return 0
-
-
-def file_size_bytes(path: Optional[str]) -> int:
-    """On-disk size of ``path`` (0 when unset/absent) — the WAL gauge."""
-    if not path:
-        return 0
-    try:
-        return os.stat(path).st_size
     except OSError:
         return 0
 
@@ -121,8 +111,7 @@ class Histogram:
 class MetricsRegistry:
     """Holds one instance of each metric; renders Prometheus text format.
 
-    ``labels`` are constant labels stamped on every sample (the replica id,
-    so a mixed-runtime cluster's scrapes aggregate per replica). Metrics
+    ``labels`` are constant labels stamped on every sample. Metrics
     are looked up by manifest name; unknown names raise — drift from
     trace_schema.py must fail loudly, not mint ad-hoc series."""
 
@@ -160,15 +149,12 @@ class MetricsRegistry:
     def histogram(self, name: str) -> Histogram:
         return self._get(name, "histogram")
 
-    def preregister(self, emitter: Optional[str] = None) -> None:
-        """Create every manifest metric (zero-valued) up front — scrape
-        uniformity with the C++ registry, which registers eagerly: a mixed
-        cluster must expose the SAME series set from every replica, even
-        for events that haven't happened yet (view changes) or can't
-        happen in this runtime (the async-verifier deadline). ``emitter``
-        restricts to that source's manifest subset (the service)."""
+    def preregister(self, emitter: str) -> None:
+        """Create every manifest metric of ``emitter`` (zero-valued) up
+        front, as the C++ registry does: a scrape shows the series of events
+        that have not happened yet."""
         for name, (kind, emitters) in trace_schema.METRIC_SCHEMAS.items():
-            if emitter is None or emitter in emitters:
+            if emitter in emitters:
                 self._get(name, kind)
 
     def set_enabled(self, enabled: bool) -> None:
@@ -230,15 +216,6 @@ class ConsensusSpans:
     Bounded: at most ``max_open`` open spans; a slot that never executes
     (view abandoned, replica crashed mid-protocol) is evicted oldest-first
     rather than leaking.
-
-    Two waits ride on the same stamps (ISSUE 32). The runtime sets
-    ``batch_oldest_at`` when a request finds the primary's open batch empty;
-    the "request" transition (the seal) observes ``pbft_request_wait_seconds``
-    against it and keeps the wait in ``request_wait_s`` for the
-    ``batch_sealed`` trace event. With ``tentative`` the "executed" stamp of
-    each sequence number is kept until ``on_commit`` (Replica.commit_hook:
-    the committed floor passed it) observes
-    ``pbft_tentative_commit_lag_seconds``.
     """
 
     def __init__(
@@ -248,7 +225,6 @@ class ConsensusSpans:
         replica: int = -1,
         clock: Callable[[], float] = time.monotonic,
         max_open: int = 4096,
-        tentative: bool = False,
     ):
         self.registry = registry
         self.tracer = tracer
@@ -262,34 +238,9 @@ class ConsensusSpans:
         }
         self._e2e = registry.histogram("pbft_request_reply_seconds")
         self._executed = registry.counter("pbft_executed_total")
-        self._request_wait = registry.histogram("pbft_request_wait_seconds")
-        self.batch_oldest_at: Optional[float] = None
-        self.request_wait_s = 0.0
-        self._commit_lag = registry.histogram("pbft_tentative_commit_lag_seconds")
-        self._tentative = tentative
-        self._executed_at: Dict[int, float] = {}  # seq -> stamp, tentative mode
-
-    def on_commit(self, seq: int) -> None:
-        at = self._executed_at.pop(seq, None)
-        if at is None:
-            return
-        lag = max(0.0, self.clock() - at)
-        self._commit_lag.observe(lag)
-        if self.tracer is not None and self.tracer.enabled:
-            self.tracer.event("commit_lag", replica=self.replica, seq=seq, lag_s=round(lag, 6))
 
     def on_phase(self, phase: str, view: int, seq: int) -> None:
         now = self.clock()
-        if phase == "request":
-            oldest, self.batch_oldest_at = self.batch_oldest_at, None
-            self.request_wait_s = 0.0 if oldest is None else max(0.0, now - oldest)
-            self._request_wait.observe(self.request_wait_s)
-        elif phase == "executed" and self._tentative:
-            # Re-stamped when a rolled-back sequence number re-executes; one
-            # that state transfer carried the floor past is evicted here.
-            self._executed_at[seq] = now
-            if len(self._executed_at) > self.max_open:
-                del self._executed_at[next(iter(self._executed_at))]
         key = (view, seq)
         span = self._open.get(key)
         if span is None:
@@ -326,8 +277,8 @@ def start_metrics_server(
     """Serve ``registry`` as Prometheus text on ``/metrics`` (any path,
     really — scrapers vary) from a daemon thread. Returns the HTTPServer;
     the bound port is ``server.server_address[1]`` (useful with port=0).
-    Works for both runtimes' Python processes: the asyncio replica server
-    and the threaded verifier service — registry reads are GIL-atomic.
+    Registry reads are GIL-atomic, so the asyncio gateway and the threaded
+    verifier service are served alike.
 
     With ``status_fn``, GET /status serves its dict as JSON — the health
     document (ISSUE 16; C++ mirror: net.cc serve_metrics_ready routes

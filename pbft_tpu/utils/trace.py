@@ -1,4 +1,4 @@
-"""Structured JSONL tracing for the replica runtimes.
+"""Structured JSONL tracing for the Python processes.
 
 Events are single JSON lines: {"ts": <monotonic>, "ev": <name>, ...fields}.
 Disabled (no-op, one attribute check) unless a sink is set — tracing must
@@ -64,21 +64,3 @@ def current_span() -> Optional[dict]:
     """The record the nearest ``open_span()`` on this thread yielded, or
     None when nobody up the stack asked for one."""
     return getattr(_span, "rec", None)
-
-
-_tracer = Tracer()
-
-
-def get_tracer() -> Tracer:
-    return _tracer
-
-
-def set_trace_file(path: Optional[str]) -> Tracer:
-    """Route global tracing to a JSONL file (None disables); closes any
-    previously set sink. Raises OSError if the file cannot be opened."""
-    global _tracer
-    old_sink = _tracer.sink
-    _tracer = Tracer(open(path, "a") if path else None)
-    if old_sink is not None:
-        old_sink.close()
-    return _tracer

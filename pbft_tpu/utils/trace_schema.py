@@ -1,10 +1,11 @@
 """THE schema manifest for trace events and metrics — single source of truth.
 
-Both runtimes (pbft_tpu/net/server.py + net/service.py in Python,
-core/net.cc in C++) emit JSONL trace events and Prometheus metrics whose
-names and field sets must stay identical, or a mixed-runtime cluster's
-traces stop merging and its scrapes stop aggregating. This module is the
-contract; scripts/check_trace_schema.py lints every emitter against it
+pbftd (core/net.cc) and the Python processes round it (net/service.py,
+net/verify_service.py, net/gateway.py, net/client.py) emit JSONL trace
+events and Prometheus metrics that one set of scripts merges and one
+scrape aggregates, so their names and field sets are fixed here. This
+module is the contract; scripts/check_trace_schema.py lints every emitter
+against it
 (wired into tier-1 via tests/test_trace_schema.py), and core/metrics.cc
 mirrors the metric table (checked by the same lint).
 
@@ -69,7 +70,7 @@ EVENT_SCHEMAS = {
             "chunks", "split", "t_dev", "devices", "rows_per_chip", "fused", "ahead",
             "apply_s", "loop_us", "shard_us", "pipe_us", "handoff",
         },
-        "emitters": {"server.py", "service.py", "net.cc"},
+        "emitters": {"service.py", "net.cc"},
     },
     "verify_window_failed": {
         "required": {"ts", "ev", "replica", "size", "requests", "rejected", "secs"},
@@ -104,7 +105,7 @@ EVENT_SCHEMAS = {
     "view_change_start": {
         "required": {"ts", "ev", "replica", "pending_view", "backoff"},
         "optional": set(),
-        "emitters": {"server.py", "net.cc"},
+        "emitters": {"net.cc"},
     },
     # One span per executed (view, seq): absolute monotonic stamps for each
     # consensus phase this replica observed. "request" is primary-only (a
@@ -113,7 +114,7 @@ EVENT_SCHEMAS = {
     "consensus_span": {
         "required": {"ts", "ev", "replica", "view", "seq", "pre_prepare", "executed"},
         "optional": {"request", "prepared", "committed"},
-        "emitters": {"server.py", "net.cc"},
+        "emitters": {"net.cc"},
     },
     # The wedged-async-verifier bound: the
     # inflight launch overran its deadline, the connection was dropped and
@@ -130,7 +131,7 @@ EVENT_SCHEMAS = {
     "commit_lag": {
         "required": {"ts", "ev", "replica", "seq", "lag_s"},
         "optional": set(),
-        "emitters": {"server.py", "net.cc"},
+        "emitters": {"net.cc"},
     },
     # -- request-level latency waterfall (ISSUE 9) --------------------------
     #
@@ -142,7 +143,7 @@ EVENT_SCHEMAS = {
     "request_rx": {
         "required": {"ts", "ev", "replica", "client", "req_ts"},
         "optional": set(),
-        "emitters": {"server.py", "net.cc"},
+        "emitters": {"net.cc"},
     },
     # The primary sealed its open batch under a sequence number. wait_s is
     # how long the first request sat in the open batch (the "batch wait"
@@ -151,12 +152,12 @@ EVENT_SCHEMAS = {
     "batch_sealed": {
         "required": {"ts", "ev", "replica", "view", "seq", "batch", "wait_s"},
         "optional": {"reqs"},
-        "emitters": {"server.py", "net.cc"},
+        "emitters": {"net.cc"},
     },
     "reply_tx": {
         "required": {"ts", "ev", "replica", "client", "req_ts", "view"},
         "optional": set(),
-        "emitters": {"server.py", "net.cc"},
+        "emitters": {"net.cc"},
     },
     # -- view-change spans (ROADMAP item 4) ---------------------------------
     #
@@ -168,17 +169,17 @@ EVENT_SCHEMAS = {
     "view_timer_fired": {
         "required": {"ts", "ev", "replica", "view", "backoff"},
         "optional": set(),
-        "emitters": {"server.py", "net.cc"},
+        "emitters": {"net.cc"},
     },
     "view_change_sent": {
         "required": {"ts", "ev", "replica", "pending_view"},
         "optional": set(),
-        "emitters": {"server.py", "net.cc"},
+        "emitters": {"net.cc"},
     },
     "new_view_installed": {
         "required": {"ts", "ev", "replica", "view"},
         "optional": set(),
-        "emitters": {"server.py", "net.cc"},
+        "emitters": {"net.cc"},
     },
     # Client-side half of the waterfall (net/client.py write_trace): send /
     # first-reply / f+1-quorum monotonic stamps per (client, req_ts).
@@ -194,30 +195,29 @@ EVENT_SCHEMAS = {
 
 # -- metrics (Prometheus text format at --metrics-port) ---------------------
 #
-# name -> (type, emitters). Replica runtimes (server.py, net.cc) must emit
-# the full replica set with IDENTICAL names so a mixed-runtime cluster
-# scrapes uniformly; the verifier service emits the verify subset.
+# name -> (type, emitters). pbftd (net.cc) emits the full replica set; the
+# verifier service emits the verify subset under the SAME names, the gateway
+# its own.
 
 METRIC_SCHEMAS = {
-    "pbft_frames_in_total": ("counter", {"server.py", "net.cc"}),
-    "pbft_executed_total": ("counter", {"server.py", "net.cc"}),
-    "pbft_view_changes_total": ("counter", {"server.py", "net.cc"}),
-    "pbft_verify_batches_total": ("counter", {"server.py", "service.py", "net.cc"}),
-    "pbft_verify_items_total": ("counter", {"server.py", "service.py", "net.cc"}),
-    "pbft_verify_rejected_total": ("counter", {"server.py", "service.py", "net.cc"}),
+    "pbft_frames_in_total": ("counter", {"net.cc"}),
+    "pbft_executed_total": ("counter", {"net.cc"}),
+    "pbft_view_changes_total": ("counter", {"net.cc"}),
+    "pbft_verify_batches_total": ("counter", {"service.py", "net.cc"}),
+    "pbft_verify_items_total": ("counter", {"service.py", "net.cc"}),
+    "pbft_verify_rejected_total": ("counter", {"service.py", "net.cc"}),
     "pbft_verify_deadline_fired_total": ("counter", {"net.cc"}),
     # Batches a replica verified on the host although a verify service is
     # configured: the service was warming, unreachable, killed mid-stream
     # or (pbftd) past its verify deadline. The fallback is the liveness
     # guarantee; the count is what keeps it from hiding a dead device.
     # metrics_json mirrors it as verify_service_fallbacks.
-    "pbft_verify_service_fallbacks_total": ("counter", {"server.py", "net.cc"}),
-    "pbft_verify_queue_depth": ("gauge", {"server.py", "service.py", "net.cc"}),
-    "pbft_verify_inflight_age_seconds": ("gauge", {"server.py", "net.cc"}),
+    "pbft_verify_service_fallbacks_total": ("counter", {"net.cc"}),
+    "pbft_verify_queue_depth": ("gauge", {"service.py", "net.cc"}),
+    "pbft_verify_inflight_age_seconds": ("gauge", {"net.cc"}),
     # Native verify-pool surface (core/verify_pool.cc): pool width, windows
     # queued by the last dispatch, lifetime busy/(wall*threads) ratio, and
-    # the per-dispatch RLC window width. C++ runtime only — the Python
-    # replica's parallelism lives in the JAX mesh, not a thread pool.
+    # the per-dispatch RLC window width.
     "pbft_verify_pool_threads": ("gauge", {"net.cc"}),
     "pbft_verify_pool_queue_depth": ("gauge", {"net.cc"}),
     "pbft_verify_pool_utilization": ("gauge", {"net.cc"}),
@@ -227,24 +227,23 @@ METRIC_SCHEMAS = {
     # never per peer, so in a single-codec cluster
     # pbft_broadcast_encodes_total tracks the broadcast count instead of
     # broadcasts x peers. (The two outbound-frames-per-codec counters went
-    # in ISSUE 38: nothing read them; the Python runtime's metrics() dict
-    # keeps codec_binary_frames / codec_json_frames.)
-    "pbft_broadcast_encodes_total": ("counter", {"server.py", "net.cc"}),
+    # in ISSUE 38: nothing read them.)
+    "pbft_broadcast_encodes_total": ("counter", {"net.cc"}),
     # Batching surface (ISSUE 4): requests executed vs three-phase
     # instances executed (their ratio is the batch amplification), and
     # the per-accepted-pre-prepare batch occupancy histogram. Note
     # pbft_executed_total counts per SEQUENCE (span closes), so it tracks
     # pbft_consensus_rounds_total, not requests.
-    "pbft_requests_executed_total": ("counter", {"server.py", "net.cc"}),
-    "pbft_consensus_rounds_total": ("counter", {"server.py", "net.cc"}),
+    "pbft_requests_executed_total": ("counter", {"net.cc"}),
+    "pbft_consensus_rounds_total": ("counter", {"net.cc"}),
     # Chaos/fault-injection surface (ISSUE 5): behaviors the --fault mode
     # actually fired (corrupted signatures, equivocating pre-prepares,
     # muted sends, stutter replays) and outbound frames the seeded
     # --chaos-drop-pct link dropped. Both zero on a healthy replica — a
     # nonzero value in production is an alarm, in a chaos test it is the
     # proof the injection ran.
-    "pbft_faults_injected_total": ("counter", {"server.py", "net.cc"}),
-    "pbft_chaos_dropped_total": ("counter", {"server.py", "net.cc"}),
+    "pbft_faults_injected_total": ("counter", {"net.cc"}),
+    "pbft_chaos_dropped_total": ("counter", {"net.cc"}),
     # Persistent verify-service surface (ISSUE 7): XLA launches the
     # coalescing dispatcher actually shipped, items per launch window,
     # and how many client connections each merged window carried. The
@@ -252,8 +251,7 @@ METRIC_SCHEMAS = {
     # (cold = traced+compiled shapes, warm = serialized-executable or
     # cache reloads) so the bench can report it OUTSIDE the timed
     # region. Registered in core/metrics.cc too (eager registration:
-    # every runtime exposes the same series set, zero-valued where the
-    # lifecycle can't happen).
+    # zero-valued where the lifecycle can't happen).
     "pbft_verify_service_launches_total": (
         "counter",
         {"service.py", "net.cc"},
@@ -275,23 +273,22 @@ METRIC_SCHEMAS = {
         {"verify_service.py", "net.cc"},
     ),
     # Scale-out surface (ISSUE 10). Replica side: live sockets, event-loop
-    # readiness wakeups (epoll_wait/poll returns in C++; stream read
-    # completions in asyncio), bounded-outbound drops + partial-write
-    # backpressure episodes, and client requests received over gateway
+    # readiness wakeups (epoll_wait/poll returns), bounded-outbound drops
+    # + partial-write backpressure episodes, and client requests received over gateway
     # links. Gateway side (pbft_tpu/net/gateway.py): downstream client
     # connections open and requests forwarded upstream — the tier's
     # multiplexing ratio is gateway_clients_open vs the replicas'
     # connections_open.
-    "pbft_connections_open": ("gauge", {"server.py", "net.cc"}),
-    "pbft_epoll_wakeups_total": ("counter", {"server.py", "net.cc"}),
+    "pbft_connections_open": ("gauge", {"net.cc"}),
+    "pbft_epoll_wakeups_total": ("counter", {"net.cc"}),
     "pbft_write_backpressure_events_total": (
         "counter",
-        {"server.py", "net.cc", "gateway.py"},
+        {"net.cc", "gateway.py"},
     ),
     "pbft_gateway_clients_open": ("gauge", {"gateway.py"}),
     "pbft_gateway_forwarded_total": (
         "counter",
-        {"gateway.py", "server.py", "net.cc"},
+        {"gateway.py", "net.cc"},
     ),
     # The gateway works by the read (ISSUE 33): one write a destination a
     # loop turn, so messages a write = (forwarded + replies routed) /
@@ -303,30 +300,28 @@ METRIC_SCHEMAS = {
     # a cluster failing to converge. Overload rejections: client requests
     # answered with an explicit {"type":"overloaded"} instead of being
     # queued into the tail (admission control: per-client in-flight caps
-    # + the global backlog watermark; gateway and both replica runtimes).
+    # + the global backlog watermark; gateway and pbftd).
     # Gateway failovers: a gateway-fabric link had to be replaced — a
     # client failing over to another gateway (GatewayClient), a gateway
     # re-dialing a dead replica link (ClientGateway), or a replica losing
-    # a live gateway link (both runtimes).
-    "pbft_view_timer_backoff_level": ("gauge", {"server.py", "net.cc"}),
+    # a live gateway link (pbftd).
+    "pbft_view_timer_backoff_level": ("gauge", {"net.cc"}),
     # Multi-core surface (ISSUE 13). Loop threads: event-loop shards the
-    # replica runs (pbftd net_threads; always 1 on the single-loop
-    # asyncio runtime). Offload depth: aggregate occupancy of the
-    # per-shard crypto-pipeline queues (AEAD seal/open + codec work held
+    # replica runs (pbftd net_threads). Offload depth: aggregate
+    # occupancy of the per-shard crypto-pipeline queues (AEAD seal/open + codec work held
     # off the loop threads). Cross-thread wakes: eventfd/pipe wakes
     # crossing the loop-shard / crypto-pipeline / consensus boundaries —
-    # the handoff cost the sharding pays for its parallelism. The asyncio
-    # runtime emits the latter two as zeros for series-set parity.
-    "pbft_net_loop_threads": ("gauge", {"server.py", "net.cc"}),
-    "pbft_crypto_offload_queue_depth": ("gauge", {"server.py", "net.cc"}),
-    "pbft_cross_thread_wakes_total": ("counter", {"server.py", "net.cc"}),
+    # the handoff cost the sharding pays for its parallelism.
+    "pbft_net_loop_threads": ("gauge", {"net.cc"}),
+    "pbft_crypto_offload_queue_depth": ("gauge", {"net.cc"}),
+    "pbft_cross_thread_wakes_total": ("counter", {"net.cc"}),
     "pbft_overload_rejections_total": (
         "counter",
-        {"gateway.py", "server.py", "net.cc"},
+        {"gateway.py", "net.cc"},
     ),
     "pbft_gateway_failovers_total": (
         "counter",
-        {"gateway.py", "server.py", "net.cc"},
+        {"gateway.py", "net.cc"},
     ),
     # Fast-path surface (ISSUE 14, protocol 1.3.0). MAC frames: outbound
     # normal-case frames authenticated by a per-link session-MAC vector
@@ -336,9 +331,9 @@ METRIC_SCHEMAS = {
     # tentative sequences undone by a view change / certified-checkpoint
     # catch-up — nonzero rollbacks with zero client-visible divergence is
     # exactly the §5.3 story the chaos matrix checks.
-    "pbft_mac_frames_total": ("counter", {"server.py", "net.cc"}),
-    "pbft_tentative_executions_total": ("counter", {"server.py", "net.cc"}),
-    "pbft_tentative_rollbacks_total": ("counter", {"server.py", "net.cc"}),
+    "pbft_mac_frames_total": ("counter", {"net.cc"}),
+    "pbft_tentative_executions_total": ("counter", {"net.cc"}),
+    "pbft_tentative_rollbacks_total": ("counter", {"net.cc"}),
     # Durable-recovery surface (ISSUE 15). WAL appends: records written
     # to the write-ahead log (votes, view transitions, stable
     # checkpoints); fsyncs: group-commit fsync syscalls (one per emit
@@ -346,10 +341,10 @@ METRIC_SCHEMAS = {
     # wal_fsync off); bytes: file bytes written (appends + compactions).
     # Recovery seconds: wall time of the last WAL replay + state
     # reinstall (gauge; 0 = this life started fresh).
-    "pbft_wal_appends_total": ("counter", {"server.py", "net.cc"}),
-    "pbft_wal_fsyncs_total": ("counter", {"server.py", "net.cc"}),
-    "pbft_wal_bytes_total": ("counter", {"server.py", "net.cc"}),
-    "pbft_recovery_seconds": ("gauge", {"server.py", "net.cc"}),
+    "pbft_wal_appends_total": ("counter", {"net.cc"}),
+    "pbft_wal_fsyncs_total": ("counter", {"net.cc"}),
+    "pbft_wal_bytes_total": ("counter", {"net.cc"}),
+    "pbft_recovery_seconds": ("gauge", {"net.cc"}),
     # Health-introspection surface (ISSUE 16). Resource gauges a soak can
     # gate flat: resident set (/proc/self/statm x page size), open file
     # descriptors (/proc/self/fd entries), and the WAL file's on-disk
@@ -358,19 +353,19 @@ METRIC_SCHEMAS = {
     # scrape/refresh time) and the verify-inbox depth. All five refresh
     # lazily when the status/metrics surface is rendered — a dead-idle
     # replica pays nothing for them.
-    "pbft_process_rss_bytes": ("gauge", {"server.py", "net.cc"}),
-    "pbft_open_fds": ("gauge", {"server.py", "net.cc"}),
-    "pbft_wal_disk_bytes": ("gauge", {"server.py", "net.cc"}),
-    "pbft_last_progress_seconds": ("gauge", {"server.py", "net.cc"}),
-    "pbft_inbox_depth": ("gauge", {"server.py", "net.cc"}),
-    "pbft_batch_size": ("histogram", {"server.py", "net.cc"}),
-    "pbft_verify_batch_size": ("histogram", {"server.py", "service.py", "net.cc"}),
-    "pbft_verify_seconds": ("histogram", {"server.py", "service.py", "net.cc"}),
-    "pbft_phase_pre_prepare_seconds": ("histogram", {"server.py", "net.cc"}),
-    "pbft_phase_prepare_seconds": ("histogram", {"server.py", "net.cc"}),
-    "pbft_phase_commit_seconds": ("histogram", {"server.py", "net.cc"}),
-    "pbft_phase_reply_seconds": ("histogram", {"server.py", "net.cc"}),
-    "pbft_request_reply_seconds": ("histogram", {"server.py", "net.cc"}),
+    "pbft_process_rss_bytes": ("gauge", {"net.cc"}),
+    "pbft_open_fds": ("gauge", {"net.cc"}),
+    "pbft_wal_disk_bytes": ("gauge", {"net.cc"}),
+    "pbft_last_progress_seconds": ("gauge", {"net.cc"}),
+    "pbft_inbox_depth": ("gauge", {"net.cc"}),
+    "pbft_batch_size": ("histogram", {"net.cc"}),
+    "pbft_verify_batch_size": ("histogram", {"service.py", "net.cc"}),
+    "pbft_verify_seconds": ("histogram", {"service.py", "net.cc"}),
+    "pbft_phase_pre_prepare_seconds": ("histogram", {"net.cc"}),
+    "pbft_phase_prepare_seconds": ("histogram", {"net.cc"}),
+    "pbft_phase_commit_seconds": ("histogram", {"net.cc"}),
+    "pbft_phase_reply_seconds": ("histogram", {"net.cc"}),
+    "pbft_request_reply_seconds": ("histogram", {"net.cc"}),
     # One verify trip, the replica's share (pbftd only). Inbox wait: observed
     # once per verify batch at launch, launch time minus the arrival of the
     # oldest item no earlier launch took (an item that arrives while a batch
@@ -479,14 +474,15 @@ METRIC_SCHEMAS = {
     # Inline verifies: signature checks done on the host in the normal
     # case, i.e. a checkpoint's embedded signature in MAC mode (the view
     # change's proofs are not counted); /status: inline_verifies.
-    "pbft_request_wait_seconds": ("histogram", {"server.py", "net.cc"}),
-    "pbft_seal_refused_total": ("counter", {"server.py", "net.cc"}),
-    "pbft_tentative_commit_lag_seconds": ("histogram", {"server.py", "net.cc"}),
-    "pbft_inline_verifies_total": ("counter", {"server.py", "net.cc"}),
+    "pbft_request_wait_seconds": ("histogram", {"net.cc"}),
+    "pbft_seal_refused_total": ("counter", {"net.cc"}),
+    "pbft_tentative_commit_lag_seconds": ("histogram", {"net.cc"}),
+    "pbft_inline_verifies_total": ("counter", {"net.cc"}),
 }
 
 # Fixed histogram bucket upper edges (le semantics: v <= edge). Shared by
-# both runtimes — core/metrics.cc mirrors these values; the lint compares.
+# pbftd and the Python processes — core/metrics.cc mirrors these values; the
+# lint compares.
 LATENCY_BUCKETS_S = (
     0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025,
     0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0,
@@ -507,7 +503,7 @@ PHASES = ("request", "pre_prepare", "prepared", "committed", "executed")
 
 # -- black-box flight recorder (ISSUE 9) -------------------------------------
 #
-# Both runtimes keep a fixed-size ring of compact binary records
+# pbftd and the gateway keep a fixed-size ring of compact binary records
 # (core/flight.{h,cc} lock-free atomics; pbft_tpu/utils/flight.py a
 # bounded deque) dumped to a file on SIGTERM/fatal/invariant-failure and
 # decoded by scripts/flight_dump.py. The on-disk format is shared:
@@ -515,7 +511,7 @@ PHASES = ("request", "pre_prepare", "prepared", "committed", "executed")
 #   header  FLIGHT_MAGIC (8B) + u32le version + u32le record count
 #   record  u64le t_ns, u16le event id, i16le peer, i32le view, i32le seq
 #
-# Event ids are the cross-runtime contract below; core/flight.h mirrors
+# Event ids are the contract below; core/flight.h mirrors
 # them (enum FlightEvent). The "request" consensus phase records as
 # batch_sealed (the primary's sequence assignment IS the seal).
 FLIGHT_MAGIC = b"PBFTBBX1"
@@ -606,14 +602,14 @@ VERIFYD_PER_SHAPE_KEYS = {
 
 # -- health document (ISSUE 16) ----------------------------------------------
 #
-# Both runtimes extend their metrics_json/metrics() status surface into a
+# pbftd's metrics_json (and the gateway's /status) is a
 # versioned health document: resource readings (rss_bytes, open_fds,
 # wal_disk_bytes), progress watermarks (inbox_depth, sealed_unexecuted,
 # waiting_requests, last_progress_seconds, uptime_seconds) and identity
 # digests (chain_digest, state_digest) alongside the existing counters.
 # health_version stamps the document shape so pbft_top and the detector
 # library (pbft_tpu/analysis/health.py) can refuse snapshots from a
-# runtime speaking a different schema. core/net.h mirrors the value
+# process speaking a different schema. core/net.h mirrors the value
 # (kHealthDocVersion — constants lint pair "health document version").
 HEALTH_DOC_VERSION = 1
 

@@ -545,7 +545,7 @@ class HealthSampler(threading.Thread):
 
 
 def run_arm_traced(
-    arm, n, clients, requests_each, window, batch, batch_flush_us, impl,
+    arm, n, clients, requests_each, window, batch, batch_flush_us,
     gateways, vc_timeout_ms, admission_inflight, admission_backlog,
     fault_at_s, heal_at_s, deadline_s, seed, blackbox_dir, mode="sig",
     health_gate=False,
@@ -583,7 +583,6 @@ def run_arm_traced(
             n=n,
             verifier="cpu",
             metrics_every=1,
-            impl=impl,
             vc_timeout_ms=vc_timeout_ms,
             batch_max_items=batch,
             batch_flush_us=batch_flush_us,
@@ -707,7 +706,7 @@ def run_arm_traced(
                     "batch_flush_us": batch_flush_us,
                     "window": window,
                     "gateways": n_gw,
-                    "verifier": f"gateway-{impl}",
+                    "verifier": "gateway-cxx",
                     "completed_pct": round(
                         100.0 * done / max(1, clients * requests_each), 1
                     ),
@@ -777,7 +776,6 @@ def main() -> int:
     parser.add_argument("--window", type=int, default=8)
     parser.add_argument("--batch", type=int, default=32)
     parser.add_argument("--batch-flush-us", type=int, default=2000)
-    parser.add_argument("--impl", default="cxx", choices=("cxx", "py"))
     parser.add_argument("--gateways", type=int, default=1,
                         help="gateway tier width (gateway-kill raises to "
                         ">= 2 so a survivor exists)")
@@ -816,7 +814,7 @@ def main() -> int:
         for mode in modes:
             row = run_arm_traced(
                 arm, args.n, args.clients, args.requests, args.window,
-                args.batch, args.batch_flush_us, args.impl, args.gateways,
+                args.batch, args.batch_flush_us, args.gateways,
                 args.vc_timeout_ms, args.admission_inflight,
                 args.admission_backlog, args.fault_at_s, args.heal_at_s,
                 args.deadline_s, args.seed, args.blackbox_dir, mode=mode,

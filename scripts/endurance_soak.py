@@ -110,8 +110,6 @@ def main(argv=None) -> int:
     parser.add_argument("--requests-each", type=int, default=200,
                         help="requests per client per load round")
     parser.add_argument("--window", type=int, default=8)
-    parser.add_argument("--impl", default="cxx",
-                        help='"cxx", "py", or comma list per replica')
     parser.add_argument("--seed", type=int, default=16)
     parser.add_argument("--no-wal", action="store_true")
     parser.add_argument("--no-gate", action="store_true",
@@ -119,13 +117,12 @@ def main(argv=None) -> int:
     parser.add_argument("--out", default=None, help="append JSONL row here")
     args = parser.parse_args(argv)
 
-    impl = args.impl.split(",") if "," in args.impl else args.impl
     f = (args.n - 1) // 3
     history: list = []
     t_start = time.monotonic()
 
     with LocalCluster(
-        n=args.n, impl=impl, wal=not args.no_wal, metrics_ports=True,
+        n=args.n, wal=not args.no_wal, metrics_ports=True,
         batch_max_items=32, batch_flush_us=2000,
     ) as cluster:
         tmp = pathlib.Path(cluster.tmpdir.name)

@@ -1,9 +1,9 @@
 #!/usr/bin/env python
 """flight_dump — decode black-box flight-recorder dumps.
 
-Both runtimes keep a fixed-size ring of the last N protocol events
-(core/flight.cc in pbftd, pbft_tpu/utils/flight.py in the asyncio
-runtime and the chaos-soak simulator) and dump it on SIGTERM/fatal/
+pbftd, the gateway and the chaos-soak simulator keep a fixed-size ring of
+the last N protocol events (core/flight.cc in pbftd,
+pbft_tpu/utils/flight.py in Python) and dump it on SIGTERM/fatal/
 invariant-failure. This tool turns a dump back into ordered, named
 protocol events — what the dead replica was doing in its final moments.
 

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""pbft_lint — run every static-analysis pass over both runtimes.
+"""pbft_lint — run every static-analysis pass over core/ and pbft_tpu/.
 
 One entry point for the conformance-and-lint layer (ISSUE 8,
 pbft_tpu/analysis/): cross-runtime constant conformance, the

@@ -2,7 +2,7 @@
 """pbft_top — live cluster health console + anomaly gate (ISSUE 16).
 
 Polls every replica's /status endpoint (the versioned health document
-both runtimes serve next to /metrics; optionally a gateway's too) on an
+pbftd serves next to /metrics; optionally a gateway's too) on an
 interval, renders a one-screen view — view/seq/floor, req/s, the net
 loop's busy share, RSS, fds, WAL size, backoff level per replica — and
 continuously runs the
@@ -84,7 +84,7 @@ def loop_busy(history, rid, span_snapshots=5):
     """The share of the last few snapshots' span that the replica's net
     loop spent OUTSIDE its poller's wait: 1 - (wait gained) / (all seven
     stages gained), from /status ``loop_us`` (pbftd; ISSUE 38). None where
-    a runtime has no loop clock (the asyncio replica) or the span is one
+    a document has no loop clock (a gateway's) or the span is one
     snapshot. A loop near 1.0 is a stage standing at a full core."""
     series = [
         s["replicas"][rid]["loop_us"]

@@ -229,7 +229,6 @@ def run_point(
     window: int,
     batch: int,
     batch_flush_us: int,
-    impl: str,
     gateways: int,
     deadline_s: float,
     net_threads: int = 1,
@@ -252,7 +251,6 @@ def run_point(
         n=n,
         verifier="cpu",
         metrics_every=1,
-        impl=impl,
         batch_max_items=batch,
         batch_flush_us=batch_flush_us,
         net_threads=net_threads,
@@ -361,7 +359,7 @@ def run_point(
         "window": window,
         "net_threads": net_threads,
         "gateways": len(gws),
-        "verifier": f"gateway-{impl}",
+        "verifier": "gateway-cxx",
         "completed_pct": round(
             100.0 * total / max(1, clients * requests_each), 1
         ),
@@ -386,8 +384,6 @@ def main() -> int:
     parser.add_argument("--batch", type=int, default=256,
                         help="batch_max_items (BASELINE's 256-req windows)")
     parser.add_argument("--batch-flush-us", type=int, default=2000)
-    parser.add_argument("--impl", default="cxx", choices=("cxx", "py"),
-                        help="replica runtime (default the C++ daemon)")
     parser.add_argument("--gateways", type=int, default=1)
     parser.add_argument(
         "--net-threads", type=int, default=1,
@@ -423,7 +419,7 @@ def main() -> int:
         for mode in modes:
             row = run_point(
                 n, args.clients, args.requests, args.window, args.batch,
-                args.batch_flush_us, args.impl, args.gateways,
+                args.batch_flush_us, args.gateways,
                 args.deadline_s, net_threads=args.net_threads, mode=mode,
                 wal=args.wal,
             )

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Summarize pbft_tpu JSONL traces (pbftd --trace / server.py --trace).
+"""Summarize pbft_tpu JSONL traces (pbftd --trace, verifyd --trace).
 
 Reads one or more per-replica trace files and prints, per replica and
 cluster-wide: verify-batch count/size/time percentiles, batching-window
@@ -328,8 +328,8 @@ def report(files) -> dict:
         failed = [e for e in events if e.get("ev") == "verify_window_failed"]
         if failed:
             print(f"{path.name}: {len(failed)} FAILED merged windows")
-        # Both runtimes emit "view_change_start" (core/net.cc
-        # trace_view_change, server.py _timer_loop).
+        # pbftd emits "view_change_start" (core/net.cc
+        # trace_view_change).
         vcs = [e for e in events if e.get("ev") == "view_change_start"]
         spans = [e for e in events if e.get("ev") == "consensus_span"]
         deadline_fired = [

@@ -52,7 +52,7 @@ def test_completion_bars_cover_every_arm():
 def _run(arm, **kw):
     args = dict(
         n=4, clients=4, requests_each=15, window=8, batch=32,
-        batch_flush_us=2000, impl="cxx", gateways=1, vc_timeout_ms=500,
+        batch_flush_us=2000, gateways=1, vc_timeout_ms=500,
         admission_inflight=0, admission_backlog=0, fault_at_s=0.5,
         heal_at_s=1.5, deadline_s=150.0, seed=7, blackbox_dir=None,
     )
@@ -60,7 +60,7 @@ def _run(arm, **kw):
     return chaos_bench.run_arm_traced(
         arm, args["n"], args["clients"], args["requests_each"],
         args["window"], args["batch"], args["batch_flush_us"],
-        args["impl"], args["gateways"], args["vc_timeout_ms"],
+        args["gateways"], args["vc_timeout_ms"],
         args["admission_inflight"], args["admission_backlog"],
         args["fault_at_s"], args["heal_at_s"], args["deadline_s"],
         args["seed"], args["blackbox_dir"],

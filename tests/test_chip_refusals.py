@@ -156,18 +156,17 @@ def test_a_failed_build_shows_the_compilers_words(monkeypatch, tmp_path):
 # -- one process per chip ------------------------------------------------------
 
 
-def test_launcher_refuses_several_inprocess_jax_replicas_off_the_cpu_arm(
-    monkeypatch,
-):
+def test_launcher_has_no_in_process_way_onto_the_chip():
+    """A replica is pbftd, which never touches JAX: the one way onto the
+    chip is verifyd's address. ``verifier="jax"`` (the in-process arm of the
+    Python replica that is gone) is refused with a pointer at verifyd,
+    whatever JAX_PLATFORMS says."""
     from pbft_tpu.net import LocalCluster
 
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    with pytest.raises(ValueError, match="each claim the accelerator"):
-        LocalCluster(n=4, verifier="jax", impl="py")
-    LocalCluster(n=4, verifier="jax", impl=["py", "cxx", "cxx", "cxx"])  # one: fine
-    LocalCluster(n=4, verifier="127.0.0.1:7600", impl="py")  # verifyd: fine
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    LocalCluster(n=4, verifier="jax", impl="py")  # the CPU test arm
+    with pytest.raises(ValueError, match="verifyd"):
+        LocalCluster(n=4, verifier="jax")
+    LocalCluster(n=4, verifier="127.0.0.1:7600")  # verifyd: fine
+    LocalCluster(n=4, verifier="cpu")  # the native pool: fine
 
 
 # -- no switch chooses a multiply path ------------------------------------------

@@ -173,8 +173,8 @@ def _assert_protocol_order(records):
 
 
 @pytest.mark.skipif(not native.available(), reason="native core not built")
-@pytest.mark.parametrize("impl", ["cxx", "py"])
-def test_killed_replica_ships_black_box(impl, tmp_path):
+@pytest.mark.parametrize("net_threads", [1, 2])
+def test_killed_replica_ships_black_box(net_threads, tmp_path):
     """Kill a replica mid-run (SIGTERM, the chaos-soak kill path): its
     flight dump exists, decodes, and shows ordered protocol events —
     request_rx through executed — from the dead process."""
@@ -182,7 +182,7 @@ def test_killed_replica_ships_black_box(impl, tmp_path):
 
     flight_dir = tmp_path / "flight"
     with LocalCluster(
-        n=4, verifier="cpu", impl=impl, flight_dir=str(flight_dir)
+        n=4, verifier="cpu", net_threads=net_threads, flight_dir=str(flight_dir)
     ) as cluster:
         client = PbftClient(cluster.config)
         try:

@@ -169,23 +169,23 @@ def test_gateway_pipelined_many_and_replica_counters():
             _stop(proc)
 
 
-def test_gateway_mixed_runtime_trust():
-    """The asyncio replica honors role=gateway links the same way the C++
-    daemon does: a mixed cluster serves a gateway client with replies
-    fanning back from BOTH runtimes."""
+@pytest.mark.parametrize("net_threads", [1, 2])
+def test_gateway_link_trusted_by_every_replica(net_threads):
+    """Every replica honors role=gateway links, on both socket layers: the
+    cluster serves a gateway client with replies fanning back from the
+    primary's side and the backups' alike."""
     with LocalCluster(
-        n=4, verifier="cpu", metrics_every=1,
-        impl=["cxx", "py", "cxx", "py"],
+        n=4, verifier="cpu", metrics_every=1, net_threads=net_threads,
     ) as cluster:
         proc, addr = _start_gateway(cluster)
         try:
             client = GatewayClient(cluster.config, addr)
             req = client.request("mixed-gw")
             assert client.wait_result(req.timestamp, timeout=40)
-            # Replies crossed back from at least one replica of EACH
-            # runtime (0/2 are cxx, 1/3 are py). The quorum may be met by
-            # the fastest f+1, so poll briefly for the slower runtime's
-            # fan-back instead of asserting on the first snapshot.
+            # Replies crossed back from at least one replica of each pair
+            # (0/2 and 1/3). The quorum may be met by the fastest f+1, so
+            # poll briefly for the slower ones' fan-back instead of
+            # asserting on the first snapshot.
             deadline = time.monotonic() + 10
             while True:
                 with client._lock:
@@ -399,16 +399,16 @@ def test_gateway_admission_rejects_past_inflight_cap():
             _stop(proc)
 
 
-@pytest.mark.parametrize("impl", ["cxx", "py"])
-def test_replica_admission_inflight_cap_and_recovery(impl):
-    """Admission control at the REPLICA (both runtimes, ISSUE 12): with
+@pytest.mark.parametrize("net_threads", [1, 2])
+def test_replica_admission_inflight_cap_and_recovery(net_threads):
+    """Admission control at the REPLICA (both socket layers, ISSUE 12): with
     admission_inflight=3 in network.json and a long batch-flush window, a
     burst of 10 fresh requests gets explicit overloaded replies past the
     cap — and the rejected requests still complete once the client
     retries after the backlog drains (liveness is never admission-gated,
     retransmissions always pass)."""
     with LocalCluster(
-        n=4, verifier="cpu", metrics_every=1, impl=impl,
+        n=4, verifier="cpu", metrics_every=1, net_threads=net_threads,
         batch_max_items=64, batch_flush_us=500000, admission_inflight=3,
     ) as cluster:
         proc, addr = _start_gateway(cluster)

@@ -6,7 +6,7 @@ Two halves:
    synthetic bad history (a silent stall, an fd ramp, a forked chain
    digest, a wedged view change, a saturated inbox) and stay QUIET on a
    healthy one. The synthetic histories are built from the same
-   health-document shape both runtimes serve on /status.
+   health-document shape pbftd serves on /status.
 2. Live smoke: ``pbft_top --gate --once`` against a real LocalCluster —
    exit 0 on a healthy loaded cluster, exit 1 with a machine-readable
    silent-stall verdict when the primary is muted and holds sealed work
@@ -313,7 +313,7 @@ def test_pbft_top_gate_passes_healthy_cluster():
     from pbft_tpu.net.client import PbftClient
     from pbft_tpu.net.launcher import LocalCluster
 
-    with LocalCluster(n=4, impl="cxx", metrics_ports=True) as c:
+    with LocalCluster(n=4, metrics_ports=True) as c:
         cl = PbftClient(c.config)
         req = cl.request("health-smoke")
         assert cl.wait_result(req.timestamp, timeout=30) is not None
@@ -332,7 +332,7 @@ def test_pbft_top_gate_catches_muted_primary_stall():
     from pbft_tpu.net.client import PbftClient
     from pbft_tpu.net.launcher import LocalCluster
 
-    with LocalCluster(n=4, impl="cxx", metrics_ports=True,
+    with LocalCluster(n=4, metrics_ports=True,
                       faults={0: "mute"}) as c:
         cl = PbftClient(c.config)
         cl.request("doomed", to_replica=0)  # sealed by 0, never executed

@@ -106,8 +106,8 @@ def test_divergent_protocol_version_trips(tmp_path):
 
 def test_divergent_mac_constants_trip(tmp_path):
     """ISSUE 14 pairs: a drifted MAC tag length, domain label, or frame
-    code each fails the build — one byte of drift and a mixed-runtime
-    mac link rejects every frame."""
+    code each fails the build — one byte of drift and the
+    reference's MAC vectors stop matching pbftd's."""
     root = _shadow_tree(tmp_path)
     sec = root / "pbft_tpu" / "net" / "secure.py"
     text = sec.read_text()
@@ -167,9 +167,9 @@ def test_divergent_config_default_trips(tmp_path):
 def test_divergent_wal_constants_trip(tmp_path):
     """ISSUE 15 pairs: a drifted WAL magic, record tag, or wal_fsync
     config default each fails the build — the on-disk format is the
-    cross-runtime recovery contract (a pbftd-written log must replay in
-    the Python tooling byte-for-byte, and a sparse network.json must
-    mean fsync-on in both runtimes)."""
+    recovery contract between pbftd and the reference (a pbftd-written
+    log must replay in the Python tooling byte-for-byte, and a sparse
+    network.json must mean fsync-on to both)."""
     root = _shadow_tree(tmp_path)
     w = root / "pbft_tpu" / "consensus" / "wal.py"
     text = w.read_text()
@@ -263,13 +263,15 @@ def test_unregistered_metric_trips(tmp_path):
 
 def test_unregistered_metric_in_emitter_trips(tmp_path):
     root = _shadow_tree(tmp_path)
-    server = root / "pbft_tpu" / "net" / "server.py"
-    text = server.read_text()
-    anchor = '"pbft_frames_in_total"'
+    gateway = root / "pbft_tpu" / "net" / "gateway.py"
+    text = gateway.read_text()
+    anchor = '"pbft_gateway_writes_total"'
     assert anchor in text
-    server.write_text(text.replace(anchor, '"pbft_frames_in_renamed_total"', 1))
+    gateway.write_text(text.replace(anchor, '"pbft_gateway_writes_renamed_total"', 1))
     errors = metrics_lint.check(root)
-    assert any("pbft_frames_in_renamed_total" in e for e in errors), errors
+    assert any("pbft_gateway_writes_renamed_total" in e for e in errors), errors
+    # The manifest's own name is then recorded by nobody: the other half.
+    assert any("'pbft_gateway_writes_total' is never recorded" in e for e in errors), errors
 
 
 def test_wrong_metric_kind_trips(tmp_path):

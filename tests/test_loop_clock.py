@@ -100,7 +100,7 @@ def test_the_stage_clock_accounts_for_a_served_replicas_whole_loop(mode):
     daemon = VerifyServiceDaemon(backend="native").start()
     try:
         with LocalCluster(
-            n=4, verifier=daemon.address, impl="cxx", metrics_ports=True, wal=True, **more
+            n=4, verifier=daemon.address, metrics_ports=True, wal=True, **more
         ) as cluster:
             _serve(cluster, 1, 2, "links-up")  # every link's lanes exist after this
             _settled(cluster)

@@ -60,8 +60,9 @@ def _send_keys(links: dict, derive) -> dict:
 
 
 def _accepted_by_the_three(frame: bytes, rid: int, key: bytes, digest: bytes) -> list:
-    """The receiver's check as the reference, the asyncio runtime
-    (``server._ingest_mac``'s two calls) and the native library make it."""
+    """The receiver's check as the reference, the Python codec and handshake
+    (``messages.mac_frame_lane`` against ``secure.mac_tag``) and the native
+    library make it."""
     py_lane = M.mac_frame_lane(frame, rid)
     cc_lane = native.mac_frame_lane(frame, rid)
     return [

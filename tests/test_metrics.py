@@ -1,15 +1,15 @@
 """The observability layer (ISSUE 1): registry semantics, consensus-phase
-span lifecycle, the /metrics scrape surface on both Python runtimes, the
+span lifecycle, the /metrics scrape surface of pbftd and the verify service, the
 cross-replica timeline analyzer against the checked-in r5 fixtures, and
 the Tracer hot-loop hardening."""
 
-import asyncio
 import io
 import json
 import pathlib
 import socket
 import subprocess
 import sys
+import time
 import urllib.request
 
 import pytest
@@ -163,67 +163,40 @@ def _scrape(port: int) -> str:
         return resp.read().decode()
 
 
-def test_async_cluster_metrics_endpoint_end_to_end():
-    """A 4-replica in-process asyncio cluster with --metrics-port semantics:
-    one committed client request must surface per-phase latency histograms
-    and verify counters on the scrape endpoint, with manifest names."""
-    from pbft_tpu.net.launcher import free_ports
-    from pbft_tpu.net.server import AsyncReplicaServer
+def test_cluster_metrics_endpoint_end_to_end():
+    """A 4-replica pbftd cluster with --metrics-port: one committed client
+    request must surface per-phase latency histograms and verify counters
+    on the scrape endpoint, with manifest names."""
+    from pbft_tpu.net import LocalCluster, PbftClient
 
-    async def scenario():
-        config, seeds = make_local_cluster(4, base_port=0)
-        ports = free_ports(4)
-        config = ClusterConfig(
-            replicas=[
-                type(r)(r.replica_id, r.host, ports[i], r.pubkey)
-                for i, r in enumerate(config.replicas)
-            ]
-        )
-        servers = []
-        for i in range(4):
-            servers.append(
-                await AsyncReplicaServer(
-                    config, i, seeds[i], metrics_port=0
-                ).start()
-            )
+    with LocalCluster(n=4, verifier="cpu", metrics_ports=True) as cluster:
+        client = PbftClient(cluster.config)
         try:
-            req = {
-                "type": "client-request",
-                "operation": "observe me",
-                "timestamp": 1,
-                "client": "127.0.0.1:1",  # dial-back dropped; irrelevant
-            }
-            _, w = await asyncio.open_connection("127.0.0.1", ports[0])
-            w.write(json.dumps(req).encode() + b"\n")
-            await w.drain()
-            w.close()
-            for _ in range(200):
-                if all(s.replica.executed_upto >= 1 for s in servers):
-                    break
-                await asyncio.sleep(0.05)
-            assert all(s.replica.executed_upto >= 1 for s in servers)
-            loop = asyncio.get_running_loop()
-            texts = [
-                await loop.run_in_executor(
-                    None, _scrape, s.metrics_listen_port
-                )
-                for s in servers
-            ]
+            req = client.request("observe me")
+            assert client.wait_result(req.timestamp, timeout=20) == "awesome!"
         finally:
-            for s in servers:
-                await s.stop()
-        for i, text in enumerate(texts):
-            label = '{replica="%d"}' % i
-            assert f"pbft_request_reply_seconds_count{label} 1" in text
-            assert f"pbft_phase_prepare_seconds_count{label} 1" in text
-            assert f"pbft_phase_commit_seconds_count{label} 1" in text
-            assert "# TYPE pbft_verify_batches_total counter" in text
-            assert f"pbft_executed_total{label} 1" in text
-        # The request stamp exists only on the primary.
-        assert 'pbft_phase_pre_prepare_seconds_count{replica="0"} 1' in texts[0]
-        assert 'pbft_phase_pre_prepare_seconds_count{replica="1"} 0' in texts[1]
-
-    asyncio.run(scenario())
+            client.close()
+        # The client's quorum is f+1 replies: wait for the slowest replica.
+        deadline = time.monotonic() + 10
+        while True:
+            texts = [_scrape(port) for port in cluster.metrics_ports]
+            if all(
+                'pbft_executed_total{replica="%d"} 1' % i in text
+                for i, text in enumerate(texts)
+            ):
+                break
+            assert time.monotonic() < deadline, texts
+            time.sleep(0.1)
+    for i, text in enumerate(texts):
+        label = '{replica="%d"}' % i
+        assert f"pbft_request_reply_seconds_count{label} 1" in text
+        assert f"pbft_phase_prepare_seconds_count{label} 1" in text
+        assert f"pbft_phase_commit_seconds_count{label} 1" in text
+        assert "# TYPE pbft_verify_batches_total counter" in text
+        assert f"pbft_executed_total{label} 1" in text
+    # The request stamp exists only on the primary.
+    assert 'pbft_phase_pre_prepare_seconds_count{replica="0"} 1' in texts[0]
+    assert 'pbft_phase_pre_prepare_seconds_count{replica="1"} 0' in texts[1]
 
 
 def test_verifier_service_metrics_endpoint():

@@ -47,7 +47,7 @@ def test_scale_curve_n4_smoke(tmp_path):
 
     row = scale_curve.run_point(
         n=4, clients=4, requests_each=5, window=4, batch=16,
-        batch_flush_us=2000, impl="cxx", gateways=1, deadline_s=240,
+        batch_flush_us=2000, gateways=1, deadline_s=240,
     )
     _check_row(row, 4)
     assert row["mean_batch"] >= 1.0
@@ -105,7 +105,7 @@ def test_scale_curve_f5_f10_sustained(tmp_path):
     for n, clients, reqs in ((16, 8, 8), (31, 8, 4)):
         row = scale_curve.run_point(
             n=n, clients=clients, requests_each=reqs, window=4, batch=256,
-            batch_flush_us=4000, impl="cxx", gateways=1, deadline_s=900,
+            batch_flush_us=4000, gateways=1, deadline_s=900,
         )
         _check_row(row, n)
         rows.append(row)

@@ -63,7 +63,7 @@ def test_every_front_end_threads_stages_sum_to_its_elapsed_time_and_a_drain_obse
     from pbft_tpu.net import LocalCluster
 
     threads = 2
-    with LocalCluster(n=4, impl="cxx", net_threads=threads, metrics_ports=True, wal=True,
+    with LocalCluster(n=4, net_threads=threads, metrics_ports=True, wal=True,
                       trace_dir=str(tmp_path)) as cluster:
         _serve_in_order(cluster, 1, 2, "links-up")
         _settled(cluster)
@@ -195,7 +195,7 @@ def test_a_reader_of_the_shard_tier_names_what_exists_and_reads_the_hand_made_ru
         kind, emitters = trace_schema.METRIC_SCHEMAS[s]
         assert kind == ("histogram" if s == args.get("histogram") else "counter")
         # What ISSUE 40 added is pbftd's alone; the wakes' counter is older.
-        assert emitters == ({"server.py", "net.cc"} if s == "pbft_cross_thread_wakes_total"
+        assert emitters == ({"net.cc"} if s == "pbft_cross_thread_wakes_total"
                             else {"net.cc", "net_shard.cc"})
     want = {
         "net_threads_seen": 2.0, "shard_busy_share": 0.3, "pipe_busy_share": 0.15,

@@ -1,7 +1,6 @@
 """Tier-1 wiring for the schema lint (scripts/check_trace_schema.py): the
-Python and C++ runtimes cannot drift from the event/metric manifest
-(pbft_tpu/utils/trace_schema.py) without failing here — the mixed-runtime
-schema-parity contract."""
+Python emitters and pbftd cannot drift from the event/metric manifest
+(pbft_tpu/utils/trace_schema.py) without failing here."""
 
 import importlib.util
 import pathlib

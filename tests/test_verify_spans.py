@@ -998,7 +998,7 @@ def test_replica_histograms_on_the_async_branch():
     daemon = VerifyServiceDaemon(backend="native").start()
     try:
         with LocalCluster(
-            n=4, verifier=daemon.address, impl="cxx", metrics_ports=True, wal=True
+            n=4, verifier=daemon.address, metrics_ports=True, wal=True
         ) as cluster:
             client = PbftClient(cluster.config)
             try:
@@ -1041,7 +1041,7 @@ def test_a_served_cluster_launches_ahead_of_kept_verdicts(tmp_path, capsys):
     daemon = VerifyServiceDaemon(backend="native").start()
     try:
         with LocalCluster(
-            n=4, verifier=daemon.address, impl="cxx", metrics_ports=True, wal=True,
+            n=4, verifier=daemon.address, metrics_ports=True, wal=True,
             trace_dir=str(tmp_path),
         ) as cluster:
 

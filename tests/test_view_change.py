@@ -417,65 +417,13 @@ def test_view_event_ordering_violations_flagged():
     assert check_view_events(installed_before_sent)
 
 
-# -- view-timer backoff + retransmission (ISSUE 12) ---------------------------
-
-
-def test_view_timer_backoff_policy_escalates_and_caps():
-    """§4.5.2 exponential backoff, as the runtimes run it (server.py
-    ViewTimerBackoff; core/net.cc mirrors the state machine): arm at
-    T x level, double per consecutive no-progress expiry, cap at 64."""
-    from pbft_tpu.net.server import ViewTimerBackoff
-
-    p = ViewTimerBackoff(1.0)
-    assert p.poll(0.0, 0, 0, False) == "armed"
-    assert p.deadline == 1.0
-    assert p.poll(0.5, 0, 0, False) == "idle"
-    assert p.poll(1.1, 0, 0, False) == "escalate"
-    assert p.level == 2
-    assert p.poll(1.2, 0, 0, False) == "armed"
-    assert p.deadline == 1.2 + 2.0  # T x level
-    now = 1.2
-    for _ in range(10):  # drive to the cap
-        now = p.deadline + 0.1
-        assert p.poll(now, 0, 0, False) == "escalate"
-        assert p.poll(now, 0, 0, False) == "armed"
-    assert p.level == ViewTimerBackoff.MAX_LEVEL == 64
-    p.clear()
-    assert p.level == 1 and p.deadline is None
-
-
-def test_view_timer_backoff_resets_on_progress():
-    from pbft_tpu.net.server import ViewTimerBackoff
-
-    p = ViewTimerBackoff(1.0)
-    assert p.poll(0.0, 5, 2, False) == "armed"
-    assert p.poll(2.0, 6, 2, False) == "progress"  # executed advanced
-    assert p.level == 1
-    assert p.poll(2.1, 6, 2, False) == "armed"
-    assert p.poll(3.5, 6, 3, False) == "progress"  # view advanced
-    assert p.level == 1
-
-
-def test_view_timer_backoff_retransmits_before_escalating():
-    """Mid-view-change, the FIRST no-progress expiry retransmits the
-    pending VIEW-CHANGE (same view, lost-frame recovery); only the next
-    one escalates and doubles — repeated timer fires must not burn a
-    view number each (ISSUE 12)."""
-    from pbft_tpu.net.server import ViewTimerBackoff
-
-    p = ViewTimerBackoff(1.0)
-    assert p.poll(0.0, 0, 0, True) == "armed"
-    assert p.poll(1.1, 0, 0, True) == "retransmit"
-    assert p.level == 1  # retransmission never doubles
-    assert p.poll(1.2, 0, 0, True) == "armed"
-    assert p.poll(2.3, 0, 0, True) == "escalate"
-    assert p.level == 2
-    # After escalation the cycle repeats: retransmit, then escalate.
-    assert p.poll(2.4, 0, 0, True) == "armed"
-    assert p.poll(4.5, 0, 0, True) == "retransmit"
-    assert p.poll(4.6, 0, 0, True) == "armed"
-    assert p.poll(6.7, 0, 0, True) == "escalate"
-    assert p.level == 4
+# -- view-change retransmission (ISSUE 12) ------------------------------------
+#
+# pbftd's timer policy (arm at T x level, retransmit before escalating, double
+# a no-progress expiry, cap at 64: core/net.cc check_progress_timer) is held on
+# real clusters by tests/test_integration.py
+# test_mute_primary_bounded_view_change_storm and
+# test_view_change_fires_under_accumulation_window.
 
 
 def _direct_replicas(n=4):

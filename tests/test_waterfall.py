@@ -77,8 +77,9 @@ def test_build_waterfall_partial_evidence_degrades_gracefully():
 
 
 @pytest.mark.skipif(not native.available(), reason="native core not built")
-def test_waterfall_from_real_cluster_traces(tmp_path):
-    """Drive a batching mixed-runtime cluster with traces on, write the
+@pytest.mark.parametrize("net_threads", [1, 2])
+def test_waterfall_from_real_cluster_traces(tmp_path, net_threads):
+    """Drive a batching cluster (either socket layer) with traces on, write the
     client trace next to the replica traces, and require
     consensus_timeline --waterfall to join them: every segment populated,
     requests joined, mean batch surfaced."""
@@ -89,7 +90,7 @@ def test_waterfall_from_real_cluster_traces(tmp_path):
     with LocalCluster(
         n=4,
         verifier="cpu",
-        impl=["cxx", "py", "cxx", "py"],
+        net_threads=net_threads,
         trace_dir=str(trace_dir),
         batch_max_items=4,
         batch_flush_us=2000,
